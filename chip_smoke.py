@@ -1,0 +1,525 @@
+"""chip_smoke.py: prove that the system starts and computes correctly on
+the TPU it is measured on. One process, no arguments, no network.
+
+Five stages, each driven through the entry points a user calls, each
+checked by the repo's own means (a NumPy float32 statement of the updater
+rule, a falling finite loss, ``parallel.ring.reference_attention``):
+
+  device  a TPU is present; versions and the compile-cache directory
+  tables  sync plane: MatrixTable row adds/gets, ArrayTable add/get
+  we      WordEmbedding: fused trainer, then the PS-block trainer
+  ps      uncoordinated plane: a two-rank world with device-backed shards
+  lm      the 472M transformer step with the Pallas flash kernel
+
+and a closing ``memory`` check that every device ended up holding bytes.
+
+Exit 0 only if every stage passed; the last stdout line is then
+``{"ok": true, "device": {...}, ...}``. Without a TPU it names the reason
+and exits non-zero before any stage runs: nothing here pins the CPU,
+interprets a kernel or swaps one attention for another. The stage
+functions take their sizes as arguments so tests/test_chip_smoke.py can
+run them tiny on the CPU mesh (``chip=False`` drops the assertions only a
+TPU can meet).
+
+This is a health check, not a benchmark: its wall times are set-up costs
+(compilation included) and are not device metrics.
+"""
+
+from __future__ import annotations
+
+import json
+import sys
+import tempfile
+import time
+import traceback
+from typing import Any, Callable, Dict, List, Tuple
+
+import numpy as np
+
+# ---------------------------------------------------------------------- #
+# tolerances (every comparison in this file uses one of these)
+# ---------------------------------------------------------------------- #
+# f32 updater rules vs NumPy f32: the chip's divide/sqrt differ from
+# NumPy's in the last bits, and three chained adagrad steps compound that
+TABLE_RTOL, TABLE_ATOL = 1e-4, 1e-5
+# bf16 attention vs an f32 reference of the same bf16 inputs, as max|err|
+# over max|reference|: bf16 keeps 8 mantissa bits (2^-8 = 4e-3 per
+# rounding) and the kernel rounds p, ds and the output
+ATTN_BF16_TOL = 2e-2
+SEED = 7
+
+# not 2 or 3: the chip tool uses those for calls it refused or lost
+EXIT_NO_CHIP = 4
+EXIT_STAGE_FAILED = 1
+
+
+class NoChip(RuntimeError):
+    """JAX found no TPU: the smoke has nothing to say."""
+
+
+def _say(stage: str, **facts: Any) -> None:
+    print(json.dumps({"stage": stage, **facts}, default=str), flush=True)
+
+
+def _close(got: np.ndarray, want: np.ndarray, what: str) -> float:
+    """Assert table parity at TABLE_RTOL/TABLE_ATOL; return max |err|."""
+    got, want = np.asarray(got), np.asarray(want)
+    if got.shape != want.shape:
+        raise AssertionError(f"{what}: shape {got.shape} != {want.shape}")
+    if not np.all(np.isfinite(got)):
+        raise AssertionError(f"{what}: non-finite values")
+    np.testing.assert_allclose(got, want, rtol=TABLE_RTOL, atol=TABLE_ATOL,
+                               err_msg=what)
+    return float(np.max(np.abs(got - want))) if got.size else 0.0
+
+
+def _on_all_devices(arr, what: str) -> List[int]:
+    """Assert ``arr`` is sharded over every device; return their ids."""
+    import jax
+    ids = sorted(d.id for d in arr.sharding.device_set)
+    if len(ids) != jax.device_count():
+        raise AssertionError(
+            f"{what} lives on devices {ids}, not all {jax.device_count()}")
+    return ids
+
+
+# ---------------------------------------------------------------------- #
+# NumPy float32 statements of the updater rules (updaters/__init__.py)
+# ---------------------------------------------------------------------- #
+def _np_dedupe(ids: np.ndarray, vals: np.ndarray
+               ) -> Tuple[np.ndarray, np.ndarray]:
+    """Duplicate ids in one call sum their deltas (float64 accumulate, one
+    cast) before the updater sees them — the rule of both table planes."""
+    uids, inv = np.unique(ids, return_inverse=True)
+    acc = np.zeros((uids.size, vals.shape[1]), np.float64)
+    np.add.at(acc, inv, vals.astype(np.float64))
+    return uids, acc.astype(np.float32)
+
+
+def _np_adagrad_rows(data, g_sqr, ids, vals, lr: float, rho: float,
+                     eps: float = 1e-10) -> None:
+    """adagrad: G += d^2 / lr^2 ; data -= d * rho / (sqrt(G) + eps)."""
+    uids, d = _np_dedupe(ids, vals)
+    lr, rho = np.float32(lr), np.float32(rho)
+    g_sqr[uids] += np.square(d) / np.square(lr)
+    data[uids] -= d * rho / (np.sqrt(g_sqr[uids]) + np.float32(eps))
+
+
+def _np_default_rows(data, ids, vals) -> None:
+    """default: data += delta."""
+    uids, d = _np_dedupe(ids, vals)
+    data[uids] += d
+
+
+# ---------------------------------------------------------------------- #
+# stages
+# ---------------------------------------------------------------------- #
+def stage_device() -> Dict[str, Any]:
+    """A TPU is there; say what it is and where compiled programs go."""
+    from importlib import metadata
+
+    import jax
+    import jaxlib
+
+    from multiverso_tpu.utils.platform import enable_compile_cache
+
+    cache_dir = enable_compile_cache()
+    try:
+        devices = jax.devices()
+    except RuntimeError as e:
+        raise NoChip(f"JAX could not initialise a backend: {e}") from e
+    platform = devices[0].platform
+    if platform != "tpu":
+        raise NoChip(f"jax.devices()[0].platform is {platform!r}, not 'tpu' "
+                     f"({len(devices)} {platform} device(s) found)")
+    try:
+        libtpu = metadata.version("libtpu")
+    except metadata.PackageNotFoundError:
+        libtpu = "not installed"
+    return {"platform": platform, "kind": devices[0].device_kind,
+            "count": len(devices), "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__, "libtpu": libtpu,
+            "compile_cache_dir": cache_dir}
+
+
+def stage_tables(rows: int = 100_000, cols: int = 128, batch: int = 4096,
+                 array_size: int = 1_000_000) -> Dict[str, Any]:
+    """Sync plane on the default mesh: the matrix_sparse_row_add shape
+    (three duplicate-carrying adagrad row batches, then a row get) and a
+    whole-table ArrayTable add / add_async+wait / get, against NumPy."""
+    import multiverso_tpu as mv
+    from multiverso_tpu.updaters import AddOption
+
+    mv.init()
+    rng = np.random.default_rng(SEED)
+    lr, rho = 0.05, 0.1
+    opt = AddOption(learning_rate=lr, rho=rho)
+
+    mt = mv.MatrixTable(rows, cols, updater="adagrad", name="smoke_rows")
+    matrix_devices = _on_all_devices(mt.raw(), "MatrixTable")
+    want = np.zeros((rows, cols), np.float32)
+    g_sqr = np.zeros((rows, cols), np.float32)
+    touched = []
+    for _ in range(3):
+        # draws from half the id range: duplicates inside each batch and
+        # rows revisited across batches (the g² history must carry over)
+        ids = rng.integers(0, max(rows // 2, 1), batch).astype(np.int32)
+        vals = rng.normal(size=(batch, cols)).astype(np.float32)
+        mt.add_rows(ids, vals, opt)
+        _np_adagrad_rows(want, g_sqr, ids, vals, lr, rho)
+        touched.append(ids)
+    probe = np.concatenate(touched + [np.arange(rows - 8, rows)])
+    rows_err = _close(mt.get_rows(probe), want[probe], "MatrixTable rows")
+    duplicates = int(sum(t.size - np.unique(t).size for t in touched))
+
+    at = mv.ArrayTable(array_size, updater="default", name="smoke_array")
+    array_devices = _on_all_devices(at.raw(), "ArrayTable")
+    d1 = rng.normal(size=array_size).astype(np.float32)
+    d2 = rng.normal(size=array_size).astype(np.float32)
+    at.add(d1)
+    at.wait(at.add_async(d2))
+    array_err = _close(at.get(), d1 + d2, "ArrayTable")
+    return {"matrix": f"{rows}x{cols} adagrad", "batch": batch,
+            "duplicate_ids": duplicates, "rows_max_abs_err": rows_err,
+            "array": array_size, "array_max_abs_err": array_err,
+            "matrix_devices": matrix_devices,
+            "array_devices": array_devices}
+
+
+def _falls(losses: List[float], what: str) -> None:
+    if not all(np.isfinite(losses)):
+        raise AssertionError(f"{what}: non-finite loss in {losses}")
+    if not losses[-1] < losses[0]:
+        raise AssertionError(f"{what}: loss did not fall: {losses}")
+
+
+def _finite_table(table, what: str) -> None:
+    if not np.all(np.isfinite(table.get())):
+        raise AssertionError(f"{what} holds NaN/Inf after training")
+
+
+def stage_we(fused_tokens: int = 400_000, fused_vocab: int = 10_000,
+             fused_batch: int = 16384, shared_negatives: int = 256,
+             ps_tokens: int = 120_000, ps_vocab: int = 5_000,
+             ps_batch: int = 8192, ps_block: int = 50_000,
+             dim: int = 128) -> Dict[str, Any]:
+    """The flagship trainer through its normal entry, at the bench
+    configuration: three fused epochs, then two passes of the PS-block
+    trainer (pull -> train -> push per block) on the device plane."""
+    import jax.numpy as jnp
+
+    from multiverso_tpu.apps.word_embedding import (WEConfig, WordEmbedding,
+                                                    synthetic_corpus)
+    from multiverso_tpu.data.dictionary import Dictionary
+
+    tokens = synthetic_corpus(fused_tokens, vocab=fused_vocab, seed=SEED)
+    cfg = WEConfig(size=dim, min_count=5, batch_size=fused_batch, negative=5,
+                   window=5, epoch=1, shared_negatives=shared_negatives)
+    we = WordEmbedding(cfg, Dictionary.build(tokens, cfg.min_count))
+    ids = we.prepare_ids(tokens)
+    fused = [we.train_fused(ids, epochs=1)["loss"] for _ in range(3)]
+    _falls(fused, "train_fused")
+    for t in (we.table_in, we.table_out):
+        _finite_table(t, f"fused {t.name}")
+    fused_devices = _on_all_devices(we.table_in.raw(), "fused embed_in")
+
+    tokens = synthetic_corpus(ps_tokens, vocab=ps_vocab, seed=11)
+    cfg = WEConfig(size=dim, min_count=5, batch_size=ps_batch, negative=5,
+                   window=5, epoch=1, data_block_size=ps_block, use_ps="1")
+    wps = WordEmbedding(cfg, Dictionary.build(tokens, cfg.min_count))
+    ids_ps = wps.prepare_ids(tokens)
+    blocks = -(-ids_ps.size // ps_block)
+    if blocks < 2:
+        raise AssertionError(f"PS-block run has {blocks} block(s); the "
+                             "smoke needs at least two")
+    if not wps._use_device_plane(wps._ps_topology()[0]):
+        raise AssertionError("PS-block trainer did not take the device "
+                             "plane on a single worker")
+    ps = [wps.train_ps_blocks(ids_ps, epochs=1)["loss"] for _ in range(2)]
+    _falls(ps, "train_ps_blocks")
+    for t in (wps.table_in, wps.table_out):
+        _finite_table(t, f"ps-block {t.name}")
+    return {"fused_loss": [round(x, 4) for x in fused],
+            "fused_compute_dtype": jnp.dtype(we.fused_compute_dtype).name,
+            "fused_tokens": int(ids.size), "vocab": len(we.dict),
+            "fused_devices": fused_devices,
+            "ps_block_loss": [round(x, 4) for x in ps],
+            "ps_blocks_per_pass": blocks, "ps_device_plane": True,
+            "ps_tokens": int(ids_ps.size)}
+
+
+def stage_ps(rows: int = 100_000, cols: int = 128, batch: int = 4096,
+             chip: bool = True) -> Dict[str, Any]:
+    """Uncoordinated plane: a two-rank world inside this process (every
+    cross-rank op crosses a localhost socket), one adagrad table and one
+    stateless table, row adds spanning both owners, gets from both ranks,
+    against NumPy. On a TPU the shards must be device-backed — the branch
+    no CPU run reaches."""
+    import jax
+
+    from multiverso_tpu.ps.service import (FileRendezvous, PSContext,
+                                           PSService)
+    from multiverso_tpu.ps.tables import AsyncMatrixTable
+    from multiverso_tpu.updaters import AddOption
+
+    rng = np.random.default_rng(SEED + 1)
+    lr, rho = 0.05, 0.1
+    opt = AddOption(learning_rate=lr, rho=rho)
+    out: Dict[str, Any] = {}
+    with tempfile.TemporaryDirectory(prefix="mv_smoke_rdv_") as rdv_dir:
+        rdv = FileRendezvous(rdv_dir)
+        ctxs = [PSContext(r, 2, PSService(r, 2, rdv)) for r in range(2)]
+        try:
+            for updater in ("adagrad", "default"):
+                tables = [AsyncMatrixTable(rows, cols, updater=updater,
+                                           name=f"smoke_ps_{updater}", ctx=c)
+                          for c in ctxs]
+                want = np.zeros((rows, cols), np.float32)
+                g_sqr = np.zeros((rows, cols), np.float32)
+                sent = []
+                for t in tables:   # each rank pushes its own batch
+                    ids = rng.integers(0, rows, batch).astype(np.int64)
+                    vals = rng.normal(size=(batch, cols)).astype(np.float32)
+                    owners = np.unique(ids // -(-rows // 2))
+                    if owners.size != 2:
+                        raise AssertionError("batch does not span both "
+                                             f"owners: {owners}")
+                    t.add_rows(ids, vals, opt)
+                    if updater == "adagrad":
+                        _np_adagrad_rows(want, g_sqr, ids, vals, lr, rho)
+                    else:
+                        _np_default_rows(want, ids, vals)
+                    sent.append(ids)
+                probe = np.concatenate(sent)
+                err = max(_close(t.get_rows(probe), want[probe],
+                                 f"ps[{updater}] get_rows from rank {r}")
+                          for r, t in enumerate(tables))
+                shards = [t._shard for t in tables]
+                facts = {
+                    "max_abs_err": err,
+                    "host_serve": [s._host_serve for s in shards],
+                    "np_mode": [s._np_mode for s in shards],
+                    # a numpy-mode shard (CPU only) has no devices
+                    "shard_devices": [
+                        sorted(f"{d.platform}:{d.id}"
+                               for d in s._data.devices())
+                        if isinstance(s._data, jax.Array) else []
+                        for s in shards],
+                    "local_sharding": [s._local_sharding is not None
+                                       for s in shards],
+                    "natively_served_shard": [s._native_ref is not None
+                                              for s in shards],
+                }
+                if chip:
+                    if any(facts["host_serve"]) or any(facts["np_mode"]):
+                        raise AssertionError(
+                            f"ps[{updater}] shard is host-served on a "
+                            f"TPU: {facts}")
+                    for s in shards:
+                        for leaf in jax.tree.leaves((s._data, s._ustate)):
+                            kinds = {d.platform for d in leaf.devices()}
+                            if kinds != {"tpu"}:
+                                raise AssertionError(
+                                    f"ps[{updater}] buffer on {kinds}")
+                    if jax.device_count() > 1 and not all(
+                            facts["local_sharding"]):
+                        raise AssertionError(
+                            f"ps[{updater}] shard of "
+                            f"{rows // 2 * cols * 4 / 1e6:.1f} MB is not "
+                            "sharded over the local devices")
+                out[updater] = facts
+            # the wire: C++ connection threads when libmv_ps built (they
+            # punt device-backed shards' ops to the Python handler), else
+            # the Python plane end to end
+            out["wire_plane"] = ("native" if all(
+                c.service._native is not None for c in ctxs) else "python")
+        finally:
+            for c in ctxs:
+                c.close()
+    return out
+
+
+def _attention_errors(shape: Tuple[int, int, int, int], block: int,
+                      seed: int) -> Dict[str, float]:
+    """flash_attention forward and gradients vs reference_attention run in
+    f32 on the same bf16 inputs: max|err| / max|reference| per tensor."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.ops.attention_kernels import flash_attention
+    from multiverso_tpu.parallel.ring import reference_attention
+
+    rng = np.random.default_rng(seed)
+    q, k, v, g = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                  for _ in range(4))
+
+    def fwd_and_grads(fn, *args):
+        out, vjp = jax.vjp(fn, *args)
+        return (out,) + vjp(g.astype(out.dtype))
+
+    got = jax.jit(lambda q, k, v: fwd_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, True, block, block),
+        q, k, v))(q, k, v)
+    f32 = lambda t: t.astype(jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda q, k, v: fwd_and_grads(
+            lambda q, k, v: reference_attention(q, k, v, causal=True),
+            f32(q), f32(k), f32(v)))(q, k, v)
+    errs = {}
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if not np.all(np.isfinite(a)):
+            raise AssertionError(f"flash {name} {shape}/{block}: non-finite")
+        errs[name] = float(np.max(np.abs(a - b)) / np.max(np.abs(b)))
+        if errs[name] > ATTN_BF16_TOL:
+            raise AssertionError(
+                f"flash {name} {shape} block {block}: relative error "
+                f"{errs[name]:.4f} > {ATTN_BF16_TOL}")
+    return errs
+
+
+def stage_lm(vocab: int = 32768, dim: int = 2048, heads: int = 16,
+             layers: int = 8, seq: int = 1024, batch_per_chip: int = 2,
+             kernel_shapes: Tuple = (((2, 16, 1024, 128), 512),
+                                     ((2, 16, 1024, 128), 128),
+                                     ((8, 8, 512, 32), 512)),
+             chip: bool = True) -> Dict[str, Any]:
+    """The widest model the repo runs (472M, d2048/L8, bf16) with the
+    Pallas flash kernel: three donated train steps on a fixed batch, then
+    the kernel alone against reference_attention. With several devices the
+    batch shards over the mesh and the kernel runs under shard_map."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import multiverso_tpu as mv
+    from multiverso_tpu.models import transformer as tfm
+    from multiverso_tpu.ops.attention_kernels import _resolve_interpret
+
+    mv.init()
+    interpret = _resolve_interpret(None)
+    if chip and interpret is not False:
+        raise AssertionError("flash kernel would run in interpret mode")
+    mesh, n_dev = mv.mesh(), jax.device_count()
+    cfg = tfm.TransformerConfig(
+        vocab_size=vocab, dim=dim, num_heads=heads, num_layers=layers,
+        max_seq=seq, attn="flash", dtype=jnp.bfloat16,
+        batch_axis=mesh.axis_names[0] if n_dev > 1 else None)
+    params = jax.device_put(tfm.init_params(cfg, seed=0),
+                            NamedSharding(mesh, P()))
+    n_params = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
+    b = batch_per_chip * n_dev
+    toks = np.random.default_rng(0).integers(
+        0, vocab, (b, seq + 1)).astype(np.int32)
+    tok = tfm.shard_batch(toks[:, :-1], cfg)
+    tgt = tfm.shard_batch(toks[:, 1:], cfg)
+    # donated params, as bench.bench_transformer steps it
+    step = jax.jit(tfm.make_train_step(cfg, 1e-2), donate_argnums=(0,))
+    mosaic = "tpu_custom_call" in step.lower(params, tok, tgt).as_text()
+    if chip and not mosaic:
+        raise AssertionError("lowered train step has no Mosaic custom call")
+    losses = []
+    for _ in range(3):
+        params, loss = step(params, tok, tgt)
+        losses.append(float(loss))
+    _falls(losses, "lm train step")
+    if not all(bool(jnp.all(jnp.isfinite(p.astype(jnp.float32))))
+               for p in jax.tree.leaves(params)):
+        raise AssertionError("lm params hold NaN/Inf after three steps")
+    del params
+    kernel = {f"{shape}/{block}": _attention_errors(shape, block, SEED + i)
+              for i, (shape, block) in enumerate(kernel_shapes)}
+    return {"params": n_params, "global_batch": b, "seq": seq,
+            "batch_axis": cfg.batch_axis, "interpret": interpret,
+            "mosaic_custom_call": mosaic,
+            "loss": [round(x, 4) for x in losses],
+            "kernel_rel_err": kernel, "kernel_tol": ATTN_BF16_TOL}
+
+
+def stage_memory() -> Dict[str, Any]:
+    """After the run every device holds bytes: every chip was used."""
+    import jax
+    used = [int((d.memory_stats() or {}).get("bytes_in_use", 0))
+            for d in jax.devices()]
+    if not all(used):
+        raise AssertionError(f"a device holds no bytes: {used}")
+    return {"bytes_in_use": used}
+
+
+def _cache_counters() -> Dict[str, int]:
+    """Count persistent-compile-cache hits and writes from here on."""
+    import jax.monitoring
+
+    counts = {"hits": 0, "writes": 0}
+    names = {"/jax/compilation_cache/cache_hits": "hits",
+             "/jax/compilation_cache/cache_misses": "writes"}
+
+    def on_event(event: str, **_kw) -> None:
+        if event in names:
+            counts[names[event]] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    return counts
+
+
+STAGES: Tuple[Tuple[str, Callable[[], Dict[str, Any]]], ...] = (
+    ("tables", stage_tables), ("we", stage_we), ("ps", stage_ps),
+    ("lm", stage_lm), ("memory", stage_memory))
+
+
+def main() -> int:
+    t_start = time.perf_counter()
+    try:
+        import multiverso_tpu as mv
+    except ImportError as e:
+        print(f"chip_smoke: the multiverso_tpu package is not beside this "
+              f"script: {e}", file=sys.stderr)
+        return EXIT_NO_CHIP
+    try:
+        device = stage_device()
+    except NoChip as e:
+        print(f"chip_smoke: no TPU, nothing checked: {e}", file=sys.stderr)
+        return EXIT_NO_CHIP
+    _say("device", ok=True, **device)
+    cache = _cache_counters()
+    from multiverso_tpu import native
+    from multiverso_tpu.ps import native as ps_native
+    _say("native", libmv_data=native.available(),
+         libmv_ps=ps_native.available(),
+         build_failures={n: native.build_failure(n)
+                         for n in ("libmv_data.so", "libmv_ps.so")
+                         if native.build_failure(n)})
+
+    verdicts: Dict[str, str] = {}
+    for name, stage in STAGES:
+        t0 = time.perf_counter()
+        try:
+            facts = stage()
+            verdicts[name] = "pass"
+            _say(name, ok=True, seconds=round(time.perf_counter() - t0, 1),
+                 **facts)
+        except Exception as e:   # noqa: BLE001 — report, run the rest
+            verdicts[name] = "FAIL"
+            traceback.print_exc()
+            _say(name, ok=False, seconds=round(time.perf_counter() - t0, 1),
+                 error=f"{type(e).__name__}: {e}"[:2000])
+    mv.shutdown()
+
+    ok = all(v == "pass" for v in verdicts.values())
+    if not ok:
+        print(f"chip_smoke: FAILED {verdicts}", file=sys.stderr)
+        return EXIT_STAGE_FAILED
+    print(json.dumps({
+        "ok": True,
+        "device": {"platform": device["platform"], "kind": device["kind"],
+                   "count": device["count"]},
+        "stages": verdicts,
+        "compile_cache": {"dir": device["compile_cache_dir"], **cache},
+        "wall_s": round(time.perf_counter() - t_start, 1),
+        "claim": None,
+    }), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -5,8 +5,8 @@
 # (mpirun -np 4 distributed_wordembedding), rebuilt TPU-native.
 # Mirrors tests/we_async_worker.py, runnable by hand.
 #
-# The wire rides the native C++ transport when libmv_ps.so builds
-# (auto-built on first use); MV_PS_NATIVE=0 ./async_ps_demo.sh forces
+# The wire rides the native C++ transport when libmv_ps builds
+# (on first use); MV_PS_NATIVE=0 ./async_ps_demo.sh forces
 # the pure-python plane for an A/B.
 set -e
 cd "$(dirname "$0")/.."
@@ -14,6 +14,10 @@ cd "$(dirname "$0")/.."
 # tests/ — the repo root must come from PYTHONPATH
 PYTHONPATH="$PWD${PYTHONPATH:+:$PYTHONPATH}"
 export PYTHONPATH
+# one host, four processes: each on the CPU backend (one chip can't be
+# shared); the worker pins it in code too (tests/we_async_worker.py)
+JAX_PLATFORMS=cpu
+export JAX_PLATFORMS
 RDV=$(mktemp -d)
 PIDS=""
 # kill stragglers before deleting their rendezvous dir (a crashed rank

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from multiverso_tpu.parallel import ring
-from multiverso_tpu.utils.platform import shard_map as _shard_map
 
 
 class TransformerConfig(NamedTuple):
@@ -123,7 +122,7 @@ def _attention(cfg: TransformerConfig, q, k, v):
 
         from multiverso_tpu.zoo import Zoo
         spec = P(cfg.batch_axis, cfg.tp_axis, None, None)
-        return _shard_map(
+        return jax.shard_map(
             lambda q, k, v: flash_attention(q, k, v, True, blk, blk),
             mesh=Zoo.get().mesh(), in_specs=(spec, spec, spec),
             out_specs=spec, check_vma=False)(q, k, v)

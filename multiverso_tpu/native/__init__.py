@@ -1,58 +1,116 @@
-"""ctypes loader for the native data pipeline (libmv_data.so).
+"""ctypes loader for the native data pipeline (libmv_data).
 
-The library is optional: if the .so is missing it is built on first use when
-a toolchain is present, else callers fall back to the pure-Python/numpy
-implementations (``available()`` reports which path is active). See
-mv_data.cpp for what lives here and why.
+The library is optional: it is built on first use when a toolchain is
+present, else callers fall back to the pure-Python/numpy implementations
+(``available()`` reports which path is active, :func:`build_failure` why
+a build did not produce a library). See mv_data.cpp for what lives here
+and why.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from multiverso_tpu.utils import log
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_DIR, "libmv_data.so")
 _lib = None
 _lock = threading.Lock()
 _build_failed = False
+
+_CXX_FLAGS = ("-O3", "-std=c++17", "-fPIC", "-shared", "-march=native")
+_build_failures: Dict[str, str] = {}
+
+
+def _host_id() -> str:
+    """What ``-march=native`` resolved against: this host's CPU model and
+    instruction-set flags. A library built on another CPU may use
+    instructions this one lacks, so the host is part of the build key."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            seen: Dict[str, str] = {}
+            for line in f:
+                key = line.split(":", 1)[0].strip()
+                if key in ("model name", "flags", "Features"):
+                    seen.setdefault(key, line)
+        if seen:
+            return "".join(seen[k] for k in sorted(seen))
+    except OSError:
+        pass
+    return platform.machine() + platform.processor()
+
+
+def artefact_path(so_name: str, src_name: str,
+                  extra_flags: Tuple[str, ...] = ()) -> str:
+    """Where the build of ``native/<src_name>`` for THIS source, compiler,
+    flag set and host lives: ``native/<stem>.<key>.so``. The key in the
+    name is the stamp — a library whose name does not carry the current
+    key (a stale build, one copied from another machine, one made by
+    hand) is never loaded."""
+    h = hashlib.sha256()
+    with open(os.path.join(_DIR, src_name), "rb") as f:
+        h.update(f.read())
+    h.update("\0".join((os.environ.get("CXX", "g++"), *_CXX_FLAGS,
+                        *extra_flags, _host_id())).encode())
+    return os.path.join(
+        _DIR, f"{so_name.removesuffix('.so')}.{h.hexdigest()[:16]}.so")
+
+
+def build_failure(so_name: str) -> Optional[str]:
+    """Why the last build of ``so_name`` produced no library (None when it
+    did, or was never attempted)."""
+    return _build_failures.get(so_name)
 
 
 def build_and_load(so_name: str, src_name: str,
                    extra_flags: Tuple[str, ...] = (),
                    timeout: int = 180) -> Optional[ctypes.CDLL]:
-    """Build ``native/<src_name>`` into ``native/<so_name>`` if missing
-    (atomic rename so concurrent workers never load a half-written .so),
-    then CDLL it. One implementation for every native helper's
-    build-on-first-use path (this module and ps/native). Returns None when
-    no toolchain produced a loadable library."""
-    so = os.path.join(_DIR, so_name)
+    """Build ``native/<src_name>`` into :func:`artefact_path` unless that
+    exact build already exists (atomic rename so concurrent workers never
+    load a half-written .so), then CDLL it. One implementation for every
+    native helper's build-on-first-use path (this module and ps/native).
+    Returns None when no loadable library resulted; the reason — with the
+    compiler's stderr when it ran — is logged and kept for
+    :func:`build_failure`."""
+    so = artefact_path(so_name, src_name, extra_flags)
     if not os.path.exists(so):
         tmp = f"{so}.build.{os.getpid()}"
+        cmd = [os.environ.get("CXX", "g++"), *_CXX_FLAGS, *extra_flags,
+               "-o", tmp, os.path.join(_DIR, src_name)]
         try:
-            subprocess.run(
-                [os.environ.get("CXX", "g++"), "-O3", "-std=c++17",
-                 "-fPIC", "-shared", "-march=native", *extra_flags,
-                 "-o", tmp, os.path.join(_DIR, src_name)],
-                check=True, capture_output=True, timeout=timeout)
+            subprocess.run(cmd, check=True, capture_output=True, text=True,
+                           timeout=timeout)
             os.replace(tmp, so)
-        except (subprocess.SubprocessError, OSError):
+        except FileNotFoundError:
+            _build_failures[so_name] = f"no C++ compiler ({cmd[0]!r})"
+            log.info("native %s not built: %s; the Python plane serves",
+                     so_name, _build_failures[so_name])
+            return None
+        except (subprocess.SubprocessError, OSError) as e:
+            stderr = getattr(e, "stderr", None) or ""
+            _build_failures[so_name] = f"{e}\n{stderr[-2000:]}".strip()
+            log.error("native %s BUILD FAILED; the Python plane serves: %s",
+                      so_name, _build_failures[so_name])
+            return None
+        finally:
             if os.path.exists(tmp):
-                try:
-                    os.remove(tmp)
-                except OSError:
-                    pass
-            if not os.path.exists(so):
-                return None
+                os.remove(tmp)
     try:
-        return ctypes.CDLL(so)
-    except OSError:
+        lib = ctypes.CDLL(so)
+    except OSError as e:
+        _build_failures[so_name] = f"built library failed to load: {e}"
+        log.error("native %s: %s", so_name, _build_failures[so_name])
         return None
+    _build_failures.pop(so_name, None)
+    return lib
 
 
 def _try_load() -> Optional[ctypes.CDLL]:

@@ -7,7 +7,7 @@
  * `make -C multiverso_tpu/native capi_test` (CI) and
  * tests/test_bindings.py.
  *
- * Requires PYTHONPATH to reach multiverso_tpu; set MV_CAPI_PLATFORM=cpu
+ * Requires PYTHONPATH to reach multiverso_tpu; set JAX_PLATFORMS=cpu
  * to keep the embedded interpreter off the (single) TPU chip.
  */
 

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 
 from multiverso_tpu.updaters import AddOption
-from multiverso_tpu.utils import platform as _platform
 
 AXIS = "shards"
 
@@ -79,7 +78,7 @@ def build_apply(updater, row_axes, mesh: Optional[Any]):
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
         spec = P(AXIS)
-        inner = _platform.shard_map(
+        inner = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(spec, spec, spec, spec, spec),
             out_specs=(spec, spec))
@@ -97,7 +96,7 @@ def build_gather(mesh: Optional[Any]):
     if mesh is not None:
         from jax.sharding import PartitionSpec as P
         spec = P(AXIS)
-        inner = _platform.shard_map(inner, mesh=mesh,
+        inner = jax.shard_map(inner, mesh=mesh,
                                     in_specs=(spec, spec),
                                     out_specs=spec)
     return jax.jit(inner)

@@ -38,8 +38,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from multiverso_tpu.telemetry import devstats as _devstats
-from multiverso_tpu.utils.platform import (
-    axis_size as _axis_size, shard_map as _shard_map)
 from multiverso_tpu.zoo import Zoo
 
 
@@ -132,7 +130,7 @@ def all_reduce(x, axis: Optional[str] = None,
     x = jnp.asarray(x)
 
     def build():
-        @partial(_shard_map, mesh=mesh, in_specs=P(ax), out_specs=P(),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P(ax), out_specs=P(),
                  check_vma=False)
         def _psum(v):
             return jax.lax.psum(v, ax)
@@ -150,7 +148,7 @@ def all_gather(x, axis: Optional[str] = None,
     x = jnp.asarray(x)
 
     def build():
-        @partial(_shard_map, mesh=mesh, in_specs=P(ax), out_specs=P(),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P(ax), out_specs=P(),
                  check_vma=False)
         def _ag(v):
             return jax.lax.all_gather(v, ax, tiled=True)
@@ -169,10 +167,10 @@ def reduce_scatter(x, axis: Optional[str] = None,
     x = jnp.asarray(x)
 
     def build():
-        @partial(_shard_map, mesh=mesh, in_specs=P(), out_specs=P(ax),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P(), out_specs=P(ax),
                  check_vma=False)
         def _rs(v):
-            n = _axis_size(ax)
+            n = jax.lax.axis_size(ax)
             i = jax.lax.axis_index(ax)
             chunk = v.shape[0] // n
             return jax.lax.dynamic_slice_in_dim(v, i * chunk, chunk)
@@ -190,7 +188,7 @@ def broadcast(x, root: int = 0, axis: Optional[str] = None,
     x = jnp.asarray(x)
 
     def build():
-        @partial(_shard_map, mesh=mesh, in_specs=P(ax), out_specs=P(),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P(ax), out_specs=P(),
                  check_vma=False)
         def _bc(v):
             full = jax.lax.all_gather(v, ax)

@@ -30,8 +30,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from multiverso_tpu.zoo import Zoo
-from multiverso_tpu.utils.platform import (
-    axis_size as _axis_size, shard_map as _shard_map)
 
 
 class MoEConfig(NamedTuple):
@@ -100,7 +98,7 @@ def _local_moe(x, w1, w2, router, cfg: MoEConfig, capacity: int,
                batch_axis: Optional[str] = None):
     """Per-shard body. x: [T_local, D]; w1/w2: local experts [E_local, ...]."""
     ax = cfg.axis
-    n = _axis_size(ax)
+    n = jax.lax.axis_size(ax)
     e = cfg.num_experts
     e_local = e // n
     t = x.shape[0]
@@ -192,7 +190,7 @@ def moe_layer(x: jax.Array, params: Dict, cfg: MoEConfig,
                                      batch_axis)
         return y.reshape(x.shape), aux, dropped
 
-    y, aux, dropped = _shard_map(
+    y, aux, dropped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, espec, espec, P()),
         out_specs=(xspec, P(), P()), check_vma=False)(

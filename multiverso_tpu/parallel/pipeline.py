@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from multiverso_tpu.zoo import Zoo
-from multiverso_tpu.utils.platform import shard_map as _shard_map
 
 
 def shard_stages(stacked_params: Any, axis: str = "pp",
@@ -120,7 +119,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
     pspec = (param_specs if param_specs is not None
              else jax.tree.map(lambda _: P(axis), stage_params))
     xspec = P(None, batch_axis) if batch_axis else P()
-    out = _shard_map(body, mesh=mesh,
+    out = jax.shard_map(body, mesh=mesh,
                         in_specs=(pspec, xspec), out_specs=xspec,
                         check_vma=False)(stage_params, xs)
     return out.reshape(b, *x.shape[1:])
@@ -227,7 +226,7 @@ def pipeline_apply_interleaved(stage_fn: Callable[[Any, jax.Array],
     pspec = (param_specs if param_specs is not None
              else jax.tree.map(lambda _: P(axis), stage_params))
     xspec = P(None, batch_axis) if batch_axis else P()
-    out = _shard_map(body, mesh=mesh,
+    out = jax.shard_map(body, mesh=mesh,
                         in_specs=(pspec, xspec), out_specs=xspec,
                         check_vma=False)(stage_params, xs)
     return out.reshape(b, *x.shape[1:])

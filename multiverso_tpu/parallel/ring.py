@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from multiverso_tpu.telemetry import devstats as _devstats
-from multiverso_tpu.utils.platform import (
-    axis_size as _axis_size, shard_map as _shard_map)
 from multiverso_tpu.zoo import Zoo
 
 # jit-wrapped shard_map callable cache keyed on EVERY closed-over
@@ -88,7 +86,7 @@ def _online_update(qc, kc, vc, scale, allowed, m, l, o):
 def _ring_attention_local(q, k, v, axis_name: str, scale: float,
                           causal: bool = False):
     """Per-shard body: local q [B,H,Sq,D] against rotating k/v blocks."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, h, sq, d = q.shape
     sk = k.shape[2]
@@ -154,7 +152,7 @@ def ring_attention(q, k, v, axis_name: Optional[str] = None,
     mapped = _mapped(
         ("ring", mesh, ax, scale, causal, batch_axis, head_axis,
          precision),
-        lambda: _shard_map(
+        lambda: jax.shard_map(
             partial(_ring_attention_local, axis_name=ax, scale=scale,
                     causal=causal),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
@@ -206,7 +204,7 @@ def ulysses_attention(q, k, v, axis_name: Optional[str] = None,
     nbytes = q.nbytes + k.nbytes + v.nbytes
     mapped = _mapped(
         ("ulysses", mesh, ax, scale, causal, batch_axis),
-        lambda: _shard_map(local, mesh=mesh,
+        lambda: jax.shard_map(local, mesh=mesh,
                            in_specs=(spec, spec, spec), out_specs=spec))
     with _devstats.collective_span("ulysses_attention", nbytes, mesh=mesh):
         return mapped(q, k, v)
@@ -235,7 +233,7 @@ def _zigzag_ring_local(q, k, v, axis_name: str, scale: float):
     each (q-chunk, k-chunk) pair is decided per tick with ``lax.switch`` so
     dead pairs cost nothing and every shard computes exactly 2 of 4 pairs
     every tick — balanced, ~half the FLOPs of masked contiguous ring."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, h, s2, d = q.shape
     c = s2 // 2
@@ -316,7 +314,7 @@ def zigzag_ring_attention(q, k, v, axis_name: Optional[str] = None,
     spec = P(batch_axis, head_axis, ax, None)
     mapped = _mapped(
         ("zigzag", mesh, ax, scale, batch_axis, head_axis, precision),
-        lambda: _shard_map(
+        lambda: jax.shard_map(
             partial(_zigzag_ring_local, axis_name=ax, scale=scale),
             mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
             check_vma=False))

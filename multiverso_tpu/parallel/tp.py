@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from multiverso_tpu.telemetry import devstats as _devstats
-from multiverso_tpu.utils.platform import shard_map as _shard_map
 from multiverso_tpu.zoo import Zoo
 
 
@@ -151,7 +150,7 @@ def column_parallel(x: jax.Array, w: jax.Array, axis: str = "tp",
                                    x.nbytes + w.nbytes, mesh=mesh):
         return _mapped(
             ("col", mesh, axis, lead),
-            lambda: _shard_map(
+            lambda: jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(*lead, None), P(None, axis)),
                 out_specs=P(*lead, axis), check_vma=False))(x, w)
@@ -174,7 +173,7 @@ def row_parallel(x: jax.Array, w: jax.Array, axis: str = "tp",
                                    x.nbytes + w.nbytes, mesh=mesh):
         return _mapped(
             ("row", mesh, axis, lead),
-            lambda: _shard_map(
+            lambda: jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P(*lead, axis), P(axis, None)),
                 out_specs=P(*lead, None), check_vma=False))(x, w)

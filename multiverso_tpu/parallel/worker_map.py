@@ -24,7 +24,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from multiverso_tpu.updaters import AddOption
-from multiverso_tpu.utils.platform import shard_map as _shard_map
 from multiverso_tpu.zoo import Zoo
 
 
@@ -62,7 +61,7 @@ def worker_step(table, grad_fn: Callable, learning_rate: float = 0.1,
     def step(state, batch):
         data = state["data"]
 
-        @partial(_shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P(), P(axis)), out_specs=(P(), P()),
                  check_vma=False)
         def _grads(params, local_batch):

@@ -12,16 +12,15 @@ thin Python face of it:
   buffer-filling gets over one persistent connection, with a C++ recv
   thread (no Python wakeup per reply).
 
-Everything degrades gracefully: if the .so is missing it is built on
-first use when a toolchain is present (same pattern as native/__init__),
-else ``available()`` is False and the pure-Python plane runs unchanged.
+The library is built on first use by ``native.build_and_load`` (keyed to
+its source, flags and build host). Without a toolchain ``available()`` is
+False and the pure-Python plane runs unchanged; a build that FAILED is
+logged with the compiler's stderr (``native.build_failure``).
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import threading
 from typing import Callable, Optional, Tuple
 

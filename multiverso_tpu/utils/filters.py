@@ -6,7 +6,7 @@ rewrites a blob as (index, value) pairs when >50% of entries fall under a
 clip threshold; ``OneBitsFilter`` (:160-161) was declared and never
 implemented). On TPU the intra-pod wire is ICI managed by XLA, so these
 filters matter on the *host/DCN* seams: compressing deltas before
-cross-process aggregation or before a tunneled host<->device transfer.
+cross-process aggregation or before a slow host<->device transfer.
 
 ``OneBitsFilter`` is actually implemented here — 1-bit sign quantization with
 per-block scale and error-feedback residual (the 1-bit SGD recipe the

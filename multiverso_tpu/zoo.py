@@ -28,6 +28,7 @@ import numpy as np
 
 from multiverso_tpu.utils import config, log
 from multiverso_tpu.utils.dashboard import Dashboard
+from multiverso_tpu.utils.platform import enable_compile_cache
 
 
 class Zoo:
@@ -65,6 +66,7 @@ class Zoo:
             return
         config.parse_cmd_flags(argv)
         log.configure_from_flags()
+        enable_compile_cache()   # before the mesh: bound at first compile
         self._mesh = mesh if mesh is not None else self._default_mesh()
         # telemetry plane: adopt the trace_ids flag and start the
         # flag-gated metrics exporter (both no-ops unless configured; a

@@ -15,8 +15,6 @@ def main():
     import jax
     jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_num_cpu_devices", 2)
-    from multiverso_tpu.utils.platform import enable_cpu_collectives
-    enable_cpu_collectives()   # gloo: cross-process CPU computations
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=nprocs, process_id=pid)
     import numpy as np

@@ -144,6 +144,15 @@ def test_dump_metrics_tool(tmp_path):
     assert main(["diff", path, path]) == 0
 
 
+def test_bench_main_refuses_a_cpu():
+    """bench.py prints device numbers only: with no TPU main() exits
+    non-zero before it measures anything."""
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code not in (0, None)
+    assert "needs a TPU" in str(exc.value.code)
+
+
 def test_bench_truncation_recording(tmp_path):
     """The SIGTERM salvage exits bench.TRUNCATED_EXIT (documented,
     nonzero, distinct from a hard failure) and tools/run_bench records

@@ -344,7 +344,7 @@ def test_c_abi_driver_end_to_end():
                            capture_output=True, text=True, timeout=300)
     assert build.returncode == 0, build.stderr[-2000:]
     env = dict(os.environ)
-    env["MV_CAPI_PLATFORM"] = "cpu"   # keep off the single TPU chip
+    env["JAX_PLATFORMS"] = "cpu"   # keep off the single TPU chip
     env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
     run = subprocess.run([os.path.join(native, "mv_capi_test")],
                          capture_output=True, text=True, timeout=300,

@@ -1,0 +1,58 @@
+"""chip_smoke.py between chip runs: every stage function at a tiny size on
+the 8-device CPU mesh, the flash kernel in interpret mode, so the script
+the driver runs on the TPU cannot rot unnoticed. ``chip=False`` drops only
+the assertions a TPU alone can meet (compiled kernel, device-backed
+shards); the comparisons against NumPy and reference_attention all run.
+"""
+
+import os
+import sys
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _REPO)
+
+import chip_smoke  # noqa: E402  (repo-root script, not a package)
+
+
+def test_tables_stage():
+    facts = chip_smoke.stage_tables(rows=1000, cols=16, batch=256,
+                                    array_size=5000)
+    assert facts["duplicate_ids"] > 0   # the dedupe rule was exercised
+    assert facts["rows_max_abs_err"] <= chip_smoke.TABLE_ATOL
+    assert len(facts["matrix_devices"]) == 8
+
+
+def test_we_stage():
+    facts = chip_smoke.stage_we(
+        fused_tokens=30_000, fused_vocab=500, fused_batch=512,
+        shared_negatives=32, ps_tokens=30_000, ps_vocab=500, ps_batch=512,
+        ps_block=8000, dim=16)
+    assert facts["fused_loss"][-1] < facts["fused_loss"][0]
+    assert facts["ps_block_loss"][-1] < facts["ps_block_loss"][0]
+    assert facts["ps_blocks_per_pass"] >= 2
+    assert facts["fused_compute_dtype"] == "float32"   # bf16 is TPU-only
+
+
+def test_ps_stage():
+    facts = chip_smoke.stage_ps(rows=1000, cols=16, batch=256, chip=False)
+    for updater in ("adagrad", "default"):
+        assert facts[updater]["max_abs_err"] <= chip_smoke.TABLE_ATOL
+    assert facts["wire_plane"] in ("native", "python")
+
+
+def test_lm_stage():
+    facts = chip_smoke.stage_lm(
+        vocab=128, dim=32, heads=4, layers=2, seq=32, batch_per_chip=1,
+        kernel_shapes=(((1, 2, 64, 16), 32),), chip=False)
+    assert facts["batch_axis"] == "mv"      # kernel ran under shard_map
+    assert facts["loss"][-1] < facts["loss"][0]
+    errs = facts["kernel_rel_err"]["(1, 2, 64, 16)/32"]
+    assert set(errs) == {"out", "dq", "dk", "dv"}
+
+
+def test_main_refuses_a_cpu(capsys):
+    """No TPU: non-zero exit, the reason on stderr, no result on stdout."""
+    assert chip_smoke.main() == chip_smoke.EXIT_NO_CHIP
+    captured = capsys.readouterr()
+    assert captured.out == ""
+    assert "no TPU" in captured.err and "'cpu'" in captured.err

@@ -1,6 +1,8 @@
 """Native C++ data pipeline vs the Python reference implementations
 (mv_data.cpp; ref reader.cpp/dictionary.cpp territory)."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -101,3 +103,43 @@ class TestNativeLibsvm:
     def test_comment_and_empty(self):
         assert native.parse_libsvm_line(b"# hi", 4) is None
         assert native.parse_libsvm_line(b"   ", 4) is None
+
+
+class TestBuildKey:
+    """native.build_and_load loads only the build whose name carries the
+    hash of the current source, flags and host."""
+
+    @pytest.fixture
+    def native_dir(self, tmp_path, monkeypatch):
+        monkeypatch.setattr(native, "_DIR", str(tmp_path))
+        return tmp_path
+
+    @staticmethod
+    def _write(native_dir, value):
+        (native_dir / "t.cpp").write_text(
+            'extern "C" int answer() { return %d; }\n' % value)
+
+    def test_rebuilds_when_the_source_changes(self, native_dir):
+        self._write(native_dir, 1)
+        assert native.build_and_load("libt.so", "t.cpp").answer() == 1
+        first = native.artefact_path("libt.so", "t.cpp")
+        self._write(native_dir, 2)
+        second = native.artefact_path("libt.so", "t.cpp")
+        assert second != first
+        assert native.build_and_load("libt.so", "t.cpp").answer() == 2
+        assert sorted(p.name for p in native_dir.glob("*.so")) == sorted(
+            os.path.basename(p) for p in (first, second))
+
+    def test_refuses_a_library_without_the_stamp(self, native_dir):
+        # a library somebody left behind, under the plain name and under
+        # another build's key: neither may load (these bytes could not)
+        self._write(native_dir, 3)
+        (native_dir / "libt.so").write_bytes(b"not a library")
+        (native_dir / "libt.0123456789abcdef.so").write_bytes(b"stale")
+        assert native.build_and_load("libt.so", "t.cpp").answer() == 3
+
+    def test_failed_build_keeps_the_compiler_output(self, native_dir):
+        (native_dir / "t.cpp").write_text("this is not C++\n")
+        assert native.build_and_load("libt.so", "t.cpp") is None
+        assert "error" in native.build_failure("libt.so")
+        assert not list(native_dir.glob("*.so*"))   # no half-built file

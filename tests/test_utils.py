@@ -116,3 +116,37 @@ def test_documentation_citations_resolve():
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     assert mod.check() == []
+
+
+class TestCompileCache:
+    """utils/platform.enable_compile_cache: whoever launches the process
+    places the cache; otherwise it sits in the checkout, at one path."""
+
+    def test_honours_a_placed_cache(self, monkeypatch, tmp_path):
+        import jax
+
+        from multiverso_tpu.utils.platform import enable_compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before   # untouched
+
+    def test_default_is_one_path_from_any_directory(self, monkeypatch,
+                                                    tmp_path):
+        import os
+
+        import jax
+
+        from multiverso_tpu.utils import platform
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            monkeypatch.chdir(tmp_path)
+            first = platform.enable_compile_cache()
+            monkeypatch.chdir("/")
+            second = platform.enable_compile_cache()
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert first == second == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == first
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)

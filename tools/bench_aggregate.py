@@ -15,12 +15,6 @@ def main():
                              int(sys.argv[3]), float(sys.argv[4]))
     import jax
     jax.config.update("jax_platforms", "cpu")
-    # without this, N coordinated CPU processes initialize fine and
-    # then every cross-process computation (all four variants below)
-    # raises "Multiprocess computations aren't implemented on the CPU
-    # backend" — jaxlib has gloo, it just doesn't select it by default
-    from multiverso_tpu.utils.platform import enable_cpu_collectives
-    enable_cpu_collectives()
     jax.distributed.initialize(f"127.0.0.1:{port}", world, rank)
     import numpy as np
 

@@ -92,7 +92,10 @@ def main():
     # case: a single-process run with MV_WE_BENCH_TPU=1 keeps the real
     # backend, which is how the words/s floor below actually arms —
     # without this escape hatch the gate would be dead code on every
-    # machine, TPU hosts included.
+    # machine, TPU hosts included. That run is a STANDALONE command
+    # (`MV_WE_BENCH_TPU=1 python tools/bench_we_async.py <rdv> 1 0 <n>`):
+    # bench.py never sets the variable, because its own process already
+    # holds the chip and this one would fail or hang reaching for it.
     if world > 1 or os.environ.get("MV_WE_BENCH_TPU") != "1":
         jax.config.update("jax_platforms", "cpu")
 
