@@ -13,9 +13,11 @@ rule, a falling finite loss, ``parallel.ring.reference_attention``):
 
 and a closing ``memory`` check that every device ended up holding bytes.
 
-Exit 0 only if every stage passed; the last stdout line is then
-``{"ok": true, "device": {...}, ...}``. Without a TPU it names the reason
-and exits non-zero before any stage runs: nothing here pins the CPU,
+Exit 0 only if every stage passed. Once a TPU was found, a ``summary``
+line (stages, cache counters, wall time) is followed by the last stdout
+line, which holds exactly ``{"ok": true|false, "device": {"platform",
+"kind", "count"}}`` and no other key. Without a TPU it prints no result,
+names the reason and exits non-zero before any stage runs: nothing here pins the CPU,
 interprets a kernel or swaps one attention for another. The stage
 functions take their sizes as arguments so tests/test_chip_smoke.py can
 run them tiny on the CPU mesh (``chip=False`` drops the assertions only a
@@ -462,6 +464,15 @@ def _cache_counters() -> Dict[str, int]:
     return counts
 
 
+def result_line(ok: bool, device: Dict[str, Any]) -> Dict[str, Any]:
+    """The last stdout line once a TPU was found: these keys and no
+    others, the device as JAX reports it. The rest goes on ``summary``."""
+    return {"ok": ok,
+            "device": {"platform": str(device["platform"]),
+                       "kind": str(device["kind"]),
+                       "count": int(device["count"])}}
+
+
 STAGES: Tuple[Tuple[str, Callable[[], Dict[str, Any]]], ...] = (
     ("tables", stage_tables), ("we", stage_we), ("ps", stage_ps),
     ("lm", stage_lm), ("memory", stage_memory))
@@ -506,18 +517,13 @@ def main() -> int:
     mv.shutdown()
 
     ok = all(v == "pass" for v in verdicts.values())
+    _say("summary", ok=ok, stages=verdicts,
+         compile_cache={"dir": device["compile_cache_dir"], **cache},
+         wall_s=round(time.perf_counter() - t_start, 1), claim=None)
+    print(json.dumps(result_line(ok, device)), flush=True)
     if not ok:
         print(f"chip_smoke: FAILED {verdicts}", file=sys.stderr)
         return EXIT_STAGE_FAILED
-    print(json.dumps({
-        "ok": True,
-        "device": {"platform": device["platform"], "kind": device["kind"],
-                   "count": device["count"]},
-        "stages": verdicts,
-        "compile_cache": {"dir": device["compile_cache_dir"], **cache},
-        "wall_s": round(time.perf_counter() - t_start, 1),
-        "claim": None,
-    }), flush=True)
     return 0
 
 

@@ -5,8 +5,11 @@ the assertions a TPU alone can meet (compiled kernel, device-backed
 shards); the comparisons against NumPy and reference_attention all run.
 """
 
+import json
 import os
 import sys
+
+import pytest
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
@@ -56,3 +59,27 @@ def test_main_refuses_a_cpu(capsys):
     captured = capsys.readouterr()
     assert captured.out == ""
     assert "no TPU" in captured.err and "'cpu'" in captured.err
+
+
+@pytest.mark.parametrize("fail", [False, True])
+def test_last_line_is_the_result_and_nothing_else(monkeypatch, capsys, fail):
+    """The driver parses the last stdout line: exactly ``ok`` and
+    ``device``, the device exactly ``platform``/``kind``/``count``."""
+    def stage():
+        if fail:
+            raise RuntimeError("boom")
+        return {"fact": 1}
+
+    monkeypatch.setattr(chip_smoke, "stage_device", lambda: {
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+        "compile_cache_dir": "/x"})
+    monkeypatch.setattr(chip_smoke, "STAGES", (("only", stage),))
+    rc = chip_smoke.main()
+    assert rc == (chip_smoke.EXIT_STAGE_FAILED if fail else 0)
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert json.loads(lines[-1]) == {
+        "ok": not fail,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    summary = json.loads(lines[-2])
+    assert summary["stage"] == "summary" and summary["claim"] is None
+    assert list(summary)[-1] == "claim"
