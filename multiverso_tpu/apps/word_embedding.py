@@ -53,10 +53,10 @@ from multiverso_tpu.ops import row_assemble as _rowasm
 from multiverso_tpu.telemetry import devstats as _devstats
 from multiverso_tpu.telemetry import memstats as _memstats
 from multiverso_tpu.telemetry import profiler as _prof
+from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.utils import config, log
 from multiverso_tpu.tables.matrix_table import _bucket_size
 from multiverso_tpu.utils.async_buffer import AsyncBuffer
-from multiverso_tpu.utils.dashboard import monitor
 
 config.define_int(
     "we_prepare_depth", 4,
@@ -224,6 +224,7 @@ class WordEmbedding:
         self.word_count = kv(name="word_count")
         self.unigram = dictionary.unigram_table()
         self._trained_words = 0
+        self._calls = 0     # training calls so far: the call spans' request
         # caller already sharded the corpus (skip the blocks[wid::nw] split;
         # we_async_worker-style drivers that feed per-rank shards set it via
         # -data_presplit 1)
@@ -279,26 +280,34 @@ class WordEmbedding:
         device-resident batches (keyed by a corpus fingerprint) keeps repeat
         epochs off the host->device path entirely.
         """
-        key = (ids.shape, hash(ids.tobytes()),
-               self.cfg.window, self.cfg.seed, self.cfg.batch_size)
-        with self._pair_cache_lock:
-            hit = self._pair_cache.get(key)
-            if hit is not None:
-                self._pair_cache.move_to_end(key)
-                return hit
-        # pair gen + device put happen OFF the lock (one-time corpus
-        # preprocessing — a concurrent gauge pull must not stall on it);
-        # a racing duplicate build just overwrites with equal content
-        centers, contexts = _gen_pairs(ids, self.cfg.window,
-                                       self.cfg.seed)
-        cb, xb = self._batches(centers, contexts)
-        hit = (jnp.asarray(cb), jnp.asarray(xb), cb.size)
-        with self._pair_cache_lock:
-            self._pair_cache[key] = hit
-            cap = max(1, int(config.get_flag("we_pair_cache_corpora")))
-            while len(self._pair_cache) > cap:   # bounded LRU
-                self._pair_cache.popitem(last=False)
-        return hit
+        with _trace.span("we.fused.pairs", cache_hit=1) as sp:
+            key = (ids.shape, hash(ids.tobytes()),
+                   self.cfg.window, self.cfg.seed, self.cfg.batch_size)
+            with self._pair_cache_lock:
+                hit = self._pair_cache.get(key)
+                if hit is not None:
+                    self._pair_cache.move_to_end(key)
+                    sp.set(pairs=hit[2])
+                    return hit
+            # pair gen + device put happen OFF the lock (one-time corpus
+            # preprocessing — a concurrent gauge pull must not stall on
+            # it); a racing duplicate build just overwrites with equal
+            # content
+            with _trace.span("we.pairs.generate"):
+                centers, contexts = _gen_pairs(ids, self.cfg.window,
+                                               self.cfg.seed)
+                cb, xb = self._batches(centers, contexts)
+            with _trace.span("we.pairs.upload"):
+                hit = (jnp.asarray(cb), jnp.asarray(xb), cb.size)
+                jax.block_until_ready(hit[:2])
+            sp.set(cache_hit=0, pairs=cb.size,
+                   h2d_bytes=cb.nbytes + xb.nbytes)
+            with self._pair_cache_lock:
+                self._pair_cache[key] = hit
+                cap = max(1, int(config.get_flag("we_pair_cache_corpora")))
+                while len(self._pair_cache) > cap:   # bounded LRU
+                    self._pair_cache.popitem(last=False)
+            return hit
 
     def pair_cache_memory_stats(self) -> Dict[str, int]:
         """PR-10 ledger gauges for the pair-batch LRU (pull-only)."""
@@ -312,124 +321,110 @@ class WordEmbedding:
     # ------------------------------------------------------------------ #
     # fused path (device-resident training)
     # ------------------------------------------------------------------ #
+    def _fused_epoch_fn(self):
+        """The jitted, table-donating epoch program of the active (cbow,
+        hs, shared-negatives) mode, built once. ``shared`` programs
+        thread the LCG sampler state through instead of a PRNG key."""
+        cfg = self.cfg
+        shared = not (cfg.cbow or cfg.hs) and cfg.shared_negatives > 0
+        name = (("cbow_hs" if cfg.hs else "cbow") if cfg.cbow else
+                "hs" if cfg.hs else "sg_shared" if shared else "sg")
+        fn = self._fused_cache.get(name)
+        if fn is not None:
+            return fn, shared
+        w2v_cfg = w2v.W2VConfig(len(self.dict), cfg.size, cfg.negative,
+                                cfg.window, cfg.alpha, cfg.cbow, cfg.hs,
+                                cfg.shared_negatives)
+        if cfg.hs:
+            make = (w2v.make_fused_cbow_hs_epoch if cfg.cbow
+                    else w2v.make_fused_hs_epoch)
+            fn = make(w2v_cfg, *self._hs)
+        elif shared:
+            # TPU-first fast path: batch-shared negatives on the MXU
+            cd = self.fused_compute_dtype = (
+                jnp.bfloat16 if jax.devices()[0].platform == "tpu"
+                else jnp.float32)
+            fn = w2v.make_fused_shared_epoch(w2v_cfg, self.unigram,
+                                             compute_dtype=cd)
+            self._lcg = jnp.asarray(w2v.init_lcg_state(
+                cfg.shared_negatives, cfg.seed))
+        else:
+            make = (w2v.make_fused_cbow_epoch if cfg.cbow
+                    else w2v.make_fused_epoch)
+            fn = make(w2v_cfg, self.unigram)
+        self._fused_cache[name] = fn
+        return fn, shared
+
+    def _device_cbow_batches(self, ids: np.ndarray):
+        """Batched (windows, masks, targets) arrays on the device and
+        their example count; generated per call (no cache)."""
+        with _trace.span("we.fused.pairs", cache_hit=0) as sp:
+            with _trace.span("we.pairs.generate"):
+                windows, masks, targets = w2v.generate_cbow_batches(
+                    ids, self.cfg.window)
+                b = self.cfg.batch_size
+                n = (targets.size // b) * b
+                if n == 0:
+                    raise ValueError("corpus too small for batch size")
+                host = (windows[:n].reshape(-1, b, windows.shape[1]),
+                        masks[:n].reshape(-1, b, masks.shape[1]),
+                        targets[:n].reshape(-1, b))
+            with _trace.span("we.pairs.upload"):
+                batches = jax.block_until_ready(
+                    tuple(jnp.asarray(a) for a in host))
+            sp.set(pairs=n, h2d_bytes=sum(a.nbytes for a in host))
+        return batches, n
+
     def train_fused(self, ids: np.ndarray,
                     epochs: Optional[int] = None) -> Dict[str, float]:
         cfg = self.cfg
         epochs = epochs or cfg.epoch
-        w2v_cfg = w2v.W2VConfig(len(self.dict), cfg.size, cfg.negative,
-                                cfg.window, cfg.alpha, cfg.cbow, cfg.hs,
-                                cfg.shared_negatives)
-        key = jax.random.key(cfg.seed)
-        t0, loss, pairs = time.perf_counter(), None, 0
-
-        if cfg.cbow:
-            windows, masks, targets = w2v.generate_cbow_batches(ids, cfg.window)
-            b = cfg.batch_size
-            n = (targets.size // b) * b
-            if n == 0:
-                raise ValueError("corpus too small for batch size")
-            wb = jnp.asarray(windows[:n].reshape(-1, b, windows.shape[1]))
-            mb = jnp.asarray(masks[:n].reshape(-1, b, masks.shape[1]))
-            tb = jnp.asarray(targets[:n].reshape(-1, b))
-            pairs = n
-            state_in = self.table_in.state
-            win = state_in["data"]
-            if cfg.hs:
-                codes, points, lengths = self._hs
-                epoch_fn = self._fused_cache.get("cbow_hs")
-                if epoch_fn is None:
-                    epoch_fn = self._fused_cache["cbow_hs"] = (
-                        w2v.make_fused_cbow_hs_epoch(w2v_cfg, codes, points,
-                                                     lengths))
-                state_hs = self.table_hs.state
-                hs_out = state_hs["data"]
-                for _ in range(epochs):
-                    key, sub = jax.random.split(key)
-                    win, hs_out, loss = epoch_fn(win, hs_out, wb, mb, tb,
-                                                 sub)
-                jax.block_until_ready(win)
-                self.table_hs.adopt({"data": hs_out,
-                                     "ustate": state_hs["ustate"]})
+        self._calls += 1
+        with _trace.span("we.fused", request=self._calls, epochs=epochs,
+                         words=epochs * int(ids.size)) as call:
+            t0, loss = time.perf_counter(), None
+            if cfg.cbow:
+                batches, pairs = self._device_cbow_batches(ids)
             else:
-                epoch_fn = self._fused_cache.get("cbow")
-                if epoch_fn is None:
-                    epoch_fn = self._fused_cache["cbow"] = (
-                        w2v.make_fused_cbow_epoch(w2v_cfg, self.unigram))
-                state_out = self.table_out.state
-                wout = state_out["data"]
-                for _ in range(epochs):
-                    key, sub = jax.random.split(key)
-                    win, wout, loss = epoch_fn(win, wout, wb, mb, tb, sub)
-                jax.block_until_ready(win)
-                self.table_out.adopt({"data": wout,
-                                      "ustate": state_out["ustate"]})
-            self.table_in.adopt({"data": win, "ustate": state_in["ustate"]})
-        else:
-            cbd, xbd, pairs = self._device_pairs(ids)
-            state_in = self.table_in.state
-            win = state_in["data"]
-            if cfg.hs:
-                codes, points, lengths = self._hs
-                epoch_fn = self._fused_cache.get("hs")
-                if epoch_fn is None:
-                    epoch_fn = self._fused_cache["hs"] = (
-                        w2v.make_fused_hs_epoch(w2v_cfg, codes, points,
-                                                lengths))
-                state_hs = self.table_hs.state
-                hs_out = state_hs["data"]
-                for _ in range(epochs):
-                    key, sub = jax.random.split(key)
-                    win, hs_out, loss = epoch_fn(win, hs_out, cbd, xbd, sub)
-                jax.block_until_ready(win)
-                self.table_hs.adopt({"data": hs_out,
-                                     "ustate": state_hs["ustate"]})
-            elif cfg.shared_negatives > 0:
-                # TPU-first fast path: batch-shared negatives on the MXU
-                epoch_fn = self._fused_cache.get("sg_shared")
-                if epoch_fn is None:
-                    cd = self.fused_compute_dtype = (
-                        jnp.bfloat16 if jax.devices()[0].platform == "tpu"
-                        else jnp.float32)
-                    epoch_fn = self._fused_cache["sg_shared"] = (
-                        w2v.make_fused_shared_epoch(w2v_cfg, self.unigram,
-                                                    compute_dtype=cd))
-                    self._lcg = jnp.asarray(w2v.init_lcg_state(
-                        cfg.shared_negatives, cfg.seed))
-                state_out = self.table_out.state
+                cbd, xbd, pairs = self._device_pairs(ids)
+                batches = (cbd, xbd)
+            call.set(pairs=int(pairs), batches=int(batches[0].shape[0]))
+            epoch_fn, shared = self._fused_epoch_fn()
+            sec_table = self._sec_table()
+            state_in, state_sec = self.table_in.state, sec_table.state
+            win, wsec = state_in["data"], state_sec["data"]
+            if shared:
                 # epoch_fn donates its table args; chain from copies so the
                 # live table buffers survive a mid-epoch failure (OOM/^C)
-                win = jnp.copy(win)
-                wout = jnp.copy(state_out["data"])
+                with _trace.span("we.fused.copy",
+                                 bytes=int(win.nbytes + wsec.nbytes)):
+                    win, wsec = jnp.copy(win), jnp.copy(wsec)
+            with _trace.span("we.fused.dispatch", programs=epochs):
+                key = None if shared else jax.random.key(cfg.seed)
                 for _ in range(epochs):
-                    win, wout, loss, self._lcg = epoch_fn(
-                        win, wout, cbd, xbd, self._lcg)
+                    if shared:
+                        win, wsec, loss, self._lcg = epoch_fn(
+                            win, wsec, *batches, self._lcg)
+                    else:
+                        key, sub = jax.random.split(key)
+                        win, wsec, loss = epoch_fn(win, wsec, *batches, sub)
+            with _trace.span("we.fused.wait"):
                 jax.block_until_ready(win)
-                self.table_out.adopt({"data": wout,
-                                      "ustate": state_out["ustate"]})
-            else:
-                epoch_fn = self._fused_cache.get("sg")
-                if epoch_fn is None:
-                    epoch_fn = self._fused_cache["sg"] = (
-                        w2v.make_fused_epoch(w2v_cfg, self.unigram))
-                state_out = self.table_out.state
-                wout = state_out["data"]
-                for _ in range(epochs):
-                    key, sub = jax.random.split(key)
-                    win, wout, loss = epoch_fn(win, wout, cbd, xbd, sub)
-                jax.block_until_ready(win)
-                self.table_out.adopt({"data": wout,
-                                      "ustate": state_out["ustate"]})
-            self.table_in.adopt({"data": win, "ustate": state_in["ustate"]})
-
-        # fetch the scalar loss BEFORE stopping the clock: the readback
-        # waits for the whole epoch chain
-        loss_f = float(loss)
-        dt = time.perf_counter() - t0
-        # words/sec follows the word2vec convention: corpus *tokens* consumed
-        # per second (ref trainer.cpp words/sec), not training pairs.
-        words = epochs * int(ids.size)
-        self._trained_words += words
-        self.word_count.add([0], [words])
+                # fetch the scalar loss BEFORE stopping the clock: the
+                # readback waits for the whole epoch chain
+                loss_f = float(loss)
+            with _trace.span("we.fused.adopt"):
+                sec_table.adopt({"data": wsec,
+                                 "ustate": state_sec["ustate"]})
+                self.table_in.adopt({"data": win,
+                                     "ustate": state_in["ustate"]})
+                dt = time.perf_counter() - t0
+                # words/sec follows the word2vec convention: corpus
+                # *tokens* consumed per second (ref trainer.cpp
+                # words/sec), not training pairs.
+                words = epochs * int(ids.size)
+                self._trained_words += words
+                self.word_count.add([0], [words])
         return {"loss": loss_f, "words_per_sec": words / dt,
                 "seconds": dt, "pairs": int(pairs),
                 "pairs_per_sec": epochs * pairs / dt}
@@ -468,11 +463,24 @@ class WordEmbedding:
         worker and server are separate address spaces; here both live on
         the same chip, so the Get/Add hop is a device gather/scatter — the
         semantics, not the message flow, is the parity surface)."""
+        device_plane = self._use_device_plane(self._ps_topology()[0])
+        self._calls += 1
+        # the watcher (device plane) closes after the drain, when every
+        # block it waits on is done
+        with _trace.span("we.blocks", request=self._calls,
+                         plane="device" if device_plane else "host"
+                         ) as call, _trace.DeviceWatcher() as watcher:
+            return self._run_ps_blocks(ids, epochs or self.cfg.epoch,
+                                       device_plane, call, watcher)
+
+    def _run_ps_blocks(self, ids: np.ndarray, epochs: int,
+                       device_plane: bool, call, watcher
+                       ) -> Dict[str, float]:
+        """The body of :meth:`train_ps_blocks`, inside its ``we.blocks``
+        span (``call``, which takes the block and word counts)."""
         cfg = self.cfg
-        epochs = epochs or cfg.epoch
         rng = np.random.default_rng(cfg.seed)
         nw, wid = self._ps_topology()
-        device_plane = self._use_device_plane(nw)
         t0, losses, words = time.perf_counter(), [], 0
         dev_losses: List[jax.Array] = []
         blocks = [ids[lo: lo + cfg.data_block_size]
@@ -515,14 +523,17 @@ class WordEmbedding:
             with BlockPrepareQueue(
                     list(range(len(schedule))),
                     lambda idx, _i: self._prepare_block_device(
-                        schedule[idx], child_rngs[idx]),
+                        schedule[idx], child_rngs[idx], idx),
                     depth=int(config.get_flag("we_prepare_depth")),
                     threads=int(config.get_flag("we_prepare_threads"))
                     ) as q:
                 for i, block in enumerate(schedule):
-                    prepared = q.next()
+                    with _trace.span("we.block.wait_prepared", request=i,
+                                     queue_depth=q.ready()):
+                        prepared = q.next()
                     if prepared is not None:
-                        dev_losses.append(self._train_block_device(prepared))
+                        dev_losses.append(self._train_block_device(
+                            prepared, i, watcher))
                     words += block.size
         elif schedule and cfg.pipeline and len(schedule) > 1:
             # ISSUE-11 pipelined host plane: producers run the CPU-heavy
@@ -568,18 +579,21 @@ class WordEmbedding:
                     losses.append(self._train_prepared(prepared, nw))
                 words += block.size
                 prepared = nxt
-        if dev_losses:
-            # ONE host readback for the whole run: materializing the stacked
-            # per-block losses drains the device program chain, so the
-            # trained state is durable when the clock stops
-            losses = [float(x) for x in np.asarray(jnp.stack(dev_losses))]
-        # drain in-flight async pushes so the trained state is durable
-        # before the caller reads embeddings (sync tables order by program
-        # order; async tables need the explicit flush)
-        for t in (self.table_in, self.table_out,
-                  getattr(self, "table_hs", None)):
-            if t is not None and hasattr(t, "flush"):
-                t.flush()
+        call.set(blocks=len(schedule), words=words)
+        with _trace.span("we.blocks.drain", blocks=len(schedule)):
+            if dev_losses:
+                # ONE host readback for the whole run: materializing the
+                # stacked per-block losses drains the device program chain,
+                # so the trained state is durable when the clock stops
+                losses = [float(x)
+                          for x in np.asarray(jnp.stack(dev_losses))]
+            # drain in-flight async pushes so the trained state is durable
+            # before the caller reads embeddings (sync tables order by
+            # program order; async tables need the explicit flush)
+            for t in (self.table_in, self.table_out,
+                      getattr(self, "table_hs", None)):
+                if t is not None and hasattr(t, "flush"):
+                    t.flush()
         dt = time.perf_counter() - t0
         self._trained_words += words
         self.word_count.add([0], [words])
@@ -659,7 +673,7 @@ class WordEmbedding:
         so the dispatch point within prepare never changes results)."""
         cfg = self.cfg
         b = cfg.batch_size
-        with monitor("we.prepare"):
+        with _trace.span("we.prepare"):
             prep = self._block_arrays(block, rng)
             n = (prep["examples"].size // b) * b
             if n == 0:
@@ -745,7 +759,7 @@ class WordEmbedding:
         cfg = self.cfg
         if prep is None:
             return 0.0
-        with monitor("we.block"):
+        with _trace.span("we.block"):
             # device pad (ops/row_assemble): ONE transfer of the real
             # rows, the zero padding materializes in-graph — the old
             # np.pad + jnp.asarray paid a host copy of the padded block
@@ -787,7 +801,7 @@ class WordEmbedding:
                 d_in = np.asarray(d_in)
                 d_sec = np.asarray(d_sec)
                 _devstats.note_transfer(d_in.nbytes + d_sec.nbytes, "d2h")
-            with monitor("we.push"), _prof.phase("push"):
+            with _trace.span("we.push", phase="push"):
                 k = prep["vocab"].size
                 self.table_in.add_rows_async(
                     prep["vocab"], d_in[:k] / num_workers)
@@ -930,61 +944,80 @@ class WordEmbedding:
         if fn is not None:
             return fn
         step = self._step_fn_raw()
-        fn = self._fused_cache["ps_local"] = jax.jit(
-            lambda ri, rs, v, b: self._run_block_scan(step, ri, rs, v, b))
+
+        def local_train(ri, rs, v, b):
+            with jax.named_scope("mv.scan"):    # device-trace name
+                return self._run_block_scan(step, ri, rs, v, b)
+
+        fn = self._fused_cache["ps_local"] = jax.jit(local_train)
         return fn
 
-    def _prepare_block_device(self, block: np.ndarray, rng) -> Optional[Dict]:
+    def _prepare_block_device(self, block: np.ndarray, rng, index: int
+                              ) -> Optional[Tuple[Dict, int]]:
         """Device-plane block prep: bucketed table-id lists + packed
         batches, shipped in ONE pytree device_put per block (overlapped
-        with the previous block's compute by JAX async dispatch)."""
+        with the previous block's compute by JAX async dispatch).
+        Returns the payload and the id of its ``we.prepare`` span, which
+        the consumer names as the cause of the block's dispatch."""
         cfg = self.cfg
         b = cfg.batch_size
-        with monitor("we.prepare"):
-            prep = self._block_arrays(block, rng)
+        with _trace.span("we.prepare", request=index) as sp:
+            with _trace.span("we.prepare.arrays", request=index):
+                prep = self._block_arrays(block, rng)
             n = (prep["examples"].size // b) * b
             if n == 0:
                 return None
-            # multiple-of-8 bucket: pair counts per fixed-size block jitter
-            # by << 8 minibatches, so this stays on one compiled program
-            # while wasting far less upload padding than pow2 would
-            nbb = -(-(n // b) // 8) * 8
-            vocab = prep["vocab"]
-            k = vocab.size
-            vbb = _bucket_size(k, self.table_in.padded_shape[0])
-            # bucket the pulled-row count; pad ids gather the table's
-            # scratch row (zero delta scatters back into it, a no-op)
-            ids_in = np.full(vbb, self.table_in.scratch_row, np.int32)
-            ids_in[:k] = vocab
-            remap = np.full(len(self.dict), vbb, np.int64)  # default: dummy
-            remap[vocab] = np.arange(k)
-            remap_hs, hsb = None, 0
-            if cfg.hs:
-                hs_rows = prep["hs_rows"]
-                hk = hs_rows.size
-                hsb = _bucket_size(hk, self._sec_table().padded_shape[0])
-                ids_sec = np.full(hsb, self._sec_table().scratch_row,
-                                  np.int32)
-                ids_sec[:hk] = hs_rows
-                remap_hs = np.full(self.table_hs.shape[0] + 1, hsb, np.int64)
-                remap_hs[hs_rows] = np.arange(hk)
-            else:
-                ids_sec = ids_in
-            batch, valid = self._pack_batches(prep, n, nbb, remap, vbb,
-                                              remap_hs, hsb,
-                                              dev_negs=self._dev_negs)
-            payload = {"ids_in": ids_in, "ids_sec": ids_sec, "valid": valid,
-                       "batch": batch, "remap": None, "neg_seed": None}
-            if self._dev_negs:
-                # in-graph negatives need the step index, the global->local
-                # remap (V small ids), and the block's 4-byte draw seed
-                payload["batch"] = (np.arange(nbb, dtype=np.uint32),) + batch
-                payload["remap"] = remap.astype(self._idt(vbb))
-                payload["neg_seed"] = np.uint32(prep["neg_seed"])
-            return jax.device_put(
-                payload,
-                jax.sharding.NamedSharding(mv.mesh(),
-                                           jax.sharding.PartitionSpec()))
+            with _trace.span("we.prepare.pack", request=index):
+                # multiple-of-8 bucket: pair counts per fixed-size block
+                # jitter by << 8 minibatches, so this stays on one compiled
+                # program while wasting far less upload padding than pow2
+                nbb = -(-(n // b) // 8) * 8
+                vocab = prep["vocab"]
+                k = vocab.size
+                vbb = _bucket_size(k, self.table_in.padded_shape[0])
+                # bucket the pulled-row count; pad ids gather the table's
+                # scratch row (zero delta scatters back into it, a no-op)
+                ids_in = np.full(vbb, self.table_in.scratch_row, np.int32)
+                ids_in[:k] = vocab
+                remap = np.full(len(self.dict), vbb, np.int64)  # dummy
+                remap[vocab] = np.arange(k)
+                remap_hs, hsb = None, 0
+                if cfg.hs:
+                    hs_rows = prep["hs_rows"]
+                    hk = hs_rows.size
+                    hsb = _bucket_size(hk,
+                                       self._sec_table().padded_shape[0])
+                    ids_sec = np.full(hsb, self._sec_table().scratch_row,
+                                      np.int32)
+                    ids_sec[:hk] = hs_rows
+                    remap_hs = np.full(self.table_hs.shape[0] + 1, hsb,
+                                       np.int64)
+                    remap_hs[hs_rows] = np.arange(hk)
+                else:
+                    ids_sec = ids_in
+                batch, valid = self._pack_batches(prep, n, nbb, remap, vbb,
+                                                  remap_hs, hsb,
+                                                  dev_negs=self._dev_negs)
+                payload = {"ids_in": ids_in, "ids_sec": ids_sec,
+                           "valid": valid, "batch": batch, "remap": None,
+                           "neg_seed": None}
+                if self._dev_negs:
+                    # in-graph negatives need the step index, the
+                    # global->local remap (V small ids), and the block's
+                    # 4-byte draw seed
+                    payload["batch"] = (
+                        np.arange(nbb, dtype=np.uint32),) + batch
+                    payload["remap"] = remap.astype(self._idt(vbb))
+                    payload["neg_seed"] = np.uint32(prep["neg_seed"])
+            sp.set(rows_touched=int(k), rows_bucket=int(vbb),
+                   minibatches=int(nbb), pairs=int(n),
+                   h2d_bytes=sum(int(np.asarray(a).nbytes)
+                                 for a in jax.tree.leaves(payload)))
+            with _trace.span("we.prepare.put", request=index):
+                return jax.device_put(
+                    payload,
+                    jax.sharding.NamedSharding(
+                        mv.mesh(), jax.sharding.PartitionSpec())), sp.id
 
     def _fused_block_fn(self):
         """One jitted program = the whole reference block cycle: pull
@@ -1008,8 +1041,11 @@ class WordEmbedding:
 
         def fused(din, uin, dsec, usec, ids_in, ids_sec, valid, batch,
                   remap, neg_seed, neg_table):
-            old_in = jnp.take(din, ids_in, axis=0)
-            old_sec = jnp.take(dsec, ids_sec, axis=0)
+            # mv.pull / mv.scan / mv.push: the names the block's three
+            # regions carry in a device trace (metadata only)
+            with jax.named_scope("mv.pull"):
+                old_in = jnp.take(din, ids_in, axis=0)
+                old_sec = jnp.take(dsec, ids_sec, axis=0)
             neg_fn = None
             if dev_negs:
                 dummy_id = ids_in.shape[0]
@@ -1026,12 +1062,14 @@ class WordEmbedding:
                     # vocab pass, so point them at the dummy row
                     return jnp.where(w > 0, nl, jnp.int32(dummy_id))
 
-            d_in, d_sec, loss = self._run_block_scan(
-                step, old_in, old_sec, valid, batch, neg_fn)
-            s_in = t_in.functional_add_rows(
-                {"data": din, "ustate": uin}, ids_in, d_in)
-            s_sec = t_sec.functional_add_rows(
-                {"data": dsec, "ustate": usec}, ids_sec, d_sec)
+            with jax.named_scope("mv.scan"):
+                d_in, d_sec, loss = self._run_block_scan(
+                    step, old_in, old_sec, valid, batch, neg_fn)
+            with jax.named_scope("mv.push"):
+                s_in = t_in.functional_add_rows(
+                    {"data": din, "ustate": uin}, ids_in, d_in)
+                s_sec = t_sec.functional_add_rows(
+                    {"data": dsec, "ustate": usec}, ids_sec, d_sec)
             return (s_in["data"], s_in["ustate"],
                     s_sec["data"], s_sec["ustate"], loss)
 
@@ -1039,16 +1077,24 @@ class WordEmbedding:
         self._fused_cache["ps_block"] = fn
         return fn
 
-    def _train_block_device(self, prep: Dict) -> jax.Array:
+    def _train_block_device(self, prepared: Tuple[Dict, int], index: int,
+                            watcher: "_trace.DeviceWatcher") -> jax.Array:
         """Dispatch one fused block program; returns the block loss as a
-        DEVICE scalar (readback deferred to end of run)."""
+        DEVICE scalar (readback deferred to end of run). The dispatch is
+        the ``we.block.dispatch`` span; the block itself ends when the
+        device is done, which ``watcher`` records as ``we.block.device``
+        while a profiler trace or ``trace_ids`` can read it."""
+        prep, prepare_span = prepared
         t_in, t_sec = self.table_in, self._sec_table()
         fn = self._fused_block_fn()
         if self._dev_negs and self._neg_dev is None:
             self._neg_dev = jax.device_put(
                 self._neg_host, jax.sharding.NamedSharding(
                     mv.mesh(), jax.sharding.PartitionSpec()))
-        with monitor("we.block"), t_in._dispatch_lock, t_sec._dispatch_lock:
+        t0_ns = time.time_ns()
+        with _trace.span("we.block.dispatch", request=index,
+                         cause=prepare_span) as sp, \
+                t_in._dispatch_lock, t_sec._dispatch_lock:
             si, ss = t_in.state, t_sec.state
             din, uin, dsec, usec, loss = fn(
                 si["data"], si["ustate"], ss["data"], ss["ustate"],
@@ -1057,6 +1103,8 @@ class WordEmbedding:
                 self._neg_dev)
             t_in.adopt({"data": din, "ustate": uin})
             t_sec.adopt({"data": dsec, "ustate": usec})
+        watcher.watch("we.block.device", loss, t0_ns, request=index,
+                      cause=sp.id)
         return loss
 
     def _ps_topology(self) -> Tuple[int, int]:

@@ -199,6 +199,12 @@ class BlockPrepareQueue:
             raise payload
         return payload
 
+    def ready(self) -> int:
+        """Results produced and not yet consumed (the depth a consumer
+        finds at its next ``next()``)."""
+        with self._cond:
+            return len(self._results)
+
     def __iter__(self) -> Iterator[Any]:
         while True:
             try:

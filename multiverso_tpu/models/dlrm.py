@@ -172,22 +172,28 @@ def make_train_step(cfg: DLRMConfig, emb_table, mlp_table, mlp_meta,
         _replicated = None
 
     def step(emb_state, mlp_state, cat_ids, dense, labels):
-        ids = (cat_ids + offsets[None, :]).reshape(-1)        # [B*F] global
-        rows = jnp.take(emb_state["data"], ids, axis=0)
+        # mv.dlrm.*: the names the step's regions carry in a device trace
+        # (metadata only); the updater rule inside functional_add is
+        # mv.rowapply.rule
         b, f = cat_ids.shape
-        rows = rows.reshape(b, f, cfg.embed_dim)
-        flat_params = mlp_state["data"][:n_mlp]
-        if _replicated is not None:
-            flat_params = jax.lax.with_sharding_constraint(
-                flat_params, _replicated)
-        mlp = unflatten_mlp(flat_params, mlp_meta)
-        loss, (g_mlp, g_rows) = jax.value_and_grad(
-            loss_fn, argnums=(0, 1))(mlp, rows, dense, labels, cfg)
+        with jax.named_scope("mv.dlrm.gather"):
+            ids = (cat_ids + offsets[None, :]).reshape(-1)    # [B*F] global
+            rows = jnp.take(emb_state["data"], ids, axis=0)
+            rows = rows.reshape(b, f, cfg.embed_dim)
+        with jax.named_scope("mv.dlrm.mlp"):
+            flat_params = mlp_state["data"][:n_mlp]
+            if _replicated is not None:
+                flat_params = jax.lax.with_sharding_constraint(
+                    flat_params, _replicated)
+            mlp = unflatten_mlp(flat_params, mlp_meta)
+            loss, (g_mlp, g_rows) = jax.value_and_grad(
+                loss_fn, argnums=(0, 1))(mlp, rows, dense, labels, cfg)
         # PS push: duplicate-accumulating scatter of row grads into a dense
         # table-shaped delta, then ONE updater application (grad aggregation
         # before update = BSP server semantics)
-        emb_delta = jnp.zeros_like(emb_state["data"]).at[ids].add(
-            g_rows.reshape(b * f, cfg.embed_dim))
+        with jax.named_scope("mv.dlrm.delta"):
+            emb_delta = jnp.zeros_like(emb_state["data"]).at[ids].add(
+                g_rows.reshape(b * f, cfg.embed_dim))
         emb_state = emb_table.functional_add(emb_state, emb_delta, emb_opt)
         flat_g = jnp.concatenate(
             [g.reshape(-1) for g in jax.tree.leaves(g_mlp)])

@@ -125,21 +125,28 @@ def _ns_forward_backward(v: jax.Array, u: jax.Array, labels: jax.Array,
 
 def skipgram_ns_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
                      contexts: jax.Array, negatives: jax.Array,
-                     lr: float) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                     lr: float, scope: str = "mv.scan"
+                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One skipgram negative-sampling minibatch.
 
-    centers/contexts: (B,) int32; negatives: (B, K) int32.
+    centers/contexts: (B,) int32; negatives: (B, K) int32. ``scope``
+    prefixes the names its gathers, gradient and scatters carry in a
+    device trace (``mv.scan`` in a PS block, ``mv.fused`` in a fused
+    epoch).
     """
     b, k = negatives.shape
-    v = jnp.take(win, centers, axis=0)                       # (B, D)
-    targets = jnp.concatenate([contexts[:, None], negatives], axis=1)
-    u = jnp.take(wout, targets, axis=0)                      # (B, K+1, D)
-    labels = jnp.concatenate(
-        [jnp.ones((b, 1), v.dtype), jnp.zeros((b, k), v.dtype)], axis=1)
-    loss, dv, du = _ns_forward_backward(v, u, labels, lr)
-    win = win.at[centers].add(dv)
-    wout = wout.at[targets.reshape(-1)].add(
-        du.reshape(-1, du.shape[-1]))
+    with jax.named_scope(scope + ".gather"):
+        v = jnp.take(win, centers, axis=0)                   # (B, D)
+        targets = jnp.concatenate([contexts[:, None], negatives], axis=1)
+        u = jnp.take(wout, targets, axis=0)                  # (B, K+1, D)
+    with jax.named_scope(scope + ".grad"):
+        labels = jnp.concatenate(
+            [jnp.ones((b, 1), v.dtype), jnp.zeros((b, k), v.dtype)], axis=1)
+        loss, dv, du = _ns_forward_backward(v, u, labels, lr)
+    with jax.named_scope(scope + ".scatter"):
+        win = win.at[centers].add(dv)
+        wout = wout.at[targets.reshape(-1)].add(
+            du.reshape(-1, du.shape[-1]))
     return win, wout, loss
 
 
@@ -251,11 +258,12 @@ def make_fused_epoch(cfg: W2VConfig, unigram: np.ndarray):
             neg = sample_negatives_table(sub, neg_table, c.shape[0],
                                          cfg.negatives)
             win, wout, loss = skipgram_ns_step(
-                win, wout, c, ctx, neg, cfg.learning_rate)
+                win, wout, c, ctx, neg, cfg.learning_rate, "mv.fused")
             return (win, wout, key), loss
 
-        (win, wout, _), losses = jax.lax.scan(
-            body, (win, wout, key), (centers, contexts))
+        with jax.named_scope("mv.fused"):   # device-trace name
+            (win, wout, _), losses = jax.lax.scan(
+                body, (win, wout, key), (centers, contexts))
         return win, wout, jnp.mean(losses)
 
     return epoch_fn
@@ -306,25 +314,28 @@ def shared_neg_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
     ``compute_dtype`` (bf16 on the MXU).
     """
     cd = compute_dtype
-    v = jnp.take(win, centers, axis=0).astype(cd)              # (B, D)
-    up = jnp.take(wout, contexts, axis=0).astype(cd)           # (B, D)
-    un = jnp.take(wout, neg_ids, axis=0).astype(cd)            # (K', D)
-    pos = jnp.sum(v * up, axis=-1).astype(jnp.float32)         # (B,)
-    negs = jnp.dot(v, un.T).astype(jnp.float32)                # (B, K') MXU
-    gp = ((1.0 - jax.nn.sigmoid(pos)) * lr).astype(cd)
-    gn = (-jax.nn.sigmoid(negs) * (lr * neg_weight)).astype(cd)
-    dv = gp[:, None] * up + jnp.dot(gn, un)                    # (B, D) MXU
-    dup = gp[:, None] * v
-    dun = jnp.dot(gn.T, v)                                     # (K', D) MXU
-    loss = (-jnp.mean(jax.nn.log_sigmoid(pos))
-            - neg_weight * jnp.mean(
-                jnp.sum(jax.nn.log_sigmoid(-negs), axis=-1)))
-    win = win.at[centers].add(dv.astype(win.dtype))
-    # two scatters, NOT one concat'd scatter: the K'-row pool scatter is
-    # nearly free while concatenation forces an extra [B+K', D]
-    # materialization (measured ~30% slower per batch on-chip)
-    wout = wout.at[contexts].add(dup.astype(wout.dtype))
-    wout = wout.at[neg_ids].add(dun.astype(wout.dtype))
+    with jax.named_scope("mv.fused.gather"):
+        v = jnp.take(win, centers, axis=0).astype(cd)          # (B, D)
+        up = jnp.take(wout, contexts, axis=0).astype(cd)       # (B, D)
+        un = jnp.take(wout, neg_ids, axis=0).astype(cd)        # (K', D)
+    with jax.named_scope("mv.fused.grad"):
+        pos = jnp.sum(v * up, axis=-1).astype(jnp.float32)     # (B,)
+        negs = jnp.dot(v, un.T).astype(jnp.float32)            # (B, K') MXU
+        gp = ((1.0 - jax.nn.sigmoid(pos)) * lr).astype(cd)
+        gn = (-jax.nn.sigmoid(negs) * (lr * neg_weight)).astype(cd)
+        dv = gp[:, None] * up + jnp.dot(gn, un)                # (B, D) MXU
+        dup = gp[:, None] * v
+        dun = jnp.dot(gn.T, v)                                 # (K', D) MXU
+        loss = (-jnp.mean(jax.nn.log_sigmoid(pos))
+                - neg_weight * jnp.mean(
+                    jnp.sum(jax.nn.log_sigmoid(-negs), axis=-1)))
+    with jax.named_scope("mv.fused.scatter"):
+        win = win.at[centers].add(dv.astype(win.dtype))
+        # two scatters, NOT one concat'd scatter: the K'-row pool scatter
+        # is nearly free while concatenation forces an extra [B+K', D]
+        # materialization (measured ~30% slower per batch on-chip)
+        wout = wout.at[contexts].add(dup.astype(wout.dtype))
+        wout = wout.at[neg_ids].add(dun.astype(wout.dtype))
     return win, wout, loss
 
 
@@ -357,10 +368,11 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
         # batched table gather (bit-identical to stepping the LCG per
         # batch, which serialized ~17% of the epoch on small VPU ops)
         At, Ct = _lcg_jump_consts(centers.shape[0])
-        s_all = (lcg_state[None, :] * jnp.asarray(At)[:, None]
-                 + jnp.asarray(Ct)[:, None])
-        nids = jnp.take(neg_table, (s_all >> shift).astype(jnp.int32),
-                        axis=0)
+        with jax.named_scope("mv.fused.sample"):
+            s_all = (lcg_state[None, :] * jnp.asarray(At)[:, None]
+                     + jnp.asarray(Ct)[:, None])
+            nids = jnp.take(neg_table, (s_all >> shift).astype(jnp.int32),
+                            axis=0)
 
         def body(carry, batch):
             win, wout, = carry
@@ -370,8 +382,9 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
                 compute_dtype)
             return (win, wout), loss
 
-        (win, wout), losses = jax.lax.scan(
-            body, (win, wout), (centers, contexts, nids))
+        with jax.named_scope("mv.fused"):   # device-trace name
+            (win, wout), losses = jax.lax.scan(
+                body, (win, wout), (centers, contexts, nids))
         return win, wout, jnp.mean(losses), s_all[-1]
 
     return epoch_fn
@@ -399,8 +412,9 @@ def make_fused_cbow_epoch(cfg: W2VConfig, unigram: np.ndarray):
                                            cfg.learning_rate)
             return (win, wout, key), loss
 
-        (win, wout, _), losses = jax.lax.scan(
-            body, (win, wout, key), (windows, masks, targets))
+        with jax.named_scope("mv.fused"):   # device-trace name
+            (win, wout, _), losses = jax.lax.scan(
+                body, (win, wout, key), (windows, masks, targets))
         return win, wout, jnp.mean(losses)
 
     return epoch_fn
@@ -441,8 +455,9 @@ def make_fused_hs_epoch(cfg: W2VConfig, codes: np.ndarray, points: np.ndarray,
                 win, hs_out, c, code, point, mask, cfg.learning_rate)
             return (win, hs_out), loss
 
-        (win, hs_out), losses = jax.lax.scan(
-            body, (win, hs_out), (centers, contexts))
+        with jax.named_scope("mv.fused"):   # device-trace name
+            (win, hs_out), losses = jax.lax.scan(
+                body, (win, hs_out), (centers, contexts))
         return win, hs_out, jnp.mean(losses)
 
     return epoch_fn
@@ -466,8 +481,9 @@ def make_fused_cbow_hs_epoch(cfg: W2VConfig, codes: np.ndarray,
                 win, hs_out, w, m, code, point, pmask, cfg.learning_rate)
             return (win, hs_out), loss
 
-        (win, hs_out), losses = jax.lax.scan(
-            body, (win, hs_out), (windows, masks, targets))
+        with jax.named_scope("mv.fused"):   # device-trace name
+            (win, hs_out), losses = jax.lax.scan(
+                body, (win, hs_out), (windows, masks, targets))
         return win, hs_out, jnp.mean(losses)
 
     return epoch_fn

@@ -46,6 +46,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from multiverso_tpu import updaters as updaters_lib
 from multiverso_tpu.ops import wire_codec
 from multiverso_tpu.telemetry import memstats as _memstats
+from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.updaters import AddOption
 from multiverso_tpu.utils import config, log
 from multiverso_tpu.utils.dashboard import Dashboard, monitor
@@ -159,11 +160,18 @@ class Table:
                 updater, num_workers=zoo.num_workers(), dtype=self.dtype)
         self.updater = updater
 
-        host_init = self._build_init(init, seed, init_scale)
-        self._data = jax.device_put(host_init, self._sharding)
-        self._ustate = jax.tree.map(self._place_state,
-                                    updater.init_state(self._padded_shape,
-                                                       self.dtype))
+        with _trace.span(
+                "table.init", table=name, rows=self._padded_rows,
+                width=int(np.prod(self.shape[1:])),
+                bytes=int(np.prod(self._padded_shape)) * self.dtype.itemsize):
+            with _trace.span("table.init.host"):
+                host_init = self._build_init(init, seed, init_scale)
+            with _trace.span("table.init.put"):
+                self._data = jax.block_until_ready(
+                    jax.device_put(host_init, self._sharding))
+            self._ustate = jax.tree.map(
+                self._place_state,
+                updater.init_state(self._padded_shape, self.dtype))
         self.table_id = zoo.register_table(self)
 
         if wire_filter not in ("none", "bf16", "1bit", "topk"):

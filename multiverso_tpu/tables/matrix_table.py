@@ -328,14 +328,9 @@ class MatrixTable(Table):
         unused slots by pointing them at scratch_row with zero vals."""
         opt = opt or AddOption()
         row_axes = jax.tree.map(self._state_row_axis, state["ustate"])
-        rows = jnp.take(state["data"], ids, axis=0)
 
         def gather(leaf, axis):
             return jnp.take(leaf, ids, axis=axis) if axis is not None else leaf
-
-        gstate = jax.tree.map(gather, state["ustate"], row_axes)
-        new_rows, new_gstate = self.updater.apply(rows, gstate, vals, opt)
-        data = state["data"].at[ids].set(new_rows)
 
         def scatter(leaf, new_leaf, axis):
             if axis is None:
@@ -343,7 +338,16 @@ class MatrixTable(Table):
             idx = (slice(None),) * axis + (ids,)
             return leaf.at[idx].set(new_leaf)
 
-        ustate = jax.tree.map(scatter, state["ustate"], new_gstate, row_axes)
+        # device-trace names (metadata only): the updater's apply is
+        # mv.rowapply.rule
+        with jax.named_scope("mv.rowapply.gather"):
+            rows = jnp.take(state["data"], ids, axis=0)
+            gstate = jax.tree.map(gather, state["ustate"], row_axes)
+        new_rows, new_gstate = self.updater.apply(rows, gstate, vals, opt)
+        with jax.named_scope("mv.rowapply.scatter"):
+            data = state["data"].at[ids].set(new_rows)
+            ustate = jax.tree.map(scatter, state["ustate"], new_gstate,
+                                  row_axes)
         return {"data": data, "ustate": ustate}
 
     @property

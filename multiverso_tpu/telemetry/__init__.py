@@ -13,11 +13,13 @@ Nine cooperating pieces:
 
 * :mod:`~multiverso_tpu.telemetry.histogram` — the lock-free (caller-
   synchronized) log2-bucket histogram every Monitor embeds.
-* :mod:`~multiverso_tpu.telemetry.trace` — per-request trace IDs carried
-  in PS frame meta (``ps/wire.TRACE_META_KEY``) and ``trace_event``-format
-  spans recorded on both endpoints, dumped as JSONL for Perfetto
-  (``tools/dump_metrics.py to-perfetto`` wraps them for the viewer)
-  alongside the XLA traces from ``utils/profiling.py``.
+* :mod:`~multiverso_tpu.telemetry.trace` — the one span primitive:
+  always-on coarse program spans with counts (per call, block, table
+  build, compile; also written into a ``jax.profiler`` trace while one
+  is captured) and flag-gated fine spans keyed by the per-request trace
+  IDs carried in PS frame meta (``ps/wire.TRACE_META_KEY``), kept in a
+  bounded ring and dumped as JSONL for Perfetto
+  (``tools/dump_metrics.py to-perfetto`` wraps them for the viewer).
 * :mod:`~multiverso_tpu.telemetry.exporter` — flag-gated background
   thread (``metrics_interval_s`` / ``metrics_dir``) dumping Dashboard +
   shard snapshots as JSONL and Prometheus-style text.

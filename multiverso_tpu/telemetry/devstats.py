@@ -69,6 +69,7 @@ import time
 import warnings
 from typing import Any, Dict, List, Optional
 
+from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.utils import config
 
 config.define_bool(
@@ -335,9 +336,16 @@ class DevStats:
 
     def _on_duration(self, name: str, dur: float, **kw) -> None:
         # same event the PR-9 profiler counts globally; here each
-        # compile is ADDITIONALLY keyed to the active mesh shape
-        if not name.endswith("backend_compile_duration") \
-                or not self.enabled:
+        # compile is ADDITIONALLY keyed to the active mesh shape, and
+        # leaves one coarse xla.compile span (telemetry/trace.py)
+        if not self.enabled:
+            return
+        if name.endswith("cache_retrieval_time_sec"):
+            # fires inside the compile event, on the same thread, when
+            # the executable came from the persistent cache
+            self._tls.cache_load = True
+            return
+        if not name.endswith("backend_compile_duration"):
             return
         label = self._mesh_label()
         with self._lock:
@@ -345,6 +353,13 @@ class DevStats:
                 label, {"compiles": 0, "compile_s": 0.0})
             d["compiles"] += 1
             d["compile_s"] = round(d["compile_s"] + float(dur), 6)
+        loaded = getattr(self._tls, "cache_load", False)
+        self._tls.cache_load = False
+        t1 = time.time_ns()
+        _trace.record("xla.compile", t1 - int(float(dur) * 1e9), t1,
+                      seconds=float(dur), mesh=label,
+                      event="cache_load" if loaded else "compile",
+                      fun=str(kw.get("fun_name", "")))
 
     # ------------------------------------------------------------------ #
     # mesh context

@@ -1,64 +1,166 @@
-"""Wire-correlated trace spans for the async PS plane.
+"""The one span primitive: program spans and counts, on the profiler's clock.
 
-One logical op (say a windowed ``add_rows_async``) crosses four threads
-and two processes: caller enqueue -> window flusher -> peer socket ->
-shard apply wave. A per-request **trace ID** minted at the client rides
-the frame meta (``ps/wire.TRACE_META_KEY``, and each MSG_BATCH inner
-frame's own meta), so spans recorded independently on the client
-(enqueue, window flush, ack) and on the owning shard (serve, wave apply)
-stitch into one causal chain by ID.
+A **span** is one interval of host work (or of asynchronous device work
+closed by the watcher, below) with enough identity to be read back
+later: its ``name``, start ``ts`` and ``dur``, its own ``id``, the
+``parent`` open on the same thread when it began (so a reader can take
+**self time**, :func:`self_ms`), an optional ``cause`` (a span on
+*another* thread that made this one happen: a producer's ``we.prepare``
+causes the consumer's ``we.block.dispatch``), a ``request`` shared by
+every span of one call or one block (the PS plane's per-request trace
+ID is this field), the thread, the counts given at entry or set on the
+handle before exit, and ``prof``: whether a ``jax.profiler`` trace was
+being captured.
 
-Spans are Chrome ``trace_event`` complete events (``"ph": "X"``) with
-``ts``/``dur`` in microseconds of ``time.time()`` — an absolute clock, so
-events from every rank of a single-host run land on one Perfetto
-timeline (``pid`` = PS rank, ``tid`` = OS thread). Files are JSONL (one
-event per line, append-friendly across crashes);
-``tools/dump_metrics.py to-perfetto`` wraps them into the
-``{"traceEvents": [...]}`` envelope viewers expect (``python tools/dump_metrics.py to-perfetto in.jsonl out.json``),
-and they sit next to the XLA traces from ``utils/profiling.py`` for
-side-by-side timelines.
+Two classes of site, one gate each:
 
-Cost discipline: everything is OFF unless the ``trace_ids`` flag is set.
-The hot-path check is one module function returning a plain bool
-attribute — no flag-registry lock, no allocation. Natively-served ops
-(zero-Python C++ fast path) are not traced by design: the punt path
-(MSG_BATCH, compressed wires, MSG_STATS) and the pure-Python plane are.
+* **coarse** sites (:func:`span`; :func:`record` for one that has
+  already ended) fire at most a few dozen times a second: once per
+  training call, per block, per table build, per compile. They are
+  recorded ALWAYS, into
+  the bounded ring below, as the flight recorder and the Dashboard
+  monitors already are, and feed the Dashboard ``Monitor`` of the same
+  name, so a site is one ``with`` statement.
+* **fine** sites (plain :func:`add_span`, per PS request) stay behind
+  the ``trace_ids`` flag. The hot-path check is :func:`enabled`, one
+  attribute read; callers pre-check it to skip even the clock reads.
+  A per-request **trace ID** minted at the client rides the frame meta
+  (``ps/wire.TRACE_META_KEY``, and each MSG_BATCH inner frame's own
+  meta), so spans recorded independently on the client (enqueue, window
+  flush, ack) and on the owning shard (serve, wave apply) stitch into
+  one causal chain by ID. Natively-served ops (zero-Python C++ fast
+  path) are not traced by design: the punt path (MSG_BATCH, compressed
+  wires, MSG_STATS) and the pure-Python plane are.
+
+No per-minibatch, per-row or in-``jit`` site belongs in either class.
+
+One clock with the device trace: while a ``jax.profiler`` trace is
+active (``TraceAnnotation.is_enabled()``), a span also opens a
+``jax.profiler.TraceAnnotation(name, request=...)``, so the same span
+lies in the xplane's host plane beside the device operations, and the
+in-memory record says ``prof: true``. Stamps are ``time.time_ns()``; the
+profiler counts from the start of its own session, so the first span of
+a capture also writes one ``mv.trace.anchor`` annotation that carries
+its ``time_ns`` (:meth:`Tracer._anchor`), which ties the two clocks.
+
+Records are Chrome ``trace_event`` complete events (``"ph": "X"``,
+``ts``/``dur`` in microseconds of ``time.time_ns()``: an absolute
+clock, so events from every rank of a single-host run land on one
+Perfetto timeline; ``pid`` = PS rank, ``tid`` = OS thread; counts under
+``args``). Files are JSONL (one event per line, append-friendly across
+crashes); ``tools/dump_metrics.py to-perfetto`` wraps them into the
+``{"traceEvents": [...]}`` envelope viewers expect
+(``python tools/dump_metrics.py to-perfetto in.jsonl out.json``).
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import queue
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
-from typing import Dict, Iterator, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
+import jax
+from jax.profiler import TraceAnnotation
+
+from multiverso_tpu.telemetry import profiler as _profiler
 from multiverso_tpu.utils import config
+from multiverso_tpu.utils.dashboard import Dashboard
 
 config.define_bool(
     "trace_ids", False,
     "mint per-request trace IDs on async-PS client ops, carry them in "
-    "frame meta, and record trace_event spans on both endpoints "
-    "(telemetry/trace.py). Off by default: tracing must cost nothing "
-    "when unused. Spans dump to metrics_dir as trace-rank<r>.jsonl")
+    "frame meta, and record the FINE trace_event spans (per PS request) "
+    "on both endpoints, plus the device-completion watcher of the "
+    "WordEmbedding block pipeline (telemetry/trace.py). Off by default: "
+    "fine tracing must cost nothing when unused; coarse program spans "
+    "(per call, block, table build, compile) are always recorded. Spans "
+    "dump to metrics_dir as trace-rank<r>.jsonl")
 
-# bounded span buffer: a forgotten always-on tracer must cap memory, not
-# OOM a training run; 200k events is hours of windowed PS traffic
+# bounded span buffer: an always-on tracer must cap memory, not OOM a
+# training run; 200k events is hours of coarse spans or of windowed PS
+# traffic
 _MAX_EVENTS = 200_000
+ANCHOR = "mv.trace.anchor"
+
+
+def profiling() -> bool:
+    """Whether a ``jax.profiler`` trace is being captured right now."""
+    return TraceAnnotation.is_enabled()
+
+
+class Span:
+    """An open coarse span; what ``with span(...) as s`` yields.
+    ``s.set(rows=3)`` adds counts before exit; ``s.id`` is what another
+    thread passes as ``cause=``."""
+
+    __slots__ = ("name", "id", "parent", "cause", "request", "counts",
+                 "prof", "_tracer", "_t0", "_ann", "_phase")
+
+    def __init__(self, tracer: "Tracer", name: str, request, cause,
+                 phase: Optional[str], counts: Dict[str, Any]):
+        self._tracer, self.name = tracer, name
+        self.request, self.cause = request, cause
+        self.counts = counts
+        self.id = next(tracer._span_ids)
+        self.parent: Optional[int] = None
+        self.prof = False
+        self._ann = None
+        # the step profiler's phase of that name, while step_profile is
+        # on: the site stays one with-statement
+        self._phase = (_profiler.phase(phase)
+                       if phase and _profiler.enabled() else None)
+
+    def set(self, **counts) -> None:
+        self.counts.update(counts)
+
+    def __enter__(self) -> "Span":
+        stack = self._tracer._stack()
+        self.parent = stack[-1] if stack else None
+        stack.append(self.id)
+        self.prof = profiling()
+        if self.prof != self._tracer._anchored:
+            self._tracer._anchor(self.prof)
+        if self.prof:
+            self._ann = (TraceAnnotation(self.name)
+                         if self.request is None else
+                         TraceAnnotation(self.name, request=self.request))
+            self._ann.__enter__()
+        if self._phase is not None:
+            self._phase.__enter__()
+        self._t0 = time.time_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.time_ns()
+        if self._phase is not None:
+            self._phase.__exit__(*exc)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._tracer._stack().pop()
+        self._tracer._record(
+            self.name, self._t0, t1, self.id, self.parent, self.cause,
+            self.request, "prog", self.counts, self.prof)
+        Dashboard.get(self.name).observe_ms((t1 - self._t0) * 1e-6)
 
 
 class Tracer:
     """Process-global span recorder (one per process, like Dashboard)."""
 
     def __init__(self) -> None:
-        self.enabled = False     # plain attribute: the hot-path gate
+        self.enabled = False     # plain attribute: the fine sites' gate
         self.rank = 0
         self._rank_pinned = False
         self._lock = threading.Lock()
         self._events: deque = deque(maxlen=_MAX_EVENTS)
         self._next_id = 0
+        self._span_ids = itertools.count(1)
+        self._tls = threading.local()
+        self._anchored = False   # an anchor was written for this capture
 
     # ------------------------------------------------------------------ #
     def configure(self, rank: Optional[int] = None) -> None:
@@ -86,40 +188,77 @@ class Tracer:
         return ((self.rank & 0xFFFF) << 32) | (n & 0xFFFFFFFF)
 
     # ------------------------------------------------------------------ #
-    def add_span(self, name: str, t0: float, t1: float,
-                 trace: Optional[int] = None, cat: str = "ps",
-                 args: Optional[Dict] = None) -> None:
-        """Record a complete span; ``t0``/``t1`` are ``time.time()``
-        seconds. No-op when disabled (callers usually pre-check
-        :func:`enabled` to skip even the clock reads)."""
-        if not self.enabled:
-            return
-        a = dict(args) if args else {}
-        if trace is not None:
-            a["trace"] = trace
+    def _stack(self) -> List[int]:
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        return stack
+
+    def _anchor(self, prof: bool) -> None:
+        """The two clocks' tie, once per capture: the profiler stamps its
+        events from the start of its own session, the ring in
+        ``time.time_ns()``. When a span first finds a capture running,
+        write one ``mv.trace.anchor`` annotation whose ``time_ns``
+        argument is the ring's clock at the annotation's start, and the
+        same span into the ring: a reader of either converts with it."""
+        self._anchored = prof
+        if prof:
+            with TraceAnnotation(ANCHOR):    # the first one is slow
+                pass
+            with TraceAnnotation(ANCHOR) as ann:
+                t0 = time.time_ns()
+                ann.set_metadata(time_ns=t0)
+            self.record(ANCHOR, t0, time.time_ns(), time_ns=t0)
+
+    def _record(self, name: str, t0_ns: int, t1_ns: int, span_id: int,
+                parent: Optional[int], cause: Optional[int], request,
+                cat: str, args: Dict[str, Any], prof: bool) -> None:
         ev = {
             "name": name, "cat": cat, "ph": "X",
-            "ts": int(t0 * 1e6), "dur": max(int((t1 - t0) * 1e6), 0),
+            "ts": t0_ns / 1e3, "dur": max(t1_ns - t0_ns, 0) / 1e3,
             "pid": self.rank, "tid": threading.get_ident() & 0x7FFFFFFF,
-            "args": a,
+            "id": span_id, "parent": parent, "cause": cause,
+            "request": request, "prof": prof, "args": args,
         }
         # append under the lock: dump()'s snapshot-then-clear would
         # otherwise drop a span landing between its two steps
         with self._lock:
             self._events.append(ev)
 
-    @contextmanager
-    def span(self, name: str, trace: Optional[int] = None,
-             cat: str = "ps", **args) -> Iterator[None]:
+    def add_span(self, name: str, t0: float, t1: float,
+                 trace: Optional[int] = None, cat: str = "ps",
+                 args: Optional[Dict] = None) -> None:
+        """A FINE site: record a span that has already ended; ``t0`` /
+        ``t1`` are ``time.time()`` seconds. No-op with ``trace_ids`` off
+        (callers pre-check :func:`enabled` to skip even the clock
+        reads). ``trace`` is the request the span belongs to."""
         if not self.enabled:
-            yield
             return
-        t0 = time.time()
-        try:
-            yield
-        finally:
-            self.add_span(name, t0, time.time(), trace=trace, cat=cat,
-                          args=args or None)
+        a = dict(args) if args else {}
+        if trace is not None:
+            a["trace"] = trace
+        self.record(name, int(t0 * 1e9), int(t1 * 1e9), request=trace,
+                    cat=cat, **a)
+
+    def record(self, name: str, t0_ns: int, t1_ns: int, *, request=None,
+               cause: Optional[int] = None, cat: str = "prog",
+               **counts) -> None:
+        """A COARSE span that has already ended (``time.time_ns()``
+        stamps): what a listener or the watcher learns after the fact.
+        Its parent is the span open on the calling thread."""
+        stack = self._stack()
+        self._record(name, t0_ns, t1_ns, next(self._span_ids),
+                     stack[-1] if stack else None, cause, request, cat,
+                     counts, profiling())
+
+    def span(self, name: str, *, request=None, cause: Optional[int] = None,
+             phase: Optional[str] = None, **counts) -> Span:
+        """A COARSE span around a ``with`` block: always recorded, feeds
+        the Dashboard monitor ``name``, annotates the profiler's trace
+        while one is captured, and (``phase=``) marks that phase of the
+        step profiler's current step. Not for per-request or
+        per-minibatch sites."""
+        return Span(self, name, request, cause, phase, counts)
 
     # ------------------------------------------------------------------ #
     def events(self) -> List[Dict]:
@@ -156,8 +295,82 @@ class Tracer:
 TRACER = Tracer()
 
 
+class DeviceWatcher:
+    """Closes spans for asynchronous device work when the device is done.
+
+    A dispatch returns before the device has run it, so a host span
+    around the dispatch reads a millisecond for a block that holds the
+    chip for hundreds. ``watch(name, array, ...)`` hands ``array`` (any
+    output of the dispatched program) to ONE thread that waits on each
+    in submission order (``block_until_ready`` releases the GIL) and
+    records a coarse span from the dispatch's start to the ready time.
+
+    Only while it can be read: with no profiler trace being captured and
+    ``trace_ids`` off, ``watch`` does nothing, no thread exists and
+    nothing waits. ``close()`` (or leaving the ``with`` block) waits for
+    what was submitted and ends the thread."""
+
+    def __init__(self) -> None:
+        self._queue: Optional[queue.SimpleQueue] = None
+        self._thread: Optional[threading.Thread] = None
+
+    def watch(self, name: str, array: Any, t0_ns: int, *, request=None,
+              cause: Optional[int] = None) -> None:
+        if not (TRACER.enabled or profiling()):
+            return
+        if self._thread is None:
+            self._queue = queue.SimpleQueue()
+            self._thread = threading.Thread(
+                target=self._run, name="mv-trace-watcher", daemon=True)
+            self._thread.start()
+        self._queue.put((name, array, t0_ns, request, cause))
+
+    def _run(self) -> None:
+        while True:
+            item = self._queue.get()
+            if item is None:
+                return
+            name, array, t0_ns, request, cause = item
+            try:
+                jax.block_until_ready(array)
+            except Exception:   # noqa: BLE001 — a failed program is the
+                continue        # caller's to raise; record no span for it
+            TRACER.record(name, t0_ns, time.time_ns(), request=request,
+                          cause=cause, cat="device")
+
+    def close(self) -> None:
+        if self._thread is not None:
+            self._queue.put(None)
+            self._thread.join()
+            self._thread = self._queue = None
+
+    def __enter__(self) -> "DeviceWatcher":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+
+def self_ms(events: List[Dict]) -> Dict[int, float]:
+    """Self time of every span in ``events``, by span ``id``, in ms: its
+    duration minus the union of its children's intervals (children are
+    the spans whose ``parent`` it is; each is clipped to the span)."""
+    children: Dict[int, List[Tuple[float, float]]] = {}
+    for e in events:
+        if e.get("parent") is not None:
+            children.setdefault(e["parent"], []).append(
+                (e["ts"], e["ts"] + e["dur"]))
+    out: Dict[int, float] = {}
+    for e in events:
+        lo, hi = e["ts"], e["ts"] + e["dur"]
+        covered = _profiler.union_length([(max(a, lo), min(b, hi))
+                                for a, b in children.get(e["id"], ())])
+        out[e["id"]] = (e["dur"] - covered) * 1e-3
+    return out
+
+
 def enabled() -> bool:
-    """THE hot-path gate (attribute read, no locks)."""
+    """THE hot-path gate of the fine sites (attribute read, no locks)."""
     return TRACER.enabled
 
 
@@ -174,8 +387,21 @@ def add_span(name: str, t0: float, t1: float, trace: Optional[int] = None,
     TRACER.add_span(name, t0, t1, trace=trace, cat=cat, args=args)
 
 
-def span(name: str, trace: Optional[int] = None, cat: str = "ps", **args):
-    return TRACER.span(name, trace=trace, cat=cat, **args)
+def record(name: str, t0_ns: int, t1_ns: int, *, request=None,
+           cause: Optional[int] = None, cat: str = "prog",
+           **counts) -> None:
+    TRACER.record(name, t0_ns, t1_ns, request=request, cause=cause,
+                  cat=cat, **counts)
+
+
+def span(name: str, *, request=None, cause: Optional[int] = None,
+         phase: Optional[str] = None, **counts) -> Span:
+    return TRACER.span(name, request=request, cause=cause, phase=phase,
+                       **counts)
+
+
+def events() -> List[Dict]:
+    return TRACER.events()
 
 
 def trace_path(directory: str, rank: Optional[int] = None) -> str:
@@ -186,5 +412,5 @@ def trace_path(directory: str, rank: Optional[int] = None) -> str:
 
 def dump_to(directory: str) -> int:
     """Dump buffered spans to the canonical per-rank file (no-op and 0
-    when tracing never recorded anything)."""
+    when nothing was recorded)."""
     return TRACER.dump(trace_path(directory))

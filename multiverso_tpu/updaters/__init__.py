@@ -29,10 +29,22 @@ Semantics parity notes (signs follow the reference):
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+
+
+def _rule(apply):
+    """Name a rule's operations ``mv.rowapply.rule`` in a device trace
+    (metadata only: the compiled program is the same). A scope per call:
+    one shared ``named_scope`` object is not safe across tracing threads."""
+    @functools.wraps(apply)
+    def scoped(self, data, state, delta, opt):
+        with jax.named_scope("mv.rowapply.rule"):
+            return apply(self, data, state, delta, opt)
+    return scoped
 
 
 class AddOption(NamedTuple):
@@ -55,6 +67,7 @@ class Updater:
     def init_state(self, shape, dtype) -> Any:
         return ()
 
+    @_rule
     def apply(self, data: jax.Array, state: Any, delta: jax.Array,
               opt: AddOption) -> Tuple[jax.Array, Any]:
         return data + delta, state
@@ -63,6 +76,7 @@ class Updater:
 class SGDUpdater(Updater):
     name = "sgd"
 
+    @_rule
     def apply(self, data, state, delta, opt):
         return data - delta, state
 
@@ -73,6 +87,7 @@ class MomentumUpdater(Updater):
     def init_state(self, shape, dtype):
         return {"smooth": jnp.zeros(shape, dtype)}
 
+    @_rule
     def apply(self, data, state, delta, opt):
         m = jnp.asarray(opt.momentum, data.dtype)
         smooth = m * state["smooth"] + (1.0 - m) * delta
@@ -93,6 +108,7 @@ class AdaGradUpdater(Updater):
             return {"g_sqr": jnp.zeros((self.num_workers,) + tuple(shape), dtype)}
         return {"g_sqr": jnp.zeros(shape, dtype)}
 
+    @_rule
     def apply(self, data, state, delta, opt):
         lr = jnp.asarray(opt.learning_rate, data.dtype)
         rho = jnp.asarray(opt.rho, data.dtype)
@@ -123,6 +139,7 @@ class AdamUpdater(Updater):
             "t": jnp.zeros((), jnp.int32),
         }
 
+    @_rule
     def apply(self, data, state, delta, opt):
         lr = jnp.asarray(opt.learning_rate, data.dtype)
         b1 = jnp.asarray(self.beta1, data.dtype)
@@ -156,6 +173,7 @@ class FTRLUpdater(Updater):
     def init_state(self, shape, dtype):
         return {"z": jnp.zeros(shape, dtype), "n": jnp.zeros(shape, dtype)}
 
+    @_rule
     def apply(self, data, state, delta, opt):
         g = delta
         z, n = state["z"], state["n"]

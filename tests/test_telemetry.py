@@ -213,12 +213,15 @@ class TestTraceWire:
 
 
 class TestTracer:
-    def test_disabled_records_nothing(self):
+    def test_disabled_records_no_fine_span(self):
+        # the two classes: add_span (per PS request) is behind trace_ids,
+        # span() (per call/block/build) is always recorded
         tr = ttrace.Tracer()
         tr.add_span("x", 0.0, 1.0, trace=1)
+        assert tr.events() == []
         with tr.span("y"):
             pass
-        assert tr.events() == []
+        assert [e["name"] for e in tr.events()] == ["y"]
 
     def test_span_shape_and_dump(self, tmp_path):
         tr = ttrace.Tracer()

@@ -1,0 +1,500 @@
+"""The one span primitive (telemetry/trace.py) and its sites on the
+measured paths: parents and self time against a brute-force oracle, the
+two classes and their gates, ``prof`` and the profiler's own trace, the
+spans and counts ``train_fused``, the device-plane ``train_ps_blocks``
+and a table build leave, the device-completion watcher, and the scope
+names the traced regions carry."""
+
+import glob
+import re
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import multiverso_tpu as mv
+from multiverso_tpu.telemetry import trace as ttrace
+from multiverso_tpu.utils import config
+from multiverso_tpu.utils.dashboard import Dashboard
+
+
+def _ev(i, ts, dur, parent=None, name="s"):
+    return {"name": name, "id": i, "parent": parent, "ts": float(ts),
+            "dur": float(dur)}
+
+
+def _oracle_self_us(events, span_id):
+    """Microseconds of the span no child covers, one at a time."""
+    e = next(x for x in events if x["id"] == span_id)
+    kids = [x for x in events if x["parent"] == span_id]
+    return sum(1 for t in range(int(e["ts"]), int(e["ts"] + e["dur"]))
+               if not any(k["ts"] <= t < k["ts"] + k["dur"] for k in kids))
+
+
+SELF_CASES = {
+    "leaf": [_ev(1, 0, 100)],
+    "one_child": [_ev(1, 0, 100), _ev(2, 10, 30, 1)],
+    "disjoint_children": [_ev(1, 0, 100), _ev(2, 10, 20, 1),
+                          _ev(3, 50, 25, 1)],
+    "overlapping_children": [_ev(1, 0, 100), _ev(2, 10, 40, 1),
+                             _ev(3, 30, 40, 1)],
+    "grandchild_counts_once": [_ev(1, 0, 100), _ev(2, 10, 50, 1),
+                               _ev(3, 20, 10, 2)],
+    "child_overruns_parent": [_ev(1, 0, 100), _ev(2, 80, 50, 1)],
+    # another thread's span of the same interval has its own parent chain
+    "cross_thread_is_no_child": [_ev(1, 0, 100), _ev(2, 10, 30, 1),
+                                 _ev(3, 0, 100), _ev(4, 5, 90, 3)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(SELF_CASES))
+def test_self_ms_matches_brute_force(case):
+    events = SELF_CASES[case]
+    got = ttrace.self_ms(events)
+    assert set(got) == {e["id"] for e in events}
+    for e in events:
+        assert got[e["id"]] == pytest.approx(
+            _oracle_self_us(events, e["id"]) * 1e-3)
+
+
+def test_parent_comes_from_the_threads_own_stack():
+    tr = ttrace.Tracer()
+    seen = {}
+
+    def other():
+        with tr.span("b.outer") as o:
+            with tr.span("b.inner", cause=seen["a"]) as i:
+                seen["b"] = (o.id, i.id, i.parent, o.parent)
+
+    with tr.span("a.outer", request=7) as a:
+        seen["a"] = a.id
+        t = threading.Thread(target=other)
+        t.start()
+        t.join(timeout=10)
+        assert not t.is_alive()
+        with tr.span("a.inner") as inner:
+            assert inner.parent == a.id
+        tr.add_span("fine", 0.0, 1.0)        # trace_ids off: nothing
+        t_late = time.time_ns()
+        time.sleep(0.001)
+        tr.record("a.late", t_late, time.time_ns())   # after the fact
+    o_id, i_id, i_parent, o_parent = seen["b"]
+    assert o_parent is None and i_parent == o_id    # not a.outer
+    by = {e["name"]: e for e in tr.events()}
+    assert set(by) == {"a.outer", "a.inner", "a.late", "b.outer", "b.inner"}
+    assert by["b.inner"]["cause"] == a.id
+    assert by["a.late"]["parent"] == a.id
+    assert by["a.outer"]["request"] == 7 and by["a.outer"]["parent"] is None
+    assert by["a.outer"]["tid"] != by["b.outer"]["tid"]
+    live = ttrace.self_ms(tr.events())
+    assert live[a.id] <= by["a.outer"]["dur"] * 1e-3
+    assert live[a.id] == pytest.approx(
+        (by["a.outer"]["dur"] - by["a.inner"]["dur"]
+         - by["a.late"]["dur"]) * 1e-3, abs=1e-3)   # ts is a float of us
+
+
+@pytest.mark.parametrize("site,recorded", [
+    ("span", True), ("record", True), ("add_span", False),
+    ("add_span_with_trace_ids", True)])
+def test_coarse_always_fine_only_with_trace_ids(site, recorded):
+    mv.init()            # every flag at its default
+    before = len(ttrace.events())
+    if site == "add_span_with_trace_ids":
+        config.set_flag("trace_ids", True)
+        ttrace.configure()
+    assert ttrace.enabled() is (site == "add_span_with_trace_ids")
+    if site == "span":
+        with ttrace.span("t.site", request=3, rows=5) as s:
+            s.set(more=1)
+    elif site == "record":
+        ttrace.record("t.site", 10, 20, request=3, rows=5)
+    else:
+        ttrace.add_span("t.site", 1.0, 2.0, trace=3, args={"rows": 5})
+    new = [e for e in ttrace.events()[before:] if e["name"] == "t.site"]
+    assert len(new) == (1 if recorded else 0)
+    if recorded:
+        e = new[0]
+        assert e["request"] == 3 and e["args"]["rows"] == 5
+        assert e["ph"] == "X" and e["prof"] is False and e["id"] > 0
+
+
+def test_enabled_is_one_attribute_read():
+    assert ttrace.enabled() is ttrace.TRACER.enabled
+    ttrace.TRACER.enabled = True
+    assert ttrace.enabled() is True
+
+
+def test_span_feeds_the_monitor_of_its_name():
+    with ttrace.span("t.mon"):
+        time.sleep(0.002)
+    snap = Dashboard.snapshot()["t.mon"]
+    [e] = [e for e in ttrace.events() if e["name"] == "t.mon"]
+    assert snap.count == 1
+    assert snap.total_ms == pytest.approx(e["dur"] * 1e-3, rel=1e-6)
+
+
+def test_span_is_recorded_when_its_body_raises():
+    with pytest.raises(KeyError):
+        with ttrace.span("t.raises"):
+            raise KeyError("x")
+    assert [e["name"] for e in ttrace.events()] == ["t.raises"]
+    with ttrace.span("t.after") as s:
+        assert s.parent is None              # the stack was unwound
+
+
+def test_phase_keyword_marks_the_step_profilers_phase():
+    from multiverso_tpu.telemetry import profiler as prof
+    config.set_flag("step_profile", True)
+    prof.configure(0)
+    with prof.step("s"):
+        with ttrace.span("t.push", phase="push"):
+            time.sleep(0.002)
+    [rec] = prof.records()
+    assert rec["phases"]["push"]["count"] == 1
+
+
+def test_ring_stays_bounded(monkeypatch):
+    monkeypatch.setattr(ttrace, "_MAX_EVENTS", 50)
+    tr = ttrace.Tracer()
+    for i in range(500):
+        with tr.span("t.many", request=i):
+            pass
+    events = tr.events()
+    assert len(events) == 50
+    assert events[-1]["request"] == 499      # the newest are kept
+
+
+def test_no_annotation_and_no_watcher_at_defaults(monkeypatch):
+    class Refuse:
+        is_enabled = staticmethod(lambda: False)
+
+        def __init__(self, *a, **k):
+            raise AssertionError("TraceAnnotation opened with no profiler")
+
+    monkeypatch.setattr(ttrace, "TraceAnnotation", Refuse)
+    threads = threading.active_count()
+    with ttrace.DeviceWatcher() as w:
+        with ttrace.span("t.quiet", request=1):
+            w.watch("t.device", jnp.ones(3), time.time_ns(), request=1)
+        assert w._thread is None
+        assert threading.active_count() == threads
+    assert [e["name"] for e in ttrace.events()
+            if e["name"].startswith("t.")] == ["t.quiet"]
+
+
+def test_watcher_closes_spans_in_order_when_trace_ids_is_on():
+    ttrace.TRACER.enabled = True
+    t0 = time.time_ns()
+    with ttrace.DeviceWatcher() as w:
+        for i in range(5):
+            w.watch("t.device", jnp.full((4,), i) * 2, t0, request=i,
+                    cause=100 + i)
+        assert w._thread is not None and w._thread.is_alive()
+        thread = w._thread
+    assert not thread.is_alive() and w._thread is None
+    done = [e for e in ttrace.events() if e["name"] == "t.device"]
+    assert [e["request"] for e in done] == list(range(5))
+    assert [e["cause"] for e in done] == [100 + i for i in range(5)]
+    ends = [e["ts"] + e["dur"] for e in done]
+    assert ends == sorted(ends) and all(e["parent"] is None for e in done)
+
+
+def _xplane_names(trace_dir):
+    from jax.profiler import ProfileData
+    [path] = glob.glob(str(trace_dir / "plugins" / "profile" / "*" /
+                           "*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("t.", "mv.trace")):
+                    found.setdefault(e.name, []).append(
+                        (e.start_ns, dict(e.stats)))
+    return found
+
+
+def test_prof_is_false_at_rest_and_true_inside_a_profiler_trace(tmp_path):
+    assert ttrace.profiling() is False
+    with ttrace.span("t.rest"):
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert ttrace.profiling() is True
+        with ttrace.span("t.traced", request=41, rows=2):
+            time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    with ttrace.span("t.after"):
+        pass
+    by = {e["name"]: e for e in ttrace.events()}
+    assert by["t.rest"]["prof"] is False and by["t.after"]["prof"] is False
+    assert by["t.traced"]["prof"] is True
+    # the same span lies in the profiler's own trace, with its request,
+    # and the anchor ties the two clocks to within a few hundred us here
+    names = _xplane_names(tmp_path)
+    assert "t.rest" not in names and "t.after" not in names
+    [(start_ns, stats)] = names["t.traced"]
+    assert stats["request"] == 41
+    anchor = by[ttrace.ANCHOR]
+    [(a_ns, a_stats)] = [x for x in names[ttrace.ANCHOR]
+                         if "time_ns" in x[1]]
+    assert int(a_stats["time_ns"]) == anchor["args"]["time_ns"]
+    offset = a_ns - int(a_stats["time_ns"])
+    assert abs(start_ns - (by["t.traced"]["ts"] * 1e3 + offset)) < 5e5
+
+
+# ---------------------------------------------------------------------- #
+# the sites
+# ---------------------------------------------------------------------- #
+@pytest.mark.parametrize("shape,dtype", [((37, 8), "float32"),
+                                         ((12, 3), "int32"),
+                                         ((100,), "float32")])
+def test_table_init_carries_rows_width_bytes(shape, dtype):
+    mv.init()
+    if len(shape) == 2:
+        t = mv.MatrixTable(*shape, dtype=dtype, name="spanned")
+    else:
+        t = mv.ArrayTable(shape[0], dtype=dtype, name="spanned")
+    events = ttrace.events()
+    [init] = [e for e in events if e["name"] == "table.init"]
+    a = init["args"]
+    rows, width = t.padded_shape[0], int(np.prod(t.padded_shape[1:]))
+    assert (a["table"], a["rows"], a["width"]) == ("spanned", rows, width)
+    assert a["bytes"] == rows * width * np.dtype(dtype).itemsize
+    assert a["bytes"] == t.raw().nbytes
+    kids = {e["name"] for e in events if e["parent"] == init["id"]}
+    assert kids == {"table.init.host", "table.init.put"}
+    assert init["parent"] is None and init["prof"] is False
+
+
+def test_compile_leaves_an_xla_compile_span():
+    mv.init()
+    before = len(ttrace.events())
+    with ttrace.span("t.caller") as s:
+        jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+    new = [e for e in ttrace.events()[before:] if e["name"] == "xla.compile"]
+    assert new, "the devstats listener recorded no compile"
+    e = new[-1]
+    assert e["args"]["event"] in ("compile", "cache_load")
+    assert e["args"]["seconds"] > 0 and "mv" in e["args"]["mesh"]
+    assert e["dur"] == pytest.approx(e["args"]["seconds"] * 1e6, rel=1e-3)
+    assert e["parent"] == s.id
+
+
+def _tiny_we(**kw):
+    from multiverso_tpu.apps.word_embedding import (WEConfig, WordEmbedding,
+                                                    synthetic_corpus)
+    from multiverso_tpu.data.dictionary import Dictionary
+
+    mv.init()
+    tokens = synthetic_corpus(6_000, vocab=60, seed=0)
+    cfg = WEConfig(**{**dict(size=8, min_count=1, batch_size=64, negative=2,
+                             window=2, epoch=1, sample=0), **kw})
+    we = WordEmbedding(cfg, Dictionary.build(tokens, 1))
+    return we, we.prepare_ids(tokens)
+
+
+def _children(events, parent):
+    return [e["name"] for e in sorted(events, key=lambda e: e["ts"])
+            if e["parent"] == parent["id"] and e["name"] != "xla.compile"]
+
+
+@pytest.mark.parametrize("mode", ["sg_shared", "sg", "cbow", "hs"])
+def test_train_fused_leaves_its_spans_and_counts(mode):
+    kw = {"sg_shared": {}, "sg": {"shared_negatives": 0},
+          "cbow": {"cbow": 1}, "hs": {"hs": 1, "negative": 0}}[mode]
+    we, ids = _tiny_we(**kw)
+    start = len(ttrace.events())
+    out1 = we.train_fused(ids, epochs=2)
+    first = ttrace.events()[start:]
+    mid = len(ttrace.events())
+    out2 = we.train_fused(ids, epochs=2)
+    second = ttrace.events()[mid:]
+    assert np.isfinite(out1["loss"]) and np.isfinite(out2["loss"])
+    copy = ["we.fused.copy"] if mode == "sg_shared" else []
+    want = (["we.fused.pairs"] + copy
+            + ["we.fused.dispatch", "we.fused.wait", "we.fused.adopt"])
+    for events, hit in ((first, 0), (second, 0 if mode == "cbow" else 1)):
+        [call] = [e for e in events if e["name"] == "we.fused"]
+        assert _children(events, call) == want
+        a = call["args"]
+        assert a["words"] == 2 * ids.size and a["epochs"] == 2
+        assert a["pairs"] == out1["pairs"] > 0
+        assert a["batches"] == a["pairs"] // we.cfg.batch_size
+        assert call["parent"] is None and call["request"] > 0
+        [pairs] = [e for e in events if e["name"] == "we.fused.pairs"]
+        assert pairs["args"]["cache_hit"] == hit
+        assert pairs["args"]["pairs"] == a["pairs"]
+        made = _children(events, pairs)
+        if hit:
+            assert made == [] and "h2d_bytes" not in pairs["args"]
+        else:
+            assert made == ["we.pairs.generate", "we.pairs.upload"]
+            assert pairs["args"]["h2d_bytes"] > 0
+        [disp] = [e for e in events if e["name"] == "we.fused.dispatch"]
+        assert disp["args"]["programs"] == 2
+        for e in events:
+            if e["name"] == "we.fused.copy":
+                assert e["args"]["bytes"] == (we.table_in.raw().nbytes
+                                              + we.table_out.raw().nbytes)
+        # the parts lie inside the call and leave little of it unnamed
+        assert ttrace.self_ms(events)[call["id"]] <= call["dur"] * 1e-3
+    assert second[-1]["request"] == first[-1]["request"] + 1
+
+
+BLOCK_SPANS = {"we.blocks", "we.prepare", "we.prepare.arrays",
+               "we.prepare.pack", "we.prepare.put", "we.block.wait_prepared",
+               "we.block.dispatch", "we.blocks.drain"}
+
+
+@pytest.mark.parametrize("mode", ["defaults", "profiler", "trace_ids"])
+def test_device_plane_blocks_leave_their_spans_and_counts(mode, tmp_path):
+    we, ids = _tiny_we(use_ps=1, data_block_size=1500)
+    assert we._use_device_plane(1)
+    we.train_ps_blocks(ids, epochs=1)           # compile outside the case
+    n_blocks = -(-ids.size // 1500)
+    if mode == "trace_ids":
+        config.set_flag("trace_ids", True)
+        ttrace.configure()
+    if mode == "profiler":
+        jax.profiler.start_trace(str(tmp_path))
+    threads = {t.name for t in threading.enumerate()}
+    start = len(ttrace.events())
+    try:
+        out = we.train_ps_blocks(ids, epochs=1)
+    finally:
+        if mode == "profiler":
+            jax.profiler.stop_trace()
+    events = [e for e in ttrace.events()[start:]
+              if e["name"] not in ("xla.compile", ttrace.ANCHOR)]
+    assert np.isfinite(out["loss"])
+    assert not any(t.name == "mv-trace-watcher"
+                   for t in threading.enumerate())
+    watched = mode != "defaults"
+    names = {e["name"] for e in events}
+    assert names == BLOCK_SPANS | ({"we.block.device"} if watched else set())
+    assert all(e["prof"] is (mode == "profiler") for e in events)
+    [call] = [e for e in events if e["name"] == "we.blocks"]
+    assert call["args"] == {"plane": "device", "blocks": n_blocks,
+                            "words": int(ids.size)}
+    by = {n: sorted((e for e in events if e["name"] == n),
+                    key=lambda e: e["request"]) for n in names}
+    for n in ("we.prepare", "we.block.wait_prepared", "we.block.dispatch"):
+        assert [e["request"] for e in by[n]] == list(range(n_blocks)), n
+    for prep, disp in zip(by["we.prepare"], by["we.block.dispatch"]):
+        a = prep["args"]
+        assert 0 < a["rows_touched"] <= a["rows_bucket"]
+        assert a["rows_bucket"] <= we.table_in.padded_shape[0]
+        assert a["pairs"] > 0 and a["h2d_bytes"] > 0
+        assert a["minibatches"] * we.cfg.batch_size >= a["pairs"]
+        assert _children(events, prep) == [
+            "we.prepare.arrays", "we.prepare.pack", "we.prepare.put"]
+        assert prep["tid"] != call["tid"] and prep["parent"] is None
+        assert disp["cause"] == prep["id"] and disp["parent"] == call["id"]
+    assert all("queue_depth" in e["args"] and e["parent"] == call["id"]
+               for e in by["we.block.wait_prepared"])
+    [drain] = by["we.blocks.drain"]
+    assert drain["args"]["blocks"] == n_blocks
+    if watched:
+        done = by["we.block.device"]
+        assert [e["request"] for e in done] == list(range(n_blocks))
+        for dev, disp in zip(done, by["we.block.dispatch"]):
+            assert dev["cause"] == disp["id"]
+            assert dev["ts"] <= disp["ts"] + 1.0      # from the dispatch
+            assert dev["ts"] + dev["dur"] <= call["ts"] + call["dur"]
+    # the dispatch is its own monitor now; a block is we.block.device
+    snap = Dashboard.snapshot()
+    assert "we.block" not in snap
+    assert snap["we.block.dispatch"].count >= n_blocks
+    assert snap["we.prepare"].count >= n_blocks
+
+
+def test_host_plane_keeps_its_block_monitors():
+    we, ids = _tiny_we(use_ps=1, data_block_size=1500, ps_device_plane="0")
+    Dashboard.reset()
+    we.train_ps_blocks(ids, epochs=1)
+    snap = Dashboard.snapshot()
+    n_blocks = -(-ids.size // 1500)
+    for name in ("we.prepare", "we.block", "we.push"):
+        assert snap[name].count == n_blocks, name
+    [call] = [e for e in ttrace.events() if e["name"] == "we.blocks"]
+    assert call["args"]["plane"] == "host"
+
+
+# ---------------------------------------------------------------------- #
+# names on the device (metadata of the compiled programs)
+# ---------------------------------------------------------------------- #
+def _scopes(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return set(re.findall(r"mv\.[a-z_.]+", text))
+
+
+def test_block_program_carries_pull_scan_push_scopes():
+    we, ids = _tiny_we(use_ps=1, data_block_size=1500)
+    we.train_ps_blocks(ids[:1500], epochs=1)    # builds what a block needs
+    prep, _ = we._prepare_block_device(ids[:1500],
+                                       np.random.default_rng(0), 0)
+    si, ss = we.table_in.state, we.table_out.state
+    text = we._fused_block_fn().lower(
+        si["data"], si["ustate"], ss["data"], ss["ustate"], prep["ids_in"],
+        prep["ids_sec"], prep["valid"], prep["batch"], prep.get("remap"),
+        prep.get("neg_seed"), we._neg_dev).compile().as_text()
+    found = set(re.findall(r"mv\.[a-z_.]+", text))
+    assert {"mv.pull", "mv.scan", "mv.push", "mv.scan.gather",
+            "mv.scan.grad", "mv.scan.scatter", "mv.rowapply.gather",
+            "mv.rowapply.rule", "mv.rowapply.scatter"} <= found
+
+
+@pytest.mark.parametrize("shared", [True, False])
+def test_fused_epoch_carries_fused_scopes(shared):
+    from multiverso_tpu.models import word2vec as w2v
+    cfg = w2v.W2VConfig(40, 8, 2, 2, 0.025, False, False, 8 if shared else 0)
+    unigram = np.full(40, 1 / 40)
+    tables = (jnp.zeros((41, 8)), jnp.zeros((41, 8)))
+    batches = (jnp.zeros((3, 16), jnp.int32), jnp.ones((3, 16), jnp.int32))
+    if shared:
+        fn = w2v.make_fused_shared_epoch(cfg, unigram, jnp.float32)
+        last = jnp.asarray(w2v.init_lcg_state(8, 0))
+    else:
+        fn, last = w2v.make_fused_epoch(cfg, unigram), jax.random.key(0)
+    text = fn.lower(*tables, *batches, last).compile().as_text()
+    found = set(re.findall(r"mv\.[a-z_.]+", text))
+    assert {"mv.fused", "mv.fused.gather", "mv.fused.grad",
+            "mv.fused.scatter"} <= found
+    assert not any(s.startswith("mv.scan") for s in found)
+
+
+def test_dlrm_step_carries_dlrm_and_rule_scopes():
+    from multiverso_tpu.models import dlrm
+    mv.init()
+    cfg = dlrm.DLRMConfig(vocab_sizes=(11, 7), embed_dim=4, dense_dim=3,
+                          bottom_mlp=(8, 4), top_mlp=(8, 1))
+    emb = mv.MatrixTable(dlrm.total_rows(cfg), 4, updater="adagrad")
+    flat, meta = dlrm.flatten_mlp(dlrm.init_mlp_params(cfg, 0))
+    mlp = mv.ArrayTable(flat.size, updater="adagrad", init=flat)
+    cat, dense, labels = dlrm.synthetic_ctr(cfg, 16, 0)
+    found = _scopes(dlrm.make_train_step(cfg, emb, mlp, meta), emb.state,
+                    mlp.state, jnp.asarray(cat), jnp.asarray(dense),
+                    jnp.asarray(labels))
+    assert {"mv.dlrm.gather", "mv.dlrm.mlp", "mv.dlrm.delta",
+            "mv.rowapply.rule"} <= found
+
+
+@pytest.mark.parametrize("updater", ["default", "sgd", "momentum_sgd",
+                                     "adagrad", "adam", "ftrl"])
+def test_every_updater_rule_is_scoped_and_unchanged(updater):
+    from multiverso_tpu import updaters
+    u = updaters.get_updater(updater)
+    data = jnp.linspace(-1, 1, 24).reshape(6, 4)
+    delta = jnp.full((6, 4), 0.25)
+    state = u.init_state((6, 4), jnp.float32)
+    opt = updaters.AddOption(learning_rate=0.1, rho=0.1, momentum=0.5)
+    assert "mv.rowapply.rule" in _scopes(
+        lambda d, s, g: u.apply(d, s, g, opt), data, state, delta)
+    # the scope is metadata: the rule's own function gives the same values
+    plain, _ = type(u).apply.__wrapped__(u, data, state, delta, opt)
+    scoped, _ = u.apply(data, state, delta, opt)
+    np.testing.assert_array_equal(np.asarray(plain), np.asarray(scoped))
