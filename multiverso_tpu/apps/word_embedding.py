@@ -322,8 +322,9 @@ class WordEmbedding:
     # fused path (device-resident training)
     # ------------------------------------------------------------------ #
     def _fused_epoch_fn(self):
-        """The jitted, table-donating epoch program of the active (cbow,
-        hs, shared-negatives) mode, built once. ``shared`` programs
+        """The jitted epoch program of the active (cbow, hs,
+        shared-negatives) mode, built once; it hands both tables back in
+        their own formats. ``shared`` programs donate the tables and
         thread the LCG sampler state through instead of a PRNG key."""
         cfg = self.cfg
         shared = not (cfg.cbow or cfg.hs) and cfg.shared_negatives > 0
@@ -335,23 +336,28 @@ class WordEmbedding:
         w2v_cfg = w2v.W2VConfig(len(self.dict), cfg.size, cfg.negative,
                                 cfg.window, cfg.alpha, cfg.cbow, cfg.hs,
                                 cfg.shared_negatives)
+        formats = (self.table_in.format, self._sec_table().format)
         if cfg.hs:
             make = (w2v.make_fused_cbow_hs_epoch if cfg.cbow
                     else w2v.make_fused_hs_epoch)
-            fn = make(w2v_cfg, *self._hs)
+            fn = make(w2v_cfg, *self._hs, table_formats=formats)
         elif shared:
             # TPU-first fast path: batch-shared negatives on the MXU
             cd = self.fused_compute_dtype = (
                 jnp.bfloat16 if jax.devices()[0].platform == "tpu"
                 else jnp.float32)
             fn = w2v.make_fused_shared_epoch(w2v_cfg, self.unigram,
-                                             compute_dtype=cd)
-            self._lcg = jnp.asarray(w2v.init_lcg_state(
-                cfg.shared_negatives, cfg.seed))
+                                             compute_dtype=cd,
+                                             table_formats=formats)
+            # replicated on the mesh, as the epoch hands it back
+            self._lcg = jax.device_put(
+                w2v.init_lcg_state(cfg.shared_negatives, cfg.seed),
+                jax.sharding.NamedSharding(
+                    mv.mesh(), jax.sharding.PartitionSpec()))
         else:
             make = (w2v.make_fused_cbow_epoch if cfg.cbow
                     else w2v.make_fused_epoch)
-            fn = make(w2v_cfg, self.unigram)
+            fn = make(w2v_cfg, self.unigram, table_formats=formats)
         self._fused_cache[name] = fn
         return fn, shared
 
@@ -377,6 +383,18 @@ class WordEmbedding:
 
     def train_fused(self, ids: np.ndarray,
                     epochs: Optional[int] = None) -> Dict[str, float]:
+        """Train ``epochs`` passes over ``ids`` on the device, one program
+        a pass, and leave the result in the tables.
+
+        The programs run on the tables' own buffers (the shared-negatives
+        epoch donates them), as a block of :meth:`train_ps_blocks` does:
+        under both tables' dispatch locks each pass takes the tables'
+        ``program_state()`` and the tables adopt what it returns. An
+        error raised before a program runs (trace, compile, allocation at
+        dispatch) leaves both tables as the last completed dispatch left
+        them: readable, and before the first pass unchanged. A device
+        fault in the middle of a pass loses the tables, here as in the
+        block path; a checkpoint is the remedy in both."""
         cfg = self.cfg
         epochs = epochs or cfg.epoch
         self._calls += 1
@@ -390,34 +408,26 @@ class WordEmbedding:
                 batches = (cbd, xbd)
             call.set(pairs=int(pairs), batches=int(batches[0].shape[0]))
             epoch_fn, shared = self._fused_epoch_fn()
-            sec_table = self._sec_table()
-            state_in, state_sec = self.table_in.state, sec_table.state
-            win, wsec = state_in["data"], state_sec["data"]
-            if shared:
-                # epoch_fn donates its table args; chain from copies so the
-                # live table buffers survive a mid-epoch failure (OOM/^C)
-                with _trace.span("we.fused.copy",
-                                 bytes=int(win.nbytes + wsec.nbytes)):
-                    win, wsec = jnp.copy(win), jnp.copy(wsec)
-            with _trace.span("we.fused.dispatch", programs=epochs):
+            t_in, t_sec = self.table_in, self._sec_table()
+            with _trace.span("we.fused.dispatch", programs=epochs), \
+                    t_in._dispatch_lock, t_sec._dispatch_lock:
                 key = None if shared else jax.random.key(cfg.seed)
                 for _ in range(epochs):
+                    si, ss = t_in.program_state(), t_sec.program_state()
                     if shared:
                         win, wsec, loss, self._lcg = epoch_fn(
-                            win, wsec, *batches, self._lcg)
+                            si["data"], ss["data"], *batches, self._lcg)
                     else:
                         key, sub = jax.random.split(key)
-                        win, wsec, loss = epoch_fn(win, wsec, *batches, sub)
+                        win, wsec, loss = epoch_fn(
+                            si["data"], ss["data"], *batches, sub)
+                    t_in.adopt({"data": win, "ustate": si["ustate"]})
+                    t_sec.adopt({"data": wsec, "ustate": ss["ustate"]})
             with _trace.span("we.fused.wait"):
-                jax.block_until_ready(win)
                 # fetch the scalar loss BEFORE stopping the clock: the
                 # readback waits for the whole epoch chain
                 loss_f = float(loss)
-            with _trace.span("we.fused.adopt"):
-                sec_table.adopt({"data": wsec,
-                                 "ustate": state_sec["ustate"]})
-                self.table_in.adopt({"data": win,
-                                     "ustate": state_in["ustate"]})
+            with _trace.span("we.fused.count"):
                 dt = time.perf_counter() - t0
                 # words/sec follows the word2vec convention: corpus
                 # *tokens* consumed per second (ref trainer.cpp
@@ -1023,9 +1033,10 @@ class WordEmbedding:
         """One jitted program = the whole reference block cycle: pull
         (device gather of the block's rows), local train (lax.scan over
         minibatches), push (new - old deltas through the table updater,
-        functional_add_rows). Donates both tables' buffers — the block
-        chain re-uses device memory like the reference's in-place server
-        shard (ref distributed_wordembedding.cpp:147-252 collapsed into
+        functional_add_rows). Donates both tables' buffers and hands them
+        back in the tables' own formats — the block chain re-uses device
+        memory like the reference's in-place server shard
+        (ref distributed_wordembedding.cpp:147-252 collapsed into
         XLA)."""
         fn = self._fused_cache.get("ps_block")
         if fn is not None:
@@ -1066,14 +1077,20 @@ class WordEmbedding:
                 d_in, d_sec, loss = self._run_block_scan(
                     step, old_in, old_sec, valid, batch, neg_fn)
             with jax.named_scope("mv.push"):
+                # _prepare_block_device: sorted ids, then scratch rows
                 s_in = t_in.functional_add_rows(
-                    {"data": din, "ustate": uin}, ids_in, d_in)
+                    {"data": din, "ustate": uin}, ids_in, d_in,
+                    sorted_ids=True)
                 s_sec = t_sec.functional_add_rows(
-                    {"data": dsec, "ustate": usec}, ids_sec, d_sec)
+                    {"data": dsec, "ustate": usec}, ids_sec, d_sec,
+                    sorted_ids=True)
             return (s_in["data"], s_in["ustate"],
                     s_sec["data"], s_sec["ustate"], loss)
 
-        fn = jax.jit(fused, donate_argnums=(0, 1, 2, 3))
+        f_in, f_sec = t_in.state_format, t_sec.state_format
+        fn = jax.jit(fused, donate_argnums=(0, 1, 2, 3),
+                     out_shardings=(f_in["data"], f_in["ustate"],
+                                    f_sec["data"], f_sec["ustate"], None))
         self._fused_cache["ps_block"] = fn
         return fn
 
@@ -1095,7 +1112,7 @@ class WordEmbedding:
         with _trace.span("we.block.dispatch", request=index,
                          cause=prepare_span) as sp, \
                 t_in._dispatch_lock, t_sec._dispatch_lock:
-            si, ss = t_in.state, t_sec.state
+            si, ss = t_in.program_state(), t_sec.program_state()
             din, uin, dsec, usec, loss = fn(
                 si["data"], si["ustate"], ss["data"], ss["ustate"],
                 prep["ids_in"], prep["ids_sec"], prep["valid"],

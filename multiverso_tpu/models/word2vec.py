@@ -29,6 +29,7 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 
 class W2VConfig(NamedTuple):
@@ -242,14 +243,33 @@ def cbow_hs_step(win: jax.Array, hs_out: jax.Array, windows: jax.Array,
     return win, hs_out, loss
 
 
-def make_fused_epoch(cfg: W2VConfig, unigram: np.ndarray):
+def _epoch_jit(table_formats, carried: int = 0, **jit_kw):
+    """``jax.jit`` for an epoch program ``(win, wsec, ...) -> (win, wsec,
+    loss, *carried)``. ``table_formats`` is the two tables'
+    ``Table.format``: the tables come back laid out as they are given
+    (``Table.program_state``), so a chain of calls copies no table. State
+    ``carried`` from call to call comes back replicated on the tables'
+    mesh, where :func:`init_lcg_state`'s caller puts it, so the second
+    call finds the first call's program. ``None`` leaves every result to
+    the compiler."""
+    if table_formats is not None:
+        whole = table_formats[0].sharding
+        if isinstance(whole, NamedSharding):
+            whole = NamedSharding(whole.mesh, PartitionSpec())
+        jit_kw["out_shardings"] = (tuple(table_formats) + (None,)
+                                   + (whole,) * carried)
+    return functools.partial(jax.jit, **jit_kw)
+
+
+def make_fused_epoch(cfg: W2VConfig, unigram: np.ndarray,
+                     table_formats=None):
     """Build a jitted scan over skipgram-NS pair minibatches: the whole block
     trains on device; negatives are drawn in-graph. Returns
     ``epoch_fn(win, wout, centers, contexts, key) -> (win, wout, mean_loss)``
     where centers/contexts are (num_batches, B)."""
     neg_table = jnp.asarray(build_negative_table(unigram))
 
-    @jax.jit
+    @_epoch_jit(table_formats)
     def epoch_fn(win, wout, centers, contexts, key):
         def body(carry, batch):
             win, wout, key = carry
@@ -340,7 +360,8 @@ def shared_neg_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
 
 
 def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
-                            compute_dtype=jnp.bfloat16, table_bits: int = 20):
+                            compute_dtype=jnp.bfloat16, table_bits: int = 20,
+                            table_formats=None):
     """Fused epoch with batch-shared negatives and an in-graph LCG sampler.
 
     The negative draw uses the reference's own RNG design — word2vec.c's
@@ -362,7 +383,7 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
 
     # donate the tables: epochs chain win/wout through, and without donation
     # every call pays a full-table copy before the first scatter
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    @_epoch_jit(table_formats, 1, donate_argnums=(0, 1))
     def epoch_fn(win, wout, centers, contexts, lcg_state):
         # the whole epoch's sampler states in one closed-form jump + ONE
         # batched table gather (bit-identical to stepping the LCG per
@@ -396,11 +417,12 @@ def init_lcg_state(k_shared: int, seed: int = 0) -> np.ndarray:
         0, np.iinfo(np.uint32).max, size=(k_shared,), dtype=np.uint32)
 
 
-def make_fused_cbow_epoch(cfg: W2VConfig, unigram: np.ndarray):
+def make_fused_cbow_epoch(cfg: W2VConfig, unigram: np.ndarray,
+                          table_formats=None):
     """CBOW-NS variant: scans (windows, masks, targets) batches."""
     neg_table = jnp.asarray(build_negative_table(unigram))
 
-    @jax.jit
+    @_epoch_jit(table_formats)
     def epoch_fn(win, wout, windows, masks, targets, key):
         def body(carry, batch):
             win, wout, key = carry
@@ -440,12 +462,12 @@ def _make_path_gather(codes: np.ndarray, points: np.ndarray,
 
 
 def make_fused_hs_epoch(cfg: W2VConfig, codes: np.ndarray, points: np.ndarray,
-                        lengths: np.ndarray):
+                        lengths: np.ndarray, table_formats=None):
     """Hierarchical-softmax skipgram variant: each batch gathers its
     contexts' Huffman paths in-graph."""
     path = _make_path_gather(codes, points, lengths)
 
-    @jax.jit
+    @_epoch_jit(table_formats)
     def epoch_fn(win, hs_out, centers, contexts, key):
         def body(carry, batch):
             win, hs_out = carry
@@ -464,12 +486,13 @@ def make_fused_hs_epoch(cfg: W2VConfig, codes: np.ndarray, points: np.ndarray,
 
 
 def make_fused_cbow_hs_epoch(cfg: W2VConfig, codes: np.ndarray,
-                             points: np.ndarray, lengths: np.ndarray):
+                             points: np.ndarray, lengths: np.ndarray,
+                             table_formats=None):
     """CBOW x HS variant: scans (windows, masks, targets) batches; each
     batch gathers its TARGETS' Huffman paths in-graph."""
     path = _make_path_gather(codes, points, lengths)
 
-    @jax.jit
+    @_epoch_jit(table_formats)
     def epoch_fn(win, hs_out, windows, masks, targets, key):
         del key  # HS draws no negatives; kept for dispatch uniformity
 

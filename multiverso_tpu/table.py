@@ -35,12 +35,16 @@ hardware roofline rather than paying a host round-trip per step.
 
 from __future__ import annotations
 
+import concurrent.futures
+import functools
+import os
 import threading
 from typing import Any, Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from multiverso_tpu import updaters as updaters_lib
@@ -49,6 +53,7 @@ from multiverso_tpu.telemetry import memstats as _memstats
 from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.updaters import AddOption
 from multiverso_tpu.utils import config, log
+from multiverso_tpu.utils import platform as _platform
 from multiverso_tpu.utils.dashboard import Dashboard, monitor
 from multiverso_tpu.zoo import Zoo
 
@@ -111,6 +116,91 @@ def _ceil_to(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
+def _uniform(seed: int, scale: float, shape: Tuple[int, ...],
+             dtype) -> np.ndarray:
+    """``default_rng(seed).uniform(-scale, scale, shape).astype(dtype)``,
+    value for value, drawn by every core at once and a megabyte-sized
+    piece at a time: a uniform double takes one step of the PCG64 stream,
+    so a generator advanced by a piece's offset draws that piece, and
+    NumPy fills without the interpreter lock. (Drawn in one piece, a 1.8M
+    x 300 table took 9 s, most of it the page faults of a 4.3 GB
+    ``float64`` temporary: most of a word2vec build.)"""
+    out = np.empty(shape, dtype)
+    flat = out.reshape(-1)
+    workers = min(32, os.cpu_count() or 1)
+    share, piece = -(-flat.size // workers), 1 << 20
+
+    def draw(lo: int) -> None:
+        bits = np.random.PCG64(seed)
+        bits.advance(lo)
+        rng = np.random.Generator(bits)
+        hi = min(lo + share, flat.size)
+        for a in range(lo, hi, piece):
+            b = min(a + piece, hi)
+            flat[a:b] = rng.uniform(-scale, scale, b - a)
+
+    with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+        list(pool.map(draw, range(0, flat.size, share)))
+    return out
+
+
+@functools.lru_cache(maxsize=256)
+def _default_order(shape: Tuple[int, ...], dtype,
+                   sharding) -> Optional[Tuple[int, ...]]:
+    """``major_to_minor`` of the layout ``sharding``'s devices give a shard
+    of ``shape`` by default; ``None`` where the backend has no layouts."""
+    device = min(sharding.device_set, key=lambda d: d.id)
+    try:
+        return Layout.from_pjrt_layout(device.client.get_default_layout(
+            jnp.dtype(dtype), sharding.shard_shape(shape),
+            device)).major_to_minor
+    except jax.errors.JaxRuntimeError as e:
+        if str(e).startswith("UNIMPLEMENTED"):
+            return None
+        raise
+
+
+def _row_probe(data, ids):
+    """What every row program does to a table: gather rows, scatter-add
+    them back. Compiled, never run (:func:`row_program_layout`)."""
+    return data.at[ids].add(jnp.take(data, ids, axis=0))
+
+
+@functools.lru_cache(maxsize=64)
+def row_program_layout(shape: Tuple[int, ...], dtype,
+                       sharding) -> Optional[Layout]:
+    """The layout the row programs of a table of ``shape`` run in on
+    ``sharding``'s devices, where that is not the device's default for
+    the shape; ``None`` where the default serves.
+
+    Two questions to the device, and no width rule here. First its
+    default layout for a shard of the shape: where rows are the major
+    dimension (row-major: every backend but the TPU, and there a
+    ``float32`` width that fills its 128 lanes) nothing more is asked.
+    Otherwise a row gather and scatter-add on the table
+    (:func:`_row_probe`) is compiled with the table's layout left to the
+    compiler (``Layout.AUTO``), and what it picks is the answer. On a v5e
+    that is row-major for widths such as 64, 100 and 300: the default
+    there has rows as the MINOR dimension, and a program that takes such
+    a table whole transposes it on the way in and again on the way out.
+    For a million rows of width 2, 10 or 32 it is the default again:
+    row-major tiles would pad those 4 to 64 times over."""
+    default = _default_order(shape, dtype, sharding)
+    if (default is None or default == tuple(range(len(shape)))
+            # a program that returns a chosen layout has to be compiled in
+            # this process (the probe below is the first of them)
+            or not _platform.compile_result_layouts_in_process()):
+        return None
+    auto = Format(Layout.AUTO, sharding)
+    compiled = jax.jit(
+        _row_probe, donate_argnums=0, in_shardings=(auto, None),
+        out_shardings=auto).lower(
+            jax.ShapeDtypeStruct(shape, dtype, sharding=sharding),
+            jax.ShapeDtypeStruct((8,), jnp.int32)).compile()
+    picked = compiled.input_formats[0][0].layout.major_to_minor
+    return None if picked == default else Layout(major_to_minor=picked)
+
+
 class Table:
     """Base sharded table. Subclasses fix dimensionality and op surface."""
 
@@ -152,6 +242,11 @@ class Table:
         self._data_spec = P(self._axis, *([None] * (len(self.shape) - 1)))
         self._sharding = NamedSharding(mesh, self._data_spec)
         self._replicated = NamedSharding(mesh, P())
+        # what the table's own row programs take and return it in; the
+        # layout is None where that is the device's default
+        self._format = Format(
+            row_program_layout(self._padded_shape, self.dtype,
+                               self._sharding), self._sharding)
 
         if updater is None:
             updater = config.get_flag("updater_type")
@@ -163,7 +258,8 @@ class Table:
         with _trace.span(
                 "table.init", table=name, rows=self._padded_rows,
                 width=int(np.prod(self.shape[1:])),
-                bytes=int(np.prod(self._padded_shape)) * self.dtype.itemsize):
+                bytes=int(np.prod(self._padded_shape)) * self.dtype.itemsize,
+                row_major=int(self._format.layout is not None)):
             with _trace.span("table.init.host"):
                 host_init = self._build_init(init, seed, init_scale)
             with _trace.span("table.init.put"):
@@ -286,20 +382,31 @@ class Table:
             # Uniform(-scale, scale) random init — the reference's word2vec
             # input-embedding server init (ref src/table/matrix_table.cpp:372-384
             # and Applications/WordEmbedding/src/communicator.cpp:20).
-            rng = np.random.default_rng(seed)
-            out = rng.uniform(-init_scale, init_scale,
-                              self._padded_shape).astype(self.dtype)
+            out = _uniform(seed, init_scale, self._padded_shape, self.dtype)
             out[self.shape[0]:] = 0
             return out
         return np.zeros(self._padded_shape, dtype=self.dtype)
 
-    def _place_state(self, x: jax.Array) -> jax.Array:
-        """Shard updater state like the data where shapes line up, else replicate."""
+    def _leaf_format(self, x) -> Format:
+        """How the table's own row programs hold the data, or a leaf of
+        updater state: sharded and laid out like the data where shapes
+        line up (leading axes, such as a per-worker history's, stay
+        major), else replicated in the default layout."""
         nd, pd = np.ndim(x), len(self._padded_shape)
         if nd >= pd and tuple(np.shape(x)[nd - pd:]) == self._padded_shape:
-            spec = P(*([None] * (nd - pd)), self._axis, *([None] * (pd - 1)))
-            return jax.device_put(x, NamedSharding(self._mesh, spec))
-        return jax.device_put(x, self._replicated)
+            lead = nd - pd
+            spec = P(*([None] * lead), self._axis, *([None] * (pd - 1)))
+            layout = self._format.layout
+            if layout is not None and lead:
+                layout = Layout(major_to_minor=tuple(range(lead)) + tuple(
+                    lead + a for a in layout.major_to_minor))
+            return Format(layout, NamedSharding(self._mesh, spec))
+        return Format(None, self._replicated)
+
+    def _place_state(self, x: jax.Array) -> jax.Array:
+        """Shard updater state like the data where shapes line up, else
+        replicate; in the default layout, as the data is built."""
+        return jax.device_put(x, self._leaf_format(x).sharding)
 
     # ------------------------------------------------------------------ #
     # mutation bookkeeping (Zoo dirty fence + get-cache version)
@@ -473,9 +580,60 @@ class Table:
     # ------------------------------------------------------------------ #
     @property
     def state(self) -> Dict[str, Any]:
-        """Current table pytree {data, ustate}; safe to close over in jit."""
+        """Current table pytree {data, ustate}, in the device's default
+        layout; safe to close over in jit."""
         self._flush_host_adds()
+        self._lay_out(own=False)
         return {"data": self._data, "ustate": self._ustate}
+
+    def program_state(self) -> Dict[str, Any]:
+        """:attr:`state` as the table's own row programs take it: laid out
+        in :attr:`state_format`. Such a program donates the state, names
+        :attr:`state_format` as the ``out_shardings`` of the state it
+        returns, and its caller holds ``_dispatch_lock`` from this call
+        until the table has adopted that. A chain of such calls copies no
+        table; handing the table to anyone else (:attr:`state`,
+        :meth:`raw`) or back costs one copy of it, counted
+        (``table.relayout``)."""
+        self._flush_host_adds()
+        self._lay_out(own=True)
+        return {"data": self._data, "ustate": self._ustate}
+
+    def _lay_out(self, own: bool) -> None:
+        """Hold the data and the row-shaped updater state in the layout of
+        the table's own row programs (``own``) or in the device's
+        default. Where the two are one layout (``format.layout`` is None:
+        every table on a CPU) there is nothing to do; otherwise what lies
+        in the other layout is copied over, each copy a ``table.relayout``
+        span with its direction and bytes."""
+        if self._format.layout is None:
+            return
+        with self._dispatch_lock:
+            self._data = self._relaid(self._data, own)
+            self._ustate = jax.tree.map(
+                lambda x: self._relaid(x, own), self._ustate)
+
+    def _relaid(self, x: jax.Array, own: bool) -> jax.Array:
+        fmt = self._leaf_format(x)
+        if fmt.layout is None:          # a leaf with one layout
+            return x
+        if own:
+            want = fmt.layout.major_to_minor
+        else:
+            fmt = Format(None, fmt.sharding)
+            want = _default_order(x.shape, x.dtype, fmt.sharding)
+        if x.format.layout.major_to_minor == want:
+            return x
+        with _trace.span("table.relayout", table=self.name,
+                         to="rows" if own else "default", relayouts=1,
+                         bytes=int(x.nbytes)):
+            # the source goes as a donated buffer goes, whoever else still
+            # holds it: what follows is a donating program (or the reader
+            # who asked), and two live copies of a table are what a 16 GB
+            # chip has no room for. Waited for, so that it can go now.
+            laid = jax.block_until_ready(jax.device_put(x, fmt))
+            x.delete()
+            return laid
 
     def functional_add(self, state: Dict[str, Any], delta: jax.Array,
                        opt: Optional[AddOption] = None) -> Dict[str, Any]:
@@ -512,12 +670,28 @@ class Table:
         return self._sharding
 
     @property
+    def format(self) -> Format:
+        """Sharding and layout the table's own row programs hold the data
+        in; the layout is ``None`` where it is the device's default
+        (:func:`row_program_layout`)."""
+        return self._format
+
+    @property
+    def state_format(self) -> Dict[str, Any]:
+        """:meth:`program_state`'s pytree with a ``Format`` at every
+        leaf."""
+        return {"data": self._format,
+                "ustate": jax.tree.map(self._leaf_format, self._ustate)}
+
+    @property
     def padded_shape(self) -> Tuple[int, ...]:
         return self._padded_shape
 
     def raw(self) -> jax.Array:
-        """The live padded, sharded data array (graph-plane read)."""
+        """The live padded, sharded data array (graph-plane read), in the
+        device's default layout."""
         self._flush_host_adds()   # reads see every prior async add
+        self._lay_out(own=False)
         return self._data
 
     # ------------------------------------------------------------------ #

@@ -108,11 +108,14 @@ class MatrixTable(Table):
 
         def _update(data, ustate, ids, vals, opt):
             state = self.functional_add_rows(
-                {"data": data, "ustate": ustate}, ids, vals, opt)
+                {"data": data, "ustate": ustate}, ids, vals, opt,
+                sorted_ids=True)    # _prep_ids: np.unique, then scratch rows
             token = jnp.ravel(state["data"])[0]
             return state["data"], state["ustate"], token
 
-        fn = jax.jit(_update, donate_argnums=(0, 1))
+        fmt = self.state_format     # a row program: see program_state
+        fn = jax.jit(_update, donate_argnums=(0, 1),
+                     out_shardings=(fmt["data"], fmt["ustate"], None))
         self._jit_cache[key] = fn
         return fn
 
@@ -224,6 +227,7 @@ class MatrixTable(Table):
                 # their zero vals are ignored by the cache)
                 self._train_cache.on_push(ids, vals)
             fn = self._row_update_fn(ids.size)
+            self._lay_out(own=True)
             self._data, self._ustate, token = fn(
                 self._data, self._ustate,
                 jax.device_put(ids, self._replicated),
@@ -323,29 +327,38 @@ class MatrixTable(Table):
     # ------------------------------------------------------------------ #
     def functional_add_rows(self, state: Dict[str, Any], ids: jax.Array,
                             vals: jax.Array,
-                            opt: Optional[AddOption] = None) -> Dict[str, Any]:
+                            opt: Optional[AddOption] = None,
+                            sorted_ids: bool = False) -> Dict[str, Any]:
         """Pure row-batch add; ``ids``/``vals`` static-shaped, caller masks
-        unused slots by pointing them at scratch_row with zero vals."""
+        unused slots by pointing them at scratch_row with zero vals.
+        ``sorted_ids`` promises ids in ascending order (scratch-row slots
+        last: it is the highest row), which the compiler is told
+        (``indices_are_sorted``): without it the v5e compiler takes 12 s
+        over the write-back of a 524,288-row bucket into 1.8M rows, with
+        it 0.4 s."""
         opt = opt or AddOption()
         row_axes = jax.tree.map(self._state_row_axis, state["ustate"])
 
         def gather(leaf, axis):
-            return jnp.take(leaf, ids, axis=axis) if axis is not None else leaf
+            if axis is None:
+                return leaf
+            return jnp.take(leaf, ids, axis=axis,
+                            indices_are_sorted=sorted_ids)
 
         def scatter(leaf, new_leaf, axis):
             if axis is None:
                 return new_leaf
             idx = (slice(None),) * axis + (ids,)
-            return leaf.at[idx].set(new_leaf)
+            return leaf.at[idx].set(new_leaf, indices_are_sorted=sorted_ids)
 
         # device-trace names (metadata only): the updater's apply is
         # mv.rowapply.rule
         with jax.named_scope("mv.rowapply.gather"):
-            rows = jnp.take(state["data"], ids, axis=0)
+            rows = gather(state["data"], 0)
             gstate = jax.tree.map(gather, state["ustate"], row_axes)
         new_rows, new_gstate = self.updater.apply(rows, gstate, vals, opt)
         with jax.named_scope("mv.rowapply.scatter"):
-            data = state["data"].at[ids].set(new_rows)
+            data = scatter(state["data"], new_rows, 0)
             ustate = jax.tree.map(scatter, state["ustate"], new_gstate,
                                   row_axes)
         return {"data": data, "ustate": ustate}

@@ -1,0 +1,442 @@
+"""A table's own row programs keep it in the layout they run in
+(``table.py:row_program_layout``) and hand it back so; everyone else gets
+the device's default layout, and each change of hands is one counted
+copy (``table.relayout``).
+
+On the CPU the two are one layout, so the first tests hold that nothing
+moved: no re-layout is ever counted and results equal what the
+copy-chained path gave, bit for bit. Two layouts are then driven for
+real on the CPU with a stand-in (column-major against the default
+row-major). The rule itself, and the whole-table copies it removes, are
+checked against the v5e's compiler, which this image has without the
+chip (the ``topo`` fixture; skipped where the topology cannot be
+described).
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
+
+import multiverso_tpu as mv
+from multiverso_tpu import table as table_lib
+from multiverso_tpu.models import word2vec as w2v
+from multiverso_tpu.telemetry import trace as ttrace
+
+RELAYOUT = "table.relayout"
+
+
+def _relayouts(since: int = 0, to=None):
+    return [e for e in ttrace.events()[since:] if e["name"] == RELAYOUT
+            and to in (None, e["args"]["to"])]
+
+
+def _order(x):
+    return x.format.layout.major_to_minor
+
+
+def _we(vocab=60, tokens=6_000, **kw):
+    from multiverso_tpu.apps.word_embedding import (WEConfig, WordEmbedding,
+                                                    synthetic_corpus)
+    from multiverso_tpu.data.dictionary import Dictionary
+
+    mv.init()
+    corpus = synthetic_corpus(tokens, vocab=vocab, seed=0)
+    cfg = WEConfig(**{**dict(size=8, min_count=1, batch_size=64, negative=2,
+                             window=2, epoch=1, sample=0), **kw})
+    we = WordEmbedding(cfg, Dictionary.build(corpus, 1))
+    return we, we.prepare_ids(corpus)
+
+
+@pytest.fixture
+def column_major_rows(monkeypatch):
+    """Stand-in for a v5e on the CPU: tables of two dimensions whose row
+    programs run in another layout (column-major) than the device's
+    default (row-major), as a 300-wide table's do there the other way
+    round. The CPU backend keeps both layouts for real."""
+    monkeypatch.setattr(
+        table_lib, "row_program_layout",
+        lambda shape, dtype, sharding:
+            Layout(major_to_minor=(1, 0)) if len(shape) == 2 else None)
+
+
+# ---------------------------------------------------------------------- #
+# (a) on the CPU: one layout, no re-layout, the same bits
+# ---------------------------------------------------------------------- #
+@pytest.mark.parametrize("width", [300, 128])
+def test_cpu_table_has_one_layout_and_never_relayouts(width):
+    mv.init()
+    start = len(ttrace.events())
+    t = mv.MatrixTable(50, width, updater="adagrad", name=f"lay{width}")
+    assert t.format == Format(None, t.sharding)
+    fmt = t.state_format
+    assert fmt["data"] is t.format
+    assert jax.tree.structure(fmt) == jax.tree.structure(
+        jax.tree.map(lambda x: 0, t.state))
+    [init] = [e for e in ttrace.events()[start:] if e["name"] == "table.init"
+              and e["args"]["table"] == f"lay{width}"]
+    assert init["args"]["row_major"] == 0
+    # the logical surface is what it was
+    assert t.padded_shape == t.raw().shape == (56, width)
+    ids = jnp.asarray([3, 7, t.scratch_row], jnp.int32)
+    vals = jnp.ones((3, width), jnp.float32)
+    t.adopt(jax.jit(t.functional_add_rows)(t.state, ids, vals))
+    t.adopt(jax.jit(t.functional_add_rows)(t.program_state(), ids, vals))
+    t.add_rows([1, 3], np.ones((2, width), np.float32))
+    t.add(np.ones((50, width), np.float32))
+    assert t.get().shape == (50, width)
+    assert _relayouts(start) == []
+
+
+def test_train_fused_equals_the_copy_chained_epoch_bit_for_bit():
+    """What ``train_fused`` did until PR 26: the donated epoch chained from
+    ``jnp.copy`` of both tables, its results adopted afterwards."""
+    start = len(ttrace.events())
+    we, ids = _we()
+    ref, _ = _we()
+    for _ in range(2):
+        out = we.train_fused(ids, epochs=2)
+    cb, xb, _n = ref._device_pairs(ids)
+    cfg = ref.cfg
+    epoch = w2v.make_fused_shared_epoch(
+        w2v.W2VConfig(len(ref.dict), cfg.size, cfg.negative, cfg.window,
+                      cfg.alpha, False, False, cfg.shared_negatives),
+        ref.unigram, compute_dtype=jnp.float32)
+    lcg = jnp.asarray(w2v.init_lcg_state(cfg.shared_negatives, cfg.seed))
+    win, wout = jnp.copy(ref.table_in.raw()), jnp.copy(ref.table_out.raw())
+    for _ in range(4):
+        win, wout, loss, lcg = epoch(win, wout, cb, xb, lcg)
+    np.testing.assert_array_equal(np.asarray(we.table_in.raw()),
+                                  np.asarray(win))
+    np.testing.assert_array_equal(np.asarray(we.table_out.raw()),
+                                  np.asarray(wout))
+    assert out["loss"] == float(loss)
+    # one program serves every call: the sampler state goes in as it
+    # comes back
+    assert we._fused_epoch_fn()[0]._cache_size() == 1
+    assert _relayouts(start) == []
+
+
+def test_a_ps_block_on_the_device_plane_never_relayouts():
+    start = len(ttrace.events())
+    we, ids = _we(use_ps=1, data_block_size=1500)
+    before = we.table_in.get()
+    we.train_ps_blocks(ids[:3000], epochs=1)
+    assert not np.array_equal(before, we.table_in.get())
+    assert _relayouts(start) == []
+
+
+# ---------------------------------------------------------------------- #
+# (b) two layouts: the table's own row programs keep theirs, everyone
+#     else gets the default, and every change of hands is counted
+# ---------------------------------------------------------------------- #
+def test_own_programs_and_the_outside_each_get_their_layout(
+        column_major_rows):
+    mv.init()
+    start = len(ttrace.events())
+    t = mv.MatrixTable(50, 300, updater="adagrad", name="lay_two")
+    assert t.format == Format(Layout(major_to_minor=(1, 0)), t.sharding)
+    [init] = [e for e in ttrace.events()[start:] if e["name"] == "table.init"]
+    assert init["args"]["row_major"] == 1
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, 50, 16)
+    vals = rng.normal(size=(16, 300)).astype(np.float32)
+    opt = mv.AddOption(learning_rate=0.1, rho=0.1)
+    # built, and handed out, in the default layout: nothing to count
+    assert _order(t.raw()) == (0, 1) and _relayouts(start) == []
+    # a row program lays data and history out, once
+    t.add_rows(ids, vals, opt)
+    events = _relayouts(start)
+    assert [(e["args"]["table"], e["args"]["to"]) for e in events] == [
+        ("lay_two", "rows")] * 2
+    assert all(e["args"]["relayouts"] == 1
+               and e["args"]["bytes"] == 56 * 300 * 4 for e in events)
+    assert _order(t._data) == _order(t._ustate["g_sqr"]) == (1, 0)
+    # ... and the next finds them so, as does a host-plane row read
+    t.add_rows(ids, vals, opt)
+    rows = t.get_rows(ids)
+    state = t.program_state()
+    assert _order(state["data"]) == (1, 0) and len(_relayouts(start)) == 2
+    # the outside gets the default layout back, at a copy each
+    assert _order(t.state["ustate"]["g_sqr"]) == _order(t.raw()) == (0, 1)
+    assert len(_relayouts(start, "default")) == 2
+    assert len(_relayouts(start)) == 4
+    # state comes back as it is given; the next row program lays it out
+    t.adopt(t.state)
+    assert _order(t._data) == (0, 1) and len(_relayouts(start)) == 4
+    # same numbers as a table that never left the default layout
+    plain = mv.MatrixTable(50, 300, updater="adagrad", name="lay_one")
+    plain._format = Format(None, plain.sharding)
+    plain.add_rows(ids, vals, opt)
+    plain.add_rows(ids, vals, opt)
+    np.testing.assert_array_equal(rows, plain.get_rows(ids))
+    np.testing.assert_array_equal(t.get(), plain.get())
+
+
+def test_training_calls_change_hands_once_and_then_never(column_major_rows):
+    start = len(ttrace.events())
+    we, ids = _we(use_ps=1, data_block_size=1500)
+    assert we.table_in.format.layout.major_to_minor == (1, 0)
+    we.train_fused(ids, epochs=1)
+    assert [(e["args"]["table"], e["args"]["to"])
+            for e in _relayouts(start)] == [("embed_in", "rows"),
+                                            ("embed_out", "rows")]
+    mark = len(ttrace.events())
+    # the measured windows: call after call, block after block
+    for _ in range(3):
+        we.train_fused(ids, epochs=2)
+    we.train_ps_blocks(ids, epochs=1)
+    we.train_fused(ids, epochs=1)
+    assert _relayouts(mark) == []
+    assert _order(we.table_in._data) == _order(we.table_out._data) == (1, 0)
+    # a reader from outside costs a copy each way
+    assert np.isfinite(np.asarray(we.table_in.raw())).all()
+    we.train_fused(ids, epochs=1)
+    assert [(e["args"]["table"], e["args"]["to"])
+            for e in _relayouts(mark)] == [("embed_in", "default"),
+                                           ("embed_in", "rows")]
+
+
+def test_two_layouts_train_to_the_same_tables(column_major_rows,
+                                              monkeypatch):
+    we, ids = _we(use_ps=1, data_block_size=1500)
+    we.train_fused(ids, epochs=2)
+    we.train_ps_blocks(ids[:3000], epochs=1)
+    monkeypatch.undo()
+    ref, _ = _we(use_ps=1, data_block_size=1500)
+    assert ref.table_in.format.layout is None
+    ref.train_fused(ids, epochs=2)
+    ref.train_ps_blocks(ids[:3000], epochs=1)
+    for got, want in ((we.table_in, ref.table_in),
+                      (we.table_out, ref.table_out)):
+        np.testing.assert_allclose(got.get(), want.get(), rtol=1e-5,
+                                   atol=1e-7)
+
+
+def test_leading_axes_of_updater_state_stay_major(column_major_rows):
+    mv.init()
+    t = mv.MatrixTable(20, 300, name="lay_lead")
+    per_worker = np.zeros((3,) + t.padded_shape, np.float32)
+    fmt = t._leaf_format(per_worker)
+    assert fmt.layout.major_to_minor == (0, 2, 1)
+    assert fmt.sharding.spec == jax.sharding.PartitionSpec(
+        None, t._axis, None)
+    assert t._leaf_format(np.zeros((), np.int32)) == Format(
+        None, t._replicated)
+
+
+# ---------------------------------------------------------------------- #
+# the build's host draw, by every core, is the draw it was
+# ---------------------------------------------------------------------- #
+@pytest.mark.parametrize("shape", [(5,), (7, 3), (1001, 300), (70_001, 31)])
+def test_parallel_uniform_draw_is_the_sequential_draw(shape):
+    seed, scale = 2 ** 40 + 17, 0.5 / 300
+    want = np.random.default_rng(seed).uniform(-scale, scale, shape).astype(
+        np.float32)
+    np.testing.assert_array_equal(
+        table_lib._uniform(seed, scale, shape, np.float32), want)
+
+
+def test_seeded_table_build_draws_what_it_drew():
+    mv.init()
+    t = mv.MatrixTable(1000, 300, seed=17, init_scale=0.5 / 300)
+    want = np.random.default_rng(17).uniform(
+        -0.5 / 300, 0.5 / 300, t.padded_shape).astype(np.float32)[:1000]
+    np.testing.assert_array_equal(t.get(), want)
+    assert not np.asarray(t.raw())[1000:].any()
+
+
+# ---------------------------------------------------------------------- #
+# (c) an epoch that fails before it runs leaves the tables as they were
+# ---------------------------------------------------------------------- #
+def test_train_fused_failing_before_the_program_runs_keeps_the_tables(
+        monkeypatch):
+    we, ids = _we()
+    we.train_fused(ids, epochs=1)
+    before = (we.table_in.get(), we.table_out.get())
+    versions = (we.table_in.version, we.table_out.version)
+
+    def refuses(*_a, **_k):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of memory at dispatch")
+
+    monkeypatch.setattr(we, "_fused_epoch_fn", lambda: (refuses, True))
+    with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        we.train_fused(ids, epochs=2)
+    monkeypatch.undo()
+    np.testing.assert_array_equal(we.table_in.get(), before[0])
+    np.testing.assert_array_equal(we.table_out.get(), before[1])
+    assert (we.table_in.version, we.table_out.version) == versions
+    # and the locks were let go: the next call trains
+    assert np.isfinite(we.train_fused(ids, epochs=1)["loss"])
+    assert not np.array_equal(we.table_in.get(), before[0])
+
+
+# ---------------------------------------------------------------------- #
+# programs that return a chosen layout stay out of the compile cache
+# ---------------------------------------------------------------------- #
+def test_result_layout_programs_are_compiled_in_process(monkeypatch):
+    from jax._src import compiler
+    from multiverso_tpu.utils import platform
+
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    column = Format(Layout(major_to_minor=(1, 0)), sharding)
+    x = jax.device_put(np.arange(12, dtype=np.float32).reshape(3, 4),
+                       sharding)
+
+    def module(**kw):
+        return jax.jit(lambda a: a * 2, **kw).lower(x).compiler_ir()
+
+    assert platform._asks_for_result_layout(module(out_shardings=column))
+    assert not platform._asks_for_result_layout(module())
+    assert not platform._asks_for_result_layout(module(in_shardings=sharding,
+                                                       out_shardings=sharding))
+    through_cache = []
+    real = compiler.compile_or_get_cached
+
+    def spy(backend, computation, *rest, **kw):
+        through_cache.append(platform._asks_for_result_layout(computation))
+        return real(backend, computation, *rest, **kw)
+
+    monkeypatch.setattr(compiler, "compile_or_get_cached", spy)
+    assert platform.compile_result_layouts_in_process()
+    guarded = compiler.compile_or_get_cached
+    assert platform.compile_result_layouts_in_process()      # idempotent
+    assert compiler.compile_or_get_cached is guarded
+    y = jax.jit(lambda a: a * 3, out_shardings=column)(x)    # in process
+    assert _order(y) == (1, 0) and through_cache == []
+    z = jax.jit(lambda a: a.sum())(y)       # takes a layout, returns none
+    assert float(z) == 3 * 66 and through_cache == [False]
+
+
+# ---------------------------------------------------------------------- #
+# (d) against the v5e's compiler, without the chip
+# ---------------------------------------------------------------------- #
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2",
+            chips_per_host_bounds=(2, 2, 1), num_slices=1)
+    except Exception as e:   # no TPU compiler in this image
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+VOCAB = 240_000       # far more rows than a block of the test pulls
+ROWS = 240_008        # that table's padded rows on the 8-device CPU mesh
+
+
+def test_the_rule_on_a_v5e(one_chip):
+    """Row-major where the chip's row programs run row-major and its
+    default is not; the default wherever it serves or row-major tiles
+    would pad a narrow row many times over."""
+    f32 = jnp.dtype(jnp.float32)
+    for width in (300, 100, 64):
+        layout = table_lib.row_program_layout((ROWS, width), f32, one_chip)
+        assert layout is not None and layout.major_to_minor == (0, 1), width
+    for width in (128, 256):
+        assert table_lib.row_program_layout((ROWS, width), f32,
+                                            one_chip) is None, width
+    # the compiler weighs the padding: a million 10-wide rows stay as the
+    # device stores them (64 MB; row-major tiles would take 512 MB)
+    for width in (32, 10, 2):
+        assert table_lib.row_program_layout((1_000_001, width), f32,
+                                            one_chip) is None, width
+    assert table_lib.row_program_layout((ROWS,), f32, one_chip) is None
+
+
+def _table_copies(compiled, shape):
+    """Names of the ``copy`` ops of ``compiled`` whose result has a
+    table's shape: the whole-table layout conversions."""
+    dims = ",".join(str(d) for d in shape)
+    return re.findall(r"^\s*(\S*copy\S*) = f32\[%s\]" % re.escape(dims),
+                      compiled.as_text(), re.M)
+
+
+def _on_chip(tree, one_chip):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip), tree)
+
+
+@pytest.fixture(scope="module")
+def we300():
+    from multiverso_tpu.apps.word_embedding import WEConfig, WordEmbedding
+    from multiverso_tpu.data.dictionary import Dictionary
+
+    mv.init()
+    counts = np.maximum(1_000_000 // np.arange(1, VOCAB + 1), 5)
+    cfg = WEConfig(size=300, min_count=1, batch_size=256, negative=5,
+                   window=5, epoch=1, sample=0, shared_negatives=64,
+                   use_ps=1, data_block_size=1_000)
+    return WordEmbedding(cfg, Dictionary.from_counts(
+        [str(i) for i in range(VOCAB)], counts, 1))
+
+
+@pytest.mark.parametrize("kept", [True, False])
+def test_fused_epoch_on_a_v5e_copies_no_table(one_chip, kept):
+    shape = (ROWS, 300)
+    layout = table_lib.row_program_layout(shape, jnp.dtype(jnp.float32),
+                                          one_chip)
+    fmt = Format(layout if kept else None, one_chip)
+    cfg = w2v.W2VConfig(VOCAB, 300, 5, 5, 0.025, False, False, 64)
+    fn = w2v.make_fused_shared_epoch(
+        cfg, np.full(VOCAB, 1 / VOCAB), jnp.bfloat16,
+        table_formats=(fmt, fmt))
+    table = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=fmt)
+    batch = jax.ShapeDtypeStruct((4, 256), jnp.int32, sharding=one_chip)
+    lcg = jax.ShapeDtypeStruct((64,), jnp.uint32, sharding=one_chip)
+    compiled = fn.lower(table, table, batch, batch, lcg).compile()
+    copies = _table_copies(compiled, shape)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    one_table = ROWS * 384 * 4          # a row is three lane tiles
+    if kept:
+        assert copies == [] and temp < one_table
+        assert compiled.output_formats[0].layout.major_to_minor == (0, 1)
+    else:
+        # the defect, as the compiler shows it for a default-layout table:
+        # two copies in, two out, and both tables again as temporaries
+        assert len(copies) == 4 and temp > 2 * one_table
+
+
+def test_fused_block_program_on_a_v5e_copies_no_table(one_chip, we300,
+                                                      monkeypatch):
+    we = we300
+    layout = table_lib.row_program_layout(
+        we.table_in.padded_shape, jnp.dtype(jnp.float32), one_chip)
+    assert we.table_in.padded_shape == (ROWS, 300)
+    fmt = Format(layout, one_chip)
+    for t in (we.table_in, we.table_out):
+        monkeypatch.setattr(t, "_format", fmt)
+    we._fused_cache.pop("ps_block", None)
+    ids = (np.random.default_rng(0).zipf(1.3, 1_000) % VOCAB).astype(
+        np.int64)
+    we._host_negs(1, 1, np.random.default_rng(0))     # the sampling table
+    prep, _ = we._prepare_block_device(ids, np.random.default_rng(0), 0)
+    table = jax.ShapeDtypeStruct(we.table_in.padded_shape, jnp.float32,
+                                 sharding=fmt)
+    rest = _on_chip((prep["ids_in"], prep["ids_sec"], prep["valid"],
+                     prep["batch"], prep.get("remap"), prep.get("neg_seed"),
+                     jnp.asarray(we._neg_host)), one_chip)
+    try:
+        compiled = we._fused_block_fn().lower(
+            table, (), table, (), *rest).compile()
+    finally:
+        we._fused_cache.pop("ps_block", None)
+    assert _table_copies(compiled, we.table_in.padded_shape) == []
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < ROWS * 384 * 4)
+    assert [f.layout.major_to_minor for f in
+            (compiled.output_formats[0], compiled.output_formats[2])] == [
+                (0, 1), (0, 1)]
