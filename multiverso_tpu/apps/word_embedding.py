@@ -274,7 +274,15 @@ class WordEmbedding:
         return (centers[:n].reshape(-1, b), contexts[:n].reshape(-1, b))
 
     def _device_pairs(self, ids: np.ndarray):
-        """Batched (centers, contexts) pair arrays, resident on device.
+        """Batched (centers, contexts) pair arrays, resident on device,
+        and their pair count."""
+        return self._cached_pairs(ids)[0]
+
+    def _cached_pairs(self, ids: np.ndarray):
+        """The pair cache's entry for ``ids``: :meth:`_device_pairs`'s
+        triple, and how many of those centres and contexts each row shard
+        of the tables owns (``[shards]``, counted once here, so that a
+        call which finds its pairs cached counts nothing).
 
         Pair generation is one-time corpus preprocessing; caching the
         device-resident batches (keyed by a corpus fingerprint) keeps repeat
@@ -287,7 +295,7 @@ class WordEmbedding:
                 hit = self._pair_cache.get(key)
                 if hit is not None:
                     self._pair_cache.move_to_end(key)
-                    sp.set(pairs=hit[2])
+                    sp.set(pairs=hit[0][2])
                     return hit
             # pair gen + device put happen OFF the lock (one-time corpus
             # preprocessing — a concurrent gauge pull must not stall on
@@ -297,9 +305,10 @@ class WordEmbedding:
                 centers, contexts = _gen_pairs(ids, self.cfg.window,
                                                self.cfg.seed)
                 cb, xb = self._batches(centers, contexts)
+                by_shard = self._rows_by_shard(cb) + self._rows_by_shard(xb)
             with _trace.span("we.pairs.upload"):
-                hit = (jnp.asarray(cb), jnp.asarray(xb), cb.size)
-                jax.block_until_ready(hit[:2])
+                hit = ((jnp.asarray(cb), jnp.asarray(xb), cb.size), by_shard)
+                jax.block_until_ready(hit[0][:2])
             sp.set(cache_hit=0, pairs=cb.size,
                    h2d_bytes=cb.nbytes + xb.nbytes)
             with self._pair_cache_lock:
@@ -309,12 +318,19 @@ class WordEmbedding:
                     self._pair_cache.popitem(last=False)
             return hit
 
+    def _rows_by_shard(self, ids: np.ndarray) -> np.ndarray:
+        """How many of the row ids ``ids`` each contiguous row shard of
+        the embedding tables owns (``[shards]`` int64)."""
+        t = self.table_in
+        return np.bincount(ids.reshape(-1) // t.rows_per_shard,
+                           minlength=t.num_shards)
+
     def pair_cache_memory_stats(self) -> Dict[str, int]:
         """PR-10 ledger gauges for the pair-batch LRU (pull-only)."""
         with self._pair_cache_lock:   # vs the training thread's insert
             entries = list(self._pair_cache.values())
         dev = sum(int(getattr(a, "nbytes", 0) or 0)
-                  for cb, xb, _n in entries
+                  for (cb, xb, _n), _rows in entries
                   for a in (cb, xb))
         return {"corpora": len(entries), "device_bytes": dev}
 
@@ -346,9 +362,14 @@ class WordEmbedding:
             cd = self.fused_compute_dtype = (
                 jnp.bfloat16 if jax.devices()[0].platform == "tpu"
                 else jnp.float32)
+            # the sampler's slot table, kept on the host too: which words
+            # a call's pools held is read from it (fused_pool)
+            self._fused_slots = w2v.build_negative_table(
+                self.unigram, 1 << w2v.FUSED_TABLE_BITS)
             fn = w2v.make_fused_shared_epoch(w2v_cfg, self.unigram,
                                              compute_dtype=cd,
-                                             table_formats=formats)
+                                             table_formats=formats,
+                                             slots=self._fused_slots)
             # replicated on the mesh, as the epoch hands it back
             self._lcg = jax.device_put(
                 w2v.init_lcg_state(cfg.shared_negatives, cfg.seed),
@@ -360,6 +381,49 @@ class WordEmbedding:
             fn = make(w2v_cfg, self.unigram, table_formats=formats)
         self._fused_cache[name] = fn
         return fn, shared
+
+    def fused_pool(self, next_batches: Optional[int] = None) -> np.ndarray:
+        """Word ids of the shared negative pools of the fused epoch, read
+        from the program's own slot table and sampler state: with no
+        argument the pool ``[K']`` that the last batch of the last
+        :meth:`train_fused` call drew; with ``next_batches`` the pools
+        ``[next_batches, K']`` that the next call's batches will draw, in
+        order. A check that holds a call to a reference asks here and
+        knows nothing of the sampler."""
+        if not self._fused_epoch_fn()[1]:
+            raise ValueError("only the shared-negatives skip-gram epoch "
+                             "(shared_negatives > 0, no -cbow, no -hs) "
+                             "draws a pool")
+        state = np.asarray(self._lcg)
+        if next_batches is not None:
+            state = w2v.lcg_epoch_states(state, next_batches)
+        return self._fused_slots[w2v.lcg_slots(state)]
+
+    def _sharded_call_counts(self, pair_rows: np.ndarray, lcg_state,
+                             batches: int, epochs: int) -> Dict[str, object]:
+        """What a shared-negatives :meth:`train_fused` call does to
+        row-sharded tables, as counts for its span: the update rows each
+        shard owns (``pair_rows``: the pairs' centres and contexts, from
+        the pair cache; plus every batch's pool, drawn here on the host
+        from the sampler state the call started with, while the device
+        runs the call), and the bytes the partitioner's all-reduce
+        carries: each batch's gathered rows (B centres, B contexts, K'
+        pool rows) in the compute dtype, none on one shard."""
+        cfg, t = self.cfg, self.table_in
+        per_batch = 2 * cfg.batch_size + cfg.shared_negatives
+        if t.num_shards == 1:       # whole tables: nothing to draw again
+            return {"update_rows_by_shard": [epochs * batches * per_batch],
+                    "allreduce_bytes": 0}
+        rows, state = epochs * pair_rows, np.asarray(lcg_state)
+        for _ in range(epochs):
+            states = w2v.lcg_epoch_states(state, batches)
+            rows = rows + self._rows_by_shard(
+                self._fused_slots[w2v.lcg_slots(states)])
+            state = states[-1]
+        return {"update_rows_by_shard": [int(n) for n in rows],
+                "allreduce_bytes": int(
+                    epochs * batches * per_batch * cfg.size
+                    * jnp.dtype(self.fused_compute_dtype).itemsize)}
 
     def _device_cbow_batches(self, ids: np.ndarray):
         """Batched (windows, masks, targets) arrays on the device and
@@ -404,11 +468,14 @@ class WordEmbedding:
             if cfg.cbow:
                 batches, pairs = self._device_cbow_batches(ids)
             else:
-                cbd, xbd, pairs = self._device_pairs(ids)
+                (cbd, xbd, pairs), pair_rows = self._cached_pairs(ids)
                 batches = (cbd, xbd)
-            call.set(pairs=int(pairs), batches=int(batches[0].shape[0]))
             epoch_fn, shared = self._fused_epoch_fn()
             t_in, t_sec = self.table_in, self._sec_table()
+            n_batches = int(batches[0].shape[0])
+            call.set(pairs=int(pairs), batches=n_batches,
+                     shards=t_in.num_shards)
+            lcg_before = self._lcg if shared else None
             with _trace.span("we.fused.dispatch", programs=epochs), \
                     t_in._dispatch_lock, t_sec._dispatch_lock:
                 key = None if shared else jax.random.key(cfg.seed)
@@ -423,6 +490,9 @@ class WordEmbedding:
                             si["data"], ss["data"], *batches, sub)
                     t_in.adopt({"data": win, "ustate": si["ustate"]})
                     t_sec.adopt({"data": wsec, "ustate": ss["ustate"]})
+            if shared:
+                call.set(**self._sharded_call_counts(
+                    pair_rows, lcg_before, n_batches, epochs))
             with _trace.span("we.fused.wait"):
                 # fetch the scalar loss BEFORE stopping the clock: the
                 # readback waits for the whole epoch chain

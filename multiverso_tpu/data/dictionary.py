@@ -18,23 +18,48 @@ import numpy as np
 
 
 class Dictionary:
-    """Word <-> id with count-based pruning (ref dictionary.cpp)."""
+    """Word <-> id with count-based pruning (ref dictionary.cpp).
+
+    Training needs the counts alone (subsampling, the negative sampler,
+    the table's row count): a vocabulary may be given as counts without
+    a word list (:meth:`from_counts` with ``words=None``), and then
+    ``words`` (the ids' decimal names) and ``word2id`` are made when
+    something first asks for them, as ``word2id`` is for every
+    dictionary. A web-scale vocabulary (12M words) trains without 12M
+    strings and a dict over them on the host."""
 
     def __init__(self, min_count: int = 5):
         self.min_count = min_count
-        self.word2id: Dict[str, int] = {}
-        self.words: List[str] = []
         self.counts: np.ndarray = np.zeros(0, dtype=np.int64)
+        self._words: Optional[List[str]] = []
+        self._word2id: Optional[Dict[str, int]] = None
+
+    @property
+    def words(self) -> List[str]:
+        if self._words is None:
+            self._words = [str(i) for i in range(self.counts.size)]
+        return self._words
+
+    @words.setter
+    def words(self, words: List[str]) -> None:
+        self._words, self._word2id = words, None
+
+    @property
+    def word2id(self) -> Dict[str, int]:
+        if self._word2id is None:
+            self._word2id = {w: i for i, w in enumerate(self.words)}
+        return self._word2id
 
     @classmethod
-    def from_counts(cls, words: List[str], counts: np.ndarray,
+    def from_counts(cls, words: Optional[List[str]], counts: np.ndarray,
                     min_count: int = 5) -> "Dictionary":
         """Adopt a pre-counted vocabulary (e.g. from the native corpus
-        loader), which is already pruned and count-desc sorted."""
+        loader), which is already pruned and count-desc sorted. ``words``
+        may be ``None``: ids are then all there is, until a word is
+        asked for."""
         d = cls(min_count)
-        d.words = list(words)
-        d.word2id = {w: i for i, w in enumerate(d.words)}
         d.counts = np.asarray(counts, dtype=np.int64)
+        d._words = None if words is None else list(words)
         return d
 
     @classmethod
@@ -47,12 +72,12 @@ class Dictionary:
         if max_vocab is not None:
             items = items[:max_vocab]
         d.words = [w for w, _ in items]
-        d.word2id = {w: i for i, w in enumerate(d.words)}
         d.counts = np.array([c for _, c in items], dtype=np.int64)
         return d
 
     def __len__(self) -> int:
-        return len(self.words)
+        return (int(self.counts.size) if self._words is None
+                else len(self._words))
 
     def encode(self, tokens: Iterable[str]) -> np.ndarray:
         """Token stream -> id stream, dropping OOV (ref reader behavior)."""

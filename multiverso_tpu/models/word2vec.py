@@ -359,9 +359,32 @@ def shared_neg_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
     return win, wout, loss
 
 
+FUSED_TABLE_BITS = 20      # the fused sampler's slot table: 2^20 slots
+
+
+def lcg_epoch_states(lcg_state: np.ndarray, batches: int) -> np.ndarray:
+    """On the host, the sampler states ``[batches, K']`` of the fused
+    epoch that starts from ``lcg_state``: batch t draws with row t, and
+    the last row is the state the epoch hands back. The epoch's own
+    arithmetic (:func:`_lcg_jump_consts`), bit for bit."""
+    at, ct = _lcg_jump_consts(batches)
+    s = np.asarray(lcg_state, np.uint32)
+    return s[None, :] * at[:, None] + ct[:, None]
+
+
+def lcg_slots(lcg_states: np.ndarray,
+              table_bits: int = FUSED_TABLE_BITS) -> np.ndarray:
+    """The negative table's slots that the fused sampler reads with
+    ``lcg_states`` (any shape): the states' top bits, as in the epoch."""
+    return (np.asarray(lcg_states, np.uint32)
+            >> np.uint32(32 - table_bits)).astype(np.int64)
+
+
 def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
-                            compute_dtype=jnp.bfloat16, table_bits: int = 20,
-                            table_formats=None):
+                            compute_dtype=jnp.bfloat16,
+                            table_bits: int = FUSED_TABLE_BITS,
+                            table_formats=None,
+                            slots: Optional[np.ndarray] = None):
     """Fused epoch with batch-shared negatives and an in-graph LCG sampler.
 
     The negative draw uses the reference's own RNG design — word2vec.c's
@@ -372,12 +395,17 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
     replacing both a threefry invocation (profiled at ~55% of the epoch)
     and the earlier per-batch in-scan LCG step (~17%).
     Returns ``epoch_fn(win, wout, centers, contexts, lcg_state) ->
-    (win, wout, mean_loss, lcg_state)``.
+    (win, wout, mean_loss, lcg_state)``. ``slots`` is the negative table
+    (word ids, ``2^table_bits`` of them) where the caller has built it
+    already (:func:`build_negative_table`; at 12M words a fifth of a
+    second and 4 MB that need not be made twice).
     """
     k_shared = cfg.shared_negatives
     if k_shared <= 0:
         raise ValueError("cfg.shared_negatives must be > 0")
-    neg_table = jnp.asarray(build_negative_table(unigram, 1 << table_bits))
+    if slots is None:
+        slots = build_negative_table(unigram, 1 << table_bits)
+    neg_table = jnp.asarray(slots)
     neg_weight = cfg.negatives / k_shared
     shift = jnp.uint32(32 - table_bits)  # top bits: LCG low bits are weak
 

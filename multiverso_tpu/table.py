@@ -116,15 +116,18 @@ def _ceil_to(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
-def _uniform(seed: int, scale: float, shape: Tuple[int, ...],
-             dtype) -> np.ndarray:
-    """``default_rng(seed).uniform(-scale, scale, shape).astype(dtype)``,
-    value for value, drawn by every core at once and a megabyte-sized
-    piece at a time: a uniform double takes one step of the PCG64 stream,
-    so a generator advanced by a piece's offset draws that piece, and
-    NumPy fills without the interpreter lock. (Drawn in one piece, a 1.8M
-    x 300 table took 9 s, most of it the page faults of a 4.3 GB
-    ``float64`` temporary: most of a word2vec build.)"""
+def _uniform(seed: int, scale: float, shape: Tuple[int, ...], dtype,
+             offset: int = 0) -> np.ndarray:
+    """``default_rng(seed).uniform(-scale, scale, n).astype(dtype)``'s
+    elements ``offset`` to ``offset + prod(shape)``, value for value, as
+    an array of ``shape``; drawn by every core at once and a
+    megabyte-sized piece at a time: a uniform double takes one step of
+    the PCG64 stream, so a generator advanced by a piece's offset draws
+    that piece (any row range of a table is addressable so, which is how
+    a sharded table is drawn a shard at a time), and NumPy fills without
+    the interpreter lock. (Drawn in one piece, a 1.8M x 300 table took
+    9 s, most of it the page faults of a 4.3 GB ``float64`` temporary:
+    most of a word2vec build.)"""
     out = np.empty(shape, dtype)
     flat = out.reshape(-1)
     workers = min(32, os.cpu_count() or 1)
@@ -132,7 +135,7 @@ def _uniform(seed: int, scale: float, shape: Tuple[int, ...],
 
     def draw(lo: int) -> None:
         bits = np.random.PCG64(seed)
-        bits.advance(lo)
+        bits.advance(offset + lo)
         rng = np.random.Generator(bits)
         hi = min(lo + share, flat.size)
         for a in range(lo, hi, piece):
@@ -158,6 +161,13 @@ def _default_order(shape: Tuple[int, ...], dtype,
         if str(e).startswith("UNIMPLEMENTED"):
             return None
         raise
+
+
+@functools.lru_cache(maxsize=64)
+def _zeros_program(shape: Tuple[int, ...], dtype, sharding):
+    """A program that fills an array of ``shape`` with zeros on
+    ``sharding``'s devices: one per table shape, not one per table."""
+    return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sharding)
 
 
 def _row_probe(data, ids):
@@ -259,12 +269,9 @@ class Table:
                 "table.init", table=name, rows=self._padded_rows,
                 width=int(np.prod(self.shape[1:])),
                 bytes=int(np.prod(self._padded_shape)) * self.dtype.itemsize,
-                row_major=int(self._format.layout is not None)):
-            with _trace.span("table.init.host"):
-                host_init = self._build_init(init, seed, init_scale)
-            with _trace.span("table.init.put"):
-                self._data = jax.block_until_ready(
-                    jax.device_put(host_init, self._sharding))
+                row_major=int(self._format.layout is not None)) as built:
+            self._data, host_bytes = self._build_data(init, seed, init_scale)
+            built.set(shards=self._num_shards, host_bytes=host_bytes)
             self._ustate = jax.tree.map(
                 self._place_state,
                 updater.init_state(self._padded_shape, self.dtype))
@@ -369,23 +376,70 @@ class Table:
     # ------------------------------------------------------------------ #
     # construction helpers
     # ------------------------------------------------------------------ #
-    def _build_init(self, init, seed, init_scale) -> np.ndarray:
+    def _build_data(self, init, seed, init_scale) -> Tuple[jax.Array, int]:
+        """The table's first data on its devices, in the default layout,
+        and the most host memory the build held for it at once.
+
+        One path for one chip and for many: a table of zeros is filled
+        on the devices and costs the host nothing; one with values (an
+        ``init`` array, or the seeded draw) is made a shard at a time,
+        the rows of one shard on the host, copied to the device(s) that
+        hold them, and dropped before the next shard's are made, so the
+        host holds one shard and never the table (a 12M x 300 table is
+        14.4 GB, its four shards 3.6 GB each). One copy is in flight at
+        a time: two at once take seven times what they take in turn
+        (PERF.md, PR 25)."""
         if init is not None:
-            arr = np.asarray(init, dtype=self.dtype)
-            if arr.shape != self.shape:
+            init = np.asarray(init, dtype=self.dtype)
+            if init.shape != self.shape:
                 raise ValueError(
-                    f"init shape {arr.shape} != table shape {self.shape}")
-            out = np.zeros(self._padded_shape, dtype=self.dtype)
-            out[: self.shape[0]] = arr
+                    f"init shape {init.shape} != table shape {self.shape}")
+        elif seed is None or init_scale == 0.0:
+            with _trace.span("table.init.zeros"):
+                return jax.block_until_ready(_zeros_program(
+                    self._padded_shape, self.dtype, self._sharding)()), 0
+        # devices by the rows they hold: replicas (a mesh with more axes
+        # than the table's) share one draw
+        holders: Dict[Tuple[int, int], list] = {}
+        for device, index in self._sharding.addressable_devices_indices_map(
+                self._padded_shape).items():
+            lo, hi, _ = index[0].indices(self._padded_rows)
+            holders.setdefault((lo, hi), []).append(device)
+        placed, host_bytes = {}, 0
+        for k, ((lo, hi), devices) in enumerate(sorted(holders.items())):
+            with _trace.span("table.init.shard", shard=k, rows=hi - lo) as sp:
+                with _trace.span("table.init.host"):
+                    rows = self._shard_rows(init, seed, init_scale, lo, hi)
+                with _trace.span("table.init.put"):
+                    for device in devices:
+                        placed[device] = jax.block_until_ready(
+                            jax.device_put(rows, device))
+                sp.set(host_bytes=int(rows.nbytes))
+                host_bytes = max(host_bytes, int(rows.nbytes))
+                del rows
+        return jax.make_array_from_single_device_arrays(
+            self._padded_shape, self._sharding,
+            [placed[d] for d in sorted(placed, key=lambda d: d.id)]
+        ), host_bytes
+
+    def _shard_rows(self, init: Optional[np.ndarray], seed, init_scale,
+                    lo: int, hi: int) -> np.ndarray:
+        """Rows ``lo`` to ``hi`` of the padded table on the host: the
+        caller's ``init`` or, with none, Uniform(-scale, scale) from
+        ``seed`` (the reference's word2vec input-embedding server init,
+        ref src/table/matrix_table.cpp:372-384 and
+        Applications/WordEmbedding/src/communicator.cpp:20), value for
+        value the rows the whole table's draw gives; padding rows zero."""
+        live = max(min(hi, self.shape[0]) - lo, 0)
+        shape = (hi - lo,) + self.shape[1:]
+        if init is not None:
+            out = np.zeros(shape, self.dtype)
+            out[:live] = init[lo:lo + live]
             return out
-        if seed is not None and init_scale != 0.0:
-            # Uniform(-scale, scale) random init — the reference's word2vec
-            # input-embedding server init (ref src/table/matrix_table.cpp:372-384
-            # and Applications/WordEmbedding/src/communicator.cpp:20).
-            out = _uniform(seed, init_scale, self._padded_shape, self.dtype)
-            out[self.shape[0]:] = 0
-            return out
-        return np.zeros(self._padded_shape, dtype=self.dtype)
+        out = _uniform(seed, init_scale, shape, self.dtype,
+                       lo * int(np.prod(self.shape[1:])))
+        out[live:] = 0
+        return out
 
     def _leaf_format(self, x) -> Format:
         """How the table's own row programs hold the data, or a leaf of
@@ -686,6 +740,18 @@ class Table:
     @property
     def padded_shape(self) -> Tuple[int, ...]:
         return self._padded_shape
+
+    @property
+    def num_shards(self) -> int:
+        """How many contiguous row shards the table is split into (the
+        size of the mesh's table axis; the reference's server count)."""
+        return self._num_shards
+
+    @property
+    def rows_per_shard(self) -> int:
+        """Padded rows a shard holds: row ``r`` lives in shard
+        ``r // rows_per_shard`` (ref matrix_table.cpp:266-313)."""
+        return self._padded_rows // self._num_shards
 
     def raw(self) -> jax.Array:
         """The live padded, sharded data array (graph-plane read), in the

@@ -1,0 +1,216 @@
+"""The sharded table path (ISSUE 27): tables row-sharded over a mesh are
+built a shard at a time, a vocabulary of counts alone trains, and
+``train_fused`` on row-sharded tables does what the plain reference
+does, says which shard owns its update rows, and touches no other row.
+
+Meshes of 1, 2, 4 and 8 of the CPU's eight virtual devices stand in for
+one chip and for a four-chip host; weights are seeded."""
+
+import os
+import sys
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import multiverso_tpu as mv
+from benchmark.reference import w2v_sgns
+from benchmark.reference.sharding import owner_rows
+from multiverso_tpu.apps.word_embedding import WEConfig, WordEmbedding
+from multiverso_tpu.data.dictionary import Dictionary
+from multiverso_tpu.telemetry import trace as ttrace
+
+VOCAB, WIDTH = 203, 8          # 203: no multiple of any shard count
+
+
+def _init(shards: int) -> None:
+    mv.init(mesh=Mesh(np.asarray(jax.devices()[:shards]), ("mv",)))
+
+
+def _counts(vocab: int = VOCAB) -> np.ndarray:
+    return np.maximum((1e6 / np.arange(1, vocab + 1) ** 1.1).astype(np.int64),
+                      5)
+
+
+def _stream(n: int, seed: int = 0, vocab: int = VOCAB) -> np.ndarray:
+    p = _counts(vocab) / _counts(vocab).sum()
+    return np.random.default_rng(seed).choice(vocab, n, p=p).astype(np.int64)
+
+
+def _we(words=None, **kw) -> WordEmbedding:
+    cfg = WEConfig(**{**dict(size=WIDTH, min_count=5, batch_size=64,
+                             negative=5, shared_negatives=16, window=2,
+                             epoch=1, sample=0, seed=3), **kw})
+    return WordEmbedding(cfg, Dictionary.from_counts(words, _counts(), 5))
+
+
+def _spans(name: str, since: int = 0):
+    return [e for e in ttrace.events()[since:] if e["name"] == name]
+
+
+# ---------------------------------------------------------------------- #
+# a table built by shards is the table drawn whole
+# ---------------------------------------------------------------------- #
+@pytest.mark.parametrize("shards", [1, 4, 8])
+def test_table_built_by_shards_equals_the_whole_draw(shards):
+    _init(shards)
+    start = len(ttrace.events())
+    seed, scale, rows, width = 2 ** 40 + 17, 0.5 / 300, 1001, 300
+    t = mv.MatrixTable(rows, width, seed=seed, init_scale=scale,
+                       name="by_shards")
+    want = np.random.default_rng(seed).uniform(
+        -scale, scale, t.padded_shape).astype(np.float32)
+    want[rows:] = 0
+    np.testing.assert_array_equal(np.asarray(t.raw()), want)
+    [init] = _spans("table.init", start)
+    a = init["args"]
+    assert (t.num_shards, a["shards"]) == (shards, shards)
+    assert t.rows_per_shard * shards == t.padded_shape[0]
+    # the host held one shard at a time, never the table
+    assert a["host_bytes"] == t.rows_per_shard * width * 4
+    assert a["host_bytes"] * shards == a["bytes"]
+    kids = [e for e in _spans("table.init.shard", start)
+            if e["parent"] == init["id"]]
+    assert [k["args"]["shard"] for k in kids] == list(range(shards))
+    assert all(k["args"]["rows"] == t.rows_per_shard
+               and k["args"]["host_bytes"] == a["host_bytes"] for k in kids)
+    inside = {e["name"] for e in ttrace.events()[start:]
+              if e["parent"] in {k["id"] for k in kids}}
+    assert inside == {"table.init.host", "table.init.put"}
+
+
+def test_given_values_and_zeros_are_built_by_shards_too():
+    _init(4)
+    start = len(ttrace.events())
+    init = np.arange(37 * 3, dtype=np.float32).reshape(37, 3)
+    t = mv.MatrixTable(37, 3, init=init, name="given")
+    np.testing.assert_array_equal(t.get(), init)
+    assert not np.asarray(t.raw())[37:].any()
+    z = mv.ArrayTable(50, name="zeros")
+    assert not np.asarray(z.raw()).any()
+    given, zeros = _spans("table.init", start)
+    assert given["args"]["host_bytes"] == t.rows_per_shard * 3 * 4
+    assert (zeros["args"]["host_bytes"], zeros["args"]["shards"]) == (0, 4)
+    with pytest.raises(ValueError, match="init shape"):
+        mv.MatrixTable(5, 3, init=np.zeros((4, 3), np.float32))
+
+
+# ---------------------------------------------------------------------- #
+# a vocabulary of counts alone
+# ---------------------------------------------------------------------- #
+def test_counts_only_vocabulary_trains_to_the_same_tables():
+    mv.init()
+    ids = _stream(4_000)
+    named = _we(words=[f"w{i}" for i in range(VOCAB)])
+    bare = _we(words=None)
+    assert len(bare.dict) == len(named.dict) == VOCAB
+    for we in (named, bare):
+        for _ in range(2):
+            out = we.train_fused(ids, epochs=2)
+        assert np.isfinite(out["loss"])
+    for a, b in ((named.table_in, bare.table_in),
+                 (named.table_out, bare.table_out)):
+        np.testing.assert_array_equal(a.get(), b.get())
+    assert named.table_out.get().any()
+    # nothing asked for a word, so none was made
+    assert bare.dict._words is None and bare.dict._word2id is None
+
+
+def test_words_are_made_when_asked_for():
+    d = Dictionary.from_counts(None, _counts(12), 5)
+    assert len(d) == 12 and d._words is None
+    assert d.words[7] == "7" and d.word2id["11"] == 11
+    np.testing.assert_array_equal(d.encode(["3", "x", "0"]), [3, 0])
+    named = Dictionary.from_counts(["a", "b"], [9, 7], 5)
+    assert named._word2id is None           # the map waits for a lookup
+    assert named.word2id == {"a": 0, "b": 1} and len(named) == 2
+    named.words = ["c", "d"]
+    assert named.word2id == {"c": 0, "d": 1}
+
+
+# ---------------------------------------------------------------------- #
+# train_fused on row-sharded tables against the plain reference
+# ---------------------------------------------------------------------- #
+def _seed_out(we: WordEmbedding, seed: int = 11) -> None:
+    """embed_out starts as zeros; give it seeded values so that every
+    gradient of the batch is alive."""
+    t = we.table_out
+    vals = np.zeros(t.padded_shape, np.float32)
+    vals[:VOCAB] = np.random.default_rng(seed).uniform(
+        -0.5, 0.5, (VOCAB, WIDTH))
+    t.adopt({"data": jax.device_put(vals, t.sharding),
+             "ustate": t.state["ustate"]})
+
+
+@pytest.mark.parametrize("shards", [2, 8])
+def test_one_batch_on_row_sharded_tables_matches_the_reference(shards):
+    _init(shards)
+    we = _we()
+    _seed_out(we)
+    ids = _stream(40, seed=5)       # 64 to 127 pairs: one batch of 64
+    cb, xb, pairs = we._device_pairs(ids)
+    assert cb.shape == (1, 64)
+    centers, contexts = np.asarray(cb[0]), np.asarray(xb[0])
+    pool = we.fused_pool(next_batches=1)[0]
+    old = we.table_in.get(), we.table_out.get()
+    out = we.train_fused(ids, epochs=1)
+    new = we.table_in.get(), we.table_out.get()
+    np.testing.assert_array_equal(we.fused_pool(), pool)
+    loss, ref = w2v_sgns.step(old[0], old[1], centers, contexts, pool,
+                              we.cfg.alpha, we.cfg.negative / pool.size)
+    assert out["loss"] == pytest.approx(loss, rel=1e-4)
+    for k, side in ((0, "in"), (1, "out")):
+        touched, want = ref[side + "_ids"], ref[side + "_delta"]
+        got = new[k][touched] - old[k][touched]
+        assert np.abs(got - want).max() <= 1e-4 * np.abs(want).max()
+        assert np.abs(want).max() > 0
+        others = np.setdiff1d(np.arange(VOCAB), touched)
+        assert others.size
+        np.testing.assert_array_equal(new[k][others], old[k][others])
+    # the rows the batch touched lie in more than one shard
+    assert np.unique(owner_rows(ref["in_ids"], VOCAB, shards)).size > 1
+
+
+# ---------------------------------------------------------------------- #
+# the counts on the we.fused span
+# ---------------------------------------------------------------------- #
+@pytest.mark.parametrize("shards,epochs", [(4, 1), (4, 2), (1, 1)])
+def test_fused_span_counts_update_rows_by_shard(shards, epochs):
+    _init(shards)
+    we = _we()
+    ids = _stream(3_000, seed=9)
+    cb, xb, pairs = we._device_pairs(ids)
+    batches = int(cb.shape[0])
+    assert batches > 3
+    we.train_fused(ids, epochs=1)               # pairs cached from here on
+    pools = we.fused_pool(next_batches=epochs * batches)
+    start = len(ttrace.events())
+    we.train_fused(ids, epochs=epochs)
+    [call] = _spans("we.fused", start)
+    a = call["args"]
+    rows = np.concatenate([np.tile(np.asarray(cb).ravel(), epochs),
+                           np.tile(np.asarray(xb).ravel(), epochs),
+                           pools.ravel()])
+    want = np.bincount(owner_rows(rows, VOCAB, shards), minlength=shards)
+    assert a["shards"] == shards
+    assert a["update_rows_by_shard"] == want.tolist()
+    assert sum(a["update_rows_by_shard"]) == epochs * batches * (2 * 64 + 16)
+    per_batch = (2 * 64 + 16) * WIDTH * 4       # float32 on the CPU
+    assert a["allreduce_bytes"] == (shards > 1) * epochs * batches * per_batch
+    # the pairs' share was counted when the pairs were generated
+    [pairs_span] = [e for e in _spans("we.fused.pairs", start)]
+    assert pairs_span["args"]["cache_hit"] == 1
+    # the pool of the call's last batch is what the sampler hands back
+    np.testing.assert_array_equal(we.fused_pool(), pools[-1])
+
+
+def test_fused_pool_is_only_for_the_shared_negatives_epoch():
+    mv.init()
+    we = _we(shared_negatives=0)
+    with pytest.raises(ValueError, match="shared-negatives"):
+        we.fused_pool()

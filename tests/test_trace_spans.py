@@ -265,8 +265,10 @@ def test_table_init_carries_rows_width_bytes(shape, dtype):
     assert (a["table"], a["rows"], a["width"]) == ("spanned", rows, width)
     assert a["bytes"] == rows * width * np.dtype(dtype).itemsize
     assert a["bytes"] == t.raw().nbytes
+    # a table of zeros is filled on the devices: no host work, no shard
     kids = {e["name"] for e in events if e["parent"] == init["id"]}
-    assert kids == {"table.init.host", "table.init.put"}
+    assert kids == {"table.init.zeros"}
+    assert (a["shards"], a["host_bytes"]) == (t._num_shards, 0)
     assert init["parent"] is None and init["prof"] is False
 
 
