@@ -50,6 +50,7 @@ from multiverso_tpu.data.dictionary import Dictionary, build_huffman
 from multiverso_tpu.io.sample_reader import BlockPrepareQueue
 from multiverso_tpu.models import word2vec as w2v
 from multiverso_tpu.ops import row_assemble as _rowasm
+from multiverso_tpu.ops import row_combine
 from multiverso_tpu.telemetry import devstats as _devstats
 from multiverso_tpu.telemetry import memstats as _memstats
 from multiverso_tpu.telemetry import profiler as _prof
@@ -245,6 +246,7 @@ class WordEmbedding:
         # alternating epochs no longer thrash it every epoch, and its
         # device bytes ride the PR-10 ledger
         self._pair_cache: "OrderedDict[object, object]" = OrderedDict()
+        self._plan_fn = None    # the pair cache's plan program, on first use
         # guards the LRU against the memstats sampler thread's gauge
         # pull (mutation is per corpus-epoch — the lock is never hot)
         self._pair_cache_lock = threading.Lock()
@@ -280,9 +282,12 @@ class WordEmbedding:
 
     def _cached_pairs(self, ids: np.ndarray):
         """The pair cache's entry for ``ids``: :meth:`_device_pairs`'s
-        triple, and how many of those centres and contexts each row shard
+        triple; how many of those centres and contexts each row shard
         of the tables owns (``[shards]``, counted once here, so that a
-        call which finds its pairs cached counts nothing).
+        call which finds its pairs cached counts nothing); and, for the
+        shared-negatives epoch, how every minibatch's update rows combine
+        (:meth:`_pair_plans`, made once here for the same reason), else
+        ``None``.
 
         Pair generation is one-time corpus preprocessing; caching the
         device-resident batches (keyed by a corpus fingerprint) keeps repeat
@@ -307,8 +312,10 @@ class WordEmbedding:
                 cb, xb = self._batches(centers, contexts)
                 by_shard = self._rows_by_shard(cb) + self._rows_by_shard(xb)
             with _trace.span("we.pairs.upload"):
-                hit = ((jnp.asarray(cb), jnp.asarray(xb), cb.size), by_shard)
-                jax.block_until_ready(hit[0][:2])
+                cbd, xbd = jnp.asarray(cb), jnp.asarray(xb)
+                hit = ((cbd, xbd, cb.size), by_shard,
+                       self._pair_plans(cbd, xbd))
+                jax.block_until_ready(hit)
             sp.set(cache_hit=0, pairs=cb.size,
                    h2d_bytes=cb.nbytes + xb.nbytes)
             with self._pair_cache_lock:
@@ -317,6 +324,24 @@ class WordEmbedding:
                 while len(self._pair_cache) > cap:   # bounded LRU
                     self._pair_cache.popitem(last=False)
             return hit
+
+    def _pair_plans(self, centers: jax.Array, contexts: jax.Array):
+        """``ops/row_combine.plan_rows`` of every minibatch of the pair
+        batches ``[batches, B]``, for the input and the output table: one
+        jitted program, run once a corpus and table (three sorts a
+        minibatch, 14 ms of a v5e at 439 x 8,192: two of them would be
+        4% of every call), replicated on the tables' mesh, where the
+        epoch program reads them. ``None`` unless the epoch is the
+        shared-negatives one: only it combines its update rows."""
+        if not self._shared_epoch:
+            return None
+        if self._plan_fn is None:
+            self._plan_fn = jax.jit(
+                row_combine.plan_rows, static_argnums=1,
+                out_shardings=jax.sharding.NamedSharding(
+                    mv.mesh(), jax.sharding.PartitionSpec()))
+        return (self._plan_fn(centers, self.table_in.padded_shape[0]),
+                self._plan_fn(contexts, self.table_out.padded_shape[0]))
 
     def _rows_by_shard(self, ids: np.ndarray) -> np.ndarray:
         """How many of the row ids ``ids`` each contiguous row shard of
@@ -330,20 +355,25 @@ class WordEmbedding:
         with self._pair_cache_lock:   # vs the training thread's insert
             entries = list(self._pair_cache.values())
         dev = sum(int(getattr(a, "nbytes", 0) or 0)
-                  for (cb, xb, _n), _rows in entries
-                  for a in (cb, xb))
+                  for (cb, xb, _n), _rows, plans in entries
+                  for a in (cb, xb, *jax.tree.leaves(plans)))
         return {"corpora": len(entries), "device_bytes": dev}
 
     # ------------------------------------------------------------------ #
     # fused path (device-resident training)
     # ------------------------------------------------------------------ #
+    @property
+    def _shared_epoch(self) -> bool:
+        """Whether the fused epoch is the shared-negatives skip-gram one."""
+        cfg = self.cfg
+        return not (cfg.cbow or cfg.hs) and cfg.shared_negatives > 0
+
     def _fused_epoch_fn(self):
         """The jitted epoch program of the active (cbow, hs,
         shared-negatives) mode, built once; it hands both tables back in
         their own formats. ``shared`` programs donate the tables and
         thread the LCG sampler state through instead of a PRNG key."""
-        cfg = self.cfg
-        shared = not (cfg.cbow or cfg.hs) and cfg.shared_negatives > 0
+        cfg, shared = self.cfg, self._shared_epoch
         name = (("cbow_hs" if cfg.hs else "cbow") if cfg.cbow else
                 "hs" if cfg.hs else "sg_shared" if shared else "sg")
         fn = self._fused_cache.get(name)
@@ -468,7 +498,7 @@ class WordEmbedding:
             if cfg.cbow:
                 batches, pairs = self._device_cbow_batches(ids)
             else:
-                (cbd, xbd, pairs), pair_rows = self._cached_pairs(ids)
+                (cbd, xbd, pairs), pair_rows, plans = self._cached_pairs(ids)
                 batches = (cbd, xbd)
             epoch_fn, shared = self._fused_epoch_fn()
             t_in, t_sec = self.table_in, self._sec_table()
@@ -476,14 +506,17 @@ class WordEmbedding:
             call.set(pairs=int(pairs), batches=n_batches,
                      shards=t_in.num_shards)
             lcg_before = self._lcg if shared else None
+            unique = []     # a pass's distinct update rows, on the device
             with _trace.span("we.fused.dispatch", programs=epochs), \
                     t_in._dispatch_lock, t_sec._dispatch_lock:
                 key = None if shared else jax.random.key(cfg.seed)
                 for _ in range(epochs):
                     si, ss = t_in.program_state(), t_sec.program_state()
                     if shared:
-                        win, wsec, loss, self._lcg = epoch_fn(
-                            si["data"], ss["data"], *batches, self._lcg)
+                        win, wsec, loss, self._lcg, u = epoch_fn(
+                            si["data"], ss["data"], *batches, self._lcg,
+                            plans)
+                        unique.append(u)
                     else:
                         key, sub = jax.random.split(key)
                         win, wsec, loss = epoch_fn(
@@ -497,6 +530,11 @@ class WordEmbedding:
                 # fetch the scalar loss BEFORE stopping the clock: the
                 # readback waits for the whole epoch chain
                 loss_f = float(loss)
+            if shared:
+                # the pairs' update rows as the table scatters were handed
+                # them: before combining, and the distinct ones after
+                call.set(update_rows=2 * epochs * int(pairs),
+                         unique_rows=sum(int(u) for u in unique))
             with _trace.span("we.fused.count"):
                 dt = time.perf_counter() - t0
                 # words/sec follows the word2vec convention: corpus

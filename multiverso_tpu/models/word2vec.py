@@ -31,6 +31,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from multiverso_tpu.ops import row_combine
+
 
 class W2VConfig(NamedTuple):
     vocab_size: int
@@ -243,21 +245,22 @@ def cbow_hs_step(win: jax.Array, hs_out: jax.Array, windows: jax.Array,
     return win, hs_out, loss
 
 
-def _epoch_jit(table_formats, carried: int = 0, **jit_kw):
+def _epoch_jit(table_formats, carried: int = 0, counts: int = 0, **jit_kw):
     """``jax.jit`` for an epoch program ``(win, wsec, ...) -> (win, wsec,
-    loss, *carried)``. ``table_formats`` is the two tables'
+    loss, *carried, *counts)``. ``table_formats`` is the two tables'
     ``Table.format``: the tables come back laid out as they are given
     (``Table.program_state``), so a chain of calls copies no table. State
     ``carried`` from call to call comes back replicated on the tables'
     mesh, where :func:`init_lcg_state`'s caller puts it, so the second
-    call finds the first call's program. ``None`` leaves every result to
-    the compiler."""
+    call finds the first call's program; ``counts`` are scalars for the
+    call's span, left to the compiler like the loss. ``None`` leaves
+    every result to the compiler."""
     if table_formats is not None:
         whole = table_formats[0].sharding
         if isinstance(whole, NamedSharding):
             whole = NamedSharding(whole.mesh, PartitionSpec())
         jit_kw["out_shardings"] = (tuple(table_formats) + (None,)
-                                   + (whole,) * carried)
+                                   + (whole,) * carried + (None,) * counts)
     return functools.partial(jax.jit, **jit_kw)
 
 
@@ -316,7 +319,9 @@ def _lcg_jump_consts(n: int) -> Tuple[np.ndarray, np.ndarray]:
 def shared_neg_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
                     contexts: jax.Array, neg_ids: jax.Array, lr: float,
                     neg_weight: float = 1.0,
-                    compute_dtype=jnp.bfloat16
+                    compute_dtype=jnp.bfloat16,
+                    plans: Tuple[Optional[row_combine.RowPlan],
+                                 Optional[row_combine.RowPlan]] = (None, None)
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Skipgram-NS minibatch with a batch-SHARED negative pool.
 
@@ -331,7 +336,10 @@ def shared_neg_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
 
     centers/contexts: (B,) int32; neg_ids: (K',) int32.
     Tables stay in their storage dtype (f32); compute runs in
-    ``compute_dtype`` (bf16 on the MXU).
+    ``compute_dtype`` (bf16 on the MXU). The pairs' update rows reach the
+    tables with their duplicates combined (``ops/row_combine``); ``plans``
+    is the (centers, contexts) pair of :func:`row_combine.plan_rows` where
+    the caller made them ahead of the step; a ``None`` is made in it.
     """
     cd = compute_dtype
     with jax.named_scope("mv.fused.gather"):
@@ -350,11 +358,11 @@ def shared_neg_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
                 - neg_weight * jnp.mean(
                     jnp.sum(jax.nn.log_sigmoid(-negs), axis=-1)))
     with jax.named_scope("mv.fused.scatter"):
-        win = win.at[centers].add(dv.astype(win.dtype))
+        win = row_combine.add_rows(win, centers, dv, plans[0])
         # two scatters, NOT one concat'd scatter: the K'-row pool scatter
         # is nearly free while concatenation forces an extra [B+K', D]
         # materialization (measured ~30% slower per batch on-chip)
-        wout = wout.at[contexts].add(dup.astype(wout.dtype))
+        wout = row_combine.add_rows(wout, contexts, dup, plans[1])
         wout = wout.at[neg_ids].add(dun.astype(wout.dtype))
     return win, wout, loss
 
@@ -394,8 +402,16 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
     (:func:`_lcg_jump_consts`) + one batched table gather before the scan,
     replacing both a threefry invocation (profiled at ~55% of the epoch)
     and the earlier per-batch in-scan LCG step (~17%).
-    Returns ``epoch_fn(win, wout, centers, contexts, lcg_state) ->
-    (win, wout, mean_loss, lcg_state)``. ``slots`` is the negative table
+    Returns ``epoch_fn(win, wout, centers, contexts, lcg_state,
+    plans=None) -> (win, wout, mean_loss, lcg_state, unique_rows)``.
+    ``plans`` is ``(plan_rows(centers, rows), plan_rows(contexts, rows))``
+    (``ops/row_combine``) where the caller keeps them with the pairs: the
+    sorts behind them are 4% of an epoch of 439 x 8,192 on a v5e, so a
+    caller that runs the same pairs again makes them once; without them
+    the epoch makes its own. ``unique_rows`` is how many distinct centre
+    and context rows those plans name, of ``2 * centers.size`` update
+    rows: the table scatters' work after and before combining.
+    ``slots`` is the negative table
     (word ids, ``2^table_bits`` of them) where the caller has built it
     already (:func:`build_negative_table`; at 12M words a fifth of a
     second and 4 MB that need not be made twice).
@@ -411,8 +427,8 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
 
     # donate the tables: epochs chain win/wout through, and without donation
     # every call pays a full-table copy before the first scatter
-    @_epoch_jit(table_formats, 1, donate_argnums=(0, 1))
-    def epoch_fn(win, wout, centers, contexts, lcg_state):
+    @_epoch_jit(table_formats, 1, 1, donate_argnums=(0, 1))
+    def epoch_fn(win, wout, centers, contexts, lcg_state, plans=None):
         # the whole epoch's sampler states in one closed-form jump + ONE
         # batched table gather (bit-identical to stepping the LCG per
         # batch, which serialized ~17% of the epoch on small VPU ops)
@@ -422,19 +438,26 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
                      + jnp.asarray(Ct)[:, None])
             nids = jnp.take(neg_table, (s_all >> shift).astype(jnp.int32),
                             axis=0)
+        # and how every minibatch's update rows combine, for the whole
+        # epoch before the scan, off the minibatch's path
+        if plans is None:
+            with jax.named_scope("mv.fused.plan"):
+                plans = (row_combine.plan_rows(centers, win.shape[0]),
+                         row_combine.plan_rows(contexts, wout.shape[0]))
 
         def body(carry, batch):
             win, wout, = carry
-            c, x, nid = batch
+            c, x, nid, plan = batch
             win, wout, loss = shared_neg_step(
                 win, wout, c, x, nid, cfg.learning_rate, neg_weight,
-                compute_dtype)
+                compute_dtype, plan)
             return (win, wout), loss
 
         with jax.named_scope("mv.fused"):   # device-trace name
             (win, wout), losses = jax.lax.scan(
-                body, (win, wout), (centers, contexts, nids))
-        return win, wout, jnp.mean(losses), s_all[-1]
+                body, (win, wout), (centers, contexts, nids, plans))
+        unique = jnp.sum(plans[0].count) + jnp.sum(plans[1].count)
+        return win, wout, jnp.mean(losses), s_all[-1], unique
 
     return epoch_fn
 

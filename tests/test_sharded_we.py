@@ -176,6 +176,41 @@ def test_one_batch_on_row_sharded_tables_matches_the_reference(shards):
     assert np.unique(owner_rows(ref["in_ids"], VOCAB, shards)).size > 1
 
 
+def test_combined_scatters_on_row_sharded_tables_equal_one_device(
+        monkeypatch):
+    """ISSUE 28: the epoch combines a minibatch's duplicate update rows
+    and walks the distinct ones a chunk at a time; partitioned over four
+    row shards it leaves the tables one device leaves, and no other row
+    moves. Chunks of 64 slots, so a batch of 192 walks up to three."""
+    from multiverso_tpu.ops import row_combine
+    monkeypatch.setattr(row_combine, "CHUNK", 64)
+    ids = _stream(900, seed=11)
+    got = {}
+    for shards in (1, 4):
+        _init(shards)
+        we = _we(batch_size=192)
+        _seed_out(we)
+        cb, xb, _ = we._device_pairs(ids)
+        assert cb.shape[0] >= 3 and cb.shape[1] == 192
+        old = we.table_in.get(), we.table_out.get()
+        pools = we.fused_pool(next_batches=int(cb.shape[0]))
+        out = we.train_fused(ids, epochs=1)
+        got[shards] = (we.table_in.get(), we.table_out.get(), out["loss"])
+        touched = (np.unique(np.asarray(cb)),
+                   np.unique(np.concatenate([np.asarray(xb).ravel(),
+                                             pools.ravel()])))
+        for k in (0, 1):
+            others = np.setdiff1d(np.arange(VOCAB), touched[k])
+            assert others.size
+            np.testing.assert_array_equal(got[shards][k][others],
+                                          old[k][others])
+            assert (got[shards][k][touched[k]] != old[k][touched[k]]).any()
+    assert np.unique(owner_rows(touched[0], VOCAB, 4)).size == 4
+    for k in (0, 1):
+        np.testing.assert_array_equal(got[4][k], got[1][k])
+    assert got[4][2] == got[1][2]
+
+
 # ---------------------------------------------------------------------- #
 # the counts on the we.fused span
 # ---------------------------------------------------------------------- #
@@ -200,6 +235,12 @@ def test_fused_span_counts_update_rows_by_shard(shards, epochs):
     assert a["shards"] == shards
     assert a["update_rows_by_shard"] == want.tolist()
     assert sum(a["update_rows_by_shard"]) == epochs * batches * (2 * 64 + 16)
+    # ISSUE 28: the pairs' update rows before combining, and the distinct
+    # rows the table scatters were handed after (centres plus contexts)
+    assert a["update_rows"] == epochs * batches * 2 * 64
+    assert a["unique_rows"] == epochs * sum(
+        np.unique(r).size for r in list(np.asarray(cb)) + list(np.asarray(xb)))
+    assert a["unique_rows"] < a["update_rows"]
     per_batch = (2 * 64 + 16) * WIDTH * 4       # float32 on the CPU
     assert a["allreduce_bytes"] == (shards > 1) * epochs * batches * per_batch
     # the pairs' share was counted when the pairs were generated

@@ -110,7 +110,7 @@ def test_train_fused_equals_the_copy_chained_epoch_bit_for_bit():
     lcg = jnp.asarray(w2v.init_lcg_state(cfg.shared_negatives, cfg.seed))
     win, wout = jnp.copy(ref.table_in.raw()), jnp.copy(ref.table_out.raw())
     for _ in range(4):
-        win, wout, loss, lcg = epoch(win, wout, cb, xb, lcg)
+        win, wout, loss, lcg, _ = epoch(win, wout, cb, xb, lcg)
     np.testing.assert_array_equal(np.asarray(we.table_in.raw()),
                                   np.asarray(win))
     np.testing.assert_array_equal(np.asarray(we.table_out.raw()),
@@ -408,6 +408,38 @@ def test_fused_epoch_on_a_v5e_copies_no_table(one_chip, kept):
         # the defect, as the compiler shows it for a default-layout table:
         # two copies in, two out, and both tables again as temporaries
         assert len(copies) == 4 and temp > 2 * one_table
+
+
+@pytest.mark.parametrize("batch", [256, 1024])
+def test_fused_epoch_on_a_v5e_scatters_distinct_rows_in_place(one_chip,
+                                                              batch):
+    """ISSUE 28: the epoch combines a minibatch's duplicate update rows.
+    A batch of 256 is one table scatter a table, 1,024 the walk over
+    chunks of ``row_combine.CHUNK`` slots: either way no table is copied,
+    the plans and the combined rows are small beside a table, and the two
+    table scatters of the pairs promise distinct rows (the pool's, whose
+    rows may repeat, does not). None is told its ids are sorted: the v5e
+    then streams the whole table (PERF.md, PR 28)."""
+    shape = (ROWS, 300)
+    fmt = Format(table_lib.row_program_layout(
+        shape, jnp.dtype(jnp.float32), one_chip), one_chip)
+    cfg = w2v.W2VConfig(VOCAB, 300, 5, 5, 0.025, False, False, 64)
+    fn = w2v.make_fused_shared_epoch(
+        cfg, np.full(VOCAB, 1 / VOCAB), jnp.bfloat16,
+        table_formats=(fmt, fmt))
+    table = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=fmt)
+    pairs = jax.ShapeDtypeStruct((4, batch), jnp.int32, sharding=one_chip)
+    lcg = jax.ShapeDtypeStruct((64,), jnp.uint32, sharding=one_chip)
+    compiled = fn.lower(table, table, pairs, pairs, lcg).compile()
+    assert _table_copies(compiled, shape) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < ROWS * 384 * 4 / 50
+    assert compiled.output_formats[0].layout.major_to_minor == (0, 1)
+    scatters = re.findall(
+        r"= f32\[%d,300\]\S* scatter\(.*" % ROWS, compiled.as_text())
+    assert len(scatters) == 3
+    assert sum("unique_indices=true" in s for s in scatters) == 2
+    assert not any("indices_are_sorted=true" in s for s in scatters)
+    assert "tpu_custom_call" not in compiled.as_text()
 
 
 def test_fused_block_program_on_a_v5e_copies_no_table(one_chip, we300,

@@ -326,6 +326,16 @@ def test_train_fused_leaves_its_spans_and_counts(mode):
         assert a["words"] == 2 * ids.size and a["epochs"] == 2
         assert a["pairs"] == out1["pairs"] > 0
         assert a["batches"] == a["pairs"] // we.cfg.batch_size
+        # only the shared-negatives epoch combines its update rows
+        # (ISSUE 28), and only it says how far
+        assert ("unique_rows" in a) == ("update_rows" in a) == (
+            mode == "sg_shared")
+        if mode == "sg_shared":
+            assert a["update_rows"] == 2 * 2 * a["pairs"]
+            assert 0 < a["unique_rows"] < a["update_rows"]
+            # the count before combining is what it was: pool rows too
+            assert sum(a["update_rows_by_shard"]) == (
+                a["update_rows"] + 2 * a["batches"] * we.cfg.shared_negatives)
         assert call["parent"] is None and call["request"] > 0
         [pairs] = [e for e in events if e["name"] == "we.fused.pairs"]
         assert pairs["args"]["cache_hit"] == hit

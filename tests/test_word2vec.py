@@ -177,8 +177,8 @@ class TestSteps:
         lcg = jnp.asarray(w2v.init_lcg_state(8, 0))
         losses = []
         for _ in range(6):
-            win, wout, loss, lcg = epoch_fn(win, wout, jnp.asarray(cs),
-                                            jnp.asarray(cs), lcg)
+            win, wout, loss, lcg, _ = epoch_fn(win, wout, jnp.asarray(cs),
+                                               jnp.asarray(cs), lcg)
             losses.append(float(loss))
         assert losses[-1] < losses[0]
 
