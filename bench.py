@@ -696,11 +696,10 @@ def bench_array_table(size: int = 1_000_000, iters: int = 10):
         pipe.append((time.perf_counter() - t0) / 8)
 
     # wire-compressed plane (ref quantization_util.h filters on the MPI
-    # wire; here the host<->device link): bf16 halves the payload, 1bit
-    # sends sign bits + block scales with error feedback. Measured
+    # wire; here the host<->device link): bf16 halves the payload. Measured
     # INTERLEAVED with a plain table so drift between runs cannot
     # masquerade as a filter effect — compare the *_vs_plain ratios.
-    wire_modes = ("bf16", "1bit", "topk")
+    wire_modes = ("bf16",)
     tables = {"plain": t}
     for mode in wire_modes:
         tables[mode] = mv.ArrayTable(size, updater="sgd",
@@ -721,11 +720,7 @@ def bench_array_table(size: int = 1_000_000, iters: int = 10):
     plain_get = _percentile_ms(samples["plain"]["get"])
     wf = {"plain_interleaved": {"add_p50_ms": plain_add,
                                 "get_p50_ms": plain_get}}
-    from multiverso_tpu.ops import wire_codec
-    add_wire_bytes = {"bf16": 2 * size,
-                      "1bit": wire_codec.onebit_compressed_nbytes(size),
-                      "topk": wire_codec.topk_compressed_nbytes(
-                          wire_codec.default_topk(size))}
+    add_wire_bytes = {"bf16": 2 * size}
     for mode in wire_modes:
         am = _percentile_ms(samples[mode]["add"])
         gm = _percentile_ms(samples[mode]["get"])
@@ -751,18 +746,15 @@ def bench_array_table(size: int = 1_000_000, iters: int = 10):
     get_cached_ms = _percentile_ms(rep)
     get_cache_hits = cache_mon.count - hits_before
     # in-run bit-parity of the read path (ISSUE 5 acceptance): whatever
-    # served the gets above — blocking transfer, version cache, or the
-    # write-triggered snapshot prefetch — the returned bytes must equal
-    # the live table's exactly. A latency number without this is
-    # meaningless, so parity failure FAILS the bench.
+    # served the gets above — blocking transfer or version cache — the
+    # returned bytes must equal the live table's exactly. A latency number
+    # without this is meaningless, so parity failure FAILS the bench.
     host_now = t.get()
     raw_now = np.asarray(t.raw())[: size].reshape(host_now.shape)
     if not np.array_equal(host_now, raw_now):
         raise AssertionError(
             "bench_array get parity broke: the read path returned "
             "different bytes than the live device table")
-    get_prefetch_hits = Dashboard.get(
-        "table[bench_array].get.prefetched").count
     # device plane: delta already resident (the real TPU deployment shape —
     # grads are produced on device; host numbers above are link-bound)
     import jax
@@ -808,7 +800,6 @@ def bench_array_table(size: int = 1_000_000, iters: int = 10):
         "wire_filtered": wf,
         "get_repeat_cached_ms": get_cached_ms,
         "get_cache_hits": int(get_cache_hits),
-        "get_prefetch_hits": int(get_prefetch_hits),
         "get_parity_bit_for_bit": True,   # asserted above, else raise
         "device_add_ms": dev_add_s * 1e3,
         "device_add_gbps": nbytes / dev_add_s / 1e9,

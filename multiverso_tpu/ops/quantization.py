@@ -1,7 +1,7 @@
 """Weight-only int8 quantization for inference.
 
-Complements the wire-compression filters (utils/filters.py — the
-reference's SparseFilter/OneBitsFilter surface, ref
+Complements the wire-compression filter (utils/filters.py — the
+reference's SparseFilter, ref
 include/multiverso/util/quantization_util.h) with *storage* quantization:
 params are held as int8 + per-channel f32 scales — 4x smaller in HBM, the
 win for HBM-bandwidth-bound decoding — and dequantized on use (the

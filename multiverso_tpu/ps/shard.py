@@ -1014,21 +1014,16 @@ class RowShard:
     def _prep_add(self, meta: Dict, arrays: Sequence[np.ndarray]
                   ) -> Tuple[np.ndarray, np.ndarray, AddOption]:
         """Validate an ADD_ROWS request into (local ids, vals, opt). The
-        value payload decodes ONCE here, straight from the frame blobs
-        into the apply (wire.decode_payload) — there is no intermediate
-        re-encode hop for compressed wires."""
+        value payload decodes ONCE here, straight from the frame blob
+        into the apply (a bf16 blob casts back to the table's dtype)."""
         opt = AddOption(**meta.get("opt", {}))
         local = self._localize_raw(arrays[0])
         self._note_rows(local)   # one sketch record per add (plain+batch)
-        wirem = meta.get("wire", "none")
-        if wirem in ("none", "bf16"):   # single blob decodes implicitly
-            vals = np.asarray(arrays[1], self.dtype)[: local.size]
-        else:
-            vals = wire.decode_payload(arrays[1:], wirem,
-                                       (local.size, self.num_col),
-                                       self.dtype)
+        if meta.get("wire", "none") not in wire.WIRE_MODES:
+            raise ValueError(f"unknown wire {meta['wire']!r}")
+        vals = np.asarray(arrays[1], self.dtype)[: local.size]
         # ENCODED payload bytes (the blobs as they crossed the wire —
-        # a 1bit add must not count as 4 bytes/element), per REQUEST
+        # a bf16 add must not count as 4 bytes/element), per REQUEST
         # (like _stat_adds counts requests): the coalescing queue
         # merges K overlapping adds into one deduped apply, and
         # counting at apply time would underreport by up to Kx
@@ -1319,9 +1314,9 @@ class RowShard:
                                        meta.get(wire.TENANT_META_KEY))
         t0 = time.time() if tr is not None else 0.0
         payload = wire.encode_payload(rows, w)
-        # ENCODED reply bytes (what actually crosses the wire — a topk/
-        # 1bit reply is ~16-29x smaller than the gathered f32 rows);
-        # feeds the aggregator's wire-bytes/s honestly
+        # ENCODED reply bytes (what actually crosses the wire — a bf16
+        # reply is half the gathered f32 rows); feeds the aggregator's
+        # wire-bytes/s honestly
         nbytes = sum(int(a.nbytes) for a in payload)
         self._stat_get_bytes += nbytes
         # every reply-encoded read (get, full-get, snapshot pull) is one

@@ -844,14 +844,12 @@ class _SendWindow:
                                   for e in entries),
                        note=f"ops={len(entries)}")
         w = t._wire_for(owner)
-        # merging conditions, ALL required for bit-transparency: an
-        # elementwise wire ("none"/"bf16" — 1bit/topk mix values across
-        # block/top-k structure, so each op keeps its own codec payload),
+        # merging conditions, ALL required for bit-transparency:
         # disjoint row sets, a row-local-state updater (adam's global
         # step counter advances once per APPLY — a merge would miscount),
-        # and matching AddOptions (unless the updater never reads them)
-        exact = (w in ("none", "bf16")
-                 and type(t.updater) in updaters_lib.ROW_LOCAL_STATE)
+        # and matching AddOptions (unless the updater never reads them).
+        # Both wires are elementwise, so neither stands in the way
+        exact = type(t.updater) in updaters_lib.ROW_LOCAL_STATE
         merge_all = type(t.updater) in updaters_lib.OPT_INSENSITIVE
         groups: List[List] = []   # [ids[], vals[], opt, futs[], idset,
         merged_rows = 0           #  traces[], tenant]
@@ -1713,17 +1711,8 @@ class AsyncMatrixTable(_AsyncBase):
         as bfloat16 — half the bytes on the DCN-analogue wire, the role
         the reference's SparseFilter played on its MPI wire
         (quantization_util.h); values are cast back at the endpoint.
-        ``wire="1bit"`` sends whole-table add deltas as sign bits +
-        per-block scales (~29x fewer bytes; 1-bit SGD) with per-owner
-        error feedback, row-batch adds as stateless 1-bit payloads (row
-        sets change between batches, so a positional residual has no
-        stable meaning there), and get replies as bf16 (parameter VALUES
-        are not deltas; sign-quantizing them would be destructive —
-        same rule as the sync table's 1bit mode). ``wire="topk"`` is the
-        same shape with the ~3% largest-|x| entries exact (QSGD-style)
-        instead of sign bits. All encodes go through
-        ``ps/wire.encode_payload``: the frame blobs ARE the codec
-        output, decoded exactly once at the receiving shard.
+        Encodes go through ``ps/wire.encode_payload``, decoded exactly
+        once at the receiving shard.
 
         ``send_window_ms`` overrides the ``batch_window_ms`` flag for
         this table: > 0 buffers ``add_rows_async`` client-side and ships
@@ -1737,18 +1726,9 @@ class AsyncMatrixTable(_AsyncBase):
         see _GetWindow). Values are unchanged; only how many frames a
         burst of concurrent gets costs."""
         super().__init__(ctx, name)
-        if wire not in ("none", "bf16", "1bit", "topk"):
+        if wire not in wire_mod.WIRE_MODES:
             raise ValueError(f"unknown wire {wire!r}")
         self._wire = wire
-        # per-owner error-feedback residuals for 1bit whole-table adds
-        # (each rank's delta slice has a fixed shape, so the residual's
-        # positions are stable across payloads). The lock serializes the
-        # encode: filter_in reads AND writes the residual, so two
-        # threaded add()s racing it would compensate the same error
-        # twice and bias the stream (the sync Table guards its residual
-        # with the dispatch lock for the same reason)
-        self._add_filters: Dict[int, Any] = {}
-        self._add_filter_lock = threading.Lock()
         self.num_row, self.num_col = int(num_row), int(num_col)
         self.shape = (self.num_row, self.num_col)
         self.dtype = np.dtype(dtype)
@@ -1959,18 +1939,11 @@ class AsyncMatrixTable(_AsyncBase):
         return ("none" if rank == self.ctx.rank
                 or rank in self._routed_set else self._wire)
 
-    def _reply_wire(self) -> str:
-        """Reply wire for gets, rank-independent: 1bit/topk apply to
-        DELTAS (add traffic); parameter values ride bf16 instead —
-        sparsifying a pulled VALUE block would zero ~97% of the weights
-        (sync-table rule). THE one place that rule lives."""
-        return "bf16" if self._wire in ("1bit", "topk") else self._wire
-
     def _get_wire_for(self, rank: int) -> str:
         """Reply wire per source rank (local short-circuit and routed
         in-process ranks stay raw)."""
         return ("none" if rank == self.ctx.rank
-                or rank in self._routed_set else self._reply_wire())
+                or rank in self._routed_set else self._wire)
 
     def _owner_conns(self, uids: np.ndarray):
         """Native conns for the C-side fanout, indexed by rank. ONLY the
@@ -2307,7 +2280,7 @@ class AsyncMatrixTable(_AsyncBase):
                 return futs, _assemble_win
             # remote peers share one packed meta (with the table's reply
             # wire); the local short-circuit keeps its uncompressed dict
-            gw = self._reply_wire()
+            gw = self._wire
             chunk = int(config.get_flag("get_chunk_rows"))
             tid = ttrace.new_id() if ttrace.enabled() else None
             t_send0 = time.time() if tid is not None else 0.0
@@ -2520,42 +2493,7 @@ class AsyncMatrixTable(_AsyncBase):
             futs = []
             for r, a, b in self._ranges:
                 w = self._wire_for(r)
-                if w == "1bit":
-                    # per-owner error feedback: this rank's slice shape is
-                    # fixed, so the residual's positions are stable — the
-                    # quantization error of each payload rides the next
-                    # one (1-bit SGD), and the filter's (bits, scales)
-                    # blobs ARE the frame payload. Encode under the
-                    # filter lock: filter_in reads and writes the
-                    # residual, and threaded adds must not double-apply
-                    # the same compensation
-                    from multiverso_tpu.utils.filters import OneBitsFilter
-                    with self._add_filter_lock:
-                        filt = self._add_filters.get(r)
-                        if filt is None:
-                            filt = self._add_filters[r] = OneBitsFilter(
-                                block=wire_mod.ONEBIT_BLOCK)
-                        _, bits, scales = filt.filter_in(delta[a:b])
-                    arrays = [bits, scales]
-                elif w == "topk":
-                    # same per-owner error-feedback rule as 1bit: the
-                    # slice shape is fixed, so residual positions are
-                    # stable — without the filter the ~97% of gradient
-                    # mass off the top-k support would be PERMANENTLY
-                    # dropped every call (unbounded systematic bias); the
-                    # stateless encode is only for row batches, whose row
-                    # sets change between calls
-                    from multiverso_tpu.utils.filters import (TopKFilter,
-                                                              default_topk)
-                    with self._add_filter_lock:
-                        filt = self._add_filters.get(r)
-                        if filt is None:
-                            filt = self._add_filters[r] = TopKFilter(
-                                default_topk((b - a) * self.num_col))
-                        _, idx, topv = filt.filter_in(delta[a:b])
-                    arrays = [idx, topv]
-                else:
-                    arrays = wire_mod.encode_payload(delta[a:b], w)
+                arrays = wire_mod.encode_payload(delta[a:b], w)
                 meta = {"table": self.name, "opt": opt._asdict()}
                 if w != "none":
                     meta["wire"] = w

@@ -125,9 +125,6 @@ def with_tenant(meta: Dict, tenant) -> Dict:
     return meta
 
 
-ONEBIT_BLOCK = 1024   # per-block scale granularity of the "1bit" wire
-
-
 class ChunkedReply:
     """A streamed get reply: ``meta`` is the FINAL frame's meta (carries
     ``chunks``/``rows`` so the client knows the stream's shape) and
@@ -147,55 +144,29 @@ class ChunkedReply:
         self.meta, self.chunks = meta, chunks
 
 
-def to_wire(arr: np.ndarray, wire: str) -> np.ndarray:
-    """Single-blob codec for a wire mode ("none" | "bf16"): shared by
-    client sends and shard replies. The receiving side decodes implicitly
-    — ``np.asarray(x, table_dtype)`` casts back. Multi-blob modes
-    ("1bit") go through :func:`encode_payload`."""
-    if wire == "bf16":
-        import ml_dtypes
-        return np.asarray(arr).astype(ml_dtypes.bfloat16)
-    return arr
+WIRE_MODES = ("none", "bf16")
 
 
 def encode_payload(arr: np.ndarray, wire: str) -> List[np.ndarray]:
     """The ONE place PS payloads are wire-encoded: an array -> the blob
-    list that travels in the frame. "none" -> [arr]; "bf16" -> [bf16];
-    "1bit" -> [sign bits, per-block scales] (~29x fewer bytes; matches
-    the device codec in ops/wire_codec bit-for-bit, so an encoded frame
-    decodes identically at either endpoint — no decode/re-encode hop);
-    "topk" -> [i32 idx, f32 vals] of the ~3% largest-|x| entries
-    (~16x fewer bytes). 1bit/topk are stateless at THIS layer: error
-    feedback (residuals) belongs to the endpoint that owns the stream
-    (ps/tables.py for adds)."""
-    if wire == "1bit":
-        from multiverso_tpu.utils import filters
-        bits, scales = filters.onebit_encode_np(
-            np.asarray(arr, np.float32).reshape(-1), ONEBIT_BLOCK)
-        return [bits, scales]
-    if wire == "topk":
-        from multiverso_tpu.utils import filters
-        idx, vals = filters.topk_encode_np(
-            np.asarray(arr, np.float32).reshape(-1))
-        return [idx, vals]
-    return [to_wire(arr, wire)]
+    list that travels in the frame, shared by client sends and shard
+    replies. "none" -> [arr]; "bf16" -> [arr as bfloat16], half the
+    bytes, a cast with no state."""
+    if wire not in WIRE_MODES:
+        raise ValueError(f"unknown wire {wire!r}")
+    if wire == "bf16":
+        import ml_dtypes
+        return [np.asarray(arr).astype(ml_dtypes.bfloat16)]
+    return [arr]
 
 
 def decode_payload(arrays: Sequence[np.ndarray], wire: str,
                    shape: Tuple[int, ...], dtype) -> np.ndarray:
-    """Inverse of :func:`encode_payload` (the other endpoint)."""
-    if wire == "1bit":
-        from multiverso_tpu.utils import filters
-        n = int(np.prod(shape, dtype=np.int64))
-        flat = filters.onebit_decode_np(np.asarray(arrays[0]),
-                                        np.asarray(arrays[1]), n,
-                                        ONEBIT_BLOCK)
-        return flat.reshape(shape).astype(dtype, copy=False)
-    if wire == "topk":
-        from multiverso_tpu.utils import filters
-        n = int(np.prod(shape, dtype=np.int64))
-        flat = filters.topk_decode_np(arrays[0], arrays[1], n)
-        return flat.reshape(shape).astype(dtype, copy=False)
+    """Inverse of :func:`encode_payload` (the other endpoint): the cast
+    back to the table's dtype. A mode this build does not speak (a
+    peer's frame meta is outside input) is refused, not guessed at."""
+    if wire not in WIRE_MODES:
+        raise ValueError(f"unknown wire {wire!r}")
     return np.asarray(arrays[0], dtype).reshape(shape)
 
 

@@ -48,7 +48,6 @@ from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from multiverso_tpu import updaters as updaters_lib
-from multiverso_tpu.ops import wire_codec
 from multiverso_tpu.telemetry import memstats as _memstats
 from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.updaters import AddOption
@@ -66,24 +65,6 @@ config.define_bool(
     "costs one memcpy, not one wire round-trip). Safe multi-controller: "
     "host-plane ops are collective and identical on every process, so "
     "versions advance in lockstep and all ranks hit or miss together")
-
-config.define_bool(
-    "table_get_prefetch", True,
-    "write-triggered snapshot prefetch for whole-table Get across a "
-    "slow host<->device link: once a Get-after-Add pattern is observed, "
-    "each whole-table Add also dispatches a non-donating snapshot of "
-    "the post-update data and starts its device->host copy "
-    "IMMEDIATELY, so the transfer streams while the caller is still "
-    "waiting out the Add's own round-trip — the next Get at that "
-    "version waits only the residual instead of paying the full "
-    "dispatch round-trip + transfer. Bit-exact: the snapshot is the "
-    "same bytes a "
-    "blocking Get would pull at that version; a version mismatch "
-    "(another mutation landed first) discards it. Costs one extra "
-    "table-sized device buffer + one background transfer per "
-    "prefetching Add, so it self-disarms when two Adds pass with no "
-    "Get consuming the snapshot. Single-controller only (multi-host "
-    "pulls stay collective)")
 
 
 class _HostAdd:
@@ -170,6 +151,14 @@ def _zeros_program(shape: Tuple[int, ...], dtype, sharding):
     return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sharding)
 
 
+@jax.jit
+def _bf16_cast(x: jax.Array) -> jax.Array:
+    """bfloat16 down-cast of a Get snapshot (``wire_filter="bf16"``: half
+    the download bytes). Non-donating: the live table data must survive
+    the cast."""
+    return x.astype(jnp.bfloat16)
+
+
 def _row_probe(data, ids):
     """What every row program does to a table: gather rows, scatter-add
     them back. Compiled, never run (:func:`row_program_layout`)."""
@@ -221,18 +210,10 @@ class Table:
                  seed: Optional[int] = None,
                  init_scale: float = 0.0,
                  wire_filter: str = "none"):
-        """``wire_filter`` compresses the host<->device wire of whole-table
-        Add/Get (the reference compressed its MPI wire the same way,
-        quantization_util.h SparseFilter; OneBitsFilter was declared there
-        and implemented here): "bf16" halves both directions (near-lossless
-        for SGD traffic); "1bit" sends sign bits + per-block scales with
-        error feedback (1-bit SGD) on Add and bf16 on Get; "topk" sends
-        the ~3% largest-|x| delta entries exactly (QSGD-style
-        sparsification) with error feedback on Add and bf16 on Get.
-        Encoding runs through the jitted ops/wire_codec kernels (on the
-        host-side CPU backend, so the f32 payload never crosses the
-        accelerator wire just to be compressed); decode runs in-graph,
-        fused into the updater apply. Row ops are unaffected (their
+        """``wire_filter="bf16"`` sends whole-table Add deltas and Get
+        snapshots across the host<->device wire as bfloat16: half the
+        bytes both ways, a cast with no state (the reference compressed
+        its MPI wire, quantization_util.h). Row ops are unaffected (their
         payloads are already small)."""
         zoo = Zoo.get()
         self._zoo = zoo
@@ -277,37 +258,9 @@ class Table:
                 updater.init_state(self._padded_shape, self.dtype))
         self.table_id = zoo.register_table(self)
 
-        if wire_filter not in ("none", "bf16", "1bit", "topk"):
+        if wire_filter not in ("none", "bf16"):
             raise ValueError(f"unknown wire_filter {wire_filter!r}")
         self._wire = wire_filter
-        if wire_filter == "1bit":
-            from multiverso_tpu.utils.filters import OneBitsFilter
-            self._one_bit = OneBitsFilter(block=1024)
-        elif wire_filter == "topk":
-            from multiverso_tpu.utils.filters import TopKFilter
-            self._topk_k = wire_codec.default_topk(int(np.prod(self.shape)))
-            self._topk = TopKFilter(self._topk_k)
-        if wire_filter in ("1bit", "topk"):
-            # jitted encode runs on the host-side CPU backend (numpy
-            # reference filter when unavailable); the error-feedback
-            # residual stays resident there as table state — it never
-            # round-trips through a host pull
-            self._codec_dev = wire_codec.host_codec_device()
-            self._wire_residual: Optional[jax.Array] = None
-        if wire_filter != "none":
-            # filters trade encode CPU for wire bytes; on a FAST link that
-            # trade loses (1bit measured ~10x slower than plain with no
-            # link at all, on the CPU backend) — warn at creation, when
-            # the user can still change the flag
-            from multiverso_tpu.utils import linkprobe
-            ms = linkprobe.device_link_ms()
-            if ms < linkprobe.FAST_LINK_MS:
-                log.error(
-                    "table[%s]: wire_filter=%r but the host<->device link "
-                    "is fast (1 MB upload ~%.1f ms): the filter's encode "
-                    "cost will likely exceed its wire savings — use "
-                    "wire_filter='none' unless this process feeds a device "
-                    "across a slow link", name, wire_filter, ms)
 
         self._pending: Dict[int, Any] = {}
         self._next_msg_id = 0
@@ -318,22 +271,6 @@ class Table:
         # dispatch + device->host transfer entirely (flag table_get_cache)
         self._version = 0
         self._get_cache: Optional[Tuple[int, np.ndarray]] = None
-        # write-triggered snapshot prefetch (flag table_get_prefetch):
-        # (version, in-flight device snapshot) dispatched by the LAST
-        # whole-table add, consumed by the next Get at that version.
-        # _prefetch_armed latches on the first Get and drops when a
-        # prefetch goes unconsumed (two adds, no get), so add-only
-        # workloads never pay the extra snapshot. All under the
-        # dispatch lock.
-        self._get_prefetch: Optional[Tuple[int, jax.Array]] = None
-        self._prefetch_armed = False
-        # unconsumed-prefetch backoff: each wasted snapshot doubles how
-        # many arming opportunities are skipped (capped), and one
-        # CONSUMED prefetch resets it — a mixed add,add,get cadence
-        # decays to ~no wasted transfers instead of burning one
-        # table-sized device->host copy per cycle
-        self._prefetch_backoff = 0
-        self._prefetch_skip = 0
         # Serializes op *dispatch* (not device execution): a donating add on
         # one thread must not delete the data buffer while another thread
         # (e.g. an AsyncBuffer prefetch pull) is snapshotting it.
@@ -354,24 +291,17 @@ class Table:
         # create it behind the train_cache_rows flag — base ops only need
         # to INVALIDATE on coarse mutations)
         self._train_cache = None
-        # memory ledger (telemetry/memstats.py): the PR-1 get cache and
-        # the write-triggered prefetch staging buffer are the sync
-        # plane's two table-sized hoards; gauges are pull-only
+        # memory ledger (telemetry/memstats.py): the get cache is the
+        # sync plane's table-sized hoard; gauges are pull-only
         _memstats.register(f"table[{name}]", self)
 
     def memory_stats(self) -> Dict[str, Any]:
-        """Byte-ledger gauges: cached whole-table Get host copy +
-        in-flight prefetch snapshot (device) bytes. Lock-free reads of
-        the two tuple refs — benign vs the dispatch lock, and the
-        ledger tolerates a one-sample-stale figure."""
+        """Byte-ledger gauge: the cached whole-table Get host copy. A
+        lock-free read of the tuple ref — benign vs the dispatch lock,
+        and the ledger tolerates a one-sample-stale figure."""
         cache = self._get_cache
-        pf = self._get_prefetch
-        return {
-            "cache_bytes": (int(cache[1].nbytes)
-                            if cache is not None else 0),
-            "prefetch_bytes": (int(getattr(pf[1], "nbytes", 0))
-                               if pf is not None else 0),
-        }
+        return {"cache_bytes": (int(cache[1].nbytes)
+                                if cache is not None else 0)}
 
     # ------------------------------------------------------------------ #
     # construction helpers
@@ -514,58 +444,6 @@ class Table:
             np.copyto(into.reshape(self.shape), cache[1])
             return into
         return cache[1].copy()
-
-    def _maybe_prefetch(self) -> None:
-        """Write-triggered snapshot prefetch (caller holds the dispatch
-        lock, right after a whole-table update dispatched): snapshot the
-        post-update data (non-donating) and start its device->host copy
-        NOW, so the bytes stream back concurrently with the caller's own
-        wait on the add — the read path's half of the off-lock snapshot
-        theme, applied to the host<->device seam. Armed only while a
-        Get-after-Add pattern holds: an unconsumed prefetch (two adds,
-        no get between) disarms it, so add-only workloads pay nothing."""
-        if self._get_prefetch is not None:
-            # the previous prefetch was never consumed: this workload is
-            # not in a clean get-after-add regime — drop it, disarm, and
-            # back off exponentially (a Get re-arms, but a thrashing
-            # add,add,get cadence must not buy one wasted table-sized
-            # transfer per cycle forever)
-            self._prefetch_armed = False
-            self._get_prefetch = None
-            self._prefetch_backoff = min(self._prefetch_backoff * 2 + 1,
-                                         16)
-            self._prefetch_skip = self._prefetch_backoff
-            return
-        if (not self._prefetch_armed
-                or not config.get_flag("table_get_prefetch")
-                or self._zoo.size() > 1):
-            return
-        if self._prefetch_skip > 0:
-            self._prefetch_skip -= 1
-            return
-        snap = (self._bf16_cast_fn()(self._data) if self._wire != "none"
-                else self._snapshot_fn()(self._data))
-        try:
-            snap.copy_to_host_async()
-        except AttributeError:
-            pass
-        self._get_prefetch = (self._version, snap)
-
-    def _take_prefetch(self) -> Optional[jax.Array]:
-        """The in-flight prefetched snapshot for the CURRENT version, or
-        None (caller holds the dispatch lock). A stale snapshot (another
-        mutation landed after it) is dropped — its bytes are not the
-        bytes a Get at this version must return."""
-        self._prefetch_armed = True
-        pf = self._get_prefetch
-        if pf is None:
-            return None
-        self._get_prefetch = None
-        if pf[0] != self._version:
-            return None
-        self._prefetch_backoff = 0   # consumed: the regime is real
-        Dashboard.get(f"table[{self.name}].get.prefetched").incr()
-        return pf[1]
 
     def _store_get_cache(self, version: int, host: np.ndarray) -> None:
         """Caller holds the dispatch lock. An older-version store (a slow
@@ -842,48 +720,6 @@ class Table:
                 _update, donate_argnums=(0, 1))
         return fn
 
-    def _pad_flat_delta(self, flat: jax.Array, dtype) -> jax.Array:
-        """Raveled logical-size delta -> padded table shape (in-graph)."""
-        n = int(np.prod(self.shape))
-        return jnp.zeros(self._padded_shape, dtype).reshape(-1).at[:n].set(
-            flat.astype(dtype)).reshape(self._padded_shape)
-
-    def _onebit_update_fn(self):
-        fn = self._jit_cache.get("full_1bit")
-        if fn is None:
-            updater = self.updater
-            n = int(np.prod(self.shape))
-            block = self._one_bit.block
-
-            def _update(data, ustate, bits, scales, opt):
-                # in-graph decode of the 1-bit payload (ops/wire_codec),
-                # fused into the updater apply
-                flat = wire_codec.onebit_decode(bits, scales, n=n,
-                                                block=block)
-                delta = self._pad_flat_delta(flat, data.dtype)
-                data, ustate = updater.apply(data, ustate, delta, opt)
-                return data, ustate, jnp.ravel(data)[0]
-
-            fn = self._jit_cache["full_1bit"] = jax.jit(
-                _update, donate_argnums=(0, 1))
-        return fn
-
-    def _topk_update_fn(self):
-        fn = self._jit_cache.get("full_topk")
-        if fn is None:
-            updater = self.updater
-            n = int(np.prod(self.shape))
-
-            def _update(data, ustate, idx, vals, opt):
-                flat = wire_codec.topk_decode(idx, vals, n=n)
-                delta = self._pad_flat_delta(flat, data.dtype)
-                data, ustate = updater.apply(data, ustate, delta, opt)
-                return data, ustate, jnp.ravel(data)[0]
-
-            fn = self._jit_cache["full_topk"] = jax.jit(
-                _update, donate_argnums=(0, 1))
-        return fn
-
     # ------------------------------------------------------------------ #
     # client-side add coalescing
     # ------------------------------------------------------------------ #
@@ -892,13 +728,7 @@ class Table:
         updater: stateless linear updater (sum of deltas == sequence of
         adds, and opt is never read), single controller (a collective
         process_sum must keep one per-process issue order). Wire-filtered
-        tables coalesce too: the single applier thread preserves encode
-        order, and under a linear updater the error-feedback codecs are
-        indifferent to whether N deltas are encoded one-by-one or as
-        their sum — the residual carries whatever any one payload left
-        out. This is also what takes the encode off the caller's
-        dispatch path (the inline 1bit encode+compile once made
-        add_async ~1400x the uncompressed dispatch)."""
+        tables coalesce too: the merged batch is cast once."""
         return (self._zoo.size() == 1
                 and not isinstance(delta, jax.Array)
                 and type(self.updater) in updaters_lib.STATELESS_LINEAR)
@@ -942,9 +772,6 @@ class Table:
                 self._data, self._ustate, token = self._full_update_fn()(
                     self._data, self._ustate, delta_dev, batch[0].opt)
                 self._version_applied()
-                # prefetch BEFORE the waiters wake: the snapshot's
-                # device->host copy streams while they block on the token
-                self._maybe_prefetch()
             for e in batch:
                 e.token = token
             if self._train_cache is not None:
@@ -1033,7 +860,6 @@ class Table:
                         self._full_update_fn()(
                             self._data, self._ustate, delta_dev, opt)
                     self._version_applied()
-                    self._maybe_prefetch()
             return self._track(token)
         finally:
             if self._train_cache is not None:
@@ -1045,63 +871,25 @@ class Table:
                 self._train_cache.clear()
 
     def _add_async_wire(self, delta: ArrayLike, opt: AddOption) -> int:
-        """Compressed upload: the host payload shrinks 2x (bf16) / ~29x
-        (1bit) / ~16x (topk) before crossing the wire; decode runs
-        in-graph, fused into the updater apply."""
+        """Compressed upload: the host payload is cast to bfloat16
+        before crossing the wire and cast back in the update program."""
         arr = np.asarray(delta, dtype=self.dtype).reshape(self.shape)
         if self._zoo.size() > 1:
             from multiverso_tpu.parallel.collectives import process_sum
             arr = process_sum(arr)
         return self._track(self._dispatch_wire_add(arr, opt))
 
-    def _encode_residual(self) -> jax.Array:
-        """The device-resident error-feedback residual (lazy zeros)."""
-        if self._wire_residual is None:
-            self._wire_residual = jax.device_put(
-                np.zeros(int(np.prod(self.shape)), np.float32),
-                self._codec_dev)
-        return self._wire_residual
-
     def _dispatch_wire_add(self, arr: np.ndarray, opt: AddOption):
-        """Encode (jitted wire_codec kernel on the host-side CPU backend,
-        numpy reference filter when that backend is unavailable) + ship
-        only the compressed payload across the host<->device seam + apply
-        via the in-graph decode+update program. Caller holds the dispatch
-        lock (the codec residual is table state). Returns the completion
-        token."""
-        if self._wire == "bf16":
-            import ml_dtypes
-            padded = np.zeros(self._padded_shape, ml_dtypes.bfloat16)
-            padded[: self.shape[0]] = arr.astype(ml_dtypes.bfloat16)
-            dev = jax.device_put(padded, self._sharding)
-            self._data, self._ustate, token = self._bf16_update_fn()(
-                self._data, self._ustate, dev, opt)
-        elif self._wire == "1bit":
-            if self._codec_dev is not None:
-                bits, scales, self._wire_residual = wire_codec.onebit_encode(
-                    arr.reshape(-1).astype(np.float32, copy=False),
-                    self._encode_residual(), block=self._one_bit.block)
-                bits, scales = np.asarray(bits), np.asarray(scales)
-            else:
-                _, bits, scales = self._one_bit.filter_in(arr)
-            self._data, self._ustate, token = self._onebit_update_fn()(
-                self._data, self._ustate,
-                jax.device_put(bits, self._replicated),
-                jax.device_put(scales, self._replicated), opt)
-        else:  # topk
-            if self._codec_dev is not None:
-                idx, vals, self._wire_residual = wire_codec.topk_encode(
-                    arr.reshape(-1).astype(np.float32, copy=False),
-                    self._encode_residual(), k=self._topk_k)
-                idx, vals = np.asarray(idx), np.asarray(vals)
-            else:
-                _, idx, vals = self._topk.filter_in(arr)
-            self._data, self._ustate, token = self._topk_update_fn()(
-                self._data, self._ustate,
-                jax.device_put(idx, self._replicated),
-                jax.device_put(vals, self._replicated), opt)
+        """Cast to bfloat16 on the host, ship half the bytes across the
+        host<->device seam, apply via the cast-back + update program.
+        Caller holds the dispatch lock. Returns the completion token."""
+        import ml_dtypes
+        padded = np.zeros(self._padded_shape, ml_dtypes.bfloat16)
+        padded[: self.shape[0]] = arr.astype(ml_dtypes.bfloat16)
+        dev = jax.device_put(padded, self._sharding)
+        self._data, self._ustate, token = self._bf16_update_fn()(
+            self._data, self._ustate, dev, opt)
         self._version_applied()
-        self._maybe_prefetch()
         return token
 
     def add(self, delta: ArrayLike, opt: Optional[AddOption] = None) -> None:
@@ -1120,18 +908,13 @@ class Table:
             if cached is not None:
                 return self._track((), lambda _: cached)
             version = self._version
-            # a write-triggered prefetch at this version already has its
-            # transfer in flight — adopt it instead of dispatching a
-            # fresh snapshot (same bytes by construction)
-            snap = self._take_prefetch()
-            if snap is None:
-                snap = (self._bf16_cast_fn()(self._data)
-                        if self._wire != "none"
-                        else self._snapshot_fn()(self._data))
-                try:
-                    snap.copy_to_host_async()
-                except AttributeError:
-                    pass
+            snap = (_bf16_cast(self._data)
+                    if self._wire != "none"
+                    else self._snapshot_fn()(self._data))
+            try:
+                snap.copy_to_host_async()
+            except AttributeError:
+                pass
 
             def _finalize(s, _v=version):
                 host = self._to_host(s)[: self.shape[0]]
@@ -1142,10 +925,6 @@ class Table:
                 return host
 
             return self._track(snap, _finalize)
-
-    def _bf16_cast_fn(self):
-        # the non-donating codec kernel: table data stays live
-        return wire_codec.bf16_cast
 
     def get(self, out: Optional[np.ndarray] = None) -> np.ndarray:
         """ref WorkerTable::Get — blocking pull of the whole logical table.
@@ -1163,15 +942,8 @@ class Table:
             if hit is not None:
                 return hit
             version = self._version
-            snap = self._take_prefetch()
-            if snap is not None:
-                # the prefetched transfer has been streaming since the
-                # add dispatched it: wait out only the residual
-                host = self._to_host(snap)[: self.shape[0]]
-                if host.dtype != self.dtype:
-                    host = host.astype(self.dtype)
-            elif self._wire != "none":
-                host = self._to_host(self._bf16_cast_fn()(self._data))
+            if self._wire != "none":
+                host = self._to_host(_bf16_cast(self._data))
                 host = host[: self.shape[0]].astype(self.dtype)
             else:
                 host = self._to_host(self._data)[: self.shape[0]]

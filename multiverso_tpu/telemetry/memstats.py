@@ -19,7 +19,7 @@ have flagged. This module is the byte-level answer:
   ``RowShard.memory_stats`` (live table buffers per dtype, pinned-epoch
   count x retired-buffer bytes with per-pin age, apply-queue pending
   bytes), ``_SendWindow.memory_stats`` (pending + replay-retained
-  frames/bytes), ``Table.memory_stats`` (get cache + prefetch staging),
+  frames/bytes), ``Table.memory_stats`` (get cache),
   ``ReadReplica.memory_stats`` (snapshot buffer, device cache, staging
   copy), checkpoint/failover staging + on-disk tag bytes. Registration
   is one dict store at construct time; gauges are computed only when a

@@ -412,9 +412,10 @@ class TestWireBf16:
         np.testing.assert_allclose(t0.get()[1], 1.0, rtol=2e-2)
 
     def test_unknown_wire_raises(self, two_ranks):
-        with pytest.raises(ValueError):
-            AsyncMatrixTable(4, 2, name="wx", wire="zstd",
-                             ctx=two_ranks[0])
+        for mode in ("zstd", "1bit", "topk"):
+            with pytest.raises(ValueError):
+                AsyncMatrixTable(4, 2, name="wx", wire=mode,
+                                 ctx=two_ranks[0])
 
     def test_store_keeps_full_precision_despite_wire(self, two_ranks,
                                                      tmp_path):

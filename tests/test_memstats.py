@@ -388,7 +388,7 @@ class TestComponentGauges:
             from multiverso_tpu.table import Table
             t = Table((16, 4), name="syncmem")
             g = t.memory_stats()
-            assert g == {"cache_bytes": 0, "prefetch_bytes": 0}
+            assert g == {"cache_bytes": 0}
             t.get()
             g = t.memory_stats()
             assert g["cache_bytes"] == 16 * 4 * 4

@@ -41,26 +41,23 @@ class TestBatchFraming:
                 assert np.array_equal(a, b)
 
     def test_round_trip_preserves_codec_payloads(self):
-        """A sub-op carrying a compressed wire (1bit bits+scales) must
+        """A sub-op carrying a compressed wire (a bfloat16 blob) must
         come back byte-identical — the shard decodes straight from the
         batch blobs."""
-        from multiverso_tpu.utils import filters
         rng = np.random.default_rng(4)
-        vals = rng.normal(size=4 * 32).astype(np.float32)
-        bits, scales = filters.onebit_encode_np(vals, wire.ONEBIT_BLOCK)
+        vals = rng.normal(size=(4, 32)).astype(np.float32)
+        [half] = wire.encode_payload(vals, "bf16")
         ids = np.arange(4, dtype=np.int64)
         blob = wire.encode(svc.MSG_ADD_ROWS, 0,
-                           {"table": "t", "wire": "1bit"},
-                           [ids, bits, scales])
+                           {"table": "t", "wire": "bf16"}, [ids, half])
         [(mt, meta, arrs)] = wire.unpack_batch(wire.pack_batch([blob]))
-        assert meta["wire"] == "1bit"
-        assert np.array_equal(arrs[1], bits)
-        assert np.array_equal(arrs[2], scales)
-        dec = filters.onebit_decode_np(arrs[1], arrs[2], vals.size,
-                                       wire.ONEBIT_BLOCK)
-        ref = filters.onebit_decode_np(bits, scales, vals.size,
-                                       wire.ONEBIT_BLOCK)
-        assert np.array_equal(dec, ref)
+        assert meta["wire"] == "bf16"
+        assert arrs[1].dtype == half.dtype
+        assert arrs[1].nbytes == vals.nbytes // 2
+        assert arrs[1].tobytes() == half.tobytes()
+        assert np.array_equal(
+            wire.decode_payload(arrs[1:], "bf16", vals.shape, np.float32),
+            half.astype(np.float32))
 
     def test_empty_and_oversize_batches_rejected(self):
         with pytest.raises(wire.WireError):

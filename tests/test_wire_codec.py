@@ -1,161 +1,21 @@
-"""Device wire-codec parity + table get-cache tests (ISSUE 1 tentpole).
+"""The PS wire's payload codec (none / bf16) and the table get cache.
 
-The jitted kernels in ``ops/wire_codec.py`` must match the numpy
-reference filters in ``utils/filters.py`` **bit-for-bit** on the encoded
-bits and per-block scales — a payload encoded by either side must decode
-identically at the other (the PS wire ships the same frames). These are
-the property tests that pin that contract, plus the version-stamped get
-cache's monitor-counter behavior (a repeated Get with no intervening Add
-must not dispatch a device transfer).
+``ps/wire.encode_payload`` and ``decode_payload`` must invert each other
+at either endpoint and refuse a mode they do not speak; the
+version-stamped get cache's monitor counter must show that a repeated
+Get with no intervening Add dispatches no device transfer.
 """
 
 import numpy as np
 import pytest
 
 import multiverso_tpu as mv
-from multiverso_tpu.ops import wire_codec
-from multiverso_tpu.utils import config, filters
+from multiverso_tpu.utils import config
 from multiverso_tpu.utils.dashboard import Dashboard
 
 
-def _cases(seed=0):
-    """Random + adversarial flat f32 payloads: odd sizes (padding tail),
-    denormals (scale underflow territory), all-negative and all-positive
-    blocks (the empty-side scale is defined as 0), zeros, and huge
-    magnitudes."""
-    rng = np.random.default_rng(seed)
-    cases = []
-    for n in (1, 7, 1024, 1025, 4096, 10_000):
-        cases.append(rng.normal(size=n).astype(np.float32))
-    cases.append(np.full(3000, -0.25, np.float32))          # all-negative
-    cases.append(np.full(2048, 1e-3, np.float32))           # all-positive
-    cases.append(np.zeros(1536, np.float32))                # no signal
-    denorm = rng.normal(size=2048).astype(np.float32) * np.float32(1e-41)
-    cases.append(denorm)                                    # denormal blocks
-    cases.append((rng.normal(size=1024) * 1e30).astype(np.float32))
-    mixed = rng.normal(size=5000).astype(np.float32)
-    mixed[::7] = 0.0
-    cases.append(mixed)
-    return cases
-
-
-class TestOneBitParity:
-    @pytest.mark.parametrize("block", [8, 256, 1024])
-    def test_encode_bit_for_bit(self, block):
-        for flat in _cases():
-            ref_bits, ref_scales = filters.onebit_encode_np(flat, block)
-            zeros = np.zeros_like(flat)
-            bits, scales, _ = wire_codec.onebit_encode(flat, zeros,
-                                                       block=block)
-            bits, scales = np.asarray(bits), np.asarray(scales)
-            assert bits.dtype == np.uint8
-            np.testing.assert_array_equal(bits, ref_bits)
-            # bit-for-bit: scales are f32-identical, not just close
-            assert scales.tobytes() == ref_scales.astype(np.float32
-                                                         ).tobytes()
-
-    def test_decode_roundtrip_matches_numpy(self):
-        for flat in _cases(seed=1):
-            n = flat.size
-            bits, scales = filters.onebit_encode_np(flat, 1024)
-            ref = filters.onebit_decode_np(bits, scales, n, 1024)
-            dev = np.asarray(wire_codec.onebit_decode(bits, scales, n=n,
-                                                      block=1024))
-            assert dev.tobytes() == ref.tobytes()
-
-    def test_block_must_be_multiple_of_8(self):
-        with pytest.raises(ValueError):
-            filters.onebit_encode_np(np.ones(16, np.float32), 12)
-        with pytest.raises(ValueError):
-            filters.OneBitsFilter(block=12)
-
-    def test_residuals_converge_identically(self):
-        """Error feedback carried on device vs the numpy filter: the two
-        residual streams stay bit-identical over 100 steps (same adds, same
-        quantization error accrual)."""
-        rng = np.random.default_rng(2)
-        n, block = 2048, 256
-        filt = filters.OneBitsFilter(block=block)
-        residual = np.zeros(n, np.float32)
-        for step in range(100):
-            delta = rng.normal(size=n).astype(np.float32)
-            _, ref_bits, ref_scales = filt.filter_in(delta)
-            bits, scales, residual = wire_codec.onebit_encode(
-                delta, residual, block=block)
-            bits, scales, residual = (np.asarray(bits), np.asarray(scales),
-                                      np.asarray(residual))
-            np.testing.assert_array_equal(bits, ref_bits, err_msg=f"{step}")
-            assert scales.tobytes() == ref_scales.tobytes(), step
-            assert residual.tobytes() == filt._residual.astype(
-                np.float32).tobytes(), step
-
-
-class TestTopKParity:
-    @pytest.mark.parametrize("k", [1, 32, 500])
-    def test_encode_matches_numpy(self, k):
-        for flat in _cases(seed=3):
-            kk = min(k, flat.size)
-            filt = filters.TopKFilter(kk)
-            _, ref_idx, ref_vals = filt.filter_in(flat)
-            zeros = np.zeros_like(flat)
-            idx, vals, res = wire_codec.topk_encode(flat, zeros, k=kk)
-            idx, vals, res = (np.asarray(idx), np.asarray(vals),
-                              np.asarray(res))
-            np.testing.assert_array_equal(idx, ref_idx)
-            assert vals.tobytes() == ref_vals.tobytes()
-            assert res.tobytes() == filt._residual.astype(
-                np.float32).tobytes()
-
-    def test_decode_roundtrip(self):
-        rng = np.random.default_rng(4)
-        flat = rng.normal(size=1000).astype(np.float32)
-        idx, vals, _ = wire_codec.topk_encode(flat, np.zeros_like(flat),
-                                              k=100)
-        out = np.asarray(wire_codec.topk_decode(idx, vals, n=1000))
-        ref = filters.TopKFilter(100)
-        header, ridx, rvals = ref.filter_in(flat)
-        np.testing.assert_array_equal(out, ref.filter_out(header, ridx,
-                                                          rvals))
-
-    def test_error_feedback_preserves_sum(self):
-        """EF property: after N payloads, decoded-sum + residual == the
-        true running sum (nothing is ever lost, only deferred)."""
-        rng = np.random.default_rng(5)
-        n, k = 512, 16
-        residual = np.zeros(n, np.float32)
-        decoded_sum = np.zeros(n, np.float64)
-        true_sum = np.zeros(n, np.float64)
-        for _ in range(50):
-            delta = rng.normal(size=n).astype(np.float32) * 0.01
-            true_sum += delta
-            idx, vals, residual = wire_codec.topk_encode(delta, residual,
-                                                         k=k)
-            decoded_sum += np.asarray(
-                wire_codec.topk_decode(idx, vals, n=n))
-            residual = np.asarray(residual)
-        np.testing.assert_allclose(decoded_sum + residual, true_sum,
-                                   atol=1e-3)
-
-
 class TestPSWirePayload:
-    """ps/wire.encode_payload must produce the SAME frames as the device
-    codec, and decode_payload must invert them (either endpoint)."""
-
-    def test_onebit_frame_parity(self):
-        from multiverso_tpu.ps import wire as ps_wire
-        rng = np.random.default_rng(6)
-        arr = rng.normal(size=(33, 40)).astype(np.float32)
-        blobs = ps_wire.encode_payload(arr, "1bit")
-        assert len(blobs) == 2
-        flat = arr.reshape(-1)
-        bits, scales, _ = wire_codec.onebit_encode(
-            flat, np.zeros_like(flat), block=ps_wire.ONEBIT_BLOCK)
-        np.testing.assert_array_equal(blobs[0], np.asarray(bits))
-        assert blobs[1].tobytes() == np.asarray(scales).tobytes()
-        out = ps_wire.decode_payload(blobs, "1bit", arr.shape, np.float32)
-        ref = filters.onebit_decode_np(blobs[0], blobs[1], arr.size,
-                                       ps_wire.ONEBIT_BLOCK)
-        assert out.tobytes() == ref.tobytes()
+    """ps/wire.decode_payload inverts encode_payload (either endpoint)."""
 
     def test_none_and_bf16_roundtrip(self):
         from multiverso_tpu.ps import wire as ps_wire
@@ -165,19 +25,14 @@ class TestPSWirePayload:
             out = ps_wire.decode_payload(blobs, mode, arr.shape, np.float32)
             np.testing.assert_allclose(out, arr, rtol=1e-2)
 
-    def test_compressed_frame_is_smaller(self):
+    @pytest.mark.parametrize("mode", ["1bit", "topk"])
+    def test_removed_modes_are_refused(self, mode):
         from multiverso_tpu.ps import wire as ps_wire
-        arr = np.ones(100_000, np.float32)
-        plain = sum(b.nbytes for b in ps_wire.encode_payload(arr, "none"))
-        onebit = sum(b.nbytes for b in ps_wire.encode_payload(arr, "1bit"))
-        assert onebit * 20 < plain   # ~29x fewer bytes on the wire
-        # the size-contract helpers predict the frame exactly
-        assert onebit == wire_codec.onebit_compressed_nbytes(
-            arr.size, ps_wire.ONEBIT_BLOCK)
-        idx, vals, _ = wire_codec.topk_encode(arr, np.zeros_like(arr),
-                                              k=64)
-        assert (np.asarray(idx).nbytes + np.asarray(vals).nbytes
-                == wire_codec.topk_compressed_nbytes(64))
+        arr = np.ones(8, np.float32)
+        with pytest.raises(ValueError):
+            ps_wire.encode_payload(arr, mode)
+        with pytest.raises(ValueError):
+            ps_wire.decode_payload([arr], mode, arr.shape, np.float32)
 
 
 class TestGetCache:
@@ -276,146 +131,3 @@ class TestAsyncBufferVersionSkip:
         assert buf.get() == 1
         assert buf.get() == 2
         buf.stop()
-
-
-class TestWireFilteredTable:
-    """End-to-end through the sync Table's compressed host<->device wire:
-    the device encode + in-graph decode must agree with the numpy
-    reference semantics."""
-
-    def test_1bit_add_matches_reference_decode(self):
-        mv.init()
-        rng = np.random.default_rng(7)
-        t = mv.ArrayTable(4096, updater="sgd", name="w1bit_t")
-        tw = mv.ArrayTable(4096, updater="sgd", name="w1bit_tw",
-                           wire_filter="1bit")
-        delta = rng.normal(size=4096).astype(np.float32)
-        # reference: what one EF-encoded payload should apply ("sgd"
-        # subtracts the delta as-is; callers pre-scale by lr)
-        filt = filters.OneBitsFilter(block=1024)
-        header, bits, scales = filt.filter_in(delta)
-        expected = -filters.onebit_decode_np(bits, scales, 4096, 1024)
-        tw.add(delta)
-        np.testing.assert_allclose(tw.get(), expected, rtol=1e-2,
-                                   atol=1e-6)
-        del t
-
-    def test_1bit_error_feedback_converges(self):
-        """100 identical adds through the 1bit wire. Two properties:
-
-        (1) EF conservation, end-to-end through the table: decoded sum
-        (the table) plus the table's carried residual equals the true
-        sum — quantization error is deferred, never lost.
-        (2) EF beats no-EF: without feedback the per-payload bias is
-        constant (same delta -> same decode every step) and accumulates
-        linearly; with feedback the error stays well under half of it.
-
-        (Per-element error is NOT tiny here — an above-block-scale
-        element lags until the scales adapt, so max|err| can reach ~1 of
-        ~3-magnitude entries at step 100. That is expected 1-bit SGD
-        behavior, identical in the numpy reference — see
-        test_residuals_converge_identically.)"""
-        mv.init()
-        rng = np.random.default_rng(8)
-        n = 2048
-        tw = mv.ArrayTable(n, updater="default", name="w1bit_conv",
-                           wire_filter="1bit")
-        # ArrayTable default updater is a plain sum (delta applied as-is)
-        delta = rng.normal(size=n).astype(np.float32) * 0.01
-        steps = 100
-        for _ in range(steps):
-            tw.add(delta)
-        got = np.asarray(tw.get(), np.float64)
-        true = delta.astype(np.float64) * steps   # entries ~ N(0, 1)
-        residual = np.asarray(
-            tw._wire_residual if tw._wire_residual is not None
-            else tw._one_bit._residual, np.float64)
-        # (1) conservation: table + residual == true sum, up to the bf16
-        # Get-reply rounding of ~3-magnitude entries
-        np.testing.assert_allclose(got + residual, true, atol=0.05)
-        # (2) linear no-EF bias for this constant delta, for comparison
-        bits, scales = filters.onebit_encode_np(delta, 1024)
-        no_ef = np.abs(true - steps * filters.onebit_decode_np(
-            bits, scales, n, 1024).astype(np.float64)).max()
-        assert np.abs(got - true).max() < 0.5 * no_ef
-
-    def test_topk_add_applies_support_exactly(self):
-        mv.init()
-        rng = np.random.default_rng(9)
-        n = 4096
-        tw = mv.ArrayTable(n, updater="default", name="wtopk_t",
-                           wire_filter="topk")
-        delta = np.zeros(n, np.float32)
-        hot = rng.choice(n, size=32, replace=False)
-        delta[hot] = rng.normal(size=32).astype(np.float32)
-        tw.add(delta)   # sparse delta fits entirely in the top-k support
-        got = tw.get()
-        np.testing.assert_allclose(got[hot], delta[hot], rtol=1e-2,
-                                   atol=1e-6)
-
-
-class TestAsyncTableOneBitWire:
-    """The PS (socket) plane with wire="1bit": encoded frames cross the
-    wire and decode exactly once at the owning shard."""
-
-    def test_whole_table_add_get(self, two_ranks):
-        from multiverso_tpu.ps.tables import AsyncMatrixTable
-        tables = [AsyncMatrixTable(8, 4, name="onebit_ps", wire="1bit",
-                                   updater="default", ctx=c)
-                  for c in two_ranks]
-        rng = np.random.default_rng(10)
-        # uniform magnitude, random sign: the per-block mean EQUALS every
-        # entry's magnitude, so each 1bit payload decodes exactly and the
-        # EF residual stays zero — the sum is exact, only bf16 reply
-        # rounding remains (mixed magnitudes would exercise EF stability,
-        # which small 16-element blocks do not guarantee; the EF-sum
-        # invariant is covered by test_error_feedback_preserves_sum)
-        delta = (0.5 * rng.choice([-1.0, 1.0], size=(8, 4))
-                 ).astype(np.float32)
-        steps = 60
-        for _ in range(steps):
-            tables[0].add(delta)
-        got = tables[0].get()
-        # exact local short-circuit + exactly-decoding remote payloads:
-        # both halves land on the true sum (remote half read back bf16)
-        np.testing.assert_allclose(got, delta * steps, rtol=1e-2)
-        # a fresh get from the OTHER rank sees the same state (its local
-        # shard exactly, the peer's through the bf16 reply wire)
-        np.testing.assert_allclose(tables[1].get(), got, rtol=1e-2)
-
-    def test_row_add_roundtrip(self, two_ranks):
-        from multiverso_tpu.ps.tables import AsyncMatrixTable
-        tables = [AsyncMatrixTable(8, 4, name="onebit_rows", wire="1bit",
-                                   updater="default", ctx=c)
-                  for c in two_ranks]
-        vals = np.full((2, 4), 0.5, np.float32)
-        # rows 6,7 live on rank 1: the payload crosses the socket 1bit-
-        # encoded; all values equal => block scale reproduces them exactly
-        tables[0].add_rows([6, 7], vals)
-        got = tables[0].get_rows([6, 7])
-        np.testing.assert_allclose(got, vals, rtol=1e-2)
-
-
-class TestAsyncTableTopkWire:
-    """wire="topk" on the PS plane: the sparsification applies to ADD
-    deltas only — get replies must carry the FULL value block (bf16)."""
-
-    def test_get_replies_are_not_sparsified(self, two_ranks):
-        """Regression: _reply_wire used to pass "topk" through for gets,
-        so a remote pull returned a ~3% top-k skeleton of the weights
-        (everything else zeroed) — destructive for parameter VALUES, the
-        same rule 1bit already followed."""
-        from multiverso_tpu.ps.tables import AsyncMatrixTable
-        tables = [AsyncMatrixTable(8, 32, name="topk_get", wire="topk",
-                                   updater="default", ctx=c)
-                  for c in two_ranks]
-        # set_rows ships raw (no add codec): the table holds exactly vals
-        vals = np.linspace(1.0, 2.0, 8 * 32,
-                           dtype=np.float32).reshape(8, 32)
-        tables[0].set_rows(np.arange(8), vals)
-        for t in tables:   # both ranks: local short-circuit AND remote
-            got = t.get_rows(np.arange(8))
-            assert np.count_nonzero(got) == got.size, \
-                "get reply was sparsified"
-            np.testing.assert_allclose(got, vals, rtol=1e-2)
-            np.testing.assert_allclose(t.get(), vals, rtol=1e-2)

@@ -28,10 +28,9 @@ def main():
         g = multihost_utils.process_allgather(a, tiled=False)
         return np.asarray(g).sum(axis=0).astype(a.dtype)
 
-    # Compressed-wire variants of the host aggregation (VERDICT r4 item 5:
-    # the 1-bit filter's design point is a slow wire; the cross-process
-    # delta aggregation is the seam where its 29x byte reduction could
-    # dominate encode cost — measure it against bf16 and plain here).
+    # Compressed-wire variant of the host aggregation: the cross-process
+    # delta aggregation is a seam where half the bytes could beat the
+    # cast's cost — measured against plain here.
     def bf16_agg(a):
         import ml_dtypes
         from jax.experimental import multihost_utils
@@ -39,27 +38,11 @@ def main():
             a.astype(ml_dtypes.bfloat16), tiled=False)
         return np.asarray(g).astype(np.float32).sum(axis=0)
 
-    from multiverso_tpu.utils.filters import OneBitsFilter
-    onebit = OneBitsFilter()
-
-    def onebit_agg(a):
-        from jax.experimental import multihost_utils
-        header, bits, scales = onebit.filter_in(a)
-        gb = np.asarray(multihost_utils.process_allgather(bits,
-                                                          tiled=False))
-        gs = np.asarray(multihost_utils.process_allgather(scales,
-                                                          tiled=False))
-        acc = np.zeros_like(a)
-        for r in range(world):
-            acc += onebit.filter_out(header, gb[r], gs[r])
-        return acc
-
     out = {}
     want = world * (world + 1) / 2
     for name, fn, exact in (("process_sum", process_sum, True),
                             ("allgather", legacy, True),
-                            ("allgather_bf16", bf16_agg, False),
-                            ("allgather_1bit", onebit_agg, False)):
+                            ("allgather_bf16", bf16_agg, False)):
         fn(arr)                     # warm/compile
         reps, t0 = 5, time.monotonic()
         for _ in range(reps):
@@ -68,16 +51,12 @@ def main():
         if exact:
             assert got[0] == want, got[0]
         else:
-            # lossy wires: constant positive blocks decode near-exactly
+            # lossy wire: small integers survive bfloat16 near-exactly
             assert abs(got[0] - want) < 0.1 * want, (name, got[0])
         out[name + "_ms"] = round(dt * 1e3, 2)
     out["speedup"] = round(out["allgather_ms"] / out["process_sum_ms"], 2)
     out["bf16_vs_plain"] = round(out["allgather_ms"]
                                  / out["allgather_bf16_ms"], 2)
-    out["1bit_vs_plain"] = round(out["allgather_ms"]
-                                 / out["allgather_1bit_ms"], 2)
-    out["1bit_vs_bf16"] = round(out["allgather_bf16_ms"]
-                                / out["allgather_1bit_ms"], 2)
     if rank == 0:
         print("RESULT " + json.dumps(out), flush=True)
 
