@@ -506,17 +506,17 @@ class WordEmbedding:
             call.set(pairs=int(pairs), batches=n_batches,
                      shards=t_in.num_shards)
             lcg_before = self._lcg if shared else None
-            unique = []     # a pass's distinct update rows, on the device
+            rows = []   # a pass's distinct update rows, on the device
             with _trace.span("we.fused.dispatch", programs=epochs), \
                     t_in._dispatch_lock, t_sec._dispatch_lock:
                 key = None if shared else jax.random.key(cfg.seed)
                 for _ in range(epochs):
                     si, ss = t_in.program_state(), t_sec.program_state()
                     if shared:
-                        win, wsec, loss, self._lcg, u = epoch_fn(
+                        win, wsec, loss, self._lcg, r = epoch_fn(
                             si["data"], ss["data"], *batches, self._lcg,
                             plans)
-                        unique.append(u)
+                        rows.append(r)
                     else:
                         key, sub = jax.random.split(key)
                         win, wsec, loss = epoch_fn(
@@ -528,13 +528,17 @@ class WordEmbedding:
                     pair_rows, lcg_before, n_batches, epochs))
             with _trace.span("we.fused.wait"):
                 # fetch the scalar loss BEFORE stopping the clock: the
-                # readback waits for the whole epoch chain
-                loss_f = float(loss)
+                # readback waits for the whole epoch chain; the passes'
+                # row counts come with it, one round trip for all
+                loss_f, rows = jax.device_get((loss, rows))
+                loss_f = float(loss_f)
             if shared:
-                # the pairs' update rows as the table scatters were handed
-                # them: before combining, and the distinct ones after
+                # the pairs' update rows before combining, the distinct
+                # ones after, and those of them that the dense adds of the
+                # tables' heads took
+                unique, head = np.sum(rows, axis=0)
                 call.set(update_rows=2 * epochs * int(pairs),
-                         unique_rows=sum(int(u) for u in unique))
+                         unique_rows=int(unique), head_rows=int(head))
             with _trace.span("we.fused.count"):
                 dt = time.perf_counter() - t0
                 # words/sec follows the word2vec convention: corpus

@@ -321,7 +321,8 @@ def shared_neg_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
                     neg_weight: float = 1.0,
                     compute_dtype=jnp.bfloat16,
                     plans: Tuple[Optional[row_combine.RowPlan],
-                                 Optional[row_combine.RowPlan]] = (None, None)
+                                 Optional[row_combine.RowPlan]] = (None, None),
+                    shardings=(None, None)
                     ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Skipgram-NS minibatch with a batch-SHARED negative pool.
 
@@ -340,6 +341,8 @@ def shared_neg_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
     tables with their duplicates combined (``ops/row_combine``); ``plans``
     is the (centers, contexts) pair of :func:`row_combine.plan_rows` where
     the caller made them ahead of the step; a ``None`` is made in it.
+    ``shardings`` are the two tables', where their rows are sharded over
+    a mesh.
     """
     cd = compute_dtype
     with jax.named_scope("mv.fused.gather"):
@@ -358,11 +361,13 @@ def shared_neg_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
                 - neg_weight * jnp.mean(
                     jnp.sum(jax.nn.log_sigmoid(-negs), axis=-1)))
     with jax.named_scope("mv.fused.scatter"):
-        win = row_combine.add_rows(win, centers, dv, plans[0])
+        win = row_combine.add_rows(win, centers, dv, plans[0],
+                                   shardings[0])
         # two scatters, NOT one concat'd scatter: the K'-row pool scatter
         # is nearly free while concatenation forces an extra [B+K', D]
         # materialization (measured ~30% slower per batch on-chip)
-        wout = row_combine.add_rows(wout, contexts, dup, plans[1])
+        wout = row_combine.add_rows(wout, contexts, dup, plans[1],
+                                    shardings[1])
         wout = wout.at[neg_ids].add(dun.astype(wout.dtype))
     return win, wout, loss
 
@@ -403,14 +408,17 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
     replacing both a threefry invocation (profiled at ~55% of the epoch)
     and the earlier per-batch in-scan LCG step (~17%).
     Returns ``epoch_fn(win, wout, centers, contexts, lcg_state,
-    plans=None) -> (win, wout, mean_loss, lcg_state, unique_rows)``.
+    plans=None) -> (win, wout, mean_loss, lcg_state, rows)``.
     ``plans`` is ``(plan_rows(centers, rows), plan_rows(contexts, rows))``
     (``ops/row_combine``) where the caller keeps them with the pairs: the
     sorts behind them are 4% of an epoch of 439 x 8,192 on a v5e, so a
     caller that runs the same pairs again makes them once; without them
-    the epoch makes its own. ``unique_rows`` is how many distinct centre
-    and context rows those plans name, of ``2 * centers.size`` update
-    rows: the table scatters' work after and before combining.
+    the epoch makes its own. ``rows`` is ``int32[2]``, one array for one
+    read-back: how many distinct centre and context rows those plans
+    name, of ``2 * centers.size`` update rows (the table writes' work
+    after and before combining), and how many of them lay in the tables'
+    heads, which the dense adds took and the walks did not
+    (``row_combine.HEAD``).
     ``slots`` is the negative table
     (word ids, ``2^table_bits`` of them) where the caller has built it
     already (:func:`build_negative_table`; at 12M words a fifth of a
@@ -424,6 +432,8 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
     neg_table = jnp.asarray(slots)
     neg_weight = cfg.negatives / k_shared
     shift = jnp.uint32(32 - table_bits)  # top bits: LCG low bits are weak
+    shardings = (tuple(f.sharding for f in table_formats)
+                 if table_formats else (None, None))
 
     # donate the tables: epochs chain win/wout through, and without donation
     # every call pays a full-table copy before the first scatter
@@ -450,14 +460,15 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
             c, x, nid, plan = batch
             win, wout, loss = shared_neg_step(
                 win, wout, c, x, nid, cfg.learning_rate, neg_weight,
-                compute_dtype, plan)
+                compute_dtype, plan, shardings)
             return (win, wout), loss
 
         with jax.named_scope("mv.fused"):   # device-trace name
             (win, wout), losses = jax.lax.scan(
                 body, (win, wout), (centers, contexts, nids, plans))
-        unique = jnp.sum(plans[0].count) + jnp.sum(plans[1].count)
-        return win, wout, jnp.mean(losses), s_all[-1], unique
+        rows = jnp.stack([jnp.sum(plans[0].count) + jnp.sum(plans[1].count),
+                          jnp.sum(plans[0].head) + jnp.sum(plans[1].head)])
+        return win, wout, jnp.mean(losses), s_all[-1], rows
 
     return epoch_fn
 

@@ -1,7 +1,9 @@
 """``ops/row_combine`` (ISSUE 28): a minibatch's duplicate update rows are
 summed first, in float32, and the table scatter writes every distinct row
 once and no other row at all; the shared-negatives fused epoch built on it
-does what a plain sequence of steps with raw scatter-adds does."""
+does what a plain sequence of steps with raw scatter-adds does. ISSUE 31:
+the rows below ``HEAD`` take one dense add and the walk only the others,
+wherever ``HEAD`` falls among the ids."""
 
 import jax
 import jax.numpy as jnp
@@ -30,20 +32,30 @@ def _table(rng) -> np.ndarray:
     return table
 
 
+# HEAD against a table of 1,003 rows and Zipf ids: the whole table (a table
+# smaller than HEAD), no row, row 77 (the one_row case's) on either side,
+# and a boundary that falls inside a chunk of the walk with duplicates on
+# both sides of it
+HEADS = [8192, 0, 77, 78, 5, 300]
+
+
 @pytest.mark.parametrize("made_ahead", [True, False])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("b,chunk", [(256, 256), (256, 64), (200, 64)])
+@pytest.mark.parametrize("head", HEADS)
 @pytest.mark.parametrize("kind", ["distinct", "one_row", "zipf", "sorted"])
-def test_add_rows_is_the_scatter_add(kind, b, chunk, dtype, made_ahead,
+def test_add_rows_is_the_scatter_add(kind, head, b, chunk, dtype, made_ahead,
                                      monkeypatch):
     monkeypatch.setattr(row_combine, "CHUNK", chunk)
+    monkeypatch.setattr(row_combine, "HEAD", head)
     rng = np.random.default_rng(len(kind) * 1000 + b + chunk)
     ids, table = _ids(kind, b, rng), _table(rng)
     updates = jnp.asarray(rng.normal(size=(b, WIDTH)).astype(np.float32)
                           ).astype(dtype)
     plan = (row_combine.plan_rows(jnp.asarray(ids), ROWS) if made_ahead
             else None)
-    got = np.asarray(jax.jit(row_combine.add_rows)(
+    # a fresh function: jit would hand back the trace of another HEAD
+    got = np.asarray(jax.jit(lambda *a: row_combine.add_rows(*a))(
         jnp.asarray(table), jnp.asarray(ids), updates, plan))
     # whatever the updates' type, a run is summed in float32
     upd32 = np.asarray(updates.astype(jnp.float32))
@@ -60,17 +72,26 @@ def test_add_rows_is_the_scatter_add(kind, b, chunk, dtype, made_ahead,
         np.testing.assert_array_equal(got, want)
 
 
+@pytest.mark.parametrize("head", HEADS)
 @pytest.mark.parametrize("kind", ["distinct", "one_row", "zipf", "sorted"])
-def test_plan_rows_names_every_distinct_row_once(kind):
+def test_plan_rows_names_every_distinct_row_once(kind, head, monkeypatch):
+    monkeypatch.setattr(row_combine, "HEAD", head)
     rng = np.random.default_rng(7)
     ids = np.stack([_ids(kind, 96, rng) for _ in range(5)])
-    plan = jax.jit(row_combine.plan_rows, static_argnums=1)(
-        jnp.asarray(ids), ROWS)
-    run, uniq, count = (np.asarray(a) for a in plan)
-    assert run.shape == uniq.shape == ids.shape and count.shape == (5,)
+    plan = jax.jit(lambda ids: row_combine.plan_rows(ids, ROWS))(
+        jnp.asarray(ids))
+    run, uniq, count, heads, head_run = (np.asarray(a) for a in plan)
+    assert run.shape == uniq.shape == ids.shape
+    assert count.shape == heads.shape == (5,)
+    assert head_run.shape == (5, min(head, ROWS))
     for k in range(5):
         distinct = np.unique(ids[k])
         assert count[k] == distinct.size
+        # the head: the runs below HEAD, and each of its rows' run
+        assert heads[k] == (distinct < head).sum()
+        held = np.flatnonzero(head_run[k] < 96)
+        np.testing.assert_array_equal(held, distinct[distinct < head])
+        np.testing.assert_array_equal(uniq[k][head_run[k][held]], held)
         np.testing.assert_array_equal(uniq[k, :count[k]], distinct)
         # the pads: out of range and distinct, so the whole is sorted and
         # unique and a dropping scatter writes nothing for them
@@ -103,10 +124,13 @@ def _raw_step(win, wout, c, x, nid, lr, nw):
     return win, wout.at[nid].add(gn.T @ v), loss
 
 
-@pytest.mark.parametrize("batch,chunk", [(64, 256), (192, 64)])
-def test_fused_epoch_equals_sequential_raw_scatter_steps(batch, chunk,
+# heads: the whole table of 301 rows, and one it is larger than
+@pytest.mark.parametrize("batch,chunk,head", [
+    (64, 256, 8192), (192, 64, 8192), (64, 256, 40), (192, 64, 40)])
+def test_fused_epoch_equals_sequential_raw_scatter_steps(batch, chunk, head,
                                                          monkeypatch):
     monkeypatch.setattr(row_combine, "CHUNK", chunk)
+    monkeypatch.setattr(row_combine, "HEAD", head)
     vocab, dim, pool, batches = 300, 16, 8, 6
     cfg = w2v.W2VConfig(vocab, dim, negatives=4, shared_negatives=pool,
                         learning_rate=0.05)
@@ -118,7 +142,7 @@ def test_fused_epoch_equals_sequential_raw_scatter_steps(batch, chunk,
     wout0 = rng.uniform(-0.5, 0.5, (vocab + 1, dim)).astype(np.float32)
     lcg0 = w2v.init_lcg_state(pool, 1)
     epoch = w2v.make_fused_shared_epoch(cfg, unigram, jnp.float32)
-    win, wout, loss, lcg, unique = epoch(
+    win, wout, loss, lcg, rows = epoch(
         jnp.asarray(win0), jnp.asarray(wout0), jnp.asarray(cs),
         jnp.asarray(xs), jnp.asarray(lcg0))
     # the same pools, from the sampler's own host arithmetic
@@ -139,5 +163,6 @@ def test_fused_epoch_equals_sequential_raw_scatter_steps(batch, chunk,
         assert np.abs(np.asarray(got) - np.asarray(ref)).max() <= (
             TOL_F32 * delta)
     assert np.array_equal(np.asarray(win)[vocab], win0[vocab])  # scratch row
-    assert int(unique) == sum(np.unique(r).size for r in cs) + sum(
-        np.unique(r).size for r in xs)
+    distinct = [np.unique(r) for r in list(cs) + list(xs)]
+    assert rows.tolist() == [sum(d.size for d in distinct),
+                             sum((d < head).sum() for d in distinct)]

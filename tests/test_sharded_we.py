@@ -53,6 +53,21 @@ def _spans(name: str, since: int = 0):
     return [e for e in ttrace.events()[since:] if e["name"] == name]
 
 
+@pytest.fixture
+def set_head(monkeypatch):
+    """``row_combine.HEAD`` for one test. ``jax.jit`` keeps a trace by the
+    function it wraps, and the app wraps ``plan_rows`` itself, so another
+    HEAD's traces are dropped on the way in and on the way out."""
+    from multiverso_tpu.ops import row_combine
+
+    def set_(head: int) -> None:
+        monkeypatch.setattr(row_combine, "HEAD", head)
+        jax.clear_caches()
+
+    yield set_
+    jax.clear_caches()
+
+
 # ---------------------------------------------------------------------- #
 # a table built by shards is the table drawn whole
 # ---------------------------------------------------------------------- #
@@ -176,14 +191,20 @@ def test_one_batch_on_row_sharded_tables_matches_the_reference(shards):
     assert np.unique(owner_rows(ref["in_ids"], VOCAB, shards)).size > 1
 
 
+# of 204 padded rows, 51 a shard on four: the head is the whole table (all
+# of every shard), lies inside shard 0, ends inside shard 2, or is empty
+@pytest.mark.parametrize("head", [8192, 30, 120, 0])
 def test_combined_scatters_on_row_sharded_tables_equal_one_device(
-        monkeypatch):
+        head, set_head, monkeypatch):
     """ISSUE 28: the epoch combines a minibatch's duplicate update rows
     and walks the distinct ones a chunk at a time; partitioned over four
     row shards it leaves the tables one device leaves, and no other row
-    moves. Chunks of 64 slots, so a batch of 192 walks up to three."""
+    moves. Chunks of 64 slots, so a batch of 192 walks up to three.
+    ISSUE 31: the rows below ``HEAD`` take a dense add, which every shard
+    makes to its own rows."""
     from multiverso_tpu.ops import row_combine
     monkeypatch.setattr(row_combine, "CHUNK", 64)
+    set_head(head)
     ids = _stream(900, seed=11)
     got = {}
     for shards in (1, 4):
@@ -214,8 +235,11 @@ def test_combined_scatters_on_row_sharded_tables_equal_one_device(
 # ---------------------------------------------------------------------- #
 # the counts on the we.fused span
 # ---------------------------------------------------------------------- #
-@pytest.mark.parametrize("shards,epochs", [(4, 1), (4, 2), (1, 1)])
-def test_fused_span_counts_update_rows_by_shard(shards, epochs):
+@pytest.mark.parametrize("shards,epochs,head", [
+    (4, 1, 8192), (4, 2, 40), (1, 1, 40), (1, 2, 8192)])
+def test_fused_span_counts_update_rows_by_shard(shards, epochs, head,
+                                                set_head):
+    set_head(head)
     _init(shards)
     we = _we()
     ids = _stream(3_000, seed=9)
@@ -238,9 +262,13 @@ def test_fused_span_counts_update_rows_by_shard(shards, epochs):
     # ISSUE 28: the pairs' update rows before combining, and the distinct
     # rows the table scatters were handed after (centres plus contexts)
     assert a["update_rows"] == epochs * batches * 2 * 64
-    assert a["unique_rows"] == epochs * sum(
-        np.unique(r).size for r in list(np.asarray(cb)) + list(np.asarray(xb)))
+    distinct = [np.unique(r)
+                for r in list(np.asarray(cb)) + list(np.asarray(xb))]
+    assert a["unique_rows"] == epochs * sum(d.size for d in distinct)
     assert a["unique_rows"] < a["update_rows"]
+    # ISSUE 31: and those of them that the tables' heads took
+    assert a["head_rows"] == epochs * sum((d < head).sum() for d in distinct)
+    assert (a["head_rows"] < a["unique_rows"]) == (head < VOCAB)
     per_batch = (2 * 64 + 16) * WIDTH * 4       # float32 on the CPU
     assert a["allreduce_bytes"] == (shards > 1) * epochs * batches * per_batch
     # the pairs' share was counted when the pairs were generated

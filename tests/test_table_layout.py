@@ -410,6 +410,33 @@ def test_fused_epoch_on_a_v5e_copies_no_table(one_chip, kept):
         assert len(copies) == 4 and temp > 2 * one_table
 
 
+def _compiled_epoch(rows, tables, whole, batch):
+    """The shared-negatives epoch over four minibatches of ``batch``
+    pairs, compiled for two ``f32[rows, 300]`` tables placed as
+    ``tables`` says and laid out as their row programs run; ids and
+    sampler state lie ``whole`` on every device."""
+    fmt = Format(table_lib.row_program_layout(
+        (rows, 300), jnp.dtype(jnp.float32), tables), tables)
+    cfg = w2v.W2VConfig(rows - 8, 300, 5, 5, 0.025, False, False, 64)
+    fn = w2v.make_fused_shared_epoch(
+        cfg, np.full(rows - 8, 1 / (rows - 8)), jnp.bfloat16,
+        table_formats=(fmt, fmt))
+    table = jax.ShapeDtypeStruct((rows, 300), jnp.float32, sharding=fmt)
+    pairs = jax.ShapeDtypeStruct((4, batch), jnp.int32, sharding=whole)
+    lcg = jax.ShapeDtypeStruct((64,), jnp.uint32, sharding=whole)
+    return fn.lower(table, table, pairs, pairs, lcg).compile()
+
+
+def _pair_scatters_promise_distinct_rows_only(compiled, rows):
+    """Three table scatters: the pairs' two promise distinct rows, the
+    pool's does not, and none is told its ids are sorted."""
+    scatters = re.findall(
+        r"= f32\[%d,300\]\S* scatter\(.*" % rows, compiled.as_text())
+    return (len(scatters) == 3
+            and sum("unique_indices=true" in s for s in scatters) == 2
+            and not any("indices_are_sorted=true" in s for s in scatters))
+
+
 @pytest.mark.parametrize("batch", [256, 1024])
 def test_fused_epoch_on_a_v5e_scatters_distinct_rows_in_place(one_chip,
                                                               batch):
@@ -419,27 +446,50 @@ def test_fused_epoch_on_a_v5e_scatters_distinct_rows_in_place(one_chip,
     the plans and the combined rows are small beside a table, and the two
     table scatters of the pairs promise distinct rows (the pool's, whose
     rows may repeat, does not). None is told its ids are sorted: the v5e
-    then streams the whole table (PERF.md, PR 28)."""
+    then streams the whole table (PERF.md, PR 28). ISSUE 31: the first
+    ``row_combine.HEAD`` rows of each table take a dense add, written
+    into the table where it lies."""
     shape = (ROWS, 300)
-    fmt = Format(table_lib.row_program_layout(
-        shape, jnp.dtype(jnp.float32), one_chip), one_chip)
-    cfg = w2v.W2VConfig(VOCAB, 300, 5, 5, 0.025, False, False, 64)
-    fn = w2v.make_fused_shared_epoch(
-        cfg, np.full(VOCAB, 1 / VOCAB), jnp.bfloat16,
-        table_formats=(fmt, fmt))
-    table = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=fmt)
-    pairs = jax.ShapeDtypeStruct((4, batch), jnp.int32, sharding=one_chip)
-    lcg = jax.ShapeDtypeStruct((64,), jnp.uint32, sharding=one_chip)
-    compiled = fn.lower(table, table, pairs, pairs, lcg).compile()
+    compiled = _compiled_epoch(ROWS, one_chip, one_chip, batch)
     assert _table_copies(compiled, shape) == []
     assert compiled.memory_analysis().temp_size_in_bytes < ROWS * 384 * 4 / 50
     assert compiled.output_formats[0].layout.major_to_minor == (0, 1)
-    scatters = re.findall(
-        r"= f32\[%d,300\]\S* scatter\(.*" % ROWS, compiled.as_text())
-    assert len(scatters) == 3
-    assert sum("unique_indices=true" in s for s in scatters) == 2
-    assert not any("indices_are_sorted=true" in s for s in scatters)
+    assert _pair_scatters_promise_distinct_rows_only(compiled, ROWS)
     assert "tpu_custom_call" not in compiled.as_text()
+    assert len(_head_adds(compiled, shape)) == 2
+
+
+def _head_adds(compiled, shape):
+    """The dense adds of a table's head in ``compiled``: updates of a
+    slice of a buffer of the table's (a shard's) shape."""
+    return re.findall(r"= f32\[%d,%d\]\S* dynamic-update-slice\(" % shape,
+                      compiled.as_text())
+
+
+@pytest.mark.parametrize("rows", [ROWS, 16_008])
+def test_fused_epoch_on_four_v5e_row_shards_adds_the_head_in_place(topo,
+                                                                   rows):
+    """The epoch on tables row-sharded over a four-chip host (ISSUE 27),
+    with the head's dense add (ISSUE 31): every chip adds to its own
+    rows. No chip gathers another's (no ``all-gather``; the all-reduce of
+    the gathered rows stays the one collective), none copies its shard,
+    and the walk's scatters are what they are on one chip. At 60,002 rows
+    a shard the head lies in shard 0; at 4,002 it ends in shard 2."""
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices), ("mv",))
+    sharded = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec("mv", None))
+    whole = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    per = (rows // 4, 300)
+    compiled = _compiled_epoch(rows, sharded, whole, 1024)
+    text = compiled.as_text()
+    assert "all-gather" not in text and "collective-permute" not in text
+    assert len(re.findall(r"= \(.*\) all-reduce\(", text)) == 1
+    assert _pair_scatters_promise_distinct_rows_only(compiled, per[0])
+    if rows == ROWS:    # a shard of 6 MB is all head, and moved about whole
+        assert _table_copies(compiled, per) == []
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < per[0] * 384 * 4 / 2)
+        assert len(_head_adds(compiled, per)) == 2
 
 
 def test_fused_block_program_on_a_v5e_copies_no_table(one_chip, we300,

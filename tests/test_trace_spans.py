@@ -329,10 +329,12 @@ def test_train_fused_leaves_its_spans_and_counts(mode):
         # only the shared-negatives epoch combines its update rows
         # (ISSUE 28), and only it says how far
         assert ("unique_rows" in a) == ("update_rows" in a) == (
-            mode == "sg_shared")
+            "head_rows" in a) == (mode == "sg_shared")
         if mode == "sg_shared":
             assert a["update_rows"] == 2 * 2 * a["pairs"]
             assert 0 < a["unique_rows"] < a["update_rows"]
+            # a table of 61 rows is all head (ISSUE 31)
+            assert a["head_rows"] == a["unique_rows"]
             # the count before combining is what it was: pool rows too
             assert sum(a["update_rows_by_shard"]) == (
                 a["update_rows"] + 2 * a["batches"] * we.cfg.shared_negatives)
