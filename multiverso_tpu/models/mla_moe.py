@@ -1,0 +1,598 @@
+"""A decoder with latent attention (MLA), routed experts beside a shared
+one, and a multi-token-prediction module, trained through Adam tables.
+
+One chip's share of a deployment in which several chips share each
+layer: this chip holds ``experts_held`` of a layer's ``n_experts`` routed
+experts (``parallel/moe.held_expert_layer``: it routes over all of them
+and computes its own experts' part, no token dropped) and a slice of the
+vocabulary; attention, the shared expert, the router and the norms are
+whole. The equations, for a block with input ``x``:
+
+* ``h = x + Attn(RMSNorm(x))``, ``y = h + F(RMSNorm(h))``; ``F`` is the
+  gated MLP ``(silu(u W_g) * (u W_u)) W_d`` in the leading dense layers
+  and ``Shared(u) + held experts' part`` after them.
+* MLA: ``c_q = RMSNorm(x W_DQ)``, ``q = c_q W_UQ`` -> heads of
+  [nope | rope]; ``[c_kv | k_r] = x W_DKV``, ``c_kv = RMSNorm(c_kv)``,
+  ``[k_n | v] = c_kv W_UKV`` per head; rotary on the query's rope part
+  and on ``k_r``, which every head shares; causal softmax of
+  ``(q . k) / sqrt(nope + rope)``; output ``o W_O``. Keys and values are
+  materialised per head, so the flash kernel computes the core.
+* the prediction module: ``eh_proj([RMSNorm(Emb(t_{i+1})) | RMSNorm(h_i)])``
+  through one block of the expert kind and its own output norm to the
+  SAME head, predicting ``t_{i+2}``; loss ``CE(main) + w * CE(module)``.
+
+Every trained parameter lies in a ``Table`` (:func:`make_tables`);
+:func:`make_train_step` is to this model what
+``models/dlrm.make_train_step`` is to DLRM: one program takes the tables'
+states donated, computes loss and float32 gradients, and hands each
+gradient to its table's updater through ``functional_add``. Matrix
+products take bfloat16 operands (``compute_dtype``) and sum in float32;
+router, softmax, norms, loss and tables are float32.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Dict, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from multiverso_tpu.ops.attention_kernels import flash_attention
+from multiverso_tpu.parallel import moe
+from multiverso_tpu.telemetry import trace as _trace
+from multiverso_tpu.updaters import AddOption
+
+
+class MLAMoEConfig(NamedTuple):
+    vocab: int = 512                 # token ids held here (a slice)
+    dim: int = 64
+    n_heads: int = 2
+    q_lora_rank: int = 24
+    kv_lora_rank: int = 16
+    qk_nope_dim: int = 6
+    qk_rope_dim: int = 2
+    v_head_dim: int = 8
+    rope_theta: float = 1e6
+    dense_ffn: int = 320
+    n_dense_layers: int = 1
+    n_moe_layers: int = 2
+    moe_ffn: int = 48
+    n_experts: int = 16              # the router's outputs
+    experts_held: int = 4
+    expert_offset: int = 0
+    top_k: int = 4
+    routed_scale: float = 1.8
+    n_mtp: int = 1                   # prediction modules (0 or 1)
+    mtp_weight: float = 0.3
+    bias_speed: float = 1e-3
+    eps: float = 1e-5
+    # the attention core: "flash" (the Pallas kernel) or "xla"; ``None``
+    # takes the kernel on a TPU and XLA off it, where the kernel's
+    # interpreter takes minutes a step
+    attn: Optional[str] = None
+    expert_kernel: Optional[str] = None  # parallel/moe.held_expert_layer's
+    attn_block: int = 512            # the flash kernel's q and k blocks
+    loss_chunk: int = 4096           # positions a chunk of the two losses
+    compute_dtype: Any = jnp.bfloat16
+
+
+# The held experts' buffer, in rows, for a layer's ``tokens``: twice what
+# an even router sends here, and never under the floor (the loads of a few
+# hundred tokens swing far more than a batch's), nor over what the routing
+# can send at all. With balanced routers one window in 24 had a batch of
+# 16,384 tokens that passed a buffer of 9,728 rows by 220, where 8,192 is
+# even (PERF.md section 6, PR 33); a row past the buffer is counted
+# (``overflow_rows``) and left out.
+BUFFER_OVER_EVEN, BUFFER_FLOOR = 2, 2048
+
+
+def held(cfg: MLAMoEConfig, tokens: int) -> moe.HeldExperts:
+    """The expert layer's part that lies here, for ``tokens`` a layer."""
+    most = tokens * min(cfg.top_k, cfg.experts_held)
+    even = tokens * cfg.top_k * cfg.experts_held // cfg.n_experts
+    # the grouped products' tile: 512 where every width divides by it
+    # (the published ones do), else the kernel's own 128
+    tile = 512 if cfg.dim % 512 == 0 and cfg.moe_ffn % 512 == 0 else 128
+    return moe.HeldExperts(
+        num_experts=cfg.n_experts, experts_held=cfg.experts_held,
+        expert_offset=cfg.expert_offset, top_k=cfg.top_k,
+        routed_scale=cfg.routed_scale, tile=tile, dtype=cfg.compute_dtype,
+        buffer_rows=min(most, max(BUFFER_OVER_EVEN * even, BUFFER_FLOOR)))
+
+
+def expert_layers(cfg: MLAMoEConfig) -> Tuple[str, ...]:
+    """The layers that have a router, in the order of the bias rows and of
+    the step's counts: the expert layers, then the prediction module."""
+    first = cfg.n_dense_layers
+    return tuple(f"L{i}" for i in range(first, first + cfg.n_moe_layers)) \
+        + (("mtp",) if cfg.n_mtp else ())
+
+
+# ---------------------------------------------------------------------- #
+# parameters
+# ---------------------------------------------------------------------- #
+def _attn_shapes(cfg: MLAMoEConfig) -> Dict[str, Tuple[int, ...]]:
+    d, h = cfg.dim, cfg.n_heads
+    qk = cfg.qk_nope_dim + cfg.qk_rope_dim
+    return {
+        "attn_norm": (d,), "wdq": (d, cfg.q_lora_rank),
+        "q_norm": (cfg.q_lora_rank,), "wuq": (cfg.q_lora_rank, h * qk),
+        # a row an output: [c_kv | k_r] = x W_DKV^T
+        "wdkv": (cfg.kv_lora_rank + cfg.qk_rope_dim, d),
+        "kv_norm": (cfg.kv_lora_rank,),
+        "wukv": (cfg.kv_lora_rank, h * (cfg.qk_nope_dim + cfg.v_head_dim)),
+        "wo": (h * cfg.v_head_dim, d), "ffn_norm": (d,)}
+
+
+def _expert_ffn_shapes(cfg: MLAMoEConfig) -> Dict[str, Tuple[int, ...]]:
+    d, f, e = cfg.dim, cfg.moe_ffn, cfg.experts_held
+    return {"router": (cfg.n_experts, d),        # a row an expert
+            "sg": (d, f), "su": (d, f), "sd": (f, d),
+            "eg": (e, d, f), "eu": (e, d, f), "ed": (e, f, d)}
+
+
+def param_shapes(cfg: MLAMoEConfig) -> Dict[str, Tuple[int, ...]]:
+    """Every trained parameter by name. Layers are ``L<i>.``; the
+    prediction module is ``mtp.``; ``embed`` and ``head`` have a row a
+    token id."""
+    d = cfg.dim
+    out = {"embed": (cfg.vocab, d), "head": (cfg.vocab, d),
+           "final_norm": (d,)}
+    for i in range(cfg.n_dense_layers):
+        block = dict(_attn_shapes(cfg), wg=(d, cfg.dense_ffn),
+                     wu=(d, cfg.dense_ffn), wd=(cfg.dense_ffn, d))
+        out.update({f"L{i}.{k}": v for k, v in block.items()})
+    for name in expert_layers(cfg):
+        block = dict(_attn_shapes(cfg), **_expert_ffn_shapes(cfg))
+        if name == "mtp":
+            block.update(enorm=(d,), hnorm=(d,), eh_proj=(2 * d, d),
+                         out_norm=(d,))
+        out.update({f"{name}.{k}": v for k, v in block.items()})
+    return out
+
+
+def _draw(shape, key, scale: float, norm: bool, pad: int = 0) -> jax.Array:
+    """A parameter's first values: ones for a norm, else Normal(0, scale);
+    ``pad`` zero rows after them (a table's padding)."""
+    x = (jnp.ones(shape, jnp.float32) if norm
+         else scale * jax.random.normal(key, shape, jnp.float32))
+    return jnp.pad(x, [(0, pad)] + [(0, 0)] * (len(shape) - 1))
+
+
+def _keys(cfg: MLAMoEConfig, seed: int):
+    """(name, shape, key) of every parameter, by name; ``seed`` is any
+    whole number (a benchmark's pass 2**31)."""
+    seed = int(seed)
+    # XLA's own bit generator: a table's draw compiles and runs in a
+    # fraction of threefry's time, and one seed still gives one model
+    key = jax.random.fold_in(jax.random.key(seed % (2 ** 31), impl="rbg"),
+                             seed // (2 ** 31))
+    return [(name, shape, jax.random.fold_in(key, i)) for i, (name, shape)
+            in enumerate(sorted(param_shapes(cfg).items()))]
+
+
+def _scale_of(name: str, scale: float,
+              scales: Optional[Dict[str, float]]) -> float:
+    return (scales or {}).get(name.split(".")[-1], scale)
+
+
+def init(cfg: MLAMoEConfig, seed: int = 0, scale: float = 0.02,
+         scales: Optional[Dict[str, float]] = None) -> Dict[str, jax.Array]:
+    """Parameters from ``seed``: Normal(0, scale) matrices, norms of ones;
+    ``scales`` gives a kind of parameter its own scale by the last part of
+    its name (``{"router": 0.01, "embed": 1.0}``). The same values
+    :func:`make_tables` puts into the tables."""
+    return {name: _draw(table_shape(shape), key,
+                        _scale_of(name, scale, scales),
+                        name.endswith("norm")).reshape(shape)
+            for name, shape, key in _keys(cfg, seed)}
+
+
+def init_bias(cfg: MLAMoEConfig) -> jax.Array:
+    """The routers' selection biases, a row a layer of
+    :func:`expert_layers`: not trained, moved by ``moe.bias_update``."""
+    return jnp.zeros((len(expert_layers(cfg)), cfg.n_experts), jnp.float32)
+
+
+def table_shape(shape: Tuple[int, ...]) -> Tuple[int, ...]:
+    """A parameter's shape as its table holds it: the held experts' stack
+    lies as rows of one matrix (a table pads its leading dimension by
+    one, and a ninth expert would be 12%)."""
+    return shape if len(shape) < 3 else (shape[0] * shape[1], shape[2])
+
+
+def make_tables(cfg: MLAMoEConfig, seed: int = 0, scale: float = 0.02,
+                updater: Any = "adam",
+                scales: Optional[Dict[str, float]] = None) -> Dict[str, Any]:
+    """One table a parameter (a ``MatrixTable`` for matrices, whose rows
+    are token ids for ``embed`` and ``head``; an ``ArrayTable`` for a
+    norm), filled on the device with :func:`init`'s values."""
+    import multiverso_tpu as mv
+
+    tables = {}
+    for name, shape, key in _keys(cfg, seed):
+        shape = table_shape(shape)
+        table = (mv.ArrayTable(shape[0], updater=updater, name=name)
+                 if len(shape) == 1 else
+                 mv.MatrixTable(shape[0], shape[1], updater=updater,
+                                name=name))
+        # one program a shape, not one a table
+        draw = jax.jit(_draw, static_argnums=(0, 2, 3, 4),
+                       out_shardings=table.sharding)
+        data = draw(shape, key, _scale_of(name, scale, scales),
+                    name.endswith("norm"), table.padded_shape[0] - shape[0])
+        table.adopt({"data": data, "ustate": table.state["ustate"]})
+        tables[name] = table
+    return tables
+
+
+# ---------------------------------------------------------------------- #
+# layers
+# ---------------------------------------------------------------------- #
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def matmul(x, w, transpose_w: bool, dtype, out_dtype):
+    """``x @ w`` (``x @ w.T`` with ``transpose_w``) with operands in
+    ``dtype`` and a float32 sum, forward and backward; ``w`` is float32
+    (a table's data) and takes a float32 gradient."""
+    return _matmul_fwd(x, w, transpose_w, dtype, out_dtype)[0]
+
+
+def _dot(a, b, contract, out_dtype):
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())),
+        preferred_element_type=jnp.float32).astype(out_dtype)
+
+
+def _matmul_fwd(x, w, transpose_w, dtype, out_dtype):
+    xc, wc = x.astype(dtype), w.astype(dtype)
+    y = _dot(xc, wc, ((x.ndim - 1,), (1 if transpose_w else 0,)), out_dtype)
+    return y, (xc, wc, jnp.zeros((0,), x.dtype))
+
+
+def _matmul_bwd(transpose_w, dtype, out_dtype, res, g):
+    xc, wc, like = res
+    g = g.astype(dtype)
+    lead = tuple(range(xc.ndim - 1))
+    dx = _dot(g, wc, ((g.ndim - 1,), (0 if transpose_w else 1,)), like.dtype)
+    dw = (_dot(g, xc, (lead, lead), jnp.float32) if transpose_w
+          else _dot(xc, g, (lead, lead), jnp.float32))
+    return dx, dw
+
+
+matmul.defvjp(_matmul_fwd, _matmul_bwd)
+
+
+def rms_norm(x, w, eps: float):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def rotary(x, theta: float):
+    """Rotary positions on the last axis of ``x`` [B, S, ..., R], pairing
+    element ``i`` with ``i + R/2``; float32."""
+    s, r = x.shape[1], x.shape[-1]
+    freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None, :]
+    shape = (1, s) + (1,) * (x.ndim - 3) + (r // 2,)
+    cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
+def _xla_attention(q, k, v):
+    """Causal attention over [B, H, S, D] in plain XLA, float32 softmax:
+    the CPU tests' core, and the flash kernel's stand-in off the chip."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) / q.shape[-1] ** 0.5
+    n = q.shape[2]
+    s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e30)
+    p = jax.nn.softmax(s, -1).astype(v.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def mla(u, p, cfg: MLAMoEConfig):
+    """Latent attention on the normed input ``u`` [B, S, D] -> [B, S, D]
+    float32."""
+    b, s, _ = u.shape
+    h, dt = cfg.n_heads, cfg.compute_dtype
+    nope, rope, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    if nope + rope != dv:
+        # the kernel takes one head size; so does the published model
+        raise ValueError("qk_nope_dim + qk_rope_dim must equal v_head_dim")
+    mm = functools.partial(matmul, dtype=dt)
+    with jax.named_scope("mv.lm.attn"):
+        c_q = rms_norm(mm(u, p["wdq"], False, out_dtype=jnp.float32),
+                       p["q_norm"], cfg.eps)
+        q = mm(c_q, p["wuq"], False, out_dtype=jnp.float32)
+        q = q.reshape(b, s, h, nope + rope)
+        down = mm(u, p["wdkv"], True, out_dtype=jnp.float32)
+        c_kv = rms_norm(down[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.eps)
+        k_r = rotary(down[..., cfg.kv_lora_rank:], cfg.rope_theta)
+        kv = mm(c_kv, p["wukv"], False, out_dtype=dt)
+        kv = kv.reshape(b, s, h, nope + dv)
+        q = jnp.concatenate(
+            [q[..., :nope], rotary(q[..., nope:], cfg.rope_theta)], -1)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(
+                k_r[:, :, None, :], (b, s, h, rope)).astype(dt)], -1)
+        heads = lambda t: t.astype(dt).transpose(0, 2, 1, 3)
+        q, k, v = heads(q), heads(k), heads(kv[..., nope:])
+        attn = cfg.attn or (
+            "flash" if jax.devices()[0].platform == "tpu" else "xla")
+        if attn == "flash":
+            blk = min(cfg.attn_block, s)
+            o = flash_attention(q, k, v, True, blk, blk)
+        else:
+            o = _xla_attention(q, k, v)
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
+        return mm(o, p["wo"], False, out_dtype=jnp.float32)
+
+
+def gated_mlp(u, wg, wu, wd, cfg: MLAMoEConfig):
+    mm = functools.partial(matmul, dtype=cfg.compute_dtype)
+    hidden = (jax.nn.silu(mm(u, wg, False, out_dtype=jnp.float32))
+              * mm(u, wu, False, out_dtype=jnp.float32))
+    return mm(hidden, wd, False, out_dtype=jnp.float32)
+
+
+def dense_ffn(u, p, cfg: MLAMoEConfig):
+    with jax.named_scope("mv.lm.dense"):
+        return gated_mlp(u, p["wg"], p["wu"], p["wd"], cfg), None
+
+
+def expert_ffn(u, p, bias, cfg: MLAMoEConfig):
+    """``Shared(u)`` + the held experts' part; aux = (counts [E],
+    overflow_rows)."""
+    b, s, d = u.shape
+    f = cfg.moe_ffn
+    with jax.named_scope("mv.lm.moe.shared"):
+        shared = gated_mlp(u, p["sg"], p["su"], p["sd"], cfg)
+    routed, counts, overflow = moe.held_expert_layer(
+        u.reshape(b * s, d),
+        {"router": p["router"],
+         "w_gate": p["eg"].reshape(cfg.experts_held, d, f),
+         "w_up": p["eu"].reshape(cfg.experts_held, d, f),
+         "w_down": p["ed"].reshape(cfg.experts_held, f, d)},
+        bias, held(cfg, b * s), cfg.expert_kernel)
+    return shared + routed.reshape(b, s, d), (counts, overflow)
+
+
+def block(x, p, attn, ffn, cfg: MLAMoEConfig):
+    """The one block: ``attn`` and ``ffn`` take the normed input and the
+    block's parameters. The dense layer, the expert layers and the
+    prediction module all call it. Returns (y, ffn's aux)."""
+    h = x + attn(rms_norm(x, p["attn_norm"], cfg.eps), p)
+    f, aux = ffn(rms_norm(h, p["ffn_norm"], cfg.eps), p)
+    return h + f, aux
+
+
+def _sub(params: Dict[str, Any], prefix: str) -> Dict[str, Any]:
+    return {k[len(prefix) + 1:]: v for k, v in params.items()
+            if k.startswith(prefix + ".")}
+
+
+def _run_block(x, p, bias, cfg: MLAMoEConfig):
+    """A rematerialised block: dense where ``bias`` is None, else of the
+    expert kind."""
+    attn = lambda u, q: mla(u, q, cfg)
+    if bias is None:
+        ffn = lambda u, q: dense_ffn(u, q, cfg)
+    else:
+        ffn = lambda u, q: expert_ffn(u, q, bias, cfg)
+    return jax.checkpoint(lambda x, p: block(x, p, attn, ffn, cfg))(x, p)
+
+
+def _chunked_ce(h, head, targets, weights, cfg: MLAMoEConfig):
+    """Sum over positions of ``weights * CE(h @ head.T, targets)``, float32,
+    ``loss_chunk`` positions at a time (the whole logits would be
+    positions x vocabulary floats); a chunk's logits are recomputed in the
+    backward pass."""
+    n, d = h.shape
+    chunk = min(cfg.loss_chunk, n)
+    if n % chunk:
+        raise ValueError(f"{n} positions do not divide into chunks of {chunk}")
+
+    @jax.checkpoint
+    def one(head, hc, tc, wc):
+        logits = matmul(hc, head, True, cfg.compute_dtype, jnp.float32)
+        lse = jax.nn.logsumexp(logits, -1)
+        at = jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
+        return jnp.sum(wc * (lse - at))
+
+    def body(total, xs):
+        return total + one(head, *xs), None
+
+    xs = (h.reshape(n // chunk, chunk, d), targets.reshape(-1, chunk),
+          weights.reshape(-1, chunk))
+    return jax.lax.scan(body, jnp.zeros((), jnp.float32), xs)[0]
+
+
+def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
+            tokens: jax.Array, cfg: MLAMoEConfig):
+    """tokens [B, S] -> (loss, (counts [layers, E], overflow [layers])).
+
+    ``CE(main, t_{i+1}) + mtp_weight * CE(module, t_{i+2})``, each a mean
+    over the positions that have a target (S-1 and S-2 a sequence). The
+    module runs on all S positions, so that its attention has the main
+    model's shape; the last, which has no next token, takes the
+    sequence's first in its place and has no target."""
+    b, s = tokens.shape
+    with jax.named_scope("mv.lm.embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+    aux = []
+    for i in range(cfg.n_dense_layers):
+        x, _ = _run_block(x, _sub(params, f"L{i}"), None, cfg)
+    names = expert_layers(cfg)
+    for row, name in enumerate(names):
+        if name == "mtp":
+            continue
+        x, a = _run_block(x, _sub(params, name), bias[row], cfg)
+        aux.append(a)
+    position = jnp.arange(s)[None, :]
+    nxt = jnp.roll(tokens, -1, axis=1)
+    with jax.named_scope("mv.lm.head"):
+        main = _chunked_ce(
+            rms_norm(x, params["final_norm"], cfg.eps).reshape(b * s, -1),
+            params["head"], nxt.reshape(-1),
+            jnp.broadcast_to(position < s - 1, (b, s)).reshape(-1)
+            .astype(jnp.float32), cfg) / (b * (s - 1))
+    loss = main
+    if cfg.n_mtp:
+        with jax.named_scope("mv.lm.mtp"):
+            p = _sub(params, "mtp")
+            joined = jnp.concatenate(
+                [rms_norm(jnp.take(params["embed"], nxt, axis=0),
+                          p["enorm"], cfg.eps),
+                 rms_norm(x, p["hnorm"], cfg.eps)], -1)
+            y = matmul(joined, p["eh_proj"], False, cfg.compute_dtype,
+                       jnp.float32)
+            y, a = _run_block(y, p, bias[len(names) - 1], cfg)
+            aux.append(a)
+            module = _chunked_ce(
+                rms_norm(y, p["out_norm"], cfg.eps).reshape(b * s, -1),
+                params["head"], jnp.roll(tokens, -2, axis=1).reshape(-1),
+                jnp.broadcast_to(position < s - 2, (b, s)).reshape(-1)
+                .astype(jnp.float32), cfg) / (b * (s - 2))
+        loss = main + cfg.mtp_weight * module
+    counts = jnp.stack([c for c, _ in aux])
+    overflow = jnp.stack([o for _, o in aux])
+    return loss, (counts, overflow)
+
+
+# ---------------------------------------------------------------------- #
+# the step through the tables
+# ---------------------------------------------------------------------- #
+def _params_of(states, shapes):
+    out = {}
+    for name, shape in shapes.items():
+        rows = table_shape(shape)[0]
+        out[name] = states[name]["data"][:rows]
+    return out
+
+
+def make_train_step(cfg: MLAMoEConfig, tables: Dict[str, Any],
+                    opt: Optional[AddOption] = None):
+    """``step(states, bias, tokens) -> (states, bias, loss, counts)``.
+
+    ``states`` maps each table's name to its ``program_state()``; jit with
+    ``donate_argnums=(0, 1)``. Reads ``state["data"]``, computes loss and
+    float32 gradients (each block rematerialised, the two losses in
+    chunks of positions), hands each gradient to its table's updater
+    through ``functional_add`` (the learning rate is ``opt``'s), applies
+    the selection biases' rule, and returns the states to be adopted, the
+    new biases, the loss and one int32 array [layers, E + 1]: the tokens
+    that chose each expert, and in the last column the rows that
+    overflowed the held experts' buffer."""
+    shapes = param_shapes(cfg)
+    opt = opt or AddOption(learning_rate=1e-4)
+
+    def step(states, bias, tokens):
+        params = _params_of(states, shapes)
+        (loss, (counts, overflow)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params, bias, tokens, cfg)
+        new = {}
+        for name, table in tables.items():
+            delta = table.pad_delta(grads[name].reshape(
+                table_shape(shapes[name])))
+            new[name] = table.functional_add(states[name], delta, opt)
+        bias = moe.bias_update(bias, counts, cfg.bias_speed)
+        return new, bias, loss, jnp.concatenate(
+            [counts, overflow[:, None]], axis=1)
+
+    return step
+
+
+def make_forward(cfg: MLAMoEConfig):
+    """``forward(states, bias, tokens) -> (loss, counts [layers, E + 1])``
+    on the tables' states, nothing written: what a calibration of the
+    selection biases runs."""
+    shapes = param_shapes(cfg)
+
+    def forward(states, bias, tokens):
+        loss, (counts, overflow) = loss_fn(
+            _params_of(states, shapes), bias, tokens, cfg)
+        return loss, jnp.concatenate([counts, overflow[:, None]], axis=1)
+
+    return forward
+
+
+def routing_counts(counts: np.ndarray, cfg: MLAMoEConfig) -> Dict[str, Any]:
+    """What a step's [layers, E + 1] array says: ``routed_rows`` (token x
+    expert assignments over all layers), ``held_rows`` (those to experts
+    held here), ``overflow_rows``, ``load_max_over_mean`` (the busiest of
+    all E experts over the mean, worst layer) and ``expert_rows`` (the
+    array's [layers][E] part as lists, so that a reader can add steps up
+    before it asks which expert was busiest)."""
+    counts = np.asarray(counts)
+    c = counts[:, :cfg.n_experts]
+    lo = cfg.expert_offset
+    return {"routed_rows": int(c.sum()),
+            "held_rows": int(c[:, lo:lo + cfg.experts_held].sum()),
+            "overflow_rows": int(counts[:, cfg.n_experts].sum()),
+            "load_max_over_mean": float(np.max(c.max(1) / c.mean(1))),
+            "expert_rows": c.tolist()}
+
+
+class Trainer:
+    """The host's side of training through the tables: holds the states
+    between steps (one donated program a step, no table copied) and
+    records each step as an ``lm.step`` span. :meth:`adopt` hands the
+    states back to the tables at the end."""
+
+    def __init__(self, cfg: MLAMoEConfig, tables: Dict[str, Any],
+                 opt: Optional[AddOption] = None,
+                 bias: Optional[jax.Array] = None):
+        self.cfg, self.tables = cfg, tables
+        self.bias = init_bias(cfg) if bias is None else bias
+        self._step = jax.jit(make_train_step(cfg, tables, opt),
+                             donate_argnums=(0, 1))
+        self.states = {n: t.program_state() for n, t in tables.items()}
+        self.steps = 0
+        self._ahead = None      # (loss, counts) of a step not read back yet
+
+    def _turn(self, tokens, ahead: bool):
+        """Queue a step on ``tokens`` (where given), then read back the
+        step that is due: this one, or with ``ahead`` the one before it."""
+        self.steps += tokens is not None
+        with _trace.span("lm.step", request=self.steps) as sp:
+            due = self._ahead
+            if tokens is not None:
+                sp.set(tokens=int(np.prod(tokens.shape)))
+                self.states, self.bias, loss, counts = self._step(
+                    self.states, self.bias, tokens)
+                due, self._ahead = ((due, (loss, counts)) if ahead
+                                    else ((loss, counts), None))
+            else:
+                self._ahead = None
+            if due is None:
+                return None
+            with _trace.span("lm.step.wait"):
+                # one read-back a step: it waits for the whole program
+                loss, counts = jax.device_get(due)
+            sp.set(**routing_counts(counts, self.cfg))
+        return float(loss), counts
+
+    def step(self, tokens) -> Tuple[float, np.ndarray]:
+        """One step on ``tokens`` [B, S]; returns its (loss, counts)."""
+        if self._ahead is not None:
+            raise RuntimeError("a step is still ahead: drain() first")
+        return self._turn(tokens, ahead=False)
+
+    def step_ahead(self, tokens) -> Optional[Tuple[float, np.ndarray]]:
+        """Queue a step on ``tokens`` and read back the step BEFORE it
+        (``None`` the first time): the device has the next program while
+        the host reads the last one's loss, so a stall of the host costs
+        the device nothing. :meth:`drain` reads the last step back."""
+        return self._turn(tokens, ahead=True)
+
+    def drain(self) -> Optional[Tuple[float, np.ndarray]]:
+        return self._turn(None, ahead=True)
+
+    def adopt(self) -> None:
+        """Hand the states back to their tables (end of training)."""
+        self.drain()
+        for name, table in self.tables.items():
+            table.adopt(self.states[name])

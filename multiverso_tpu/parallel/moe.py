@@ -18,15 +18,28 @@ Design choices, TPU-first:
   load-balance loss returned to the caller.
 * One ``all_to_all`` out, one back; expert compute is a single batched
   einsum over the local experts — MXU-shaped, no scalar loops.
+
+The second layer here, :func:`held_expert_layer`, is one chip's share of
+a layer whose experts are divided over several chips: it is told which
+experts it holds (``experts_held``, ``expert_offset``), routes over all
+of them (sigmoid scores, a selection bias that takes no gradient), and
+computes its own experts' part of the result without dropping a token.
+The rows routed here lie sorted by expert in one buffer, and the three
+products run over that buffer as grouped matrix products (Pallas
+``megablox``), so their cost follows the buffer's length and not the
+busiest expert. On one chip it runs without its exchange.
 """
 
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Optional, Tuple
+import functools
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _gmm
+from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm as _tgmm
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from multiverso_tpu.zoo import Zoo
@@ -196,3 +209,150 @@ def moe_layer(x: jax.Array, params: Dict, cfg: MoEConfig,
         out_specs=(xspec, P(), P()), check_vma=False)(
             x, params["w1"], params["w2"], params["router"])
     return y, aux, dropped
+
+
+# ---------------------------------------------------------------------- #
+# one chip's share of an expert layer: no capacity, no dropped token
+# ---------------------------------------------------------------------- #
+class HeldExperts(NamedTuple):
+    """Which part of an expert layer lies here. ``buffer_rows`` is the
+    static length of the sorted buffer the held experts compute over;
+    ``None`` sizes it for the most the routing can send (every token to
+    ``min(top_k, experts_held)`` held experts), which cannot overflow."""
+    num_experts: int             # the router's outputs: every expert
+    experts_held: int
+    expert_offset: int = 0
+    top_k: int = 1
+    routed_scale: float = 1.0
+    buffer_rows: Optional[int] = None
+    tile: int = 128              # the grouped products' tile (m, k, n)
+    dtype: Any = jnp.bfloat16    # the products' operands (float32 sums)
+
+
+def sigmoid_route(u: jax.Array, router: jax.Array, bias: jax.Array,
+                  cfg: HeldExperts):
+    """Scores ``sigmoid(u W_r)`` in float32 over every expert (``router``
+    is [E, D]: a row an expert); the ``top_k`` largest of ``score + bias``
+    are chosen, gates are the chosen scores over their sum, times
+    ``routed_scale``. ``bias`` only selects: it is in no gate and takes no
+    gradient. Returns (chosen [T, K], gates [T, K] float32, counts [E]
+    int32: the tokens that chose each expert)."""
+    with jax.named_scope("mv.lm.moe.route"):
+        logits = jax.lax.dot_general(
+            u.astype(jnp.float32), router.astype(jnp.float32),
+            (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(
+            scores + jax.lax.stop_gradient(bias)[None, :], cfg.top_k)
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        gates = cfg.routed_scale * picked / picked.sum(-1, keepdims=True)
+        counts = (chosen[..., None] == jnp.arange(cfg.num_experts)).sum(
+            (0, 1), dtype=jnp.int32)
+    return chosen, gates, counts
+
+
+def bias_update(bias: jax.Array, counts: jax.Array, speed) -> jax.Array:
+    """The selection bias's rule: ``b_e += speed * sign(mean(c) - c_e)``
+    (an expert under the mean load is made likelier, one over it less)."""
+    c = counts.astype(jnp.float32)
+    return bias + speed * jnp.sign(c.mean(-1, keepdims=True) - c)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def grouped_matmul(lhs, rhs, group_sizes, tile: int, interpret: bool,
+                   dtype=jnp.bfloat16):
+    """``lhs[rows of group g] @ rhs[g]`` for consecutive row groups of
+    ``group_sizes`` (they sum to ``lhs.shape[0]``) as one Pallas kernel
+    (``megablox``): operands and result in ``dtype`` (``lhs`` comes in
+    it), float32 accumulation. ``rhs`` is float32 [G, K, N] (a table's
+    data) and takes a float32 gradient."""
+    return _gmm_fwd(lhs, rhs, group_sizes, tile, interpret, dtype)[0]
+
+
+def _gmm_fwd(lhs, rhs, group_sizes, tile, interpret, dtype):
+    rhs = rhs.astype(dtype)
+    out = _gmm(lhs, rhs, group_sizes, dtype, (tile, tile, tile),
+               interpret=interpret)
+    return out, (lhs, rhs, group_sizes)
+
+
+def _gmm_bwd(tile, interpret, dtype, res, g):
+    lhs, rhs, group_sizes = res
+    g = g.astype(dtype)
+    tiling = (tile, tile, tile)
+    d_lhs = _gmm(g, rhs, group_sizes, dtype, tiling, transpose_rhs=True,
+                 interpret=interpret)
+    d_rhs = _tgmm(lhs.swapaxes(0, 1), g, group_sizes, jnp.float32, tiling,
+                  num_actual_groups=rhs.shape[0], interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def _grouped_matmul_xla(lhs, rhs, group_sizes, dtype):
+    """The same product off the chip, where the kernel's interpreter
+    takes seconds a call: XLA's own ``ragged_dot``."""
+    return jax.lax.ragged_dot(lhs, rhs.astype(dtype), group_sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(dtype)
+
+
+def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
+                      cfg: HeldExperts, kernel: Optional[str] = None):
+    """The routed part of an expert layer that this chip computes.
+
+    ``u`` [T, D]; ``params``: ``router`` [E, D], ``w_gate`` / ``w_up``
+    [H, D, F] and ``w_down`` [H, F, D] for the H held experts (numbers
+    ``expert_offset`` to ``expert_offset + H - 1`` of the E). Returns
+    (``sum over the chosen experts held here of gate * expert(u)`` [T, D]
+    float32, counts [E] int32, overflow_rows int32). What the absent
+    experts would add is left out. The rows routed here are sorted by
+    expert into a buffer of ``buffer_rows`` rows; the padding after them
+    is zero rows that the last expert's group takes, so the products do
+    the same work whatever the routing. ``overflow_rows`` counts rows
+    that did not fit the buffer and were left out: 0 unless the buffer
+    was sized under the load (it cannot be with ``buffer_rows=None``).
+    ``kernel``: ``"pallas"`` (the chip's default), ``"interpret"`` (the
+    same kernel in Pallas's interpreter) or ``"xla"`` (``ragged_dot``, the
+    default off the chip)."""
+    t, d = u.shape
+    held, k = cfg.experts_held, cfg.top_k
+    if kernel is None:
+        kernel = "pallas" if jax.devices()[0].platform == "tpu" else "xla"
+    chosen, gates, counts = sigmoid_route(u, params["router"], bias, cfg)
+    rows = cfg.buffer_rows or t * min(k, held)
+    rows = -(-rows // cfg.tile) * cfg.tile
+    with jax.named_scope("mv.lm.moe.dispatch"):
+        local = chosen.reshape(-1) - cfg.expert_offset          # [T*K]
+        here = (local >= 0) & (local < held)
+        # held assignments first, grouped by expert, in token order
+        order = jnp.argsort(jnp.where(here, local, held), stable=True)
+        take = order[:rows] if rows <= t * k else jnp.pad(
+            order, (0, rows - t * k))
+        sizes = jax.lax.dynamic_slice(counts, (cfg.expert_offset,), (held,))
+        ends = jnp.minimum(jnp.cumsum(sizes), rows)
+        held_rows = ends[-1]
+        overflow = sizes.sum() - held_rows
+        # the padding rows go to the last group: the grid is the same
+        # whatever the routing
+        groups = jnp.diff(ends, prepend=0).at[-1].add(rows - held_rows)
+        live = jnp.arange(rows) < held_rows
+        token = take // k
+        x = jnp.where(live[:, None], u.astype(cfg.dtype)[token], 0)
+        gate = jnp.where(live, gates.reshape(-1)[take], 0.0)
+    with jax.named_scope("mv.lm.moe.experts"):
+        if kernel == "xla":
+            mm = functools.partial(_grouped_matmul_xla, group_sizes=groups,
+                                   dtype=cfg.dtype)
+        else:
+            mm = functools.partial(grouped_matmul, group_sizes=groups,
+                                   tile=cfg.tile, dtype=cfg.dtype,
+                                   interpret=kernel == "interpret")
+        h = (jax.nn.silu(mm(x, params["w_gate"]).astype(jnp.float32))
+             * mm(x, params["w_up"]).astype(jnp.float32))
+        y = mm(h.astype(cfg.dtype), params["w_down"])
+    with jax.named_scope("mv.lm.moe.combine"):
+        out = jnp.zeros((t, d), jnp.float32).at[token].add(
+            y.astype(jnp.float32) * gate[:, None])
+    return out, counts, overflow

@@ -522,3 +522,43 @@ def test_fused_block_program_on_a_v5e_copies_no_table(one_chip, we300,
     assert [f.layout.major_to_minor for f in
             (compiled.output_formats[0], compiled.output_formats[2])] == [
                 (0, 1), (0, 1)]
+
+
+def test_language_model_kernels_compile_for_a_v5e_at_published_widths(
+        one_chip):
+    """The flash kernel at head size 256 over 8,192 positions, forward
+    and backward, and the held experts' grouped products over a
+    16,384-row buffer (2,048 x 1,536, eight groups), as
+    ``glm47f-train-8k`` calls them: the chip's compiler takes them, and
+    the kernels are in the program."""
+    from multiverso_tpu.ops import attention_kernels
+    from multiverso_tpu.parallel import moe
+
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+
+    def core(q, k, v):
+        return attention_kernels.flash_attention(
+            q, k, v, True, 512, 512, False).astype(jnp.float32).sum()
+
+    qkv = shape((2, 20, 8192, 256), jnp.bfloat16)
+    text = jax.jit(jax.grad(core, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3      # forward, dQ, dK with dV
+
+    held = moe.HeldExperts(num_experts=64, experts_held=8, top_k=4,
+                           routed_scale=1.8, buffer_rows=16384, tile=512)
+
+    def experts(u, router, wg, wu, wd):
+        out, counts, overflow = moe.held_expert_layer(
+            u, {"router": router, "w_gate": wg, "w_up": wu, "w_down": wd},
+            jnp.zeros((64,)), held, kernel="pallas")
+        return out.sum(), (counts, overflow)
+
+    args = (shape((16384, 2048), jnp.float32), shape((64, 2048), jnp.float32),
+            shape((8, 2048, 1536), jnp.float32),
+            shape((8, 2048, 1536), jnp.float32),
+            shape((8, 1536, 2048), jnp.float32))
+    compiled = jax.jit(jax.grad(experts, argnums=(0, 2, 3, 4),
+                                has_aux=True)).lower(*args).compile()
+    # three products forward, and for each the input's and the weight's
+    assert compiled.as_text().count("tpu_custom_call") >= 9

@@ -493,6 +493,44 @@ def test_dlrm_step_carries_dlrm_and_rule_scopes():
             "mv.rowapply.rule"} <= found
 
 
+def _tiny_lm():
+    from multiverso_tpu.models import mla_moe
+    mv.init()
+    cfg = mla_moe.MLAMoEConfig(vocab=64, n_moe_layers=1, attn="xla",
+                               loss_chunk=32, compute_dtype=jnp.float32)
+    tables = mla_moe.make_tables(cfg, 0, 0.1, updater="adam")
+    tokens = jax.random.randint(jax.random.key(0), (2, 32), 0, cfg.vocab)
+    return mla_moe, cfg, tables, tokens
+
+
+def test_lm_step_carries_its_counts():
+    mla_moe, cfg, tables, tokens = _tiny_lm()
+    trainer = mla_moe.Trainer(cfg, tables)
+    before = len(ttrace.events())
+    _, counts = trainer.step(tokens)
+    trainer.adopt()
+    events = ttrace.events()[before:]
+    step = next(e for e in events if e["name"] == "lm.step")
+    wait = next(e for e in events if e["name"] == "lm.step.wait")
+    assert wait["parent"] == step["id"] and step["request"] == 1
+    layers = len(mla_moe.expert_layers(cfg))
+    want = mla_moe.routing_counts(counts, cfg)
+    assert step["args"] == dict(want, tokens=64)
+    assert want["routed_rows"] == layers * 64 * cfg.top_k
+    assert 0 <= want["held_rows"] <= want["routed_rows"]
+    assert want["overflow_rows"] == 0 and want["load_max_over_mean"] >= 1.0
+
+
+def test_lm_step_carries_lm_and_rule_scopes():
+    mla_moe, cfg, tables, tokens = _tiny_lm()
+    states = {n: t.state for n, t in tables.items()}
+    found = _scopes(mla_moe.make_train_step(cfg, tables), states,
+                    mla_moe.init_bias(cfg), tokens)
+    assert {"mv.lm.attn", "mv.lm.dense", "mv.lm.moe.route",
+            "mv.lm.moe.experts", "mv.lm.moe.shared", "mv.lm.head",
+            "mv.lm.mtp", "mv.rowapply.rule"} <= found
+
+
 @pytest.mark.parametrize("updater", ["default", "sgd", "momentum_sgd",
                                      "adagrad", "adam", "ftrl"])
 def test_every_updater_rule_is_scoped_and_unchanged(updater):
