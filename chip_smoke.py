@@ -341,16 +341,18 @@ def stage_ps(rows: int = 100_000, cols: int = 128, batch: int = 4096,
     return out
 
 
-def _attention_errors(shape: Tuple[int, int, int, int], block: int,
+def _attention_errors(shape: Tuple[int, int, int, int], block,
                       seed: int) -> Dict[str, float]:
     """flash_attention forward and gradients vs reference_attention run in
-    f32 on the same bf16 inputs: max|err| / max|reference| per tensor."""
+    f32 on the same bf16 inputs: max|err| / max|reference| per tensor.
+    ``block`` is the q and k block, or the pair of them."""
     import jax
     import jax.numpy as jnp
 
     from multiverso_tpu.ops.attention_kernels import flash_attention
     from multiverso_tpu.parallel.ring import reference_attention
 
+    blocks = block if isinstance(block, tuple) else (block, block)
     rng = np.random.default_rng(seed)
     q, k, v, g = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
                   for _ in range(4))
@@ -360,7 +362,7 @@ def _attention_errors(shape: Tuple[int, int, int, int], block: int,
         return (out,) + vjp(g.astype(out.dtype))
 
     got = jax.jit(lambda q, k, v: fwd_and_grads(
-        lambda q, k, v: flash_attention(q, k, v, True, block, block),
+        lambda q, k, v: flash_attention(q, k, v, True, *blocks),
         q, k, v))(q, k, v)
     f32 = lambda t: t.astype(jnp.float32)
     with jax.default_matmul_precision("highest"):
@@ -384,7 +386,13 @@ def stage_lm(vocab: int = 32768, dim: int = 2048, heads: int = 16,
              layers: int = 8, seq: int = 1024, batch_per_chip: int = 2,
              kernel_shapes: Tuple = (((2, 16, 1024, 128), 512),
                                      ((2, 16, 1024, 128), 128),
-                                     ((8, 8, 512, 32), 512)),
+                                     ((8, 8, 512, 32), 512),
+                                     # glm47f-train-8k's head and length,
+                                     # square and as mla_moe.attn_blocks
+                                     # has it; four heads, so that the
+                                     # reference's float32 [S, S] fits
+                                     ((1, 4, 8192, 256), 512),
+                                     ((1, 4, 8192, 256), (512, 1024))),
              chip: bool = True) -> Dict[str, Any]:
     """The widest model the repo runs (472M, d2048/L8, bf16) with the
     Pallas flash kernel: three donated train steps on a fixed batch, then

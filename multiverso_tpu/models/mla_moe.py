@@ -39,7 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from multiverso_tpu.ops.attention_kernels import flash_attention
+from multiverso_tpu.ops.attention_kernels import (causal_pairs,
+                                                   flash_attention)
 from multiverso_tpu.parallel import moe
 from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.updaters import AddOption
@@ -73,7 +74,7 @@ class MLAMoEConfig(NamedTuple):
     # interpreter takes minutes a step
     attn: Optional[str] = None
     expert_kernel: Optional[str] = None  # parallel/moe.held_expert_layer's
-    attn_block: int = 512            # the flash kernel's q and k blocks
+    attn_block: int = 512            # the flash kernel's q block (attn_blocks)
     loss_chunk: int = 4096           # positions a chunk of the two losses
     compute_dtype: Any = jnp.bfloat16
 
@@ -281,6 +282,35 @@ def rotary(x, theta: float):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
+def attn_core(cfg: MLAMoEConfig) -> str:
+    """The attention core that runs: ``cfg.attn``, or by the device."""
+    return cfg.attn or (
+        "flash" if jax.devices()[0].platform == "tpu" else "xla")
+
+
+def attn_blocks(cfg: MLAMoEConfig, s: int) -> Tuple[int, int]:
+    """The flash kernel's (q, k) blocks over ``s`` positions: ``attn_block``
+    rows of q, and twice as many of k where a k block of at most 1,024
+    rows at a head size of at most 256 divides ``s`` (what fits the
+    kernels' VMEM: 1,024 x 1,024 and 512 x 2,048 do not). At (8192, 256)
+    on a v5e the forward, dQ and dK/dV kernels read 13.3 / 13.9 / 17.9 ms
+    a call at 512 x 512, 12.0 / 13.4 / 17.7 at 1,024 x 512 and 10.9 / 13.4 /
+    17.6 at 512 x 1,024: an accumulator is rescaled once a k block."""
+    bq = min(cfg.attn_block, s)
+    wide = 2 * bq <= 1024 and cfg.v_head_dim <= 256 and s % (2 * bq) == 0
+    return bq, 2 * bq if wide else bq
+
+
+def attn_grid(cfg: MLAMoEConfig, s: int) -> Dict[str, int]:
+    """What one flash kernel call over ``s`` positions does a (batch x
+    head), as ``lm.step`` spans carry it; nothing where XLA is the core."""
+    if attn_core(cfg) != "flash":
+        return {}
+    n = causal_pairs(s, *attn_blocks(cfg, s))
+    return {"attn_grid_steps": n["grid_steps"], "attn_pairs_live": n["live"],
+            "attn_pairs_masked": n["masked"]}
+
+
 def _xla_attention(q, k, v):
     """Causal attention over [B, H, S, D] in plain XLA, float32 softmax:
     the CPU tests' core, and the flash kernel's stand-in off the chip."""
@@ -320,11 +350,8 @@ def mla(u, p, cfg: MLAMoEConfig):
                 k_r[:, :, None, :], (b, s, h, rope)).astype(dt)], -1)
         heads = lambda t: t.astype(dt).transpose(0, 2, 1, 3)
         q, k, v = heads(q), heads(k), heads(kv[..., nope:])
-        attn = cfg.attn or (
-            "flash" if jax.devices()[0].platform == "tpu" else "xla")
-        if attn == "flash":
-            blk = min(cfg.attn_block, s)
-            o = flash_attention(q, k, v, True, blk, blk)
+        if attn_core(cfg) == "flash":
+            o = flash_attention(q, k, v, True, *attn_blocks(cfg, s))
         else:
             o = _xla_attention(q, k, v)
         o = o.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
@@ -552,6 +579,7 @@ class Trainer:
         self.states = {n: t.program_state() for n, t in tables.items()}
         self.steps = 0
         self._ahead = None      # (loss, counts) of a step not read back yet
+        self._attn: Dict[str, int] = {}     # attn_grid of the first step
 
     def _turn(self, tokens, ahead: bool):
         """Queue a step on ``tokens`` (where given), then read back the
@@ -560,6 +588,8 @@ class Trainer:
         with _trace.span("lm.step", request=self.steps) as sp:
             due = self._ahead
             if tokens is not None:
+                if self.steps == 1:     # one program, one shape
+                    self._attn = attn_grid(self.cfg, int(tokens.shape[1]))
                 sp.set(tokens=int(np.prod(tokens.shape)))
                 self.states, self.bias, loss, counts = self._step(
                     self.states, self.bias, tokens)
@@ -567,6 +597,7 @@ class Trainer:
                                     else ((loss, counts), None))
             else:
                 self._ahead = None
+            sp.set(**self._attn)
             if due is None:
                 return None
             with _trace.span("lm.step.wait"):

@@ -3,38 +3,48 @@
 Single-chip counterpart of the cross-chip schemes in parallel/ring.py (the
 reference framework predates attention entirely — SURVEY §5 "long-context:
 absent"). The kernel never materializes the [S, S] score matrix: the grid
-walks (batch*heads, q_blocks, k_blocks) with the k dimension innermost and
-sequential, carrying the online-softmax state (running max ``m``, denominator
-``l``, f32 accumulator) in VMEM scratch that persists across the k steps —
-the same math as ``ring._ring_attention_local`` with ppermute hops replaced
-by grid steps over HBM-resident K/V blocks.
+walks (batch*heads, then the (q block, k block) pairs with the k block
+innermost and sequential), carrying the online-softmax state (running max
+``m``, denominator ``l``, f32 accumulator) in VMEM scratch that persists
+across the k steps — the same math as ``ring._ring_attention_local`` with
+ppermute hops replaced by grid steps over HBM-resident K/V blocks.
 
 MXU/VPU notes: both matmuls (q@k^T, p@v) run on the MXU in the input dtype
 with f32 accumulation (``preferred_element_type``); masking, exp and the
-rescale are VPU elementwise ops on (block_q, block_k) tiles. Causal blocks
-strictly above the diagonal skip their compute with ``pl.when`` (the
-block pipeline still streams those K/V blocks — only the MXU/VPU work is
-saved).
+rescale are VPU elementwise ops on (block_q, block_k) tiles. The causal
+structure is known before the kernel runs, and the grid uses it: a causal
+call walks a table of the live pairs only (:func:`live_pairs`, read by the
+index maps as scalar-prefetch operands), so a block strictly above the
+diagonal costs no grid step and no K/V copy, and only a pair the diagonal
+crosses builds and applies the mask; a pair under it runs the same tile
+function without. A non-causal call walks the whole rectangle, unmasked.
 
 The backward pass is Pallas too (FlashAttention-2 style): the forward
 additionally emits the per-row logsumexp, and two blockwise kernels
 recompute ``p = exp(s - lse)`` tile by tile — one walking k-blocks
 innermost to accumulate dQ, one walking q-blocks innermost to accumulate
-dK/dV — so the [S, S] score matrix is never materialized in either
-direction. Measured in rounds 3-5 on the 472M LM bench (b=2, s=1024;
-not re-measured since): full-XLA attention 70 ms/step, Pallas fwd +
-XLA-recompute bwd ~61 ms, Pallas fwd+bwd 57.5 ms at the default 128x128
-blocks, and 47-54 ms with the 512x512 blocks the transformer model now
-auto-selects — in total 97 -> 113-124 whole-model TFLOP/s.
+dK/dV (its table is column-major) — so the [S, S] score matrix is never
+materialized in either direction.
+
+Measured on one v5e chip at (B x H, S, D) = (40, 8192, 256), bfloat16,
+causal, milliseconds a call, forward / dQ / dK with dV (builder's chip
+run, PR 34; PERF.md section 6): the whole rectangle with dead steps
+skipped by ``pl.when`` and every live pair masked (the parent) 17.0 /
+17.7 / 24.1 at 512 x 512 blocks; this table of live pairs 13.3 / 13.9 /
+17.9 at 512 x 512 (bit for bit the parent's results), 12.0 / 13.4 / 17.7
+at 1,024 x 512 and 10.9 / 13.4 / 17.6 at 512 x 1,024; the rectangle with
+a dead step's index maps clamped to the resident block 14.6 / 15.4 / 17.9.
+1,024 x 1,024 and 512 x 2,048 do not fit the kernels' VMEM.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -43,47 +53,171 @@ _RES_LANES = 8    # lse residual lane width (smallest legal TPU tile)
 _NEG_INF = -1e30
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, *refs,
-                  scale: float, causal: bool, block_q: int, block_k: int,
-                  nk: int, emit_lse: bool):
-    if emit_lse:
-        o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
-    else:   # inference-only call: skip the residual's VPU work + HBM write
-        (o_ref, m_ref, l_ref, acc_ref), lse_ref = refs, None
-    i = pl.program_id(1)
-    j = pl.program_id(2)
+def _blocks(s: int, block_q: int, block_k: int) -> Tuple[int, int]:
+    """The block sizes a call over ``s`` positions runs with: clamped to
+    ``s``, and dividing it."""
+    block_q, block_k = min(block_q, s), min(block_k, s)
+    if s % block_q or s % block_k:
+        raise ValueError(f"seq len {s} not divisible by blocks "
+                         f"({block_q}, {block_k})")
+    return block_q, block_k
 
-    @pl.when(j == 0)
+
+def live_pairs(s: int, block_q: int, block_k: int, q_inner: bool = False
+               ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The (q block, k block) pairs of a causal call that hold a position
+    at or under the diagonal, in the order a kernel walks them: row-major
+    (k innermost: forward and dQ) or, with ``q_inner``, column-major
+    (dK with dV). Returns ``(qi, kj, crossing)``; a pair is *crossing*
+    when the diagonal passes through it and *interior* (no mask needed)
+    when its last k position is at or before its first q position."""
+    block_q, block_k = _blocks(s, block_q, block_k)
+    i, j = np.indices((s // block_q, s // block_k), dtype=np.int32)
+    if q_inner:
+        i, j = i.T, j.T
+    live = j * block_k <= i * block_q + block_q - 1
+    i, j = i[live], j[live]
+    return i, j, j * block_k + block_k - 1 > i * block_q
+
+
+def causal_pairs(s: int, block_q: int, block_k: int) -> Dict[str, int]:
+    """What ONE causal kernel call does for one (batch x head): the grid
+    steps it takes, the pairs among them that compute, and those of them
+    that take the masked path."""
+    _, _, crossing = live_pairs(s, block_q, block_k)
+    return {"grid_steps": int(crossing.size), "live": int(crossing.size),
+            "masked": int(crossing.sum())}
+
+
+class _Walk(NamedTuple):
+    """How a kernel's grid visits the (q block i, k block j) pairs. A
+    causal call walks the table of :func:`live_pairs`, handed to the
+    kernel as two scalar-prefetch operands (grid ``(bh, pairs)``): no grid
+    step and no block copy for a pair above the diagonal. Any other call
+    walks the rectangle. Either way the inner index is the accumulator's:
+    k for forward and dQ, q (``q_inner``) for dK with dV."""
+    causal: bool
+    s: int
+    block_q: int
+    block_k: int
+    q_inner: bool
+
+    @property
+    def nq(self) -> int:
+        return self.s // self.block_q
+
+    @property
+    def nk(self) -> int:
+        return self.s // self.block_k
+
+    def specs(self, d: int) -> Tuple[pl.BlockSpec, ...]:
+        """The block specs of a q-shaped operand, a k-shaped one and the
+        logsumexp residual, at this walk's (q block, k block)."""
+        def spec(rows, lanes, at):      # at(b, i, j) -> block index
+            if self.causal:
+                index = lambda b, t, qi, kj: at(b, qi[t], kj[t])
+            elif self.q_inner:
+                index = lambda b, j, i: at(b, i, j)
+            else:
+                index = at
+            return pl.BlockSpec((1, rows, lanes), index)
+
+        of_q, of_k = (lambda b, i, j: (b, i, 0)), (lambda b, i, j: (b, j, 0))
+        return (spec(self.block_q, d, of_q), spec(self.block_k, d, of_k),
+                spec(self.block_q, _RES_LANES, of_q))
+
+    def call(self, kernel, bh: int, operands, *, interpret: bool,
+             out_shape, **specs):
+        """``pl.pallas_call`` of ``kernel`` over ``bh`` (batch x head)s of
+        this walk; ``specs`` are the grid spec's in, out and scratch."""
+        if self.causal:
+            table = live_pairs(self.s, self.block_q, self.block_k,
+                               self.q_inner)[:2]
+            grid, inner = (bh, len(table[0])), ("arbitrary",)
+        else:
+            table = ()
+            grid = ((bh, self.nk, self.nq) if self.q_inner
+                    else (bh, self.nq, self.nk))
+            inner = ("parallel", "arbitrary")
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(table), grid=grid, **specs),
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",) + inner),
+            interpret=interpret,
+        )(*table, *operands)
+
+    def enter(self, refs):
+        """In the kernel: ``(i, j, first, last, crossing, refs)`` of this
+        grid step, ``refs`` without the table's. ``first`` / ``last`` say
+        whether the pair opens / closes its accumulator's row (column for
+        ``q_inner``): scalar arithmetic on the pair. ``crossing`` is
+        ``None`` where nothing is masked at all."""
+        bq, bk = self.block_q, self.block_k
+        if self.causal:
+            t = pl.program_id(1)
+            i, j, refs = refs[0][t], refs[1][t], refs[2:]
+            crossing = j * bk + bk - 1 > i * bq
+        else:
+            x, y = pl.program_id(1), pl.program_id(2)
+            (i, j), crossing = ((y, x) if self.q_inner else (x, y)), None
+        if self.q_inner:
+            lo = jax.lax.div(j * bk, bq) if self.causal else 0
+            return i, j, i == lo, i == self.nq - 1, crossing, refs
+        hi = self.nk - 1
+        if self.causal:
+            hi = jnp.minimum(hi, jax.lax.div(i * bq + bq - 1, bk))
+        return i, j, j == 0, j == hi, crossing, refs
+
+
+def _masked_or_not(crossing, tile: Callable[[bool], None]) -> None:
+    """Run ``tile(masked)``: masked on a pair the diagonal crosses, plain
+    on one under it."""
+    if crossing is None:
+        tile(False)
+        return
+    pl.when(crossing)(lambda: tile(True))
+    pl.when(jnp.logical_not(crossing))(lambda: tile(False))
+
+
+def _causal_mask(s, i, j, block_q: int, block_k: int):
+    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + i * block_q
+    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * block_k
+    return jnp.where(qpos >= kpos, s, _NEG_INF)
+
+
+def _flash_kernel(*refs, walk: _Walk, scale: float, emit_lse: bool):
+    i, j, first, last, crossing, refs = walk.enter(refs)
+    q_ref, k_ref, v_ref = refs[:3]
+    if emit_lse:
+        o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[3:]
+    else:   # inference-only call: skip the residual's VPU work + HBM write
+        (o_ref, m_ref, l_ref, acc_ref), lse_ref = refs[3:], None
+
+    @pl.when(first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # causal: the whole block is masked iff its first k position exceeds
-    # the last q position of this q block
-    live = (j * block_k <= i * block_q + block_q - 1) if causal else True
-
-    @pl.when(live)
-    def _step():
+    def tile(masked: bool):
         qb = q_ref[0]                                     # (bq, d)
         kb = k_ref[0]                                     # (bk, d)
         vb = v_ref[0]
         s = jax.lax.dot_general(
             qb, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (bq, bk)
-        if causal:
-            qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-                + i * block_q
-            kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
-                + j * block_k
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
+        if masked:
+            s = _causal_mask(s, i, j, walk.block_q, walk.block_k)
         m_prev = m_ref[...][:, :1]                        # (bq, 1)
         l_prev = l_ref[...][:, :1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_next = jnp.maximum(m_prev, m_cur)
         corr = jnp.exp(m_prev - m_next)
         p = jnp.exp(s - m_next)
-        if causal:
+        if masked:
             # rows whose every position is masked would get exp(-inf-(-inf))
             p = jnp.where(s > _NEG_INF / 2, p, 0.0)
         l_next = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
@@ -94,7 +228,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs,
         m_ref[...] = jnp.broadcast_to(m_next, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_next, l_ref.shape)
 
-    @pl.when(j == nk - 1)
+    _masked_or_not(crossing, tile)
+
+    @pl.when(last)
     def _emit():
         l = l_ref[...][:, :1]
         l = jnp.where(l == 0.0, 1.0, l)
@@ -110,48 +246,31 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs,
 def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
                    interpret: bool, with_lse: bool):
     b, h, s, d = q.shape
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
-    if s % block_q or s % block_k:
-        raise ValueError(f"seq len {s} not divisible by blocks "
-                         f"({block_q}, {block_k})")
-    bh, nq, nk = b * h, s // block_q, s // block_k
-    scale = 1.0 / (d ** 0.5)
+    block_q, block_k = _blocks(s, block_q, block_k)
+    bh = b * h
+    walk = _Walk(causal, s, block_q, block_k, False)
     flat = lambda t: t.reshape(bh, s, d)
-
-    kernel = functools.partial(
-        _flash_kernel, scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, nk=nk, emit_lse=with_lse)
-    ospec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+    qspec, kspec, lspec = walk.specs(d)
     oshape = jax.ShapeDtypeStruct((bh, s, d), q.dtype)
-    lspec = pl.BlockSpec((1, block_q, _RES_LANES),
-                         lambda b, i, j: (b, i, 0))
     lshape = jax.ShapeDtypeStruct((bh, s, _RES_LANES), jnp.float32)
-    res = pl.pallas_call(
-        kernel,
-        grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=[ospec, lspec] if with_lse else [ospec],
+    res = walk.call(
+        functools.partial(_flash_kernel, walk=walk, scale=1.0 / (d ** 0.5),
+                          emit_lse=with_lse),
+        bh, (flat(q), flat(k), flat(v)), interpret=interpret,
+        in_specs=[qspec, kspec, kspec],
+        out_specs=[qspec, lspec] if with_lse else [qspec],
         out_shape=[oshape, lshape] if with_lse else [oshape],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),   # running max
             pltpu.VMEM((block_q, _LANES), jnp.float32),   # denominator
             pltpu.VMEM((block_q, d), jnp.float32),        # output acc
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(flat(q), flat(k), flat(v))
+        ])
     out = res[0].reshape(b, h, s, d)
     return (out, res[1]) if with_lse else (out, None)
 
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, i, j, *,
-              scale: float, causal: bool, block_q: int, block_k: int):
+              scale: float, masked: bool, block_q: int, block_k: int):
     """Shared backward recompute for ONE (q-block i, k-block j) tile:
     returns (p, ds) with ds already scale-folded — the one definition of
     the tile math, so the dQ and dK/dV kernels cannot desynchronize.
@@ -165,12 +284,8 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, i, j, *,
     s = jax.lax.dot_general(
         qb, kb, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale           # (bq, bk)
-    if causal:
-        qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-            + i * block_q
-        kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
-            + j * block_k
-        s = jnp.where(qpos >= kpos, s, _NEG_INF)
+    if masked:
+        s = _causal_mask(s, i, j, block_q, block_k)
     p = jnp.exp(s - lse)               # masked entries: exp(-inf-..) = 0
     dp = jax.lax.dot_general(
         dob, vb, (((1,), (1,)), ((), ())),
@@ -179,50 +294,43 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, i, j, *,
     return p, ds
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                   dq_ref, acc_ref, *, scale: float, causal: bool,
-                   block_q: int, block_k: int, nk: int):
-    i = pl.program_id(1)
-    j = pl.program_id(2)
+def _bwd_dq_kernel(*refs, walk: _Walk, scale: float):
+    i, j, first, last, crossing, refs = walk.enter(refs)
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, acc_ref = refs
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    live = (j * block_k <= i * block_q + block_q - 1) if causal else True
-
-    @pl.when(live)
-    def _step():
+    def tile(masked: bool):
         _, ds = _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                          i, j, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k)
+                          i, j, scale=scale, masked=masked,
+                          block_q=walk.block_q, block_k=walk.block_k)
         acc_ref[...] += jax.lax.dot_general(
             ds, k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # (bq, d)
 
-    @pl.when(j == nk - 1)
+    _masked_or_not(crossing, tile)
+
+    @pl.when(last)
     def _emit():
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                    causal: bool, block_q: int, block_k: int, nq: int):
-    j = pl.program_id(1)
-    i = pl.program_id(2)
+def _bwd_dkv_kernel(*refs, walk: _Walk, scale: float):
+    i, j, first, last, crossing, refs = walk.enter(refs)
+    (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+     dk_ref, dv_ref, dk_acc, dv_acc) = refs
 
-    @pl.when(i == 0)
+    @pl.when(first)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    live = (i * block_q + block_q - 1 >= j * block_k) if causal else True
-
-    @pl.when(live)
-    def _step():
+    def tile(masked: bool):
         p, ds = _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                          i, j, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k)
+                          i, j, scale=scale, masked=masked,
+                          block_q=walk.block_q, block_k=walk.block_k)
         dob = do_ref[0]
         dv_acc[...] += jax.lax.dot_general(
             p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
@@ -231,7 +339,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             ds, q_ref[0], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # (bk, d)
 
-    @pl.when(i == nq - 1)
+    _masked_or_not(crossing, tile)
+
+    @pl.when(last)
     def _emit():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -240,49 +350,32 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
 def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
                     block_k: int, interpret: bool):
     b, h, s, d = q.shape
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
-    bh, nq, nk = b * h, s // block_q, s // block_k
+    block_q, block_k = _blocks(s, block_q, block_k)
+    bh = b * h
     scale = 1.0 / (d ** 0.5)
     flat = lambda t: t.reshape(bh, s, d)
-    qf, kf, vf, of, dof = flat(q), flat(k), flat(v), flat(out), flat(do)
+    operands = (flat(q), flat(k), flat(v), flat(out), flat(do), lse)
+    like = lambda t: jax.ShapeDtypeStruct((bh, s, d), t.dtype)
 
-    qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
-    rspec = pl.BlockSpec((1, block_q, _RES_LANES),
-                         lambda b, i, j: (b, i, 0))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, nk=nk),
-        grid=(bh, nq, nk),
+    walk = _Walk(causal, s, block_q, block_k, False)
+    qspec, kspec, rspec = walk.specs(d)
+    dq = walk.call(
+        functools.partial(_bwd_dq_kernel, walk=walk, scale=scale),
+        bh, operands, interpret=interpret,
         in_specs=[qspec, kspec, kspec, qspec, qspec, rspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qf, kf, vf, of, dof, lse)
+        out_specs=qspec, out_shape=like(q),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)])
 
-    # dK/dV walk q-blocks innermost: grid axis 1 is the K block
-    qspec2 = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-    kspec2 = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    rspec2 = pl.BlockSpec((1, block_q, _RES_LANES),
-                          lambda b, j, i: (b, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, nq=nq),
-        grid=(bh, nk, nq),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, qspec2, rspec2],
-        out_specs=[kspec2, kspec2],
-        out_shape=[jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, s, d), v.dtype)],
+    # dK/dV walk q-blocks innermost
+    walk = walk._replace(q_inner=True)
+    qspec, kspec, rspec = walk.specs(d)
+    dk, dv = walk.call(
+        functools.partial(_bwd_dkv_kernel, walk=walk, scale=scale),
+        bh, operands, interpret=interpret,
+        in_specs=[qspec, kspec, kspec, qspec, qspec, rspec],
+        out_specs=[kspec, kspec], out_shape=[like(k), like(v)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qf, kf, vf, of, dof, lse)
+                        pltpu.VMEM((block_k, d), jnp.float32)])
     shape = (b, h, s, d)
     return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)
 

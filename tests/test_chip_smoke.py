@@ -46,11 +46,13 @@ def test_ps_stage():
 def test_lm_stage():
     facts = chip_smoke.stage_lm(
         vocab=128, dim=32, heads=4, layers=2, seq=32, batch_per_chip=1,
-        kernel_shapes=(((1, 2, 64, 16), 32),), chip=False)
+        kernel_shapes=(((1, 2, 64, 16), 32), ((1, 2, 64, 16), (16, 32))),
+        chip=False)
     assert facts["batch_axis"] == "mv"      # kernel ran under shard_map
     assert facts["loss"][-1] < facts["loss"][0]
     errs = facts["kernel_rel_err"]["(1, 2, 64, 16)/32"]
     assert set(errs) == {"out", "dq", "dk", "dv"}
+    assert "(1, 2, 64, 16)/(16, 32)" in facts["kernel_rel_err"]
 
 
 def test_main_refuses_a_cpu(capsys):

@@ -1,0 +1,139 @@
+"""The flash kernels' causal grid (ops/attention_kernels.py): the table of
+live (q block, k block) pairs a causal call walks, with no kernel at all,
+and the three kernels over it in interpreter mode at small sizes, forward
+and every gradient against plain XLA attention."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from multiverso_tpu.models.mla_moe import _xla_attention
+from multiverso_tpu.ops import attention_kernels as ak
+from multiverso_tpu.parallel.ring import reference_attention
+
+# (s, block_q, block_k): equal blocks, q over k, k over q, one block
+# (s under a block), and the language-model cell's own
+GRIDS = [(128, 32, 32), (256, 64, 32), (256, 32, 64), (64, 128, 128),
+         (192, 64, 32), (8192, 512, 512)]
+
+
+def _brute(s, bq, bk):
+    """Every pair by its positions: live where some q >= some k, crossing
+    where besides some q < some k."""
+    bq, bk = min(bq, s), min(bk, s)
+    live, crossing = set(), set()
+    for i in range(s // bq):
+        for j in range(s // bk):
+            if j * bk <= i * bq + bq - 1:
+                live.add((i, j))
+                if i * bq < j * bk + bk - 1:
+                    crossing.add((i, j))
+    return live, crossing
+
+
+@pytest.mark.parametrize("s,bq,bk", GRIDS)
+def test_table_holds_every_live_pair_once_and_no_dead_one(s, bq, bk):
+    live, _ = _brute(s, bq, bk)
+    for q_inner in (False, True):
+        qi, kj, _ = ak.live_pairs(s, bq, bk, q_inner)
+        assert qi.dtype == kj.dtype == np.int32
+        pairs = list(zip(qi.tolist(), kj.tolist()))
+        assert len(pairs) == len(set(pairs)) and set(pairs) == live
+
+
+@pytest.mark.parametrize("s,bq,bk", GRIDS)
+def test_forward_walks_row_major_and_dkv_column_major(s, bq, bk):
+    qi, kj, _ = ak.live_pairs(s, bq, bk)
+    assert list(zip(qi.tolist(), kj.tolist())) == sorted(
+        zip(qi.tolist(), kj.tolist()))
+    qi, kj, _ = ak.live_pairs(s, bq, bk, q_inner=True)
+    assert list(zip(kj.tolist(), qi.tolist())) == sorted(
+        zip(kj.tolist(), qi.tolist()))
+
+
+@pytest.mark.parametrize("s,bq,bk", GRIDS)
+def test_masked_exactly_on_the_pairs_the_diagonal_crosses(s, bq, bk):
+    _, crossing = _brute(s, bq, bk)
+    for q_inner in (False, True):
+        qi, kj, masked = ak.live_pairs(s, bq, bk, q_inner)
+        got = {(i, j) for i, j, m in zip(qi.tolist(), kj.tolist(), masked)
+               if m}
+        assert got == crossing
+
+
+@pytest.mark.parametrize("s,bq,bk", GRIDS)
+def test_init_and_emit_fall_on_a_rows_first_and_last_pair(s, bq, bk):
+    """The kernels' scalar arithmetic (``_Walk.enter``) names the first
+    and last pair of each accumulator's run, as the table has them."""
+    bq, bk = min(bq, s), min(bk, s)
+    nq, nk = s // bq, s // bk
+    qi, kj, _ = ak.live_pairs(s, bq, bk)
+    for i in range(nq):                     # forward, dQ: a q block's row
+        row = kj[qi == i]
+        assert row[0] == 0 and row[-1] == min(nk - 1, (i * bq + bq - 1) // bk)
+        assert np.all(np.diff(np.flatnonzero(qi == i)) == 1)   # one run
+    qi, kj, _ = ak.live_pairs(s, bq, bk, q_inner=True)
+    for j in range(nk):                     # dK with dV: a k block's column
+        col = qi[kj == j]
+        assert col[0] == (j * bk) // bq and col[-1] == nq - 1
+        assert np.all(np.diff(np.flatnonzero(kj == j)) == 1)
+
+
+@pytest.mark.parametrize("s,bq,bk,want", [
+    (8192, 512, 512, (136, 136, 16)),       # glm47f-train-8k's call
+    (8192, 1024, 512, (72, 72, 16)),
+    (8192, 512, 1024, (72, 72, 16)),
+    (64, 128, 128, (1, 1, 1)),
+    (128, 32, 32, (10, 10, 4)),
+])
+def test_causal_pairs_counts_steps_live_and_masked(s, bq, bk, want):
+    got = ak.causal_pairs(s, bq, bk)
+    assert (got["grid_steps"], got["live"], got["masked"]) == want
+
+
+def test_blocks_that_do_not_divide_the_sequence_are_refused():
+    with pytest.raises(ValueError, match="not divisible"):
+        ak.live_pairs(96, 64, 32)
+
+
+def _inputs(shape, seed):
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.normal(size=shape), jnp.float32)
+                 for _ in range(4))
+
+
+def _out_and_grads(fn, q, k, v, g):
+    out, vjp = jax.vjp(fn, q, k, v)
+    return (out,) + vjp(g)
+
+
+@pytest.mark.parametrize("s,bq,bk,causal", [
+    (128, 32, 32, True),        # equal blocks: 10 pairs, 4 crossing
+    (256, 64, 32, True),        # block_q > block_k: rows wholly masked
+    (256, 32, 64, True),        # block_q < block_k
+    (192, 64, 32, True),        # three q blocks over six k blocks
+    (64, 128, 128, True),       # s under a block: one pair, crossing
+    (128, 32, 64, False),       # no mask at all: the rectangle
+    (128, 64, 64, False),
+])
+def test_kernels_match_xla_attention_forward_and_three_gradients(
+        s, bq, bk, causal):
+    q, k, v, g = _inputs((1, 2, s, 32), seed=s + bq)
+    oracle = (_xla_attention if causal else
+              lambda q, k, v: reference_attention(q, k, v, causal=False))
+    got = jax.jit(lambda *a: _out_and_grads(
+        lambda q, k, v: ak.flash_attention(q, k, v, causal, bq, bk, True),
+        *a))(q, k, v, g)
+    want = jax.jit(lambda *a: _out_and_grads(oracle, *a))(q, k, v, g)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-5, err_msg=name)
+
+
+def test_inference_call_without_the_residual_matches_too():
+    q, k, v, _ = _inputs((2, 1, 128, 32), seed=7)
+    got = ak.flash_attention(q, k, v, True, 32, 64, True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_xla_attention(q, k, v)),
+                               rtol=2e-4, atol=2e-5)

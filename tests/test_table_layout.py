@@ -527,23 +527,28 @@ def test_fused_block_program_on_a_v5e_copies_no_table(one_chip, we300,
 def test_language_model_kernels_compile_for_a_v5e_at_published_widths(
         one_chip):
     """The flash kernel at head size 256 over 8,192 positions, forward
-    and backward, and the held experts' grouped products over a
-    16,384-row buffer (2,048 x 1,536, eight groups), as
-    ``glm47f-train-8k`` calls them: the chip's compiler takes them, and
-    the kernels are in the program."""
+    and backward, at 512 x 512 blocks and at the 512 x 1,024 that
+    ``models/mla_moe.attn_blocks`` gives the cell, and the held experts'
+    grouped products over a 16,384-row buffer (2,048 x 1,536, eight
+    groups), as ``glm47f-train-8k`` calls them: the chip's compiler takes
+    them, and the kernels are in the program."""
+    from multiverso_tpu.models import mla_moe
     from multiverso_tpu.ops import attention_kernels
     from multiverso_tpu.parallel import moe
 
     shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-
-    def core(q, k, v):
-        return attention_kernels.flash_attention(
-            q, k, v, True, 512, 512, False).astype(jnp.float32).sum()
-
     qkv = shape((2, 20, 8192, 256), jnp.bfloat16)
-    text = jax.jit(jax.grad(core, argnums=(0, 1, 2))).lower(
-        qkv, qkv, qkv).compile().as_text()
-    assert text.count("tpu_custom_call") >= 3      # forward, dQ, dK with dV
+    cell = mla_moe.attn_blocks(mla_moe.MLAMoEConfig(v_head_dim=256), 8192)
+    assert cell == (512, 1024)
+    for bq, bk in ((512, 512), cell):
+        def core(q, k, v):
+            return attention_kernels.flash_attention(
+                q, k, v, True, bq, bk, False).astype(jnp.float32).sum()
+
+        text = jax.jit(jax.grad(core, argnums=(0, 1, 2))).lower(
+            qkv, qkv, qkv).compile().as_text()
+        # forward, dQ, dK with dV
+        assert text.count("tpu_custom_call") >= 3, (bq, bk)
 
     held = moe.HeldExperts(num_experts=64, experts_held=8, top_k=4,
                            routed_scale=1.8, buffer_rows=16384, tile=512)

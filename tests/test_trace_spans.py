@@ -521,6 +521,27 @@ def test_lm_step_carries_its_counts():
     assert want["overflow_rows"] == 0 and want["load_max_over_mean"] >= 1.0
 
 
+def test_lm_step_carries_the_flash_kernels_grid_counts():
+    """With the flash kernel as the core, every ``lm.step`` span says what
+    one kernel call walks a (batch x head): 32 positions in 16-row q
+    blocks under 32-row k blocks are two pairs, both on the diagonal."""
+    mla_moe, cfg, tables, tokens = _tiny_lm()
+    cfg = cfg._replace(attn="flash", attn_block=16)
+    assert mla_moe.attn_blocks(cfg, 32) == (16, 32)
+    trainer = mla_moe.Trainer(cfg, tables)
+    before = len(ttrace.events())
+    assert trainer.step_ahead(tokens) is None
+    trainer.adopt()
+    steps = [e for e in ttrace.events()[before:] if e["name"] == "lm.step"]
+    assert len(steps) == 2          # the step queued, and the drain
+    grid = {"attn_grid_steps": 2, "attn_pairs_live": 2,
+            "attn_pairs_masked": 2}
+    for e in steps:
+        assert {k: e["args"][k] for k in grid} == grid
+    assert "routed_rows" not in steps[0]["args"]     # nothing read back yet
+    assert mla_moe.attn_grid(cfg._replace(attn="xla"), 32) == {}
+
+
 def test_lm_step_carries_lm_and_rule_scopes():
     mla_moe, cfg, tables, tokens = _tiny_lm()
     states = {n: t.state for n, t in tables.items()}
