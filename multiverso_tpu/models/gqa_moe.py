@@ -1,0 +1,120 @@
+"""A decoder of grouped-query attention, window layers interleaved with
+full ones, and routed experts alone in every layer, trained through Adam
+tables: the second model on ``models/mla_moe.py``'s one decoder path.
+
+This file says what is this model's own: its configuration, the shapes
+of its attention's parameters, and the attention. The block, the
+products, the norms, rotary positions, the expert layer's call, the
+chunked loss, the tables, the step and the ``Trainer`` are
+``mla_moe``'s, used as they are. The equations, for a block with input
+``x`` [B, S, D]:
+
+* ``h = x + Attn(RMSNorm(x))``, ``y = h + Experts(RMSNorm(h))``.
+* Attn: ``q = u W_q`` -> ``n_heads`` heads of ``head_dim``; ``k = u W_k``,
+  ``v = u W_v`` -> ``n_kv_heads`` heads; no bias; rotary on q and k
+  (half-split pairing), plain in a ``window`` layer and under YaRN's
+  frequencies and factor in a ``full`` one (``mla_moe.rotary``); query
+  head ``h`` reads key-value head ``h // (n_heads / n_kv_heads)``, and K
+  and V are never repeated: the flash kernel's index maps know the group.
+  Scores over ``sqrt(head_dim)``; position ``i`` sees ``j`` where ``0 <=
+  i - j`` and, in a ``window`` layer, ``i - j < window``; float32
+  softmax; ``o W_o``.
+* Experts: ``parallel/moe.held_expert_layer`` under its softmax route:
+  probabilities over all ``n_experts``, the ``top_k`` largest
+  renormalised, no bias, no shared expert; this chip computes the part of
+  the experts it holds. The loss gains ``balance_coef`` times the sum
+  over the layers of the route's load-balance term.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Dict, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from multiverso_tpu.models import mla_moe
+from multiverso_tpu.models.mla_moe import Layer, Yarn
+from multiverso_tpu.ops.attention_kernels import flash_attention
+
+
+class GQAMoEConfig(NamedTuple):
+    vocab: int = 512                 # token ids held here (a slice)
+    dim: int = 64
+    n_heads: int = 4
+    n_kv_heads: int = 2
+    head_dim: int = 8
+    window: int = 16
+    layer_kinds: Tuple[str, ...] = ("window", "window", "window", "full")
+    rope_theta: float = 5e5
+    yarn: Optional[Yarn] = Yarn(16.0, 32, 32.0, 1.0, 1.2772588722239782)
+    moe_ffn: int = 48
+    n_experts: int = 16              # the router's outputs
+    experts_held: int = 4
+    expert_offset: int = 0
+    top_k: int = 8
+    balance_coef: float = 1e-3       # on the routers' load-balance terms
+    eps: float = 1e-6
+    attn: Optional[str] = None       # as MLAMoEConfig's
+    expert_kernel: Optional[str] = None
+    attn_block: int = 512
+    loss_chunk: int = 4096
+    compute_dtype: Any = jnp.bfloat16
+
+    def layers(self) -> Tuple[Layer, ...]:
+        return tuple(Layer(f"L{i}", kind, "experts")
+                     for i, kind in enumerate(self.layer_kinds))
+
+    def attn_shapes(self, kind: str) -> Dict[str, Tuple[int, ...]]:
+        d, hd = self.dim, self.head_dim
+        return {"attn_norm": (d,), "wq": (d, self.n_heads * hd),
+                "wk": (d, self.n_kv_heads * hd),
+                "wv": (d, self.n_kv_heads * hd),
+                "wo": (self.n_heads * hd, d), "ffn_norm": (d,)}
+
+    def attend(self, u, p, kind: str):
+        return gqa(u, p, self, kind)
+
+    @property
+    def head_size(self) -> int:
+        return self.head_dim
+
+    @property
+    def kv_group(self) -> int:       # query heads a key-value head
+        return self.n_heads // self.n_kv_heads
+
+    @property
+    def route(self) -> str:          # parallel/moe.HeldExperts.route
+        return "softmax"
+
+    @property
+    def routed_scale(self) -> float:     # the gates sum to 1
+        return 1.0
+
+
+def gqa(u, p, cfg: GQAMoEConfig, kind: str):
+    """Grouped-query attention of ``kind`` (``"full"`` or ``"window"``) on
+    the normed input ``u`` [B, S, D] -> [B, S, D] float32."""
+    b, s, _ = u.shape
+    h, hkv, hd, dt = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.compute_dtype
+    window = cfg.window if kind == "window" else None
+    yarn = cfg.yarn if kind == "full" else None
+    mm = functools.partial(mla_moe.matmul, dtype=dt)
+    with jax.named_scope("mv.lm.attn"):
+        q = mm(u, p["wq"], False, out_dtype=jnp.float32).reshape(b, s, h, hd)
+        k = mm(u, p["wk"], False, out_dtype=jnp.float32).reshape(b, s, hkv, hd)
+        v = mm(u, p["wv"], False, out_dtype=dt).reshape(b, s, hkv, hd)
+        q = mla_moe.rotary(q, cfg.rope_theta, yarn)
+        k = mla_moe.rotary(k, cfg.rope_theta, yarn)
+        heads = lambda t: t.astype(dt).transpose(0, 2, 1, 3)
+        q, k, v = heads(q), heads(k), heads(v)
+        with jax.named_scope("mv.lm.attn." + kind):
+            if mla_moe.attn_core(cfg) == "flash":
+                o = flash_attention(q, k, v, True,
+                                    *mla_moe.attn_blocks(cfg, s), None,
+                                    window)
+            else:
+                o = mla_moe._xla_attention(q, k, v, window)
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
+        return mm(o, p["wo"], False, out_dtype=jnp.float32)

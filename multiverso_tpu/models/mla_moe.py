@@ -21,6 +21,18 @@ whole. The equations, for a block with input ``x``:
   through one block of the expert kind and its own output norm to the
   SAME head, predicting ``t_{i+2}``; loss ``CE(main) + w * CE(module)``.
 
+The decoder itself is one code path for more than this model: a
+configuration says its layers as data (``cfg.layers()``: a
+:class:`Layer` names each block's attention kind, ``latent`` here,
+``full`` or ``window`` grouped-query heads in ``models/gqa_moe.py``, and
+its feed-forward kind, ``dense``, ``shared+experts`` or ``experts``
+alone), how its routers score (``cfg.route`` and ``cfg.routed_scale``:
+``parallel/moe.HeldExperts``'s), the shapes of an attention kind's
+parameters (``cfg.attn_shapes(kind)``) and the attention itself
+(``cfg.attend(u, p, kind)``); :func:`block`, :func:`matmul`, :func:`rms_norm`,
+:func:`rotary`, the chunked loss, the tables, the step and
+:class:`Trainer` below are shared by every such configuration.
+
 Every trained parameter lies in a ``Table`` (:func:`make_tables`);
 :func:`make_train_step` is to this model what
 ``models/dlrm.make_train_step`` is to DLRM: one program takes the tables'
@@ -44,6 +56,13 @@ from multiverso_tpu.ops.attention_kernels import (causal_pairs,
 from multiverso_tpu.parallel import moe
 from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.updaters import AddOption
+
+
+class Layer(NamedTuple):
+    """One block of a decoder, as data."""
+    name: str       # its parameters' prefix: "L<i>", or "mtp"
+    attn: str       # "latent", "full" or "window"
+    ffn: str        # "dense", "shared+experts" or "experts"
 
 
 class MLAMoEConfig(NamedTuple):
@@ -78,6 +97,38 @@ class MLAMoEConfig(NamedTuple):
     loss_chunk: int = 4096           # positions a chunk of the two losses
     compute_dtype: Any = jnp.bfloat16
 
+    def layers(self) -> Tuple[Layer, ...]:
+        """Dense layers, then expert layers, then the prediction module."""
+        first = self.n_dense_layers
+        return (tuple(Layer(f"L{i}", "latent", "dense")
+                      for i in range(first))
+                + tuple(Layer(f"L{i}", "latent", "shared+experts")
+                        for i in range(first, first + self.n_moe_layers))
+                + ((Layer("mtp", "latent", "shared+experts"),)
+                   if self.n_mtp else ()))
+
+    def attn_shapes(self, kind: str) -> Dict[str, Tuple[int, ...]]:
+        return _attn_shapes(self)
+
+    def attend(self, u, p, kind: str):
+        return mla(u, p, self)
+
+    @property
+    def head_size(self) -> int:
+        return self.v_head_dim
+
+    @property
+    def kv_group(self) -> int:       # query heads a key-value head
+        return 1
+
+    @property
+    def route(self) -> str:          # parallel/moe.HeldExperts.route
+        return "sigmoid"
+
+    @property
+    def balance_coef(self) -> float:     # no load-balance term in the loss
+        return 0.0
+
 
 # The held experts' buffer, in rows, for a layer's ``tokens``: twice what
 # an even router sends here, and never under the floor (the loads of a few
@@ -89,26 +140,22 @@ class MLAMoEConfig(NamedTuple):
 BUFFER_OVER_EVEN, BUFFER_FLOOR = 2, 2048
 
 
-def held(cfg: MLAMoEConfig, tokens: int) -> moe.HeldExperts:
+def held(cfg, tokens: int) -> moe.HeldExperts:
     """The expert layer's part that lies here, for ``tokens`` a layer."""
     most = tokens * min(cfg.top_k, cfg.experts_held)
     even = tokens * cfg.top_k * cfg.experts_held // cfg.n_experts
-    # the grouped products' tile: 512 where every width divides by it
-    # (the published ones do), else the kernel's own 128
-    tile = 512 if cfg.dim % 512 == 0 and cfg.moe_ffn % 512 == 0 else 128
     return moe.HeldExperts(
         num_experts=cfg.n_experts, experts_held=cfg.experts_held,
         expert_offset=cfg.expert_offset, top_k=cfg.top_k,
-        routed_scale=cfg.routed_scale, tile=tile, dtype=cfg.compute_dtype,
+        routed_scale=cfg.routed_scale, route=cfg.route,
+        tile=moe.product_tile(cfg.dim, cfg.moe_ffn), dtype=cfg.compute_dtype,
         buffer_rows=min(most, max(BUFFER_OVER_EVEN * even, BUFFER_FLOOR)))
 
 
-def expert_layers(cfg: MLAMoEConfig) -> Tuple[str, ...]:
+def expert_layers(cfg) -> Tuple[str, ...]:
     """The layers that have a router, in the order of the bias rows and of
-    the step's counts: the expert layers, then the prediction module."""
-    first = cfg.n_dense_layers
-    return tuple(f"L{i}" for i in range(first, first + cfg.n_moe_layers)) \
-        + (("mtp",) if cfg.n_mtp else ())
+    the step's counts (the prediction module, where there is one, last)."""
+    return tuple(layer.name for layer in cfg.layers() if layer.ffn != "dense")
 
 
 # ---------------------------------------------------------------------- #
@@ -127,30 +174,33 @@ def _attn_shapes(cfg: MLAMoEConfig) -> Dict[str, Tuple[int, ...]]:
         "wo": (h * cfg.v_head_dim, d), "ffn_norm": (d,)}
 
 
-def _expert_ffn_shapes(cfg: MLAMoEConfig) -> Dict[str, Tuple[int, ...]]:
-    d, f, e = cfg.dim, cfg.moe_ffn, cfg.experts_held
-    return {"router": (cfg.n_experts, d),        # a row an expert
-            "sg": (d, f), "su": (d, f), "sd": (f, d),
-            "eg": (e, d, f), "eu": (e, d, f), "ed": (e, f, d)}
+def _ffn_shapes(cfg, kind: str) -> Dict[str, Tuple[int, ...]]:
+    d = cfg.dim
+    if kind == "dense":
+        return {"wg": (d, cfg.dense_ffn), "wu": (d, cfg.dense_ffn),
+                "wd": (cfg.dense_ffn, d)}
+    f, e = cfg.moe_ffn, cfg.experts_held
+    out = {"router": (cfg.n_experts, d),        # a row an expert
+           "eg": (e, d, f), "eu": (e, d, f), "ed": (e, f, d)}
+    if kind == "shared+experts":
+        out.update(sg=(d, f), su=(d, f), sd=(f, d))
+    return out
 
 
-def param_shapes(cfg: MLAMoEConfig) -> Dict[str, Tuple[int, ...]]:
+def param_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
     """Every trained parameter by name. Layers are ``L<i>.``; the
     prediction module is ``mtp.``; ``embed`` and ``head`` have a row a
     token id."""
     d = cfg.dim
     out = {"embed": (cfg.vocab, d), "head": (cfg.vocab, d),
            "final_norm": (d,)}
-    for i in range(cfg.n_dense_layers):
-        block = dict(_attn_shapes(cfg), wg=(d, cfg.dense_ffn),
-                     wu=(d, cfg.dense_ffn), wd=(cfg.dense_ffn, d))
-        out.update({f"L{i}.{k}": v for k, v in block.items()})
-    for name in expert_layers(cfg):
-        block = dict(_attn_shapes(cfg), **_expert_ffn_shapes(cfg))
-        if name == "mtp":
+    for layer in cfg.layers():
+        block = dict(cfg.attn_shapes(layer.attn),
+                     **_ffn_shapes(cfg, layer.ffn))
+        if layer.name == "mtp":
             block.update(enorm=(d,), hnorm=(d,), eh_proj=(2 * d, d),
                          out_norm=(d,))
-        out.update({f"{name}.{k}": v for k, v in block.items()})
+        out.update({f"{layer.name}.{k}": v for k, v in block.items()})
     return out
 
 
@@ -162,7 +212,7 @@ def _draw(shape, key, scale: float, norm: bool, pad: int = 0) -> jax.Array:
     return jnp.pad(x, [(0, pad)] + [(0, 0)] * (len(shape) - 1))
 
 
-def _keys(cfg: MLAMoEConfig, seed: int):
+def _keys(cfg, seed: int):
     """(name, shape, key) of every parameter, by name; ``seed`` is any
     whole number (a benchmark's pass 2**31)."""
     seed = int(seed)
@@ -179,7 +229,7 @@ def _scale_of(name: str, scale: float,
     return (scales or {}).get(name.split(".")[-1], scale)
 
 
-def init(cfg: MLAMoEConfig, seed: int = 0, scale: float = 0.02,
+def init(cfg, seed: int = 0, scale: float = 0.02,
          scales: Optional[Dict[str, float]] = None) -> Dict[str, jax.Array]:
     """Parameters from ``seed``: Normal(0, scale) matrices, norms of ones;
     ``scales`` gives a kind of parameter its own scale by the last part of
@@ -191,7 +241,7 @@ def init(cfg: MLAMoEConfig, seed: int = 0, scale: float = 0.02,
             for name, shape, key in _keys(cfg, seed)}
 
 
-def init_bias(cfg: MLAMoEConfig) -> jax.Array:
+def init_bias(cfg) -> jax.Array:
     """The routers' selection biases, a row a layer of
     :func:`expert_layers`: not trained, moved by ``moe.bias_update``."""
     return jnp.zeros((len(expert_layers(cfg)), cfg.n_experts), jnp.float32)
@@ -204,7 +254,7 @@ def table_shape(shape: Tuple[int, ...]) -> Tuple[int, ...]:
     return shape if len(shape) < 3 else (shape[0] * shape[1], shape[2])
 
 
-def make_tables(cfg: MLAMoEConfig, seed: int = 0, scale: float = 0.02,
+def make_tables(cfg, seed: int = 0, scale: float = 0.02,
                 updater: Any = "adam",
                 scales: Optional[Dict[str, float]] = None) -> Dict[str, Any]:
     """One table a parameter (a ``MatrixTable`` for matrices, whose rows
@@ -270,57 +320,123 @@ def rms_norm(x, w, eps: float):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
 
 
-def rotary(x, theta: float):
+class Yarn(NamedTuple):
+    """YaRN's rescaling of rotary frequencies, under the names the
+    published ``rope_parameters`` give its numbers."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+def rotary_frequencies(r: int, theta: float, yarn: Optional[Yarn] = None):
+    """(frequencies [r / 2], the factor on cos and sin). Plain:
+    ``theta^(-2i/r)`` and 1. YaRN: a dimension that turns ``beta_fast``
+    times or more within the original length keeps its frequency, one
+    that turns ``beta_slow`` times or fewer has it divided by ``factor``,
+    and those between blend by a linear ramp over the dimension's number;
+    cos and sin are multiplied by ``attention_factor``."""
+    freq = theta ** (-np.arange(0, r, 2, dtype=np.float64) / r)
+    if yarn is None:
+        return jnp.asarray(freq, jnp.float32), 1.0
+
+    def dimension_turning(turns: float) -> float:
+        return (r * np.log(yarn.original_max_position_embeddings
+                           / (turns * 2 * np.pi)) / (2 * np.log(theta)))
+
+    low = max(np.floor(dimension_turning(yarn.beta_fast)), 0)
+    high = min(np.ceil(dimension_turning(yarn.beta_slow)), r - 1)
+    ramp = np.clip((np.arange(r // 2) - low) / max(high - low, 1e-3), 0, 1)
+    freq = freq / yarn.factor * ramp + freq * (1 - ramp)
+    return jnp.asarray(freq, jnp.float32), float(yarn.attention_factor)
+
+
+def rotary(x, theta: float, yarn: Optional[Yarn] = None):
     """Rotary positions on the last axis of ``x`` [B, S, ..., R], pairing
-    element ``i`` with ``i + R/2``; float32."""
+    element ``i`` with ``i + R/2``; float32. ``yarn``: see
+    :func:`rotary_frequencies`."""
     s, r = x.shape[1], x.shape[-1]
-    freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    freq, factor = rotary_frequencies(r, theta, yarn)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None, :]
     shape = (1, s) + (1,) * (x.ndim - 3) + (r // 2,)
     cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    if factor != 1.0:
+        cos, sin = factor * cos, factor * sin
     a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
 
-def attn_core(cfg: MLAMoEConfig) -> str:
+def attn_core(cfg) -> str:
     """The attention core that runs: ``cfg.attn``, or by the device."""
     return cfg.attn or (
         "flash" if jax.devices()[0].platform == "tpu" else "xla")
 
 
-def attn_blocks(cfg: MLAMoEConfig, s: int) -> Tuple[int, int]:
-    """The flash kernel's (q, k) blocks over ``s`` positions: ``attn_block``
-    rows of q, and twice as many of k where a k block of at most 1,024
-    rows at a head size of at most 256 divides ``s`` (what fits the
-    kernels' VMEM: 1,024 x 1,024 and 512 x 2,048 do not). At (8192, 256)
-    on a v5e the forward, dQ and dK/dV kernels read 13.3 / 13.9 / 17.9 ms
-    a call at 512 x 512, 12.0 / 13.4 / 17.7 at 1,024 x 512 and 10.9 / 13.4 /
-    17.6 at 512 x 1,024: an accumulator is rescaled once a k block."""
+def attn_blocks(cfg, s: int) -> Tuple[int, int]:
+    """The flash kernel's (q, k) blocks over ``s`` positions, from ``s``
+    and the head size: ``attn_block`` rows of q, and twice as many of k
+    where a k block of at most 1,024 rows at a head size of at most 256
+    divides ``s``; at a head size of at most 128 the q block doubles with
+    it (what fits the kernels' VMEM: at 256, 1,024 x 1,024 and 512 x 2,048
+    do not). At (8192, 256) on a v5e the forward, dQ and dK/dV kernels
+    read 13.3 / 13.9 / 17.9 ms a call at 512 x 512, 12.0 / 13.4 / 17.7 at
+    1,024 x 512 and 10.9 / 13.4 / 17.6 at 512 x 1,024: an accumulator is
+    rescaled once a k block. At (8192, 128) with 32 query heads over 4
+    key-value heads (PERF.md section 6, PR 35) 20.5 / 12.9 / 16.8 at 512 x
+    512, 11.9 / 12.0 / 15.3 at 512 x 1,024 and 10.3 / 11.1 / 14.9 at 1,024 x
+    1,024. A window changes nothing in the choice: under one of 1,024 the
+    same three read 7.5 / 4.8 / 6.1, 5.7 / 5.8 / 6.9 and 5.1 / 5.4 / 6.8,
+    and the forward runs twice a block: the band's edges are paid in
+    whole blocks (15 pairs of 1,024 x 1,024 a head are twice the band's
+    area, 45 of 512 x 512 one and a half times), but a k block's rescale
+    and a grid step's cost weigh more than the masked half of a tile."""
     bq = min(cfg.attn_block, s)
-    wide = 2 * bq <= 1024 and cfg.v_head_dim <= 256 and s % (2 * bq) == 0
-    return bq, 2 * bq if wide else bq
+    wide = 2 * bq <= 1024 and cfg.head_size <= 256 and s % (2 * bq) == 0
+    if not wide:
+        return bq, bq
+    return (2 * bq if cfg.head_size <= 128 else bq), 2 * bq
 
 
-def attn_grid(cfg: MLAMoEConfig, s: int) -> Dict[str, int]:
+def attn_grid(cfg, s: int) -> Dict[str, Any]:
     """What one flash kernel call over ``s`` positions does a (batch x
-    head), as ``lm.step`` spans carry it; nothing where XLA is the core."""
+    head), as ``lm.step`` spans carry it: the layers' attention kinds,
+    the query heads a key-value head, the causal walk's counts and, where
+    some layer is of the window kind, the band's beside what a causal
+    walk of the band's blocks would visit; nothing where XLA is the
+    core."""
     if attn_core(cfg) != "flash":
         return {}
-    n = causal_pairs(s, *attn_blocks(cfg, s))
-    return {"attn_grid_steps": n["grid_steps"], "attn_pairs_live": n["live"],
-            "attn_pairs_masked": n["masked"]}
+    kinds = [layer.attn for layer in cfg.layers()]
+    blocks = attn_blocks(cfg, s)
+    n = causal_pairs(s, *blocks)
+    out = {"attn_grid_steps": n["grid_steps"], "attn_pairs_live": n["live"],
+           "attn_pairs_masked": n["masked"], "attn_kinds": ",".join(kinds),
+           "kv_group": cfg.kv_group}
+    if "window" in kinds:
+        band = causal_pairs(s, *blocks, cfg.window)
+        out.update(attn_pairs_live_window=band["live"],
+                   attn_pairs_masked_window=band["masked"],
+                   attn_pairs_causal_window=n["live"])
+    return out
 
 
-def _xla_attention(q, k, v):
-    """Causal attention over [B, H, S, D] in plain XLA, float32 softmax:
+def _xla_attention(q, k, v, window: Optional[int] = None):
+    """Causal attention over q [B, H, S, D] and k, v [B, Hkv, S, D] in
+    plain XLA, float32 softmax, under a ``window`` where one is given:
     the CPU tests' core, and the flash kernel's stand-in off the chip."""
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) / q.shape[-1] ** 0.5
-    n = q.shape[2]
-    s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e30)
-    p = jax.nn.softmax(s, -1).astype(v.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v,
-                      preferred_element_type=jnp.float32).astype(q.dtype)
+    b, h, n, d = q.shape
+    grouped = q.reshape(b, k.shape[1], h // k.shape[1], n, d)
+    s = jnp.einsum("bkgqd,bkjd->bkgqj", grouped, k,
+                   preferred_element_type=jnp.float32) / d ** 0.5
+    i, j = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
+    seen = i >= j
+    if window is not None:
+        seen &= i - j < window
+    p = jax.nn.softmax(jnp.where(seen, s, -1e30), -1).astype(v.dtype)
+    return jnp.einsum("bkgqj,bkjd->bkgqd", p, v,
+                      preferred_element_type=jnp.float32
+                      ).astype(q.dtype).reshape(q.shape)
 
 
 def mla(u, p, cfg: MLAMoEConfig):
@@ -358,39 +474,44 @@ def mla(u, p, cfg: MLAMoEConfig):
         return mm(o, p["wo"], False, out_dtype=jnp.float32)
 
 
-def gated_mlp(u, wg, wu, wd, cfg: MLAMoEConfig):
+def gated_mlp(u, wg, wu, wd, cfg):
     mm = functools.partial(matmul, dtype=cfg.compute_dtype)
     hidden = (jax.nn.silu(mm(u, wg, False, out_dtype=jnp.float32))
               * mm(u, wu, False, out_dtype=jnp.float32))
     return mm(hidden, wd, False, out_dtype=jnp.float32)
 
 
-def dense_ffn(u, p, cfg: MLAMoEConfig):
+def dense_ffn(u, p, cfg):
     with jax.named_scope("mv.lm.dense"):
         return gated_mlp(u, p["wg"], p["wu"], p["wd"], cfg), None
 
 
-def expert_ffn(u, p, bias, cfg: MLAMoEConfig):
-    """``Shared(u)`` + the held experts' part; aux = (counts [E],
-    overflow_rows)."""
+def expert_ffn(u, p, bias, cfg, shared: bool = True):
+    """The held experts' part under the configuration's route, beside
+    ``Shared(u)`` where the layer has a shared expert and alone where it
+    has none; aux = (counts [E], overflow_rows, the route's load-balance
+    term)."""
     b, s, d = u.shape
     f = cfg.moe_ffn
-    with jax.named_scope("mv.lm.moe.shared"):
-        shared = gated_mlp(u, p["sg"], p["su"], p["sd"], cfg)
-    routed, counts, overflow = moe.held_expert_layer(
+    if shared:
+        with jax.named_scope("mv.lm.moe.shared"):
+            beside = gated_mlp(u, p["sg"], p["su"], p["sd"], cfg)
+    routed, counts, overflow, balance = moe.held_expert_layer(
         u.reshape(b * s, d),
         {"router": p["router"],
          "w_gate": p["eg"].reshape(cfg.experts_held, d, f),
          "w_up": p["eu"].reshape(cfg.experts_held, d, f),
          "w_down": p["ed"].reshape(cfg.experts_held, f, d)},
         bias, held(cfg, b * s), cfg.expert_kernel)
-    return shared + routed.reshape(b, s, d), (counts, overflow)
+    routed = routed.reshape(b, s, d)
+    return (beside + routed if shared else routed), (counts, overflow,
+                                                     balance)
 
 
-def block(x, p, attn, ffn, cfg: MLAMoEConfig):
+def block(x, p, attn, ffn, cfg):
     """The one block: ``attn`` and ``ffn`` take the normed input and the
-    block's parameters. The dense layer, the expert layers and the
-    prediction module all call it. Returns (y, ffn's aux)."""
+    block's parameters. Every layer of every kind calls it. Returns (y,
+    ffn's aux)."""
     h = x + attn(rms_norm(x, p["attn_norm"], cfg.eps), p)
     f, aux = ffn(rms_norm(h, p["ffn_norm"], cfg.eps), p)
     return h + f, aux
@@ -401,18 +522,20 @@ def _sub(params: Dict[str, Any], prefix: str) -> Dict[str, Any]:
             if k.startswith(prefix + ".")}
 
 
-def _run_block(x, p, bias, cfg: MLAMoEConfig):
-    """A rematerialised block: dense where ``bias`` is None, else of the
-    expert kind."""
-    attn = lambda u, q: mla(u, q, cfg)
-    if bias is None:
+def _run_block(x, p, layer: Layer, bias, cfg, remat: bool = True):
+    """A block of ``layer``'s kinds, rematerialised unless told not to;
+    ``bias`` is its router's (a dense layer has none)."""
+    attn = lambda u, q: cfg.attend(u, q, layer.attn)
+    if layer.ffn == "dense":
         ffn = lambda u, q: dense_ffn(u, q, cfg)
     else:
-        ffn = lambda u, q: expert_ffn(u, q, bias, cfg)
-    return jax.checkpoint(lambda x, p: block(x, p, attn, ffn, cfg))(x, p)
+        ffn = lambda u, q: expert_ffn(u, q, bias, cfg,
+                                      layer.ffn == "shared+experts")
+    run = lambda x, p: block(x, p, attn, ffn, cfg)
+    return (jax.checkpoint(run) if remat else run)(x, p)
 
 
-def _chunked_ce(h, head, targets, weights, cfg: MLAMoEConfig):
+def _chunked_ce(h, head, targets, weights, cfg):
     """Sum over positions of ``weights * CE(h @ head.T, targets)``, float32,
     ``loss_chunk`` positions at a time (the whole logits would be
     positions x vocabulary floats); a chunk's logits are recomputed in the
@@ -437,27 +560,43 @@ def _chunked_ce(h, head, targets, weights, cfg: MLAMoEConfig):
     return jax.lax.scan(body, jnp.zeros((), jnp.float32), xs)[0]
 
 
-def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
-            tokens: jax.Array, cfg: MLAMoEConfig):
-    """tokens [B, S] -> (loss, (counts [layers, E], overflow [layers])).
-
-    ``CE(main, t_{i+1}) + mtp_weight * CE(module, t_{i+2})``, each a mean
-    over the positions that have a target (S-1 and S-2 a sequence). The
-    module runs on all S positions, so that its attention has the main
-    model's shape; the last, which has no next token, takes the
-    sequence's first in its place and has no target."""
-    b, s = tokens.shape
+def _trunk(params, bias, tokens, cfg, still: bool = False):
+    """Embedding and every layer but the prediction module: (x, [each
+    expert layer's aux]). ``still``: each block's input is held fixed (no
+    gradient flows from a layer into the one before it, so nothing is
+    rematerialised either)."""
     with jax.named_scope("mv.lm.embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
+    rows = {name: row for row, name in enumerate(expert_layers(cfg))}
     aux = []
-    for i in range(cfg.n_dense_layers):
-        x, _ = _run_block(x, _sub(params, f"L{i}"), None, cfg)
-    names = expert_layers(cfg)
-    for row, name in enumerate(names):
-        if name == "mtp":
+    for layer in cfg.layers():
+        if layer.name == "mtp":
             continue
-        x, a = _run_block(x, _sub(params, name), bias[row], cfg)
-        aux.append(a)
+        if still:
+            x = jax.lax.stop_gradient(x)
+        x, a = _run_block(
+            x, _sub(params, layer.name), layer,
+            bias[rows[layer.name]] if layer.name in rows else None, cfg,
+            remat=not still)
+        if a is not None:
+            aux.append(a)
+    return x, aux
+
+
+def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
+            tokens: jax.Array, cfg):
+    """tokens [B, S] -> (loss, (counts [layers, E], overflow [layers],
+    balance [layers])).
+
+    ``CE(main, t_{i+1}) + mtp_weight * CE(module, t_{i+2})``, each a mean
+    over the positions that have a target (S-1 and S-2 a sequence), plus
+    ``balance_coef`` times the sum of the layers' load-balance terms where
+    the configuration has such a coefficient. The module runs on all S
+    positions, so that its attention has the main model's shape; the
+    last, which has no next token, takes the sequence's first in its
+    place and has no target."""
+    b, s = tokens.shape
+    x, aux = _trunk(params, bias, tokens, cfg)
     position = jnp.arange(s)[None, :]
     nxt = jnp.roll(tokens, -1, axis=1)
     with jax.named_scope("mv.lm.head"):
@@ -467,7 +606,8 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
             jnp.broadcast_to(position < s - 1, (b, s)).reshape(-1)
             .astype(jnp.float32), cfg) / (b * (s - 1))
     loss = main
-    if cfg.n_mtp:
+    mtp = [layer for layer in cfg.layers() if layer.name == "mtp"]
+    if mtp:
         with jax.named_scope("mv.lm.mtp"):
             p = _sub(params, "mtp")
             joined = jnp.concatenate(
@@ -476,7 +616,7 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
                  rms_norm(x, p["hnorm"], cfg.eps)], -1)
             y = matmul(joined, p["eh_proj"], False, cfg.compute_dtype,
                        jnp.float32)
-            y, a = _run_block(y, p, bias[len(names) - 1], cfg)
+            y, a = _run_block(y, p, mtp[0], bias[len(aux)], cfg)
             aux.append(a)
             module = _chunked_ce(
                 rms_norm(y, p["out_norm"], cfg.eps).reshape(b * s, -1),
@@ -484,9 +624,10 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
                 jnp.broadcast_to(position < s - 2, (b, s)).reshape(-1)
                 .astype(jnp.float32), cfg) / (b * (s - 2))
         loss = main + cfg.mtp_weight * module
-    counts = jnp.stack([c for c, _ in aux])
-    overflow = jnp.stack([o for _, o in aux])
-    return loss, (counts, overflow)
+    counts, overflow, balance = (jnp.stack(a) for a in zip(*aux))
+    if cfg.balance_coef:
+        loss = loss + cfg.balance_coef * jnp.sum(balance)
+    return loss, (counts, overflow, balance)
 
 
 # ---------------------------------------------------------------------- #
@@ -500,53 +641,96 @@ def _params_of(states, shapes):
     return out
 
 
-def make_train_step(cfg: MLAMoEConfig, tables: Dict[str, Any],
+def _with_overflow(counts, overflow):
+    return jnp.concatenate([counts, overflow[:, None]], axis=1)
+
+
+def make_train_step(cfg, tables: Dict[str, Any],
                     opt: Optional[AddOption] = None):
-    """``step(states, bias, tokens) -> (states, bias, loss, counts)``.
+    """``step(states, bias, tokens) -> (states, bias, loss, counts,
+    balance)``.
 
     ``states`` maps each table's name to its ``program_state()``; jit with
     ``donate_argnums=(0, 1)``. Reads ``state["data"]``, computes loss and
     float32 gradients (each block rematerialised, the two losses in
     chunks of positions), hands each gradient to its table's updater
     through ``functional_add`` (the learning rate is ``opt``'s), applies
-    the selection biases' rule, and returns the states to be adopted, the
-    new biases, the loss and one int32 array [layers, E + 1]: the tokens
-    that chose each expert, and in the last column the rows that
-    overflowed the held experts' buffer."""
+    the selection biases' rule where a layer routes under one, and
+    returns the states to be adopted, the new biases, the loss, one int32
+    array [layers, E + 1] (the tokens that chose each expert, and in the
+    last column the rows that overflowed the held experts' buffer) and
+    the load-balance term as it stands in the loss (0 without one)."""
     shapes = param_shapes(cfg)
     opt = opt or AddOption(learning_rate=1e-4)
+    biased = cfg.route == "sigmoid"     # the route that selects under a bias
 
     def step(states, bias, tokens):
         params = _params_of(states, shapes)
-        (loss, (counts, overflow)), grads = jax.value_and_grad(
+        (loss, (counts, overflow, balance)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, bias, tokens, cfg)
         new = {}
         for name, table in tables.items():
             delta = table.pad_delta(grads[name].reshape(
                 table_shape(shapes[name])))
             new[name] = table.functional_add(states[name], delta, opt)
-        bias = moe.bias_update(bias, counts, cfg.bias_speed)
-        return new, bias, loss, jnp.concatenate(
-            [counts, overflow[:, None]], axis=1)
+        if biased:
+            bias = moe.bias_update(bias, counts, cfg.bias_speed)
+        return (new, bias, loss, _with_overflow(counts, overflow),
+                cfg.balance_coef * jnp.sum(balance))
 
     return step
 
 
-def make_forward(cfg: MLAMoEConfig):
+def make_forward(cfg):
     """``forward(states, bias, tokens) -> (loss, counts [layers, E + 1])``
     on the tables' states, nothing written: what a calibration of the
     selection biases runs."""
     shapes = param_shapes(cfg)
 
     def forward(states, bias, tokens):
-        loss, (counts, overflow) = loss_fn(
+        loss, (counts, overflow, _) = loss_fn(
             _params_of(states, shapes), bias, tokens, cfg)
-        return loss, jnp.concatenate([counts, overflow[:, None]], axis=1)
+        return loss, _with_overflow(counts, overflow)
 
     return forward
 
 
-def routing_counts(counts: np.ndarray, cfg: MLAMoEConfig) -> Dict[str, Any]:
+def make_balance_step(cfg, tables: Dict[str, Any]):
+    """``step(routers, others, bias, tokens, rate) -> (routers, counts
+    [layers, E + 1], balance [layers])``: the load-balance terms ALONE
+    move the routers' tables ALONE, through ``functional_add`` at the
+    learning rate ``rate`` (a traced number: one program for a whole
+    schedule). ``routers`` are the states of the ``<layer>.router``
+    tables (jit with ``donate_argnums=(0,)``), ``others`` every other
+    table's. Each layer's term is differentiated with the layer's input
+    held fixed: the path through the router's own probabilities, which
+    is what the term is for; what a router's choice does to the layers
+    after it is left out. The prediction module is no part of it."""
+    shapes = param_shapes(cfg)
+    names = [name + ".router" for name in expert_layers(cfg)
+             if name != "mtp"]
+
+    def step(routers, others, bias, tokens, rate):
+        params = _params_of({**others, **routers}, shapes)
+
+        def terms(moved):
+            _, aux = _trunk({**params, **moved}, bias, tokens, cfg,
+                            still=True)
+            counts, overflow, balance = (jnp.stack(a) for a in zip(*aux))
+            return jnp.sum(balance), (_with_overflow(counts, overflow),
+                                      balance)
+
+        (_, (counts, balance)), grads = jax.value_and_grad(
+            terms, has_aux=True)({n: params[n] for n in names})
+        opt = AddOption(learning_rate=rate)
+        new = {n: tables[n].functional_add(
+            routers[n], tables[n].pad_delta(grads[n]), opt) for n in names}
+        return new, counts, balance
+
+    return step
+
+
+def routing_counts(counts: np.ndarray, cfg) -> Dict[str, Any]:
     """What a step's [layers, E + 1] array says: ``routed_rows`` (token x
     expert assignments over all layers), ``held_rows`` (those to experts
     held here), ``overflow_rows``, ``load_max_over_mean`` (the busiest of
@@ -569,7 +753,7 @@ class Trainer:
     records each step as an ``lm.step`` span. :meth:`adopt` hands the
     states back to the tables at the end."""
 
-    def __init__(self, cfg: MLAMoEConfig, tables: Dict[str, Any],
+    def __init__(self, cfg, tables: Dict[str, Any],
                  opt: Optional[AddOption] = None,
                  bias: Optional[jax.Array] = None):
         self.cfg, self.tables = cfg, tables
@@ -578,7 +762,8 @@ class Trainer:
                              donate_argnums=(0, 1))
         self.states = {n: t.program_state() for n, t in tables.items()}
         self.steps = 0
-        self._ahead = None      # (loss, counts) of a step not read back yet
+        # (loss, counts, balance) of a step not read back yet
+        self._ahead = None
         self._attn: Dict[str, int] = {}     # attn_grid of the first step
 
     def _turn(self, tokens, ahead: bool):
@@ -591,10 +776,9 @@ class Trainer:
                 if self.steps == 1:     # one program, one shape
                     self._attn = attn_grid(self.cfg, int(tokens.shape[1]))
                 sp.set(tokens=int(np.prod(tokens.shape)))
-                self.states, self.bias, loss, counts = self._step(
+                self.states, self.bias, *back = self._step(
                     self.states, self.bias, tokens)
-                due, self._ahead = ((due, (loss, counts)) if ahead
-                                    else ((loss, counts), None))
+                due, self._ahead = ((due, back) if ahead else (back, None))
             else:
                 self._ahead = None
             sp.set(**self._attn)
@@ -602,8 +786,10 @@ class Trainer:
                 return None
             with _trace.span("lm.step.wait"):
                 # one read-back a step: it waits for the whole program
-                loss, counts = jax.device_get(due)
+                loss, counts, balance = jax.device_get(due)
             sp.set(**routing_counts(counts, self.cfg))
+            if self.cfg.balance_coef:
+                sp.set(aux_loss=float(balance))
         return float(loss), counts
 
     def step(self, tokens) -> Tuple[float, np.ndarray]:

@@ -35,6 +35,27 @@ skipped by ``pl.when`` and every live pair masked (the parent) 17.0 /
 at 1,024 x 512 and 10.9 / 13.4 / 17.6 at 512 x 1,024; the rectangle with
 a dead step's index maps clamped to the resident block 14.6 / 15.4 / 17.9.
 1,024 x 1,024 and 512 x 2,048 do not fit the kernels' VMEM.
+
+A causal call may carry a ``window`` (position ``i`` sees ``j`` only where
+``i - j < window``): the pair table then holds the band alone, so a pair
+under the band costs no grid step and no K/V copy either, in all three
+walks, and a pair is masked only where the diagonal or the band's far edge
+crosses it. And it may have fewer key-value heads than query heads: K and
+V come in and dK and dV go out at the key-value heads' shape, never
+repeated; forward and dQ read block ``b // group`` of them, and dK with dV
+walks the key-value heads with the group's query heads innermost in its
+table, so one accumulator sums the group. Measured on one v5e chip at q
+(2, 32, 8192, 128) over k, v (2, 4, 8192, 128), bfloat16, ms a call forward
+(with the residual) / dQ / dK with dV (builder's chip run, PR 35; PERF.md
+section 6), causal: 20.5 / 12.9 / 16.8 at 512 x 512, 11.9 / 12.0 / 15.3 at
+512 x 1,024, **10.3 / 11.1 / 14.9 at 1,024 x 1,024**, which fits at this head
+size, 11.5 / 12.3 / 16.0 at 512 x 2,048; under a window of 1,024 (23% of
+the triangle's positions): 7.5 / 4.8 / 6.1 at 512 x 512 (45 pairs a head),
+5.7 / 5.8 / 6.9 at 512 x 1,024 (30), **5.1 / 5.4 / 6.8 at 1,024 x 1,024**
+(15, every one masked, twice the band's area), 13.8 / 7.6 / 9.5 at 256 x
+256 (150). Against plain float32 attention on the chip the outputs and
+the three gradients differ by 0.0020 to 0.0027 of their norm, grouped or
+not, banded or not.
 """
 
 from __future__ import annotations
@@ -63,28 +84,45 @@ def _blocks(s: int, block_q: int, block_k: int) -> Tuple[int, int]:
     return block_q, block_k
 
 
-def live_pairs(s: int, block_q: int, block_k: int, q_inner: bool = False
+def _band(s: int, window: Optional[int]) -> Optional[int]:
+    """``window`` as the walks use it: ``None`` where it hides nothing
+    (none given, or one of ``s`` positions or more)."""
+    return None if window is None or window >= s else int(window)
+
+
+def live_pairs(s: int, block_q: int, block_k: int, q_inner: bool = False,
+               window: Optional[int] = None
                ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """The (q block, k block) pairs of a causal call that hold a position
-    at or under the diagonal, in the order a kernel walks them: row-major
-    (k innermost: forward and dQ) or, with ``q_inner``, column-major
-    (dK with dV). Returns ``(qi, kj, crossing)``; a pair is *crossing*
-    when the diagonal passes through it and *interior* (no mask needed)
-    when its last k position is at or before its first q position."""
+    """The (q block, k block) pairs of a causal call that hold a live
+    position, in the order a kernel walks them: row-major (k innermost:
+    forward and dQ) or, with ``q_inner``, column-major (dK with dV).
+    Position ``i`` sees ``j`` where ``0 <= i - j``, and with a ``window``
+    also ``i - j < window`` (a band under the diagonal; a window of ``s``
+    or more is none, and gives the causal table entry for entry). Returns
+    ``(qi, kj, crossing)``; a pair is *crossing* when the diagonal or the
+    band's far edge passes through it, and *interior* (no mask needed)
+    when every one of its positions is live."""
     block_q, block_k = _blocks(s, block_q, block_k)
+    window = _band(s, window)
     i, j = np.indices((s // block_q, s // block_k), dtype=np.int32)
     if q_inner:
         i, j = i.T, j.T
     live = j * block_k <= i * block_q + block_q - 1
-    i, j = i[live], j[live]
-    return i, j, j * block_k + block_k - 1 > i * block_q
+    crossing = j * block_k + block_k - 1 > i * block_q
+    if window is not None:
+        # the nearest (q, k) of the pair is inside the band; the farthest
+        # is outside it
+        live &= i * block_q - (j * block_k + block_k - 1) < window
+        crossing |= i * block_q + block_q - 1 - j * block_k >= window
+    return i[live], j[live], crossing[live]
 
 
-def causal_pairs(s: int, block_q: int, block_k: int) -> Dict[str, int]:
+def causal_pairs(s: int, block_q: int, block_k: int,
+                 window: Optional[int] = None) -> Dict[str, int]:
     """What ONE causal kernel call does for one (batch x head): the grid
     steps it takes, the pairs among them that compute, and those of them
     that take the masked path."""
-    _, _, crossing = live_pairs(s, block_q, block_k)
+    _, _, crossing = live_pairs(s, block_q, block_k, window=window)
     return {"grid_steps": int(crossing.size), "live": int(crossing.size),
             "masked": int(crossing.sum())}
 
@@ -92,15 +130,26 @@ def causal_pairs(s: int, block_q: int, block_k: int) -> Dict[str, int]:
 class _Walk(NamedTuple):
     """How a kernel's grid visits the (q block i, k block j) pairs. A
     causal call walks the table of :func:`live_pairs`, handed to the
-    kernel as two scalar-prefetch operands (grid ``(bh, pairs)``): no grid
-    step and no block copy for a pair above the diagonal. Any other call
-    walks the rectangle. Either way the inner index is the accumulator's:
-    k for forward and dQ, q (``q_inner``) for dK with dV."""
+    kernel as scalar-prefetch operands (grid ``(bh, pairs)``): no grid
+    step and no block copy for a pair above the diagonal or, with a
+    ``window``, under the band. Any other call walks the rectangle.
+    Either way the inner index is the accumulator's: k for forward and
+    dQ, q (``q_inner``) for dK with dV.
+
+    ``group`` query heads read one key-value head (K and V arrive with a
+    ``group``-th of q's heads and are never repeated): a q-shaped operand
+    of (batch x head) ``b`` goes with the k-shaped one of ``b // group``.
+    Forward and dQ walk the query heads. dK with dV walks the KEY-VALUE
+    heads, and each step of its table names one of the group's query
+    heads as well (``g`` innermost), so the accumulators of a k block sum
+    over the group before they are written once."""
     causal: bool
     s: int
     block_q: int
     block_k: int
     q_inner: bool
+    window: Optional[int] = None
+    group: int = 1
 
     @property
     def nq(self) -> int:
@@ -110,32 +159,57 @@ class _Walk(NamedTuple):
     def nk(self) -> int:
         return self.s // self.block_k
 
+    @property
+    def _group_walk(self) -> bool:
+        return self.q_inner and self.group > 1
+
+    def table(self) -> Tuple[np.ndarray, ...]:
+        """The scalar-prefetch operands: (qi, kj) of the live pairs, and
+        for the grouped dK with dV walk each pair once a query head of
+        the group, with that head's number as the third."""
+        if not self.causal:
+            return ()
+        qi, kj, _ = live_pairs(self.s, self.block_q, self.block_k,
+                               self.q_inner, self.window)
+        if not self._group_walk:
+            return qi, kj
+        g = np.tile(np.arange(self.group, dtype=np.int32), qi.size)
+        return np.repeat(qi, self.group), np.repeat(kj, self.group), g
+
     def specs(self, d: int) -> Tuple[pl.BlockSpec, ...]:
         """The block specs of a q-shaped operand, a k-shaped one and the
         logsumexp residual, at this walk's (q block, k block)."""
-        def spec(rows, lanes, at):      # at(b, i, j) -> block index
-            if self.causal:
-                index = lambda b, t, qi, kj: at(b, qi[t], kj[t])
+        group = self.group
+
+        def spec(rows, lanes, at):      # at(b, i, j, g) -> block index
+            if self._group_walk:
+                index = lambda b, t, qi, kj, g: at(b, qi[t], kj[t], g[t])
+            elif self.causal:
+                index = lambda b, t, qi, kj: at(b, qi[t], kj[t], 0)
             elif self.q_inner:
-                index = lambda b, j, i: at(b, i, j)
+                index = lambda b, j, i: at(b, i, j, 0)
             else:
-                index = at
+                index = lambda b, i, j: at(b, i, j, 0)
             return pl.BlockSpec((1, rows, lanes), index)
 
-        of_q, of_k = (lambda b, i, j: (b, i, 0)), (lambda b, i, j: (b, j, 0))
+        if self.q_inner:        # the grid's b is a key-value head
+            of_q = lambda b, i, j, g: (b * group + g, i, 0)
+            of_k = lambda b, i, j, g: (b, j, 0)
+        else:                   # the grid's b is a query head
+            of_q = lambda b, i, j, g: (b, i, 0)
+            of_k = lambda b, i, j, g: (b // group, j, 0)
         return (spec(self.block_q, d, of_q), spec(self.block_k, d, of_k),
                 spec(self.block_q, _RES_LANES, of_q))
 
     def call(self, kernel, bh: int, operands, *, interpret: bool,
              out_shape, **specs):
         """``pl.pallas_call`` of ``kernel`` over ``bh`` (batch x head)s of
-        this walk; ``specs`` are the grid spec's in, out and scratch."""
+        this walk (key-value heads for ``q_inner``, else query heads);
+        ``specs`` are the grid spec's in, out and scratch."""
+        table = self.table()
         if self.causal:
-            table = live_pairs(self.s, self.block_q, self.block_k,
-                               self.q_inner)[:2]
             grid, inner = (bh, len(table[0])), ("arbitrary",)
         else:
-            table = ()
             grid = ((bh, self.nk, self.nq) if self.q_inner
                     else (bh, self.nq, self.nk))
             inner = ("parallel", "arbitrary")
@@ -152,24 +226,37 @@ class _Walk(NamedTuple):
     def enter(self, refs):
         """In the kernel: ``(i, j, first, last, crossing, refs)`` of this
         grid step, ``refs`` without the table's. ``first`` / ``last`` say
-        whether the pair opens / closes its accumulator's row (column for
+        whether the step opens / closes its accumulator's row (column for
         ``q_inner``): scalar arithmetic on the pair. ``crossing`` is
         ``None`` where nothing is masked at all."""
-        bq, bk = self.block_q, self.block_k
+        bq, bk, w = self.block_q, self.block_k, self.window
+        n = 2 + self._group_walk if self.causal else 0     # the table's refs
         if self.causal:
             t = pl.program_id(1)
-            i, j, refs = refs[0][t], refs[1][t], refs[2:]
+            i, j = refs[0][t], refs[1][t]
             crossing = j * bk + bk - 1 > i * bq
+            if w is not None:
+                crossing |= i * bq + bq - 1 - j * bk >= w
         else:
             x, y = pl.program_id(1), pl.program_id(2)
             (i, j), crossing = ((y, x) if self.q_inner else (x, y)), None
         if self.q_inner:
-            lo = jax.lax.div(j * bk, bq) if self.causal else 0
-            return i, j, i == lo, i == self.nq - 1, crossing, refs
-        hi = self.nk - 1
+            lo, hi = 0, self.nq - 1
+            if self.causal:
+                lo = jax.lax.div(j * bk, bq)
+            if w is not None:
+                hi = jnp.minimum(hi, jax.lax.div(w + j * bk + bk - 2, bq))
+            first, last = i == lo, i == hi
+            if self._group_walk:
+                g = refs[2][t]
+                first, last = first & (g == 0), last & (g == self.group - 1)
+            return i, j, first, last, crossing, refs[n:]
+        lo, hi = 0, self.nk - 1
         if self.causal:
             hi = jnp.minimum(hi, jax.lax.div(i * bq + bq - 1, bk))
-        return i, j, j == 0, j == hi, crossing, refs
+        if w is not None:
+            lo = jnp.maximum(lo, jax.lax.div(i * bq - w + 1, bk))
+        return i, j, j == lo, j == hi, crossing, refs[n:]
 
 
 def _masked_or_not(crossing, tile: Callable[[bool], None]) -> None:
@@ -182,10 +269,13 @@ def _masked_or_not(crossing, tile: Callable[[bool], None]) -> None:
     pl.when(jnp.logical_not(crossing))(lambda: tile(False))
 
 
-def _causal_mask(s, i, j, block_q: int, block_k: int):
-    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + i * block_q
-    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * block_k
-    return jnp.where(qpos >= kpos, s, _NEG_INF)
+def _causal_mask(s, i, j, walk: _Walk):
+    qpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + i * walk.block_q
+    kpos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * walk.block_k
+    seen = qpos >= kpos
+    if walk.window is not None:
+        seen &= qpos - kpos < walk.window
+    return jnp.where(seen, s, _NEG_INF)
 
 
 def _flash_kernel(*refs, walk: _Walk, scale: float, emit_lse: bool):
@@ -210,7 +300,7 @@ def _flash_kernel(*refs, walk: _Walk, scale: float, emit_lse: bool):
             qb, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (bq, bk)
         if masked:
-            s = _causal_mask(s, i, j, walk.block_q, walk.block_k)
+            s = _causal_mask(s, i, j, walk)
         m_prev = m_ref[...][:, :1]                        # (bq, 1)
         l_prev = l_ref[...][:, :1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -243,20 +333,42 @@ def _flash_kernel(*refs, walk: _Walk, scale: float, emit_lse: bool):
                                           lse_ref.shape[1:])
 
 
-def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
-                   interpret: bool, with_lse: bool):
-    b, h, s, d = q.shape
+def _heads(q, k, causal: bool) -> Tuple[int, int]:
+    """(batch x key-value heads, query heads a key-value head) of a call
+    on q [B, H, S, D] and k [B, Hkv, S, D]."""
+    b, h, hkv = q.shape[0], q.shape[1], k.shape[1]
+    if h % hkv:
+        raise ValueError(f"{h} query heads do not divide over {hkv} "
+                         "key-value heads")
+    if h != hkv and not causal:
+        raise ValueError("grouped-query heads take a causal call")
+    return b * hkv, h // hkv
+
+
+def _walk_of(q, k, causal: bool, block_q: int, block_k: int,
+             window: Optional[int]) -> Tuple[_Walk, int]:
+    """(the forward's and dQ's walk, batch x key-value heads) of a call."""
+    s = q.shape[2]
     block_q, block_k = _blocks(s, block_q, block_k)
-    bh = b * h
-    walk = _Walk(causal, s, block_q, block_k, False)
-    flat = lambda t: t.reshape(bh, s, d)
+    bkv, group = _heads(q, k, causal)
+    return _Walk(causal, s, block_q, block_k, False,
+                 _band(s, window) if causal else None, group), bkv
+
+
+def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
+                   interpret: bool, with_lse: bool,
+                   window: Optional[int] = None):
+    b, h, s, d = q.shape
+    walk, bkv = _walk_of(q, k, causal, block_q, block_k, window)
+    block_q, bh = walk.block_q, b * h
     qspec, kspec, lspec = walk.specs(d)
     oshape = jax.ShapeDtypeStruct((bh, s, d), q.dtype)
     lshape = jax.ShapeDtypeStruct((bh, s, _RES_LANES), jnp.float32)
     res = walk.call(
         functools.partial(_flash_kernel, walk=walk, scale=1.0 / (d ** 0.5),
                           emit_lse=with_lse),
-        bh, (flat(q), flat(k), flat(v)), interpret=interpret,
+        bh, (q.reshape(bh, s, d), k.reshape(bkv, s, d),
+             v.reshape(bkv, s, d)), interpret=interpret,
         in_specs=[qspec, kspec, kspec],
         out_specs=[qspec, lspec] if with_lse else [qspec],
         out_shape=[oshape, lshape] if with_lse else [oshape],
@@ -270,7 +382,7 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
 
 
 def _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, i, j, *,
-              scale: float, masked: bool, block_q: int, block_k: int):
+              scale: float, masked: bool, walk: _Walk):
     """Shared backward recompute for ONE (q-block i, k-block j) tile:
     returns (p, ds) with ds already scale-folded — the one definition of
     the tile math, so the dQ and dK/dV kernels cannot desynchronize.
@@ -285,7 +397,7 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, i, j, *,
         qb, kb, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale           # (bq, bk)
     if masked:
-        s = _causal_mask(s, i, j, block_q, block_k)
+        s = _causal_mask(s, i, j, walk)
     p = jnp.exp(s - lse)               # masked entries: exp(-inf-..) = 0
     dp = jax.lax.dot_general(
         dob, vb, (((1,), (1,)), ((), ())),
@@ -304,8 +416,7 @@ def _bwd_dq_kernel(*refs, walk: _Walk, scale: float):
 
     def tile(masked: bool):
         _, ds = _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                          i, j, scale=scale, masked=masked,
-                          block_q=walk.block_q, block_k=walk.block_k)
+                          i, j, scale=scale, masked=masked, walk=walk)
         acc_ref[...] += jax.lax.dot_general(
             ds, k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # (bq, d)
@@ -329,8 +440,7 @@ def _bwd_dkv_kernel(*refs, walk: _Walk, scale: float):
 
     def tile(masked: bool):
         p, ds = _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                          i, j, scale=scale, masked=masked,
-                          block_q=walk.block_q, block_k=walk.block_k)
+                          i, j, scale=scale, masked=masked, walk=walk)
         dob = do_ref[0]
         dv_acc[...] += jax.lax.dot_general(
             p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
@@ -348,36 +458,36 @@ def _bwd_dkv_kernel(*refs, walk: _Walk, scale: float):
 
 
 def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
-                    block_k: int, interpret: bool):
+                    block_k: int, interpret: bool,
+                    window: Optional[int] = None):
     b, h, s, d = q.shape
-    block_q, block_k = _blocks(s, block_q, block_k)
-    bh = b * h
+    walk, bkv = _walk_of(q, k, causal, block_q, block_k, window)
+    block_q, block_k, bh = walk.block_q, walk.block_k, b * h
     scale = 1.0 / (d ** 0.5)
-    flat = lambda t: t.reshape(bh, s, d)
-    operands = (flat(q), flat(k), flat(v), flat(out), flat(do), lse)
-    like = lambda t: jax.ShapeDtypeStruct((bh, s, d), t.dtype)
-
-    walk = _Walk(causal, s, block_q, block_k, False)
+    operands = (q.reshape(bh, s, d), k.reshape(bkv, s, d),
+                v.reshape(bkv, s, d), out.reshape(bh, s, d),
+                do.reshape(bh, s, d), lse)
     qspec, kspec, rspec = walk.specs(d)
     dq = walk.call(
         functools.partial(_bwd_dq_kernel, walk=walk, scale=scale),
         bh, operands, interpret=interpret,
         in_specs=[qspec, kspec, kspec, qspec, qspec, rspec],
-        out_specs=qspec, out_shape=like(q),
+        out_specs=qspec, out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)])
 
-    # dK/dV walk q-blocks innermost
+    # dK/dV walk the key-value heads, q-blocks (and the group) innermost
     walk = walk._replace(q_inner=True)
     qspec, kspec, rspec = walk.specs(d)
     dk, dv = walk.call(
         functools.partial(_bwd_dkv_kernel, walk=walk, scale=scale),
-        bh, operands, interpret=interpret,
+        bkv, operands, interpret=interpret,
         in_specs=[qspec, kspec, kspec, qspec, qspec, rspec],
-        out_specs=[kspec, kspec], out_shape=[like(k), like(v)],
+        out_specs=[kspec, kspec],
+        out_shape=[jax.ShapeDtypeStruct((bkv, s, d), k.dtype),
+                   jax.ShapeDtypeStruct((bkv, s, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)])
-    shape = (b, h, s, d)
-    return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -387,29 +497,34 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
             else interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: Optional[bool] = None):
-    """Fused attention over [B, H, S, D]; S must divide by the block sizes
-    (blocks auto-clamp to S when S < 128). ``interpret=None`` auto-selects
-    interpreter mode off-TPU (tests); pass False to force the compiled path.
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None):
+    """Fused attention over q [B, H, S, D] and k, v [B, Hkv, S, D]; S must
+    divide by the block sizes (blocks auto-clamp to S when S < 128). With
+    ``Hkv < H`` (a causal call) query head ``h`` reads key-value head
+    ``h // (H / Hkv)``, and dK and dV come out at k's shape. ``window``
+    (a causal call's): position ``i`` sees ``j`` only where ``i - j <
+    window``. ``interpret=None`` auto-selects interpreter mode off-TPU
+    (tests); pass False to force the compiled path.
     """
     out, _ = _flash_forward(q, k, v, causal, block_q, block_k,
-                            _resolve_interpret(interpret), with_lse=False)
+                            _resolve_interpret(interpret), False, window)
     return out
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret):
+def _fwd(q, k, v, causal, block_q, block_k, interpret, window):
     out, lse = _flash_forward(q, k, v, causal, block_q, block_k,
-                              _resolve_interpret(interpret), with_lse=True)
+                              _resolve_interpret(interpret), True, window)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, block_q, block_k, interpret, res, g):
+def _bwd(causal, block_q, block_k, interpret, window, res, g):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
-                           _resolve_interpret(interpret))
+                           _resolve_interpret(interpret), window)
 
 
 flash_attention.defvjp(_fwd, _bwd)

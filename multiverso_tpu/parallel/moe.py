@@ -22,7 +22,8 @@ Design choices, TPU-first:
 The second layer here, :func:`held_expert_layer`, is one chip's share of
 a layer whose experts are divided over several chips: it is told which
 experts it holds (``experts_held``, ``expert_offset``), routes over all
-of them (sigmoid scores, a selection bias that takes no gradient), and
+of them (sigmoid scores under a selection bias that takes no gradient,
+or a softmax with the load-balance term its family trains by), and
 computes its own experts' part of the result without dropping a token.
 The rows routed here lie sorted by expert in one buffer, and the three
 products run over that buffer as grouped matrix products (Pallas
@@ -225,8 +226,11 @@ class HeldExperts(NamedTuple):
     top_k: int = 1
     routed_scale: float = 1.0
     buffer_rows: Optional[int] = None
-    tile: int = 128              # the grouped products' tile (m, k, n)
+    # the grouped products' tile (rows, over dim, over ffn):
+    # ``product_tile`` chooses it from the widths
+    tile: Tuple[int, int, int] = (128, 128, 128)
     dtype: Any = jnp.bfloat16    # the products' operands (float32 sums)
+    route: str = "sigmoid"       # or "softmax"
 
 
 def sigmoid_route(u: jax.Array, router: jax.Array, bias: jax.Array,
@@ -251,6 +255,28 @@ def sigmoid_route(u: jax.Array, router: jax.Array, bias: jax.Array,
     return chosen, gates, counts
 
 
+def softmax_route(u: jax.Array, router: jax.Array, cfg: HeldExperts):
+    """Probabilities ``softmax(u W_r)`` in float32 over every expert
+    (``router`` is [E, D]); the ``top_k`` largest are chosen, gates are
+    the chosen probabilities over their sum: no bias, no scale. Returns
+    (chosen [T, K], gates [T, K] float32, counts [E] int32, balance): the
+    last is the load-balance term ``E * sum_e f_e P_e``, ``f_e`` the share
+    of the T x K assignments that chose ``e`` (no gradient) and ``P_e``
+    the mean of ``p_e`` over the tokens: 1 where the load is even."""
+    with jax.named_scope("mv.lm.moe.route"):
+        logits = jax.lax.dot_general(
+            u.astype(jnp.float32), router.astype(jnp.float32),
+            (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, -1)
+        picked, chosen = jax.lax.top_k(probs, cfg.top_k)
+        gates = picked / picked.sum(-1, keepdims=True)
+        counts = (chosen[..., None] == jnp.arange(cfg.num_experts)).sum(
+            (0, 1), dtype=jnp.int32)
+        share = counts.astype(jnp.float32) / (u.shape[0] * cfg.top_k)
+        balance = cfg.num_experts * jnp.sum(share * probs.mean(0))
+    return chosen, gates, counts, balance
+
+
 def bias_update(bias: jax.Array, counts: jax.Array, speed) -> jax.Array:
     """The selection bias's rule: ``b_e += speed * sign(mean(c) - c_e)``
     (an expert under the mean load is made likelier, one over it less)."""
@@ -258,31 +284,48 @@ def bias_update(bias: jax.Array, counts: jax.Array, speed) -> jax.Array:
     return bias + speed * jnp.sign(c.mean(-1, keepdims=True) - c)
 
 
+def product_tile(dim: int, ffn: int) -> Tuple[int, int, int]:
+    """The grouped products' tile (over rows, over ``dim``, over ``ffn``)
+    from the widths. A width that divides by 512 takes 512; another takes
+    the largest multiple of 128 up to 1,024 that divides it (2,304 = 18 x
+    128 takes 768, 896 = 7 x 128 takes itself: at 128 cubed a 65,536-row
+    buffer is 64,512 grid steps a product), and the kernel's own 128 where
+    there is none. Rows go by 512 where both widths' tiles reach it."""
+    def of(width: int) -> int:
+        if width % 512 == 0:
+            return 512
+        fits = [t for t in range(128, 1025, 128) if width % t == 0]
+        return fits[-1] if fits else 128
+
+    over_dim, over_ffn = of(dim), of(ffn)
+    return 512 if min(over_dim, over_ffn) >= 512 else 128, over_dim, over_ffn
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def grouped_matmul(lhs, rhs, group_sizes, tile: int, interpret: bool,
+def grouped_matmul(lhs, rhs, group_sizes, tile, interpret: bool,
                    dtype=jnp.bfloat16):
     """``lhs[rows of group g] @ rhs[g]`` for consecutive row groups of
     ``group_sizes`` (they sum to ``lhs.shape[0]``) as one Pallas kernel
     (``megablox``): operands and result in ``dtype`` (``lhs`` comes in
     it), float32 accumulation. ``rhs`` is float32 [G, K, N] (a table's
-    data) and takes a float32 gradient."""
+    data) and takes a float32 gradient. ``tile``: (m, k, n) of THIS
+    product."""
     return _gmm_fwd(lhs, rhs, group_sizes, tile, interpret, dtype)[0]
 
 
 def _gmm_fwd(lhs, rhs, group_sizes, tile, interpret, dtype):
     rhs = rhs.astype(dtype)
-    out = _gmm(lhs, rhs, group_sizes, dtype, (tile, tile, tile),
-               interpret=interpret)
+    out = _gmm(lhs, rhs, group_sizes, dtype, tile, interpret=interpret)
     return out, (lhs, rhs, group_sizes)
 
 
 def _gmm_bwd(tile, interpret, dtype, res, g):
     lhs, rhs, group_sizes = res
     g = g.astype(dtype)
-    tiling = (tile, tile, tile)
-    d_lhs = _gmm(g, rhs, group_sizes, dtype, tiling, transpose_rhs=True,
+    m, k, n = tile
+    d_lhs = _gmm(g, rhs, group_sizes, dtype, (m, n, k), transpose_rhs=True,
                  interpret=interpret)
-    d_rhs = _tgmm(lhs.swapaxes(0, 1), g, group_sizes, jnp.float32, tiling,
+    d_rhs = _tgmm(lhs.swapaxes(0, 1), g, group_sizes, jnp.float32, (m, k, n),
                   num_actual_groups=rhs.shape[0], interpret=interpret)
     return d_lhs, d_rhs, None
 
@@ -306,7 +349,10 @@ def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
     [H, D, F] and ``w_down`` [H, F, D] for the H held experts (numbers
     ``expert_offset`` to ``expert_offset + H - 1`` of the E). Returns
     (``sum over the chosen experts held here of gate * expert(u)`` [T, D]
-    float32, counts [E] int32, overflow_rows int32). What the absent
+    float32, counts [E] int32, overflow_rows int32, balance float32).
+    ``cfg.route`` names the route: ``"sigmoid"`` (:func:`sigmoid_route`
+    under ``bias``; balance is 0) or ``"softmax"`` (:func:`softmax_route`,
+    which has no bias; balance is its load-balance term). What the absent
     experts would add is left out. The rows routed here are sorted by
     expert into a buffer of ``buffer_rows`` rows; the padding after them
     is zero rows that the last expert's group takes, so the products do
@@ -320,9 +366,17 @@ def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
     held, k = cfg.experts_held, cfg.top_k
     if kernel is None:
         kernel = "pallas" if jax.devices()[0].platform == "tpu" else "xla"
-    chosen, gates, counts = sigmoid_route(u, params["router"], bias, cfg)
+    if cfg.route == "softmax":
+        chosen, gates, counts, balance = softmax_route(u, params["router"],
+                                                       cfg)
+    elif cfg.route == "sigmoid":
+        chosen, gates, counts = sigmoid_route(u, params["router"], bias, cfg)
+        balance = jnp.zeros((), jnp.float32)
+    else:
+        raise ValueError(f"no route named {cfg.route!r}")
+    tile = cfg.tile             # over rows, over dim and over ffn
     rows = cfg.buffer_rows or t * min(k, held)
-    rows = -(-rows // cfg.tile) * cfg.tile
+    rows = -(-rows // tile[0]) * tile[0]
     with jax.named_scope("mv.lm.moe.dispatch"):
         local = chosen.reshape(-1) - cfg.expert_offset          # [T*K]
         here = (local >= 0) & (local < held)
@@ -343,16 +397,18 @@ def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
         gate = jnp.where(live, gates.reshape(-1)[take], 0.0)
     with jax.named_scope("mv.lm.moe.experts"):
         if kernel == "xla":
-            mm = functools.partial(_grouped_matmul_xla, group_sizes=groups,
-                                   dtype=cfg.dtype)
+            up = down = functools.partial(
+                _grouped_matmul_xla, group_sizes=groups, dtype=cfg.dtype)
         else:
             mm = functools.partial(grouped_matmul, group_sizes=groups,
-                                   tile=cfg.tile, dtype=cfg.dtype,
+                                   dtype=cfg.dtype,
                                    interpret=kernel == "interpret")
-        h = (jax.nn.silu(mm(x, params["w_gate"]).astype(jnp.float32))
-             * mm(x, params["w_up"]).astype(jnp.float32))
-        y = mm(h.astype(cfg.dtype), params["w_down"])
+            up = functools.partial(mm, tile=tile)       # dim -> ffn
+            down = functools.partial(mm, tile=(tile[0], tile[2], tile[1]))
+        h = (jax.nn.silu(up(x, params["w_gate"]).astype(jnp.float32))
+             * up(x, params["w_up"]).astype(jnp.float32))
+        y = down(h.astype(cfg.dtype), params["w_down"])
     with jax.named_scope("mv.lm.moe.combine"):
         out = jnp.zeros((t, d), jnp.float32).at[token].add(
             y.astype(jnp.float32) * gate[:, None])
-    return out, counts, overflow
+    return out, counts, overflow, balance

@@ -137,3 +137,129 @@ def test_inference_call_without_the_residual_matches_too():
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(_xla_attention(q, k, v)),
                                rtol=2e-4, atol=2e-5)
+
+
+# ---------------------------------------------------------------------- #
+# the band: a window under the diagonal; grouped-query heads
+# ---------------------------------------------------------------------- #
+# (s, block_q, block_k, window): a window under a block, of a block, of
+# several, one that cuts blocks unevenly, and of s or more (none at all)
+BANDS = [(256, 32, 32, 8), (256, 32, 32, 32), (256, 32, 32, 96),
+         (256, 64, 32, 48), (256, 32, 64, 100), (192, 64, 32, 1),
+         (256, 32, 32, 256), (256, 32, 64, 1000), (8192, 512, 512, 1024),
+         (8192, 512, 1024, 1024)]
+
+
+def _brute_band(s, bq, bk, window):
+    """Every pair by its positions: live where some (q, k) has
+    ``0 <= q - k < window``, crossing where some other has not."""
+    q, k = np.arange(s)[:, None], np.arange(s)[None, :]
+    seen = (q >= k) & (q - k < window)
+    tiles = seen.reshape(s // bq, bq, s // bk, bk)
+    some, every = tiles.any((1, 3)), tiles.all((1, 3))
+    live = {(int(i), int(j)) for i, j in zip(*np.nonzero(some))}
+    crossing = {(int(i), int(j)) for i, j in zip(*np.nonzero(some & ~every))}
+    return live, crossing
+
+
+@pytest.mark.parametrize("q_inner", [False, True])
+@pytest.mark.parametrize("s,bq,bk,window", BANDS)
+def test_windowed_table_is_its_numpy_statement(s, bq, bk, window, q_inner):
+    if s > 1024:        # the statement by positions is 67M booleans there
+        live = {(i, j) for i in range(s // bq) for j in range(s // bk)
+                if j * bk <= i * bq + bq - 1
+                and i * bq - (j * bk + bk - 1) < window}
+        crossing = None
+    else:
+        live, crossing = _brute_band(s, bq, bk, window)
+    qi, kj, masked = ak.live_pairs(s, bq, bk, q_inner, window)
+    pairs = list(zip(qi.tolist(), kj.tolist()))
+    assert len(pairs) == len(set(pairs)) and set(pairs) == live
+    key = (lambda p: (p[1], p[0])) if q_inner else (lambda p: p)
+    assert pairs == sorted(pairs, key=key)
+    if crossing is not None:
+        assert {p for p, m in zip(pairs, masked) if m} == crossing
+    if window >= s:     # no window at all: today's table, entry for entry
+        for got, want in zip(ak.live_pairs(s, bq, bk, q_inner, window),
+                             ak.live_pairs(s, bq, bk, q_inner)):
+            np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("s,bq,bk,window", BANDS)
+def test_windowed_init_and_emit_fall_on_a_runs_first_and_last_pair(
+        s, bq, bk, window):
+    """``_Walk.enter``'s scalar arithmetic for a band: a q block's run of
+    k blocks (forward, dQ) and a k block's run of q blocks (dK with dV)
+    are contiguous in the table and start and end where it says."""
+    w = ak._band(s, window)
+    far = s if w is None else w
+    qi, kj, _ = ak.live_pairs(s, bq, bk, window=window)
+    for i in range(s // bq):
+        run = np.flatnonzero(qi == i)
+        assert np.all(np.diff(run) == 1)
+        assert kj[run[0]] == max(0, (i * bq - far + 1) // bk)
+        assert kj[run[-1]] == min(s // bk - 1, (i * bq + bq - 1) // bk)
+    qi, kj, _ = ak.live_pairs(s, bq, bk, True, window)
+    for j in range(s // bk):
+        run = np.flatnonzero(kj == j)
+        assert np.all(np.diff(run) == 1)
+        assert qi[run[0]] == (j * bk) // bq
+        assert qi[run[-1]] == min(s // bq - 1, (far + j * bk + bk - 2) // bq)
+
+
+@pytest.mark.parametrize("s,bq,bk,window,want", [
+    (8192, 512, 512, 1024, (45, 45, 30)),     # mellum2-train-8k's window
+    (8192, 512, 1024, 1024, (30, 30, 30)),
+    (8192, 512, 512, 8192, (136, 136, 16)),   # no window: the causal table
+    (8192, 512, 512, None, (136, 136, 16)),
+])
+def test_causal_pairs_counts_a_bands_steps(s, bq, bk, window, want):
+    got = ak.causal_pairs(s, bq, bk, window)
+    assert (got["grid_steps"], got["live"], got["masked"]) == want
+
+
+def _masked_attention(q, k, v, window):
+    """Plain attention under a causal band, float32: q [B, H, S, D], k and
+    v [B, Hkv, S, D], query head h reading key-value head h // group."""
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / q.shape[-1] ** 0.5
+    i, j = jnp.arange(q.shape[2])[:, None], jnp.arange(q.shape[2])[None, :]
+    seen = (i >= j) & ((i - j < window) if window else True)
+    return jnp.einsum("bhqk,bhkd->bhqd",
+                      jax.nn.softmax(jnp.where(seen, s, -1e30), -1), v)
+
+
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("s,bq,bk,window", [
+    (256, 32, 32, 8),           # under a block
+    (256, 32, 32, 32),          # of a block
+    (256, 32, 32, 96),          # of several
+    (256, 64, 32, 48),          # q block over k block: rows wholly masked
+    (256, 32, 64, 100),         # k block over q block, an uneven edge
+    (128, 32, 32, None),        # grouped heads under the plain diagonal
+    (128, 32, 32, 4096),        # a window of s or more is none
+])
+def test_banded_grouped_kernels_match_plain_masked_attention(
+        s, bq, bk, window, group):
+    rng = np.random.default_rng(s + bq + group)
+    draw = lambda h: jnp.asarray(rng.normal(size=(2, h, s, 32)), jnp.float32)
+    q, k, v, g = draw(group), draw(1), draw(1), draw(group)
+    got = jax.jit(lambda *a: _out_and_grads(
+        lambda q, k, v: ak.flash_attention(q, k, v, True, bq, bk, True,
+                                           window), *a))(q, k, v, g)
+    want = jax.jit(lambda *a: _out_and_grads(
+        lambda q, k, v: _masked_attention(q, k, v, window), *a))(q, k, v, g)
+    assert got[2].shape == got[3].shape == k.shape      # never repeated
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=3e-5, err_msg=name)
+
+
+def test_grouped_heads_without_a_causal_walk_are_refused():
+    q, k, v, _ = _inputs((1, 2, 64, 32), seed=1)
+    with pytest.raises(ValueError, match="causal"):
+        ak.flash_attention(q, k[:, :1], v[:, :1], False, 32, 32, True)
+    with pytest.raises(ValueError, match="do not divide"):
+        ak.flash_attention(jnp.concatenate([q, q[:, :1]], 1), k, v, True,
+                           32, 32, True)

@@ -82,8 +82,10 @@ def test_block_matches_the_reference(kind):
     x = jax.random.normal(jax.random.key(9), (2, 32, CFG.dim))
     name = "L0" if kind == "dense" else "L1"
     p = mla_moe._sub(params, name)
-    got, aux = mla_moe._run_block(x, p, None if kind == "dense" else bias[0],
-                                  CFG)
+    layer = CFG.layers()[0 if kind == "dense" else 1]
+    assert layer.name == name
+    got, aux = mla_moe._run_block(x, p, layer,
+                                  None if kind == "dense" else bias[0], CFG)
     if kind == "dense":
         ffn = lambda u, q: (ref.mlp(u, q["wg"], q["wu"], q["wd"]), None)
     else:
@@ -94,16 +96,16 @@ def test_block_matches_the_reference(kind):
         want = jnp.stack([ref.block(x[i], p, ffn, c)[0] for i in range(2)])
     assert _close(got, want)
     if kind == "expert":
-        counts, overflow = aux
+        counts, overflow, _ = aux
         assert int(counts.sum()) == 2 * 32 * CFG.top_k and int(overflow) == 0
 
 
 @pytest.mark.parametrize("attn,kernel", [("xla", "xla"),
                                          ("flash", "interpret")])
 def test_loss_and_every_gradient_match_the_reference(attn, kernel):
-    cfg = CFG._replace(attn=attn, expert_kernel=kernel, attn_block=32)
+    cfg = CFG._replace(attn=attn, expert_kernel=kernel, attn_block=16)
     params, bias, tokens = _inputs(cfg)
-    (loss, (counts, overflow)), grads = jax.jit(jax.value_and_grad(
+    (loss, (counts, overflow, _)), grads = jax.jit(jax.value_and_grad(
         lambda p: mla_moe.loss_fn(p, bias, tokens, cfg), has_aux=True))(params)
     want_loss, want_counts, _, want = jax.jit(
         lambda p: ref.loss_and_grads(p, bias, tokens, _ref_config(cfg)))(
@@ -150,7 +152,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     for offset in range(0, e, cfg.experts_held):
         share = dict(whole, **{k: whole[k][offset:offset + cfg.experts_held]
                                for k in ("eg", "eu", "ed")})
-        out, (counts, overflow) = mla_moe.expert_ffn(
+        out, (counts, overflow, _) = mla_moe.expert_ffn(
             u, share, bias, cfg._replace(expert_offset=offset))
         shared = mla_moe.gated_mlp(u, share["sg"], share["su"], share["sd"],
                                    cfg)
@@ -174,7 +176,7 @@ def test_a_forced_router_drops_nothing_and_makes_no_nan(force):
     if force == "all_to_one_held":
         bias[lo + 1] = 10.0
     bias = jnp.asarray(bias)
-    got, (counts, overflow) = mla_moe.expert_ffn(u, p, bias, CFG)
+    got, (counts, overflow, _) = mla_moe.expert_ffn(u, p, bias, CFG)
     c = _ref_config(CFG)
     with jax.default_matmul_precision("highest"):
         want = jnp.stack([ref.expert_layer(u[i], p, bias, c, lo, held)[0]
@@ -190,16 +192,16 @@ def test_a_forced_router_drops_nothing_and_makes_no_nan(force):
 
 def test_a_buffer_sized_under_the_load_counts_what_it_leaves_out():
     cfg = moe.HeldExperts(num_experts=8, experts_held=2, top_k=2,
-                          buffer_rows=128, tile=128, dtype=jnp.float32)
+                          buffer_rows=128, dtype=jnp.float32)
     u = jax.random.normal(jax.random.key(0), (256, 32))
     p = {"router": jnp.zeros((8, 32)),
          "w_gate": jnp.ones((2, 32, 16)), "w_up": jnp.ones((2, 32, 16)),
          "w_down": jnp.ones((2, 16, 32))}
     bias = jnp.asarray([1.0, 1.0, 0, 0, 0, 0, 0, 0])     # all 512 rows here
-    _, counts, overflow = moe.held_expert_layer(u, p, bias, cfg)
+    _, counts, overflow, _ = moe.held_expert_layer(u, p, bias, cfg)
     assert counts.tolist()[:2] == [256, 256] and int(overflow) == 512 - 128
-    _, _, none = moe.held_expert_layer(u, p, bias,
-                                       cfg._replace(buffer_rows=None))
+    _, _, none, _ = moe.held_expert_layer(u, p, bias,
+                                          cfg._replace(buffer_rows=None))
     assert int(none) == 0
 
 

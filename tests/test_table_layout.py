@@ -551,10 +551,11 @@ def test_language_model_kernels_compile_for_a_v5e_at_published_widths(
         assert text.count("tpu_custom_call") >= 3, (bq, bk)
 
     held = moe.HeldExperts(num_experts=64, experts_held=8, top_k=4,
-                           routed_scale=1.8, buffer_rows=16384, tile=512)
+                           routed_scale=1.8, buffer_rows=16384,
+                           tile=(512, 512, 512))
 
     def experts(u, router, wg, wu, wd):
-        out, counts, overflow = moe.held_expert_layer(
+        out, counts, overflow, _ = moe.held_expert_layer(
             u, {"router": router, "w_gate": wg, "w_up": wu, "w_down": wd},
             jnp.zeros((64,)), held, kernel="pallas")
         return out.sum(), (counts, overflow)
@@ -566,4 +567,65 @@ def test_language_model_kernels_compile_for_a_v5e_at_published_widths(
     compiled = jax.jit(jax.grad(experts, argnums=(0, 2, 3, 4),
                                 has_aux=True)).lower(*args).compile()
     # three products forward, and for each the input's and the weight's
+    assert compiled.as_text().count("tpu_custom_call") >= 9
+
+
+@pytest.mark.parametrize("window", [1024, None])
+def test_grouped_query_kernels_compile_for_a_v5e_at_published_widths(
+        one_chip, window):
+    """The banded and the causal flash kernels as ``mellum2-train-8k``
+    calls them: 32 query heads of 128 over 4 key-value heads, 8,192
+    positions, at the blocks ``models/mla_moe.attn_blocks`` gives a head
+    of 128. K and V go in at four heads and dK and dV come out at four: no
+    operand of the program has them repeated."""
+    from multiverso_tpu.models import gqa_moe, mla_moe
+    from multiverso_tpu.ops import attention_kernels
+
+    cfg = gqa_moe.GQAMoEConfig(n_heads=32, n_kv_heads=4, head_dim=128,
+                               window=1024)
+    blocks = mla_moe.attn_blocks(cfg, 8192)
+    assert blocks == (1024, 1024)
+    shape = lambda s: jax.ShapeDtypeStruct(s, jnp.bfloat16,
+                                           sharding=one_chip)
+
+    def core(q, k, v):
+        return attention_kernels.flash_attention(
+            q, k, v, True, *blocks, False, window).astype(jnp.float32).sum()
+
+    args = (shape((2, 32, 8192, 128)), shape((2, 4, 8192, 128)),
+            shape((2, 4, 8192, 128)))
+    grads = jax.grad(core, argnums=(0, 1, 2))
+    text = jax.jit(grads).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3       # forward, dQ, dK with dV
+    assert [g.shape for g in jax.eval_shape(grads, *args)] == [
+        a.shape for a in args]
+    # a key-value operand at 32 heads would be bf16[2,32,8192,128] beside
+    # q, its gradient and the output (and the same flattened: [64, ...]);
+    # the kernels' own k-shaped operands and results are [8, 8192, 128]
+    assert "bf16[8,8192,128]" in text
+
+
+def test_softmax_expert_layer_compiles_for_a_v5e_at_published_widths(
+        one_chip):
+    """The held experts' grouped products over the 65,536-row buffer at
+    the tile ``moe.product_tile`` reads off 2,304 and 896 (sixteen
+    groups), under the softmax route, forward and backward."""
+    from multiverso_tpu.parallel import moe
+
+    tile = moe.product_tile(2304, 896)
+    assert tile == (512, 768, 896)
+    held = moe.HeldExperts(num_experts=64, experts_held=16, top_k=8,
+                           buffer_rows=65536, tile=tile, route="softmax")
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+
+    def experts(u, router, wg, wu, wd):
+        out, counts, overflow, balance = moe.held_expert_layer(
+            u, {"router": router, "w_gate": wg, "w_up": wu, "w_down": wd},
+            None, held, kernel="pallas")
+        return out.sum() + balance, (counts, overflow)
+
+    compiled = jax.jit(jax.grad(experts, argnums=(0, 1, 2, 3, 4),
+                                has_aux=True)).lower(
+        shape(16384, 2304), shape(64, 2304), shape(16, 2304, 896),
+        shape(16, 2304, 896), shape(16, 896, 2304)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 9

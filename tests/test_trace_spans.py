@@ -523,18 +523,20 @@ def test_lm_step_carries_its_counts():
 
 def test_lm_step_carries_the_flash_kernels_grid_counts():
     """With the flash kernel as the core, every ``lm.step`` span says what
-    one kernel call walks a (batch x head): 32 positions in 16-row q
-    blocks under 32-row k blocks are two pairs, both on the diagonal."""
+    one kernel call walks a (batch x head): 32 positions in 16 x 16
+    blocks (a head of 8 doubles the q block with the k block) are three
+    pairs, two of them on the diagonal."""
     mla_moe, cfg, tables, tokens = _tiny_lm()
-    cfg = cfg._replace(attn="flash", attn_block=16)
-    assert mla_moe.attn_blocks(cfg, 32) == (16, 32)
+    cfg = cfg._replace(attn="flash", attn_block=8)
+    assert mla_moe.attn_blocks(cfg, 32) == (16, 16)
+    assert mla_moe.attn_blocks(cfg._replace(v_head_dim=256), 32) == (8, 16)
     trainer = mla_moe.Trainer(cfg, tables)
     before = len(ttrace.events())
     assert trainer.step_ahead(tokens) is None
     trainer.adopt()
     steps = [e for e in ttrace.events()[before:] if e["name"] == "lm.step"]
     assert len(steps) == 2          # the step queued, and the drain
-    grid = {"attn_grid_steps": 2, "attn_pairs_live": 2,
+    grid = {"attn_grid_steps": 3, "attn_pairs_live": 3,
             "attn_pairs_masked": 2}
     for e in steps:
         assert {k: e["args"][k] for k in grid} == grid
