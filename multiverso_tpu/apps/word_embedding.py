@@ -216,12 +216,16 @@ class WordEmbedding:
         # MatrixTables; input randomly initialized server-side). async_ps
         # swaps in the uncoordinated tables — same client API, no lockstep.
         if cfg.async_ps:
-            matrix, kv = mv.AsyncMatrixTable, mv.AsyncKVTable
+            matrix, kv, shards = mv.AsyncMatrixTable, mv.AsyncKVTable, 1
         else:
             matrix, kv = mv.MatrixTable, mv.KVTable
-        self.table_in = matrix(v, d, name="embed_in", updater="default",
+            shards = mv.mesh().shape[mv.Zoo.get().shard_axis()]
+        # on row shards the words are dealt round them (_rows), and the
+        # tables have a row for the last word of every shard
+        rows = row_combine.striped_table_rows(v, shards)
+        self.table_in = matrix(rows, d, name="embed_in", updater="default",
                                seed=cfg.seed + 17, init_scale=0.5 / d)
-        self.table_out = matrix(v, d, name="embed_out", updater="default")
+        self.table_out = matrix(rows, d, name="embed_out", updater="default")
         self.word_count = kv(name="word_count")
         self.unigram = dictionary.unigram_table()
         self._trained_words = 0
@@ -266,6 +270,26 @@ class WordEmbedding:
     def prepare_ids(self, tokens) -> np.ndarray:
         return prepare_ids(self.dict, self.dict.encode(tokens), self.cfg)
 
+    def _stripes(self) -> Tuple[int, int]:
+        """The word tables' row shards and the rows of each (an
+        uncoordinated table has none of its own on the mesh: one)."""
+        t = self.table_in
+        shards = getattr(t, "num_shards", 1)
+        return shards, (t.rows_per_shard if shards > 1 else 0)
+
+    def _rows(self, words):
+        """The rows of ``embed_in`` and ``embed_out`` that the words
+        (dictionary ids: frequency ranks) live in: THE place a word becomes
+        a row. On row shards the ranks are dealt round the shards
+        (``row_combine.striped_row``), so that every shard owns its share
+        of the hot rows; on one shard a word's row is its id, and
+        ``words`` comes back as it is."""
+        return row_combine.striped_row(words, *self._stripes())
+
+    def _words(self, rows):
+        """:meth:`_rows`'s inverse."""
+        return row_combine.striped_word(rows, *self._stripes())
+
     def _batches(self, centers: np.ndarray, contexts: np.ndarray
                  ) -> Tuple[np.ndarray, np.ndarray]:
         b = self.cfg.batch_size
@@ -277,7 +301,10 @@ class WordEmbedding:
 
     def _device_pairs(self, ids: np.ndarray):
         """Batched (centers, contexts) pair arrays, resident on device,
-        and their pair count."""
+        and their pair count: the arrays the fused epoch scans, so ROW
+        ids of ``table_in.raw()`` and ``table_out.raw()`` (:meth:`_rows`;
+        under ``-hs`` the contexts stay words: they name Huffman paths,
+        not rows)."""
         return self._cached_pairs(ids)[0]
 
     def _cached_pairs(self, ids: np.ndarray):
@@ -309,7 +336,11 @@ class WordEmbedding:
             with _trace.span("we.pairs.generate"):
                 centers, contexts = _gen_pairs(ids, self.cfg.window,
                                                self.cfg.seed)
-                cb, xb = self._batches(centers, contexts)
+                # words become rows here, once a corpus, off every
+                # minibatch's path
+                cb, xb = self._batches(
+                    self._rows(centers),
+                    contexts if self.cfg.hs else self._rows(contexts))
                 by_shard = self._rows_by_shard(cb) + self._rows_by_shard(xb)
             with _trace.span("we.pairs.upload"):
                 cbd, xbd = jnp.asarray(cb), jnp.asarray(xb)
@@ -337,15 +368,16 @@ class WordEmbedding:
             return None
         if self._plan_fn is None:
             self._plan_fn = jax.jit(
-                row_combine.plan_rows, static_argnums=1,
+                row_combine.plan_rows, static_argnums=(1, 2),
                 out_shardings=jax.sharding.NamedSharding(
                     mv.mesh(), jax.sharding.PartitionSpec()))
-        return (self._plan_fn(centers, self.table_in.padded_shape[0]),
-                self._plan_fn(contexts, self.table_out.padded_shape[0]))
+        return tuple(self._plan_fn(ids, t.padded_shape[0], t.num_shards)
+                     for ids, t in ((centers, self.table_in),
+                                    (contexts, self.table_out)))
 
     def _rows_by_shard(self, ids: np.ndarray) -> np.ndarray:
-        """How many of the row ids ``ids`` each contiguous row shard of
-        the embedding tables owns (``[shards]`` int64)."""
+        """How many of the row ids ``ids`` each row shard of the
+        embedding tables owns (``[shards]`` int64)."""
         t = self.table_in
         return np.bincount(ids.reshape(-1) // t.rows_per_shard,
                            minlength=t.num_shards)
@@ -383,6 +415,10 @@ class WordEmbedding:
                                 cfg.window, cfg.alpha, cfg.cbow, cfg.hs,
                                 cfg.shared_negatives)
         formats = (self.table_in.format, self._sec_table().format)
+        # the sampler's slot table names the rows its words live in, as
+        # the pairs do
+        slots = None if cfg.hs else self._rows(w2v.build_negative_table(
+            self.unigram, 1 << w2v.FUSED_TABLE_BITS))
         if cfg.hs:
             make = (w2v.make_fused_cbow_hs_epoch if cfg.cbow
                     else w2v.make_fused_hs_epoch)
@@ -392,14 +428,13 @@ class WordEmbedding:
             cd = self.fused_compute_dtype = (
                 jnp.bfloat16 if jax.devices()[0].platform == "tpu"
                 else jnp.float32)
-            # the sampler's slot table, kept on the host too: which words
-            # a call's pools held is read from it (fused_pool)
-            self._fused_slots = w2v.build_negative_table(
-                self.unigram, 1 << w2v.FUSED_TABLE_BITS)
+            # the slot table is kept on the host too: which rows a
+            # call's pools held is read from it (fused_pool)
+            self._fused_slots = slots
             fn = w2v.make_fused_shared_epoch(w2v_cfg, self.unigram,
                                              compute_dtype=cd,
                                              table_formats=formats,
-                                             slots=self._fused_slots)
+                                             slots=slots)
             # replicated on the mesh, as the epoch hands it back
             self._lcg = jax.device_put(
                 w2v.init_lcg_state(cfg.shared_negatives, cfg.seed),
@@ -408,12 +443,14 @@ class WordEmbedding:
         else:
             make = (w2v.make_fused_cbow_epoch if cfg.cbow
                     else w2v.make_fused_epoch)
-            fn = make(w2v_cfg, self.unigram, table_formats=formats)
+            fn = make(w2v_cfg, self.unigram, table_formats=formats,
+                      slots=slots)
         self._fused_cache[name] = fn
         return fn, shared
 
     def fused_pool(self, next_batches: Optional[int] = None) -> np.ndarray:
-        """Word ids of the shared negative pools of the fused epoch, read
+        """The shared negative pools of the fused epoch as ROW ids of
+        ``table_out.raw()`` (:meth:`_rows` of the words drawn), read
         from the program's own slot table and sampler state: with no
         argument the pool ``[K']`` that the last batch of the last
         :meth:`train_fused` call drew; with ``next_batches`` the pools
@@ -463,6 +500,9 @@ class WordEmbedding:
                 windows, masks, targets = w2v.generate_cbow_batches(
                     ids, self.cfg.window)
                 b = self.cfg.batch_size
+                windows = self._rows(windows)    # a masked slot: row 0
+                if not self.cfg.hs:     # -hs: targets name Huffman paths
+                    targets = self._rows(targets)
                 n = (targets.size // b) * b
                 if n == 0:
                     raise ValueError("corpus too small for batch size")
@@ -534,11 +574,13 @@ class WordEmbedding:
                 loss_f = float(loss_f)
             if shared:
                 # the pairs' update rows before combining, the distinct
-                # ones after, and those of them that the dense adds of the
-                # tables' heads took
-                unique, head = np.sum(rows, axis=0)
+                # ones after, those of them that the dense adds of the
+                # tables' heads took, and the slots every shard's walks
+                # were handed for the others
+                unique, head, *walk = np.sum(rows, axis=0)
                 call.set(update_rows=2 * epochs * int(pairs),
-                         unique_rows=int(unique), head_rows=int(head))
+                         unique_rows=int(unique), head_rows=int(head),
+                         walk_slots_by_shard=[int(n) for n in walk])
             with _trace.span("we.fused.count"):
                 dt = time.perf_counter() - t0
                 # words/sec follows the word2vec convention: corpus
@@ -769,7 +811,14 @@ class WordEmbedding:
             negs, neg_seed = self._host_negs(examples.size, cfg.negative, rng)
             prep.update(negs=negs, neg_seed=neg_seed)
             used.append(negs.reshape(-1))
-        prep["vocab"] = self._used_ids(len(self.dict), used)
+        vocab = self._used_ids(len(self.dict), used)
+        # the rows they live in, ascending as the pushes promise: on row
+        # shards the words follow their rows' order
+        rows = self._rows(vocab)
+        if rows is not vocab:
+            rows = np.sort(rows)
+            vocab = self._words(rows)
+        prep.update(vocab=vocab, rows=rows)
         return prep
 
     @staticmethod
@@ -852,12 +901,12 @@ class WordEmbedding:
             else:
                 prep[k_pull] = table.get_rows_async(ids)
 
-        pull(self.table_in, prep["vocab"], prep["kb"], "dev_in", "pull_in")
+        pull(self.table_in, prep["rows"], prep["kb"], "dev_in", "pull_in")
         if cfg.hs:
             pull(self.table_hs, prep["hs_rows"], prep["hkb"],
                  "dev_sec", "pull_hs")
         else:
-            pull(self.table_out, prep["vocab"], prep["kb"],
+            pull(self.table_out, prep["rows"], prep["kb"],
                  "dev_sec", "pull_out")
         return prep
 
@@ -924,10 +973,10 @@ class WordEmbedding:
                 d_sec = np.asarray(d_sec)
                 _devstats.note_transfer(d_in.nbytes + d_sec.nbytes, "d2h")
             with _trace.span("we.push", phase="push"):
-                k = prep["vocab"].size
+                k = prep["rows"].size
                 self.table_in.add_rows_async(
-                    prep["vocab"], d_in[:k] / num_workers)
-                ids_sec = prep["hs_rows"] if cfg.hs else prep["vocab"]
+                    prep["rows"], d_in[:k] / num_workers)
+                ids_sec = prep["hs_rows"] if cfg.hs else prep["rows"]
                 sec_t.add_rows_async(
                     ids_sec, d_sec[:ids_sec.size] / num_workers)
             return float(loss)
@@ -1100,7 +1149,7 @@ class WordEmbedding:
                 # bucket the pulled-row count; pad ids gather the table's
                 # scratch row (zero delta scatters back into it, a no-op)
                 ids_in = np.full(vbb, self.table_in.scratch_row, np.int32)
-                ids_in[:k] = vocab
+                ids_in[:k] = prep["rows"]
                 remap = np.full(len(self.dict), vbb, np.int64)  # dummy
                 remap[vocab] = np.arange(k)
                 remap_hs, hsb = None, 0
@@ -1255,7 +1304,12 @@ class WordEmbedding:
 
     # ------------------------------------------------------------------ #
     def embeddings(self) -> np.ndarray:
-        return self.table_in.get()
+        """The input embeddings ``[words, size]`` in WORD order, wherever
+        the rows live (:meth:`_rows`)."""
+        emb = self.table_in.get()
+        if self._stripes()[0] == 1:
+            return emb
+        return emb[self._rows(np.arange(len(self.dict)))]
 
     def nearest(self, word: str, k: int = 10) -> List[str]:
         wid = self.dict.word2id[word]

@@ -265,12 +265,16 @@ def _epoch_jit(table_formats, carried: int = 0, counts: int = 0, **jit_kw):
 
 
 def make_fused_epoch(cfg: W2VConfig, unigram: np.ndarray,
-                     table_formats=None):
+                     table_formats=None,
+                     slots: Optional[np.ndarray] = None):
     """Build a jitted scan over skipgram-NS pair minibatches: the whole block
     trains on device; negatives are drawn in-graph. Returns
     ``epoch_fn(win, wout, centers, contexts, key) -> (win, wout, mean_loss)``
-    where centers/contexts are (num_batches, B)."""
-    neg_table = jnp.asarray(build_negative_table(unigram))
+    where centers/contexts are (num_batches, B). ``slots`` is the negative
+    table where the caller has built it (:func:`build_negative_table`, a
+    word's id being the row it lives in)."""
+    neg_table = jnp.asarray(build_negative_table(unigram)
+                            if slots is None else slots)
 
     @_epoch_jit(table_formats)
     def epoch_fn(win, wout, centers, contexts, key):
@@ -335,7 +339,7 @@ def shared_neg_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
     (typically k/K') rescales the negative gradient so the expected objective
     matches the reference's k-negatives-per-pair loss.
 
-    centers/contexts: (B,) int32; neg_ids: (K',) int32.
+    centers/contexts: (B,) int32; neg_ids: (K',) int32: rows of the tables.
     Tables stay in their storage dtype (f32); compute runs in
     ``compute_dtype`` (bf16 on the MXU). The pairs' update rows reach the
     tables with their duplicates combined (``ops/row_combine``); ``plans``
@@ -409,20 +413,23 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
     and the earlier per-batch in-scan LCG step (~17%).
     Returns ``epoch_fn(win, wout, centers, contexts, lcg_state,
     plans=None) -> (win, wout, mean_loss, lcg_state, rows)``.
-    ``plans`` is ``(plan_rows(centers, rows), plan_rows(contexts, rows))``
-    (``ops/row_combine``) where the caller keeps them with the pairs: the
-    sorts behind them are 4% of an epoch of 439 x 8,192 on a v5e, so a
-    caller that runs the same pairs again makes them once; without them
-    the epoch makes its own. ``rows`` is ``int32[2]``, one array for one
-    read-back: how many distinct centre and context rows those plans
-    name, of ``2 * centers.size`` update rows (the table writes' work
-    after and before combining), and how many of them lay in the tables'
-    heads, which the dense adds took and the walks did not
-    (``row_combine.HEAD``).
-    ``slots`` is the negative table
-    (word ids, ``2^table_bits`` of them) where the caller has built it
-    already (:func:`build_negative_table`; at 12M words a fifth of a
-    second and 4 MB that need not be made twice).
+    ``centers`` and ``contexts`` are ROW ids of the tables. ``plans`` is
+    ``(plan_rows(centers, rows, shards), plan_rows(contexts, rows,
+    shards))`` (``ops/row_combine``; ``shards`` the tables' row shards)
+    where the caller keeps them with the pairs: the sorts behind them are
+    4% of an epoch of 439 x 8,192 on a v5e, so a caller that runs the same
+    pairs again makes them once; without them the epoch makes its own.
+    ``rows`` is ``int32[2 + shards]``, one array for one read-back
+    (``row_combine.plan_counts`` of both plans): how many distinct centre
+    and context rows those plans name, of ``2 * centers.size`` update rows
+    (the table writes' work after and before combining); how many of them
+    lay in the tables' heads, which the dense adds took and the walks did
+    not (``row_combine.HEAD``); and the slots each shard's walks were
+    handed.
+    ``slots`` is the negative table (``2^table_bits`` ids, a word's being
+    the row it lives in) where the caller has built it already
+    (:func:`build_negative_table`; at 12M words a fifth of a second and
+    4 MB that need not be made twice).
     """
     k_shared = cfg.shared_negatives
     if k_shared <= 0:
@@ -452,8 +459,11 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
         # epoch before the scan, off the minibatch's path
         if plans is None:
             with jax.named_scope("mv.fused.plan"):
-                plans = (row_combine.plan_rows(centers, win.shape[0]),
-                         row_combine.plan_rows(contexts, wout.shape[0]))
+                plans = tuple(
+                    row_combine.plan_rows(ids, tab.shape[0],
+                                          row_combine.row_shards(sh)[1])
+                    for ids, tab, sh in zip((centers, contexts),
+                                            (win, wout), shardings))
 
         def body(carry, batch):
             win, wout, = carry
@@ -466,8 +476,8 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
         with jax.named_scope("mv.fused"):   # device-trace name
             (win, wout), losses = jax.lax.scan(
                 body, (win, wout), (centers, contexts, nids, plans))
-        rows = jnp.stack([jnp.sum(plans[0].count) + jnp.sum(plans[1].count),
-                          jnp.sum(plans[0].head) + jnp.sum(plans[1].head)])
+        rows = (row_combine.plan_counts(plans[0])
+                + row_combine.plan_counts(plans[1]))
         return win, wout, jnp.mean(losses), s_all[-1], rows
 
     return epoch_fn
@@ -480,9 +490,12 @@ def init_lcg_state(k_shared: int, seed: int = 0) -> np.ndarray:
 
 
 def make_fused_cbow_epoch(cfg: W2VConfig, unigram: np.ndarray,
-                          table_formats=None):
-    """CBOW-NS variant: scans (windows, masks, targets) batches."""
-    neg_table = jnp.asarray(build_negative_table(unigram))
+                          table_formats=None,
+                          slots: Optional[np.ndarray] = None):
+    """CBOW-NS variant: scans (windows, masks, targets) batches; ``slots``
+    as :func:`make_fused_epoch` takes them."""
+    neg_table = jnp.asarray(build_negative_table(unigram)
+                            if slots is None else slots)
 
     @_epoch_jit(table_formats)
     def epoch_fn(win, wout, windows, masks, targets, key):

@@ -12,26 +12,39 @@ row than the scatter into the table.
 
 * :func:`plan_rows` — from ids ``[..., B]``: the run (distinct id) each
   update row belongs to, the distinct ids ascending and padded to ``B``
-  with distinct ids past the table's last row, their number, and how many
-  of them lie below ``HEAD``. Leading axes are walked a minibatch at a
-  time, so an epoch makes its plans before its scan, off the minibatch's
-  path.
+  with distinct ids past the table's last row, their number, and for each
+  row shard of the table where its rows past its head start and end among
+  them. Leading axes are walked a minibatch at a time, so an epoch makes
+  its plans before its scan, off the minibatch's path.
 * :func:`combine_rows` — the float32 sum of each run, ``[B, D]``.
 * :func:`add_rows` — the sums, then the table write over the distinct
-  rows alone: one dense add for the table's first ``HEAD`` rows, and for
-  the others the table scatter, a chunk of slots at a time from the first
-  id past the head up to the last distinct row. Pad slots of the last
-  chunk are dropped by the scatter (``mode="drop"``): they write nothing,
-  so ``unique_indices`` is a true promise.
+  rows alone: one dense add for the head, and for the others the table
+  scatter, a chunk of slots at a time from the first id past the head up
+  to the last distinct row. Pad slots of the last chunk are dropped by the
+  scatter (``mode="drop"``): they write nothing, so ``unique_indices`` is
+  a true promise.
+
+Row shards (PERF.md, PR 36). A scatter slot costs a chip the same whether
+it keeps or drops the row, so a chip that is handed the whole plan pays
+for every other chip's rows. On a row-sharded table :func:`add_rows` is
+one ``shard_map``: a chip adds its own part of the head (the first
+``HEAD // shards`` rows of EVERY shard) and walks its own range of the
+plan, nothing else. That shares the work out only if the rows a minibatch
+names are shared out, and ids that are frequency ranks, split into
+contiguous ranges, all lie in the first: :func:`striped_row` deals the
+ranks round the shards, and the app that owns the table applies it where
+a word becomes a row (``apps/word_embedding``). On one shard all of this
+is the identity and the program is the one it was.
 
 What the chip said about the promises (PERF.md, PR 28): ``unique_indices``
 changes nothing in the v5e's scatter today, and ``indices_are_sorted``
 selects a program that streams the whole table (11 times slower at 1.8M
 rows), so the ids are sorted and only the first promise is made.
 
-``HEAD`` (PERF.md, PR 31). Where ids are frequency ranks (the word2vec
-dictionary numbers words by falling count) a minibatch's hot rows are the
-first slots of its plan and the first rows of the table: on the
+``HEAD`` (PERF.md, PR 31; one shard's figures). Where ids are frequency
+ranks (the word2vec dictionary numbers words by falling count) a
+minibatch's hot rows are the first slots of its plan and the first rows
+of the table: on the
 benchmark's stream 43% of a minibatch's 5,319 distinct rows lie below
 8,192. The head's delta is gathered from the combined sums by the plan
 (``head_run``: a head row's run; ``-0.0`` where the minibatch has none),
@@ -54,7 +67,7 @@ slots) and 1.341 (a walk of the head's slots).
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -75,28 +88,65 @@ _NONE = 2 ** 31 - 1      # a head row without a run: past any buffer of sums
 
 
 class RowPlan(NamedTuple):
-    """How the update rows ``ids [..., B]`` of a table reach it combined."""
+    """How the update rows ``ids [..., B]`` of a table reach it combined.
+    ``S`` is the table's row shards (1 for a whole table)."""
     run: jax.Array       # [..., B] int32: the run of each update row
     uniq: jax.Array      # [..., B] int32: run -> row id ascending, then pads
     count: jax.Array     # [...] int32: runs, i.e. distinct ids
-    head: jax.Array      # [...] int32: runs below HEAD; the walk starts here
-    head_run: jax.Array  # [..., HEAD] int32: head row -> its run, or _NONE
+    head: jax.Array      # [..., S] int32: the slot of uniq where a shard's
+    #                      rows past its head start: its walk starts here
+    end: jax.Array       # [..., S] int32: and the slot where its rows end
+    head_run: jax.Array  # [..., S * (head rows a shard)] int32: head row ->
+    #                      its run, or _NONE
 
 
-def plan_rows(ids: jax.Array, table_rows: int) -> RowPlan:
+def striped_row(word, shards: int, per: int):
+    """The row of word (frequency rank) ``word`` in a table of ``shards``
+    row shards of ``per`` rows each: ranks are dealt round the shards, so
+    every shard owns one in ``shards`` of the hot rows (split into
+    contiguous ranges of ranks, the first shard owns them all). The
+    identity on one shard. Integers or integer arrays, NumPy's or JAX's."""
+    return word if shards == 1 else (word % shards) * per + word // shards
+
+
+def striped_word(row, shards: int, per: int):
+    """:func:`striped_row`'s inverse: the word that lives in row ``row``
+    (for a row that holds none, a number past the last word)."""
+    return row if shards == 1 else (row % per) * shards + row // per
+
+
+def striped_table_rows(words: int, shards: int) -> int:
+    """Rows a table needs so that every :func:`striped_row` of ``words``
+    words is one of its own rows, given that a table of ``n`` rows holds
+    ``ceil((n + 1) / shards)`` a shard: on one shard ``words``."""
+    return words if shards == 1 else shards * (-(-words // shards) + 1) - 1
+
+
+def row_shards(sharding) -> Tuple[Optional[str], int]:
+    """The mesh axis a table's rows are sharded over and how many shards
+    that makes, from the table's sharding: ``(None, 1)`` for a whole
+    table (or no sharding at all)."""
+    axis = (sharding.spec[0] if isinstance(sharding, NamedSharding)
+            and len(sharding.spec) else None)
+    return axis, (1 if axis is None else sharding.mesh.shape[axis])
+
+
+def plan_rows(ids: jax.Array, table_rows: int, shards: int = 1) -> RowPlan:
     """The plan for update-row ids ``[..., B]`` into a table of
-    ``table_rows`` rows. ``uniq[..., u]`` is the id of run ``u``; slots
-    past the last run hold ``table_rows + j`` for distinct ``j``: out of
-    range, ascending, never equal, so a ``mode="drop"`` scatter skips them
-    and the whole of ``uniq`` is sorted and unique. The head is the
-    table's first ``min(HEAD, table_rows)`` rows: ``head`` counts the runs
-    that lie in it, the first slots of ``uniq``, and ``head_run`` finds
-    each of its rows' run."""
+    ``table_rows`` rows in ``shards`` equal contiguous row shards.
+    ``uniq[..., u]`` is the id of run ``u``; slots past the last run hold
+    ``table_rows + j`` for distinct ``j``: out of range, ascending, never
+    equal, so a ``mode="drop"`` scatter skips them and the whole of
+    ``uniq`` is sorted and unique, and a shard's rows are one contiguous
+    range of it. The head is the first ``HEAD // shards`` rows of EVERY
+    shard (all of a shard that has no more): ``head_run`` finds the run of
+    each of them, shard after shard, and ``head[..., k]`` to
+    ``end[..., k]`` are the slots of shard ``k``'s rows past its head."""
     if ids.ndim > 1:
         # a row at a time: a batched sort of [439, 8192] takes the v5e's
         # compiler 9.5 s and 7 ms to run, this loop 1.8 s and 14 ms
         lead = ids.shape[:-1]
-        plan = lax.map(lambda row: plan_rows(row, table_rows),
+        plan = lax.map(lambda row: plan_rows(row, table_rows, shards),
                        ids.reshape(-1, ids.shape[-1]))
         return RowPlan(*(a.reshape(lead + a.shape[1:]) for a in plan))
     ids = ids.astype(jnp.int32)
@@ -110,11 +160,33 @@ def plan_rows(ids: jax.Array, table_rows: int) -> RowPlan:
     # back to the ids' own order: update row perm[i] lies in run_sorted[i]
     _, run = lax.sort((perm, run_sorted), num_keys=1)
     uniq = jnp.sort(jnp.where(first, srt, table_rows + slots))
-    rows = min(HEAD, table_rows)
-    head_run = jnp.full(rows, _NONE, jnp.int32).at[uniq].set(slots,
-                                                            mode="drop")
+    per = table_rows // shards
+    part = min(HEAD // shards, per)          # head rows a shard
+    owner, local = uniq // per, uniq % per   # a pad's owner is no shard
+    head_run = jnp.full(shards * part, _NONE, jnp.int32).at[
+        jnp.where(local < part, owner * part + local, _NONE)].set(
+            slots, mode="drop")
+    first_row = jnp.arange(shards, dtype=jnp.int32) * per
     return RowPlan(run, uniq, run_sorted[-1] + 1,
-                   jnp.sum(uniq < rows, dtype=jnp.int32), head_run)
+                   jnp.searchsorted(uniq, first_row + part).astype(jnp.int32),
+                   jnp.searchsorted(uniq, first_row + per).astype(jnp.int32),
+                   head_run)
+
+
+def plan_counts(plan: RowPlan) -> jax.Array:
+    """What the table writes of the plans ``plan`` (any leading axes) are
+    handed, ``int32[2 + S]``, one array for one read-back: the distinct
+    rows; those of them in the shards' heads, which the dense adds take;
+    and for each shard the slots its walks are handed, the pads of a last
+    chunk included."""
+    chunk = min(CHUNK, plan.run.shape[-1])
+    begin = jnp.concatenate(
+        [jnp.zeros_like(plan.end[..., :1]), plan.end[..., :-1]], axis=-1)
+    walks = (plan.end - plan.head + chunk - 1) // chunk * chunk
+    shards = plan.head.shape[-1]
+    return jnp.concatenate([
+        jnp.stack([jnp.sum(plan.count), jnp.sum(plan.head - begin)]),
+        jnp.sum(walks.reshape(-1, shards), axis=0)]).astype(jnp.int32)
 
 
 def combine_rows(updates: jax.Array, plan: RowPlan,
@@ -126,62 +198,59 @@ def combine_rows(updates: jax.Array, plan: RowPlan,
                                num_segments=updates.shape[0] + pad)
 
 
-def _add_head(table: jax.Array, delta: jax.Array, sharding) -> jax.Array:
-    """``table[:len(delta)] += delta``, one contiguous read-modify-write
-    in place. Where the table's rows are sharded over a mesh axis
-    (``sharding`` says so) every shard adds its own part of ``delta`` to
-    its own rows: left to slice a row-sharded table, the partitioner
-    sends the head's rows round the chips and copies the table."""
-    head = delta.shape[0]
-    axis = (sharding.spec[0] if isinstance(sharding, NamedSharding)
-            and len(sharding.spec) else None)
-    if axis is None or not head:
-        return table.at[:head].add(delta)
-    per = table.shape[0] // sharding.mesh.shape[axis]      # rows a shard
-    part, holders = min(head, per), -(-head // per)
-    parts = jnp.pad(delta, ((0, holders * part - head), (0, 0)),
-                    constant_values=-0.0).reshape(holders, part, delta.shape[1])
-
-    def local(tab, parts):
-        shard = lax.axis_index(axis)
-        mine = lax.dynamic_index_in_dim(
-            parts, jnp.minimum(shard, holders - 1), keepdims=False)
-        return tab.at[:part].add(jnp.where(shard < holders, mine, -0.0))
-
-    rows = PartitionSpec(axis, None)
-    return jax.shard_map(local, mesh=sharding.mesh,
-                         in_specs=(rows, PartitionSpec()),
-                         out_specs=rows)(table, parts)
-
-
 def add_rows(table: jax.Array, ids: jax.Array, updates: jax.Array,
              plan: Optional[RowPlan] = None, sharding=None) -> jax.Array:
     """``table.at[ids].add(updates)`` for ids ``[B]``, updates ``[B, D]``,
     with the duplicates summed first (float32) and every distinct row
-    written once: the rows below ``HEAD`` by one dense add, the others by
+    written once: the rows of the head by one dense add, the others by
     the walk. ``plan`` is :func:`plan_rows` of ``ids`` where the caller
     made it ahead (an epoch's, batched before its scan); else it is made
     here. ``sharding`` is the table's where its rows are sharded over a
-    mesh (a traced table does not say)."""
+    mesh (a traced table does not say). Every shard then writes the rows
+    it owns and no others, in one ``shard_map``: its own part of the
+    head's delta, and a walk of its own slots of the plan, so the trips
+    differ by chip and nothing inside the loop waits for another chip.
+    Left to slice a row-sharded table, the partitioner sends the head's
+    rows round the chips and copies the table; left to scatter into it,
+    every chip walks every slot and drops those it does not own."""
     rows, b = table.shape[0], updates.shape[0]
+    axis, shards = row_shards(sharding)
     if plan is None:
-        plan = plan_rows(ids, rows)
+        plan = plan_rows(ids, rows, shards)
     chunk = min(CHUNK, b)
     # a last chunk may run past the last run: more pad slots, as distinct
     sums = combine_rows(updates, plan, chunk).astype(table.dtype)
     uniq = jnp.concatenate(
         [plan.uniq, rows + b + jnp.arange(chunk, dtype=jnp.int32)])
-    # the head's delta: each row's run, and -0.0 where the minibatch has
-    # none, which added to a row leaves its every bit
-    table = _add_head(table, jnp.take(sums, plan.head_run, axis=0,
-                                      mode="fill", fill_value=-0.0),
-                      sharding)
+    part = plan.head_run.shape[0] // shards      # head rows a shard
 
-    def walk(i, tab):
-        at = plan.head + i * chunk
-        return tab.at[lax.dynamic_slice(uniq, (at,), (chunk,))].add(
-            lax.dynamic_slice(sums, (at, 0), (chunk, sums.shape[1])),
-            unique_indices=True, mode="drop")
+    def local(tab, shard, sums, uniq, head_run, head, end):
+        """Shard ``shard``'s rows ``tab``: the head's add, then the walk
+        from the first of its slots past the head, a chunk at a time. A
+        last chunk that runs into the next shard's ids (or the pads)
+        reads local ids past ``tab`` and drops them; none lies below."""
+        # the head's delta: each row's run, and -0.0 where the minibatch
+        # has none, which added to a row leaves its every bit
+        tab = tab.at[:part].add(jnp.take(
+            sums, lax.dynamic_slice_in_dim(head_run, shard * part, part),
+            axis=0, mode="fill", fill_value=-0.0))
+        start, first_row = head[shard], shard * tab.shape[0]
 
-    return lax.fori_loop(
-        0, (plan.count - plan.head + chunk - 1) // chunk, walk, table)
+        def walk(i, tab):
+            at = start + i * chunk
+            return tab.at[
+                lax.dynamic_slice(uniq, (at,), (chunk,)) - first_row].add(
+                    lax.dynamic_slice(sums, (at, 0), (chunk, sums.shape[1])),
+                    unique_indices=True, mode="drop")
+
+        return lax.fori_loop(
+            0, (end[shard] - start + chunk - 1) // chunk, walk, tab)
+
+    plan_args = (sums, uniq, plan.head_run, plan.head, plan.end)
+    if axis is None:
+        return local(table, 0, *plan_args)
+    spec = PartitionSpec(axis, None)
+    return jax.shard_map(
+        lambda tab, *args: local(tab, lax.axis_index(axis), *args),
+        mesh=sharding.mesh, in_specs=(spec,) + (PartitionSpec(),) * 5,
+        out_specs=spec)(table, *plan_args)

@@ -3,7 +3,9 @@ summed first, in float32, and the table scatter writes every distinct row
 once and no other row at all; the shared-negatives fused epoch built on it
 does what a plain sequence of steps with raw scatter-adds does. ISSUE 31:
 the rows below ``HEAD`` take one dense add and the walk only the others,
-wherever ``HEAD`` falls among the ids."""
+wherever ``HEAD`` falls among the ids. ISSUE 36: on row shards the head is
+the first rows of every shard, a shard walks its own rows alone, and words
+are dealt round the shards."""
 
 import jax
 import jax.numpy as jnp
@@ -72,32 +74,119 @@ def test_add_rows_is_the_scatter_add(kind, head, b, chunk, dtype, made_ahead,
         np.testing.assert_array_equal(got, want)
 
 
+def _padded(rows: int, shards: int) -> int:
+    """Rows of a table of ``rows`` rows in ``shards`` row shards: as it is
+    on one, else with a spare row and padded to equal shards."""
+    return rows if shards == 1 else -(-(rows + 1) // shards) * shards
+
+
+# one shard: the plan of a whole table, as it was before ISSUE 36
+@pytest.mark.parametrize("shards", [1, 2, 4, 8])
 @pytest.mark.parametrize("head", HEADS)
 @pytest.mark.parametrize("kind", ["distinct", "one_row", "zipf", "sorted"])
-def test_plan_rows_names_every_distinct_row_once(kind, head, monkeypatch):
+def test_plan_rows_names_every_distinct_row_once(kind, head, shards,
+                                                 monkeypatch):
     monkeypatch.setattr(row_combine, "HEAD", head)
     rng = np.random.default_rng(7)
     ids = np.stack([_ids(kind, 96, rng) for _ in range(5)])
-    plan = jax.jit(lambda ids: row_combine.plan_rows(ids, ROWS))(
+    rows = _padded(ROWS, shards)
+    per = rows // shards
+    part = min(head // shards, per)         # head rows a shard
+    plan = jax.jit(lambda ids: row_combine.plan_rows(ids, rows, shards))(
         jnp.asarray(ids))
-    run, uniq, count, heads, head_run = (np.asarray(a) for a in plan)
+    run, uniq, count, heads, ends, head_run = (np.asarray(a) for a in plan)
     assert run.shape == uniq.shape == ids.shape
-    assert count.shape == heads.shape == (5,)
-    assert head_run.shape == (5, min(head, ROWS))
+    assert count.shape == (5,) and heads.shape == ends.shape == (5, shards)
+    assert head_run.shape == (5, shards * part)
     for k in range(5):
         distinct = np.unique(ids[k])
         assert count[k] == distinct.size
-        # the head: the runs below HEAD, and each of its rows' run
-        assert heads[k] == (distinct < head).sum()
-        held = np.flatnonzero(head_run[k] < 96)
-        np.testing.assert_array_equal(held, distinct[distinct < head])
-        np.testing.assert_array_equal(uniq[k][head_run[k][held]], held)
         np.testing.assert_array_equal(uniq[k, :count[k]], distinct)
         # the pads: out of range and distinct, so the whole is sorted and
         # unique and a dropping scatter writes nothing for them
-        assert (uniq[k, count[k]:] >= ROWS).all()
+        assert (uniq[k, count[k]:] >= rows).all()
         assert (np.diff(uniq[k].astype(np.int64)) > 0).all()
         np.testing.assert_array_equal(uniq[k][run[k]], ids[k])
+        named = []
+        for s in range(shards):
+            mine = distinct[distinct // per == s]
+            in_head = mine % per < part
+            # the shard's head: each of its rows' run
+            runs = head_run[k, s * part:(s + 1) * part]
+            held = np.flatnonzero(runs < 96)
+            np.testing.assert_array_equal(held, mine[in_head] - s * per)
+            np.testing.assert_array_equal(uniq[k][runs[held]], mine[in_head])
+            # its walk: the slots of its other rows, and no other's
+            np.testing.assert_array_equal(uniq[k, heads[k, s]:ends[k, s]],
+                                          mine[~in_head])
+            named += [mine[in_head], uniq[k, heads[k, s]:ends[k, s]]]
+        # every distinct row once, by its owner's head or its owner's walk
+        np.testing.assert_array_equal(np.sort(np.concatenate(named)),
+                                      distinct)
+        assert ends[k, -1] == count[k]
+        if shards == 1:     # the runs below HEAD, then the walk to the last
+            assert heads[k, 0] == (distinct < head).sum()
+    # and what the writes are handed: distinct rows, the heads' share, and
+    # every shard's walk in whole chunks (of 96 slots here: B < CHUNK)
+    counts = np.asarray(row_combine.plan_counts(plan))
+    assert counts.shape == (2 + shards,)
+    tails = ends - heads
+    assert counts[0] == count.sum()
+    assert counts[1] == count.sum() - tails.sum()
+    np.testing.assert_array_equal(counts[2:],
+                                  (-(-tails // 96) * 96).sum(axis=0))
+
+
+@pytest.mark.parametrize("shards", [1, 2, 4, 8])
+def test_striped_rows_deal_the_ranks_round_the_shards(shards):
+    words = 1003
+    rows = row_combine.striped_table_rows(words, shards)
+    per = _padded(rows, shards) // shards
+    w = np.arange(words)
+    r = row_combine.striped_row(w, shards, per)
+    # a table of that many rows holds every word, each in a row of its own
+    assert r.max() < rows and np.unique(r).size == words
+    np.testing.assert_array_equal(row_combine.striped_word(r, shards, per), w)
+    # rank w lives in shard w % shards, the shard's (w // shards)-th row
+    np.testing.assert_array_equal(r // per, w % shards)
+    np.testing.assert_array_equal(r % per, w // shards)
+    if shards == 1:
+        assert rows == words and r is w
+
+
+# row-sharded over the CPU's virtual devices: every shard writes its own
+# rows in one shard_map, and the table is the one a plain scatter-add leaves
+@pytest.mark.parametrize("shards", [2, 4, 8])
+@pytest.mark.parametrize("head", [8192, 0, 77, 300])
+@pytest.mark.parametrize("kind", ["distinct", "zipf"])
+def test_add_rows_on_row_shards_is_the_scatter_add(kind, head, shards,
+                                                   monkeypatch):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    monkeypatch.setattr(row_combine, "CHUNK", 64)
+    monkeypatch.setattr(row_combine, "HEAD", head)
+    rng = np.random.default_rng(shards)
+    rows = _padded(ROWS, shards)
+    ids = _ids(kind, 200, rng)
+    table = np.concatenate(
+        [_table(rng), np.zeros((rows - ROWS, WIDTH), np.float32)])
+    updates = rng.normal(size=(200, WIDTH)).astype(np.float32)
+    sharding = NamedSharding(
+        Mesh(np.asarray(jax.devices()[:shards]), ("mv",)),
+        PartitionSpec("mv", None))
+    got = jax.jit(lambda t, i, u: row_combine.add_rows(
+        t, i, u, None, sharding), out_shardings=sharding)(
+            jax.device_put(table, sharding), jnp.asarray(ids),
+            jnp.asarray(updates))
+    one = jax.jit(lambda t, i, u: row_combine.add_rows(t, i, u))(
+        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(updates))
+    # the same float32 sums to the same rows: one device's table, bit for
+    # bit, the -0.0 rows that no update names included
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(one).view(np.uint32))
+    want = table.copy()
+    np.add.at(want, ids, updates)
+    assert np.abs(np.asarray(got) - want).max() <= 1e-6 * (
+        np.abs(updates).max() * np.bincount(ids).max())
 
 
 def test_combine_rows_sums_bfloat16_runs_in_float32():
@@ -164,5 +253,9 @@ def test_fused_epoch_equals_sequential_raw_scatter_steps(batch, chunk, head,
             TOL_F32 * delta)
     assert np.array_equal(np.asarray(win)[vocab], win0[vocab])  # scratch row
     distinct = [np.unique(r) for r in list(cs) + list(xs)]
-    assert rows.tolist() == [sum(d.size for d in distinct),
-                             sum((d < head).sum() for d in distinct)]
+    chunk = min(chunk, batch)
+    assert rows.tolist() == [
+        sum(d.size for d in distinct),
+        sum((d < head).sum() for d in distinct),
+        # one shard: the walks' slots, the pads of a last chunk included
+        sum(-(-(d >= head).sum() // chunk) * chunk for d in distinct)]

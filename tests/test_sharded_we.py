@@ -2,6 +2,8 @@
 built a shard at a time, a vocabulary of counts alone trains, and
 ``train_fused`` on row-sharded tables does what the plain reference
 does, says which shard owns its update rows, and touches no other row.
+ISSUE 36: words are dealt round the row shards, every shard walks its own
+rows alone, and what the app hands out is in word order all the same.
 
 Meshes of 1, 2, 4 and 8 of the CPU's eight virtual devices stand in for
 one chip and for a four-chip host; weights are seeded."""
@@ -20,8 +22,8 @@ if ROOT not in sys.path:
 
 import multiverso_tpu as mv
 from benchmark.reference import w2v_sgns
-from benchmark.reference.sharding import owner_rows
-from multiverso_tpu.apps.word_embedding import WEConfig, WordEmbedding
+from multiverso_tpu.apps.word_embedding import (WEConfig, WordEmbedding,
+                                                load_embeddings)
 from multiverso_tpu.data.dictionary import Dictionary
 from multiverso_tpu.telemetry import trace as ttrace
 
@@ -29,6 +31,8 @@ VOCAB, WIDTH = 203, 8          # 203: no multiple of any shard count
 
 
 def _init(shards: int) -> None:
+    if mv.Zoo.get().started:    # a second mesh in one test: init again,
+        mv.shutdown()           # a start on a started Zoo changes nothing
     mv.init(mesh=Mesh(np.asarray(jax.devices()[:shards]), ("mv",)))
 
 
@@ -151,15 +155,27 @@ def test_words_are_made_when_asked_for():
 # ---------------------------------------------------------------------- #
 # train_fused on row-sharded tables against the plain reference
 # ---------------------------------------------------------------------- #
-def _seed_out(we: WordEmbedding, seed: int = 11) -> None:
+def _seed_out(we: WordEmbedding, seed: int = 11, table=None) -> None:
     """embed_out starts as zeros; give it seeded values so that every
-    gradient of the batch is alive."""
-    t = we.table_out
+    gradient of the batch is alive. The values are drawn a WORD each, so
+    a word starts from the same vector wherever its row lives."""
+    t = table or we.table_out
     vals = np.zeros(t.padded_shape, np.float32)
-    vals[:VOCAB] = np.random.default_rng(seed).uniform(
+    vals[_word_rows(we)] = np.random.default_rng(seed).uniform(
         -0.5, 0.5, (VOCAB, WIDTH))
     t.adopt({"data": jax.device_put(vals, t.sharding),
              "ustate": t.state["ustate"]})
+
+
+def _word_rows(we: WordEmbedding) -> np.ndarray:
+    """The row of every word, by the placement itself and not by the
+    program's map: rank ``w`` is shard ``w % S``'s ``w // S``-th row."""
+    t, w = we.table_in, np.arange(VOCAB)
+    return (w % t.num_shards) * t.rows_per_shard + w // t.num_shards
+
+
+def _owners(we: WordEmbedding, rows) -> np.ndarray:
+    return np.asarray(rows) // we.table_in.rows_per_shard
 
 
 @pytest.mark.parametrize("shards", [2, 8])
@@ -188,11 +204,11 @@ def test_one_batch_on_row_sharded_tables_matches_the_reference(shards):
         assert others.size
         np.testing.assert_array_equal(new[k][others], old[k][others])
     # the rows the batch touched lie in more than one shard
-    assert np.unique(owner_rows(ref["in_ids"], VOCAB, shards)).size > 1
+    assert np.unique(_owners(we, ref["in_ids"])).size > 1
 
 
-# of 204 padded rows, 51 a shard on four: the head is the whole table (all
-# of every shard), lies inside shard 0, ends inside shard 2, or is empty
+# 52 rows a shard on four, and a quarter of HEAD the head of each: the
+# whole of every shard, 7 rows, 30 rows, none
 @pytest.mark.parametrize("head", [8192, 30, 120, 0])
 def test_combined_scatters_on_row_sharded_tables_equal_one_device(
         head, set_head, monkeypatch):
@@ -200,8 +216,10 @@ def test_combined_scatters_on_row_sharded_tables_equal_one_device(
     and walks the distinct ones a chunk at a time; partitioned over four
     row shards it leaves the tables one device leaves, and no other row
     moves. Chunks of 64 slots, so a batch of 192 walks up to three.
-    ISSUE 31: the rows below ``HEAD`` take a dense add, which every shard
-    makes to its own rows."""
+    ISSUE 31: the rows of the head take a dense add, which every shard
+    makes to its own rows. ISSUE 36: the words are dealt round the four
+    shards and each walks its own rows, so the tables are compared in
+    WORD order, bit for bit."""
     from multiverso_tpu.ops import row_combine
     monkeypatch.setattr(row_combine, "CHUNK", 64)
     set_head(head)
@@ -210,26 +228,111 @@ def test_combined_scatters_on_row_sharded_tables_equal_one_device(
     for shards in (1, 4):
         _init(shards)
         we = _we(batch_size=192)
+        _seed_out(we, 5, we.table_in)
         _seed_out(we)
+        rows = _word_rows(we)
         cb, xb, _ = we._device_pairs(ids)
         assert cb.shape[0] >= 3 and cb.shape[1] == 192
         old = we.table_in.get(), we.table_out.get()
         pools = we.fused_pool(next_batches=int(cb.shape[0]))
         out = we.train_fused(ids, epochs=1)
-        got[shards] = (we.table_in.get(), we.table_out.get(), out["loss"])
+        new = we.table_in.get(), we.table_out.get()
+        got[shards] = (new[0][rows], new[1][rows], out["loss"],
+                       np.asarray(cb), pools)
+        # the arrays the epoch scans and the pools it draws name ROWS
         touched = (np.unique(np.asarray(cb)),
                    np.unique(np.concatenate([np.asarray(xb).ravel(),
                                              pools.ravel()])))
         for k in (0, 1):
-            others = np.setdiff1d(np.arange(VOCAB), touched[k])
+            assert np.isin(touched[k], rows).all()
+            others = np.setdiff1d(np.arange(new[k].shape[0]), touched[k])
             assert others.size
-            np.testing.assert_array_equal(got[shards][k][others],
-                                          old[k][others])
-            assert (got[shards][k][touched[k]] != old[k][touched[k]]).any()
-    assert np.unique(owner_rows(touched[0], VOCAB, 4)).size == 4
+            np.testing.assert_array_equal(new[k][others], old[k][others])
+            assert (new[k][touched[k]] != old[k][touched[k]]).any()
+    assert np.unique(_owners(we, touched[0])).size == 4
     for k in (0, 1):
         np.testing.assert_array_equal(got[4][k], got[1][k])
     assert got[4][2] == got[1][2]
+    # the same words in the same order, under other row ids
+    for k in (3, 4):
+        assert not np.array_equal(got[4][k], got[1][k])
+        np.testing.assert_array_equal(we._words(got[4][k]), got[1][k])
+
+
+# ---------------------------------------------------------------------- #
+# the striped placement
+# ---------------------------------------------------------------------- #
+@pytest.mark.parametrize("shards", [1, 2, 4, 8])
+def test_words_are_dealt_round_the_row_shards(shards):
+    _init(shards)
+    we = _we()
+    words = np.arange(VOCAB)
+    rows = we._rows(words)
+    np.testing.assert_array_equal(rows, _word_rows(we))
+    np.testing.assert_array_equal(we._words(rows), words)
+    # every word has a row of its own among the table's logical rows, and
+    # the scratch row is no word's
+    assert np.unique(rows).size == VOCAB
+    assert rows.max() < we.table_in.shape[0] <= we.table_in.scratch_row
+    assert we.table_in.shape == we.table_out.shape
+    # the hottest ranks lie on different shards, and the shares are even
+    np.testing.assert_array_equal(_owners(we, rows[:shards]),
+                                  np.arange(shards))
+    share = np.bincount(_owners(we, rows), minlength=shards)
+    assert share.max() - share.min() <= 1
+    if shards == 1:         # the identity: nothing is computed at all
+        assert rows is words and we.table_in.shape[0] == VOCAB
+
+
+def _trained(shards: int, path, **kw):
+    """A run of two calls on ``shards`` row shards from word-seeded
+    tables: the app, its embeddings, and what it saved at ``path``."""
+    _init(shards)
+    we = _we(words=[f"w{i}" for i in range(VOCAB)], **kw)
+    _seed_out(we, 5, we.table_in)
+    _seed_out(we)
+    for _ in range(2):
+        we.train_fused(_stream(2_000, seed=4), epochs=1)
+    we.save_embeddings(str(path), binary=True)
+    return we, we.embeddings(), load_embeddings(str(path))
+
+
+def test_embeddings_and_saves_are_in_word_order_on_any_shards(tmp_path):
+    one = _trained(1, tmp_path / "one.bin")
+    four = _trained(4, tmp_path / "four.bin")
+    assert one[1].shape == four[1].shape == (VOCAB, WIDTH)
+    np.testing.assert_array_equal(four[1], one[1])
+    assert four[2][0] == one[2][0] == [f"w{i}" for i in range(VOCAB)]
+    np.testing.assert_array_equal(four[2][1], one[2][1])
+    np.testing.assert_array_equal(four[2][1], four[1])
+    # in the table itself the words lie striped
+    raw = np.asarray(four[0].table_in.raw())
+    np.testing.assert_array_equal(raw[_word_rows(four[0])], four[1])
+    assert not np.array_equal(raw[:VOCAB], four[1])
+    assert four[0].nearest("w3", 5) == one[0].nearest("w3", 5)
+
+
+@pytest.mark.parametrize("use_ps", [0, 1])
+@pytest.mark.parametrize("cbow,hs", [(0, 0), (1, 0), (0, 1), (1, 1)])
+def test_every_mode_trains_the_words_rows_on_row_shards(cbow, hs, use_ps):
+    """The other epochs and the block path turn words into rows by the
+    same map: after a pass on four shards the rows that moved are words'
+    rows, most words' rows moved, and no row without a word did."""
+    _init(4)
+    we = _we(cbow=cbow, hs=hs, shared_negatives=0, use_ps=use_ps,
+             data_block_size=1_000)
+    ids = _stream(2_000, seed=6)
+    old = we.table_in.get(), we._sec_table().get()
+    (we.train_ps_blocks if use_ps else we.train_fused)(ids, epochs=1)
+    moved = (we.table_in.get() != old[0]).any(axis=1)
+    rows = _word_rows(we)
+    seen = np.unique(ids)
+    assert moved[rows[seen]].mean() > 0.9
+    assert not np.delete(moved, rows[seen]).any()
+    if not hs:      # embed_out is a word table too
+        moved = (we.table_out.get() != old[1]).any(axis=1)
+        assert moved[rows[seen]].mean() > 0.9
+        assert not np.delete(moved, rows).any()
 
 
 # ---------------------------------------------------------------------- #
@@ -255,9 +358,13 @@ def test_fused_span_counts_update_rows_by_shard(shards, epochs, head,
     rows = np.concatenate([np.tile(np.asarray(cb).ravel(), epochs),
                            np.tile(np.asarray(xb).ravel(), epochs),
                            pools.ravel()])
-    want = np.bincount(owner_rows(rows, VOCAB, shards), minlength=shards)
+    # ISSUE 36: the owner of a row is the shard its word was dealt to
+    want = np.bincount(we._words(rows) % shards, minlength=shards)
     assert a["shards"] == shards
     assert a["update_rows_by_shard"] == want.tolist()
+    assert want.tolist() == np.bincount(_owners(we, rows),
+                                        minlength=shards).tolist()
+    assert want.min() > 0
     assert sum(a["update_rows_by_shard"]) == epochs * batches * (2 * 64 + 16)
     # ISSUE 28: the pairs' update rows before combining, and the distinct
     # rows the table scatters were handed after (centres plus contexts)
@@ -266,9 +373,20 @@ def test_fused_span_counts_update_rows_by_shard(shards, epochs, head,
                 for r in list(np.asarray(cb)) + list(np.asarray(xb))]
     assert a["unique_rows"] == epochs * sum(d.size for d in distinct)
     assert a["unique_rows"] < a["update_rows"]
-    # ISSUE 31: and those of them that the tables' heads took
-    assert a["head_rows"] == epochs * sum((d < head).sum() for d in distinct)
+    # ISSUE 31: and those of them that the tables' heads took, the first
+    # HEAD // shards rows of every shard
+    per = we.table_in.rows_per_shard
+    tails = np.asarray([[((d // per == s) & (d % per >= head // shards)).sum()
+                         for s in range(shards)] for d in distinct])
+    assert a["head_rows"] == a["unique_rows"] - epochs * tails.sum()
     assert (a["head_rows"] < a["unique_rows"]) == (head < VOCAB)
+    # ISSUE 36: a shard's walks were handed its own tail rows, in whole
+    # chunks (of 64 slots here: a batch is smaller than CHUNK), and so no
+    # more than the tail rows and a chunk a shard, a table and a minibatch
+    walk = np.asarray(a["walk_slots_by_shard"])
+    np.testing.assert_array_equal(walk,
+                                  epochs * (-(-tails // 64) * 64).sum(axis=0))
+    assert walk.sum() <= epochs * (tails.sum() + tails.shape[0] * shards * 64)
     per_batch = (2 * 64 + 16) * WIDTH * 4       # float32 on the CPU
     assert a["allreduce_bytes"] == (shards > 1) * epochs * batches * per_batch
     # the pairs' share was counted when the pairs were generated

@@ -106,7 +106,8 @@ def test_train_fused_equals_the_copy_chained_epoch_bit_for_bit():
     epoch = w2v.make_fused_shared_epoch(
         w2v.W2VConfig(len(ref.dict), cfg.size, cfg.negative, cfg.window,
                       cfg.alpha, False, False, cfg.shared_negatives),
-        ref.unigram, compute_dtype=jnp.float32)
+        ref.unigram, compute_dtype=jnp.float32,
+        slots=we._fused_slots)     # the pools as rows, as the pairs are
     lcg = jnp.asarray(w2v.init_lcg_state(cfg.shared_negatives, cfg.seed))
     win, wout = jnp.copy(ref.table_in.raw()), jnp.copy(ref.table_out.raw())
     for _ in range(4):

@@ -510,8 +510,8 @@ def _we_run(plane, pipeline, cache_rows, mode="auto"):
     we._train_prepared = lambda p, nw: (losses.append(orig(p, nw))
                                         or losses[-1])
     stats = we.train_ps_blocks(we.prepare_ids(tokens))
-    rin = we.table_in.get_rows(np.arange(we.table_in.shape[0]))
-    rout = we.table_out.get_rows(np.arange(we.table_out.shape[0]))
+    rows = we._rows(np.arange(len(we.dict)))    # the words' rows
+    rin, rout = we.table_in.get_rows(rows), we.table_out.get_rows(rows)
     cache = we.table_in.train_cache_stats()
     mv.shutdown()
     assert np.isfinite(stats["loss"])
