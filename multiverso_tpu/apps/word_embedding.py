@@ -230,6 +230,12 @@ class WordEmbedding:
         self.unigram = dictionary.unigram_table()
         self._trained_words = 0
         self._calls = 0     # training calls so far: the call spans' request
+        # closes a dispatched program's device span when the device is
+        # done with it; no thread unless a capture or trace_ids reads it.
+        # train_fused leaves it running between calls (a thread's start
+        # and join cost 0.8 ms of host a call, my chip run, PR 37);
+        # train_ps_blocks closes it with its one long call
+        self._watcher = _trace.DeviceWatcher()
         # caller already sharded the corpus (skip the blocks[wid::nw] split;
         # we_async_worker-style drivers that feed per-rank shards set it via
         # -data_presplit 1)
@@ -547,7 +553,8 @@ class WordEmbedding:
                      shards=t_in.num_shards)
             lcg_before = self._lcg if shared else None
             rows = []   # a pass's distinct update rows, on the device
-            with _trace.span("we.fused.dispatch", programs=epochs), \
+            t0_ns = time.time_ns()
+            with _trace.span("we.fused.dispatch", programs=epochs) as disp, \
                     t_in._dispatch_lock, t_sec._dispatch_lock:
                 key = None if shared else jax.random.key(cfg.seed)
                 for _ in range(epochs):
@@ -563,6 +570,10 @@ class WordEmbedding:
                             si["data"], ss["data"], *batches, sub)
                     t_in.adopt({"data": win, "ustate": si["ustate"]})
                     t_sec.adopt({"data": wsec, "ustate": ss["ustate"]})
+            # the call's programs are in flight from here until the last
+            # pass's loss is ready (it waits for the chain)
+            self._watcher.watch("we.fused.device", loss, t0_ns,
+                                request=self._calls, cause=disp.id)
             if shared:
                 call.set(**self._sharded_call_counts(
                     pair_rows, lcg_before, n_batches, epochs))
@@ -633,13 +644,12 @@ class WordEmbedding:
         # block it waits on is done
         with _trace.span("we.blocks", request=self._calls,
                          plane="device" if device_plane else "host"
-                         ) as call, _trace.DeviceWatcher() as watcher:
+                         ) as call, self._watcher:
             return self._run_ps_blocks(ids, epochs or self.cfg.epoch,
-                                       device_plane, call, watcher)
+                                       device_plane, call)
 
     def _run_ps_blocks(self, ids: np.ndarray, epochs: int,
-                       device_plane: bool, call, watcher
-                       ) -> Dict[str, float]:
+                       device_plane: bool, call) -> Dict[str, float]:
         """The body of :meth:`train_ps_blocks`, inside its ``we.blocks``
         span (``call``, which takes the block and word counts)."""
         cfg = self.cfg
@@ -697,7 +707,7 @@ class WordEmbedding:
                         prepared = q.next()
                     if prepared is not None:
                         dev_losses.append(self._train_block_device(
-                            prepared, i, watcher))
+                            prepared, i))
                     words += block.size
         elif schedule and cfg.pipeline and len(schedule) > 1:
             # ISSUE-11 pipelined host plane: producers run the CPU-heavy
@@ -1255,13 +1265,14 @@ class WordEmbedding:
         self._fused_cache["ps_block"] = fn
         return fn
 
-    def _train_block_device(self, prepared: Tuple[Dict, int], index: int,
-                            watcher: "_trace.DeviceWatcher") -> jax.Array:
+    def _train_block_device(self, prepared: Tuple[Dict, int],
+                            index: int) -> jax.Array:
         """Dispatch one fused block program; returns the block loss as a
         DEVICE scalar (readback deferred to end of run). The dispatch is
         the ``we.block.dispatch`` span; the block itself ends when the
-        device is done, which ``watcher`` records as ``we.block.device``
-        while a profiler trace or ``trace_ids`` can read it."""
+        device is done, which the app's watcher records as
+        ``we.block.device`` while a profiler trace or ``trace_ids`` can
+        read it."""
         prep, prepare_span = prepared
         t_in, t_sec = self.table_in, self._sec_table()
         fn = self._fused_block_fn()
@@ -1281,8 +1292,8 @@ class WordEmbedding:
                 self._neg_dev)
             t_in.adopt({"data": din, "ustate": uin})
             t_sec.adopt({"data": dsec, "ustate": usec})
-        watcher.watch("we.block.device", loss, t0_ns, request=index,
-                      cause=sp.id)
+        self._watcher.watch("we.block.device", loss, t0_ns, request=index,
+                            cause=sp.id)
         return loss
 
     def _ps_topology(self) -> Tuple[int, int]:

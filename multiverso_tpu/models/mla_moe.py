@@ -45,6 +45,7 @@ router, softmax, norms, loss and tables are float32.
 from __future__ import annotations
 
 import functools
+import time
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -750,8 +751,10 @@ def routing_counts(counts: np.ndarray, cfg) -> Dict[str, Any]:
 class Trainer:
     """The host's side of training through the tables: holds the states
     between steps (one donated program a step, no table copied) and
-    records each step as an ``lm.step`` span. :meth:`adopt` hands the
-    states back to the tables at the end."""
+    records each step as an ``lm.step`` span and, while a capture or
+    ``trace_ids`` can read it, the step's program as ``lm.step.device``
+    (from its dispatch until the device is done with it). :meth:`adopt`
+    hands the states back to the tables at the end."""
 
     def __init__(self, cfg, tables: Dict[str, Any],
                  opt: Optional[AddOption] = None,
@@ -765,6 +768,9 @@ class Trainer:
         # (loss, counts, balance) of a step not read back yet
         self._ahead = None
         self._attn: Dict[str, int] = {}     # attn_grid of the first step
+        # closes a step's ``lm.step.device`` span when the device is done
+        # with it; idle unless a capture or ``trace_ids`` can read it
+        self._watcher = _trace.DeviceWatcher()
 
     def _turn(self, tokens, ahead: bool):
         """Queue a step on ``tokens`` (where given), then read back the
@@ -776,8 +782,11 @@ class Trainer:
                 if self.steps == 1:     # one program, one shape
                     self._attn = attn_grid(self.cfg, int(tokens.shape[1]))
                 sp.set(tokens=int(np.prod(tokens.shape)))
+                t0_ns = time.time_ns()
                 self.states, self.bias, *back = self._step(
                     self.states, self.bias, tokens)
+                self._watcher.watch("lm.step.device", back[0], t0_ns,
+                                    request=self.steps, cause=sp.id)
                 due, self._ahead = ((due, back) if ahead else (back, None))
             else:
                 self._ahead = None
@@ -811,5 +820,6 @@ class Trainer:
     def adopt(self) -> None:
         """Hand the states back to their tables (end of training)."""
         self.drain()
+        self._watcher.close()
         for name, table in self.tables.items():
             table.adopt(self.states[name])

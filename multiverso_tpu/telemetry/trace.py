@@ -55,6 +55,7 @@ crashes); ``tools/dump_metrics.py to-perfetto`` wraps them into the
 
 from __future__ import annotations
 
+import bisect
 import itertools
 import json
 import os
@@ -75,11 +76,13 @@ config.define_bool(
     "trace_ids", False,
     "mint per-request trace IDs on async-PS client ops, carry them in "
     "frame meta, and record the FINE trace_event spans (per PS request) "
-    "on both endpoints, plus the device-completion watcher of the "
-    "WordEmbedding block pipeline (telemetry/trace.py). Off by default: "
-    "fine tracing must cost nothing when unused; coarse program spans "
-    "(per call, block, table build, compile) are always recorded. Spans "
-    "dump to metrics_dir as trace-rank<r>.jsonl")
+    "on both endpoints, plus the device-completion watcher "
+    "(telemetry/trace.py:DeviceWatcher): one device span, from dispatch "
+    "to ready, for every program train_fused, train_ps_blocks and the "
+    "language-model Trainer launch. Off by default: fine tracing must "
+    "cost nothing when unused; coarse program spans (per call, block, "
+    "table build, compile) are always recorded. Spans dump to "
+    "metrics_dir as trace-rank<r>.jsonl")
 
 # bounded span buffer: an always-on tracer must cap memory, not OOM a
 # training run; 200k events is hours of coarse spans or of windowed PS
@@ -242,14 +245,16 @@ class Tracer:
 
     def record(self, name: str, t0_ns: int, t1_ns: int, *, request=None,
                cause: Optional[int] = None, cat: str = "prog",
-               **counts) -> None:
+               prof: Optional[bool] = None, **counts) -> None:
         """A COARSE span that has already ended (``time.time_ns()``
         stamps): what a listener or the watcher learns after the fact.
-        Its parent is the span open on the calling thread."""
+        Its parent is the span open on the calling thread; ``prof`` is
+        whether a capture runs now, unless the caller saw for itself
+        when the work began."""
         stack = self._stack()
         self._record(name, t0_ns, t1_ns, next(self._span_ids),
                      stack[-1] if stack else None, cause, request, cat,
-                     counts, profiling())
+                     counts, profiling() if prof is None else prof)
 
     def span(self, name: str, *, request=None, cause: Optional[int] = None,
              phase: Optional[str] = None, **counts) -> Span:
@@ -303,7 +308,13 @@ class DeviceWatcher:
     chip for hundreds. ``watch(name, array, ...)`` hands ``array`` (any
     output of the dispatched program) to ONE thread that waits on each
     in submission order (``block_until_ready`` releases the GIL) and
-    records a coarse span from the dispatch's start to the ready time.
+    records a coarse span (``cat`` ``"device"``) from the dispatch's
+    start to the ready time, with the count ``dispatched``: the
+    ``time.time_ns()`` at which ``watch`` was called, when the dispatch
+    had returned and the runtime had the program. From ``dispatched`` to
+    the span's end the program is in flight (:func:`device_timeline`).
+    ``prof`` is taken at ``watch`` too: a program dispatched inside a
+    captured window belongs to it wherever its end falls.
 
     Only while it can be read: with no profiler trace being captured and
     ``trace_ids`` off, ``watch`` does nothing, no thread exists and
@@ -316,27 +327,31 @@ class DeviceWatcher:
 
     def watch(self, name: str, array: Any, t0_ns: int, *, request=None,
               cause: Optional[int] = None) -> None:
-        if not (TRACER.enabled or profiling()):
+        prof = profiling()
+        if not (TRACER.enabled or prof):
             return
+        dispatched = time.time_ns()
         if self._thread is None:
             self._queue = queue.SimpleQueue()
             self._thread = threading.Thread(
                 target=self._run, name="mv-trace-watcher", daemon=True)
             self._thread.start()
-        self._queue.put((name, array, t0_ns, request, cause))
+        self._queue.put((name, array, t0_ns, dispatched, request, cause,
+                         prof))
 
     def _run(self) -> None:
         while True:
             item = self._queue.get()
             if item is None:
                 return
-            name, array, t0_ns, request, cause = item
+            name, array, t0_ns, dispatched, request, cause, prof = item
             try:
                 jax.block_until_ready(array)
             except Exception:   # noqa: BLE001 — a failed program is the
                 continue        # caller's to raise; record no span for it
             TRACER.record(name, t0_ns, time.time_ns(), request=request,
-                          cause=cause, cat="device")
+                          cause=cause, cat="device", prof=prof,
+                          dispatched=dispatched)
 
     def close(self) -> None:
         if self._thread is not None:
@@ -367,6 +382,76 @@ def self_ms(events: List[Dict]) -> Dict[int, float]:
                                 for a, b in children.get(e["id"], ())])
         out[e["id"]] = (e["dur"] - covered) * 1e-3
     return out
+
+
+NO_SPAN = "_no_span_open_"
+
+
+def device_timeline(events: List[Dict], since: Optional[float] = None
+                    ) -> Optional[Dict[str, Any]]:
+    """The device's timeline as the host knew it, from span records alone.
+
+    A ``cat == "device"`` span (:class:`DeviceWatcher`) is **in flight**
+    from its ``dispatched`` count (its start, where a record has none)
+    to its end: the device has work as far as the host can know. The
+    device is **starved** wherever no program is in flight between
+    ``since`` (by default the first ``dispatched``) and the last end. A
+    starved interval's **owner** is the innermost ``cat == "prog"`` span
+    open at its midpoint on the thread that made the next dispatch (the
+    thread of that device span's ``cause``), or :data:`NO_SPAN`: the
+    caller's own code between two calls. A device span's **run** is its
+    end less the later of its ``dispatched`` and the previous end: the
+    time the device had for it alone, queueing left out.
+
+    Returns ``lo`` / ``hi`` (microseconds, the events' clock),
+    ``in_flight`` (the union, ``[(a, b)]``), ``starved`` (``[(a, b,
+    owner)]``), ``starved_s``, ``by_owner`` (seconds by owner) and
+    ``runs`` (one ``{"name", "request", "id", "run_ms"}`` a device span,
+    in order of their ends); ``None`` where there is no device span."""
+    devs = sorted((e for e in events if e.get("cat") == "device"),
+                  key=lambda e: e["ts"] + e["dur"])
+    if not devs:
+        return None
+    flights = [(e["args"].get("dispatched", e["ts"] * 1e3) * 1e-3,
+                e["ts"] + e["dur"]) for e in devs]
+    runs, prev = [], float("-inf")
+    for e, (a, b) in zip(devs, flights):
+        runs.append({"name": e["name"], "request": e.get("request"),
+                     "id": e["id"], "run_ms": (b - max(a, prev)) * 1e-3})
+        prev = b
+    lo = min(a for a, _ in flights) if since is None else since
+    hi = flights[-1][1]
+    in_flight = [(max(a, lo), b) for a, b in
+                 _profiler.union_intervals(flights) if b > lo]
+    by_id = {e["id"]: e for e in events}
+    by_tid: Dict[Any, List[Dict]] = {}      # host spans by thread, by start
+    for e in sorted((e for e in events if e.get("cat") == "prog"),
+                    key=lambda e: e["ts"]):
+        by_tid.setdefault(e["tid"], []).append(e)
+    starts = {tid: [e["ts"] for e in es] for tid, es in by_tid.items()}
+    queued = {}                 # a flight's start -> its device span
+    for e, (a, _) in zip(devs, flights):
+        queued.setdefault(a, e)
+    edges = [lo] + [x for iv in in_flight for x in iv] + [hi]
+    starved, by_owner = [], {}
+    for a, b in zip(edges[0::2], edges[1::2]):
+        if b <= a:
+            continue
+        # the thread that queued the program whose dispatch ended the
+        # wait; on it, the last span to start before the midpoint or the
+        # nearest of its parents that is still open there
+        tid = by_id.get(queued[b].get("cause"), {}).get("tid")
+        mid = 0.5 * (a + b)
+        i = bisect.bisect_right(starts.get(tid, ()), mid) - 1
+        e = by_tid[tid][i] if i >= 0 else None
+        while e is not None and e["ts"] + e["dur"] < mid:
+            e = by_id.get(e["parent"])
+        owner = e["name"] if e is not None else NO_SPAN
+        starved.append((a, b, owner))
+        by_owner[owner] = by_owner.get(owner, 0.0) + (b - a) * 1e-6
+    return {"lo": lo, "hi": hi, "in_flight": in_flight, "starved": starved,
+            "starved_s": sum(by_owner.values()), "by_owner": by_owner,
+            "runs": runs}
 
 
 def enabled() -> bool:

@@ -200,6 +200,142 @@ def test_watcher_closes_spans_in_order_when_trace_ids_is_on():
     assert [e["cause"] for e in done] == [100 + i for i in range(5)]
     ends = [e["ts"] + e["dur"] for e in done]
     assert ends == sorted(ends) and all(e["parent"] is None for e in done)
+    # when watch() was called: the dispatch had returned, the device was
+    # not done (stamps are ns, ts and dur floats of us)
+    for e in done:
+        assert e["cat"] == "device" and e["prof"] is False
+        assert e["ts"] <= e["args"]["dispatched"] * 1e-3 <= (
+            e["ts"] + e["dur"] + 1e-3)
+        assert e["args"]["dispatched"] > t0
+
+
+# ---------------------------------------------------------------------- #
+# the device's timeline, from span records alone
+# ---------------------------------------------------------------------- #
+def _prog(i, ts, dur, tid=1, name="p", parent=None):
+    return {"name": name, "cat": "prog", "id": i, "parent": parent,
+            "cause": None, "request": None, "tid": tid, "ts": float(ts),
+            "dur": float(dur), "args": {}}
+
+
+def _dev(i, ts, dispatched, end, cause=None, request=None, name="d"):
+    """A device span as the watcher records it: ``dispatched`` in ns."""
+    return {"name": name, "cat": "device", "id": i, "parent": None,
+            "cause": cause, "request": request, "tid": 99, "ts": float(ts),
+            "dur": float(end - ts), "args": {"dispatched": dispatched * 1000}}
+
+
+def _oracle_starved_us(timeline_events, lo, hi):
+    """Whole microseconds of [lo, hi) in which no device span is in
+    flight, one at a time."""
+    devs = [e for e in timeline_events if e["cat"] == "device"]
+    return sum(1 for t in range(int(lo), int(hi)) if not any(
+        e["args"]["dispatched"] / 1000 <= t < e["ts"] + e["dur"]
+        for e in devs))
+
+
+TIMELINES = {
+    # two programs in flight at once, back to back on the device: the
+    # second's run counts from the first's end, and nothing is starved
+    "overlap": dict(
+        events=[_prog(1, 0, 1000, name="call"),
+                _prog(2, 0, 20, name="dispatch.a", parent=1),
+                _prog(3, 100, 20, name="dispatch.b", parent=1),
+                _dev(10, 0, 20, 500, cause=2, request=1),
+                _dev(11, 100, 120, 900, cause=3, request=2)],
+        starved=[], runs=[0.480, 0.400]),
+    # a gap between two programs, filed under the innermost span open on
+    # the dispatching thread (tid 1), not under another thread's (tid 2)
+    "gap_innermost": dict(
+        events=[_prog(1, 0, 1000, name="call"),
+                _prog(2, 0, 20, name="dispatch", parent=1),
+                _prog(3, 500, 300, name="call.count", parent=1),
+                _prog(4, 550, 100, name="call.count.inner", parent=3),
+                _prog(5, 400, 500, tid=2, name="other.thread"),
+                _prog(6, 805, 10, name="dispatch", parent=1),
+                _dev(10, 0, 20, 500, cause=2, request=1),
+                _dev(11, 805, 815, 990, cause=6, request=2)],
+        starved=[(500, 815, "call.count")], runs=[0.480, 0.175]),
+    # between two calls no span of the program is open
+    "gap_between_calls": dict(
+        events=[_prog(1, 0, 510, name="call"),
+                _prog(2, 0, 20, name="dispatch", parent=1),
+                _prog(3, 700, 300, name="call"),
+                _prog(4, 700, 20, name="dispatch", parent=3),
+                _dev(10, 0, 20, 500, cause=2, request=1),
+                _dev(11, 700, 720, 990, cause=4, request=2)],
+        starved=[(500, 720, ttrace.NO_SPAN)], runs=[0.480, 0.270]),
+    # with a lower bound before the first dispatch, the lead-in counts
+    "lead_in": dict(
+        since=-50.0,
+        events=[_prog(1, -50, 1000, name="call"),
+                _prog(2, -10, 30, name="dispatch", parent=1),
+                _dev(10, -10, 20, 500, cause=2, request=1)],
+        starved=[(-50, 20, "call")], runs=[0.480]),
+    # a record without the count (an older file) is in flight from its
+    # start; a cause that is not in the list names no thread
+    "no_count_no_cause": dict(
+        events=[_prog(1, 0, 1000, name="call"),
+                dict(_dev(10, 0, 0, 400), args={}),
+                _dev(11, 600, 610, 900, cause=12345)],
+        starved=[(400, 610, ttrace.NO_SPAN)], runs=[0.400, 0.290]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TIMELINES))
+def test_device_timeline_on_a_synthetic_list(case):
+    c = TIMELINES[case]
+    got = ttrace.device_timeline(c["events"], since=c.get("since"))
+    assert got["starved"] == [tuple(map(float, s[:2])) + (s[2],)
+                              for s in c["starved"]]
+    assert [r["run_ms"] for r in got["runs"]] == pytest.approx(c["runs"])
+    assert [r["request"] for r in got["runs"]] == [
+        e["request"] for e in c["events"] if e["cat"] == "device"]
+    by_owner = {}
+    for a, b, owner in c["starved"]:
+        by_owner[owner] = by_owner.get(owner, 0.0) + (b - a) * 1e-6
+    assert got["by_owner"] == pytest.approx(by_owner)
+    assert got["starved_s"] == pytest.approx(sum(by_owner.values()))
+    if all("dispatched" in e["args"] for e in c["events"]
+           if e["cat"] == "device"):
+        assert got["starved_s"] * 1e6 == pytest.approx(
+            _oracle_starved_us(c["events"], got["lo"], got["hi"]))
+    # in flight and starved tile [lo, hi]
+    covered = sum(b - a for a, b in got["in_flight"]) + sum(
+        b - a for a, b, _ in got["starved"])
+    assert covered == pytest.approx(got["hi"] - got["lo"])
+
+
+@pytest.mark.parametrize("case", ["gap_innermost", "no_device_span"])
+def test_dump_metrics_timeline_prints_the_summary(case, tmp_path, capsys):
+    """The operator's reading of a ``trace-rank<r>.jsonl``."""
+    import json
+
+    from tools import dump_metrics
+
+    events = (TIMELINES[case]["events"] if case in TIMELINES
+              else [_prog(1, 0, 10)])
+    path = tmp_path / "trace-rank0.jsonl"
+    path.write_text("".join(json.dumps(e) + "\n" for e in events))
+    assert dump_metrics.main(["timeline", str(path)]) == 0
+    text = capsys.readouterr().out
+    if case == "no_device_span":
+        assert "no device span" in text and "trace_ids" in text
+        return
+    # 315 us starved of 970 (first dispatch to last ready), all of it
+    # under call.count; the longer run first, with the request that names it
+    assert "2 programs" in text and "32.474%" in text
+    lines = text.splitlines()
+    [owner] = [ln for ln in lines if ln.endswith("call.count")]
+    assert float(owner.split()[0]) == pytest.approx(315e-6)
+    runs = [ln for ln in lines if "request=" in ln]
+    assert [ln.split()[-1] for ln in runs] == ["request=1", "request=2"]
+    assert float(runs[0].split()[0]) == pytest.approx(0.480)
+
+
+def test_device_timeline_without_device_spans_is_none():
+    assert ttrace.device_timeline([_prog(1, 0, 10)]) is None
+    assert ttrace.device_timeline([]) is None
 
 
 def _xplane_names(trace_dir):
@@ -353,6 +489,66 @@ def test_train_fused_leaves_its_spans_and_counts(mode):
         # the parts lie inside the call and leave little of it unnamed
         assert ttrace.self_ms(events)[call["id"]] <= call["dur"] * 1e-3
     assert second[-1]["request"] == first[-1]["request"] + 1
+
+
+class _NoThread:
+    """In the watcher's place at defaults: no thread and no queue may be
+    made, so nothing can be held for it."""
+
+    def __init__(self, *a, **k):
+        raise AssertionError("the watcher woke with nothing to read it")
+
+
+def _quiet_watcher(monkeypatch):
+    monkeypatch.setattr(ttrace.threading, "Thread", _NoThread)
+    monkeypatch.setattr(ttrace.queue, "SimpleQueue", _NoThread)
+
+
+def _watcher_threads():
+    return [t for t in threading.enumerate() if t.name == "mv-trace-watcher"]
+
+
+@pytest.mark.parametrize("mode", ["defaults", "trace_ids"])
+def test_train_fused_records_one_device_span_a_call(mode, monkeypatch):
+    we, ids = _tiny_we()
+    if mode == "trace_ids":
+        config.set_flag("trace_ids", True)
+        ttrace.configure()
+    else:
+        _quiet_watcher(monkeypatch)
+    start = len(ttrace.events())
+    for _ in range(2):
+        we.train_fused(ids, epochs=2)
+    # the app's watcher outlives a call (no thread start and join a
+    # call); closing it waits for what it was handed
+    assert len(_watcher_threads()) == (mode == "trace_ids")
+    we._watcher.close()
+    assert not _watcher_threads() and we._watcher._queue is None
+    events = ttrace.events()[start:]
+    done = [e for e in events if e["cat"] == "device"]
+    if mode == "defaults":
+        assert done == []
+        return
+    calls = [e for e in events if e["name"] == "we.fused"]
+    disps = [e for e in events if e["name"] == "we.fused.dispatch"]
+    assert [e["name"] for e in done] == ["we.fused.device"] * 2
+    for dev, call, disp in zip(done, calls, disps):
+        assert dev["request"] == call["request"] and dev["parent"] is None
+        assert dev["cause"] == disp["id"] and disp["parent"] == call["id"]
+        # from before the dispatch until ready (the call waits for the
+        # same loss); in flight from the dispatch's return
+        assert call["ts"] <= dev["ts"] <= disp["ts"]
+        assert dev["args"]["dispatched"] * 1e-3 >= (
+            disp["ts"] + disp["dur"] - 1e-3)
+    line = ttrace.device_timeline(events)
+    assert [r["request"] for r in line["runs"]] == [
+        c["request"] for c in calls]
+    # whatever was starved (the seam between the calls, where the
+    # watcher's stamp was not late) is the main thread's: the caller's
+    # own code, or a span of a call
+    assert {owner for _, _, owner in line["starved"]} <= {
+        ttrace.NO_SPAN, "we.fused", "we.fused.dispatch", "we.fused.pairs",
+        "we.fused.wait", "we.fused.count"}
 
 
 BLOCK_SPANS = {"we.blocks", "we.prepare", "we.prepare.arrays",
@@ -519,6 +715,44 @@ def test_lm_step_carries_its_counts():
     assert want["routed_rows"] == layers * 64 * cfg.top_k
     assert 0 <= want["held_rows"] <= want["routed_rows"]
     assert want["overflow_rows"] == 0 and want["load_max_over_mean"] >= 1.0
+
+
+@pytest.mark.parametrize("mode", ["defaults", "trace_ids"])
+def test_lm_steps_record_one_device_span_a_program(mode, monkeypatch):
+    mla_moe, cfg, tables, tokens = _tiny_lm()
+    if mode == "trace_ids":
+        config.set_flag("trace_ids", True)
+        ttrace.configure()
+    else:
+        _quiet_watcher(monkeypatch)
+    trainer = mla_moe.Trainer(cfg, tables)
+    before = len(ttrace.events())
+    trainer.step(tokens)
+    assert trainer.step_ahead(tokens) is None
+    assert trainer.step_ahead(tokens) is not None
+    w = trainer._watcher
+    assert (w._thread is not None) == (mode == "trace_ids")
+    trainer.adopt()
+    assert w._thread is None and w._queue is None and not _watcher_threads()
+    events = ttrace.events()[before:]
+    steps = [e for e in events if e["name"] == "lm.step"]
+    done = [e for e in events if e["cat"] == "device"]
+    assert [e["request"] for e in steps] == [1, 2, 3, 3]   # and the drain
+    if mode == "defaults":
+        assert done == []
+        return
+    assert [e["name"] for e in done] == ["lm.step.device"] * 3
+    for dev, step in zip(done, steps):
+        assert dev["request"] == step["request"]
+        assert dev["cause"] == step["id"] and dev["parent"] is None
+        assert step["ts"] <= dev["ts"] <= dev["args"]["dispatched"] * 1e-3
+    # the third step was queued while the second ran: its program was in
+    # flight before the second's read-back returned
+    waits = [e for e in events if e["name"] == "lm.step.wait"]
+    assert done[2]["args"]["dispatched"] * 1e-3 <= waits[1]["ts"] + 1e-3
+    line = ttrace.device_timeline(events)
+    assert [r["request"] for r in line["runs"]] == [1, 2, 3]
+    assert all(r["run_ms"] > 0 for r in line["runs"])
 
 
 def test_lm_step_carries_the_flash_kernels_grid_counts():

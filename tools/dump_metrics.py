@@ -9,6 +9,7 @@ records comparable across bench runs:
   python tools/dump_metrics.py show  <metrics.jsonl> [--record N]
   python tools/dump_metrics.py diff  <a.jsonl> <b.jsonl>
   python tools/dump_metrics.py to-perfetto <trace.jsonl> <out.json>
+  python tools/dump_metrics.py timeline <trace.jsonl>
 
 ``show`` prints the chosen record (default: last) as a monitor table
 (count / mean / p50 / p90 / p99 / max) plus the shard stats. ``diff``
@@ -17,7 +18,12 @@ ratios — the "did this bench run regress the tail" question in one
 screen. ``to-perfetto`` wraps a JSONL trace-event file into the
 ``{"traceEvents": [...]}`` envelope the Perfetto UI / chrome://tracing
 expect (events from several ranks' files may be concatenated first; the
-spans carry ``pid`` = rank).
+spans carry ``pid`` = rank). ``timeline`` reads the device spans of a
+``trace-rank<r>.jsonl`` (a run with ``-trace_ids=true`` has them) and
+prints the device's timeline as the host knew it
+(``telemetry/trace.device_timeline``): the share of it the device was
+starved (no program in flight), those seconds by the host span that was
+open meanwhile, and the five longest runs with their ``request``.
 
 Both commands also accept the cluster aggregator's time series
 (``cluster.jsonl``, records with ``kind: "cluster"`` — see
@@ -38,6 +44,7 @@ from __future__ import annotations
 
 import json
 import os
+import statistics
 import sys
 from typing import Dict, List, Optional
 
@@ -650,6 +657,31 @@ def to_perfetto(trace_jsonl: str, out_path: str) -> int:
     return len(events)
 
 
+def format_timeline(events: List[Dict]) -> str:
+    """The device's timeline of a span file, for an operator: why the
+    device sat idle, as far as the host could know."""
+    from multiverso_tpu.telemetry import trace
+
+    line = trace.device_timeline(events)
+    if line is None:
+        return ("no device span in the file: run with -trace_ids=true "
+                "(docs/OBSERVABILITY.md, \"Trace IDs and spans\")")
+    extent = (line["hi"] - line["lo"]) * 1e-6
+    runs = sorted(line["runs"], key=lambda r: -r["run_ms"])
+    median = statistics.median(r["run_ms"] for r in runs)
+    out = [f"device timeline: {len(runs)} programs over {extent:.3f} s "
+           f"(first dispatch to last ready)",
+           f"  starved (no program in flight): {line['starved_s']:.6f} s "
+           f"= {100.0 * line['starved_s'] / extent:.3f}%",
+           "  starved seconds by the host span open meanwhile:"]
+    out += [f"    {seconds:12.6f}  {owner}" for owner, seconds in
+            sorted(line["by_owner"].items(), key=lambda kv: -kv[1])]
+    out.append(f"  longest runs (median {median:.3f} ms):")
+    out += [f"    {r['run_ms']:12.3f} ms  {r['name']}  request={r['request']}"
+            for r in runs[:5]]
+    return "\n".join(out)
+
+
 def main(argv: List[str]) -> int:
     if not argv:
         print(__doc__)
@@ -696,6 +728,9 @@ def main(argv: List[str]) -> int:
     if cmd == "to-perfetto":
         n = to_perfetto(rest[0], rest[1])
         print(f"wrote {n} events to {rest[1]}")
+        return 0
+    if cmd == "timeline":
+        print(format_timeline(load_records(rest[0])))
         return 0
     print(__doc__)
     return 2
