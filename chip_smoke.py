@@ -1,13 +1,16 @@
 """chip_smoke.py: prove that the system starts and computes correctly on
 the TPU it is measured on. One process, no arguments, no network.
 
-Five stages, each driven through the entry points a user calls, each
+Six stages, each driven through the entry points a user calls, each
 checked by the repo's own means (a NumPy float32 statement of the updater
-rule, a falling finite loss, ``parallel.ring.reference_attention``):
+rule, a falling finite loss, ``jnp.take``,
+``parallel.ring.reference_attention``):
 
   device  a TPU is present; versions and the compile-cache directory
   tables  sync plane: MatrixTable row adds/gets, ArrayTable add/get
   we      WordEmbedding: fused trainer, then the PS-block trainer
+  rows    a row-sharded table read by its owners (ops/row_combine.take_rows)
+          beside the partitioner's masked gather and all-reduce
   ps      uncoordinated plane: a two-rank world with device-backed shards
   lm      the 472M transformer step with the Pallas flash kernel
 
@@ -250,6 +253,112 @@ def stage_we(fused_tokens: int = 400_000, fused_vocab: int = 10_000,
             "ps_tokens": int(ids_ps.size)}
 
 
+def stage_rows(rows_per_shard: int = 3_000_001, width: int = 300,
+               batch: int = 8192, calls: int = 32) -> Dict[str, Any]:
+    """The sharded table path's read (``ops/row_combine.take_rows``) on a
+    table row-sharded over every device, at ``we-fused-x4``'s shapes: the
+    rows a minibatch's ids name, read by the shards that own them and
+    handed round by an all-gather, held bit for bit to what the
+    partitioner makes of ``jnp.take`` (a masked gather of every slot on
+    every device and an all-reduce), for ids dealt evenly and for ids that
+    all lie in the last shard (more rounds than one), and for ids without
+    a duplicate (the round's size counts on a minibatch's duplicates:
+    ``row_combine.gather_cap``; these take two rounds). Then ``calls``
+    minibatches of each in one program: the ms a call, by this process's
+    clock around programs it waits for, compilation apart. The read's
+    temporaries are held to the partitioner's: left alone, the v5e's
+    compiler casts the whole shard ahead of the later rounds' loop, half
+    a shard of temporaries (PERF.md, PR 38). On one device the two are
+    one program."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.layout import Format
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import multiverso_tpu as mv
+    from multiverso_tpu.ops import row_combine
+    from multiverso_tpu.table import row_program_layout
+
+    mv.init()
+    mesh = mv.mesh()
+    axis = mesh.axis_names[-1]
+    shards = mesh.shape[axis]
+    rows = shards * rows_per_shard
+    sharding = NamedSharding(mesh, P(axis, None))
+    # laid out as a table's own row programs hold it
+    fmt = Format(row_program_layout((rows, width), jnp.float32, sharding),
+                 sharding)
+    table = jax.jit(
+        lambda: jax.random.uniform(jax.random.key(SEED), (rows, width),
+                                   jnp.float32, -0.5, 0.5),
+        out_shardings=fmt)()
+    rng = np.random.default_rng(SEED)
+    # frequency ranks of a Zipf law, dealt round the shards as the app's
+    words = (rng.zipf(1.1, (calls, batch)) - 1) % (rows - shards)
+    ids = {"even": row_combine.striped_row(words, shards, rows_per_shard),
+           "last_shard": rows - 1 - words % rows_per_shard,
+           "distinct": np.stack([rng.choice(rows, batch, replace=False)
+                                 for _ in range(calls)])}
+    cd = jnp.bfloat16
+    plan = jax.jit(row_combine.plan_rows, static_argnums=(1, 2),
+                   out_shardings=NamedSharding(mesh, P()))
+
+    def epoch(read):
+        """``calls`` minibatches of ``read`` in one program."""
+        return jax.jit(
+            lambda tab, ids, plans: jax.lax.scan(
+                lambda _, x: (None, read(tab, *x)), None, (ids, plans))[1],
+            in_shardings=(fmt, None, None))
+
+    programs = {
+        "take_rows": epoch(lambda tab, i, p: row_combine.take_rows(
+            tab, i, p, sharding, cd)),
+        "partitioner": epoch(
+            lambda tab, i, p: jnp.take(tab, i, axis=0).astype(cd))}
+    out: Dict[str, Any] = {
+        "shards": shards, "table": f"f32[{rows},{width}]", "batch": batch,
+        "calls": calls, "cap": row_combine.gather_cap(batch, shards)}
+    shard_bytes = rows_per_shard * width * 4
+    for kind, rows_of in ids.items():
+        i = jnp.asarray(rows_of.astype(np.int32))
+        plans = plan(i, rows, shards)
+        got, temp = {}, {}
+        for name, program in programs.items():
+            compiled = program.lower(table, i, plans).compile()
+            temp[name] = compiled.memory_analysis().temp_size_in_bytes
+            got[name] = np.asarray(compiled(table, i, plans))
+            best = float("inf")
+            for _ in range(3):
+                t0 = time.perf_counter()
+                jax.block_until_ready(compiled(table, i, plans))
+                best = min(best, time.perf_counter() - t0)
+            out[f"{kind}_{name}_ms"] = round(best / calls * 1e3, 4)
+        if not np.array_equal(got["take_rows"].view(np.uint16),
+                              got["partitioner"].view(np.uint16)):
+            raise AssertionError(f"take_rows differs from jnp.take on "
+                                 f"{kind} ids over {shards} shards")
+        if not got["take_rows"].any():
+            raise AssertionError(f"take_rows read zeros ({kind})")
+        # an eighth of a shard of room (a MiB for a toy table, whose
+        # plans outweigh it): a shard cast whole is half a shard
+        if temp["take_rows"] > temp["partitioner"] + max(shard_bytes // 8,
+                                                         1 << 20):
+            raise AssertionError(
+                f"take_rows holds {temp['take_rows']} B of temporaries on "
+                f"{kind} ids, the partitioner's gather {temp['partitioner']}"
+                f", a shard {shard_bytes}")
+        out[f"{kind}_rounds_past_first"] = int(
+            np.asarray(row_combine.plan_counts(plans))[-1])
+    out["take_rows_temp_mb"] = round(temp["take_rows"] / 1e6, 1)
+    out["partitioner_temp_mb"] = round(temp["partitioner"] / 1e6, 1)
+    if shards > 1 and not (out["last_shard_rounds_past_first"] > 0
+                           < out["distinct_rounds_past_first"]):
+        raise AssertionError(f"ids of one shard, or ids without a "
+                             f"duplicate, took one round: {out}")
+    table.delete()
+    return out
+
+
 def stage_ps(rows: int = 100_000, cols: int = 128, batch: int = 4096,
              chip: bool = True) -> Dict[str, Any]:
     """Uncoordinated plane: a two-rank world inside this process (every
@@ -482,7 +591,8 @@ def result_line(ok: bool, device: Dict[str, Any]) -> Dict[str, Any]:
 
 
 STAGES: Tuple[Tuple[str, Callable[[], Dict[str, Any]]], ...] = (
-    ("tables", stage_tables), ("we", stage_we), ("ps", stage_ps),
+    ("tables", stage_tables), ("we", stage_we), ("rows", stage_rows),
+    ("ps", stage_ps),
     ("lm", stage_lm), ("memory", stage_memory))
 
 

@@ -368,8 +368,12 @@ class WordEmbedding:
         jitted program, run once a corpus and table (three sorts a
         minibatch, 14 ms of a v5e at 439 x 8,192: two of them would be
         4% of every call), replicated on the tables' mesh, where the
-        epoch program reads them. ``None`` unless the epoch is the
-        shared-negatives one: only it combines its update rows."""
+        epoch program reads them: how the update rows combine for the
+        table writes and, on row shards, where the reads find each row
+        among the rows the shards hand round (``plan.place``: one more
+        ``int32[batches, B]`` a table, 29 MB a 600,000-word chunk).
+        ``None`` unless the epoch is the shared-negatives one: only it
+        combines its update rows."""
         if not self._shared_epoch:
             return None
         if self._plan_fn is None:
@@ -479,9 +483,16 @@ class WordEmbedding:
         shard owns (``pair_rows``: the pairs' centres and contexts, from
         the pair cache; plus every batch's pool, drawn here on the host
         from the sampler state the call started with, while the device
-        runs the call), and the bytes the partitioner's all-reduce
-        carries: each batch's gathered rows (B centres, B contexts, K'
-        pool rows) in the compute dtype, none on one shard."""
+        runs the call), and ``allreduce_bytes``: each batch's gathered
+        rows (B centres, B contexts, K' pool rows) in the compute dtype,
+        none on one shard. That is what every chip is handed, and what the
+        partitioner's all-reduce carried; since the pairs' rows are read
+        by their owners (``row_combine.take_rows``) only the pool's go by
+        an all-reduce, and the count keeps its name and rule for the
+        benchmark's facts. What the all-gathers hand every chip is
+        ``gather_cap(B, shards) * shards`` rows a table and a round, in
+        the compute dtype: on four shards of the benchmark's cell 2 x
+        6,144 rows of 600 B a minibatch where this counts 2 x 8,192."""
         cfg, t = self.cfg, self.table_in
         per_batch = 2 * cfg.batch_size + cfg.shared_negatives
         if t.num_shards == 1:       # whole tables: nothing to draw again
@@ -586,12 +597,14 @@ class WordEmbedding:
             if shared:
                 # the pairs' update rows before combining, the distinct
                 # ones after, those of them that the dense adds of the
-                # tables' heads took, and the slots every shard's walks
-                # were handed for the others
-                unique, head, *walk = np.sum(rows, axis=0)
+                # tables' heads took, the slots every shard's walks were
+                # handed for the others, and the rounds past the first
+                # that the reads of row-sharded tables took
+                unique, head, *walk, rounds = np.sum(rows, axis=0)
                 call.set(update_rows=2 * epochs * int(pairs),
                          unique_rows=int(unique), head_rows=int(head),
-                         walk_slots_by_shard=[int(n) for n in walk])
+                         walk_slots_by_shard=[int(n) for n in walk],
+                         gather_rounds=int(rounds))
             with _trace.span("we.fused.count"):
                 dt = time.perf_counter() - t0
                 # words/sec follows the word2vec convention: corpus

@@ -342,16 +342,22 @@ def shared_neg_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
     centers/contexts: (B,) int32; neg_ids: (K',) int32: rows of the tables.
     Tables stay in their storage dtype (f32); compute runs in
     ``compute_dtype`` (bf16 on the MXU). The pairs' update rows reach the
-    tables with their duplicates combined (``ops/row_combine``); ``plans``
-    is the (centers, contexts) pair of :func:`row_combine.plan_rows` where
-    the caller made them ahead of the step; a ``None`` is made in it.
-    ``shardings`` are the two tables', where their rows are sharded over
-    a mesh.
+    tables with their duplicates combined, and on row shards the pairs'
+    rows are read by the shards that own them (``ops/row_combine``:
+    ``add_rows``, ``take_rows``); ``plans`` is the (centers, contexts)
+    pair of :func:`row_combine.plan_rows` where the caller made them ahead
+    of the step; a ``None`` is made where it is used. ``shardings`` are
+    the two tables', where their rows are sharded over a mesh.
     """
     cd = compute_dtype
     with jax.named_scope("mv.fused.gather"):
-        v = jnp.take(win, centers, axis=0).astype(cd)          # (B, D)
-        up = jnp.take(wout, contexts, axis=0).astype(cd)       # (B, D)
+        # a row-sharded table's rows are read by their owners and handed
+        # round (row_combine.take_rows); the pool's few stay the
+        # partitioner's
+        v = row_combine.take_rows(win, centers, plans[0], shardings[0],
+                                  cd)                          # (B, D)
+        up = row_combine.take_rows(wout, contexts, plans[1], shardings[1],
+                                   cd)                         # (B, D)
         un = jnp.take(wout, neg_ids, axis=0).astype(cd)        # (K', D)
     with jax.named_scope("mv.fused.grad"):
         pos = jnp.sum(v * up, axis=-1).astype(jnp.float32)     # (B,)
@@ -419,13 +425,14 @@ def make_fused_shared_epoch(cfg: W2VConfig, unigram: np.ndarray,
     where the caller keeps them with the pairs: the sorts behind them are
     4% of an epoch of 439 x 8,192 on a v5e, so a caller that runs the same
     pairs again makes them once; without them the epoch makes its own.
-    ``rows`` is ``int32[2 + shards]``, one array for one read-back
+    ``rows`` is ``int32[3 + shards]``, one array for one read-back
     (``row_combine.plan_counts`` of both plans): how many distinct centre
     and context rows those plans name, of ``2 * centers.size`` update rows
     (the table writes' work after and before combining); how many of them
     lay in the tables' heads, which the dense adds took and the walks did
-    not (``row_combine.HEAD``); and the slots each shard's walks were
-    handed.
+    not (``row_combine.HEAD``); the slots each shard's walks were handed;
+    and the rounds past the first that the reads of row-sharded tables
+    took (``row_combine.take_rows``).
     ``slots`` is the negative table (``2^table_bits`` ids, a word's being
     the row it lives in) where the caller has built it already
     (:func:`build_negative_table`; at 12M words a fifth of a second and

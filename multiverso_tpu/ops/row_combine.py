@@ -1,5 +1,6 @@
 """Scatter-add of a minibatch's update rows with its duplicates combined
-first: the table sees each distinct row once.
+first: the table sees each distinct row once. And, on a row-sharded table,
+the read of those rows by the shards that own them.
 
 XLA's scatter-add into a large table costs the same for every update row
 it is handed (0.10 us on a v5e into ``f32[1800001,300]``), a repeat of a
@@ -23,6 +24,8 @@ row than the scatter into the table.
   to the last distinct row. Pad slots of the last chunk are dropped by the
   scatter (``mode="drop"``): they write nothing, so ``unique_indices`` is
   a true promise.
+* :func:`take_rows` — ``jnp.take`` of the ids' rows, which on a
+  row-sharded table every shard makes of its own rows alone (below).
 
 Row shards (PERF.md, PR 36). A scatter slot costs a chip the same whether
 it keeps or drops the row, so a chip that is handed the whole plan pays
@@ -35,6 +38,27 @@ contiguous ranges, all lie in the first: :func:`striped_row` deals the
 ranks round the shards, and the app that owns the table applies it where
 a word becomes a row (``apps/word_embedding``). On one shard all of this
 is the identity and the program is the one it was.
+
+Reads on row shards (PERF.md, PR 38). Left to the partitioner, a
+``jnp.take`` on a table sharded by rows is a gather of all ``B`` slots on
+every chip (zeros for the rows it does not own) and an all-reduce of ``S``
+copies of which ``S - 1`` are zeros: on four v5e chips 0.61 of a 1.18 ms
+minibatch. The plan already holds a minibatch's distinct rows sorted by
+row id, so a shard's rows are ONE range of ``uniq``, from the end of the
+shard before it to its own ``end``. :func:`take_rows` is one
+``shard_map``: a chip reads :func:`gather_cap` slots of its range from its
+own shard, casts them to the compute type, ``lax.all_gather`` hands every
+chip every chip's ``[S * cap, D]`` (half an all-reduce's bytes on the
+links, one phase and not two), and one gather from that buffer by
+``plan.place`` (made in :func:`plan_rows`, off the minibatch's path: for
+each update row its round, its owner and its offset in the owner's range)
+gives the ``[B, D]`` rows in the ids' order. ``cap`` is static; a
+minibatch whose busiest shard owns more distinct rows takes more rounds, a
+``while`` whose trip count every chip reckons alike from the replicated
+plan, so any ids are read exactly, all in one shard included. The rows
+are the table's own bits cast once, where the partitioner summed a row
+and three zeros (which differs only for a ``-0.0``, handed on as it is
+here and as ``+0.0`` there).
 
 What the chip said about the promises (PERF.md, PR 28): ``unique_indices``
 changes nothing in the v5e's scatter today, and ``indices_are_sorted``
@@ -98,6 +122,9 @@ class RowPlan(NamedTuple):
     end: jax.Array       # [..., S] int32: and the slot where its rows end
     head_run: jax.Array  # [..., S * (head rows a shard)] int32: head row ->
     #                      its run, or _NONE
+    place: jax.Array     # [..., B] int32: where take_rows finds each update
+    #                      row among the rows the shards hand round; [..., 0]
+    #                      on one shard, which hands nothing round
 
 
 def striped_row(word, shards: int, per: int):
@@ -131,6 +158,33 @@ def row_shards(sharding) -> Tuple[Optional[str], int]:
     return axis, (1 if axis is None else sharding.mesh.shape[axis])
 
 
+def gather_cap(b: int, shards: int) -> int:
+    """Slots a row shard reads for a minibatch of ``b`` update rows in one
+    round of :func:`take_rows`: a static size (it shapes the all-gather),
+    from ``b`` and the shard count alone, three quarters of the shard's
+    share of the update rows. A shard that owns more distinct rows takes
+    another round, so the size decides speed and never the result.
+
+    What the size counts on: that the busiest shard of a minibatch owns no
+    more than ``3 * b / (4 * shards)`` DISTINCT rows, which with rows
+    dealt evenly (:func:`striped_row`) says a quarter or more of the
+    minibatch's update rows are repeats. Word pairs' are: on the benchmark's stream
+    68% of a minibatch's update rows are distinct (5,600 of 8,192;
+    ``counts.unique_share.we``) and the busiest of four shards owns 1,435
+    in the mean and 1,505 to 1,555 at the most over eight seeds, so about
+    one minibatch in 3,500 takes a second round. The read's cost follows
+    the slots: on four v5e chips the epoch runs at 1,841,700 to 1,844,000
+    words/s with 1,536 slots and 1,704,400 to 1,705,100 with ``b //
+    shards`` = 2,048 (two seeds each). Where the property fails, ids
+    without a duplicate, every minibatch takes two rounds, at ``b //
+    shards`` too (a busiest shard of 2,055 to 2,111): a read of 0.246 ms
+    where the one round takes 0.173 and the partitioner's gather and
+    all-reduce 0.439, so 0.07 ms a table slower and still ahead (PERF.md,
+    PR 38; ``chip_smoke.py`` stage ``rows``). The size cannot follow the
+    ids: it is a shape of the compiled epoch."""
+    return b if shards == 1 else max(3 * b // (4 * shards), 1)
+
+
 def plan_rows(ids: jax.Array, table_rows: int, shards: int = 1) -> RowPlan:
     """The plan for update-row ids ``[..., B]`` into a table of
     ``table_rows`` rows in ``shards`` equal contiguous row shards.
@@ -151,42 +205,74 @@ def plan_rows(ids: jax.Array, table_rows: int, shards: int = 1) -> RowPlan:
         return RowPlan(*(a.reshape(lead + a.shape[1:]) for a in plan))
     ids = ids.astype(jnp.int32)
     b = ids.shape[0]
-    # three sorts and no gather: an element gather of an epoch's ids
-    # takes a v5e five times what a sort of them does
+    # three sorts and no gather from the ids: an element gather of an
+    # epoch's ids takes a v5e five times what a sort of them does
     slots = jnp.arange(b, dtype=jnp.int32)
     srt, perm = lax.sort((ids, slots), num_keys=1)
     first = jnp.concatenate([jnp.ones(1, bool), srt[1:] != srt[:-1]])
     run_sorted = jnp.cumsum(first.astype(jnp.int32)) - 1
-    # back to the ids' own order: update row perm[i] lies in run_sorted[i]
-    _, run = lax.sort((perm, run_sorted), num_keys=1)
     uniq = jnp.sort(jnp.where(first, srt, table_rows + slots))
     per = table_rows // shards
     part = min(HEAD // shards, per)          # head rows a shard
+    first_row = jnp.arange(shards, dtype=jnp.int32) * per
+    end = jnp.searchsorted(uniq, first_row + per).astype(jnp.int32)
+    if shards == 1:
+        # back to the ids' own order: update row perm[i] lies in
+        # run_sorted[i]
+        _, run = lax.sort((perm, run_sorted), num_keys=1)
+        place = slots[:0]
+    else:
+        # and its place among the rows take_rows hands round: round after
+        # round, shard after shard, a shard's own distinct rows in order,
+        # gather_cap(b, shards) of them a round
+        cap, owner = gather_cap(b, shards), srt // per
+        at = run_sorted - jnp.take(_begin(end), owner)
+        _, run, place = lax.sort(
+            (perm, run_sorted, (at // cap * shards + owner) * cap + at % cap),
+            num_keys=1)
     owner, local = uniq // per, uniq % per   # a pad's owner is no shard
     head_run = jnp.full(shards * part, _NONE, jnp.int32).at[
         jnp.where(local < part, owner * part + local, _NONE)].set(
             slots, mode="drop")
-    first_row = jnp.arange(shards, dtype=jnp.int32) * per
     return RowPlan(run, uniq, run_sorted[-1] + 1,
                    jnp.searchsorted(uniq, first_row + part).astype(jnp.int32),
-                   jnp.searchsorted(uniq, first_row + per).astype(jnp.int32),
-                   head_run)
+                   end, head_run, place)
+
+
+def _begin(end: jax.Array) -> jax.Array:
+    """Where each shard's rows start among a plan's distinct rows, from
+    where they end (``RowPlan.end``): where the shard before it ends."""
+    return jnp.concatenate(
+        [jnp.zeros_like(end[..., :1]), end[..., :-1]], axis=-1)
+
+
+def _rounds(owned: jax.Array, cap: int) -> jax.Array:
+    """Rounds of ``cap`` slots a shard that :func:`take_rows` reads the
+    rows of a minibatch in, where its shards own ``owned [..., S]`` of
+    them: as many as the busiest shard needs, the same on every shard,
+    and one where none owns a row."""
+    return jnp.maximum((jnp.max(owned, axis=-1) + cap - 1) // cap, 1)
 
 
 def plan_counts(plan: RowPlan) -> jax.Array:
-    """What the table writes of the plans ``plan`` (any leading axes) are
-    handed, ``int32[2 + S]``, one array for one read-back: the distinct
-    rows; those of them in the shards' heads, which the dense adds take;
-    and for each shard the slots its walks are handed, the pads of a last
-    chunk included."""
-    chunk = min(CHUNK, plan.run.shape[-1])
-    begin = jnp.concatenate(
-        [jnp.zeros_like(plan.end[..., :1]), plan.end[..., :-1]], axis=-1)
+    """What the table writes and reads of the plans ``plan`` (any leading
+    axes) are handed, ``int32[3 + S]``, one array for one read-back: the
+    distinct rows; those of them in the shards' heads, which the dense
+    adds take; for each shard the slots its walks are handed, the pads of
+    a last chunk included; and the rounds past the first that the reads
+    take (:func:`take_rows`; 0 says one round of :func:`gather_cap` slots
+    a shard held every plan's busiest shard, and on one shard there are
+    no rounds). Every shard reads ``cap`` slots a round whatever it owns,
+    so its slots are the plans and these rounds times ``cap``."""
+    b, shards = plan.run.shape[-1], plan.head.shape[-1]
+    chunk = min(CHUNK, b)
+    begin = _begin(plan.end)
     walks = (plan.end - plan.head + chunk - 1) // chunk * chunk
-    shards = plan.head.shape[-1]
+    rounds = _rounds(plan.end - begin, gather_cap(b, shards))
     return jnp.concatenate([
         jnp.stack([jnp.sum(plan.count), jnp.sum(plan.head - begin)]),
-        jnp.sum(walks.reshape(-1, shards), axis=0)]).astype(jnp.int32)
+        jnp.sum(walks.reshape(-1, shards), axis=0),
+        jnp.sum(rounds - 1)[None]]).astype(jnp.int32)
 
 
 def combine_rows(updates: jax.Array, plan: RowPlan,
@@ -196,6 +282,83 @@ def combine_rows(updates: jax.Array, plan: RowPlan,
     type, the sum is taken in float32."""
     return jax.ops.segment_sum(updates.astype(jnp.float32), plan.run,
                                num_segments=updates.shape[0] + pad)
+
+
+def take_rows(table: jax.Array, ids: jax.Array,
+              plan: Optional[RowPlan] = None, sharding=None,
+              dtype=None) -> jax.Array:
+    """``jnp.take(table, ids, axis=0).astype(dtype)`` for ids ``[B]``, and
+    on a whole table (``sharding`` names no row axis, or one of one
+    shard) that very program.
+    On ``S`` row shards every shard reads the rows it owns and no others,
+    and the shards hand them round, in one ``shard_map``: a shard's
+    distinct rows are one range of the plan's ``uniq``; it reads
+    :func:`gather_cap` slots of it from its own rows (slots past its range
+    read a row it clips to, which nobody looks at), casts them, and an
+    all-gather gives every shard every shard's ``[S * cap, D]``; one
+    gather from that by ``plan.place`` lays the rows out in the ids' own
+    order, duplicates and all. A minibatch whose busiest shard owns more
+    than ``cap`` distinct rows takes more rounds: every shard reckons
+    their number alike from the replicated plan, so the all-gathers stay
+    in step. Every row is the table's own bits (cast), whatever the ids.
+
+    Left to the partitioner, ``jnp.take`` on a row-sharded table is a
+    gather of all ``B`` slots on every shard, zeros for rows it does not
+    own, and an all-reduce of ``S`` copies of which ``S - 1`` are zeros
+    (PERF.md, PR 38)."""
+    axis, shards = row_shards(sharding)
+    dtype = dtype or table.dtype
+    if shards == 1:
+        return jnp.take(table, ids, axis=0).astype(dtype)
+    if plan is None:
+        plan = plan_rows(ids, table.shape[0], shards)
+    cap = gather_cap(ids.shape[0], shards)
+    begin = _begin(plan.end)
+    # a last round may run past the last slot: pad slots, as far away
+    uniq = jnp.concatenate(
+        [plan.uniq, jnp.full(cap, _NONE, jnp.int32)])
+
+    def local(tab, uniq, begin, place, rounds):
+        shard = lax.axis_index(axis)
+        start, first_row = begin[shard], shard * tab.shape[0]
+
+        def round_(r, out=None):
+            """The update rows whose place lies in round ``r``, over
+            ``out``; the first round (no ``out``) lays all ``[B, D]``
+            out, and later rounds overwrite what it clipped."""
+            mine = lax.dynamic_slice(uniq, (start + r * cap,), (cap,))
+            rows = jnp.take(tab, mine - first_row, axis=0, mode="clip")
+            if out is not None:
+                # times one, which the compiler cannot know: else it casts
+                # before it reads, and so casts the WHOLE shard ahead of
+                # the loop of later rounds, every minibatch (2.3 GB of
+                # temporaries at 3,000,001 x 300 on a v5e; an
+                # optimization_barrier does not stop it)
+                rows = rows * jnp.minimum(r, 1).astype(rows.dtype)
+            handed = lax.all_gather(rows.astype(dtype), axis, tiled=True)
+            at = place - r * shards * cap
+            got = jnp.take(handed, at, axis=0, mode="clip")
+            if out is None:
+                return got
+            return jnp.where(((at >= 0) & (at < shards * cap))[:, None],
+                             got, out)
+
+        return lax.while_loop(
+            lambda c: c[0] < rounds, lambda c: (c[0] + 1, round_(*c)),
+            (jnp.int32(1), round_(0)))[1]
+
+    # every shard ends with the same rows, which the checker cannot know:
+    # to it what lax.all_gather hands out differs by shard. The collective
+    # that says otherwise is private in jax 0.9.0
+    # (jax._src.lax.parallel.all_gather_invariant): once jax.lax exports
+    # it, use it above and drop check_vma=False, which turns the check off
+    # for the whole body
+    return jax.shard_map(
+        local, mesh=sharding.mesh,
+        in_specs=(PartitionSpec(axis, None),) + (PartitionSpec(),) * 4,
+        out_specs=PartitionSpec(), check_vma=False)(
+            table, uniq, begin, plan.place,
+            _rounds(plan.end - begin, cap))
 
 
 def add_rows(table: jax.Array, ids: jax.Array, updates: jax.Array,

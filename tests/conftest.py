@@ -74,6 +74,46 @@ def two_ranks(request, tmp_path):
         c.close()
 
 
+@pytest.fixture(params=[False, True], ids=["default", "as_written"])
+def same_floats(request, monkeypatch):
+    """The comparison ``same(got, want)`` for a test that holds two fused
+    epoch PROGRAMS to each other (one row shard against four; rows read by
+    their owners against the partitioner's gather; placed tables against
+    copied ones), once for each way of compiling them: as XLA:CPU does by
+    default, where they agree to last bits, and with LLVM at level 0,
+    every float operation done as it is written, where they agree bit for
+    bit. Before ISSUE 38 the first did too. The one reason it does not: a
+    pair's score is ``sum(v * up)`` over a row, and where the rows'
+    gathers fuse into that loop (one shard; the partitioner's masked rows)
+    the default compile contracts multiply and add into one rounding,
+    where rows that ``row_combine.take_rows`` has laid out in memory take
+    the two roundings that are written, which are level 0's.
+
+    The bound of the default compile, read over every test that takes
+    this fixture: tables of values up to 3.2 apart by 1.0e-6 at the most
+    (``test_table_layout``, eight shards, 16 epochs; 4.8e-7 in
+    ``test_sharded_we``), the losses by less. A row read wrong moves a
+    value by the size of an update, 1e-3 and more. So: 16 times the
+    largest reading."""
+    import numpy as np
+    if request.param:
+        from multiverso_tpu.models import word2vec as w2v
+        epoch_jit = w2v._epoch_jit
+        monkeypatch.setattr(w2v, "_epoch_jit", lambda *a, **kw: epoch_jit(
+            *a, **kw,
+            compiler_options={"xla_backend_optimization_level": 0}))
+
+    def same(got, want) -> None:
+        got, want = (np.asarray(a, np.float32) for a in (got, want))
+        if request.param:
+            np.testing.assert_array_equal(got.view(np.uint32),
+                                          want.view(np.uint32))
+        else:
+            np.testing.assert_allclose(got, want, rtol=0, atol=1.6e-5)
+
+    return same
+
+
 @pytest.fixture(autouse=True)
 def _fresh_runtime():
     """Reset flags + Zoo between tests (the reference restarts processes)."""

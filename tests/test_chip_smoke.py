@@ -36,6 +36,20 @@ def test_we_stage():
     assert facts["fused_compute_dtype"] == "float32"   # bf16 is TPU-only
 
 
+def test_rows_stage():
+    facts = chip_smoke.stage_rows(rows_per_shard=101, width=8, batch=64,
+                                  calls=3)
+    assert (facts["shards"], facts["cap"]) == (8, 6)
+    assert facts["table"] == "f32[808,8]"
+    assert facts["even_rounds_past_first"] >= 0
+    # 64 ids of one shard, 6 slots a round: up to eleven rounds a call
+    assert 3 < facts["last_shard_rounds_past_first"] <= 3 * 10
+    # 64 ids without a duplicate over 8 shards: 8 or more in the busiest
+    assert 3 <= facts["distinct_rounds_past_first"]
+    assert facts["take_rows_temp_mb"] < 1 > facts["partitioner_temp_mb"]
+    assert facts["even_take_rows_ms"] > 0 < facts["even_partitioner_ms"]
+
+
 def test_ps_stage():
     facts = chip_smoke.stage_ps(rows=1000, cols=16, batch=256, chip=False)
     for updater in ("adagrad", "default"):

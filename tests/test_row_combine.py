@@ -5,7 +5,9 @@ does what a plain sequence of steps with raw scatter-adds does. ISSUE 31:
 the rows below ``HEAD`` take one dense add and the walk only the others,
 wherever ``HEAD`` falls among the ids. ISSUE 36: on row shards the head is
 the first rows of every shard, a shard walks its own rows alone, and words
-are dealt round the shards."""
+are dealt round the shards. ISSUE 38: a row-sharded table is read by the
+shards that own the rows (``take_rows``), which is ``jnp.take`` bit for
+bit whatever the ids."""
 
 import jax
 import jax.numpy as jnp
@@ -94,8 +96,14 @@ def test_plan_rows_names_every_distinct_row_once(kind, head, shards,
     part = min(head // shards, per)         # head rows a shard
     plan = jax.jit(lambda ids: row_combine.plan_rows(ids, rows, shards))(
         jnp.asarray(ids))
-    run, uniq, count, heads, ends, head_run = (np.asarray(a) for a in plan)
+    run, uniq, count, heads, ends, head_run, place = (np.asarray(a)
+                                                      for a in plan)
     assert run.shape == uniq.shape == ids.shape
+    # ISSUE 38: where take_rows finds each update row among the rows the
+    # shards hand round; one shard hands nothing round
+    cap = row_combine.gather_cap(96, shards)
+    assert place.shape == ((5, 96) if shards > 1 else (5, 0))
+    assert cap == (3 * 96 // (4 * shards) if shards > 1 else 96)
     assert count.shape == (5,) and heads.shape == ends.shape == (5, shards)
     assert head_run.shape == (5, shards * part)
     for k in range(5):
@@ -120,6 +128,16 @@ def test_plan_rows_names_every_distinct_row_once(kind, head, shards,
             np.testing.assert_array_equal(uniq[k, heads[k, s]:ends[k, s]],
                                           mine[~in_head])
             named += [mine[in_head], uniq[k, heads[k, s]:ends[k, s]]]
+            if shards > 1:
+                # its rows in order, cap of them a round, after the
+                # shards before it in the round
+                begin = ends[k, s - 1] if s else 0
+                np.testing.assert_array_equal(uniq[k, begin:ends[k, s]], mine)
+                at = np.arange(mine.size)
+                np.testing.assert_array_equal(
+                    place[k][np.isin(ids[k], mine)],
+                    ((at // cap * shards + s) * cap + at % cap)[
+                        np.searchsorted(mine, ids[k][np.isin(ids[k], mine)])])
         # every distinct row once, by its owner's head or its owner's walk
         np.testing.assert_array_equal(np.sort(np.concatenate(named)),
                                       distinct)
@@ -129,12 +147,22 @@ def test_plan_rows_names_every_distinct_row_once(kind, head, shards,
     # and what the writes are handed: distinct rows, the heads' share, and
     # every shard's walk in whole chunks (of 96 slots here: B < CHUNK)
     counts = np.asarray(row_combine.plan_counts(plan))
-    assert counts.shape == (2 + shards,)
+    assert counts.shape == (3 + shards,)
     tails = ends - heads
     assert counts[0] == count.sum()
     assert counts[1] == count.sum() - tails.sum()
-    np.testing.assert_array_equal(counts[2:],
+    np.testing.assert_array_equal(counts[2:2 + shards],
                                   (-(-tails // 96) * 96).sum(axis=0))
+    # and the reads (ISSUE 38): every shard cap slots a round, as many
+    # rounds as the busiest shard's distinct rows need, one at the least;
+    # counted are those past the first
+    owned = np.diff(np.concatenate([np.zeros((5, 1), int), ends], axis=1))
+    rounds = np.maximum(-(-owned.max(axis=1) // cap), 1)
+    assert counts[-1] == (rounds - 1).sum()
+    if kind == "one_row":
+        assert (rounds == 1).all()
+    if kind == "distinct" and shards > 1:
+        assert (rounds > 1).any()   # 96 distinct rows are never dealt even
 
 
 @pytest.mark.parametrize("shards", [1, 2, 4, 8])
@@ -187,6 +215,89 @@ def test_add_rows_on_row_shards_is_the_scatter_add(kind, head, shards,
     np.add.at(want, ids, updates)
     assert np.abs(np.asarray(got) - want).max() <= 1e-6 * (
         np.abs(updates).max() * np.bincount(ids).max())
+
+
+def _row_sharding(shards: int):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    return NamedSharding(Mesh(np.asarray(jax.devices()[:shards]), ("mv",)),
+                         PartitionSpec("mv", None))
+
+
+def _take_ids(kind: str, b: int, rows: int, shards: int, rng) -> np.ndarray:
+    """Ids for ``take_rows`` on ``shards`` row shards of ``rows // shards``
+    rows: dealt evenly; a few rows many times; all in the last shard (more
+    distinct rows than a round holds); all in the shards' heads; and the
+    table's last row among them."""
+    per = rows // shards
+    if kind == "even":
+        return rng.integers(0, rows, b).astype(np.int32)
+    if kind == "duplicates":
+        return rng.choice(rng.integers(0, rows, 5), b).astype(np.int32)
+    if kind == "one_shard":
+        return (rows - 1 - rng.choice(per, b, replace=False)).astype(np.int32)
+    if kind == "head":
+        return (rng.integers(0, shards, b) * per
+                + rng.integers(0, 4, b)).astype(np.int32)
+    ids = rng.integers(0, rows, b).astype(np.int32)
+    ids[::7] = rows - 1
+    return ids
+
+
+@pytest.mark.parametrize("made_ahead", [True, False])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("shards", [1, 2, 4])
+@pytest.mark.parametrize("kind", ["even", "duplicates", "one_shard", "head",
+                                  "last_row"])
+def test_take_rows_is_jnp_take(kind, shards, dtype, made_ahead, monkeypatch):
+    """ISSUE 38: the rows a shard owns, read by it alone and handed round,
+    are ``jnp.take``'s rows bit for bit in the ids' own order, the -0.0
+    rows and the rounds past the first included."""
+    monkeypatch.setattr(row_combine, "HEAD", 16)
+    rng = np.random.default_rng(len(kind) * 10 + shards)
+    rows, b = _padded(ROWS, shards), 96
+    table = np.concatenate(
+        [_table(rng), np.zeros((rows - ROWS, WIDTH), np.float32)])
+    ids = _take_ids(kind, b, rows, shards, rng)
+    sharding = _row_sharding(shards)
+    plan = (jax.jit(lambda i: row_combine.plan_rows(i, rows, shards))(ids)
+            if made_ahead else None)
+    got = jax.jit(lambda t, i, p: row_combine.take_rows(
+        t, i, p, sharding, dtype))(
+            jax.device_put(table, sharding), jnp.asarray(ids), plan)
+    want = jnp.asarray(table[ids]).astype(dtype)
+    assert got.dtype == want.dtype and got.shape == (b, WIDTH)
+    bits = np.uint32 if dtype == jnp.float32 else np.uint16
+    np.testing.assert_array_equal(np.asarray(got).view(bits),
+                                  np.asarray(want).view(bits))
+    assert np.signbit(np.asarray(want, np.float32)).any()
+    # how many rounds the ids took, from the plan's own counts
+    counts = np.asarray(row_combine.plan_counts(
+        row_combine.plan_rows(jnp.asarray(ids), rows, shards)))
+    cap = row_combine.gather_cap(b, shards)
+    rounds = counts[-1] + 1
+    owned = np.bincount(np.unique(ids) // (rows // shards), minlength=shards)
+    assert rounds == max(-(-owned.max() // cap), 1)
+    if kind == "one_shard" and shards > 1:
+        # 96 distinct rows of one shard, three quarters of 96 // S a round
+        assert rounds == -(-96 // cap) > shards
+    if kind in ("duplicates", "head") or shards == 1:
+        assert rounds == 1
+
+
+def test_take_rows_on_one_shard_is_the_program_it_was():
+    """On a whole table ``take_rows`` is ``jnp.take`` and a cast, the
+    lowered text letter for letter: with or without a plan, and whether
+    the table has no sharding or one over a mesh of one device."""
+    table = jnp.zeros((ROWS, WIDTH), jnp.float32)
+    ids = jnp.zeros(96, jnp.int32)
+    plan = row_combine.plan_rows(ids, ROWS)
+    was = jax.jit(lambda t, i: jnp.take(t, i, axis=0).astype(
+        jnp.bfloat16)).lower(table, ids).as_text()
+    for p in (plan, None):
+        for sharding in (None, _row_sharding(1)):
+            now = jax.jit(lambda t, i: row_combine.take_rows(
+                t, i, p, sharding, jnp.bfloat16)).lower(table, ids).as_text()
+            assert now == was
 
 
 def test_combine_rows_sums_bfloat16_runs_in_float32():
@@ -258,4 +369,6 @@ def test_fused_epoch_equals_sequential_raw_scatter_steps(batch, chunk, head,
         sum(d.size for d in distinct),
         sum((d < head).sum() for d in distinct),
         # one shard: the walks' slots, the pads of a last chunk included
-        sum(-(-(d >= head).sum() // chunk) * chunk for d in distinct)]
+        sum(-(-(d >= head).sum() // chunk) * chunk for d in distinct),
+        # and the reads' rounds past the first: one shard takes none
+        0]
