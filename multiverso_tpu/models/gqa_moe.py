@@ -3,7 +3,9 @@ full ones, and routed experts alone in every layer, trained through Adam
 tables: the second model on ``models/mla_moe.py``'s one decoder path.
 
 This file says what is this model's own: its configuration, the shapes
-of its attention's parameters, and the attention. The block, the
+of its attention's parameters, and the attention, which
+``models/afmoe.py``'s configuration takes too, under other switches
+(:func:`gqa`, :func:`gqa_shapes`). The block, the
 products, the norms, rotary positions, the expert layer's call, the
 chunked loss, the tables, the step and the ``Trainer`` are
 ``mla_moe``'s, used as they are. The equations, for a block with input
@@ -18,7 +20,12 @@ chunked loss, the tables, the step and the ``Trainer`` are
   and V are never repeated: the flash kernel's index maps know the group.
   Scores over ``sqrt(head_dim)``; position ``i`` sees ``j`` where ``0 <=
   i - j`` and, in a ``window`` layer, ``i - j < window``; float32
-  softmax; ``o W_o``.
+  softmax; ``o W_o``. Three switches of the configuration, all off here:
+  ``qk_norm`` (an RMSNorm over ``head_dim`` on every head of q and of k
+  before the positions, one gain each), ``attn_gate`` (the core's output
+  times ``sigmoid(u W_gate)``, element for element, before ``W_o``) and
+  ``rope_kinds`` (the layer kinds that take rotary positions: a kind left
+  out sees none).
 * Experts: ``parallel/moe.held_expert_layer`` under its softmax route:
   probabilities over all ``n_experts``, the ``top_k`` largest
   renormalised, no bias, no shared expert; this chip computes the part of
@@ -61,17 +68,17 @@ class GQAMoEConfig(NamedTuple):
     attn_block: int = 512
     loss_chunk: int = 4096
     compute_dtype: Any = jnp.bfloat16
+    # :func:`gqa`'s switches, as this model has them
+    rope_kinds: Tuple[str, ...] = ("window", "full")
+    qk_norm: bool = False
+    attn_gate: bool = False
 
     def layers(self) -> Tuple[Layer, ...]:
         return tuple(Layer(f"L{i}", kind, "experts")
                      for i, kind in enumerate(self.layer_kinds))
 
     def attn_shapes(self, kind: str) -> Dict[str, Tuple[int, ...]]:
-        d, hd = self.dim, self.head_dim
-        return {"attn_norm": (d,), "wq": (d, self.n_heads * hd),
-                "wk": (d, self.n_kv_heads * hd),
-                "wv": (d, self.n_kv_heads * hd),
-                "wo": (self.n_heads * hd, d), "ffn_norm": (d,)}
+        return gqa_shapes(self)
 
     def attend(self, u, p, kind: str):
         return gqa(u, p, self, kind)
@@ -92,10 +99,33 @@ class GQAMoEConfig(NamedTuple):
     def routed_scale(self) -> float:     # the gates sum to 1
         return 1.0
 
+    @property
+    def post_norms(self) -> bool:    # a block norms its branches' inputs
+        return False
 
-def gqa(u, p, cfg: GQAMoEConfig, kind: str):
+    @property
+    def embed_scale(self) -> float:
+        return 1.0
+
+
+def gqa_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
+    """The parameters of a block's attention and its two input norms."""
+    d, hd = cfg.dim, cfg.head_dim
+    out = {"attn_norm": (d,), "wq": (d, cfg.n_heads * hd),
+           "wk": (d, cfg.n_kv_heads * hd), "wv": (d, cfg.n_kv_heads * hd),
+           "wo": (cfg.n_heads * hd, d), "ffn_norm": (d,)}
+    if cfg.qk_norm:
+        out.update(q_norm=(hd,), k_norm=(hd,))
+    if cfg.attn_gate:
+        out["wgate"] = (d, cfg.n_heads * hd)
+    return out
+
+
+def gqa(u, p, cfg, kind: str):
     """Grouped-query attention of ``kind`` (``"full"`` or ``"window"``) on
-    the normed input ``u`` [B, S, D] -> [B, S, D] float32."""
+    the normed input ``u`` [B, S, D] -> [B, S, D] float32; ``cfg`` is a
+    :class:`GQAMoEConfig` or another configuration with its attention's
+    fields."""
     b, s, _ = u.shape
     h, hkv, hd, dt = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.compute_dtype
     window = cfg.window if kind == "window" else None
@@ -105,8 +135,13 @@ def gqa(u, p, cfg: GQAMoEConfig, kind: str):
         q = mm(u, p["wq"], False, out_dtype=jnp.float32).reshape(b, s, h, hd)
         k = mm(u, p["wk"], False, out_dtype=jnp.float32).reshape(b, s, hkv, hd)
         v = mm(u, p["wv"], False, out_dtype=dt).reshape(b, s, hkv, hd)
-        q = mla_moe.rotary(q, cfg.rope_theta, yarn)
-        k = mla_moe.rotary(k, cfg.rope_theta, yarn)
+        if cfg.qk_norm:
+            with jax.named_scope("mv.lm.attn.qknorm"):
+                q = mla_moe.rms_norm(q, p["q_norm"], cfg.eps)
+                k = mla_moe.rms_norm(k, p["k_norm"], cfg.eps)
+        if kind in cfg.rope_kinds:
+            q = mla_moe.rotary(q, cfg.rope_theta, yarn)
+            k = mla_moe.rotary(k, cfg.rope_theta, yarn)
         heads = lambda t: t.astype(dt).transpose(0, 2, 1, 3)
         q, k, v = heads(q), heads(k), heads(v)
         with jax.named_scope("mv.lm.attn." + kind):
@@ -117,4 +152,8 @@ def gqa(u, p, cfg: GQAMoEConfig, kind: str):
             else:
                 o = mla_moe._xla_attention(q, k, v, window)
         o = o.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
+        if cfg.attn_gate:
+            with jax.named_scope("mv.lm.attn.gate"):
+                o = o * jax.nn.sigmoid(
+                    mm(u, p["wgate"], False, out_dtype=jnp.float32))
         return mm(o, p["wo"], False, out_dtype=jnp.float32)

@@ -28,10 +28,13 @@ configuration says its layers as data (``cfg.layers()``: a
 its feed-forward kind, ``dense``, ``shared+experts`` or ``experts``
 alone), how its routers score (``cfg.route`` and ``cfg.routed_scale``:
 ``parallel/moe.HeldExperts``'s), the shapes of an attention kind's
-parameters (``cfg.attn_shapes(kind)``) and the attention itself
-(``cfg.attend(u, p, kind)``); :func:`block`, :func:`matmul`, :func:`rms_norm`,
-:func:`rotary`, the chunked loss, the tables, the step and
-:class:`Trainer` below are shared by every such configuration.
+parameters (``cfg.attn_shapes(kind)``), the attention itself
+(``cfg.attend(u, p, kind)``), where a block's norms stand
+(``cfg.post_norms``: before each branch alone, or on its way out as
+well, as ``models/afmoe.py`` has them) and what the embedding is
+multiplied by (``cfg.embed_scale``); :func:`block`, :func:`matmul`,
+:func:`rms_norm`, :func:`rotary`, the chunked loss, the tables, the step
+and :class:`Trainer` below are shared by every such configuration.
 
 Every trained parameter lies in a ``Table`` (:func:`make_tables`);
 :func:`make_train_step` is to this model what
@@ -130,6 +133,14 @@ class MLAMoEConfig(NamedTuple):
     def balance_coef(self) -> float:     # no load-balance term in the loss
         return 0.0
 
+    @property
+    def post_norms(self) -> bool:    # a block norms its branches' inputs
+        return False
+
+    @property
+    def embed_scale(self) -> float:
+        return 1.0
+
 
 # The held experts' buffer, in rows, for a layer's ``tokens``: twice what
 # an even router sends here, and never under the floor (the loads of a few
@@ -198,6 +209,8 @@ def param_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
     for layer in cfg.layers():
         block = dict(cfg.attn_shapes(layer.attn),
                      **_ffn_shapes(cfg, layer.ffn))
+        if cfg.post_norms:
+            block.update(attn_post_norm=(d,), ffn_post_norm=(d,))
         if layer.name == "mtp":
             block.update(enorm=(d,), hnorm=(d,), eh_proj=(2 * d, d),
                          out_norm=(d,))
@@ -402,10 +415,11 @@ def attn_blocks(cfg, s: int) -> Tuple[int, int]:
 def attn_grid(cfg, s: int) -> Dict[str, Any]:
     """What one flash kernel call over ``s`` positions does a (batch x
     head), as ``lm.step`` spans carry it: the layers' attention kinds,
-    the query heads a key-value head, the causal walk's counts and, where
-    some layer is of the window kind, the band's beside what a causal
-    walk of the band's blocks would visit; nothing where XLA is the
-    core."""
+    the query heads a key-value head, a block's norms and the embedding's
+    multiplier, the causal walk's counts and, where some layer is of the
+    window kind, the band's beside what a causal walk of the band's blocks
+    would visit and what the grouped-query attention does around its core
+    (``gqa_moe.gqa``'s switches); nothing where XLA is the core."""
     if attn_core(cfg) != "flash":
         return {}
     kinds = [layer.attn for layer in cfg.layers()]
@@ -413,12 +427,16 @@ def attn_grid(cfg, s: int) -> Dict[str, Any]:
     n = causal_pairs(s, *blocks)
     out = {"attn_grid_steps": n["grid_steps"], "attn_pairs_live": n["live"],
            "attn_pairs_masked": n["masked"], "attn_kinds": ",".join(kinds),
-           "kv_group": cfg.kv_group}
+           "kv_group": cfg.kv_group,
+           "block_norms": 4 if cfg.post_norms else 2,
+           "embed_scale": float(cfg.embed_scale)}
     if "window" in kinds:
         band = causal_pairs(s, *blocks, cfg.window)
         out.update(attn_pairs_live_window=band["live"],
                    attn_pairs_masked_window=band["masked"],
-                   attn_pairs_causal_window=n["live"])
+                   attn_pairs_causal_window=n["live"],
+                   attn_gated=int(cfg.attn_gate), qk_norm=int(cfg.qk_norm),
+                   rope_kinds=",".join(cfg.rope_kinds))
     return out
 
 
@@ -511,11 +529,19 @@ def expert_ffn(u, p, bias, cfg, shared: bool = True):
 
 def block(x, p, attn, ffn, cfg):
     """The one block: ``attn`` and ``ffn`` take the normed input and the
-    block's parameters. Every layer of every kind calls it. Returns (y,
-    ffn's aux)."""
-    h = x + attn(rms_norm(x, p["attn_norm"], cfg.eps), p)
+    block's parameters; with ``cfg.post_norms`` each branch's output is
+    normed as well before it joins the stream (four norms a block). Every
+    layer of every kind calls it. Returns (y, ffn's aux)."""
+    def out(branch, name):
+        if not cfg.post_norms:
+            return branch
+        with jax.named_scope("mv.lm.norm.post"):
+            return rms_norm(branch, p[name], cfg.eps)
+
+    h = x + out(attn(rms_norm(x, p["attn_norm"], cfg.eps), p),
+                "attn_post_norm")
     f, aux = ffn(rms_norm(h, p["ffn_norm"], cfg.eps), p)
-    return h + f, aux
+    return h + out(f, "ffn_post_norm"), aux
 
 
 def _sub(params: Dict[str, Any], prefix: str) -> Dict[str, Any]:
@@ -561,13 +587,18 @@ def _chunked_ce(h, head, targets, weights, cfg):
     return jax.lax.scan(body, jnp.zeros((), jnp.float32), xs)[0]
 
 
+def _embed(params, tokens, cfg):
+    x = jnp.take(params["embed"], tokens, axis=0)
+    return x if cfg.embed_scale == 1.0 else x * cfg.embed_scale
+
+
 def _trunk(params, bias, tokens, cfg, still: bool = False):
     """Embedding and every layer but the prediction module: (x, [each
     expert layer's aux]). ``still``: each block's input is held fixed (no
     gradient flows from a layer into the one before it, so nothing is
     rematerialised either)."""
     with jax.named_scope("mv.lm.embed"):
-        x = jnp.take(params["embed"], tokens, axis=0)
+        x = _embed(params, tokens, cfg)
     rows = {name: row for row, name in enumerate(expert_layers(cfg))}
     aux = []
     for layer in cfg.layers():
@@ -612,8 +643,7 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
         with jax.named_scope("mv.lm.mtp"):
             p = _sub(params, "mtp")
             joined = jnp.concatenate(
-                [rms_norm(jnp.take(params["embed"], nxt, axis=0),
-                          p["enorm"], cfg.eps),
+                [rms_norm(_embed(params, nxt, cfg), p["enorm"], cfg.eps),
                  rms_norm(x, p["hnorm"], cfg.eps)], -1)
             y = matmul(joined, p["eh_proj"], False, cfg.compute_dtype,
                        jnp.float32)

@@ -643,3 +643,58 @@ def test_softmax_expert_layer_compiles_for_a_v5e_at_published_widths(
         shape(16384, 2304), shape(64, 2304), shape(16, 2304, 896),
         shape(16, 2304, 896), shape(16, 896, 2304)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 9
+
+
+@pytest.mark.parametrize("window", [2048, None])
+def test_gated_attention_compiles_for_a_v5e_at_16k_positions(
+        one_chip, window, monkeypatch):
+    """``models/gqa_moe.gqa`` under ``models/afmoe.py``'s switches as
+    ``trinity-train-16k`` calls it: 32 query heads of 128 over 4 key-value
+    heads, ONE sequence of 16,384 positions at 1,024 x 1,024 blocks, a
+    window of two k blocks (a band three blocks wide) or none, with the q/k
+    norms and the gate in XLA around the kernels, forward and backward."""
+    from multiverso_tpu.models import afmoe, mla_moe
+    from multiverso_tpu.ops import attention_kernels
+
+    # the process's devices are the CPU's: the kernels would be interpreted
+    monkeypatch.setattr(attention_kernels, "_resolve_interpret",
+                        lambda interpret: False)
+    cfg = afmoe.AFMoEConfig(dim=2048, n_heads=32, n_kv_heads=4, head_dim=128,
+                            window=2048, attn="flash")
+    assert mla_moe.attn_blocks(cfg, 16384) == (1024, 1024)
+    kind = "full" if window is None else "window"
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {n: f32(*s) for n, s in cfg.attn_shapes(kind).items()}
+
+    def attend(u, p):
+        return cfg.attend(u, p, kind).sum()
+
+    text = jax.jit(jax.grad(attend, argnums=(0, 1))).lower(
+        f32(1, 16384, 2048), p).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3       # forward, dQ, dK with dV
+    assert "bf16[4,16384,128]" in text and "bf16[32,16384,128]" in text
+
+
+def test_sigmoid_expert_layer_over_128_compiles_for_a_v5e(one_chip):
+    """The held experts' grouped products as ``trinity-train-16k`` calls
+    them: a 32,768-row buffer in sixteen groups of 2,048 x 1,024 at GLM's
+    tile, under the sigmoid route over 128 outputs with 8 a token."""
+    from multiverso_tpu.parallel import moe
+
+    tile = moe.product_tile(2048, 1024)
+    assert tile == (512, 512, 512)
+    held = moe.HeldExperts(num_experts=128, experts_held=16, top_k=8,
+                           routed_scale=2.826, buffer_rows=32768, tile=tile)
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+
+    def experts(u, router, wg, wu, wd):
+        out, counts, overflow, _ = moe.held_expert_layer(
+            u, {"router": router, "w_gate": wg, "w_up": wu, "w_down": wd},
+            jnp.zeros((128,)), held, kernel="pallas")
+        return out.sum(), (counts, overflow)
+
+    compiled = jax.jit(jax.grad(experts, argnums=(0, 1, 2, 3, 4),
+                                has_aux=True)).lower(
+        shape(16384, 2048), shape(128, 2048), shape(16, 2048, 1024),
+        shape(16, 2048, 1024), shape(16, 1024, 2048)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 9
