@@ -10,7 +10,8 @@ rule, a falling finite loss, ``jnp.take``,
   tables  sync plane: MatrixTable row adds/gets, ArrayTable add/get
   we      WordEmbedding: fused trainer, then the PS-block trainer
   rows    a row-sharded table read by its owners (ops/row_combine.take_rows)
-          beside the partitioner's masked gather and all-reduce
+          beside the partitioner's masked gather and all-reduce; a PS
+          block's table writes, raw and through row_combine.add_rows
   ps      uncoordinated plane: a two-rank world with device-backed shards
   lm      the 472M transformer step with the Pallas flash kernel
 
@@ -254,7 +255,8 @@ def stage_we(fused_tokens: int = 400_000, fused_vocab: int = 10_000,
 
 
 def stage_rows(rows_per_shard: int = 3_000_001, width: int = 300,
-               batch: int = 8192, calls: int = 32) -> Dict[str, Any]:
+               batch: int = 8192, calls: int = 32,
+               block: Dict[str, int] = None) -> Dict[str, Any]:
     """The sharded table path's read (``ops/row_combine.take_rows``) on a
     table row-sharded over every device, at ``we-fused-x4``'s shapes: the
     rows a minibatch's ids name, read by the shards that own them and
@@ -269,7 +271,7 @@ def stage_rows(rows_per_shard: int = 3_000_001, width: int = 300,
     temporaries are held to the partitioner's: left alone, the v5e's
     compiler casts the whole shard ahead of the later rounds' loop, half
     a shard of temporaries (PERF.md, PR 38). On one device the two are
-    one program."""
+    one program. Then :func:`block_writes` (``block``: its sizes)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental.layout import Format
@@ -356,6 +358,131 @@ def stage_rows(rows_per_shard: int = 3_000_001, width: int = 300,
         raise AssertionError(f"ids of one shard, or ids without a "
                              f"duplicate, took one round: {out}")
     table.delete()
+    out["block"] = block_writes(width=width, batch=batch, **(block or {}))
+    return out
+
+
+def block_writes(bucket: int = 2 ** 19, width: int = 300, batch: int = 8192,
+                 negative: int = 5, minibatches: int = 16,
+                 vocab: int = 1_800_000) -> Dict[str, Any]:
+    """What a PS block's scan pays for its table writes
+    (``models/word2vec.skipgram_ns_step`` under ``we-psblock``, PERF.md
+    PR 40), into the block's local table ``f32[bucket + 1, width]`` on one
+    device: ``minibatches`` minibatches in one program, ms a minibatch by
+    this process's clock around a program it waits for, and the seconds
+    the compiler took (cold only where the persistent cache had no entry).
+    Ids as a block has them: words are ranks of a Zipf(1.1) law, a pair's
+    negatives follow its 0.75 power, and a word's local row is its rank
+    among the block's words. Three writes of the output table's
+    ``(negative + 1) * batch`` update rows: the raw duplicate scatter,
+    and held to it ONE ``add_rows`` of them all and ``add_rows`` a column
+    of ``batch`` at a time, which is what the step does; the input
+    table's ``batch`` beside them; the plans and the sums
+    (``combine_rows``) alone, so that what is left is the head's add and
+    the walk."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import word2vec as w2v
+    from multiverso_tpu.ops import row_combine
+
+    rng = np.random.default_rng(SEED + 2)
+    rows, cols = bucket + 1, negative + 1
+    law = np.arange(1, vocab + 1, dtype=np.float64) ** -1.1
+    draw = lambda p, shape: np.searchsorted(           # noqa: E731
+        np.cumsum(p / p.sum()), rng.random(shape))
+    words = [draw(law, (minibatches, batch)) for _ in range(2)] + [
+        draw(law ** 0.75, (minibatches, batch, negative))]
+    _, local = np.unique(np.concatenate([w.reshape(-1) for w in words]),
+                         return_inverse=True)
+    # the rarest of a block of more words than the bucket share rows
+    local = (local % bucket).astype(np.int32)
+    cut = np.cumsum([w.size for w in words])[:-1]
+    c, x, g = (jnp.asarray(a.reshape(w.shape)) for a, w in zip(
+        np.split(local, cut), words))
+    flat = jnp.concatenate([x[..., None], g], -1).reshape(minibatches, -1)
+    by_col = w2v.target_columns(x, g)              # [minibatches, cols, B]
+    table = jax.random.uniform(jax.random.key(SEED), (rows, width),
+                               jnp.float32, -0.5, 0.5)
+    v = jax.random.uniform(jax.random.key(SEED + 1),
+                           (minibatches, batch, width), jnp.float32, -.5, .5)
+    grad = jax.random.uniform(jax.random.key(SEED + 2),
+                              (minibatches, batch, cols), jnp.float32,
+                              -0.01, 0.01)
+    out: Dict[str, Any] = {
+        "table": f"f32[{rows},{width}]", "minibatches": minibatches,
+        "update_rows": [batch, cols * batch]}
+
+    def timed(name, fn, *args, donate=False):
+        """Compile ``fn``, run it three times on a fresh copy of what it
+        donates; the last result."""
+        t0 = time.perf_counter()
+        compiled = jax.jit(fn, donate_argnums=(0,) if donate else ()).lower(
+            *args).compile()
+        out[f"{name}_compile_s"] = round(time.perf_counter() - t0, 2)
+        best, got = float("inf"), None
+        for _ in range(3):
+            first = jnp.copy(args[0]) if donate else args[0]
+            jax.block_until_ready(first)
+            t0 = time.perf_counter()
+            got = jax.block_until_ready(compiled(first, *args[1:]))
+            best = min(best, time.perf_counter() - t0)
+        out[f"{name}_ms"] = round(best / minibatches * 1e3, 4)
+        return got
+
+    def du(gm, vm):                     # [B, cols, width], as the step's
+        return gm[..., None] * vm[:, None, :]
+
+    def scanned(write):
+        return lambda tab, *xs: jax.lax.scan(
+            lambda tb, x: (write(tb, *x), None), tab, xs)[0]
+
+    def by_column(tb, i, gm, vm, p):    # as skipgram_ns_step writes them
+        return jax.lax.scan(
+            lambda t, col: (row_combine.add_rows(t, *col), None), tb,
+            (i, jnp.moveaxis(du(gm, vm), 1, 0), p))[0]
+
+    plan = lambda i: row_combine.plan_rows(i, rows)      # noqa: E731
+    plans = {"centers": timed("plan_centers", plan, c),
+             "one_write": timed("plan_one_write", plan, flat),
+             "columns": timed("plan_columns", plan, by_col)}
+    for name, p in plans.items():
+        unique, head, walk, _ = np.asarray(
+            row_combine.plan_counts(p)).tolist()
+        out[f"{name}_rows"] = {"unique": unique, "head": head, "walk": walk}
+    flat_du = lambda gm, vm: du(gm, vm).reshape(-1, width)   # noqa: E731
+    got = {
+        "raw": timed("raw", scanned(
+            lambda tb, i, gm, vm: tb.at[i].add(flat_du(gm, vm))),
+            table, flat, grad, v, donate=True),
+        "one_write": timed("one_write", scanned(
+            lambda tb, i, gm, vm, p: row_combine.add_rows(
+                tb, i, flat_du(gm, vm), p)),
+            table, flat, grad, v, plans["one_write"], donate=True),
+        "columns": timed("columns", scanned(by_column), table, by_col,
+                         grad, v, plans["columns"], donate=True)}
+    want = np.asarray(got.pop("raw"))
+    if not np.abs(want - np.asarray(table)).max() > 0:
+        raise AssertionError("the raw scatter wrote nothing")
+    for name, tab in got.items():
+        out[f"{name}_max_abs_err"] = _close(np.asarray(tab), want, name)
+    timed("raw_centers", scanned(lambda tb, i, vm: tb.at[i].add(vm)),
+          table, c, v, donate=True)
+    timed("centers", scanned(row_combine.add_rows), table, c, v,
+          plans["centers"], donate=True)
+
+    def sums(updates):
+        """``combine_rows`` of every minibatch alone: a little of each
+        buffer is kept so that none of it is dead code."""
+        def body(acc, xs):
+            s = row_combine.combine_rows(updates(*xs[:-1]), xs[-1],
+                                         min(row_combine.CHUNK, batch))
+            return acc + s[:8] + s[-8:], None
+        return lambda *xs: jax.lax.scan(
+            body, jnp.zeros((8, width), jnp.float32), xs)[0]
+
+    timed("sums_one_write", sums(flat_du), grad, v, plans["one_write"])
+    timed("sums_centers", sums(lambda vm: vm), v, plans["centers"])
     return out
 
 

@@ -670,6 +670,9 @@ class WordEmbedding:
         nw, wid = self._ps_topology()
         t0, losses, words = time.perf_counter(), [], 0
         dev_losses: List[jax.Array] = []
+        # what each block's table writes were handed (_block_ahead's
+        # counts), where its mode combines them
+        row_counts = []
         blocks = [ids[lo: lo + cfg.data_block_size]
                   for lo in range(0, ids.size, cfg.data_block_size)]
         blocks = [b for b in blocks if b.size >= 2]
@@ -719,8 +722,9 @@ class WordEmbedding:
                                      queue_depth=q.ready()):
                         prepared = q.next()
                     if prepared is not None:
-                        dev_losses.append(self._train_block_device(
-                            prepared, i))
+                        loss, counts = self._train_block_device(prepared, i)
+                        dev_losses.append(loss)
+                        row_counts.append(counts)
                     words += block.size
         elif schedule and cfg.pipeline and len(schedule) > 1:
             # ISSUE-11 pipelined host plane: producers run the CPU-heavy
@@ -747,7 +751,9 @@ class WordEmbedding:
                             produced = q.next()   # io_wait-timed
                             with _prof.phase("we.pipeline"):
                                 nxt = self._dispatch_pulls(produced)
-                        losses.append(self._train_prepared(prepared, nw))
+                        loss, counts = self._train_prepared(prepared, nw)
+                        losses.append(loss)
+                        row_counts.append(counts)
                     words += block.size
                     prepared = nxt
         else:
@@ -763,7 +769,9 @@ class WordEmbedding:
                     nxt = (self._prepare_block(schedule[i + 1],
                                                child_rngs[i + 1])
                            if i + 1 < len(schedule) else None)
-                    losses.append(self._train_prepared(prepared, nw))
+                    loss, counts = self._train_prepared(prepared, nw)
+                    losses.append(loss)
+                    row_counts.append(counts)
                 words += block.size
                 prepared = nxt
         call.set(blocks=len(schedule), words=words)
@@ -774,6 +782,12 @@ class WordEmbedding:
                 # so the trained state is durable when the clock stops
                 losses = [float(x)
                           for x in np.asarray(jnp.stack(dev_losses))]
+            row_counts = [np.asarray(c, np.int64) for c in row_counts
+                          if c is not None]
+            if row_counts:
+                update, unique, head, walk, _ = np.sum(row_counts, axis=0)
+                call.set(update_rows=int(update), unique_rows=int(unique),
+                         head_rows=int(head), walk_slots=int(walk))
             # drain in-flight async pushes so the trained state is durable
             # before the caller reads embeddings (sync tables order by
             # program order; async tables need the explicit flush)
@@ -942,17 +956,18 @@ class WordEmbedding:
         with _prof.phase("prepare"):
             return self._produce_block(block, rng, dispatch_early=True)
 
-    def _train_prepared(self, prep: Optional[Dict],
-                        num_workers: int) -> float:
+    def _train_prepared(self, prep: Optional[Dict], num_workers: int
+                        ) -> Tuple[float, Optional[jax.Array]]:
         """Consume the pulls, run the block's packed scan, push the
         (new - old)/workers deltas ASYNC like the reference
         (ref communicator.cpp:144-236 AddAsync) — the push overlaps the
         next block's prep/compute. Ordering is safe: sync tables dispatch
         in program order; on the async plane arrival-order accumulation
-        is the semantics."""
+        is the semantics. Returns the block's loss and its plans' counts
+        (:meth:`_block_ahead`)."""
         cfg = self.cfg
         if prep is None:
-            return 0.0
+            return 0.0, None
         with _trace.span("we.block"):
             # device pad (ops/row_assemble): ONE transfer of the real
             # rows, the zero padding materializes in-graph — the old
@@ -985,7 +1000,7 @@ class WordEmbedding:
                 _devstats.note_transfer(sum(
                     int(np.asarray(a).nbytes)
                     for a in prep["batch"]), "h2d")
-                d_in, d_sec, loss = self._local_train_fn()(
+                d_in, d_sec, loss, counts = self._local_train_fn()(
                     win_l, wsec_l, jnp.asarray(prep["valid"]),
                     jax.device_put(prep["batch"]))
                 # materialize the deltas HERE: np.asarray is the device
@@ -1002,7 +1017,7 @@ class WordEmbedding:
                 ids_sec = prep["hs_rows"] if cfg.hs else prep["rows"]
                 sec_t.add_rows_async(
                     ids_sec, d_sec[:ids_sec.size] / num_workers)
-            return float(loss)
+            return float(loss), counts
 
     # ------------------------------------------------------------------ #
     # PS block path: shared packed-scan compute, two pull/push planes
@@ -1082,20 +1097,65 @@ class WordEmbedding:
         if cfg.hs:
             return lambda a, s, c, cd, p, pm: w2v.skipgram_hs_step(
                 a, s, c, cd, p, pm, alpha)
-        return lambda a, s, c, x, g: w2v.skipgram_ns_step(
-            a, s, c, x, g, alpha)
+        return lambda a, s, c, x, g, plans=(None, None): (
+            w2v.skipgram_ns_step(a, s, c, x, g, alpha, plans=plans))
 
     def _compute_dtype(self):
         return jnp.bfloat16 if self.cfg.ps_block_dtype == "bf16" else None
 
+    def _block_ahead(self, batch, valid, rows: int, remap=None,
+                     neg_seed=None, neg_table=None):
+        """What a block's scan is handed that is made before it, traced
+        ahead of the scan in both planes: ``(batch, plans, counts)``.
+
+        Where the negatives are re-derived in the graph (the device
+        plane's ``_dev_negs``: ``remap`` is the block's word -> local row
+        map) the whole block's are drawn here, with the counters the host
+        drew them with when it built the pull set, so only the 4-byte
+        seed crossed the wire; a padded minibatch's counters were not in
+        the host's pass, so its negatives are the dummy row ``rows - 1``.
+
+        For skip-gram with negative sampling every id of the block is
+        then known, so ``plans`` is the pair :func:`row_combine.plan_rows`
+        of every minibatch's centres and of its targets a column at a time
+        (``w2v.target_columns``) for the ``rows`` local rows (the bucket
+        and the dummy row), off the minibatch's path, and ``counts`` what
+        the table writes are handed, as ``we.fused`` says it: the update
+        rows of the real minibatches' pairs (a pair's centre, its context
+        and its negatives), then :func:`row_combine.plan_counts` of both
+        plans, summed (a padded minibatch adds the one distinct row of
+        each of its writes, the dummy). The other modes keep their raw
+        scatters: ``None``, ``None``."""
+        cfg = self.cfg
+        if remap is not None:
+            nbb, bsz, k = valid.shape[0], cfg.batch_size, cfg.negative
+            slots = w2v.counter_negs(neg_seed, nbb * bsz * k,
+                                     neg_table.shape[0] - 1)
+            negs = jnp.take(remap, jnp.take(neg_table, slots)).astype(
+                jnp.int32).reshape(nbb, bsz, k)
+            batch = batch + (jnp.where(valid[:, None, None] > 0, negs,
+                                       jnp.int32(rows - 1)),)
+        if cfg.cbow or cfg.hs:
+            return batch, None, None
+        c, x, g = (a.astype(jnp.int32) for a in batch)
+        with jax.named_scope("mv.scan.plan"):
+            plans = (row_combine.plan_rows(c, rows),
+                     row_combine.plan_rows(w2v.target_columns(x, g), rows))
+        update_rows = valid.sum().astype(jnp.int32) * (
+            c.shape[-1] * (2 + cfg.negative))
+        return batch, plans, jnp.concatenate([
+            update_rows[None],
+            row_combine.plan_counts(plans[0])
+            + row_combine.plan_counts(plans[1])])
+
     def _run_block_scan(self, step, rows_in, rows_sec, valid, batch,
-                        neg_fn=None):
+                        plans=None):
         """THE block-train scan, traced inside both planes' jits: pulled
-        rows in, (new - old) deltas + mean loss out. ``neg_fn(w, stp)``
-        appends in-graph negatives (device plane's dev-negs mode; batch[0]
-        is then the step-index array). Deltas are measured against the
-        SAME baseline the scan started from — in bf16 mode the rounded
-        rows — so a pulled-but-untrained row gets an exactly-zero delta."""
+        rows in, (new - old) deltas + mean loss out. ``plans`` is
+        :meth:`_block_ahead`'s, a minibatch's slice of which goes to its
+        step. Deltas are measured against the SAME baseline the scan
+        started from — in bf16 mode the rounded rows — so a
+        pulled-but-untrained row gets an exactly-zero delta."""
         cdtype = self._compute_dtype()
 
         def dummy(r):   # padded slots train against this extra row
@@ -1105,18 +1165,15 @@ class WordEmbedding:
 
         def body(carry, xs):
             ri, rs = carry
-            w, arrs = xs[0], xs[1:]
-            if neg_fn is not None:
-                stp, arrs = arrs[0], arrs[1:]
+            w, arrs, plan = xs
             arrs = tuple(a.astype(jnp.int32)
                          if a.dtype == jnp.int16 else a for a in arrs)
-            if neg_fn is not None:
-                arrs = arrs + (neg_fn(w, stp),)
-            ri, rs, loss = step(ri, rs, *arrs)
+            kw = {} if plan is None else {"plans": plan}
+            ri, rs, loss = step(ri, rs, *arrs, **kw)
             return (ri, rs), loss * w
 
         (ri, rs), losses = jax.lax.scan(
-            body, (dummy(rows_in), dummy(rows_sec)), (valid,) + batch)
+            body, (dummy(rows_in), dummy(rows_sec)), (valid, batch, plans))
         loss = losses.sum().astype(jnp.float32) / jnp.maximum(
             valid.sum(), 1.0)
 
@@ -1133,15 +1190,18 @@ class WordEmbedding:
         """Jitted local-train scan for the host plane — the packed
         equivalent of the reference's per-block OMP train loop
         (ref distributed_wordembedding.cpp:178-227), minus the per-
-        minibatch dispatch round-trips."""
+        minibatch dispatch round-trips. Returns the deltas, the loss and
+        :meth:`_block_ahead`'s counts."""
         fn = self._fused_cache.get("ps_local")
         if fn is not None:
             return fn
         step = self._step_fn_raw()
 
         def local_train(ri, rs, v, b):
+            b, plans, counts = self._block_ahead(b, v, ri.shape[0] + 1)
             with jax.named_scope("mv.scan"):    # device-trace name
-                return self._run_block_scan(step, ri, rs, v, b)
+                return self._run_block_scan(step, ri, rs, v, b,
+                                            plans) + (counts,)
 
         fn = self._fused_cache["ps_local"] = jax.jit(local_train)
         return fn
@@ -1196,11 +1256,8 @@ class WordEmbedding:
                            "valid": valid, "batch": batch, "remap": None,
                            "neg_seed": None}
                 if self._dev_negs:
-                    # in-graph negatives need the step index, the
-                    # global->local remap (V small ids), and the block's
-                    # 4-byte draw seed
-                    payload["batch"] = (
-                        np.arange(nbb, dtype=np.uint32),) + batch
+                    # in-graph negatives need the global->local remap (V
+                    # small ids) and the block's 4-byte draw seed
                     payload["remap"] = remap.astype(self._idt(vbb))
                     payload["neg_seed"] = np.uint32(prep["neg_seed"])
             sp.set(rows_touched=int(k), rows_bucket=int(vbb),
@@ -1213,53 +1270,50 @@ class WordEmbedding:
                     jax.sharding.NamedSharding(
                         mv.mesh(), jax.sharding.PartitionSpec())), sp.id
 
+    def _block_ahead_fn(self):
+        """:meth:`_block_ahead` as the device plane's own program,
+        dispatched right before the block's: it returns nothing in a
+        layout of its own, so the persistent compile cache serves it and
+        the sorts behind the plans (1.8 s of the v5e's compiler) stay off
+        every run's set-up, which the block program's compile is on
+        (``utils/platform.compile_result_layouts_in_process``). ``None``
+        where there is nothing to make ahead."""
+        if "ps_ahead" not in self._fused_cache:
+            fn = None
+            if self._dev_negs or not (self.cfg.cbow or self.cfg.hs):
+                fn = jax.jit(
+                    self._block_ahead, static_argnums=(2,),
+                    out_shardings=jax.sharding.NamedSharding(
+                        mv.mesh(), jax.sharding.PartitionSpec()))
+            self._fused_cache["ps_ahead"] = fn
+        return self._fused_cache["ps_ahead"]
+
     def _fused_block_fn(self):
         """One jitted program = the whole reference block cycle: pull
         (device gather of the block's rows), local train (lax.scan over
-        minibatches), push (new - old deltas through the table updater,
-        functional_add_rows). Donates both tables' buffers and hands them
-        back in the tables' own formats — the block chain re-uses device
-        memory like the reference's in-place server shard
+        minibatches, with :meth:`_block_ahead_fn`'s plans), push (new -
+        old deltas through the table updater, functional_add_rows).
+        Donates both tables' buffers and hands them back in the tables'
+        own formats — the block chain re-uses device memory like the
+        reference's in-place server shard
         (ref distributed_wordembedding.cpp:147-252 collapsed into
         XLA)."""
         fn = self._fused_cache.get("ps_block")
         if fn is not None:
             return fn
-        cfg = self.cfg
         t_in, t_sec = self.table_in, self._sec_table()
         step = self._step_fn_raw()
-        dev_negs = self._dev_negs
-        bsz, k = cfg.batch_size, cfg.negative
-        if dev_negs and self._neg_host is None:
-            self._host_negs(1, 1, np.random.default_rng(0))  # build table
-        tbl_mask = (self._neg_host.size - 1) if dev_negs else 0
 
         def fused(din, uin, dsec, usec, ids_in, ids_sec, valid, batch,
-                  remap, neg_seed, neg_table):
+                  plans):
             # mv.pull / mv.scan / mv.push: the names the block's three
             # regions carry in a device trace (metadata only)
             with jax.named_scope("mv.pull"):
                 old_in = jnp.take(din, ids_in, axis=0)
                 old_sec = jnp.take(dsec, ids_sec, axis=0)
-            neg_fn = None
-            if dev_negs:
-                dummy_id = ids_in.shape[0]
-
-                def neg_fn(w, stp):
-                    # same splitmix32 counter stream the host used to
-                    # build the pull set — only the 4-byte seed crossed
-                    # the wire
-                    base = neg_seed + stp * jnp.uint32(bsz * k)
-                    slots = w2v.counter_negs(base, bsz * k, tbl_mask)
-                    ng = jnp.take(neg_table, slots).reshape(bsz, k)
-                    nl = jnp.take(remap, ng).astype(jnp.int32)
-                    # padded steps: their counters weren't in the host's
-                    # vocab pass, so point them at the dummy row
-                    return jnp.where(w > 0, nl, jnp.int32(dummy_id))
-
             with jax.named_scope("mv.scan"):
                 d_in, d_sec, loss = self._run_block_scan(
-                    step, old_in, old_sec, valid, batch, neg_fn)
+                    step, old_in, old_sec, valid, batch, plans)
             with jax.named_scope("mv.push"):
                 # _prepare_block_device: sorted ids, then scratch rows
                 s_in = t_in.functional_add_rows(
@@ -1279,35 +1333,43 @@ class WordEmbedding:
         return fn
 
     def _train_block_device(self, prepared: Tuple[Dict, int],
-                            index: int) -> jax.Array:
-        """Dispatch one fused block program; returns the block loss as a
-        DEVICE scalar (readback deferred to end of run). The dispatch is
-        the ``we.block.dispatch`` span; the block itself ends when the
-        device is done, which the app's watcher records as
-        ``we.block.device`` while a profiler trace or ``trace_ids`` can
-        read it."""
+                            index: int
+                            ) -> Tuple[jax.Array, Optional[jax.Array]]:
+        """Dispatch one block: what is made ahead of its scan, then the
+        fused block program. Returns the block loss as a DEVICE scalar
+        (readback deferred to end of run) and the plans' counts, on their
+        way to the host behind the device's work. The dispatch is the
+        ``we.block.dispatch`` span; the block itself ends when the device
+        is done, which the app's watcher records as ``we.block.device``
+        while a profiler trace or ``trace_ids`` can read it."""
         prep, prepare_span = prepared
         t_in, t_sec = self.table_in, self._sec_table()
-        fn = self._fused_block_fn()
+        fn, ahead = self._fused_block_fn(), self._block_ahead_fn()
         if self._dev_negs and self._neg_dev is None:
             self._neg_dev = jax.device_put(
                 self._neg_host, jax.sharding.NamedSharding(
                     mv.mesh(), jax.sharding.PartitionSpec()))
         t0_ns = time.time_ns()
         with _trace.span("we.block.dispatch", request=index,
-                         cause=prepare_span) as sp, \
-                t_in._dispatch_lock, t_sec._dispatch_lock:
-            si, ss = t_in.program_state(), t_sec.program_state()
-            din, uin, dsec, usec, loss = fn(
-                si["data"], si["ustate"], ss["data"], ss["ustate"],
-                prep["ids_in"], prep["ids_sec"], prep["valid"],
-                prep["batch"], prep.get("remap"), prep.get("neg_seed"),
-                self._neg_dev)
-            t_in.adopt({"data": din, "ustate": uin})
-            t_sec.adopt({"data": dsec, "ustate": usec})
+                         cause=prepare_span) as sp:
+            batch, plans, counts = prep["batch"], None, None
+            if ahead is not None:
+                batch, plans, counts = ahead(
+                    batch, prep["valid"], prep["ids_in"].shape[0] + 1,
+                    prep["remap"], prep["neg_seed"], self._neg_dev)
+            if counts is not None:
+                counts.copy_to_host_async()
+            with t_in._dispatch_lock, t_sec._dispatch_lock:
+                si, ss = t_in.program_state(), t_sec.program_state()
+                din, uin, dsec, usec, loss = fn(
+                    si["data"], si["ustate"], ss["data"], ss["ustate"],
+                    prep["ids_in"], prep["ids_sec"], prep["valid"], batch,
+                    plans)
+                t_in.adopt({"data": din, "ustate": uin})
+                t_sec.adopt({"data": dsec, "ustate": usec})
         self._watcher.watch("we.block.device", loss, t0_ns, request=index,
                             cause=sp.id)
-        return loss
+        return loss, counts
 
     def _ps_topology(self) -> Tuple[int, int]:
         """(num_workers, worker_id) of the PS plane in use: the async

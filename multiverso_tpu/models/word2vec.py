@@ -128,14 +128,29 @@ def _ns_forward_backward(v: jax.Array, u: jax.Array, labels: jax.Array,
 
 def skipgram_ns_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
                      contexts: jax.Array, negatives: jax.Array,
-                     lr: float, scope: str = "mv.scan"
+                     lr: float, scope: str = "mv.scan", *,
+                     plans: Tuple[Optional[row_combine.RowPlan],
+                                  Optional[row_combine.RowPlan]] = (None, None)
                      ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One skipgram negative-sampling minibatch.
 
     centers/contexts: (B,) int32; negatives: (B, K) int32. ``scope``
     prefixes the names its gathers, gradient and scatters carry in a
     device trace (``mv.scan`` in a PS block, ``mv.fused`` in a fused
-    epoch).
+    epoch). Both tables are written with duplicates summed first and every
+    distinct row once (``row_combine.add_rows``): every update is computed
+    at the rows the minibatch started from, so that is the same float32
+    sum in another order. The output table takes its ``(K + 1) * B``
+    update rows a column of ``B`` at a time, the contexts and then each
+    negative (:func:`target_columns`), and not in one write: on a v5e one
+    sum of 49,152 update rows into ``f32[49408,300]`` takes 1.47 ms and
+    its compiler 10.5 s, six sums of 8,192 into ``f32[8448,300]`` 0.84 ms
+    and 1 s, and six heads take more rows off the walks than one. The
+    columns are a loop: six writes in a row run a PS block 0.25% faster
+    and cost its program, which compiles on every run, 1.3 s more of the
+    compiler (PERF.md, PR 40). ``plans`` is ``(plan_rows(centers, rows),
+    plan_rows(target_columns(contexts, negatives), rows))`` where the
+    caller made them ahead of the step; a ``None`` is made in the step.
     """
     b, k = negatives.shape
     with jax.named_scope(scope + ".gather"):
@@ -147,10 +162,22 @@ def skipgram_ns_step(win: jax.Array, wout: jax.Array, centers: jax.Array,
             [jnp.ones((b, 1), v.dtype), jnp.zeros((b, k), v.dtype)], axis=1)
         loss, dv, du = _ns_forward_backward(v, u, labels, lr)
     with jax.named_scope(scope + ".scatter"):
-        win = win.at[centers].add(dv)
-        wout = wout.at[targets.reshape(-1)].add(
-            du.reshape(-1, du.shape[-1]))
+        win = row_combine.add_rows(win, centers, dv, plans[0])
+
+        def column(wout, col):      # (ids [B], updates [B, D], plan or None)
+            return row_combine.add_rows(wout, *col), None
+
+        wout, _ = jax.lax.scan(
+            column, wout, (targets.T, jnp.moveaxis(du, 1, 0), plans[1]))
     return win, wout, loss
+
+
+def target_columns(contexts: jax.Array, negatives: jax.Array) -> jax.Array:
+    """``[..., K + 1, B]``: the ids of the output rows minibatches
+    ``contexts [..., B]``, ``negatives [..., B, K]`` update, in the
+    columns :func:`skipgram_ns_step` writes them in."""
+    return jnp.concatenate(
+        [contexts[..., None, :], jnp.swapaxes(negatives, -1, -2)], axis=-2)
 
 
 def _cbow_mean(win, windows, window_mask):

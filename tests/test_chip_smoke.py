@@ -37,8 +37,9 @@ def test_we_stage():
 
 
 def test_rows_stage():
-    facts = chip_smoke.stage_rows(rows_per_shard=101, width=8, batch=64,
-                                  calls=3)
+    facts = chip_smoke.stage_rows(
+        rows_per_shard=101, width=8, batch=64, calls=3,
+        block=dict(bucket=512, negative=2, minibatches=3, vocab=2000))
     assert (facts["shards"], facts["cap"]) == (8, 6)
     assert facts["table"] == "f32[808,8]"
     assert facts["even_rounds_past_first"] >= 0
@@ -48,6 +49,28 @@ def test_rows_stage():
     assert 3 <= facts["distinct_rounds_past_first"]
     assert facts["take_rows_temp_mb"] < 1 > facts["partitioner_temp_mb"]
     assert facts["even_take_rows_ms"] > 0 < facts["even_partitioner_ms"]
+    # the block's writes ran at the stage's width and batch
+    assert facts["block"]["update_rows"] == [64, 3 * 64]
+
+
+def test_rows_stage_block_writes(monkeypatch):
+    from multiverso_tpu.ops import row_combine
+    monkeypatch.setattr(row_combine, "HEAD", 32)    # leave the walk rows
+    monkeypatch.setattr(row_combine, "CHUNK", 16)
+    facts = chip_smoke.block_writes(bucket=512, width=8, batch=64,
+                                    negative=2, minibatches=3, vocab=2000)
+    assert facts["table"] == "f32[513,8]"
+    one, cols = facts["one_write_rows"], facts["columns_rows"]
+    # a column at a time merges no repeat across columns, and every
+    # column's rows below HEAD are in a head
+    assert 0 < one["unique"] <= cols["unique"] <= 3 * 3 * 64
+    assert 0 < one["head"] <= cols["head"] and one["walk"] > 0
+    assert facts["centers_rows"]["unique"] <= 3 * 64
+    for name in ("raw", "one_write", "columns", "raw_centers", "centers",
+                 "plan_columns", "sums_one_write", "sums_centers"):
+        assert facts[f"{name}_ms"] > 0 <= facts[f"{name}_compile_s"], name
+    assert max(facts["one_write_max_abs_err"],
+               facts["columns_max_abs_err"]) <= chip_smoke.TABLE_ATOL
 
 
 def test_ps_stage():

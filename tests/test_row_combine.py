@@ -7,7 +7,11 @@ wherever ``HEAD`` falls among the ids. ISSUE 36: on row shards the head is
 the first rows of every shard, a shard walks its own rows alone, and words
 are dealt round the shards. ISSUE 38: a row-sharded table is read by the
 shards that own the rows (``take_rows``), which is ``jnp.take`` bit for
-bit whatever the ids."""
+bit whatever the ids. ISSUE 40: the same at a PS block's sizes (a bucket
+of 2^19 rows and the dummy row, 8,192 and 6 x 8,192 update rows), and at
+8,192 update rows the program ``add_rows`` lowers to is the one it was."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +78,86 @@ def test_add_rows_is_the_scatter_add(kind, head, b, chunk, dtype, made_ahead,
                                   table[others].view(np.uint32))
     if kind == "distinct":      # one term a row: no reassociation at all
         np.testing.assert_array_equal(got, want)
+
+
+BLOCK_ROWS = 2 ** 19 + 1      # a PS block's bucket and its dummy row
+
+
+@pytest.mark.parametrize("made_ahead", [True, False])
+@pytest.mark.parametrize("b", [8192, 6 * 8192])
+@pytest.mark.parametrize("kind", ["block", "padded"])
+def test_add_rows_at_a_blocks_sizes_is_the_scatter_add(kind, b, made_ahead):
+    """Update rows as a block's minibatch names them: local rows that
+    keep the rank order of the words, so a Zipf law over the bucket, and
+    for a padded minibatch the dummy row alone."""
+    rng = np.random.default_rng(b)
+    ids = (np.full(b, BLOCK_ROWS - 1) if kind == "padded"
+           else rng.zipf(1.1, b) % (BLOCK_ROWS - 1)).astype(np.int32)
+    table = rng.normal(size=(BLOCK_ROWS, 4)).astype(np.float32)
+    table[::5] = -0.0
+    updates = rng.normal(size=(b, 4)).astype(np.float32)
+    plan = (row_combine.plan_rows(jnp.asarray(ids), BLOCK_ROWS)
+            if made_ahead else None)
+    got = np.asarray(jax.jit(row_combine.add_rows)(
+        jnp.asarray(table), jnp.asarray(ids), jnp.asarray(updates), plan))
+    want = table.copy()
+    np.add.at(want, ids, updates)
+    scale = np.abs(updates).max() * np.bincount(ids).max()
+    assert np.abs(got - want).max() <= 1e-6 * scale
+    others = np.setdiff1d(np.arange(BLOCK_ROWS), ids)
+    np.testing.assert_array_equal(got[others].view(np.uint32),
+                                  table[others].view(np.uint32))
+    distinct = np.unique(ids)
+    if kind == "block":     # rows on both sides of the head, repeats
+        assert (distinct < row_combine.HEAD).any()
+        assert (distinct >= row_combine.HEAD).any() and distinct.size < b
+    if made_ahead:
+        walk = -(-(distinct >= row_combine.HEAD).sum() // row_combine.CHUNK)
+        assert row_combine.plan_counts(plan).tolist() == [
+            distinct.size, (distinct < row_combine.HEAD).sum(),
+            walk * row_combine.CHUNK, 0]
+
+
+def _costly_ops(text: str):
+    """Of a lowered program, in order: every sort and loop, and every
+    scatter with the types it takes and gives. These are what a v5e's
+    time in ``add_rows`` goes to (PERF.md, PRs 28 and 31)."""
+    found = re.findall(
+        r'stablehlo\.(sort|while)\b|stablehlo\.scatter"\(.*?\n\s*\}\) : '
+        r'(\(.*?\) -> \S+)', text, flags=re.S)
+    return [name or types for name, types in found]
+
+
+@pytest.mark.parametrize("made_ahead", [True, False])
+def test_add_rows_at_8192_update_rows_lowers_to_the_program_it_was(
+        made_ahead):
+    """What ``we-fused`` and ``we-fused-x4`` run a minibatch (ISSUE 40
+    puts the same program under a PS block's scan): one sum of the 8,192
+    update rows into ``[8,192 + CHUNK]`` rows, the head's one dense add,
+    and ONE loop whose body scatters a chunk of 256 rows; a plan made
+    ahead leaves no sort in it, one made in the step its three."""
+    table = jax.ShapeDtypeStruct((1_800_001, 300), jnp.float32)
+    ids = jax.ShapeDtypeStruct((8192,), jnp.int32)
+    updates = jax.ShapeDtypeStruct((8192, 300), jnp.float32)
+    plan = (jax.eval_shape(lambda i: row_combine.plan_rows(i, 1_800_001),
+                           ids) if made_ahead else None)
+    ops = _costly_ops(jax.jit(row_combine.add_rows).lower(
+        table, ids, updates, plan).as_text())
+    t, wide = "tensor<1800001x300xf32>", "x300xf32>"
+    writes = [
+        f"(tensor<8448{wide}, tensor<8192x1xi32>, tensor<8192{wide}) "
+        f"-> tensor<8448{wide}",
+        f"({t}, tensor<1xi32>, tensor<8192{wide}) -> {t}",
+        "while",
+        f"({t}, tensor<256x1xi32>, tensor<256{wide}) -> {t}"]
+    if made_ahead:
+        assert ops == writes
+    else:
+        # the plan's three sorts, its head_run scatter and its
+        # searchsorted's loop come first and change none of the writes
+        assert [o for o in ops if o.endswith(wide)] == [
+            w for w in writes if w != "while"]
+        assert ops.count("sort") == 3 and ops.count("while") == 2
 
 
 def _padded(rows: int, shards: int) -> int:

@@ -522,9 +522,14 @@ def test_fused_block_program_on_a_v5e_copies_no_table(one_chip, we300,
     prep, _ = we._prepare_block_device(ids, np.random.default_rng(0), 0)
     table = jax.ShapeDtypeStruct(we.table_in.padded_shape, jnp.float32,
                                  sharding=fmt)
+    # the shapes of what is made ahead of the scan (negatives, plans)
+    batch, plans, _ = jax.eval_shape(
+        lambda *a: we._block_ahead(a[0], a[1], prep["ids_in"].shape[0] + 1,
+                                   *a[2:]),
+        prep["batch"], prep["valid"], prep["remap"], prep["neg_seed"],
+        jnp.asarray(we._neg_host))
     rest = _on_chip((prep["ids_in"], prep["ids_sec"], prep["valid"],
-                     prep["batch"], prep.get("remap"), prep.get("neg_seed"),
-                     jnp.asarray(we._neg_host)), one_chip)
+                     batch, plans), one_chip)
     try:
         compiled = we._fused_block_fn().lower(
             table, (), table, (), *rest).compile()

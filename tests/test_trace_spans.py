@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 import multiverso_tpu as mv
+from multiverso_tpu.ops import row_combine
 from multiverso_tpu.telemetry import trace as ttrace
 from multiverso_tpu.utils import config
 from multiverso_tpu.utils.dashboard import Dashboard
@@ -331,6 +332,33 @@ def test_dump_metrics_timeline_prints_the_summary(case, tmp_path, capsys):
     runs = [ln for ln in lines if "request=" in ln]
     assert [ln.split()[-1] for ln in runs] == ["request=1", "request=2"]
     assert float(runs[0].split()[0]) == pytest.approx(0.480)
+    assert "table writes" not in text       # no call with the counts
+
+
+def test_dump_metrics_timeline_prints_the_calls_table_writes(tmp_path,
+                                                             capsys):
+    """ISSUE 40: the counts on ``we.blocks`` (and ``we.fused``) have an
+    operator's reader."""
+    import json
+
+    from tools import dump_metrics
+
+    call = {**_prog(7, 0, 900), "name": "we.blocks", "request": 3,
+            "args": {"plane": "device", "blocks": 1, "words": 50,
+                     "update_rows": 2000, "unique_rows": 1480,
+                     "head_rows": 400, "walk_slots": 1280}}
+    fused = {**_prog(8, 0, 900), "name": "we.fused", "request": 4,
+             "args": {"update_rows": 100, "unique_rows": 50,
+                      "head_rows": 20, "walk_slots_by_shard": [16, 16]}}
+    path = tmp_path / "trace-rank0.jsonl"
+    path.write_text("".join(json.dumps(e) + "\n" for e in (
+        TIMELINES["gap_innermost"]["events"] + [call, fused])))
+    assert dump_metrics.main(["timeline", str(path)]) == 0
+    lines = capsys.readouterr().out.splitlines()
+    [blocks] = [ln for ln in lines if "we.blocks request=3" in ln]
+    assert blocks.split()[2:] == ["2000", "1480", "(74.0%)", "400", "1280"]
+    [fused_line] = [ln for ln in lines if "we.fused request=4" in ln]
+    assert fused_line.split()[2:] == ["100", "50", "(50.0%)", "20", "32"]
 
 
 def test_device_timeline_without_device_spans_is_none():
@@ -584,12 +612,21 @@ def test_device_plane_blocks_leave_their_spans_and_counts(mode, tmp_path):
     assert names == BLOCK_SPANS | ({"we.block.device"} if watched else set())
     assert all(e["prof"] is (mode == "profiler") for e in events)
     [call] = [e for e in events if e["name"] == "we.blocks"]
+    # ISSUE 40: what the scans' table writes were handed, as we.fused says
+    # it: a pair's centre, context and negatives, and what combining left
+    rows = {k: call["args"].pop(k) for k in (
+        "update_rows", "unique_rows", "head_rows", "walk_slots")}
     assert call["args"] == {"plane": "device", "blocks": n_blocks,
                             "words": int(ids.size)}
     by = {n: sorted((e for e in events if e["name"] == n),
                     key=lambda e: e["request"]) for n in names}
     for n in ("we.prepare", "we.block.wait_prepared", "we.block.dispatch"):
         assert [e["request"] for e in by[n]] == list(range(n_blocks)), n
+    assert rows["update_rows"] == (2 + we.cfg.negative) * sum(
+        e["args"]["pairs"] for e in by["we.prepare"])
+    assert 0 < rows["head_rows"] <= rows["unique_rows"] < rows["update_rows"]
+    assert rows["walk_slots"] % min(row_combine.CHUNK,
+                                    we.cfg.batch_size) == 0
     for prep, disp in zip(by["we.prepare"], by["we.block.dispatch"]):
         a = prep["args"]
         assert 0 < a["rows_touched"] <= a["rows_bucket"]
@@ -644,10 +681,13 @@ def test_block_program_carries_pull_scan_push_scopes():
     prep, _ = we._prepare_block_device(ids[:1500],
                                        np.random.default_rng(0), 0)
     si, ss = we.table_in.state, we.table_out.state
+    # the negatives and the plans, made ahead in a program of their own
+    batch, plans, _ = we._block_ahead_fn()(
+        prep["batch"], prep["valid"], prep["ids_in"].shape[0] + 1,
+        prep["remap"], prep["neg_seed"], we._neg_dev)
     text = we._fused_block_fn().lower(
         si["data"], si["ustate"], ss["data"], ss["ustate"], prep["ids_in"],
-        prep["ids_sec"], prep["valid"], prep["batch"], prep.get("remap"),
-        prep.get("neg_seed"), we._neg_dev).compile().as_text()
+        prep["ids_sec"], prep["valid"], batch, plans).compile().as_text()
     found = set(re.findall(r"mv\.[a-z_.]+", text))
     assert {"mv.pull", "mv.scan", "mv.push", "mv.scan.gather",
             "mv.scan.grad", "mv.scan.scatter", "mv.rowapply.gather",

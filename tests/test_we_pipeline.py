@@ -507,8 +507,13 @@ def _we_run(plane, pipeline, cache_rows, mode="auto"):
     we = WordEmbedding(cfg, Dictionary.build(tokens, 2))
     losses = []
     orig = we._train_prepared
-    we._train_prepared = lambda p, nw: (losses.append(orig(p, nw))
-                                        or losses[-1])
+
+    def train_prepared(p, nw):      # (loss, the plans' counts)
+        out = orig(p, nw)
+        losses.append(out[0])
+        return out
+
+    we._train_prepared = train_prepared
     stats = we.train_ps_blocks(we.prepare_ids(tokens))
     rows = we._rows(np.arange(len(we.dict)))    # the words' rows
     rin, rout = we.table_in.get_rows(rows), we.table_out.get_rows(rows)
@@ -541,3 +546,65 @@ class TestPipelineParity:
         # the cache actually served: parity must not be vacuous
         wt = variants["pipeline+writethrough"][3]
         assert wt is not None and wt["hits"] > 0, wt
+
+
+# ---------------------------------------------------------------------- #
+# ISSUE 40: a block's scan writes through row_combine.add_rows with plans
+# made ahead of it, in both planes
+# ---------------------------------------------------------------------- #
+@pytest.mark.parametrize("negs", ["drawn_on_the_device", "packed"])
+def test_a_device_plane_block_is_the_host_planes_local_train(negs):
+    """One PS block through ``train_ps_blocks`` on the device plane (the
+    plans a program of their own ahead of the block's) leaves the tables
+    the host plane's ``_local_train_fn`` leaves on the same block, and
+    both calls' spans say what the table writes were handed."""
+    import multiverso_tpu as mv
+    from multiverso_tpu.apps.word_embedding import (WEConfig, WordEmbedding,
+                                                    synthetic_corpus)
+    from multiverso_tpu.data.dictionary import Dictionary
+    from multiverso_tpu.telemetry import trace as ttrace
+
+    # small blocks of a large vocabulary bring their negatives packed
+    # (the benchmark's cell), large ones draw them from a 4-byte seed
+    block = 6_000 if negs == "drawn_on_the_device" else 300
+    tokens = synthetic_corpus(24_000, vocab=400, seed=3)
+    out = {}
+    for plane in ("device", "host"):
+        mv.init()
+        cfg = WEConfig(size=8, min_count=2, batch_size=64, negative=3,
+                       window=3, epoch=1, data_block_size=block, use_ps="1",
+                       ps_device_plane="1" if plane == "device" else "0",
+                       seed=7)
+        we = WordEmbedding(cfg, Dictionary.build(tokens, 2))
+        assert we._dev_negs == (negs == "drawn_on_the_device")
+        local_calls = []
+        if plane == "host":
+            fn = we._local_train_fn()
+            we._fused_cache["ps_local"] = lambda *a: (
+                local_calls.append(fn(*a)) or local_calls[-1])
+        start = len(ttrace.events())
+        stats = we.train_ps_blocks(we.prepare_ids(tokens)[:block])
+        [call] = [e for e in ttrace.events()[start:]
+                  if e["name"] == "we.blocks"]
+        assert call["args"]["plane"] == plane and call["args"]["blocks"] == 1
+        assert len(local_calls) == (plane == "host")
+        if local_calls:     # the span's counts are that program's
+            assert np.asarray(local_calls[0][3]).tolist()[:4] == [
+                call["args"][k] for k in ("update_rows", "unique_rows",
+                                          "head_rows", "walk_slots")]
+        rows = we._rows(np.arange(len(we.dict)))
+        out[plane] = (stats["loss"], np.array(we.table_in.get_rows(rows)),
+                      np.array(we.table_out.get_rows(rows)), call["args"])
+        mv.shutdown()
+    dev, host = out["device"], out["host"]
+    assert dev[0] == pytest.approx(host[0], rel=1e-5)
+    for got, want in zip(dev[1:3], host[1:3]):
+        assert np.abs(want).max() > 0
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+    # a pair's update rows are its centre, its context and its negatives;
+    # both planes were handed the same ids, whatever their buckets
+    for k in ("update_rows", "unique_rows"):
+        assert dev[3][k] == host[3][k] > 0
+    for args in (dev[3], host[3]):
+        assert 0 < args["head_rows"] <= args["unique_rows"] <= (
+            args["update_rows"])

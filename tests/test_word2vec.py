@@ -402,3 +402,92 @@ class TestModesAndRegressions:
         implied_words = stats["words_per_sec"] * stats["seconds"]
         assert implied_words == pytest.approx(ids.size, rel=0.01)
         assert stats["pairs"] > ids.size  # pairs are reported separately
+
+
+# ---------------------------------------------------------------------- #
+# ISSUE 40: skipgram_ns_step writes both tables through
+# row_combine.add_rows, with plans made ahead of it or in it
+# ---------------------------------------------------------------------- #
+def _raw_ns_step(win, wout, centers, contexts, negatives, lr):
+    """``skipgram_ns_step`` as it was before ISSUE 40, the plain
+    reference: every update row goes to its table by a raw duplicate
+    scatter-add."""
+    import jax
+    import jax.numpy as jnp
+    targets = jnp.concatenate([contexts[:, None], negatives], axis=1)
+    v, u = jnp.take(win, centers, axis=0), jnp.take(wout, targets, axis=0)
+    scores = jnp.einsum("bd,btd->bt", v, u)
+    labels = jnp.zeros(targets.shape, v.dtype).at[:, 0].set(1.0)
+    g = (labels - jax.nn.sigmoid(scores)) * lr
+    win = win.at[centers].add(jnp.einsum("bt,btd->bd", g, u))
+    du = g[..., None] * v[:, None, :]
+    return win, wout.at[targets.reshape(-1)].add(
+        du.reshape(-1, du.shape[-1]))
+
+
+NS_ROWS, NS_B, NS_K = 1003, 64, 5     # the last row stands for the dummy
+
+
+def _ns_ids(kind, rng):
+    if kind == "padded":        # a padded minibatch: every id the dummy
+        return (np.full(NS_B, NS_ROWS - 1, np.int32),) * 2 + (
+            np.full((NS_B, NS_K), NS_ROWS - 1, np.int32),)
+    if kind == "distinct":      # no row twice, in a table or a column
+        ids = rng.permutation(NS_ROWS - 1)[:NS_B * (NS_K + 2)].astype(
+            np.int32)
+        return (ids[:NS_B], ids[NS_B:2 * NS_B],
+                ids[2 * NS_B:].reshape(NS_B, NS_K))
+    draw = lambda *shape: (rng.zipf(1.2, shape) % 300).astype(np.int32)
+    return draw(NS_B), draw(NS_B), draw(NS_B, NS_K)
+
+
+# heads: the whole table, and one that leaves the walk most of the rows
+@pytest.mark.parametrize("head", [8192, 40])
+@pytest.mark.parametrize("plans", ["made_ahead", "made_in_the_step"])
+@pytest.mark.parametrize("kind", ["repeats", "distinct", "padded"])
+def test_skipgram_ns_step_writes_what_the_raw_scatters_wrote(
+        kind, plans, head, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+    from multiverso_tpu.ops import row_combine
+    monkeypatch.setattr(row_combine, "HEAD", head)
+    monkeypatch.setattr(row_combine, "CHUNK", 16)
+    rng = np.random.default_rng(40)
+    c, x, g = (jnp.asarray(a) for a in _ns_ids(kind, rng))
+    tables = rng.uniform(-0.5, 0.5, (2, NS_ROWS, 12)).astype(np.float32)
+    tables[:, ::5] = -0.0   # a slot that wrote row + 0 would leave +0.0
+    win0, wout0 = jnp.asarray(tables[0]), jnp.asarray(tables[1])
+    made = (None, None)
+    if plans == "made_ahead":
+        made = (row_combine.plan_rows(c, NS_ROWS),
+                row_combine.plan_rows(w2v.target_columns(x, g), NS_ROWS))
+        assert made[1].run.shape == (NS_K + 1, NS_B)
+    # every float operation as it is written: two programs then round
+    # alike (tests/conftest.py, same_floats)
+    as_written = {"xla_backend_optimization_level": 0}
+    win, wout, loss = jax.jit(
+        lambda *a: w2v.skipgram_ns_step(*a, 0.05, plans=made),
+        compiler_options=as_written)(win0, wout0, c, x, g)
+    rwin, rwout = jax.jit(lambda *a: _raw_ns_step(*a, 0.05),
+                          compiler_options=as_written)(win0, wout0, c, x, g)
+    assert np.isfinite(float(loss))
+    for got, ref, old, ids in ((win, rwin, tables[0], c),
+                               (wout, rwout, tables[1],
+                                np.concatenate([x, g.reshape(-1)]))):
+        got, ref = np.asarray(got), np.asarray(ref)
+        delta = np.abs(ref - old).max()
+        assert delta > 0
+        # the same float32 sum in another order
+        assert np.abs(got - ref).max() <= 1e-5 * delta
+        # every row no id names keeps its bits, the -0.0 rows included:
+        # under a padded minibatch every real row
+        others = np.setdiff1d(np.arange(NS_ROWS), np.asarray(ids))
+        assert np.signbit(old[others]).any()
+        np.testing.assert_array_equal(got[others].view(np.uint32),
+                                      old[others].view(np.uint32))
+        if kind == "padded":
+            assert others.size == NS_ROWS - 1
+        if kind == "distinct":
+            # one term a row, no reassociation: the same float, bit for
+            # bit but for the sign of a zero (a sum starts from +0.0)
+            np.testing.assert_array_equal(got, ref)

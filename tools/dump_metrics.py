@@ -23,7 +23,8 @@ spans carry ``pid`` = rank). ``timeline`` reads the device spans of a
 prints the device's timeline as the host knew it
 (``telemetry/trace.device_timeline``): the share of it the device was
 starved (no program in flight), those seconds by the host span that was
-open meanwhile, and the five longest runs with their ``request``.
+open meanwhile, the five longest runs with their ``request``, and what
+each ``we.fused`` / ``we.blocks`` call handed its table writes.
 
 Both commands also accept the cluster aggregator's time series
 (``cluster.jsonl``, records with ``kind: "cluster"`` — see
@@ -679,7 +680,31 @@ def format_timeline(events: List[Dict]) -> str:
     out.append(f"  longest runs (median {median:.3f} ms):")
     out += [f"    {r['run_ms']:12.3f} ms  {r['name']}  request={r['request']}"
             for r in runs[:5]]
-    return "\n".join(out)
+    return "\n".join(out + _table_write_lines(events))
+
+
+def _table_write_lines(events: List[Dict]) -> List[str]:
+    """What the trainers' calls handed their table writes, from the
+    counts on ``we.fused`` and ``we.blocks`` (``ops/row_combine``): the
+    update rows, the distinct rows combining left of them, those of them
+    a head's dense add took, and the slots the walks were handed (by
+    shard on ``we.fused``)."""
+    calls = [e for e in events if e.get("name") in ("we.fused", "we.blocks")
+             and "unique_rows" in e.get("args", {})]
+    if not calls:
+        return []
+    out = ["  table writes by call (update rows, distinct, in a head, "
+           "walk slots):"]
+    for e in calls:
+        a = e["args"]
+        walk = a.get("walk_slots", a.get("walk_slots_by_shard"))
+        walk = sum(walk) if isinstance(walk, list) else walk
+        out.append(
+            f"    {e['name']} request={e.get('request')}  "
+            f"{a['update_rows']}  {a['unique_rows']} "
+            f"({100.0 * a['unique_rows'] / max(a['update_rows'], 1):.1f}%)  "
+            f"{a['head_rows']}  {walk}")
+    return out
 
 
 def main(argv: List[str]) -> int:
