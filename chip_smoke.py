@@ -1,7 +1,7 @@
 """chip_smoke.py: prove that the system starts and computes correctly on
 the TPU it is measured on. One process, no arguments, no network.
 
-Six stages, each driven through the entry points a user calls, each
+Seven stages, each driven through the entry points a user calls, each
 checked by the repo's own means (a NumPy float32 statement of the updater
 rule, a falling finite loss, ``jnp.take``,
 ``parallel.ring.reference_attention``):
@@ -14,6 +14,8 @@ rule, a falling finite loss, ``jnp.take``,
           block's table writes, raw and through row_combine.add_rows
   ps      uncoordinated plane: a two-rank world with device-backed shards
   lm      the 472M transformer step with the Pallas flash kernel
+  flash   the language-model cells' flash kernel calls, a crossed pair as
+          one tile against its sub-tiles: ms a call and compile seconds
 
 and a closing ``memory`` check that every device ended up holding bytes.
 
@@ -33,6 +35,7 @@ This is a health check, not a benchmark: its wall times are set-up costs
 
 from __future__ import annotations
 
+import functools
 import json
 import sys
 import tempfile
@@ -682,6 +685,115 @@ def stage_lm(vocab: int = 32768, dim: int = 2048, heads: int = 16,
             "kernel_rel_err": kernel, "kernel_tol": ATTN_BF16_TOL}
 
 
+# (name, q's shape, key-value heads, (block_q, block_k), window): the
+# window layers of mellum2-train-8k and trinity-train-16k, their full
+# layers' call, and glm47f-train-8k's
+FLASH_CALLS = (
+    ("mellum2.window", (2, 32, 8192, 128), 4, (1024, 1024), 1024),
+    ("trinity.window", (1, 32, 16384, 128), 4, (1024, 1024), 2048),
+    ("mellum2.full", (2, 32, 8192, 128), 4, (1024, 1024), None),
+    ("glm47f.causal", (2, 20, 8192, 256), 20, (512, 1024), None),
+)
+
+
+def stage_flash(calls: Tuple = FLASH_CALLS, subs: Tuple = (),
+                repeats: int = 10, ref_heads: int = 2) -> Dict[str, Any]:
+    """The flash kernels' crossed pairs, whole-tile against sub-tiled
+    (``ops/attention_kernels.sub_tile``'s choice for the call, or each of
+    ``subs``): for the forward with its residual, dQ, and dK with dV, the
+    seconds the compiler took and the ms a call by this process's clock
+    around ``repeats`` calls it waits for; the sub-tiled results against
+    the whole-tile ones on the same inputs (norm of the difference over
+    the norm), and both against float32 attention on ``ref_heads`` query
+    heads of the call (max|err| over max|reference|, as ``stage_lm``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models.mla_moe import _xla_attention
+    from multiverso_tpu.ops import attention_kernels as ak
+
+    interpret = ak._resolve_interpret(None)
+    out: Dict[str, Any] = {}
+    for n, (name, shape, hkv, blocks, window) in enumerate(calls):
+        rng = np.random.default_rng(SEED + n)
+        kv = (shape[0], hkv) + shape[2:]
+        q, g = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                for _ in range(2))
+        k, v = (jnp.asarray(rng.normal(size=kv), jnp.bfloat16)
+                for _ in range(2))
+        rule = ak.sub_tile(*ak._blocks(shape[2], *blocks), shape[3])
+        facts: Dict[str, Any] = {"sub_tile": rule}
+        # float32 attention on a few heads of one sequence, so that the
+        # reference's [S, S] fits
+        few = lambda t, h: t[:1, :h]
+        small = (few(q, ref_heads), few(k, 1), few(v, 1))
+        gs = few(g, ref_heads)
+
+        def fwd_and_grads(fn, *args):
+            res, vjp = jax.vjp(fn, *args)
+            return (res,) + vjp(gs.astype(res.dtype))
+
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(lambda *a: fwd_and_grads(
+                lambda q, k, v: _xla_attention(q, k, v, window),
+                *(t.astype(jnp.float32) for t in a)))(*small)
+        results = {}
+        for sub in (None,) + (subs or ((rule,) if rule else ())):
+            def forward(q, k, v):
+                return ak._flash_forward(q, k, v, True, *blocks, interpret,
+                                         True, window, sub)
+
+            def backward(keep, q, k, v, o, lse, g):
+                # the kernel whose results are dropped is no part of the
+                # program: dQ (keep 0) and dK with dV (keep 1) apart
+                grads = ak._flash_backward(q, k, v, o, lse, g, True, *blocks,
+                                           interpret, window, sub)
+                return grads[:1] if keep == 0 else grads[1:]
+
+            o, lse = forward(q, k, v)
+            kernels = (("fwd", forward, (q, k, v)),
+                       ("dq", functools.partial(backward, 0),
+                        (q, k, v, o, lse, g)),
+                       ("dkv", functools.partial(backward, 1),
+                        (q, k, v, o, lse, g)))
+            tag, results[sub] = f"sub{sub or 0}", []
+            for kernel, fn, args in kernels:
+                t0 = time.perf_counter()
+                compiled = jax.jit(fn).lower(*args).compile()
+                facts[f"{tag}_{kernel}_compile_s"] = round(
+                    time.perf_counter() - t0, 2)
+                res = jax.block_until_ready(compiled(*args))
+                # the output and the gradients; not the forward's residual
+                results[sub] += [r for r in res if r.ndim == 4]
+                t0 = time.perf_counter()
+                for _ in range(repeats):
+                    res = compiled(*args)
+                jax.block_until_ready(res)
+                facts[f"{tag}_{kernel}_ms"] = round(
+                    (time.perf_counter() - t0) / repeats * 1e3, 3)
+            if sub is not None:
+                facts[f"{tag}_vs_whole"] = [
+                    float(jnp.linalg.norm((a - b).astype(jnp.float32))
+                          / jnp.linalg.norm(b.astype(jnp.float32)))
+                    for a, b in zip(results[sub], results[None])]
+                if max(facts[f"{tag}_vs_whole"]) > ATTN_BF16_TOL:
+                    raise AssertionError(
+                        f"flash {name}: sub-tiles of {sub} differ from "
+                        f"whole tiles by {facts[f'{tag}_vs_whole']}")
+            got = jax.jit(lambda *a: fwd_and_grads(
+                lambda q, k, v: ak._attention(q, k, v, True, *blocks, None,
+                                              window, sub), *a))(*small)
+            errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))
+                          / jnp.max(jnp.abs(b))) for a, b in zip(got, want)]
+            if not max(errs) <= ATTN_BF16_TOL:      # a NaN fails too
+                raise AssertionError(
+                    f"flash {name} sub {sub}: relative error {errs} > "
+                    f"{ATTN_BF16_TOL}")
+            facts[f"{tag}_rel_err"] = [round(e, 5) for e in errs]
+        out[name] = facts
+    return out
+
+
 def stage_memory() -> Dict[str, Any]:
     """After the run every device holds bytes: every chip was used."""
     import jax
@@ -720,7 +832,7 @@ def result_line(ok: bool, device: Dict[str, Any]) -> Dict[str, Any]:
 STAGES: Tuple[Tuple[str, Callable[[], Dict[str, Any]]], ...] = (
     ("tables", stage_tables), ("we", stage_we), ("rows", stage_rows),
     ("ps", stage_ps),
-    ("lm", stage_lm), ("memory", stage_memory))
+    ("lm", stage_lm), ("flash", stage_flash), ("memory", stage_memory))
 
 
 def main() -> int:

@@ -56,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from multiverso_tpu.ops.attention_kernels import (causal_pairs,
-                                                   flash_attention)
+                                                   flash_attention, sub_tile)
 from multiverso_tpu.parallel import moe
 from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.updaters import AddOption
@@ -399,12 +399,17 @@ def attn_blocks(cfg, s: int) -> Tuple[int, int]:
     rescaled once a k block. At (8192, 128) with 32 query heads over 4
     key-value heads (PERF.md section 6, PR 35) 20.5 / 12.9 / 16.8 at 512 x
     512, 11.9 / 12.0 / 15.3 at 512 x 1,024 and 10.3 / 11.1 / 14.9 at 1,024 x
-    1,024. A window changes nothing in the choice: under one of 1,024 the
-    same three read 7.5 / 4.8 / 6.1, 5.7 / 5.8 / 6.9 and 5.1 / 5.4 / 6.8,
-    and the forward runs twice a block: the band's edges are paid in
-    whole blocks (15 pairs of 1,024 x 1,024 a head are twice the band's
-    area, 45 of 512 x 512 one and a half times), but a k block's rescale
-    and a grid step's cost weigh more than the masked half of a tile."""
+    1,024. Under a window of 1,024 the same three read 7.5 / 4.8 / 6.1,
+    5.7 / 5.8 / 6.9 and 5.1 / 5.4 / 6.8 with a crossed pair one tile under
+    one mask: 15 pairs of 1,024 x 1,024 a head are twice the band's area
+    and 45 of 512 x 512 one and a half times, but a k block's rescale and
+    a grid step's cost weigh more than the masked half of a tile, so the
+    blocks stay wide and the band's edges are cut INSIDE the pair, where
+    a step costs nothing: ``attention_kernels.sub_tile`` gives a crossed
+    pair sub-tiles of 256, of which it computes the live ones (1.25 of
+    the band, not 2.00) and masks those an edge passes through: 3.3 / 3.5
+    / 4.7 at 1,024 x 1,024 (PERF.md section 6, PR 44), and the causal call
+    above 9.3 / 10.1 / 13.7 for 10.1 / 11.0 / 14.7."""
     bq = min(cfg.attn_block, s)
     wide = 2 * bq <= 1024 and cfg.head_size <= 256 and s % (2 * bq) == 0
     if not wide:
@@ -419,22 +424,31 @@ def attn_grid(cfg, s: int) -> Dict[str, Any]:
     multiplier, the causal walk's counts and, where some layer is of the
     window kind, the band's beside what a causal walk of the band's blocks
     would visit and what the grouped-query attention does around its core
-    (``gqa_moe.gqa``'s switches); nothing where XLA is the core."""
+    (``gqa_moe.gqa``'s switches); the positions a forward call computes
+    (whole tiles, and of a crossed pair the sub-tiles of
+    ``attention_kernels.sub_tile`` that hold a live position) beside those
+    it needs; nothing where XLA is the core."""
     if attn_core(cfg) != "flash":
         return {}
     kinds = [layer.attn for layer in cfg.layers()]
     blocks = attn_blocks(cfg, s)
-    n = causal_pairs(s, *blocks)
+    sub = sub_tile(*blocks, cfg.head_size)
+    n = causal_pairs(s, *blocks, sub=sub)
     out = {"attn_grid_steps": n["grid_steps"], "attn_pairs_live": n["live"],
-           "attn_pairs_masked": n["masked"], "attn_kinds": ",".join(kinds),
+           "attn_pairs_masked": n["masked"],
+           "attn_positions_computed": n["computed"],
+           "attn_positions_needed": n["needed"],
+           "attn_kinds": ",".join(kinds),
            "kv_group": cfg.kv_group,
            "block_norms": 4 if cfg.post_norms else 2,
            "embed_scale": float(cfg.embed_scale)}
     if "window" in kinds:
-        band = causal_pairs(s, *blocks, cfg.window)
+        band = causal_pairs(s, *blocks, cfg.window, sub)
         out.update(attn_pairs_live_window=band["live"],
                    attn_pairs_masked_window=band["masked"],
                    attn_pairs_causal_window=n["live"],
+                   attn_positions_computed_window=band["computed"],
+                   attn_positions_needed_window=band["needed"],
                    attn_gated=int(cfg.attn_gate), qk_norm=int(cfg.qk_norm),
                    rope_kinds=",".join(cfg.rope_kinds))
     return out

@@ -16,8 +16,24 @@ structure is known before the kernel runs, and the grid uses it: a causal
 call walks a table of the live pairs only (:func:`live_pairs`, read by the
 index maps as scalar-prefetch operands), so a block strictly above the
 diagonal costs no grid step and no K/V copy, and only a pair the diagonal
-crosses builds and applies the mask; a pair under it runs the same tile
+crosses builds and applies a mask; a pair under it runs the same tile
 function without. A non-causal call walks the whole rectangle, unmasked.
+
+A crossed pair is not one tile under one mask either, where
+:func:`sub_tile` gives the blocks sub-tiles: what is live in a pair
+follows from ``i * block_q - j * block_k`` alone (its *kind*: 0 on the
+diagonal, the window at the band's far edge where the blocks are equal),
+so each kind is one ``pl.when`` branch of static slices
+(:meth:`_Walk.pieces`). A sub-block of rows has ONE contiguous run of live
+columns (up to the diagonal, from the far edge on): the forward is one
+``q[rows] @ k[run]^T``, one max, one rescale and one ``p @ v[run]`` for it,
+no more rescales a pair than the whole tile's; dQ likewise; dK with dV
+takes a sub-block of columns with its run of live rows. The mask (two
+iotas, a compare for each edge that is there, the select, the forward's
+second select) is built and applied on the sub-tiles an edge passes
+through and nowhere else. An interior pair runs the whole tile as before,
+and the pair table, the grid, the block specs and the number of kernels
+are as they were.
 
 The backward pass is Pallas too (FlashAttention-2 style): the forward
 additionally emits the per-row logsumexp, and two blockwise kernels
@@ -56,12 +72,49 @@ the triangle's positions): 7.5 / 4.8 / 6.1 at 512 x 512 (45 pairs a head),
 256 (150). Against plain float32 attention on the chip the outputs and
 the three gradients differ by 0.0020 to 0.0027 of their norm, grouped or
 not, banded or not.
+
+The same calls with a crossed pair in sub-tiles (builder's chip runs,
+PR 44, ``chip_smoke.py`` stage ``flash``; PERF.md section 6; 1,024 x 1,024
+blocks but GLM's; whole tile / sub-tiles of 512 / 256 / 128):
+
+  window 1,024, q (2, 32, 8192, 128) over 4 key-value heads (15 pairs,
+  all crossed; 2.00 / 1.50 / 1.25 / 1.125 of the band computed):
+    forward 4.91 / - / **3.34** / 3.66, dQ 5.24 / 3.93 / **3.49** / 3.39,
+    dK with dV 6.64 / 4.98 / **4.68** / 5.06
+  window 2,048, q (1, 32, 16384, 128) (45 pairs, 30 crossed):
+    forward 6.80 / - / **5.22** / 5.55, dQ 7.39 / 6.08 / **5.65** / 5.55,
+    dK with dV 9.59 / 7.92 / **7.63** / 8.00
+  causal, q (2, 32, 8192, 128) (36 pairs, 8 crossed):
+    forward 10.09 / - / **9.27** / 9.43, dQ 10.97 / 10.38 / **10.14** /
+    10.13, dK with dV 14.74 / 13.91 / **13.73** / 14.06
+  causal, q (2, 20, 8192, 256), 512 x 1,024 blocks (72 pairs, 16 crossed):
+    forward 10.56 / - / **9.81** / 9.82, dQ 13.41 / 13.05 / **12.89** /
+    12.81, dK with dV 17.52 / 16.69 / **16.44** / 16.46
+
+A kernel compiles in 1.0 to 2.6 s with sub-tiles of 256 and 0.9 to 2.3 s
+without; the results are the whole tiles' bit for bit in bfloat16 (a dead
+position added 0.0 to a sum; the live ones keep their order). The forward
+gains only because a row's running max and denominator are kept and
+combined in every lane of their scratch: taken at [rows, 1], as they
+were, the forward of the first call read 5.08 in sub-tiles of 256 for
+4.94 whole, while the same statistics cost a whole tile nothing (4.91).
+The forward's time follows its row updates (rows x pairs), not its area:
+what the sub-tiles save there is the products, the exps and the masks of
+the area they skip, at the same number of rescales.
+
+A kernel with sub-tiles is four to nine tile bodies where it was two, and
+tracing and lowering it costs in proportion (a model calls it once a
+layer, and again under ``jax.checkpoint``: 62 traces and 56 lowerings in
+``glm47f-train-8k``'s set-up, 6.8 s more than the parent's on a warm
+compile cache). So :meth:`_Walk.call` traces a call once for its kernel
+body, static arguments and operand types and binds it again from the
+jaxpr; equations with one jaxpr also lower once a program.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -118,13 +171,65 @@ def live_pairs(s: int, block_q: int, block_k: int, q_inner: bool = False,
 
 
 def causal_pairs(s: int, block_q: int, block_k: int,
-                 window: Optional[int] = None) -> Dict[str, int]:
+                 window: Optional[int] = None,
+                 sub: Optional[int] = None) -> Dict[str, int]:
     """What ONE causal kernel call does for one (batch x head): the grid
-    steps it takes, the pairs among them that compute, and those of them
-    that take the masked path."""
+    steps it takes, the pairs among them that compute, those of them the
+    diagonal or the band's far edge crosses, the positions it computes (a
+    whole tile for an interior pair; of a crossed one the sub-tiles of
+    ``sub`` rows and columns that hold a live position, or the whole tile
+    without ``sub``) and the positions that are live."""
+    block_q, block_k = _blocks(s, block_q, block_k)
     _, _, crossing = live_pairs(s, block_q, block_k, window=window)
+    # an interior pair's sub-tiles are all live: the call computes the live
+    # tiles of the sub-tiles' own grid
+    sub_q, sub_k = _sub_blocks(block_q, block_k, sub)
+    tiles = live_pairs(s, sub_q, sub_k, window=window)[0].size
+    w = _band(s, window) or s
     return {"grid_steps": int(crossing.size), "live": int(crossing.size),
-            "masked": int(crossing.sum())}
+            "masked": int(crossing.sum()), "computed": tiles * sub_q * sub_k,
+            "needed": w * (w + 1) // 2 + (s - w) * w}
+
+
+def sub_tile(block_q: int, block_k: int, d: int) -> Optional[int]:
+    """The rows and columns of the sub-tiles a crossed pair of ``block_q``
+    x ``block_k`` is cut into at head size ``d``; ``None`` leaves it one
+    tile under one mask. 256 where the blocks are of 512 rows or more
+    (what the chip read at head sizes 128 and 256, the module's table;
+    nothing under 128 is a whole lane tile)."""
+    if d <= 256 and block_q % 512 == 0 and block_k % 512 == 0:
+        return 256
+    return None
+
+
+def _sub_blocks(block_q: int, block_k: int,
+                sub: Optional[int]) -> Tuple[int, int]:
+    """A sub-tile's (rows, columns): ``sub`` of each, at most the block."""
+    if sub is None:
+        return block_q, block_k
+    sub_q, sub_k = min(sub, block_q), min(sub, block_k)
+    if block_q % sub_q or block_k % sub_k:
+        raise ValueError(f"blocks ({block_q}, {block_k}) not divisible by "
+                         f"sub-tiles of {sub}")
+    return sub_q, sub_k
+
+
+class _Piece(NamedTuple):
+    """One product's worth of a crossed pair: a sub-block of rows with its
+    run of live columns (``axis`` 1: forward and dQ) or a sub-block of
+    columns with its run of live rows (``axis`` 0: dK with dV). ``edges``
+    are the sub-tiles of it that the diagonal or the band's far edge passes
+    through, each ``(start, stop, corner)``: where along ``axis`` it lies
+    in the piece, and ``qpos - kpos`` at its first row and column."""
+    rows: slice
+    cols: slice
+    axis: int
+    edges: Tuple[Tuple[int, int, int], ...]
+
+
+# the kernel calls traced so far, by kernel body, static arguments and
+# operand types (:meth:`_Walk.call`)
+_TRACED: Dict[tuple, Any] = {}
 
 
 class _Walk(NamedTuple):
@@ -142,7 +247,15 @@ class _Walk(NamedTuple):
     Forward and dQ walk the query heads. dK with dV walks the KEY-VALUE
     heads, and each step of its table names one of the group's query
     heads as well (``g`` innermost), so the accumulators of a k block sum
-    over the group before they are written once."""
+    over the group before they are written once.
+
+    With ``sub`` a crossed pair is no longer one tile under one mask: the
+    kernel cuts it into sub-tiles of ``sub`` rows and columns, computes
+    only those that hold a live position and masks only those an edge
+    passes through (:meth:`pieces`). What is live in a pair follows from
+    ``i * block_q - j * block_k`` alone, its *kind*, and a call's crossed
+    pairs are of a few kinds (:meth:`kinds`): each is one branch of static
+    slices in the kernel."""
     causal: bool
     s: int
     block_q: int
@@ -150,6 +263,7 @@ class _Walk(NamedTuple):
     q_inner: bool
     window: Optional[int] = None
     group: int = 1
+    sub: Optional[int] = None
 
     @property
     def nq(self) -> int:
@@ -175,6 +289,49 @@ class _Walk(NamedTuple):
             return qi, kj
         g = np.tile(np.arange(self.group, dtype=np.int32), qi.size)
         return np.repeat(qi, self.group), np.repeat(kj, self.group), g
+
+    def kinds(self) -> Tuple[int, ...]:
+        """``i * block_q - j * block_k`` of the crossed pairs, each value
+        once; none where a crossed pair stays one tile."""
+        if not self.causal or self.sub is None:
+            return ()
+        qi, kj, crossing = live_pairs(self.s, self.block_q, self.block_k,
+                                      window=self.window)
+        return tuple(int(x) for x in np.unique(
+            (qi * self.block_q - kj * self.block_k)[crossing]))
+
+    def pieces(self, kind: int) -> Tuple[_Piece, ...]:
+        """A crossed pair of ``kind`` as the kernel computes it: for each
+        sub-block of rows that has one, its run of live columns (one
+        contiguous run of sub-tiles: up to the diagonal, from the far edge
+        on), or with ``q_inner`` for each sub-block of columns its run of
+        live rows. A sub-tile holds ``qpos - kpos`` from ``lo`` to ``hi``;
+        a position is live where that is at least 0 and under the window."""
+        sub_q, sub_k = _sub_blocks(self.block_q, self.block_k, self.sub)
+        r, c = np.indices((self.block_q // sub_q, self.block_k // sub_k))
+        corner = kind + r * sub_q - c * sub_k
+        lo, hi = corner - (sub_k - 1), corner + (sub_q - 1)
+        far = np.inf if self.window is None else self.window
+        live, whole = (hi >= 0) & (lo < far), (lo >= 0) & (hi < far)
+        own, run = sub_q, sub_k
+        if self.q_inner:
+            live, whole, corner = live.T, whole.T, corner.T
+            own, run = sub_k, sub_q
+        out = []
+        for a in range(live.shape[0]):
+            along = np.flatnonzero(live[a])
+            if not along.size:
+                continue
+            first, last = int(along[0]), int(along[-1])
+            assert along.size == last - first + 1       # one run
+            edges = tuple(((b - first) * run, (b - first + 1) * run,
+                           int(corner[a, b]))
+                          for b in along.tolist() if not whole[a, b])
+            mine = slice(a * own, (a + 1) * own)
+            theirs = slice(first * run, (last + 1) * run)
+            out.append(_Piece(theirs, mine, 0, edges) if self.q_inner
+                       else _Piece(mine, theirs, 1, edges))
+        return tuple(out)
 
     def specs(self, d: int) -> Tuple[pl.BlockSpec, ...]:
         """The block specs of a q-shaped operand, a k-shaped one and the
@@ -203,25 +360,39 @@ class _Walk(NamedTuple):
 
     def call(self, kernel, bh: int, operands, *, interpret: bool,
              out_shape, **specs):
-        """``pl.pallas_call`` of ``kernel`` over ``bh`` (batch x head)s of
-        this walk (key-value heads for ``q_inner``, else query heads);
-        ``specs`` are the grid spec's in, out and scratch."""
-        table = self.table()
-        if self.causal:
-            grid, inner = (bh, len(table[0])), ("arbitrary",)
-        else:
-            grid = ((bh, self.nk, self.nq) if self.q_inner
-                    else (bh, self.nq, self.nk))
-            inner = ("parallel", "arbitrary")
-        return pl.pallas_call(
-            kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=len(table), grid=grid, **specs),
-            out_shape=out_shape,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",) + inner),
-            interpret=interpret,
-        )(*table, *operands)
+        """``pl.pallas_call`` of ``kernel`` (a partial of a kernel body
+        over its static arguments) over ``bh`` (batch x head)s of this
+        walk (key-value heads for ``q_inner``, else query heads);
+        ``specs`` are the grid spec's in, out and scratch. A model calls
+        the same kernel on the same shapes once a layer, and again under
+        ``jax.checkpoint``: the call is traced once (:data:`_TRACED`) and
+        bound again from its jaxpr, so a program also lowers it once
+        (equations with the same parameters share a lowering)."""
+        key = (kernel.func, tuple(sorted(kernel.keywords.items())), bh,
+               interpret, tuple(jax.typeof(o) for o in operands))
+        traced = _TRACED.get(key)
+        if traced is None:
+            table = self.table()
+            if self.causal:
+                grid, inner = (bh, len(table[0])), ("arbitrary",)
+            else:
+                grid = ((bh, self.nk, self.nq) if self.q_inner
+                        else (bh, self.nq, self.nk))
+                inner = ("parallel", "arbitrary")
+            call = pl.pallas_call(
+                kernel,
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=len(table), grid=grid, **specs),
+                out_shape=out_shape,
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("parallel",) + inner),
+                interpret=interpret)
+            if len(_TRACED) >= 64:      # a test suite's worth of shapes
+                _TRACED.clear()
+            traced = _TRACED[key] = jax.make_jaxpr(
+                lambda *operands: call(*table, *operands))(*operands)
+        out = jax.core.eval_jaxpr(traced.jaxpr, traced.consts, *operands)
+        return out if isinstance(out_shape, (list, tuple)) else out[0]
 
     def enter(self, refs):
         """In the kernel: ``(i, j, first, last, crossing, refs)`` of this
@@ -259,14 +430,42 @@ class _Walk(NamedTuple):
         return i, j, j == lo, j == hi, crossing, refs[n:]
 
 
-def _masked_or_not(crossing, tile: Callable[[bool], None]) -> None:
-    """Run ``tile(masked)``: masked on a pair the diagonal crosses, plain
-    on one under it."""
+_WHOLE = slice(None)
+
+
+class _Mask(NamedTuple):
+    """A tile's dead positions, as the kernels apply them: ``scores(s)``
+    is ``s`` with -1e30 there, ``probs(p, s)`` the forward's ``p`` with 0
+    there (a row with no live position at all would keep
+    ``exp(-1e30 - -1e30)``)."""
+    scores: Callable
+    probs: Callable
+
+
+def _masked_or_not(i, j, crossing, walk: _Walk,
+                   tile: Callable[[slice, slice, Optional[_Mask]], None]
+                   ) -> None:
+    """Run the pair's ``tile(rows, cols, mask)``: the whole tile unmasked
+    on an interior pair; on a crossed one the whole tile under
+    :func:`_causal_mask` or, where the walk has sub-tiles, the pieces of
+    the pair's kind, each under the mask of its edges."""
     if crossing is None:
-        tile(False)
+        tile(_WHOLE, _WHOLE, None)
         return
-    pl.when(crossing)(lambda: tile(True))
-    pl.when(jnp.logical_not(crossing))(lambda: tile(False))
+    kinds = walk.kinds()
+    if kinds:
+        kind = i * walk.block_q - j * walk.block_k
+        for k in kinds:
+            @pl.when(kind == k)
+            def _pieces(k=k):
+                for piece in walk.pieces(k):
+                    tile(piece.rows, piece.cols,
+                         _edge_mask(piece, walk.window))
+    else:
+        pl.when(crossing)(lambda: tile(_WHOLE, _WHOLE, _Mask(
+            lambda s: _causal_mask(s, i, j, walk),
+            lambda p, s: jnp.where(s > _NEG_INF / 2, p, 0.0))))
+    pl.when(jnp.logical_not(crossing))(lambda: tile(_WHOLE, _WHOLE, None))
 
 
 def _causal_mask(s, i, j, walk: _Walk):
@@ -276,6 +475,51 @@ def _causal_mask(s, i, j, walk: _Walk):
     if walk.window is not None:
         seen &= qpos - kpos < walk.window
     return jnp.where(seen, s, _NEG_INF)
+
+
+def _edge_mask(piece: _Piece, window: Optional[int]) -> _Mask:
+    """The mask of a piece of a crossed pair: built and applied on the
+    sub-tiles an edge passes through, and there by the edges that do."""
+
+    def on_edges(fn, x, *like):
+        # x with fn(corner, its sub-tile, like's) in each edge's place
+        cut = lambda a, lo, hi: a[:, lo:hi] if piece.axis else a[lo:hi]
+        parts, at = [], 0
+        for start, stop, corner in piece.edges:
+            if start > at:
+                parts.append(cut(x, at, start))
+            parts.append(fn(corner, *(cut(a, start, stop)
+                                      for a in (x,) + like)))
+            at = stop
+        if at < x.shape[piece.axis]:
+            parts.append(cut(x, at, x.shape[piece.axis]))
+        return (parts[0] if len(parts) == 1
+                else jnp.concatenate(parts, piece.axis))
+
+    def dead_scores(corner, s):
+        # qpos - kpos is corner + t
+        t = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+             - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+        seen = None
+        if corner - (s.shape[1] - 1) < 0:           # the diagonal is here
+            seen = t >= -corner
+        if window is not None and corner + s.shape[0] - 1 >= window:
+            far = t < window - corner               # the far edge is here
+            seen = far if seen is None else seen & far
+        return jnp.where(seen, s, _NEG_INF)
+
+    return _Mask(
+        lambda s: on_edges(dead_scores, s),
+        lambda p, s: on_edges(
+            lambda _, p, s: jnp.where(s > _NEG_INF / 2, p, 0.0), p, s))
+
+
+def _across(x, width: int):
+    """``x`` [rows, _LANES], a row's statistic in every lane, at ``width``
+    lanes."""
+    copies = -(-width // x.shape[1])
+    wide = x if copies == 1 else jnp.concatenate([x] * copies, axis=1)
+    return wide if wide.shape[1] == width else wide[:, :width]
 
 
 def _flash_kernel(*refs, walk: _Walk, scale: float, emit_lse: bool):
@@ -292,33 +536,34 @@ def _flash_kernel(*refs, walk: _Walk, scale: float, emit_lse: bool):
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def tile(masked: bool):
-        qb = q_ref[0]                                     # (bq, d)
-        kb = k_ref[0]                                     # (bk, d)
-        vb = v_ref[0]
+    def tile(rows, cols, mask):
+        qb = q_ref[0, rows]                               # (bq, d)
+        kb = k_ref[0, cols]                               # (bk, d)
+        vb = v_ref[0, cols]
         s = jax.lax.dot_general(
             qb, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (bq, bk)
-        if masked:
-            s = _causal_mask(s, i, j, walk)
-        m_prev = m_ref[...][:, :1]                        # (bq, 1)
-        l_prev = l_ref[...][:, :1]
+        if mask is not None:
+            s = mask.scores(s)
+        # a row's running max and denominator sit in every lane of their
+        # scratch and are combined there: at [rows, 1] a piece of a
+        # crossed pair paid more for them than its area saved
+        m_prev, l_prev = m_ref[rows], l_ref[rows]         # (bq, _LANES)
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_next = jnp.maximum(m_prev, m_cur)
         corr = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next)
-        if masked:
-            # rows whose every position is masked would get exp(-inf-(-inf))
-            p = jnp.where(s > _NEG_INF / 2, p, 0.0)
+        p = jnp.exp(s - _across(m_next, s.shape[1]))
+        if mask is not None:
+            p = mask.probs(p, s)
         l_next = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)           # (bq, d)
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = jnp.broadcast_to(m_next, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_next, l_ref.shape)
+        acc_ref[rows] = acc_ref[rows] * _across(corr, pv.shape[1]) + pv
+        m_ref[rows] = m_next
+        l_ref[rows] = l_next
 
-    _masked_or_not(crossing, tile)
+    _masked_or_not(i, j, crossing, walk, tile)
 
     @pl.when(last)
     def _emit():
@@ -346,20 +591,22 @@ def _heads(q, k, causal: bool) -> Tuple[int, int]:
 
 
 def _walk_of(q, k, causal: bool, block_q: int, block_k: int,
-             window: Optional[int]) -> Tuple[_Walk, int]:
+             window: Optional[int], sub: Optional[int]) -> Tuple[_Walk, int]:
     """(the forward's and dQ's walk, batch x key-value heads) of a call."""
     s = q.shape[2]
     block_q, block_k = _blocks(s, block_q, block_k)
     bkv, group = _heads(q, k, causal)
+    if not causal or _sub_blocks(block_q, block_k, sub) == (block_q, block_k):
+        sub = None      # no crossed pair, or none to cut: whole tiles
     return _Walk(causal, s, block_q, block_k, False,
-                 _band(s, window) if causal else None, group), bkv
+                 _band(s, window) if causal else None, group, sub), bkv
 
 
 def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
                    interpret: bool, with_lse: bool,
-                   window: Optional[int] = None):
+                   window: Optional[int] = None, sub: Optional[int] = None):
     b, h, s, d = q.shape
-    walk, bkv = _walk_of(q, k, causal, block_q, block_k, window)
+    walk, bkv = _walk_of(q, k, causal, block_q, block_k, window, sub)
     block_q, bh = walk.block_q, b * h
     qspec, kspec, lspec = walk.specs(d)
     oshape = jax.ShapeDtypeStruct((bh, s, d), q.dtype)
@@ -381,23 +628,26 @@ def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
     return (out, res[1]) if with_lse else (out, None)
 
 
-def _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, i, j, *,
-              scale: float, masked: bool, walk: _Walk):
-    """Shared backward recompute for ONE (q-block i, k-block j) tile:
-    returns (p, ds) with ds already scale-folded — the one definition of
-    the tile math, so the dQ and dK/dV kernels cannot desynchronize.
-    D_i = rowsum(dO * O) is recomputed per tile in VPU registers:
-    trivially cheap next to the three matmuls, and it saves materializing
-    a lane-padded delta array in HBM."""
-    qb, kb, vb, dob = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-    lse = lse_ref[0][:, :1]
-    delta = jnp.sum(dob.astype(jnp.float32) * o_ref[0].astype(jnp.float32),
+def _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, rows, cols,
+              mask: Optional[_Mask], *, scale: float):
+    """Shared backward recompute for ONE tile, ``rows`` of the q block
+    against ``cols`` of the k block (the whole pair, or a piece of a
+    crossed one): returns (p, ds) with ds already scale-folded, the one
+    definition of the tile math, so the dQ and dK/dV kernels cannot
+    desynchronize. D_i = rowsum(dO * O) is recomputed per tile in VPU
+    registers: trivially cheap next to the three matmuls, and it saves
+    materializing a lane-padded delta array in HBM."""
+    qb, kb, vb, dob = q_ref[0, rows], k_ref[0, cols], v_ref[0, cols], \
+        do_ref[0, rows]
+    lse = lse_ref[0, rows][:, :1]
+    delta = jnp.sum(dob.astype(jnp.float32)
+                    * o_ref[0, rows].astype(jnp.float32),
                     axis=-1, keepdims=True)
     s = jax.lax.dot_general(
         qb, kb, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale           # (bq, bk)
-    if masked:
-        s = _causal_mask(s, i, j, walk)
+    if mask is not None:
+        s = mask.scores(s)
     p = jnp.exp(s - lse)               # masked entries: exp(-inf-..) = 0
     dp = jax.lax.dot_general(
         dob, vb, (((1,), (1,)), ((), ())),
@@ -414,14 +664,14 @@ def _bwd_dq_kernel(*refs, walk: _Walk, scale: float):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def tile(masked: bool):
+    def tile(rows, cols, mask):
         _, ds = _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                          i, j, scale=scale, masked=masked, walk=walk)
-        acc_ref[...] += jax.lax.dot_general(
-            ds, k_ref[0], (((1,), (0,)), ((), ())),
+                          rows, cols, mask, scale=scale)
+        acc_ref[rows] += jax.lax.dot_general(
+            ds, k_ref[0, cols], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # (bq, d)
 
-    _masked_or_not(crossing, tile)
+    _masked_or_not(i, j, crossing, walk, tile)
 
     @pl.when(last)
     def _emit():
@@ -438,18 +688,18 @@ def _bwd_dkv_kernel(*refs, walk: _Walk, scale: float):
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def tile(masked: bool):
+    def tile(rows, cols, mask):
         p, ds = _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                          i, j, scale=scale, masked=masked, walk=walk)
-        dob = do_ref[0]
-        dv_acc[...] += jax.lax.dot_general(
+                          rows, cols, mask, scale=scale)
+        dob = do_ref[0, rows]
+        dv_acc[cols] += jax.lax.dot_general(
             p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # (bk, d)
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q_ref[0], (((0,), (0,)), ((), ())),
+        dk_acc[cols] += jax.lax.dot_general(
+            ds, q_ref[0, rows], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # (bk, d)
 
-    _masked_or_not(crossing, tile)
+    _masked_or_not(i, j, crossing, walk, tile)
 
     @pl.when(last)
     def _emit():
@@ -459,9 +709,9 @@ def _bwd_dkv_kernel(*refs, walk: _Walk, scale: float):
 
 def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
                     block_k: int, interpret: bool,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, sub: Optional[int] = None):
     b, h, s, d = q.shape
-    walk, bkv = _walk_of(q, k, causal, block_q, block_k, window)
+    walk, bkv = _walk_of(q, k, causal, block_q, block_k, window, sub)
     block_q, block_k, bh = walk.block_q, walk.block_k, b * h
     scale = 1.0 / (d ** 0.5)
     operands = (q.reshape(bh, s, d), k.reshape(bkv, s, d),
@@ -497,7 +747,6 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
             else interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: int = 128, block_k: int = 128,
                     interpret: Optional[bool] = None,
@@ -508,23 +757,35 @@ def flash_attention(q, k, v, causal: bool = False,
     ``h // (H / Hkv)``, and dK and dV come out at k's shape. ``window``
     (a causal call's): position ``i`` sees ``j`` only where ``i - j <
     window``. ``interpret=None`` auto-selects interpreter mode off-TPU
-    (tests); pass False to force the compiled path.
+    (tests); pass False to force the compiled path. A causal call's
+    crossed pairs are cut into the sub-tiles :func:`sub_tile` gives the
+    blocks and the head size.
     """
+    return _attention(q, k, v, causal, block_q, block_k, interpret, window,
+                      sub_tile(*_blocks(q.shape[2], block_q, block_k),
+                               q.shape[3]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _attention(q, k, v, causal, block_q, block_k, interpret, window, sub):
+    """:func:`flash_attention` at a given ``sub`` (``None``: whole tiles)."""
     out, _ = _flash_forward(q, k, v, causal, block_q, block_k,
-                            _resolve_interpret(interpret), False, window)
+                            _resolve_interpret(interpret), False, window,
+                            sub)
     return out
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret, window):
+def _fwd(q, k, v, causal, block_q, block_k, interpret, window, sub):
     out, lse = _flash_forward(q, k, v, causal, block_q, block_k,
-                              _resolve_interpret(interpret), True, window)
+                              _resolve_interpret(interpret), True, window,
+                              sub)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, block_q, block_k, interpret, window, res, g):
+def _bwd(causal, block_q, block_k, interpret, window, sub, res, g):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
-                           _resolve_interpret(interpret), window)
+                           _resolve_interpret(interpret), window, sub)
 
 
-flash_attention.defvjp(_fwd, _bwd)
+_attention.defvjp(_fwd, _bwd)

@@ -302,6 +302,12 @@ def test_one_step_through_the_adam_tables_is_reference_gradient_plus_adam():
     # middle block unmasked
     assert (args["attn_pairs_live_window"], args["attn_pairs_masked_window"],
             args["attn_pairs_causal_window"]) == (21, 14, 36)
+    # whole tiles of 8 x 8 at these sizes, against the positions under the
+    # diagonal (64 * 65 / 2) and in a band of 16 (16 * 17 / 2 + 48 * 16)
+    assert (args["attn_positions_computed"], args["attn_positions_needed"],
+            args["attn_positions_computed_window"],
+            args["attn_positions_needed_window"]) == (36 * 64, 2080,
+                                                      21 * 64, 904)
     assert args["routed_rows"] == 2 * 2 * 64 * cfg.top_k
 
 
@@ -345,6 +351,14 @@ def test_the_cells_walk_is_45_of_136_pairs_its_first_full_block_unmasked():
     by_offset = {d: set(crossing[qi - kj == d].tolist()) for d in (0, 1, 2)}
     assert by_offset == {0: {True}, 1: {False}, 2: {True}}
     assert band["masked"] == 16 + 14
+    # the span's counts at the cell's sizes: the crossed pairs cut into
+    # sub-tiles of 256 compute 1.125 of the band and 1.016 of the triangle
+    grid = mla_moe.attn_grid(cfg._replace(attn="flash"), 16384)
+    assert (grid["attn_positions_computed_window"],
+            grid["attn_positions_needed_window"],
+            grid["attn_positions_computed"],
+            grid["attn_positions_needed"]) == (
+                35_389_440, 31_458_304, 136_314_880, 134_225_920)
 
 
 def test_published_sizes_give_the_configurations_parameter_count():

@@ -92,6 +92,25 @@ def test_lm_stage():
     assert "(1, 2, 64, 16)/(16, 32)" in facts["kernel_rel_err"]
 
 
+def test_flash_stage():
+    """Whole tiles against sub-tiles of 16 and 8 under blocks of 32 and
+    32 x 64, a band and a plain diagonal, in the interpreter."""
+    facts = chip_smoke.stage_flash(
+        calls=(("band", (1, 4, 128, 16), 2, (32, 32), 40),
+               ("causal", (1, 2, 128, 16), 2, (32, 64), None)),
+        subs=(16, 8), repeats=1)
+    assert set(facts) == {"band", "causal"}
+    for call in facts.values():
+        assert call["sub_tile"] is None         # the rule leaves 32s whole
+        for tag in ("sub0", "sub16", "sub8"):
+            for kernel in ("fwd", "dq", "dkv"):
+                assert call[f"{tag}_{kernel}_ms"] > 0
+                assert call[f"{tag}_{kernel}_compile_s"] >= 0
+            assert max(call[f"{tag}_rel_err"]) <= chip_smoke.ATTN_BF16_TOL
+        for tag in ("sub16", "sub8"):
+            assert max(call[f"{tag}_vs_whole"]) <= 1e-2     # bfloat16 results
+
+
 def test_main_refuses_a_cpu(capsys):
     """No TPU: non-zero exit, the reason on stderr, no result on stdout."""
     assert chip_smoke.main() == chip_smoke.EXIT_NO_CHIP

@@ -280,6 +280,11 @@ def test_the_step_span_carries_the_balance_term_and_the_layer_kinds():
     assert (args["attn_pairs_live_window"], args["attn_pairs_masked_window"],
             args["attn_pairs_causal_window"]) == (7, 7, 10)
     assert args["attn_pairs_live"] == 10 and args["attn_pairs_masked"] == 4
+    # whole tiles at these sizes: the positions computed are the pairs',
+    # the needed ones the triangle's (64 * 65 / 2) and the band's
+    assert (args["attn_positions_computed"], args["attn_positions_needed"],
+            args["attn_positions_computed_window"],
+            args["attn_positions_needed_window"]) == (2560, 2080, 1792, 904)
     assert 0.015 < args["aux_loss"] < 0.04      # 0.01 x two terms near 1
 
 
@@ -350,6 +355,14 @@ def test_published_sizes_give_the_issues_parameter_count():
     held = mla_moe.held(cfg, 16384)
     assert held.buffer_rows == 65536 and held.tile == (512, 768, 896)
     assert mla_moe.attn_blocks(cfg, 8192) == (1024, 1024)
+    # crossed pairs in sub-tiles of 256: 1.25 of the band, 1.031 of the
+    # triangle, where whole tiles computed 2.00 and 1.125
+    grid = mla_moe.attn_grid(cfg._replace(attn="flash"), 8192)
+    assert (grid["attn_positions_computed_window"],
+            grid["attn_positions_needed_window"],
+            grid["attn_positions_computed"],
+            grid["attn_positions_needed"]) == (
+                9_830_400, 7_864_832, 34_603_008, 33_558_528)
     # every width is the source's
     with open("/opt/skills/guides/model-configs/architectures.jsonl") as f:
         row = next(r for r in map(json.loads, f)

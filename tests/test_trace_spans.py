@@ -810,10 +810,19 @@ def test_lm_step_carries_the_flash_kernels_grid_counts():
     trainer.adopt()
     steps = [e for e in ttrace.events()[before:] if e["name"] == "lm.step"]
     assert len(steps) == 2          # the step queued, and the drain
+    # blocks this small stay whole tiles: three pairs of 16 x 16 computed
+    # for the 32 * 33 / 2 positions under the diagonal
     grid = {"attn_grid_steps": 3, "attn_pairs_live": 3,
-            "attn_pairs_masked": 2}
+            "attn_pairs_masked": 2, "attn_positions_computed": 768,
+            "attn_positions_needed": 528}
     for e in steps:
         assert {k: e["args"][k] for k in grid} == grid
+    assert "attn_positions_needed_window" not in steps[0]["args"]
+    from tools import dump_metrics
+    assert dump_metrics._attention_lines(steps)[1:] == [
+        "    causal  768  528  1.455"]
+    assert dump_metrics._attention_lines([{"name": "lm.step", "args": {}}]
+                                         ) == []
     assert "routed_rows" not in steps[0]["args"]     # nothing read back yet
     assert mla_moe.attn_grid(cfg._replace(attn="xla"), 32) == {}
 

@@ -680,7 +680,8 @@ def format_timeline(events: List[Dict]) -> str:
     out.append(f"  longest runs (median {median:.3f} ms):")
     out += [f"    {r['run_ms']:12.3f} ms  {r['name']}  request={r['request']}"
             for r in runs[:5]]
-    return "\n".join(out + _table_write_lines(events))
+    return "\n".join(out + _table_write_lines(events)
+                     + _attention_lines(events))
 
 
 def _table_write_lines(events: List[Dict]) -> List[str]:
@@ -704,6 +705,26 @@ def _table_write_lines(events: List[Dict]) -> List[str]:
             f"{a['update_rows']}  {a['unique_rows']} "
             f"({100.0 * a['unique_rows'] / max(a['update_rows'], 1):.1f}%)  "
             f"{a['head_rows']}  {walk}")
+    return out
+
+
+def _attention_lines(events: List[Dict]) -> List[str]:
+    """What one flash kernel call computes beside what it needs, from the
+    static counts on ``lm.step`` (``models/mla_moe.attn_grid``): positions
+    a (batch x head) of a forward call, causal and, where a layer is of
+    the window kind, banded."""
+    args = next((e["args"] for e in events if e.get("name") == "lm.step"
+                 and "attn_positions_needed" in e.get("args", {})), None)
+    if args is None:
+        return []
+    out = ["  attention positions a call (computed, needed, computed / "
+           "needed):"]
+    for walk, tail in (("causal", ""), ("window", "_window")):
+        needed = args.get("attn_positions_needed" + tail)
+        if needed:
+            computed = args["attn_positions_computed" + tail]
+            out.append(f"    {walk:6s}  {computed}  {needed}  "
+                       f"{computed / needed:.3f}")
     return out
 
 
