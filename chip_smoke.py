@@ -42,6 +42,7 @@ import tempfile
 import time
 import traceback
 from typing import Any, Callable, Dict, List, Tuple
+from unittest import mock
 
 import numpy as np
 
@@ -367,7 +368,9 @@ def stage_rows(rows_per_shard: int = 3_000_001, width: int = 300,
 
 def block_writes(bucket: int = 2 ** 19, width: int = 300, batch: int = 8192,
                  negative: int = 5, minibatches: int = 16,
-                 vocab: int = 1_800_000) -> Dict[str, Any]:
+                 vocab: int = 1_800_000,
+                 walk_rows: Tuple[int, ...] = (1000, 4500, 8192)
+                 ) -> Dict[str, Any]:
     """What a PS block's scan pays for its table writes
     (``models/word2vec.skipgram_ns_step`` under ``we-psblock``, PERF.md
     PR 40), into the block's local table ``f32[bucket + 1, width]`` on one
@@ -382,7 +385,14 @@ def block_writes(bucket: int = 2 ** 19, width: int = 300, batch: int = 8192,
     of ``batch`` at a time, which is what the step does; the input
     table's ``batch`` beside them; the plans and the sums
     (``combine_rows``) alone, so that what is left is the head's add and
-    the walk."""
+    the walk. Then the walk's two prices (PERF.md, PR 45; ``walks``): into
+    the bucket as the block's scan carries it, ``f32[bucket + 1, width in
+    whole lanes]``, ``add_rows`` of ``batch`` update rows whose distinct
+    rows are ``walk_rows`` of the block's rows past the head, one write a
+    minibatch and six in a ``lax.scan`` as the step's columns are, by
+    XLA's scatter and by the tile kernel where ``row_combine.tile_walk``
+    takes it (on a TPU), held to each other bit for bit; us a row is a
+    call's ms over a call's with every row in the head."""
     import jax
     import jax.numpy as jnp
 
@@ -416,13 +426,14 @@ def block_writes(bucket: int = 2 ** 19, width: int = 300, batch: int = 8192,
         "table": f"f32[{rows},{width}]", "minibatches": minibatches,
         "update_rows": [batch, cols * batch]}
 
-    def timed(name, fn, *args, donate=False):
+    def timed(name, fn, *args, donate=False, into=out):
         """Compile ``fn``, run it three times on a fresh copy of what it
-        donates; the last result."""
+        donates; the last result. Its ms a minibatch and compile seconds
+        go ``into`` the stage's facts."""
         t0 = time.perf_counter()
         compiled = jax.jit(fn, donate_argnums=(0,) if donate else ()).lower(
             *args).compile()
-        out[f"{name}_compile_s"] = round(time.perf_counter() - t0, 2)
+        into[f"{name}_compile_s"] = round(time.perf_counter() - t0, 2)
         best, got = float("inf"), None
         for _ in range(3):
             first = jnp.copy(args[0]) if donate else args[0]
@@ -430,7 +441,7 @@ def block_writes(bucket: int = 2 ** 19, width: int = 300, batch: int = 8192,
             t0 = time.perf_counter()
             got = jax.block_until_ready(compiled(first, *args[1:]))
             best = min(best, time.perf_counter() - t0)
-        out[f"{name}_ms"] = round(best / minibatches * 1e3, 4)
+        into[f"{name}_ms"] = round(best / minibatches * 1e3, 4)
         return got
 
     def du(gm, vm):                     # [B, cols, width], as the step's
@@ -486,6 +497,65 @@ def block_writes(bucket: int = 2 ** 19, width: int = 300, batch: int = 8192,
 
     timed("sums_one_write", sums(flat_du), grad, v, plans["one_write"])
     timed("sums_centers", sums(lambda vm: vm), v, plans["centers"])
+    del table, v, grad, got, want
+
+    # the walk alone, XLA's and the kernel's, on the scan's own bucket
+    wide = row_combine.lane_wide(width)
+    table = jax.random.uniform(jax.random.key(SEED), (rows, wide),
+                               jnp.float32, -0.5, 0.5)
+    updates = jax.random.uniform(jax.random.key(SEED + 3),
+                                 (cols, batch, wide), jnp.float32, -.01, .01)
+    past = np.unique(local[local >= row_combine.HEAD])
+    kernel = row_combine.tile_walk(table)
+    walks: Dict[str, Any] = {"table": f"f32[{rows},{wide}]",
+                             "kernel": kernel is not None}
+    for n in (0,) + tuple(min(n, batch, past.size) for n in walk_rows):
+        named = [rng.choice(past, n, replace=False) if n else
+                 rng.integers(0, min(row_combine.HEAD, rows), batch)
+                 for _ in range(minibatches * cols)]
+        ids = jnp.asarray(np.stack([
+            rng.permutation(np.concatenate([d, rng.choice(d, batch - n)]))
+            if n else d for d in named]).reshape(
+                minibatches, cols, batch).astype(np.int32))
+        p = jax.jit(plan)(ids)
+        forms = {       # the updates an argument: closed over, 75 MB of
+            # constant in the program and 7 s of compile
+            "alone": lambda tb, i, p, u: jax.lax.scan(
+                lambda t, x: (row_combine.add_rows(
+                    t, x[0][0], u[0],
+                    jax.tree.map(lambda a: a[0], x[1])), None),
+                tb, (i, p))[0],
+            "six": lambda tb, i, p, u: jax.lax.scan(
+                lambda t, x: (jax.lax.scan(
+                    lambda t, col: (row_combine.add_rows(t, *col), None),
+                    t, (x[0], u, x[1]))[0], None), tb, (i, p))[0]}
+        held: Dict[str, Any] = {}
+        for form, fn in forms.items():
+            # a function of its own a program: jit keeps a function's
+            # trace, and with it the walk it was traced with
+            with mock.patch.object(row_combine, "_kernel_interpret",
+                                   lambda: None):
+                want = timed(f"xla_{form}", lambda *a: fn(*a), table, ids,
+                             p, updates, donate=True, into=held)
+            if kernel is None:
+                continue
+            got = timed(f"kernel_{form}", lambda *a: fn(*a), table, ids, p,
+                        updates, donate=True, into=held)
+            if not jnp.array_equal(
+                    jax.lax.bitcast_convert_type(got, jnp.uint32),
+                    jax.lax.bitcast_convert_type(want, jnp.uint32)):
+                raise AssertionError(
+                    f"the tile kernel's table differs from XLA's walk's "
+                    f"({n} rows, {form})")
+            del got
+        for k in [k for k in held if k.endswith("six_ms")]:
+            held[k] = round(held[k] / cols, 4)      # ms a call
+        if n:
+            for k in [k for k in held if k.endswith("_ms")]:
+                held[k.replace("_ms", "_us_a_row")] = round(
+                    (held[k] - walks["0"][k]) * 1e3 / n, 4)
+        walks[str(n)] = held
+    out["walks"] = walks
     return out
 
 

@@ -604,6 +604,10 @@ class WordEmbedding:
                 call.set(update_rows=2 * epochs * int(pairs),
                          unique_rows=int(unique), head_rows=int(head),
                          walk_slots_by_shard=[int(n) for n in walk],
+                         kernel_rows=row_combine.kernel_rows(
+                             jax.ShapeDtypeStruct(t_in.padded_shape,
+                                                  t_in.dtype),
+                             t_in.format.sharding, unique, head),
                          gather_rounds=int(rounds))
             with _trace.span("we.fused.count"):
                 dt = time.perf_counter() - t0
@@ -786,8 +790,13 @@ class WordEmbedding:
                           if c is not None]
             if row_counts:
                 update, unique, head, walk, _ = np.sum(row_counts, axis=0)
+                bucket = jax.ShapeDtypeStruct(
+                    (row_combine.TILE, row_combine.lane_wide(cfg.size)),
+                    self._compute_dtype() or self.table_in.dtype)
                 call.set(update_rows=int(update), unique_rows=int(unique),
-                         head_rows=int(head), walk_slots=int(walk))
+                         head_rows=int(head), walk_slots=int(walk),
+                         kernel_rows=row_combine.kernel_rows(
+                             bucket, None, unique, head))
             # drain in-flight async pushes so the trained state is durable
             # before the caller reads embeddings (sync tables order by
             # program order; async tables need the explicit flush)
@@ -1153,15 +1162,21 @@ class WordEmbedding:
         """THE block-train scan, traced inside both planes' jits: pulled
         rows in, (new - old) deltas + mean loss out. ``plans`` is
         :meth:`_block_ahead`'s, a minibatch's slice of which goes to its
-        step. Deltas are measured against the SAME baseline the scan
-        started from — in bf16 mode the rounded rows — so a
-        pulled-but-untrained row gets an exactly-zero delta."""
+        step. The scan runs on buckets of whole lanes (``dummy``) and the
+        deltas are their first columns. Deltas are measured against the
+        SAME baseline the scan started from — in bf16 mode the rounded
+        rows — so a pulled-but-untrained row gets an exactly-zero
+        delta."""
         cdtype = self._compute_dtype()
 
-        def dummy(r):   # padded slots train against this extra row
+        def dummy(r):
+            # padded slots train against an extra row; in the same pass
+            # the bucket is made whole lanes wide with zero columns, which
+            # every step leaves zero, so that its walks take the tile
+            # kernel (row_combine.tile_walk)
             r = r.astype(cdtype) if cdtype is not None else r
-            return jnp.concatenate(
-                [r, jnp.zeros((1, r.shape[1]), r.dtype)])
+            return jnp.pad(r, ((0, 1), (
+                0, row_combine.lane_wide(r.shape[1]) - r.shape[1])))
 
         def body(carry, xs):
             ri, rs = carry
@@ -1182,8 +1197,10 @@ class WordEmbedding:
                 return old
             return old.astype(cdtype).astype(old.dtype)
 
-        d_in = ri[:-1].astype(rows_in.dtype) - base(rows_in)
-        d_sec = rs[:-1].astype(rows_sec.dtype) - base(rows_sec)
+        d_in = ri[:-1, :rows_in.shape[1]].astype(
+            rows_in.dtype) - base(rows_in)
+        d_sec = rs[:-1, :rows_sec.shape[1]].astype(
+            rows_sec.dtype) - base(rows_sec)
         return d_in, d_sec, loss
 
     def _local_train_fn(self):

@@ -23,7 +23,11 @@ row than the scatter into the table.
   scatter, a chunk of slots at a time from the first id past the head up
   to the last distinct row. Pad slots of the last chunk are dropped by the
   scatter (``mode="drop"``): they write nothing, so ``unique_indices`` is
-  a true promise.
+  a true promise. Where the table is float32, whole lanes wide (a width
+  that is a multiple of 128) and not row-sharded, on a TPU, the walk is
+  not XLA's scatter but a Pallas read-modify-write of 8-row tiles that
+  pays by the row (:func:`tile_walk`, :func:`_walk_tiles`; PERF.md,
+  PR 45): the array decides, no caller says which.
 * :func:`take_rows` — ``jnp.take`` of the ids' rows, which on a
   row-sharded table every shard makes of its own rows alone (below).
 
@@ -91,11 +95,15 @@ slots) and 1.341 (a walk of the head's slots).
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import NamedSharding, PartitionSpec
 
 # Slots a table scatter takes at a time: the scatter's cost follows the
@@ -109,6 +117,16 @@ CHUNK = 256
 # scatter slot (the whole table where it has no more rows than this).
 HEAD = 8192
 _NONE = 2 ** 31 - 1      # a head row without a run: past any buffer of sums
+
+# A float32 array on the chip is tiles of 8 rows by 128 lanes. The tile
+# kernel (:func:`_walk_tiles`) reaches a row through its aligned 8-row tile,
+# and this Mosaic slices an HBM array only where its width is whole lanes.
+LANES, TILE = 128, 8
+# The kernel takes the tiles a call names in rounds of ROUND, each in a
+# bank of ROUND buffers of a ring of BANKS banks, and starts a round's
+# reads ROUNDS_AHEAD rounds before it adds to them.
+ROUND, BANKS, ROUNDS_AHEAD = 8, 4, 2
+LISTED = 8      # slots the kernel's listing of the tiles takes in a line
 
 
 class RowPlan(NamedTuple):
@@ -361,6 +379,242 @@ def take_rows(table: jax.Array, ids: jax.Array,
             _rounds(plan.end - begin, cap))
 
 
+def lane_wide(width: int) -> int:
+    """``width`` rounded up to whole lanes: the width at which an array
+    of a program's own (a PS block's bucket) meets :func:`tile_walk`. A
+    float32 row of 300 occupies 384 lanes on the chip either way."""
+    return -(-width // LANES) * LANES
+
+
+def _kernel_interpret() -> Optional[bool]:
+    """How the tile kernel runs here: compiled on a TPU, and off it not
+    at all (``None``: XLA's walk). A test that drives the kernel in the
+    interpreter, or compiles it for a described chip, patches this."""
+    return False if jax.devices()[0].platform == "tpu" else None
+
+
+def tile_walk(table, axis=None) -> Optional[bool]:
+    """Whether :func:`add_rows` walks ``table`` (anything with a shape
+    and a dtype; ``axis`` the mesh axis its rows are sharded over) with
+    the tile kernel, by what the array is: float32, whole lanes wide,
+    not row-sharded, on a TPU. ``None`` says XLA's scatter walks it;
+    else the kernel's ``interpret``."""
+    if (axis is not None or table.dtype != jnp.float32
+            or len(table.shape) != 2 or table.shape[1] % LANES):
+        return None
+    return _kernel_interpret()
+
+
+def kernel_rows(table, sharding, unique_rows, head_rows) -> int:
+    """Of the counts of plans whose rows :func:`add_rows` wrote into
+    ``table`` (:func:`plan_counts`' first two), the rows the tile kernel
+    was handed: every distinct row past the heads where
+    :func:`tile_walk` says it walks, none where XLA's scatter does."""
+    walks = tile_walk(table, row_shards(sharding)[0]) is not None
+    return int(unique_rows - head_rows) if walks else 0
+
+
+def _walk_tiles(tab: jax.Array, uniq: jax.Array, sums: jax.Array,
+                start: jax.Array, end: jax.Array,
+                interpret: bool) -> jax.Array:
+    """The walk as a Pallas kernel: ``tab[uniq[j]] += sums[j]`` for the
+    slots ``start <= j < end`` of ascending distinct in-range row ids,
+    ``tab`` ``f32[R, W]`` whole lanes wide, left in HBM and written in
+    place. XLA's scatter costs 0.100 us a slot on a v5e whatever the slot
+    holds; this pays by the tile a row lies in, 0.05 to 0.075 us a row
+    (PERF.md, PR 45).
+
+    A row is reached through its aligned 8-row tile (Mosaic slices a tiled
+    HBM array by whole tiles): the tile is read into a VMEM buffer, the
+    sums of the rows of ``uniq`` that fall in it are added, each to its
+    line, and the tile is written back. Ids ascend, so a tile's rows are
+    neighbours in ``uniq``: a tile is read once and written once however
+    many of its rows are named, no two DMAs of a call touch the same
+    tile, and every bit of a line that is not named comes back as it was
+    read. The trip count is the data's, so the loops are inside the
+    kernel. A first pass over the slots, scalars only, lists the tiles
+    (the id of each tile's first row and its slot). Then the tiles go by
+    in rounds of ``ROUND``, each round in its own bank of buffers,
+    ``BANKS`` banks in a ring: a round waits for its reads, adds, starts
+    its writes, and starts the reads of the round ``ROUNDS_AHEAD`` on,
+    into the bank whose writes (``BANKS`` rounds back) it first waits
+    for. What the scalar core pays for is control: a DMA started or
+    waited for behind a branch of its own cost 0.03 us, five times its
+    issue, so a round whose tiles and read-ahead are all there is ONE
+    straight line of ``ROUND`` unrolled tiles (buffers at fixed offsets
+    of the bank) and only the first and last rounds of a call take the
+    loops with their counts. The kernel ends when every write has landed.
+
+    Where ``R`` is no multiple of 8 the last rows are no whole tile (a
+    PS block's dummy row is one such): they take one dense add of their
+    own outside the kernel, as the head's rows do."""
+    rows, width = tab.shape
+    slots, whole = uniq.shape[0], rows // TILE * TILE
+    if rows > whole:
+        edge = jnp.arange(whole, rows + 1, dtype=jnp.int32)
+        # where each edge row (and the first id past them) falls in uniq
+        at = jnp.sum(uniq[None, :] < edge[:, None], axis=1, dtype=jnp.int32)
+        named = ((at[:-1] >= start) & (at[:-1] < end)
+                 & (jnp.take(uniq, at[:-1]) == edge[:-1]))
+        tab = tab.at[whole:].add(jnp.where(
+            named[:, None], jnp.take(sums, at[:-1], axis=0), -0.0))
+        end = jnp.clip(at[0], start, end)
+        if not whole:
+            return tab
+
+    traced = _tile_kernel(rows, width, slots, interpret, ROUND, BANKS,
+                          ROUNDS_AHEAD, LISTED)
+    return jax.core.eval_jaxpr(
+        traced.jaxpr, traced.consts,
+        jnp.stack([start, end]).astype(jnp.int32), uniq, sums, tab)[0]
+
+
+@functools.lru_cache(maxsize=64)
+def _tile_kernel(rows: int, width: int, slots: int, interpret,
+                 ROUND: int, BANKS: int, ROUNDS_AHEAD: int, LISTED: int):
+    """:func:`_walk_tiles`' kernel call on ``(bounds, uniq, sums, tab)``,
+    traced once for its shapes and bound again from its jaxpr: a block
+    program calls it for the centres and in the columns' loop, and
+    tracing the kernel body (then lowering it, which equations that carry
+    one jaxpr share) is seconds of Python on a busy host, in a program
+    that compiles on every run."""
+
+    # Scalars here are lax's own primitives on int32: a ``//``, ``%``,
+    # ``where`` or ``clip`` of jax.numpy is a function of several
+    # operations traced and lowered as a call, which a kernel that every
+    # process traces pays for in set-up (all operands are non-negative,
+    # so ``lax.div`` and ``lax.rem`` are the floor's).
+    tile, round_, banks = (np.int32(n) for n in (TILE, ROUND, BANKS))
+
+    def kernel(bounds, uniq, sums, _, tab, buf, landed, written, lead, lo):
+        start, end = bounds[0], bounds[1]
+
+        def note(j, carry):
+            """Slot ``j`` into the list of tiles: ``lead[k]`` the first
+            row id of tile ``k``, ``lo[k]`` its slot; no branch."""
+            tiles, last, slot, row = carry
+            of = lax.div(uniq[j], tile)
+            first = of != last
+            tiles = tiles + lax.convert_element_type(first, jnp.int32)
+            slot, row = lax.select(first, j, slot), lax.select(
+                first, uniq[j], row)
+            lead[tiles], lo[tiles] = row, slot
+            return tiles, of, slot, row
+
+        def note_some(c, carry):    # LISTED slots in a straight line
+            return lax.fori_loop(
+                0, LISTED, lambda j, carry: note(start + c * LISTED + j,
+                                                 carry), carry, unroll=True)
+
+        lines = lax.div(end - start, np.int32(LISTED))
+        carry = lax.fori_loop(0, lines, note_some,
+                              (np.int32(-1), np.int32(-1), start, start))
+        tiles = lax.fori_loop(start + lines * LISTED, end, note,
+                              carry)[0] + 1
+        lo[tiles] = end
+
+        def count(r):       # tiles of round r: ROUND, fewer, none
+            return lax.select(r >= 0, lax.clamp(
+                np.int32(0), tiles - r * ROUND, round_), np.int32(0))
+
+        def bank(r):        # the first buffer of round r's bank
+            return lax.rem(r, banks) * ROUND
+
+        def tile_of(row):
+            return tab.at[pl.ds(pl.multiple_of(
+                row - lax.rem(row, tile), TILE), TILE)]
+
+        def read(row, b):   # the copies are made again to be waited for
+            return pltpu.make_async_copy(tile_of(row), buf.at[b],
+                                         landed.at[b])
+
+        def write(row, b):
+            return pltpu.make_async_copy(buf.at[b], tile_of(row),
+                                         written.at[b])
+
+        def add(j, b):
+            line = pl.ds(lax.rem(uniq[j], tile), 1)
+            buf[b, line, :] = buf[b, line, :] + sums[pl.ds(j, 1), :]
+
+        def add_from(skip, k, b):   # tile k's rows from its skip-th on
+            lax.fori_loop(lo[k] + skip, lo[k + 1],
+                          lambda j, _: add(j, b), None)
+
+        def each(n, fn):    # fn(0) .. fn(n - 1), n the data's
+            lax.fori_loop(0, n, lambda s, _: fn(s), None)
+
+        def a_round(i, _):
+            b, k, on = bank(i), i * ROUND, i + ROUNDS_AHEAD
+            b_on, k_on = bank(on), on * ROUND
+            straight = (on + 1) * ROUND <= tiles
+
+            @pl.when(straight)
+            def _():
+                # traced once and unrolled as it is lowered
+                def line(fn):
+                    lax.fori_loop(0, ROUND, lambda s, _: fn(s), None,
+                                  unroll=True)
+                line(lambda s: read(0, b + s).wait())
+                line(lambda s: add(lo[k + s], b + s))
+
+                @pl.when(lo[k + ROUND] - lo[k] > ROUND)
+                def _():
+                    each(ROUND, lambda s: add_from(1, k + s, b + s))
+                line(lambda s: write(lead[k + s], b + s).start())
+
+                @pl.when(on >= BANKS)
+                def _():
+                    line(lambda s: write(0, b_on + s).wait())
+                line(lambda s: read(lead[k_on + s], b_on + s).start())
+
+            @pl.when(lax.bitwise_not(straight))
+            def _():
+                each(count(i), lambda s: read(0, b + s).wait())
+                each(count(i), lambda s: add_from(0, k + s, b + s))
+                each(count(i), lambda s: write(lead[k + s], b + s).start())
+                reads(on)
+
+        def reads(r):
+            """Round ``r``'s reads, once the writes of the round that
+            had its bank before have landed."""
+            b, k = bank(r), r * ROUND
+            each(count(r - BANKS), lambda s: write(0, b + s).wait())
+            each(count(r), lambda s: read(lead[k + s], b + s).start())
+
+        rounds = lax.div(tiles + (ROUND - 1), round_)
+        for r in range(ROUNDS_AHEAD):
+            reads(np.int32(r))
+        lax.fori_loop(0, rounds, a_round, None)
+        # round r's writes are waited for by round r + BANKS - ROUNDS_AHEAD
+        # as it reads ahead: those of the last rounds by nobody yet
+        for r in range(1, BANKS - ROUNDS_AHEAD + 1):
+            each(count(rounds - r), lambda s, r=r: write(
+                0, bank(rounds - r) + s).wait())
+
+    call = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((rows, width), jnp.float32),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec(memory_space=pltpu.VMEM),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.VMEM((BANKS * ROUND, TILE, width), jnp.float32),
+                        pltpu.SemaphoreType.DMA((BANKS * ROUND,)),
+                        pltpu.SemaphoreType.DMA((BANKS * ROUND,)),
+                        pltpu.SMEM((slots + 1,), jnp.int32),
+                        pltpu.SMEM((slots + 1,), jnp.int32)],
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            # the sums are held whole: 13 MB at 8,448 rows of 384
+            vmem_limit_bytes=slots * width * 4 + (16 << 20)),
+        name="row_walk_tiles", interpret=interpret)
+    ints = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)    # noqa: E731
+    return jax.make_jaxpr(call)(
+        ints(2), ints(slots),
+        jax.ShapeDtypeStruct((slots, width), jnp.float32),
+        jax.ShapeDtypeStruct((rows, width), jnp.float32))
+
+
 def add_rows(table: jax.Array, ids: jax.Array, updates: jax.Array,
              plan: Optional[RowPlan] = None, sharding=None) -> jax.Array:
     """``table.at[ids].add(updates)`` for ids ``[B]``, updates ``[B, D]``,
@@ -398,6 +652,9 @@ def add_rows(table: jax.Array, ids: jax.Array, updates: jax.Array,
             sums, lax.dynamic_slice_in_dim(head_run, shard * part, part),
             axis=0, mode="fill", fill_value=-0.0))
         start, first_row = head[shard], shard * tab.shape[0]
+        interpret = tile_walk(tab, axis)
+        if interpret is not None:
+            return _walk_tiles(tab, uniq, sums, start, end[shard], interpret)
 
         def walk(i, tab):
             at = start + i * chunk

@@ -53,12 +53,27 @@ def test_rows_stage():
     assert facts["block"]["update_rows"] == [64, 3 * 64]
 
 
-def test_rows_stage_block_writes(monkeypatch):
+@pytest.mark.parametrize("kernel", [False, True])
+def test_rows_stage_block_writes(kernel, monkeypatch):
     from multiverso_tpu.ops import row_combine
     monkeypatch.setattr(row_combine, "HEAD", 32)    # leave the walk rows
     monkeypatch.setattr(row_combine, "CHUNK", 16)
+    if kernel:      # the chip's walk of a lane-wide bucket, interpreted
+        monkeypatch.setattr(row_combine, "_kernel_interpret", lambda: True)
     facts = chip_smoke.block_writes(bucket=512, width=8, batch=64,
-                                    negative=2, minibatches=3, vocab=2000)
+                                    negative=2, minibatches=3, vocab=2000,
+                                    walk_rows=(20, 64))
+    # ISSUE 45: the walk's two prices on the scan's own bucket, the
+    # kernel's held bit for bit to XLA's inside the stage
+    walks = facts["walks"]
+    assert (walks["table"], walks["kernel"]) == ("f32[513,128]", kernel)
+    assert set(walks) == {"table", "kernel", "0", "20", "64"}
+    for n in ("0", "20", "64"):
+        for how in ("xla", "kernel") if kernel else ("xla",):
+            for form in ("alone", "six"):
+                assert walks[n][f"{how}_{form}_ms"] > 0, (n, how, form)
+                assert (f"{how}_{form}_us_a_row" in walks[n]) == (n != "0")
+        assert ("kernel_six_ms" in walks[n]) == kernel
     assert facts["table"] == "f32[513,8]"
     one, cols = facts["one_write_rows"], facts["columns_rows"]
     # a column at a time merges no repeat across columns, and every

@@ -9,7 +9,10 @@ are dealt round the shards. ISSUE 38: a row-sharded table is read by the
 shards that own the rows (``take_rows``), which is ``jnp.take`` bit for
 bit whatever the ids. ISSUE 40: the same at a PS block's sizes (a bucket
 of 2^19 rows and the dummy row, 8,192 and 6 x 8,192 update rows), and at
-8,192 update rows the program ``add_rows`` lowers to is the one it was."""
+8,192 update rows the program ``add_rows`` lowers to is the one it was.
+ISSUE 45: where the table is float32 and whole lanes wide the walk is a
+Pallas tile read-modify-write, bit for bit XLA's walk; at width 300 and on
+row shards the jaxpr is the parent's."""
 
 import re
 
@@ -158,6 +161,35 @@ def test_add_rows_at_8192_update_rows_lowers_to_the_program_it_was(
         assert [o for o in ops if o.endswith(wide)] == [
             w for w in writes if w != "while"]
         assert ops.count("sort") == 3 and ops.count("while") == 2
+
+
+@pytest.mark.parametrize("rows,shards,parent", [
+    (1_800_001, 1, "84e0ca7b39d8686d"), (3_000_004, 4, "01e37aa5fcd0f4c6")])
+def test_add_rows_at_width_300_traces_to_the_jaxpr_it_was(rows, shards,
+                                                          parent):
+    """ISSUE 45 chooses the walk by what the table is. A table 300 wide
+    (``we-fused``: one shard; ``we-fused-x4``: four row shards) keeps
+    XLA's walk, and its ``add_rows`` is to the letter the jaxpr of the
+    commit before (digests taken there, jax 0.9.0) wherever the kernel
+    could run."""
+    import hashlib
+    sharding = None if shards == 1 else _row_sharding(shards)
+    table = jax.ShapeDtypeStruct((rows, 300), jnp.float32)
+    ids = jax.ShapeDtypeStruct((8192,), jnp.int32)
+    updates = jax.ShapeDtypeStruct((8192, 300), jnp.float32)
+    plan = jax.eval_shape(
+        lambda i: row_combine.plan_rows(i, rows, shards), ids)
+
+    def traced():
+        return str(jax.make_jaxpr(lambda t, i, u, p: row_combine.add_rows(
+            t, i, u, p, sharding))(table, ids, updates, plan))
+
+    text = traced()
+    assert "pallas_call" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == parent
+    with pytest.MonkeyPatch.context() as on_a_tpu:
+        on_a_tpu.setattr(row_combine, "_kernel_interpret", lambda: False)
+        assert traced() == text
 
 
 def _padded(rows: int, shards: int) -> int:
@@ -456,3 +488,171 @@ def test_fused_epoch_equals_sequential_raw_scatter_steps(batch, chunk, head,
         sum(-(-(d >= head).sum() // chunk) * chunk for d in distinct),
         # and the reads' rounds past the first: one shard takes none
         0]
+
+
+# ---------------------------------------------------------------------- #
+# ISSUE 45: on a float32 table whole lanes wide the walk is a Pallas tile
+# read-modify-write (here in the interpreter), bit for bit XLA's walk
+# ---------------------------------------------------------------------- #
+KERNEL_HEAD = 16      # a head that leaves most of a small table to the walk
+
+
+def _kernel_ids(kind: str, b: int, rows: int, rng) -> np.ndarray:
+    """Ids past the head unless the kind says otherwise."""
+    past = np.arange(KERNEL_HEAD, rows)
+    if kind == "distinct":
+        return rng.choice(rows, b, replace=False)
+    if kind == "one_row":
+        return np.full(b, 77)
+    if kind == "zipf":
+        return rng.zipf(1.2, b) % rows
+    if kind == "one_tile":          # several rows of one 8-row tile
+        return 8 * 50 + rng.choice([1, 2, 5, 6], b)
+    if kind == "tile_edges":        # a tile's first and last line
+        return rng.choice(past[(past % 8 == 0) | (past % 8 == 7)], b)
+    if kind == "last_row":          # among others, the table's last
+        return np.where(rng.random(b) < 0.2, rows - 1, rng.choice(past, b))
+    assert kind == "all_head"       # an empty walk
+    return rng.integers(0, KERNEL_HEAD, b)
+
+
+@pytest.fixture
+def walked(monkeypatch):
+    """``add_rows`` jitted afresh under one way of walking: the tile
+    kernel in the interpreter, or XLA's scatter."""
+    monkeypatch.setattr(row_combine, "HEAD", KERNEL_HEAD)
+
+    def walk(kernel: bool):
+        monkeypatch.setattr(row_combine, "_kernel_interpret",
+                            lambda: True if kernel else None)
+        return jax.jit(lambda *a: row_combine.add_rows(*a))
+
+    return walk
+
+
+@pytest.mark.parametrize("made_ahead", [True, False])
+@pytest.mark.parametrize("rows,width", [(1003, 128), (1000, 384),
+                                        (1001, 384), (1008, 128)])
+@pytest.mark.parametrize("kind", ["distinct", "one_row", "zipf", "one_tile",
+                                  "tile_edges", "last_row", "all_head"])
+def test_the_tile_kernel_writes_what_the_scatter_walk_writes(
+        kind, rows, width, made_ahead, walked):
+    rng = np.random.default_rng(len(kind) * 100 + rows + width)
+    b = 256
+    ids = _kernel_ids(kind, b, rows, rng).astype(np.int32)
+    table = rng.normal(size=(rows, width)).astype(np.float32)
+    table[::5] = -0.0
+    updates = rng.normal(size=(b, width)).astype(np.float32)
+    plan = (row_combine.plan_rows(jnp.asarray(ids), rows) if made_ahead
+            else None)
+    args = (jnp.asarray(table), jnp.asarray(ids), jnp.asarray(updates), plan)
+    assert row_combine.tile_walk(args[0]) is None     # off the chip
+    scatter = np.asarray(walked(False)(*args))
+    kernel = walked(True)
+    assert "pallas_call" in str(jax.make_jaxpr(kernel)(*args))
+    got = np.asarray(kernel(*args))
+    # XLA's walk bit for bit: the same float32 sums added once to a row
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  scatter.view(np.uint32))
+    want = table.copy()
+    np.add.at(want, ids, updates)
+    assert np.abs(got - want).max() <= 1e-6 * (
+        np.abs(updates).max() * np.bincount(ids).max())
+    # every row no update names, to the bit: the other lines of a tile
+    # that was written, -0.0 rows among them
+    others = np.setdiff1d(np.arange(rows), ids)
+    assert np.signbit(table[others]).any()
+    np.testing.assert_array_equal(got[others].view(np.uint32),
+                                  table[others].view(np.uint32))
+    named = np.unique(ids)
+    assert (named >= KERNEL_HEAD).any() == (kind != "all_head")
+    if kind == "one_tile":
+        assert named.size > 1 and np.unique(named // 8).size == 1
+    if kind == "last_row":
+        assert rows - 1 in named
+
+
+def test_the_tile_kernel_takes_straight_rounds_on_a_sparse_walk(walked):
+    """Enough single-row tiles that most rounds are the unrolled ones."""
+    rng = np.random.default_rng(45)
+    rows, width, b = 20_001, 128, 512
+    ids = rng.choice(rows, b, replace=False).astype(np.int32)
+    assert np.unique(ids // 8).size > 8 * row_combine.ROUND
+    table = rng.normal(size=(rows, width)).astype(np.float32)
+    updates = rng.normal(size=(b, width)).astype(np.float32)
+    args = (jnp.asarray(table), jnp.asarray(ids), jnp.asarray(updates))
+    np.testing.assert_array_equal(
+        np.asarray(walked(True)(*args)).view(np.uint32),
+        np.asarray(walked(False)(*args)).view(np.uint32))
+
+
+@pytest.mark.parametrize("rows", [1003, 1000, 5])
+def test_the_tile_kernel_on_an_empty_plan_writes_nothing(rows):
+    """No slot between start and end: every bit of the table stays, the
+    rows of a last part-tile (and a table of under one tile) too."""
+    rng = np.random.default_rng(rows)
+    table = rng.normal(size=(rows, 128)).astype(np.float32)
+    table[::3] = -0.0
+    uniq = jnp.arange(rows, rows + 320, dtype=jnp.int32)      # all pads
+    sums = jnp.asarray(rng.normal(size=(320, 128)).astype(np.float32))
+    for at in (0, 17):
+        got = jax.jit(lambda t: row_combine._walk_tiles(
+            t, uniq, sums, jnp.int32(at), jnp.int32(at), True))(
+                jnp.asarray(table))
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                      table.view(np.uint32))
+
+
+def test_the_tile_kernel_waits_once_for_every_copy_it_starts():
+    """The plain interpreter lets a kernel wait for a DMA it never
+    started; the chip hangs on it. Mosaic's own interpreter keeps the
+    semaphores as the chip does, so the kernel runs under it, in a
+    process of its own with a time limit: walks that end inside every
+    kind of round (none, one tile, a round and a bank to the tile, the
+    straight rounds, several rows a tile) all come back, and right."""
+    import subprocess
+    import sys
+    code = """
+import numpy as np, jax, jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
+from multiverso_tpu.ops import row_combine as rc
+rng = np.random.default_rng(0)
+rows, width, slots = 4003, 128, 320
+walk = jax.jit(lambda t, u, s, n: rc._walk_tiles(
+    t, u, s, jnp.int32(0), n, pltpu.InterpretParams()))
+for n in (0, 1, 15, 16, 17, 47, 48, 49, 64, 65, 200, 256):
+    dense = n == 200        # several rows a tile
+    ids = np.sort(rng.choice(300 if dense else rows, n, replace=False))
+    uniq = np.concatenate([ids, rows + np.arange(slots - n)]).astype(np.int32)
+    sums = rng.normal(size=(slots, width)).astype(np.float32)
+    tab = rng.normal(size=(rows, width)).astype(np.float32)
+    got = np.asarray(walk(jnp.asarray(tab), jnp.asarray(uniq),
+                          jnp.asarray(sums), jnp.int32(n)))
+    tab[ids] += sums[:n]
+    assert np.array_equal(got, tab), n
+print("every copy waited for")
+"""
+    done = subprocess.run([sys.executable, "-c", code], timeout=600,
+                          capture_output=True, text=True)
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert "every copy waited for" in done.stdout
+
+
+@pytest.mark.parametrize("what,walks", [
+    ("f32[1003,384]", True), ("f32[1003,128]", True),
+    ("f32[1003,300]", False), ("bf16[1003,384]", False),
+    ("f32[1003,384] on row shards", False), ("f32[384]", False)])
+def test_the_walk_is_chosen_by_what_the_table_is(what, walks, monkeypatch):
+    """No flag: float32, whole lanes wide, not row-sharded, on a TPU (here
+    the interpreter stands in for one); and off a TPU never."""
+    shape = [int(n) for n in re.findall(r"\d+", what.split("[")[1])]
+    table = jax.ShapeDtypeStruct(
+        tuple(shape), jnp.bfloat16 if what.startswith("bf16") else jnp.float32)
+    axis = "mv" if "shards" in what else None
+    assert row_combine.tile_walk(table, axis) is None
+    assert row_combine.kernel_rows(table, None, 50, 20) == 0
+    monkeypatch.setattr(row_combine, "_kernel_interpret", lambda: True)
+    assert (row_combine.tile_walk(table, axis) is True) == walks
+    if axis is None:
+        assert row_combine.kernel_rows(table, None, 50, 20) == 30 * walks
+    assert row_combine.lane_wide(300) == 384 == row_combine.lane_wide(384)

@@ -543,6 +543,57 @@ def test_fused_block_program_on_a_v5e_copies_no_table(one_chip, we300,
                 (0, 1), (0, 1)]
 
 
+def test_fused_block_program_on_a_v5e_walks_its_buckets_in_tiles(
+        one_chip, we300, monkeypatch):
+    """ISSUE 45: at the published shapes of ``we-psblock`` (a bucket of
+    2^19 rows and the dummy row, 40 minibatches of 8,192 pairs, 5
+    negatives) the block's scan runs on ``f32[524289,384]`` buckets and
+    its walks are the tile kernel: one kernel shape, called for the
+    centres and in the columns' loop, written in place; no scatter is
+    left on a bucket, no bucket is copied, and the kernel adds under 1/50
+    of a bucket to what the program holds. (The tables here have 240,008
+    rows, not 1,800,001: the pull and the push, not the scan.)"""
+    from multiverso_tpu.ops import row_combine
+    we = we300
+    fmt = Format(table_lib.row_program_layout(
+        we.table_in.padded_shape, jnp.dtype(jnp.float32), one_chip), one_chip)
+    for t in (we.table_in, we.table_out):
+        monkeypatch.setattr(t, "_format", fmt)
+    monkeypatch.setattr(row_combine, "_kernel_interpret", lambda: False)
+    we._fused_cache.pop("ps_block", None)
+    bucket, nb, b, k = 2 ** 19, 40, 8192, we.cfg.negative
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    batch = (ints(nb, b), ints(nb, b), ints(nb, b, k))
+    valid = jax.ShapeDtypeStruct((nb,), jnp.float32)
+    _, plans, _ = jax.eval_shape(
+        lambda bt, v: we._block_ahead(bt, v, bucket + 1), batch, valid)
+    table = jax.ShapeDtypeStruct(we.table_in.padded_shape, jnp.float32,
+                                 sharding=fmt)
+    rest = _on_chip((ints(bucket), ints(bucket), valid, batch, plans),
+                    one_chip)
+    try:
+        compiled = we._fused_block_fn().lower(
+            table, (), table, (), *rest).compile()
+    finally:
+        we._fused_cache.pop("ps_block", None)
+    text, wide = compiled.as_text(), (bucket + 1, 384)
+    kernels = re.findall(r"^\s*\S+ = (\S+) custom-call\((.*?)\), "
+                         r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert len(kernels) == 2 and len(set(
+        re.sub(r"%\S+", "", operands) for _, operands in kernels)) == 1
+    assert all(result.startswith("f32[%d,%d]" % wide)
+               for result, _ in kernels)
+    assert text.count("row_walk_tiles") >= 2
+    assert not re.findall(r"= f32\[%d,\d+\]\S* scatter\(" % wide[0], text)
+    assert _table_copies(compiled, wide) == []
+    assert _table_copies(compiled, (bucket + 1, 300)) == []
+    # the two buckets, the pulled rows they are measured against and a
+    # delta are five buckets' worth, as at width 300 before the kernel
+    # (4,037,229,056 B): the kernel's ring and lists are under 1/50 more
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < (5 + 1 / 50) * wide[0] * wide[1] * 4)
+
+
 def test_language_model_kernels_compile_for_a_v5e_at_published_widths(
         one_chip):
     """The flash kernel at head size 256 over 8,192 positions, forward

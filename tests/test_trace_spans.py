@@ -346,7 +346,9 @@ def test_dump_metrics_timeline_prints_the_calls_table_writes(tmp_path,
     call = {**_prog(7, 0, 900), "name": "we.blocks", "request": 3,
             "args": {"plane": "device", "blocks": 1, "words": 50,
                      "update_rows": 2000, "unique_rows": 1480,
-                     "head_rows": 400, "walk_slots": 1280}}
+                     "head_rows": 400, "walk_slots": 1280,
+                     "kernel_rows": 1080}}
+    # a trace from before ISSUE 45 carries no kernel_rows: none walked
     fused = {**_prog(8, 0, 900), "name": "we.fused", "request": 4,
              "args": {"update_rows": 100, "unique_rows": 50,
                       "head_rows": 20, "walk_slots_by_shard": [16, 16]}}
@@ -356,9 +358,13 @@ def test_dump_metrics_timeline_prints_the_calls_table_writes(tmp_path,
     assert dump_metrics.main(["timeline", str(path)]) == 0
     lines = capsys.readouterr().out.splitlines()
     [blocks] = [ln for ln in lines if "we.blocks request=3" in ln]
-    assert blocks.split()[2:] == ["2000", "1480", "(74.0%)", "400", "1280"]
+    # ISSUE 45: kernel_rows over the rows past the heads, 1.00 where the
+    # tile kernel walked them all
+    assert blocks.split()[2:] == ["2000", "1480", "(74.0%)", "400", "1280",
+                                  "1.00"]
     [fused_line] = [ln for ln in lines if "we.fused request=4" in ln]
-    assert fused_line.split()[2:] == ["100", "50", "(50.0%)", "20", "32"]
+    assert fused_line.split()[2:] == ["100", "50", "(50.0%)", "20", "32",
+                                      "0.00"]
 
 
 def test_device_timeline_without_device_spans_is_none():
@@ -499,6 +505,7 @@ def test_train_fused_leaves_its_spans_and_counts(mode):
             assert 0 < a["unique_rows"] < a["update_rows"]
             # a table of 61 rows is all head (ISSUE 31)
             assert a["head_rows"] == a["unique_rows"]
+            assert a["kernel_rows"] == 0        # ISSUE 45: XLA's walk
             # the count before combining is what it was: pool rows too
             assert sum(a["update_rows_by_shard"]) == (
                 a["update_rows"] + 2 * a["batches"] * we.cfg.shared_negatives)
@@ -615,7 +622,8 @@ def test_device_plane_blocks_leave_their_spans_and_counts(mode, tmp_path):
     # ISSUE 40: what the scans' table writes were handed, as we.fused says
     # it: a pair's centre, context and negatives, and what combining left
     rows = {k: call["args"].pop(k) for k in (
-        "update_rows", "unique_rows", "head_rows", "walk_slots")}
+        "update_rows", "unique_rows", "head_rows", "walk_slots",
+        "kernel_rows")}
     assert call["args"] == {"plane": "device", "blocks": n_blocks,
                             "words": int(ids.size)}
     by = {n: sorted((e for e in events if e["name"] == n),
@@ -627,6 +635,8 @@ def test_device_plane_blocks_leave_their_spans_and_counts(mode, tmp_path):
     assert 0 < rows["head_rows"] <= rows["unique_rows"] < rows["update_rows"]
     assert rows["walk_slots"] % min(row_combine.CHUNK,
                                     we.cfg.batch_size) == 0
+    # ISSUE 45: off the chip XLA's scatter walks the lane-wide bucket
+    assert rows["kernel_rows"] == 0
     for prep, disp in zip(by["we.prepare"], by["we.block.dispatch"]):
         a = prep["args"]
         assert 0 < a["rows_touched"] <= a["rows_bucket"]
@@ -653,6 +663,25 @@ def test_device_plane_blocks_leave_their_spans_and_counts(mode, tmp_path):
     assert "we.block" not in snap
     assert snap["we.block.dispatch"].count >= n_blocks
     assert snap["we.prepare"].count >= n_blocks
+
+
+def test_blocks_count_the_rows_the_tile_kernel_walked(monkeypatch):
+    """ISSUE 45: where the block's lane-wide bucket takes the tile kernel
+    (here in the interpreter, with a head of 8 rows so that a walk is
+    left), ``kernel_rows`` is every distinct row past the heads; the
+    fused epoch's tables are 8 wide and keep XLA's walk."""
+    monkeypatch.setattr(row_combine, "HEAD", 8)
+    monkeypatch.setattr(row_combine, "_kernel_interpret", lambda: True)
+    we, ids = _tiny_we(use_ps=1, data_block_size=3000)
+    start = len(ttrace.events())
+    assert np.isfinite(we.train_ps_blocks(ids, epochs=1)["loss"])
+    assert np.isfinite(we.train_fused(ids, epochs=1)["loss"])
+    args = {e["name"]: e["args"] for e in ttrace.events()[start:]
+            if e["name"] in ("we.blocks", "we.fused")}
+    blocks, fused = args["we.blocks"], args["we.fused"]
+    assert blocks["kernel_rows"] == (
+        blocks["unique_rows"] - blocks["head_rows"]) > 0
+    assert fused["kernel_rows"] == 0 < fused["unique_rows"]
 
 
 def test_host_plane_keeps_its_block_monitors():

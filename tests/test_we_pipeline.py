@@ -608,3 +608,56 @@ def test_a_device_plane_block_is_the_host_planes_local_train(negs):
     for args in (dev[3], host[3]):
         assert 0 < args["head_rows"] <= args["unique_rows"] <= (
             args["update_rows"])
+
+
+# ---------------------------------------------------------------------- #
+# ISSUE 45: the block's scan runs on buckets of whole lanes
+# ---------------------------------------------------------------------- #
+@pytest.mark.parametrize("same_floats", [False], indirect=True,
+                         ids=["default"])
+def test_a_block_on_the_lane_wide_bucket_trains_what_the_narrow_one_did(
+        same_floats, monkeypatch):
+    """One block through the host plane's local-train program (the scan
+    both planes share) on its bucket padded to 128 columns, against the
+    same program on the bucket as pulled, 12 wide: the zero columns stay
+    zero and add nothing to a score, so the deltas and the loss are the
+    narrow scan's, to the last bits ``same_floats`` allows two programs
+    at XLA:CPU's default compile. Not bit for bit here, compiled as
+    written either (8 values of 6,144 then differ by ulps): a score is a
+    dot over the row, and XLA:CPU's dot sums a row of 128 in another
+    order than a row of 12, zeros or not. On the chip a row of 300 is 384
+    lanes before and after."""
+    import multiverso_tpu as mv
+    from multiverso_tpu.apps.word_embedding import (WEConfig, WordEmbedding,
+                                                    synthetic_corpus)
+    from multiverso_tpu.data.dictionary import Dictionary
+    from multiverso_tpu.ops import row_combine
+
+    tokens = synthetic_corpus(24_000, vocab=400, seed=3)
+    mv.init()
+    cfg = WEConfig(size=12, min_count=2, batch_size=64, negative=3, window=3,
+                   epoch=1, data_block_size=2_000, use_ps="1",
+                   ps_device_plane="0", seed=7)
+    we = WordEmbedding(cfg, Dictionary.build(tokens, 2))
+    fn, handed = we._local_train_fn(), []
+    we._fused_cache["ps_local"] = lambda *a: handed.append(a) or fn(*a)
+    we.train_ps_blocks(we.prepare_ids(tokens)[:2_000])
+    [args] = handed
+
+    def trained(lane_wide):
+        monkeypatch.setattr(row_combine, "lane_wide", lane_wide)
+        del we._fused_cache["ps_local"]
+        lowered = we._local_train_fn().lower(*args)
+        buckets = lowered.as_text().count(
+            f"tensor<{args[0].shape[0] + 1}x{lane_wide(12)}xf32>")
+        return buckets, lowered.compile()(*args)
+
+    lanes = row_combine.lane_wide
+    (wide_buckets, wide), (narrow_buckets, narrow) = (
+        trained(lanes), trained(lambda width: width))
+    assert lanes(12) == 128 and wide_buckets > 0 and narrow_buckets > 0
+    for got, want in zip(wide[:3], narrow[:3]):     # d_in, d_sec, loss
+        assert got.shape == want.shape and np.abs(np.asarray(want)).max() > 0
+        same_floats(got, want)
+    np.testing.assert_array_equal(wide[3], narrow[3])       # the counts
+    mv.shutdown()

@@ -688,23 +688,27 @@ def _table_write_lines(events: List[Dict]) -> List[str]:
     """What the trainers' calls handed their table writes, from the
     counts on ``we.fused`` and ``we.blocks`` (``ops/row_combine``): the
     update rows, the distinct rows combining left of them, those of them
-    a head's dense add took, and the slots the walks were handed (by
-    shard on ``we.fused``)."""
+    a head's dense add took, the slots the walks were handed (by shard
+    on ``we.fused``), and the share of the rows past the heads that the
+    tile kernel walked (``kernel_rows``, PR 45: 1.00 says all of them,
+    0.00 that XLA's scatter walked them)."""
     calls = [e for e in events if e.get("name") in ("we.fused", "we.blocks")
              and "unique_rows" in e.get("args", {})]
     if not calls:
         return []
     out = ["  table writes by call (update rows, distinct, in a head, "
-           "walk slots):"]
+           "walk slots, kernel rows / rows past the heads):"]
     for e in calls:
         a = e["args"]
         walk = a.get("walk_slots", a.get("walk_slots_by_shard"))
         walk = sum(walk) if isinstance(walk, list) else walk
+        past = max(a["unique_rows"] - a["head_rows"], 1)
         out.append(
             f"    {e['name']} request={e.get('request')}  "
             f"{a['update_rows']}  {a['unique_rows']} "
             f"({100.0 * a['unique_rows'] / max(a['update_rows'], 1):.1f}%)  "
-            f"{a['head_rows']}  {walk}")
+            f"{a['head_rows']}  {walk}  "
+            f"{a.get('kernel_rows', 0) / past:.2f}")
     return out
 
 
