@@ -695,60 +695,10 @@ def bench_array_table(size: int = 1_000_000, iters: int = 10):
         pipelined(8)
         pipe.append((time.perf_counter() - t0) / 8)
 
-    # wire-compressed plane (ref quantization_util.h filters on the MPI
-    # wire; here the host<->device link): bf16 halves the payload. Measured
-    # INTERLEAVED with a plain table so drift between runs cannot
-    # masquerade as a filter effect — compare the *_vs_plain ratios.
-    wire_modes = ("bf16",)
-    tables = {"plain": t}
-    for mode in wire_modes:
-        tables[mode] = mv.ArrayTable(size, updater="sgd",
-                                     name=f"bench_array_{mode}",
-                                     wire_filter=mode)
-        tables[mode].add(delta, opt)   # compile
-        tables[mode].get()
-    samples = {k: {"add": [], "get": []} for k in tables}
-    for _ in range(max(iters // 2, 5)):
-        for k, tw in tables.items():   # back-to-back: shared conditions
-            t0 = time.perf_counter()
-            tw.add(delta, opt)
-            samples[k]["add"].append(time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            tw.get()
-            samples[k]["get"].append(time.perf_counter() - t0)
-    plain_add = _percentile_ms(samples["plain"]["add"])
-    plain_get = _percentile_ms(samples["plain"]["get"])
-    wf = {"plain_interleaved": {"add_p50_ms": plain_add,
-                                "get_p50_ms": plain_get}}
-    add_wire_bytes = {"bf16": 2 * size}
-    for mode in wire_modes:
-        am = _percentile_ms(samples[mode]["add"])
-        gm = _percentile_ms(samples[mode]["get"])
-        wf[mode] = {"add_p50_ms": am, "get_p50_ms": gm,
-                    "add_vs_plain": round(plain_add / am, 3),
-                    "get_vs_plain": round(plain_get / gm, 3),
-                    "add_payload_bytes": add_wire_bytes[mode],
-                    "add_payload_vs_f32": round(4 * size
-                                                / add_wire_bytes[mode], 1)}
-
-    # version-cached repeat get (flag table_get_cache): no intervening
-    # add, so the snapshot dispatch + device->host transfer are skipped
-    # entirely — a hit costs one host memcpy
-    from multiverso_tpu.utils.dashboard import Dashboard
-    cache_mon = Dashboard.get("table[bench_array].get.cached")
-    hits_before = cache_mon.count
-    t.get()   # prime the cache at the current version
-    rep = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        t.get()
-        rep.append(time.perf_counter() - t0)
-    get_cached_ms = _percentile_ms(rep)
-    get_cache_hits = cache_mon.count - hits_before
-    # in-run bit-parity of the read path (ISSUE 5 acceptance): whatever
-    # served the gets above — blocking transfer or version cache — the
-    # returned bytes must equal the live table's exactly. A latency number
-    # without this is meaningless, so parity failure FAILS the bench.
+    # in-run bit-parity of the read path (ISSUE 5 acceptance): the bytes
+    # the gets above returned must equal the live table's exactly. A
+    # latency number without this is meaningless, so parity failure FAILS
+    # the bench.
     host_now = t.get()
     raw_now = np.asarray(t.raw())[: size].reshape(host_now.shape)
     if not np.array_equal(host_now, raw_now):
@@ -797,9 +747,6 @@ def bench_array_table(size: int = 1_000_000, iters: int = 10):
         "get_gbps": nbytes / np.percentile(gets, 50) / 1e9,
         "pipelined_add_ms": _percentile_ms(pipe),
         "pipelined_add_gbps": nbytes / np.percentile(pipe, 50) / 1e9,
-        "wire_filtered": wf,
-        "get_repeat_cached_ms": get_cached_ms,
-        "get_cache_hits": int(get_cache_hits),
         "get_parity_bit_for_bit": True,   # asserted above, else raise
         "device_add_ms": dev_add_s * 1e3,
         "device_add_gbps": nbytes / dev_add_s / 1e9,

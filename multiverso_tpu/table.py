@@ -48,47 +48,12 @@ from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from multiverso_tpu import updaters as updaters_lib
-from multiverso_tpu.telemetry import memstats as _memstats
 from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.updaters import AddOption
-from multiverso_tpu.utils import config, log
+from multiverso_tpu.utils import config
 from multiverso_tpu.utils import platform as _platform
 from multiverso_tpu.utils.dashboard import Dashboard, monitor
 from multiverso_tpu.zoo import Zoo
-
-config.define_bool(
-    "table_get_cache", True,
-    "version-stamped host cache for whole-table Get: each applied Add "
-    "bumps a table version, and a Get at an unchanged version returns "
-    "the cached host array instead of dispatching a snapshot + "
-    "device->host transfer (a repeated Get with no intervening Add "
-    "costs one memcpy, not one wire round-trip). Safe multi-controller: "
-    "host-plane ops are collective and identical on every process, so "
-    "versions advance in lockstep and all ranks hit or miss together")
-
-
-class _HostAdd:
-    """One queued client-side add awaiting the coalescing applier."""
-
-    __slots__ = ("arr", "opt", "event", "error", "token")
-
-    def __init__(self, arr: np.ndarray, opt: AddOption):
-        self.arr, self.opt = arr, opt
-        self.event = threading.Event()
-        self.error: Optional[Exception] = None
-        self.token: Optional[jax.Array] = None
-
-    def ready(self) -> bool:
-        """Sweepable: applied and the completion token is device-ready."""
-        return self.event.is_set() and (
-            self.error is not None
-            or (self.token is not None and self.token.is_ready()))
-
-    def result(self):
-        self.event.wait()
-        if self.error is not None:
-            raise self.error
-        return self.token.block_until_ready()
 
 ArrayLike = Union[np.ndarray, jax.Array, Sequence]
 
@@ -151,14 +116,6 @@ def _zeros_program(shape: Tuple[int, ...], dtype, sharding):
     return jax.jit(lambda: jnp.zeros(shape, dtype), out_shardings=sharding)
 
 
-@jax.jit
-def _bf16_cast(x: jax.Array) -> jax.Array:
-    """bfloat16 down-cast of a Get snapshot (``wire_filter="bf16"``: half
-    the download bytes). Non-donating: the live table data must survive
-    the cast."""
-    return x.astype(jnp.bfloat16)
-
-
 def _row_probe(data, ids):
     """What every row program does to a table: gather rows, scatter-add
     them back. Compiled, never run (:func:`row_program_layout`)."""
@@ -208,13 +165,7 @@ class Table:
                  name: str = "table",
                  init: Optional[ArrayLike] = None,
                  seed: Optional[int] = None,
-                 init_scale: float = 0.0,
-                 wire_filter: str = "none"):
-        """``wire_filter="bf16"`` sends whole-table Add deltas and Get
-        snapshots across the host<->device wire as bfloat16: half the
-        bytes both ways, a cast with no state (the reference compressed
-        its MPI wire, quantization_util.h). Row ops are unaffected (their
-        payloads are already small)."""
+                 init_scale: float = 0.0):
         zoo = Zoo.get()
         self._zoo = zoo
         self.name = name
@@ -258,50 +209,18 @@ class Table:
                 updater.init_state(self._padded_shape, self.dtype))
         self.table_id = zoo.register_table(self)
 
-        if wire_filter not in ("none", "bf16"):
-            raise ValueError(f"unknown wire_filter {wire_filter!r}")
-        self._wire = wire_filter
-
         self._pending: Dict[int, Any] = {}
         self._next_msg_id = 0
         self._lock = threading.Lock()
-        # version-stamped get cache: every applied mutation bumps
-        # _version (see _mark_mutated); a whole-table Get at an unchanged
-        # version returns the cached host array and skips the snapshot
-        # dispatch + device->host transfer entirely (flag table_get_cache)
-        self._version = 0
-        self._get_cache: Optional[Tuple[int, np.ndarray]] = None
         # Serializes op *dispatch* (not device execution): a donating add on
         # one thread must not delete the data buffer while another thread
         # (e.g. an AsyncBuffer prefetch pull) is snapshotting it.
         self._dispatch_lock = threading.RLock()
         self._jit_cache: Dict[Any, Any] = {}
-        # client-side add coalescing (stateless linear updaters, single
-        # controller, uncompressed wire): async host adds queue here and a
-        # background applier merges everything queued into ONE summed
-        # upload — across a slow host<->device link the transfer is the
-        # dominant cost and transfers do NOT overlap (measured: 4
-        # threaded 4 MB uploads took ~4x one), so N-deep pipelining must
-        # become 1 upload, not N concurrent ones
-        self._addq: list = []
-        self._addq_cv = threading.Condition()
-        self._addq_inflight = 0
-        self._add_applier: Optional[threading.Thread] = None
         # hot-row training cache (serving/hotcache; row-table subclasses
         # create it behind the train_cache_rows flag — base ops only need
         # to INVALIDATE on coarse mutations)
         self._train_cache = None
-        # memory ledger (telemetry/memstats.py): the get cache is the
-        # sync plane's table-sized hoard; gauges are pull-only
-        _memstats.register(f"table[{name}]", self)
-
-    def memory_stats(self) -> Dict[str, Any]:
-        """Byte-ledger gauge: the cached whole-table Get host copy. A
-        lock-free read of the tuple ref — benign vs the dispatch lock,
-        and the ledger tolerates a one-sample-stale figure."""
-        cache = self._get_cache
-        return {"cache_bytes": (int(cache[1].nbytes)
-                                if cache is not None else 0)}
 
     # ------------------------------------------------------------------ #
     # construction helpers
@@ -392,71 +311,10 @@ class Table:
         replicate; in the default layout, as the data is built."""
         return jax.device_put(x, self._leaf_format(x).sharding)
 
-    # ------------------------------------------------------------------ #
-    # mutation bookkeeping (Zoo dirty fence + get-cache version)
-    # ------------------------------------------------------------------ #
     def _mark_mutated(self) -> None:
         """Entry of every table mutation path: dirty-mark for the Zoo
-        barrier fence and bump the get-cache version CONSERVATIVELY (so a
-        ``version`` poll — e.g. an AsyncBuffer ``version_fn`` — already
-        sees a queued-but-unapplied coalesced add as a change). This
-        entry bump alone cannot make the cache correct: it happens
-        outside the dispatch lock, so a concurrent Get could stamp
-        pre-mutation data with the post-bump version. The guarantee
-        comes from :meth:`_version_applied`, which bumps AGAIN at the
-        point the mutation is dispatched while the dispatch lock is
-        held — any mutation applying after a Get's snapshot therefore
-        always moves the version past that Get's stamp."""
+        barrier fence."""
         self._zoo.mark_dirty(self.table_id)
-        self._version += 1
-
-    def _version_applied(self) -> None:
-        """Apply-side version bump (see :meth:`_mark_mutated`). Called at
-        every site that actually mutates ``_data``/``_ustate``, while the
-        dispatch lock is held (or, for adopt/load, after the state
-        assignment) — the Get cache's correctness anchor."""
-        self._version += 1
-
-    @property
-    def version(self) -> int:
-        """Monotonic mutation counter (the get-cache stamp). Cheap enough
-        to poll — e.g. as an AsyncBuffer ``version_fn`` so a prefetch pull
-        of an unchanged table is skipped entirely."""
-        return self._version
-
-    def _cached_get(self, into: Optional[np.ndarray] = None
-                    ) -> Optional[np.ndarray]:
-        """Cached host array when the version is unchanged, else None.
-        Caller holds the dispatch lock. The cache owns a private copy
-        (callers may mutate what get() hands them), so hits pay one
-        memcpy instead of a dispatch + transfer — straight into ``into``
-        when the caller supplied a reusable output buffer (one memcpy,
-        not copy-then-copyto)."""
-        if not config.get_flag("table_get_cache"):
-            return None
-        cache = self._get_cache
-        if cache is None or cache[0] != self._version:
-            return None
-        # incr, not observe_ms(0.0): a hit COUNTER must not feed fake
-        # 0-ms samples into the monitor's latency histogram
-        Dashboard.get(f"table[{self.name}].get.cached").incr()
-        if into is not None:
-            np.copyto(into.reshape(self.shape), cache[1])
-            return into
-        return cache[1].copy()
-
-    def _store_get_cache(self, version: int, host: np.ndarray) -> None:
-        """Caller holds the dispatch lock. An older-version store (a slow
-        get_async finalize racing a sync get that already cached fresher
-        data) is dropped instead of clobbering the fresher entry — it
-        could never match a future version check anyway, and replacing
-        the fresh entry would just turn the next Get into a miss."""
-        if not config.get_flag("table_get_cache"):
-            return
-        cache = self._get_cache
-        if cache is not None and cache[0] > version:
-            return
-        self._get_cache = (version, host.copy())
 
     # ------------------------------------------------------------------ #
     # msg-id / Waiter bookkeeping (ref src/table.cpp:27-97)
@@ -467,20 +325,14 @@ class Table:
             # add whose msg id is never wait()ed (finalize is None and the
             # completion token is already ready) would otherwise pin its
             # device buffer in _pending forever. Swept ids behave exactly
-            # like already-waited ones (wait returns None). Coalesced-add
-            # entries sweep once applied + token-ready.
+            # like already-waited ones (wait returns None).
             done = [mid for mid, (arrs, fin) in self._pending.items()
-                    if (isinstance(arrs, _HostAdd) and arrs.ready())
-                    or (fin is None and not isinstance(arrs, _HostAdd)
-                        and all(
+                    if fin is None and all(
                         hasattr(a, "is_ready") and a.is_ready()
                         for a in jax.tree.leaves(arrs)
-                        if isinstance(a, jax.Array)))]
+                        if isinstance(a, jax.Array))]
             for mid in done:
-                arrs, _ = self._pending.pop(mid)
-                if isinstance(arrs, _HostAdd) and arrs.error is not None:
-                    log.error("table[%s]: fire-and-forget add %d failed: "
-                              "%s", self.name, mid, arrs.error)
+                del self._pending[mid]
             msg_id = self._next_msg_id
             self._next_msg_id += 1
             self._pending[msg_id] = (arrays, finalize)
@@ -500,8 +352,6 @@ class Table:
         if entry is None:
             return None
         arrays, finalize = entry
-        if isinstance(arrays, _HostAdd):
-            return arrays.result()
         arrays = jax.tree.map(
             lambda a: a.block_until_ready() if isinstance(a, jax.Array) else a,
             arrays)
@@ -514,7 +364,6 @@ class Table:
     def state(self) -> Dict[str, Any]:
         """Current table pytree {data, ustate}, in the device's default
         layout; safe to close over in jit."""
-        self._flush_host_adds()
         self._lay_out(own=False)
         return {"data": self._data, "ustate": self._ustate}
 
@@ -527,7 +376,6 @@ class Table:
         table; handing the table to anyone else (:attr:`state`,
         :meth:`raw`) or back costs one copy of it, counted
         (``table.relayout``)."""
-        self._flush_host_adds()
         self._lay_out(own=True)
         return {"data": self._data, "ustate": self._ustate}
 
@@ -579,10 +427,8 @@ class Table:
     def adopt(self, state: Dict[str, Any]) -> None:
         """Commit an externally-advanced table state (end of in-graph loop)."""
         self._mark_mutated()
-        self._flush_host_adds()   # a late-applying add must not overwrite
         self._data = state["data"]
         self._ustate = state["ustate"]
-        self._version_applied()
         if self._train_cache is not None:
             # wholesale rewrite: all rows stale. AFTER the rebind — a
             # clear logged before the mutation is visible lets a racing
@@ -634,7 +480,6 @@ class Table:
     def raw(self) -> jax.Array:
         """The live padded, sharded data array (graph-plane read), in the
         device's default layout."""
-        self._flush_host_adds()   # reads see every prior async add
         self._lay_out(own=False)
         return self._data
 
@@ -701,196 +546,25 @@ class Table:
         padded[: self.shape[0]] = arr
         return jax.device_put(padded, self._sharding)
 
-    # ------------------------------------------------------------------ #
-    # wire-compressed upload path (ref quantization_util.h filters, applied
-    # to the host->device seam: that link is the analogue of the
-    # reference's MPI wire)
-    # ------------------------------------------------------------------ #
-    def _bf16_update_fn(self):
-        fn = self._jit_cache.get("full_bf16")
-        if fn is None:
-            updater = self.updater
-
-            def _update(data, ustate, delta_bf16, opt):
-                data, ustate = updater.apply(
-                    data, ustate, delta_bf16.astype(data.dtype), opt)
-                return data, ustate, jnp.ravel(data)[0]
-
-            fn = self._jit_cache["full_bf16"] = jax.jit(
-                _update, donate_argnums=(0, 1))
-        return fn
-
-    # ------------------------------------------------------------------ #
-    # client-side add coalescing
-    # ------------------------------------------------------------------ #
-    def _coalescible(self, delta, opt) -> bool:
-        """Async host adds coalesce when the merge is EXACT for the
-        updater: stateless linear updater (sum of deltas == sequence of
-        adds, and opt is never read), single controller (a collective
-        process_sum must keep one per-process issue order). Wire-filtered
-        tables coalesce too: the merged batch is cast once."""
-        return (self._zoo.size() == 1
-                and not isinstance(delta, jax.Array)
-                and type(self.updater) in updaters_lib.STATELESS_LINEAR)
-
-    _ADDQ_CAP = 16          # backpressure: each entry is a full host copy
-    _APPLIER_IDLE_S = 5.0   # idle applier threads exit (no table pinning)
-
-    def _enqueue_host_add(self, delta: ArrayLike, opt: AddOption) -> int:
-        entry = _HostAdd(
-            np.array(delta, dtype=self.dtype).reshape(self.shape), opt)
-        with self._addq_cv:
-            while len(self._addq) >= self._ADDQ_CAP:
-                self._addq_cv.wait()   # throttle like the old inline path
-            self._addq.append(entry)
-            self._addq_inflight += 1
-            if self._add_applier is None:
-                self._add_applier = threading.Thread(
-                    target=self._add_applier_loop,
-                    name=f"mv-add-{self.name}", daemon=True)
-                self._add_applier.start()
-            self._addq_cv.notify_all()
-        return self._track(entry)
-
-    def _apply_host_batch(self, batch) -> None:
-        """Merge + upload + apply one drained batch (caller holds the
-        dispatch lock)."""
-        try:
-            if len(batch) == 1:
-                acc = batch[0].arr
-            else:   # float64 accumulate, like every other merge seam
-                acc = np.zeros(self.shape, np.float64)
-                for e in batch:
-                    acc += e.arr
-                acc = acc.astype(self.dtype)
-            if self._wire != "none":
-                # compressed upload for the whole merged batch: ONE
-                # encode + one small transfer instead of N of either
-                token = self._dispatch_wire_add(acc, batch[0].opt)
-            else:
-                delta_dev = self._host_delta(acc)   # ONE upload for all
-                self._data, self._ustate, token = self._full_update_fn()(
-                    self._data, self._ustate, delta_dev, batch[0].opt)
-                self._version_applied()
-            for e in batch:
-                e.token = token
-            if self._train_cache is not None:
-                # the delta is VISIBLE only now (add_async's clear ran
-                # at enqueue time, before the apply): a get that won the
-                # dispatch lock ahead of this apply filled pre-add rows
-                # under a then-current token — drop them, or every later
-                # full hit would serve pre-add values forever
-                self._train_cache.clear()
-        except Exception as err:   # pragma: no cover - device failure
-            for e in batch:
-                e.error = err
-        finally:
-            with self._addq_cv:
-                for e in batch:
-                    e.event.set()
-                self._addq_inflight -= len(batch)
-                self._addq_cv.notify_all()
-
-    def _add_applier_loop(self) -> None:
-        while True:
-            with self._addq_cv:
-                while not self._addq:
-                    if (not self._addq_cv.wait(self._APPLIER_IDLE_S)
-                            and not self._addq):
-                        # idle exit: a parked thread would pin the table
-                        # (and its device buffers) for the process's life
-                        self._add_applier = None
-                        return
-            # dispatch lock FIRST, pop second: entries are only ever held
-            # by a thread that already owns the lock, so a lock-holding
-            # flusher always finds them still queued and drains inline —
-            # no lock-ordering deadlock is possible
-            with self._dispatch_lock:
-                with self._addq_cv:
-                    batch, self._addq = self._addq, []
-                    if batch:
-                        self._addq_cv.notify_all()   # free throttled adds
-                if batch:
-                    self._apply_host_batch(batch)
-
-    def _flush_host_adds(self) -> None:
-        """Reads must observe every prior async add: drain the queue
-        inline. Safe whether or not the caller already holds the dispatch
-        lock (it is reentrant). INVARIANT: entries are only ever popped by
-        a thread holding the dispatch lock, and the inflight decrement
-        happens before that hold is released — so for a dispatch-holder,
-        inflight > 0 implies the entries are still in the queue, and a
-        holder can always drain them itself (no lock-ordering deadlock)."""
-        while self._addq_inflight > 0:
-            with self._dispatch_lock:
-                with self._addq_cv:
-                    batch, self._addq = self._addq, []
-                    if batch:
-                        self._addq_cv.notify_all()   # free throttled adds
-                if batch:
-                    self._apply_host_batch(batch)
-                    continue
-            # empty queue but inflight > 0: another thread is mid-apply
-            # (it held the dispatch lock we just cycled through) — wait
-            # for its completion signal OUTSIDE the dispatch lock
-            with self._addq_cv:
-                while self._addq_inflight > 0 and not self._addq:
-                    self._addq_cv.wait()
-
     def add_async(self, delta: ArrayLike,
                   opt: Optional[AddOption] = None) -> int:
-        """ref WorkerTable::AddAsync — dispatch the update, return a msg id.
-
-        Stateless-linear host adds ride the coalescing queue: N pipelined
-        adds become one summed upload (transfers do not overlap on a slow
-        host<->device link, so fewer transfers is the only lever). Everything
-        else applies inline under the dispatch lock."""
+        """ref WorkerTable::AddAsync — dispatch the update, return a msg id."""
         opt = opt or AddOption()
         self._mark_mutated()
         try:
-            with monitor(f"table[{self.name}].add"):
-                if self._coalescible(delta, opt):
-                    return self._enqueue_host_add(delta, opt)
-                with self._dispatch_lock:
-                    if (self._wire != "none"
-                            and not isinstance(delta, jax.Array)):
-                        return self._add_async_wire(delta, opt)
-                    delta_dev = self._host_delta(delta)
-                    self._data, self._ustate, token = \
-                        self._full_update_fn()(
-                            self._data, self._ustate, delta_dev, opt)
-                    self._version_applied()
+            with monitor(f"table[{self.name}].add"), self._dispatch_lock:
+                delta_dev = self._host_delta(delta)
+                self._data, self._ustate, token = self._full_update_fn()(
+                    self._data, self._ustate, delta_dev, opt)
             return self._track(token)
         finally:
             if self._train_cache is not None:
                 # whole-table delta: conservative wholesale drop, AFTER
-                # the delta is queued/applied (every return path above) —
-                # a clear logged before the mutation is visible lets a
-                # get racing into the window re-fill pre-add rows under
-                # a current fill token, permanently stale
+                # the delta is applied — a clear logged before the
+                # mutation is visible lets a get racing into the window
+                # re-fill pre-add rows under a current fill token,
+                # permanently stale
                 self._train_cache.clear()
-
-    def _add_async_wire(self, delta: ArrayLike, opt: AddOption) -> int:
-        """Compressed upload: the host payload is cast to bfloat16
-        before crossing the wire and cast back in the update program."""
-        arr = np.asarray(delta, dtype=self.dtype).reshape(self.shape)
-        if self._zoo.size() > 1:
-            from multiverso_tpu.parallel.collectives import process_sum
-            arr = process_sum(arr)
-        return self._track(self._dispatch_wire_add(arr, opt))
-
-    def _dispatch_wire_add(self, arr: np.ndarray, opt: AddOption):
-        """Cast to bfloat16 on the host, ship half the bytes across the
-        host<->device seam, apply via the cast-back + update program.
-        Caller holds the dispatch lock. Returns the completion token."""
-        import ml_dtypes
-        padded = np.zeros(self._padded_shape, ml_dtypes.bfloat16)
-        padded[: self.shape[0]] = arr.astype(ml_dtypes.bfloat16)
-        dev = jax.device_put(padded, self._sharding)
-        self._data, self._ustate, token = self._bf16_update_fn()(
-            self._data, self._ustate, dev, opt)
-        self._version_applied()
-        return token
 
     def add(self, delta: ArrayLike, opt: Optional[AddOption] = None) -> None:
         """ref WorkerTable::Add — blocking add (Wait(AddAsync(...)))."""
@@ -898,56 +572,26 @@ class Table:
 
     def get_async(self) -> int:
         """ref WorkerTable::GetAsync — start device->host transfer, return
-        id. A version-cache hit skips the snapshot dispatch and transfer
-        entirely; with a wire filter the snapshot is cast to bf16 on
-        device first (half the download bytes — get() always did this,
-        the async variant previously pulled full f32)."""
-        self._flush_host_adds()   # before the lock: the applier needs it
+        id."""
         with monitor(f"table[{self.name}].get"), self._dispatch_lock:
-            cached = self._cached_get()
-            if cached is not None:
-                return self._track((), lambda _: cached)
-            version = self._version
-            snap = (_bf16_cast(self._data)
-                    if self._wire != "none"
-                    else self._snapshot_fn()(self._data))
+            snap = self._snapshot_fn()(self._data)
             try:
                 snap.copy_to_host_async()
             except AttributeError:
                 pass
-
-            def _finalize(s, _v=version):
-                host = self._to_host(s)[: self.shape[0]]
-                if host.dtype != self.dtype:
-                    host = host.astype(self.dtype)
-                with self._dispatch_lock:
-                    self._store_get_cache(_v, host)
-                return host
-
-            return self._track(snap, _finalize)
+            return self._track(
+                snap, lambda s: self._to_host(s)[: self.shape[0]])
 
     def get(self, out: Optional[np.ndarray] = None) -> np.ndarray:
         """ref WorkerTable::Get — blocking pull of the whole logical table.
 
-        Fast path: reads the live array directly instead of dispatching a
-        snapshot copy — safe because the transfer completes under the
-        dispatch lock, before any later donating add can delete the buffer
-        (saves one dispatch round-trip per get;
-        get_async keeps the snapshot since its read is deferred). With a
-        wire filter the download is cast to bf16 on device first (half the
-        bytes; ~3 decimal digits, plenty for parameter traffic)."""
-        self._flush_host_adds()   # before the lock: the applier needs it
+        Reads the live array directly instead of dispatching a snapshot
+        copy — safe because the transfer completes under the dispatch
+        lock, before any later donating add can delete the buffer (saves
+        one dispatch round-trip per get; get_async keeps the snapshot
+        since its read is deferred)."""
         with monitor(f"table[{self.name}].get"), self._dispatch_lock:
-            hit = self._cached_get(into=out)
-            if hit is not None:
-                return hit
-            version = self._version
-            if self._wire != "none":
-                host = self._to_host(_bf16_cast(self._data))
-                host = host[: self.shape[0]].astype(self.dtype)
-            else:
-                host = self._to_host(self._data)[: self.shape[0]]
-            self._store_get_cache(version, host)
+            host = self._to_host(self._data)[: self.shape[0]]
         if out is not None:
             np.copyto(out.reshape(self.shape), host)
             return out
@@ -975,7 +619,6 @@ class Table:
         """Write raw table + updater state (ref array_table.cpp:143-151).
         Multi-controller: fetching sharded state is a collective, so every
         process must call this together (checkpoint.save does)."""
-        self._flush_host_adds()
         np.save(stream, self._to_host(self._data), allow_pickle=False)
         flat, _ = jax.tree.flatten(self._ustate)
         np.save(stream, np.asarray(len(flat)), allow_pickle=False)
@@ -984,7 +627,6 @@ class Table:
 
     def load(self, stream) -> None:
         self._mark_mutated()
-        self._flush_host_adds()   # a late-applying add must not overwrite
         data = np.load(stream)
         if data.shape != self._padded_shape:
             raise ValueError(
@@ -997,7 +639,6 @@ class Table:
         leaves = [np.load(stream) for _ in range(n)]
         self._ustate = jax.tree.unflatten(
             treedef, [self._place_state(l) for l in leaves])
-        self._version_applied()
         if self._train_cache is not None:
             self._train_cache.clear()   # after the load is visible (the
             #  adopt()/add_async() clear-after-mutate ordering rule)

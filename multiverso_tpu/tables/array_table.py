@@ -25,10 +25,10 @@ class ArrayTable(Table):
                  updater: Union[str, updaters_lib.Updater, None] = None,
                  name: str = "array",
                  init=None, seed: Optional[int] = None,
-                 init_scale: float = 0.0, wire_filter: str = "none"):
+                 init_scale: float = 0.0):
         super().__init__((int(size),), dtype=dtype, updater=updater,
                          name=name, init=init, seed=seed,
-                         init_scale=init_scale, wire_filter=wire_filter)
+                         init_scale=init_scale)
 
     @property
     def size(self) -> int:

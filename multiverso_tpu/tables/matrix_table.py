@@ -236,7 +236,6 @@ class MatrixTable(Table):
             # union, not just this worker's set): the sparse table's dirty
             # bits must cover rows other workers contributed
             self._rows_applied(ids)
-            self._version_applied()
         return self._track(token)
 
     def _rows_applied(self, ids: np.ndarray) -> None:
@@ -247,7 +246,6 @@ class MatrixTable(Table):
         self.wait(self.add_rows_async(row_ids, values, opt))
 
     def get_rows_async(self, row_ids) -> int:
-        self._flush_host_adds()   # row reads see prior whole-table adds
         with monitor(f"table[{self.name}].get_rows"), self._dispatch_lock:
             ids, _, k, inv = self._prep_ids(row_ids)
             tc = self._train_cache

@@ -139,7 +139,6 @@ class SparseMatrixTable(MatrixTable):
         ref matrix.cpp:475-483 (GetOption.worker_id) + :540-572 (stale-only
         reply).
         """
-        self._flush_host_adds()   # row reads see prior whole-table adds
         with monitor(f"table[{self.name}].get_rows_sparse"), self._dispatch_lock:
             cache = self._worker_cache(worker_id)
             ids = np.asarray(row_ids, dtype=np.int64).reshape(-1)

@@ -5,10 +5,10 @@ system — ref-counted ``Blob``s over a pooled ``SmartAllocator``
 (ref include/multiverso/blob.h, allocator.h) — where every byte has an
 owner. The JAX port measures everything except bytes: PRs 3/4/6/9 built
 latency histograms, a flight recorder, cluster stats, and a step
-profiler, yet the framework carries at least five unmetered hoards —
+profiler, yet the framework carries at least four unmetered hoards —
 COW-retired epoch buffers pinned by readers (PR 5), send-window replay
 tails retained past ack (PR 7), replica snapshots + device hot-row
-caches (PR 8), checkpoint staging (PR 7), and the PR-1 get cache — and
+caches (PR 8) and checkpoint staging (PR 7) — and
 the three worst review-caught bugs to date (the ``_pin_buf`` identity
 anchor holding a full retired table, the per-probe socket leak, the
 flusher-thread/table leak) were silent memory leaks no surface could
@@ -19,7 +19,7 @@ have flagged. This module is the byte-level answer:
   ``RowShard.memory_stats`` (live table buffers per dtype, pinned-epoch
   count x retired-buffer bytes with per-pin age, apply-queue pending
   bytes), ``_SendWindow.memory_stats`` (pending + replay-retained
-  frames/bytes), ``Table.memory_stats`` (get cache),
+  frames/bytes),
   ``ReadReplica.memory_stats`` (snapshot buffer, device cache, staging
   copy), checkpoint/failover staging + on-disk tag bytes. Registration
   is one dict store at construct time; gauges are computed only when a

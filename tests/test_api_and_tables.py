@@ -63,47 +63,27 @@ class TestArrayTable:
             t.wait(i)
         np.testing.assert_allclose(t.get(), 5.0)
 
-    def test_async_adds_coalesce_into_one_apply(self):
-        """Pipelined host adds on a stateless-linear table merge into one
-        summed upload (transfers do not overlap on a slow link, so
-        fewer transfers is the only pipelining lever): all queued entries
-        share one completion token, and the sum is exact."""
-        t = mv.ArrayTable(64, updater="sgd")
-        base = t._m if hasattr(t, "_m") else t
-        delta = np.full(64, 2.0, np.float32)
-        # hold the dispatch lock so the applier can't run: all three adds
-        # queue, then one drain applies them as one batch
-        with base._dispatch_lock:
-            mids = [base.add_async(delta.reshape(base.shape))
-                    for _ in range(3)]
-            assert base._addq_inflight == 3
-        toks = [base.wait(m) for m in mids]
-        assert toks[0] is toks[1] is toks[2]     # ONE merged apply
-        np.testing.assert_allclose(t.get(), -6.0)   # sgd sign, exact sum
-
     def test_reads_flush_queued_adds_even_under_dispatch_lock(self):
-        """Reading .state/get while holding the dispatch lock (the fused
-        WE path does exactly this) must drain the queue inline, not
-        deadlock against the applier thread."""
+        """An add_async issued while the caller holds the dispatch lock
+        (the fused WE path holds it from program_state to adopt) is in
+        .state and in get() at once, and does not deadlock: the lock is
+        reentrant and the add is dispatched before add_async returns."""
         t = mv.ArrayTable(32, updater="sgd")
-        base = t._m if hasattr(t, "_m") else t
         delta = np.ones(32, np.float32)
-        with base._dispatch_lock:
-            base.add_async(delta.reshape(base.shape))
-            st = base.state                     # flushes inline
-            host = np.asarray(st["data"]).reshape(-1)[:32]
+        with t._dispatch_lock:
+            t.add_async(delta)
+            host = np.asarray(t.state["data"]).reshape(-1)[:32]
+            np.testing.assert_allclose(t.get(), -1.0)
         np.testing.assert_allclose(host, -1.0)
         np.testing.assert_allclose(t.get(), -1.0)
 
     def test_momentum_adds_do_not_coalesce(self):
-        """Stateful updaters must keep per-add sequencing (N sequential
+        """Stateful updaters keep per-add sequencing (N sequential
         momentum applies != one summed apply)."""
         t = mv.ArrayTable(16, updater="momentum_sgd")
-        base = t._m if hasattr(t, "_m") else t
         opt = AddOption(momentum=0.5)
         for _ in range(3):
-            base.wait(base.add_async(np.ones(base.shape, np.float32), opt))
-        assert base._addq_inflight == 0 and not base._addq
+            t.wait(t.add_async(np.ones(t.shape, np.float32), opt))
         # sequential momentum: smooth=.5,.75,.875 -> data = -2.125
         np.testing.assert_allclose(t.get(), -2.125, rtol=1e-6)
 
@@ -287,3 +267,69 @@ class TestReviewRegressions:
         assert out.base is mat or out is col
 
 
+# ---------------------------------------------------------------------- #
+# one Add path, one Get path (PR 46): what the dispatch lock and program
+# order give, with no queue, cache or stamp between caller and device
+# ---------------------------------------------------------------------- #
+def _array(updater, name):
+    return mv.ArrayTable(24, updater=updater, name=name)
+
+
+def _matrix(updater, name):
+    return mv.MatrixTable(6, 4, updater=updater, name=name)
+
+
+@pytest.mark.parametrize("make", [_array, _matrix],
+                         ids=["ArrayTable", "MatrixTable"])
+def test_get_async_issued_before_a_donating_add_reads_the_values_before_it(
+        make):
+    t = make("sgd", "snap")
+    one = np.ones(t.shape, np.float32)
+    t.add(one)
+    pending = t.get_async()
+    for _ in range(3):              # each donates the buffer get_async read
+        t.add_async(one)
+    np.testing.assert_array_equal(t.read(pending), -one)
+    np.testing.assert_array_equal(t.get(), -4 * one)
+
+
+def test_a_table_starts_no_thread_and_defines_no_cache_flag():
+    import threading
+
+    from multiverso_tpu.table import Table
+    from multiverso_tpu.utils import config
+
+    before = set(threading.enumerate())
+    t, m = _array("sgd", "quiet_a"), _matrix("sgd", "quiet_m")
+    for table in (t, m):
+        one = np.ones(table.shape, np.float32)
+        ids = [table.add_async(one) for _ in range(4)]
+        table.read(table.get_async())
+        table.get()
+        for i in ids:
+            table.wait(i)
+    m.get_rows([0, 3])
+    started = set(threading.enumerate()) - before
+    assert not started, sorted(th.name for th in started)
+    assert not any(th.name.startswith("mv-add-")
+                   for th in threading.enumerate())
+    assert not config.has_flag("table_get_cache")
+    assert not hasattr(Table, "version") and not hasattr(t, "version")
+
+
+@pytest.mark.parametrize("updater", ["sgd", "adagrad"])
+def test_store_after_unwaited_add_async_holds_the_add(updater):
+    t, twin = _array(updater, "st_a"), _array(updater, "st_b")
+    opt = AddOption(learning_rate=0.1, rho=0.1)
+    rng = np.random.default_rng(3)
+    for _ in range(3):
+        t.add_async(rng.normal(size=t.shape).astype(np.float32), opt)
+    buf = io.BytesIO()
+    t.store(buf)                    # no wait between the adds and the store
+    buf.seek(0)
+    twin.load(buf)
+    assert np.any(twin.get() != 0)
+    np.testing.assert_array_equal(twin.get(), t.get())
+    for a, b in zip(jax.tree.leaves(twin.state["ustate"]),
+                    jax.tree.leaves(t.state["ustate"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

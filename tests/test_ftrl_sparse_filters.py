@@ -122,29 +122,15 @@ class TestFilters:
 
 
 class TestWireFilteredTables:
-    """wire_filter compresses the host<->device seam of whole-table Add/Get
-    (the TPU analogue of the reference's MPI wire filters,
-    quantization_util.h; decode runs in-graph, table.py)."""
-
-    def test_bf16_wire_roundtrip(self):
-        import multiverso_tpu as mv
-        t = mv.ArrayTable(4096, name="wf_bf16", wire_filter="bf16")
-        delta = np.random.default_rng(1).normal(size=4096).astype(np.float32)
-        t.add(delta)
-        t.add(delta)
-        got = t.get()
-        np.testing.assert_allclose(got, 2 * delta, rtol=2e-2, atol=2e-2)
-
-    def test_device_resident_delta_skips_filter(self):
-        import jax.numpy as jnp
-        import multiverso_tpu as mv
-        t = mv.ArrayTable(128, name="wf_dev", wire_filter="bf16")
-        dev = jnp.ones(128, jnp.float32)
-        t.add(dev)   # device array: already past the wire, applied exactly
-        np.testing.assert_allclose(t.get(), 1.0, rtol=1e-2)
+    """The sync tables have no link filter (PR 46: the bf16 cast tied the
+    plain path on Add and doubled the Get on the chip's own host link);
+    what compresses a wire is the PS plane's ``wire=``."""
 
     def test_unknown_filter_raises(self):
         import multiverso_tpu as mv
-        for mode in ("zstd", "1bit", "topk"):
-            with pytest.raises(ValueError):
+        from multiverso_tpu.table import Table
+        for mode in ("none", "bf16", "zstd", "1bit", "topk"):
+            with pytest.raises(TypeError, match="wire_filter"):
                 mv.ArrayTable(16, name="wf_bad", wire_filter=mode)
+            with pytest.raises(TypeError, match="wire_filter"):
+                Table((16,), name="wf_bad", wire_filter=mode)

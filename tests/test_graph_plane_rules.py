@@ -117,6 +117,27 @@ def test_functional_add_rows_then_adopt_is_add_rows(updater, entry):
     _check_rows(updater, entry, shards=1)
 
 
+@pytest.mark.parametrize("updater", UPDATERS)
+def test_pipelined_add_async_equals_the_rule_applied_in_order(updater):
+    """Four ``add_async`` issued before any ``wait`` are four applies of
+    the rule in issue order: data and every state leaf equal four
+    ``functional_add`` + ``adopt`` on the twin, bit for bit (sgd too: the
+    host plane sums no deltas before it applies them)."""
+    _init(1)
+    host, graph = _twins(_matrix, updater)
+    rng = np.random.default_rng(17)
+    deltas = [rng.normal(size=(ROWS, COLS)).astype(np.float32) * 10.0 ** k
+              for k in (0, -3, 3, -6)]
+    ids = [host.add_async(d, OPT) for d in deltas]
+    for d in deltas:
+        _through(graph, "state", graph.functional_add,
+                 graph.pad_delta(jnp.asarray(d)), OPT)
+    for i in ids:
+        host.wait(i)
+    _same(host, graph)
+    np.testing.assert_array_equal(graph.get(), host.get())
+
+
 @pytest.mark.parametrize("check", [_check_dense, _check_rows],
                          ids=["dense", "rows"])
 @pytest.mark.parametrize("updater", ["sgd", "adagrad"])

@@ -381,22 +381,6 @@ class TestComponentGauges:
         assert g["armed_frames"] == 0
         assert g["owners"]["1"]["retained_frames"] == 1
 
-    def test_sync_table_cache_gauges(self):
-        from multiverso_tpu import api as mv
-        mv.init()
-        try:
-            from multiverso_tpu.table import Table
-            t = Table((16, 4), name="syncmem")
-            g = t.memory_stats()
-            assert g == {"cache_bytes": 0}
-            t.get()
-            g = t.memory_stats()
-            assert g["cache_bytes"] == 16 * 4 * 4
-            assert any(k.startswith("table[syncmem]") for k in
-                       memstats.LEDGER.snapshot()["components"])
-        finally:
-            mv.shutdown()
-
     def test_replica_gauges(self, two_ranks):
         from multiverso_tpu.serving import ReadReplica
         t0 = AsyncMatrixTable(64, 4, name="repm", ctx=two_ranks[0],

@@ -112,8 +112,7 @@ def test_windowed_adds_read_your_writes(two_ranks):
 
 def test_window_counters_surface_in_dashboard(two_ranks):
     """The zoo shutdown report prints every registered monitor — the
-    window's three counters must exist (and tick) alongside the PR-1
-    ``.get.cached`` counter."""
+    window's three counters must exist (and tick)."""
     t = AsyncMatrixTable(8, 2, name="wc", send_window_ms=60_000.0,
                          ctx=two_ranks[0])
     AsyncMatrixTable(8, 2, name="wc", ctx=two_ranks[1])
