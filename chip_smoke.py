@@ -1,7 +1,7 @@
 """chip_smoke.py: prove that the system starts and computes correctly on
 the TPU it is measured on. One process, no arguments, no network.
 
-Seven stages, each driven through the entry points a user calls, each
+Eight stages, each driven through the entry points a user calls, each
 checked by the repo's own means (a NumPy float32 statement of the updater
 rule, a falling finite loss, ``jnp.take``,
 ``parallel.ring.reference_attention``):
@@ -16,6 +16,8 @@ rule, a falling finite loss, ``jnp.take``,
   lm      the 472M transformer step with the Pallas flash kernel
   flash   the language-model cells' flash kernel calls, a crossed pair as
           one tile against its sub-tiles: ms a call and compile seconds
+  ssd     the chunked state-space scan at the hybrid cell's shapes: ms
+          forward and backward, and its error against the recurrence
 
 and a closing ``memory`` check that every device ended up holding bytes.
 
@@ -864,6 +866,84 @@ def stage_flash(calls: Tuple = FLASH_CALLS, subs: Tuple = (),
     return out
 
 
+def stage_ssd(positions: int = 16384, heads: int = 64, head_dim: int = 64,
+              groups: int = 8, state: int = 128, chunk: int = 128,
+              repeats: int = 5) -> Dict[str, Any]:
+    """``ops/ssd.ssd_chunked`` as ``nemotron3n-train-16k`` calls it (one
+    sequence of ``positions``, bfloat16 operands): the seconds the compiler
+    took and the ms a call, forward and forward with every gradient, by
+    this process's clock around ``repeats`` calls it waits for; and ONE
+    group's output and gradients against the recurrence itself, a position
+    at a time in float32 (max|err| over max|reference|, as ``stage_lm``).
+    Inputs are drawn as the mixer makes them: ``x``, ``B``, ``C`` a silu
+    of unit normals, step sizes and ``A`` by the Mamba-2 rule."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.ops.ssd import ssd_chunked
+
+    k = jax.random.split(jax.random.key(SEED), 7)
+    per = heads // groups
+    x = jax.nn.silu(jax.random.normal(k[0], (1, positions, heads, head_dim)))
+    b, c = (jax.nn.silu(jax.random.normal(key, (1, positions, groups, state)))
+            for key in k[1:3])
+    step = jnp.exp(jax.random.uniform(k[3], (heads,), minval=np.log(1e-3),
+                                      maxval=np.log(0.1)))
+    dt = jax.nn.softplus(jax.random.normal(k[4], (1, positions, heads))
+                         + step + jnp.log(-jnp.expm1(-step)))
+    a = -jax.random.uniform(k[5], (heads,), minval=1.0, maxval=16.0)
+    weight = jax.random.normal(k[6], x.shape)
+    args = (x, dt, a, b, c)
+    scan = lambda *t: ssd_chunked(*t, chunk)
+    both = lambda *t: jax.value_and_grad(
+        lambda *u: jnp.sum(weight[:, :, :u[0].shape[2]] * scan(*u)),
+        range(5))(*t)
+    facts: Dict[str, Any] = {}
+    for name, fn in (("fwd", scan), ("fwd_bwd", both)):
+        t0 = time.perf_counter()
+        compiled = jax.jit(fn).lower(*args).compile()
+        facts[f"{name}_compile_s"] = round(time.perf_counter() - t0, 2)
+        jax.block_until_ready(compiled(*args))
+        t0 = time.perf_counter()
+        for _ in range(repeats):
+            res = compiled(*args)
+        jax.block_until_ready(res)
+        facts[f"{name}_ms"] = round(
+            (time.perf_counter() - t0) / repeats * 1e3, 3)
+
+    def recurrence(x, dt, a, b, c):
+        b, c = (jnp.repeat(t[0], per, axis=1) for t in (b, c))
+
+        def one(h, each):
+            xt, dtt, bt, ct = each
+            h = (jnp.exp(dtt * a)[:, None, None] * h
+                 + (dtt[:, None] * xt)[:, :, None] * bt[:, None, :])
+            return h, jnp.sum(h * ct[:, None, :], -1)
+
+        stretch = jax.checkpoint(lambda h, each: jax.lax.scan(one, h, each))
+        each = jax.tree.map(
+            lambda t: t.reshape((-1, min(256, positions)) + t.shape[1:]),
+            (x[0], dt[0], b, c))
+        _, y = jax.lax.scan(stretch, jnp.zeros((per, head_dim, state)), each)
+        return y.reshape((1, positions, per, head_dim))
+
+    one_group = (x[:, :, :per], dt[:, :, :per], a[:per], b[:, :, :1],
+                 c[:, :, :1])
+    want = jax.jit(lambda *t: jax.value_and_grad(
+        lambda *u: jnp.sum(weight[:, :, :per] * recurrence(*u)),
+        range(5))(*t))(*one_group)
+    got = jax.jit(both)(*one_group)
+    y_err = float(jnp.max(jnp.abs(scan(*one_group) - recurrence(*one_group)))
+                  / jnp.max(jnp.abs(recurrence(*one_group))))
+    errs = [y_err] + [float(jnp.max(jnp.abs(g - w)) / jnp.max(jnp.abs(w)))
+                      for g, w in zip(got[1], want[1])]
+    if not max(errs) <= ATTN_BF16_TOL:      # a NaN fails too
+        raise AssertionError(f"ssd: relative error {errs} (y, dx, ddt, da, "
+                             f"db, dc) > {ATTN_BF16_TOL}")
+    facts["rel_err_y_dx_ddt_da_db_dc"] = [round(e, 5) for e in errs]
+    return facts
+
+
 def stage_memory() -> Dict[str, Any]:
     """After the run every device holds bytes: every chip was used."""
     import jax
@@ -902,7 +982,8 @@ def result_line(ok: bool, device: Dict[str, Any]) -> Dict[str, Any]:
 STAGES: Tuple[Tuple[str, Callable[[], Dict[str, Any]]], ...] = (
     ("tables", stage_tables), ("we", stage_we), ("rows", stage_rows),
     ("ps", stage_ps),
-    ("lm", stage_lm), ("flash", stage_flash), ("memory", stage_memory))
+    ("lm", stage_lm), ("flash", stage_flash), ("ssd", stage_ssd),
+    ("memory", stage_memory))
 
 
 def main() -> int:

@@ -84,6 +84,7 @@ class AFMoEConfig(NamedTuple):
     yarn = None                      # ``rope_scaling: null``
     post_norms = True                # ``mla_moe.block``'s
     route = "sigmoid"                # parallel/moe.HeldExperts.route
+    expert_form = "gated_silu"       # parallel/moe.HeldExperts.form
     balance_coef = 0.0               # no load-balance term in the loss
 
     @property
@@ -93,3 +94,7 @@ class AFMoEConfig(NamedTuple):
     @property
     def kv_group(self) -> int:       # query heads a key-value head
         return self.n_heads // self.n_kv_heads
+
+    @property
+    def shared_ffn(self) -> int:     # the shared expert's width
+        return self.moe_ffn

@@ -107,6 +107,10 @@ class GQAMoEConfig(NamedTuple):
     def embed_scale(self) -> float:
         return 1.0
 
+    @property
+    def expert_form(self) -> str:    # parallel/moe.HeldExperts.form
+        return "gated_silu"
+
 
 def gqa_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
     """The parameters of a block's attention and its two input norms."""

@@ -63,10 +63,12 @@ from multiverso_tpu.updaters import AddOption
 
 
 class Layer(NamedTuple):
-    """One block of a decoder, as data."""
-    name: str       # its parameters' prefix: "L<i>", or "mtp"
-    attn: str       # "latent", "full" or "window"
-    ffn: str        # "dense", "shared+experts" or "experts"
+    """One block of a decoder, as data. A block has two branches, ``attn``
+    then ``ffn``, or ONE mixer (the other is ``None``: one norm, one
+    residual sum)."""
+    name: str                   # its parameters' prefix: "L<i>", or "mtp"
+    attn: Optional[str]         # "latent", "full", "window" or "ssm"
+    ffn: Optional[str]          # "dense", "shared+experts" or "experts"
 
 
 class MLAMoEConfig(NamedTuple):
@@ -141,6 +143,14 @@ class MLAMoEConfig(NamedTuple):
     def embed_scale(self) -> float:
         return 1.0
 
+    @property
+    def expert_form(self) -> str:    # parallel/moe.HeldExperts.form
+        return "gated_silu"
+
+    @property
+    def shared_ffn(self) -> int:     # the shared expert's width
+        return self.moe_ffn
+
 
 # The held experts' buffer, in rows, for a layer's ``tokens``: twice what
 # an even router sends here, and never under the floor (the loads of a few
@@ -159,7 +169,7 @@ def held(cfg, tokens: int) -> moe.HeldExperts:
     return moe.HeldExperts(
         num_experts=cfg.n_experts, experts_held=cfg.experts_held,
         expert_offset=cfg.expert_offset, top_k=cfg.top_k,
-        routed_scale=cfg.routed_scale, route=cfg.route,
+        routed_scale=cfg.routed_scale, route=cfg.route, form=cfg.expert_form,
         tile=moe.product_tile(cfg.dim, cfg.moe_ffn), dtype=cfg.compute_dtype,
         buffer_rows=min(most, max(BUFFER_OVER_EVEN * even, BUFFER_FLOOR)))
 
@@ -167,7 +177,8 @@ def held(cfg, tokens: int) -> moe.HeldExperts:
 def expert_layers(cfg) -> Tuple[str, ...]:
     """The layers that have a router, in the order of the bias rows and of
     the step's counts (the prediction module, where there is one, last)."""
-    return tuple(layer.name for layer in cfg.layers() if layer.ffn != "dense")
+    return tuple(layer.name for layer in cfg.layers()
+                 if layer.ffn not in (None, "dense"))
 
 
 # ---------------------------------------------------------------------- #
@@ -195,7 +206,11 @@ def _ffn_shapes(cfg, kind: str) -> Dict[str, Tuple[int, ...]]:
     out = {"router": (cfg.n_experts, d),        # a row an expert
            "eg": (e, d, f), "eu": (e, d, f), "ed": (e, f, d)}
     if kind == "shared+experts":
+        f = cfg.shared_ffn
         out.update(sg=(d, f), su=(d, f), sd=(f, d))
+    if cfg.expert_form == "relu2":              # two matrices: no gate
+        del out["eg"]
+        out.pop("sg", None)
     return out
 
 
@@ -207,8 +222,13 @@ def param_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
     out = {"embed": (cfg.vocab, d), "head": (cfg.vocab, d),
            "final_norm": (d,)}
     for layer in cfg.layers():
-        block = dict(cfg.attn_shapes(layer.attn),
-                     **_ffn_shapes(cfg, layer.ffn))
+        # an attention kind's shapes bring the block's two input norms
+        block = (dict(cfg.attn_shapes(layer.attn)) if layer.attn
+                 else {"ffn_norm": (d,)})
+        if layer.ffn:
+            block.update(_ffn_shapes(cfg, layer.ffn))
+        else:
+            del block["ffn_norm"]
         if cfg.post_norms:
             block.update(attn_post_norm=(d,), ffn_post_norm=(d,))
         if layer.name == "mtp":
@@ -218,12 +238,35 @@ def param_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
     return out
 
 
-def _draw(shape, key, scale: float, norm: bool, pad: int = 0) -> jax.Array:
-    """A parameter's first values: ones for a norm, else Normal(0, scale);
-    ``pad`` zero rows after them (a table's padding)."""
-    x = (jnp.ones(shape, jnp.float32) if norm
-         else scale * jax.random.normal(key, shape, jnp.float32))
+def _draw(shape, key, scale: float, rule, pad: int = 0) -> jax.Array:
+    """A parameter's first values by ``rule`` (:func:`_rule_of`): ``"ones"``,
+    ``"normal"`` (Normal(0, scale)), ``("log_uniform", lo, hi)`` (the log of
+    a uniform draw from [lo, hi]) or ``("softplus_inverse", lo, hi, floor)``
+    (``b`` with ``softplus(b)`` log-uniform in [lo, hi], no less than
+    ``floor``); ``pad`` zero rows after them (a table's padding)."""
+    if rule == "ones":
+        x = jnp.ones(shape, jnp.float32)
+    elif rule == "normal":
+        x = scale * jax.random.normal(key, shape, jnp.float32)
+    elif rule[0] == "log_uniform":
+        x = jnp.log(jax.random.uniform(key, shape, jnp.float32, *rule[1:]))
+    elif rule[0] == "softplus_inverse":
+        lo, hi, floor = rule[1:]
+        step = jnp.maximum(floor, jnp.exp(jax.random.uniform(
+            key, shape, jnp.float32, np.log(lo), np.log(hi))))
+        x = step + jnp.log(-jnp.expm1(-step))
+    else:
+        raise ValueError(f"no first values by the rule {rule!r}")
     return jnp.pad(x, [(0, pad)] + [(0, 0)] * (len(shape) - 1))
+
+
+def _rule_of(cfg, name: str):
+    """:func:`_draw`'s rule for a parameter: ones for a norm, what the
+    configuration's ``first_values`` says of the last part of its name
+    where it has such a table, else Normal(0, scale)."""
+    if name.endswith("norm"):
+        return "ones"
+    return getattr(cfg, "first_values", {}).get(name.split(".")[-1], "normal")
 
 
 def _keys(cfg, seed: int):
@@ -245,13 +288,15 @@ def _scale_of(name: str, scale: float,
 
 def init(cfg, seed: int = 0, scale: float = 0.02,
          scales: Optional[Dict[str, float]] = None) -> Dict[str, jax.Array]:
-    """Parameters from ``seed``: Normal(0, scale) matrices, norms of ones;
-    ``scales`` gives a kind of parameter its own scale by the last part of
+    """Parameters from ``seed``: Normal(0, scale) matrices, norms of ones,
+    and what the configuration's ``first_values`` draws by a rule of its
+    own (:func:`_draw`); ``scales`` gives a kind of parameter its own
+    scale by the last part of
     its name (``{"router": 0.01, "embed": 1.0}``). The same values
     :func:`make_tables` puts into the tables."""
     return {name: _draw(table_shape(shape), key,
                         _scale_of(name, scale, scales),
-                        name.endswith("norm")).reshape(shape)
+                        _rule_of(cfg, name)).reshape(shape)
             for name, shape, key in _keys(cfg, seed)}
 
 
@@ -287,7 +332,7 @@ def make_tables(cfg, seed: int = 0, scale: float = 0.02,
         draw = jax.jit(_draw, static_argnums=(0, 2, 3, 4),
                        out_shardings=table.sharding)
         data = draw(shape, key, _scale_of(name, scale, scales),
-                    name.endswith("norm"), table.padded_shape[0] - shape[0])
+                    _rule_of(cfg, name), table.padded_shape[0] - shape[0])
         table.adopt({"data": data, "ustate": table.state["ustate"]})
         tables[name] = table
     return tables
@@ -430,7 +475,11 @@ def attn_grid(cfg, s: int) -> Dict[str, Any]:
     it needs; nothing where XLA is the core."""
     if attn_core(cfg) != "flash":
         return {}
-    kinds = [layer.attn for layer in cfg.layers()]
+    layers = cfg.layers()
+    # the kinds whose core is the flash kernel
+    kinds = [layer.attn for layer in layers
+             if layer.attn not in (None, "ssm")]
+    branches = max(bool(layer.attn) + bool(layer.ffn) for layer in layers)
     blocks = attn_blocks(cfg, s)
     sub = sub_tile(*blocks, cfg.head_size)
     n = causal_pairs(s, *blocks, sub=sub)
@@ -440,7 +489,7 @@ def attn_grid(cfg, s: int) -> Dict[str, Any]:
            "attn_positions_needed": n["needed"],
            "attn_kinds": ",".join(kinds),
            "kv_group": cfg.kv_group,
-           "block_norms": 4 if cfg.post_norms else 2,
+           "block_norms": (2 if cfg.post_norms else 1) * branches,
            "embed_scale": float(cfg.embed_scale)}
     if "window" in kinds:
         band = causal_pairs(s, *blocks, cfg.window, sub)
@@ -451,6 +500,23 @@ def attn_grid(cfg, s: int) -> Dict[str, Any]:
                    attn_positions_needed_window=band["needed"],
                    attn_gated=int(cfg.attn_gate), qk_norm=int(cfg.qk_norm),
                    rope_kinds=",".join(cfg.rope_kinds))
+    return out
+
+
+def mixer_grid(cfg, s: int) -> Dict[str, Any]:
+    """What a layer list of one-mixer blocks adds to the ``lm.step`` span:
+    every block's kind in order, the experts' form and, where some block
+    is a state-space mixer, its scan's static counts over ``s`` positions
+    (``cfg.ssm_grid``); nothing for a list of two-branch blocks."""
+    layers = cfg.layers()
+    if all(layer.attn and layer.ffn for layer in layers):
+        return {}
+    out = {"block_kinds": ",".join(layer.attn or layer.ffn
+                                   for layer in layers),
+           "expert_form": cfg.expert_form}
+    ssm = sum(layer.attn == "ssm" for layer in layers)
+    if ssm:
+        out.update(ssm_layers=ssm, **cfg.ssm_grid(s))
     return out
 
 
@@ -519,23 +585,35 @@ def dense_ffn(u, p, cfg):
         return gated_mlp(u, p["wg"], p["wu"], p["wd"], cfg), None
 
 
+def relu2_mlp(u, wu, wd, cfg):
+    """``relu(u W_u)^2 W_d``: the two-matrix MLP."""
+    mm = functools.partial(matmul, dtype=cfg.compute_dtype)
+    hidden = jnp.square(jax.nn.relu(mm(u, wu, False, out_dtype=jnp.float32)))
+    return mm(hidden, wd, False, out_dtype=jnp.float32)
+
+
 def expert_ffn(u, p, bias, cfg, shared: bool = True):
     """The held experts' part under the configuration's route, beside
     ``Shared(u)`` where the layer has a shared expert and alone where it
-    has none; aux = (counts [E], overflow_rows, the route's load-balance
-    term)."""
+    has none; experts and shared expert are of the configuration's
+    ``expert_form`` (gated silu, three matrices; or ``relu2``, two), the
+    shared one at its own width; aux = (counts [E], overflow_rows, the
+    route's load-balance term)."""
     b, s, d = u.shape
     f = cfg.moe_ffn
+    gated = cfg.expert_form == "gated_silu"
     if shared:
         with jax.named_scope("mv.lm.moe.shared"):
-            beside = gated_mlp(u, p["sg"], p["su"], p["sd"], cfg)
+            beside = (gated_mlp(u, p["sg"], p["su"], p["sd"], cfg) if gated
+                      else relu2_mlp(u, p["su"], p["sd"], cfg))
+    stacked = (("w_gate", "eg", (d, f)), ("w_up", "eu", (d, f)),
+               ("w_down", "ed", (f, d)))
+    weights = dict(router=p["router"], **{
+        role: p[name].reshape((cfg.experts_held,) + shape)
+        for role, name, shape in stacked if gated or role != "w_gate"})
     routed, counts, overflow, balance = moe.held_expert_layer(
-        u.reshape(b * s, d),
-        {"router": p["router"],
-         "w_gate": p["eg"].reshape(cfg.experts_held, d, f),
-         "w_up": p["eu"].reshape(cfg.experts_held, d, f),
-         "w_down": p["ed"].reshape(cfg.experts_held, f, d)},
-        bias, held(cfg, b * s), cfg.expert_kernel)
+        u.reshape(b * s, d), weights, bias, held(cfg, b * s),
+        cfg.expert_kernel)
     routed = routed.reshape(b, s, d)
     return (beside + routed if shared else routed), (counts, overflow,
                                                      balance)
@@ -544,18 +622,23 @@ def expert_ffn(u, p, bias, cfg, shared: bool = True):
 def block(x, p, attn, ffn, cfg):
     """The one block: ``attn`` and ``ffn`` take the normed input and the
     block's parameters; with ``cfg.post_norms`` each branch's output is
-    normed as well before it joins the stream (four norms a block). Every
-    layer of every kind calls it. Returns (y, ffn's aux)."""
+    normed as well before it joins the stream (four norms a block). A
+    block of one mixer has ``None`` for the other branch, and one norm.
+    Every layer of every kind calls it. Returns (y, ffn's aux)."""
     def out(branch, name):
         if not cfg.post_norms:
             return branch
         with jax.named_scope("mv.lm.norm.post"):
             return rms_norm(branch, p[name], cfg.eps)
 
-    h = x + out(attn(rms_norm(x, p["attn_norm"], cfg.eps), p),
-                "attn_post_norm")
-    f, aux = ffn(rms_norm(h, p["ffn_norm"], cfg.eps), p)
-    return h + out(f, "ffn_post_norm"), aux
+    h, aux = x, None
+    if attn is not None:
+        h = x + out(attn(rms_norm(x, p["attn_norm"], cfg.eps), p),
+                    "attn_post_norm")
+    if ffn is not None:
+        f, aux = ffn(rms_norm(h, p["ffn_norm"], cfg.eps), p)
+        h = h + out(f, "ffn_post_norm")
+    return h, aux
 
 
 def _sub(params: Dict[str, Any], prefix: str) -> Dict[str, Any]:
@@ -566,8 +649,11 @@ def _sub(params: Dict[str, Any], prefix: str) -> Dict[str, Any]:
 def _run_block(x, p, layer: Layer, bias, cfg, remat: bool = True):
     """A block of ``layer``'s kinds, rematerialised unless told not to;
     ``bias`` is its router's (a dense layer has none)."""
-    attn = lambda u, q: cfg.attend(u, q, layer.attn)
-    if layer.ffn == "dense":
+    attn = (None if layer.attn is None
+            else lambda u, q: cfg.attend(u, q, layer.attn))
+    if layer.ffn is None:
+        ffn = None
+    elif layer.ffn == "dense":
         ffn = lambda u, q: dense_ffn(u, q, cfg)
     else:
         ffn = lambda u, q: expert_ffn(u, q, bias, cfg,
@@ -811,7 +897,8 @@ class Trainer:
         self.steps = 0
         # (loss, counts, balance) of a step not read back yet
         self._ahead = None
-        self._attn: Dict[str, int] = {}     # attn_grid of the first step
+        # attn_grid and mixer_grid of the first step
+        self._attn: Dict[str, Any] = {}
         # closes a step's ``lm.step.device`` span when the device is done
         # with it; idle unless a capture or ``trace_ids`` can read it
         self._watcher = _trace.DeviceWatcher()
@@ -824,7 +911,9 @@ class Trainer:
             due = self._ahead
             if tokens is not None:
                 if self.steps == 1:     # one program, one shape
-                    self._attn = attn_grid(self.cfg, int(tokens.shape[1]))
+                    positions = int(tokens.shape[1])
+                    self._attn = dict(attn_grid(self.cfg, positions),
+                                      **mixer_grid(self.cfg, positions))
                 sp.set(tokens=int(np.prod(tokens.shape)))
                 t0_ns = time.time_ns()
                 self.states, self.bias, *back = self._step(

@@ -231,6 +231,9 @@ class HeldExperts(NamedTuple):
     tile: Tuple[int, int, int] = (128, 128, 128)
     dtype: Any = jnp.bfloat16    # the products' operands (float32 sums)
     route: str = "sigmoid"       # or "softmax"
+    # an expert's MLP: "gated_silu" ((silu(x W_gate) * (x W_up)) W_down,
+    # three matrices) or "relu2" (relu(x W_up)^2 W_down, two)
+    form: str = "gated_silu"
 
 
 def sigmoid_route(u: jax.Array, router: jax.Array, bias: jax.Array,
@@ -347,7 +350,8 @@ def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
 
     ``u`` [T, D]; ``params``: ``router`` [E, D], ``w_gate`` / ``w_up``
     [H, D, F] and ``w_down`` [H, F, D] for the H held experts (numbers
-    ``expert_offset`` to ``expert_offset + H - 1`` of the E). Returns
+    ``expert_offset`` to ``expert_offset + H - 1`` of the E); an expert of
+    the ``relu2`` form (``cfg.form``) has no ``w_gate``. Returns
     (``sum over the chosen experts held here of gate * expert(u)`` [T, D]
     float32, counts [E] int32, overflow_rows int32, balance float32).
     ``cfg.route`` names the route: ``"sigmoid"`` (:func:`sigmoid_route`
@@ -405,8 +409,14 @@ def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
                                    interpret=kernel == "interpret")
             up = functools.partial(mm, tile=tile)       # dim -> ffn
             down = functools.partial(mm, tile=(tile[0], tile[2], tile[1]))
-        h = (jax.nn.silu(up(x, params["w_gate"]).astype(jnp.float32))
-             * up(x, params["w_up"]).astype(jnp.float32))
+        if cfg.form == "gated_silu":
+            h = (jax.nn.silu(up(x, params["w_gate"]).astype(jnp.float32))
+                 * up(x, params["w_up"]).astype(jnp.float32))
+        elif cfg.form == "relu2":
+            h = jnp.square(jax.nn.relu(
+                up(x, params["w_up"]).astype(jnp.float32)))
+        else:
+            raise ValueError(f"no expert form named {cfg.form!r}")
         y = down(h.astype(cfg.dtype), params["w_down"])
     with jax.named_scope("mv.lm.moe.combine"):
         out = jnp.zeros((t, d), jnp.float32).at[token].add(

@@ -126,6 +126,18 @@ def test_flash_stage():
             assert max(call[f"{tag}_vs_whole"]) <= 1e-2     # bfloat16 results
 
 
+def test_ssd_stage():
+    """The chunked scan at a small size: ms and compile seconds forward
+    and with every gradient, one group held to the recurrence."""
+    facts = chip_smoke.stage_ssd(positions=256, heads=8, head_dim=8,
+                                 groups=2, state=16, chunk=32, repeats=1)
+    for name in ("fwd", "fwd_bwd"):
+        assert facts[f"{name}_ms"] > 0 and facts[f"{name}_compile_s"] >= 0
+    errs = facts["rel_err_y_dx_ddt_da_db_dc"]
+    assert len(errs) == 6 and max(errs) <= chip_smoke.ATTN_BF16_TOL
+    assert "ssd" in dict(chip_smoke.STAGES)
+
+
 def test_main_refuses_a_cpu(capsys):
     """No TPU: non-zero exit, the reason on stderr, no result on stdout."""
     assert chip_smoke.main() == chip_smoke.EXIT_NO_CHIP

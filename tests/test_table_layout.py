@@ -755,3 +755,60 @@ def test_sigmoid_expert_layer_over_128_compiles_for_a_v5e(one_chip):
         shape(16384, 2048), shape(128, 2048), shape(16, 2048, 1024),
         shape(16, 2048, 1024), shape(16, 1024, 2048)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 9
+
+
+def test_relu2_expert_layer_at_rows_of_1856_compiles_for_a_v5e(one_chip):
+    """The held experts' grouped products as ``nemotron3n-train-16k`` calls
+    them: TWO products an expert (no gate matrix), a 12,288-row buffer in
+    eight groups of 2,688 x 1,856. 1,856 is 14.5 lanes of 128: no multiple
+    of 128 divides it, so the products take the kernel's own 128 over that
+    width (its last tile half full) and 896 over 2,688."""
+    from multiverso_tpu.parallel import moe
+
+    tile = moe.product_tile(2688, 1856)
+    assert tile == (128, 896, 128)
+    held = moe.HeldExperts(num_experts=128, experts_held=8, top_k=6,
+                           routed_scale=2.5, buffer_rows=12288, tile=tile,
+                           form="relu2")
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+
+    def experts(u, router, wu, wd):
+        out, counts, overflow, _ = moe.held_expert_layer(
+            u, {"router": router, "w_up": wu, "w_down": wd},
+            jnp.zeros((128,)), held, kernel="pallas")
+        return out.sum(), (counts, overflow)
+
+    compiled = jax.jit(jax.grad(experts, argnums=(0, 1, 2, 3),
+                                has_aux=True)).lower(
+        shape(16384, 2688), shape(128, 2688), shape(8, 2688, 1856),
+        shape(8, 1856, 2688)).compile()
+    # forward, the input's and the weight's gradient, for two matrices
+    assert compiled.as_text().count("tpu_custom_call") >= 6
+
+
+def test_attention_at_sixteen_query_heads_a_key_value_head_compiles_for_a_v5e(
+        one_chip, monkeypatch):
+    """``models/gqa_moe.gqa`` under ``models/nemotron_h.py``'s switches (all
+    off, no positions) as ``nemotron3n-train-16k`` calls it: 32 query heads
+    of 128 over TWO key-value heads, one sequence of 16,384 positions at
+    1,024 x 1,024 blocks, forward and backward."""
+    from multiverso_tpu.models import mla_moe, nemotron_h
+    from multiverso_tpu.ops import attention_kernels
+
+    # the process's devices are the CPU's: the kernels would be interpreted
+    monkeypatch.setattr(attention_kernels, "_resolve_interpret",
+                        lambda interpret: False)
+    cfg = nemotron_h.NemotronHConfig(dim=2688, n_heads=32, n_kv_heads=2,
+                                     head_dim=128, attn="flash")
+    assert mla_moe.attn_blocks(cfg, 16384) == (1024, 1024)
+    assert cfg.kv_group == 16
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {n: f32(*s) for n, s in cfg.attn_shapes("full").items()}
+
+    def attend(u, p):
+        return cfg.attend(u, p, "full").sum()
+
+    text = jax.jit(jax.grad(attend, argnums=(0, 1))).lower(
+        f32(1, 16384, 2688), p).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3       # forward, dQ, dK with dV
+    assert "bf16[2,16384,128]" in text and "bf16[32,16384,128]" in text
