@@ -693,6 +693,83 @@ def _attention_errors(shape: Tuple[int, int, int, int], block,
     return errs
 
 
+# (name, dim, expert width, experts held, buffer rows, form): ONE expert
+# layer of each language-model cell as ``models/mla_moe.held`` sizes it
+# (the buffer twice the even load)
+EXPERT_CALLS = (
+    ("glm47f-train-8k", 2048, 1536, 8, 16384, "gated_silu"),
+    ("mellum2-train-8k", 2304, 896, 16, 65536, "gated_silu"),
+    ("trinity-train-16k", 2048, 1024, 16, 32768, "gated_silu"),
+    ("nemotron3n-train-16k", 2688, 1856, 8, 12288, "relu2"),
+)
+
+
+def _expert_products(dim: int, ffn: int, held: int, rows: int, form: str,
+                     kernel: str = "pallas", repeats: int = 5
+                     ) -> Dict[str, Any]:
+    """``parallel/moe.expert_products`` over a buffer half full at an even
+    load, forward and with the gradient of the buffer and of every
+    matrix, at the tile ``moe.product_tile`` gives the widths: the ms a
+    call with the padding in the last expert's group, as the layer had it
+    before PR 48 (``experts_ms_padded``), and with it in no group
+    (``experts_ms``); ONE program, the groups are data. Beside them the
+    row tiles a forward product visits either way, and how far the live
+    rows' results and the gradients of the two lie apart (0: the same
+    tiles do the same work)."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.parallel import moe
+
+    cfg = moe.HeldExperts(num_experts=held, experts_held=held, form=form,
+                          tile=moe.product_tile(dim, ffn), buffer_rows=rows)
+    k = jax.random.split(jax.random.key(SEED), 5)
+    each = rows // 2 // held
+    live = (jnp.arange(rows) < each * held)[:, None]
+    x = jnp.where(live, jax.random.normal(k[0], (rows, dim)), 0).astype(
+        cfg.dtype)
+    weight = jax.random.normal(k[1], (rows, dim))
+    params = {"w_up": 0.02 * jax.random.normal(k[2], (held, dim, ffn)),
+              "w_down": 0.02 * jax.random.normal(k[3], (held, ffn, dim))}
+    if form == "gated_silu":
+        params["w_gate"] = 0.02 * jax.random.normal(k[4], (held, dim, ffn))
+
+    def both(x, params, groups):
+        def loss(x, params):
+            y = moe.expert_products(x, params, groups, cfg, kernel)
+            y = jnp.where(live, y.astype(jnp.float32), 0.0)
+            return jnp.sum(y * weight), y
+        (_, y), (dx, dp) = jax.value_and_grad(loss, (0, 1), has_aux=True)(
+            x, params)
+        return y, jnp.where(live, dx.astype(jnp.float32), 0.0), dp
+
+    ours = jnp.full((held,), each, jnp.int32)
+    padded = ours.at[-1].add(rows - each * held)
+    compiled = jax.jit(both).lower(x, params, ours).compile()
+    facts: Dict[str, Any] = {"tile": list(cfg.tile), "rows": rows,
+                             "held_rows": each * held}
+    results = {}
+    for name, groups in (("experts_ms_padded", padded), ("experts_ms", ours)):
+        results[name] = jax.block_until_ready(compiled(x, params, groups))
+        t0 = time.perf_counter()
+        for _ in range(repeats):
+            res = compiled(x, params, groups)
+        jax.block_until_ready(res)
+        facts[name] = round((time.perf_counter() - t0) / repeats * 1e3, 3)
+        facts[name.replace("_ms", "_tiles")] = moe.product_tiles(
+            np.asarray(groups), rows, cfg.tile[0])
+    apart = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                   - b.astype(jnp.float32)))
+                   / jnp.max(jnp.abs(b.astype(jnp.float32))))
+             for a, b in zip(jax.tree.leaves(results["experts_ms"]),
+                             jax.tree.leaves(results["experts_ms_padded"]))]
+    facts["apart_from_padded"] = max(apart)
+    if not max(apart) <= 1e-2:      # NaN too
+        raise AssertionError(f"grouped products without the padding lie "
+                             f"{apart} from those with it")
+    return facts
+
+
 def stage_lm(vocab: int = 32768, dim: int = 2048, heads: int = 16,
              layers: int = 8, seq: int = 1024, batch_per_chip: int = 2,
              kernel_shapes: Tuple = (((2, 16, 1024, 128), 512),
@@ -704,11 +781,14 @@ def stage_lm(vocab: int = 32768, dim: int = 2048, heads: int = 16,
                                      # reference's float32 [S, S] fits
                                      ((1, 4, 8192, 256), 512),
                                      ((1, 4, 8192, 256), (512, 1024))),
+             expert_calls: Tuple = EXPERT_CALLS,
              chip: bool = True) -> Dict[str, Any]:
     """The widest model the repo runs (472M, d2048/L8, bf16) with the
     Pallas flash kernel: three donated train steps on a fixed batch, then
     the kernel alone against reference_attention. With several devices the
-    batch shards over the mesh and the kernel runs under shard_map."""
+    batch shards over the mesh and the kernel runs under shard_map. Then
+    the language-model cells' grouped products alone
+    (:func:`_expert_products`)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -750,11 +830,15 @@ def stage_lm(vocab: int = 32768, dim: int = 2048, heads: int = 16,
     del params
     kernel = {f"{shape}/{block}": _attention_errors(shape, block, SEED + i)
               for i, (shape, block) in enumerate(kernel_shapes)}
+    experts = {call[0]: _expert_products(
+        *call[1:], kernel="interpret" if interpret else "pallas")
+               for call in expert_calls}
     return {"params": n_params, "global_batch": b, "seq": seq,
             "batch_axis": cfg.batch_axis, "interpret": interpret,
             "mosaic_custom_call": mosaic,
             "loss": [round(x, 4) for x in losses],
-            "kernel_rel_err": kernel, "kernel_tol": ATTN_BF16_TOL}
+            "kernel_rel_err": kernel, "kernel_tol": ATTN_BF16_TOL,
+            "experts": experts}
 
 
 # (name, q's shape, key-value heads, (block_q, block_k), window): the

@@ -865,17 +865,27 @@ def routing_counts(counts: np.ndarray, cfg) -> Dict[str, Any]:
     """What a step's [layers, E + 1] array says: ``routed_rows`` (token x
     expert assignments over all layers), ``held_rows`` (those to experts
     held here), ``overflow_rows``, ``load_max_over_mean`` (the busiest of
-    all E experts over the mean, worst layer) and ``expert_rows`` (the
+    all E experts over the mean, worst layer), ``expert_rows`` (the
     array's [layers][E] part as lists, so that a reader can add steps up
-    before it asks which expert was busiest)."""
+    before it asks which expert was busiest) and, of the held experts'
+    grouped products, ``product_tiles_visited`` (the row tiles ONE forward
+    product visits, summed over the layers: ``moe.product_tiles``) beside
+    ``product_tiles_buffer`` (the row tiles the layers' buffers hold)."""
     counts = np.asarray(counts)
     c = counts[:, :cfg.n_experts]
     lo = cfg.expert_offset
+    mine = c[:, lo:lo + cfg.experts_held]
+    # every token chooses top_k experts: a layer's counts say its tokens
+    tokens = int(c[0].sum()) // cfg.top_k
+    here = held(cfg, tokens)
+    rows, tm = moe.buffer_length(here, tokens), here.tile[0]
     return {"routed_rows": int(c.sum()),
-            "held_rows": int(c[:, lo:lo + cfg.experts_held].sum()),
+            "held_rows": int(mine.sum()),
             "overflow_rows": int(counts[:, cfg.n_experts].sum()),
             "load_max_over_mean": float(np.max(c.max(1) / c.mean(1))),
-            "expert_rows": c.tolist()}
+            "expert_rows": c.tolist(),
+            "product_tiles_visited": moe.product_tiles(mine, rows, tm),
+            "product_tiles_buffer": len(c) * rows // tm}
 
 
 class Trainer:

@@ -27,8 +27,9 @@ or a softmax with the load-balance term its family trains by), and
 computes its own experts' part of the result without dropping a token.
 The rows routed here lie sorted by expert in one buffer, and the three
 products run over that buffer as grouped matrix products (Pallas
-``megablox``), so their cost follows the buffer's length and not the
-busiest expert. On one chip it runs without its exchange.
+``megablox``) whose groups end where the routed rows end, so their cost
+follows the rows routed here: not the busiest expert, and not the
+buffer's padding. On one chip it runs without its exchange.
 """
 
 from __future__ import annotations
@@ -308,11 +309,19 @@ def product_tile(dim: int, ffn: int) -> Tuple[int, int, int]:
 def grouped_matmul(lhs, rhs, group_sizes, tile, interpret: bool,
                    dtype=jnp.bfloat16):
     """``lhs[rows of group g] @ rhs[g]`` for consecutive row groups of
-    ``group_sizes`` (they sum to ``lhs.shape[0]``) as one Pallas kernel
-    (``megablox``): operands and result in ``dtype`` (``lhs`` comes in
-    it), float32 accumulation. ``rhs`` is float32 [G, K, N] (a table's
-    data) and takes a float32 gradient. ``tile``: (m, k, n) of THIS
-    product."""
+    ``group_sizes`` as one Pallas kernel (``megablox``): operands and
+    result in ``dtype`` (``lhs`` comes in it), float32 accumulation.
+    ``rhs`` is float32 [G, K, N] (a table's data) and takes a float32
+    gradient. ``tile``: (m, k, n) of THIS product.
+
+    The groups may sum to fewer rows than ``lhs`` has: the rows past the
+    last group belong to no group, the kernel's grid is the row tiles
+    that hold a group's rows (:func:`product_tiles`), and the rows past
+    the last group are UNWRITTEN memory in the result and in ``lhs``'s
+    gradient (whatever the buffer held, a NaN perhaps), so select them
+    away before anything sums over them. What ``lhs`` and the cotangent
+    hold there reaches nothing: a row of the result reads its own row of
+    ``lhs`` alone, and ``rhs``'s gradient selects each group's rows."""
     return _gmm_fwd(lhs, rhs, group_sizes, tile, interpret, dtype)[0]
 
 
@@ -338,10 +347,62 @@ grouped_matmul.defvjp(_gmm_fwd, _gmm_bwd)
 
 def _grouped_matmul_xla(lhs, rhs, group_sizes, dtype):
     """The same product off the chip, where the kernel's interpreter
-    takes seconds a call: XLA's own ``ragged_dot``."""
+    takes seconds a call: XLA's own ``ragged_dot``, which answers zero
+    rows past the last group."""
     return jax.lax.ragged_dot(lhs, rhs.astype(dtype), group_sizes,
                               preferred_element_type=jnp.float32
                               ).astype(dtype)
+
+
+def buffer_length(cfg: HeldExperts, tokens: int) -> int:
+    """The rows of the sorted buffer for a layer's ``tokens``:
+    ``cfg.buffer_rows`` (or the most the routing can send) in whole row
+    tiles."""
+    rows = cfg.buffer_rows or tokens * min(cfg.top_k, cfg.experts_held)
+    return -(-rows // cfg.tile[0]) * cfg.tile[0]
+
+
+def product_tiles(sizes, rows: int, tm: int) -> int:
+    """The row tiles ONE forward grouped product visits (``megablox``'s
+    ``make_group_metadata``: its ``num_tiles`` where empty groups are not
+    visited) for held experts' loads ``sizes`` [..., H] cut at a buffer of
+    ``rows`` rows, summed over the leading axes, on the host: a group that
+    is not empty takes ``ceil(end / tm) - floor(start / tm)`` tiles, so a
+    tile that two groups share is visited twice and the tiles past the
+    last group not at all."""
+    ends = np.minimum(np.cumsum(np.asarray(sizes, np.int64), -1), rows)
+    starts = np.concatenate([np.zeros_like(ends[..., :1]), ends[..., :-1]],
+                            -1)
+    return int(np.where(ends > starts, -(-ends // tm) - starts // tm, 0)
+               .sum())
+
+
+def expert_products(x: jax.Array, params: Dict, groups: jax.Array,
+                    cfg: HeldExperts, kernel: str) -> jax.Array:
+    """The held experts' MLPs over the sorted buffer ``x`` [rows, D] in
+    ``cfg.dtype``, ``groups`` [H] rows an expert: two or three grouped
+    products (``cfg.form``). The rows past the groups are UNWRITTEN in
+    the result, as they are in every intermediate (each product's result
+    and the hidden rows between them): see :func:`grouped_matmul`."""
+    tile = cfg.tile             # over rows, over dim and over ffn
+    if kernel == "xla":
+        up = down = functools.partial(
+            _grouped_matmul_xla, group_sizes=groups, dtype=cfg.dtype)
+    else:
+        mm = functools.partial(grouped_matmul, group_sizes=groups,
+                               dtype=cfg.dtype,
+                               interpret=kernel == "interpret")
+        up = functools.partial(mm, tile=tile)       # dim -> ffn
+        down = functools.partial(mm, tile=(tile[0], tile[2], tile[1]))
+    if cfg.form == "gated_silu":
+        h = (jax.nn.silu(up(x, params["w_gate"]).astype(jnp.float32))
+             * up(x, params["w_up"]).astype(jnp.float32))
+    elif cfg.form == "relu2":
+        h = jnp.square(jax.nn.relu(
+            up(x, params["w_up"]).astype(jnp.float32)))
+    else:
+        raise ValueError(f"no expert form named {cfg.form!r}")
+    return down(h.astype(cfg.dtype), params["w_down"])
 
 
 def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
@@ -359,8 +420,13 @@ def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
     which has no bias; balance is its load-balance term). What the absent
     experts would add is left out. The rows routed here are sorted by
     expert into a buffer of ``buffer_rows`` rows; the padding after them
-    is zero rows that the last expert's group takes, so the products do
-    the same work whatever the routing. ``overflow_rows`` counts rows
+    belongs to no expert's group, so the products visit the row tiles
+    that hold routed rows and their time follows ``held_rows``, not the
+    buffer. Past ``held_rows`` the products' results (``up``'s, the
+    hidden rows ``h``, ``down``'s ``y``) and their gradients are
+    UNWRITTEN memory: ``x`` and ``y`` are SELECTED by ``live`` on their
+    way in and out (a product with zero would keep a NaN), and nothing
+    else may reduce over those rows. ``overflow_rows`` counts rows
     that did not fit the buffer and were left out: 0 unless the buffer
     was sized under the load (it cannot be with ``buffer_rows=None``).
     ``kernel``: ``"pallas"`` (the chip's default), ``"interpret"`` (the
@@ -378,9 +444,7 @@ def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
         balance = jnp.zeros((), jnp.float32)
     else:
         raise ValueError(f"no route named {cfg.route!r}")
-    tile = cfg.tile             # over rows, over dim and over ffn
-    rows = cfg.buffer_rows or t * min(k, held)
-    rows = -(-rows // tile[0]) * tile[0]
+    rows = buffer_length(cfg, t)
     with jax.named_scope("mv.lm.moe.dispatch"):
         local = chosen.reshape(-1) - cfg.expert_offset          # [T*K]
         here = (local >= 0) & (local < held)
@@ -392,33 +456,16 @@ def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
         ends = jnp.minimum(jnp.cumsum(sizes), rows)
         held_rows = ends[-1]
         overflow = sizes.sum() - held_rows
-        # the padding rows go to the last group: the grid is the same
-        # whatever the routing
-        groups = jnp.diff(ends, prepend=0).at[-1].add(rows - held_rows)
+        # the padding rows are in no group: the grid ends with the rows
+        groups = jnp.diff(ends, prepend=0)
         live = jnp.arange(rows) < held_rows
         token = take // k
         x = jnp.where(live[:, None], u.astype(cfg.dtype)[token], 0)
         gate = jnp.where(live, gates.reshape(-1)[take], 0.0)
     with jax.named_scope("mv.lm.moe.experts"):
-        if kernel == "xla":
-            up = down = functools.partial(
-                _grouped_matmul_xla, group_sizes=groups, dtype=cfg.dtype)
-        else:
-            mm = functools.partial(grouped_matmul, group_sizes=groups,
-                                   dtype=cfg.dtype,
-                                   interpret=kernel == "interpret")
-            up = functools.partial(mm, tile=tile)       # dim -> ffn
-            down = functools.partial(mm, tile=(tile[0], tile[2], tile[1]))
-        if cfg.form == "gated_silu":
-            h = (jax.nn.silu(up(x, params["w_gate"]).astype(jnp.float32))
-                 * up(x, params["w_up"]).astype(jnp.float32))
-        elif cfg.form == "relu2":
-            h = jnp.square(jax.nn.relu(
-                up(x, params["w_up"]).astype(jnp.float32)))
-        else:
-            raise ValueError(f"no expert form named {cfg.form!r}")
-        y = down(h.astype(cfg.dtype), params["w_down"])
+        y = expert_products(x, params, groups, cfg, kernel)
     with jax.named_scope("mv.lm.moe.combine"):
         out = jnp.zeros((t, d), jnp.float32).at[token].add(
-            y.astype(jnp.float32) * gate[:, None])
+            jnp.where(live[:, None], y.astype(jnp.float32), 0.0)
+            * gate[:, None])
     return out, counts, overflow, balance

@@ -99,12 +99,20 @@ def test_lm_stage():
     facts = chip_smoke.stage_lm(
         vocab=128, dim=32, heads=4, layers=2, seq=32, batch_per_chip=1,
         kernel_shapes=(((1, 2, 64, 16), 32), ((1, 2, 64, 16), (16, 32))),
+        expert_calls=(("gated", 128, 128, 2, 512, "gated_silu"),
+                      ("relu2", 128, 128, 2, 512, "relu2")),
         chip=False)
     assert facts["batch_axis"] == "mv"      # kernel ran under shard_map
     assert facts["loss"][-1] < facts["loss"][0]
     errs = facts["kernel_rel_err"]["(1, 2, 64, 16)/32"]
     assert set(errs) == {"out", "dq", "dk", "dv"}
     assert "(1, 2, 64, 16)/(16, 32)" in facts["kernel_rel_err"]
+    # the grouped products, the padding in the last group and in none
+    assert set(facts["experts"]) == {"gated", "relu2"}
+    for call in facts["experts"].values():
+        assert call["experts_ms"] > 0 and call["experts_ms_padded"] > 0
+        assert call["experts_tiles"] == 2 and call["experts_tiles_padded"] == 4
+        assert call["apart_from_padded"] <= 1e-6
 
 
 def test_flash_stage():
