@@ -770,6 +770,79 @@ def _expert_products(dim: int, ffn: int, held: int, rows: int, form: str,
     return facts
 
 
+# (name, positions a step, vocabulary, dim): each language-model cell's
+# chunked loss as ``models/mla_moe.loss_fn`` calls it
+HEAD_CALLS = (
+    ("glm47f-train-8k", 16384, 19360, 2048),
+    ("mellum2-train-8k", 16384, 24576, 2304),
+    ("trinity-train-16k", 16384, 25024, 2048),
+    ("nemotron3n-train-16k", 16384, 16384, 2688),
+)
+# one v5e chip's bfloat16 peak (benchmark/peaks.json)
+V5E_BF16_FLOPS = 197e12
+
+
+def _head_loss(positions: int, vocab: int, dim: int, chunk: int = 4096,
+               repeats: int = 5) -> Dict[str, Any]:
+    """``models/mla_moe._chunked_ce`` alone, bfloat16 operands: the ms a
+    call of the loss with its gradients to the hidden state and to the
+    head, what THREE products of positions x vocabulary x dim would take
+    of a v5e's bfloat16 peak at that time (``three_products_peak_share``,
+    %: a loss that makes its logits twice reads under 75), and the loss
+    and both gradients against plain autodiff of the unchunked
+    cross-entropy, a chunk at a time, in the same precision."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import mla_moe
+
+    cfg = mla_moe.MLAMoEConfig(
+        vocab=vocab, dim=dim, n_heads=1, q_lora_rank=1, kv_lora_rank=1,
+        qk_nope_dim=1, qk_rope_dim=1, v_head_dim=2, dense_ffn=1,
+        n_dense_layers=1, n_moe_layers=0, moe_ffn=1, n_experts=1,
+        experts_held=1, loss_chunk=chunk)
+    k = jax.random.split(jax.random.key(SEED), 3)
+    h = jax.random.normal(k[0], (positions, dim))
+    head = 0.05 * jax.random.normal(k[1], (vocab, dim))
+    targets = jax.random.randint(k[2], (positions,), 0, vocab)
+    weights = jnp.full((positions,), 1.0 / positions).at[-1].set(0.0)
+    both = jax.jit(jax.value_and_grad(
+        lambda h, head: mla_moe._chunked_ce(h, head, targets, weights, cfg),
+        (0, 1))).lower(h, head).compile()
+    got = jax.block_until_ready(both(h, head))
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        res = both(h, head)
+    jax.block_until_ready(res)
+    ms = (time.perf_counter() - t0) / repeats * 1e3
+
+    @jax.jit
+    def plain(hc, head, tc, wc):
+        def ce(hc, head):
+            logits = mla_moe.matmul(hc, head, True, cfg.compute_dtype,
+                                    jnp.float32)
+            at = jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
+            return jnp.sum(wc * (jax.nn.logsumexp(logits, -1) - at))
+        return jax.value_and_grad(ce, (0, 1))(hc, head)
+
+    step = min(chunk, positions)
+    parts = [plain(h[i:i + step], head, targets[i:i + step],
+                   weights[i:i + step]) for i in range(0, positions, step)]
+    want = (sum(p[0] for p in parts),
+            (jnp.concatenate([p[1][0] for p in parts]),
+             sum(p[1][1] for p in parts)))
+    errs = [float(jnp.max(jnp.abs(g - w)) / jnp.max(jnp.abs(w)))
+            for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want))]
+    if not max(errs) <= ATTN_BF16_TOL:      # a NaN fails too
+        raise AssertionError(f"chunked loss: relative error {errs} (loss, "
+                             f"dh, dhead) > {ATTN_BF16_TOL}")
+    flops = 3 * 2 * positions * vocab * dim
+    return {"loss_grad_ms": round(ms, 3),
+            "three_products_peak_share": round(
+                100 * flops / (ms * 1e-3) / V5E_BF16_FLOPS, 2),
+            "rel_err_loss_dh_dhead": [round(e, 6) for e in errs]}
+
+
 def stage_lm(vocab: int = 32768, dim: int = 2048, heads: int = 16,
              layers: int = 8, seq: int = 1024, batch_per_chip: int = 2,
              kernel_shapes: Tuple = (((2, 16, 1024, 128), 512),
@@ -782,13 +855,15 @@ def stage_lm(vocab: int = 32768, dim: int = 2048, heads: int = 16,
                                      ((1, 4, 8192, 256), 512),
                                      ((1, 4, 8192, 256), (512, 1024))),
              expert_calls: Tuple = EXPERT_CALLS,
+             head_calls: Tuple = HEAD_CALLS,
              chip: bool = True) -> Dict[str, Any]:
     """The widest model the repo runs (472M, d2048/L8, bf16) with the
     Pallas flash kernel: three donated train steps on a fixed batch, then
     the kernel alone against reference_attention. With several devices the
     batch shards over the mesh and the kernel runs under shard_map. Then
     the language-model cells' grouped products alone
-    (:func:`_expert_products`)."""
+    (:func:`_expert_products`) and their chunked loss alone
+    (:func:`_head_loss`)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -833,12 +908,13 @@ def stage_lm(vocab: int = 32768, dim: int = 2048, heads: int = 16,
     experts = {call[0]: _expert_products(
         *call[1:], kernel="interpret" if interpret else "pallas")
                for call in expert_calls}
+    heads = {call[0]: _head_loss(*call[1:]) for call in head_calls}
     return {"params": n_params, "global_batch": b, "seq": seq,
             "batch_axis": cfg.batch_axis, "interpret": interpret,
             "mosaic_custom_call": mosaic,
             "loss": [round(x, 4) for x in losses],
             "kernel_rel_err": kernel, "kernel_tol": ATTN_BF16_TOL,
-            "experts": experts}
+            "experts": experts, "heads": heads}
 
 
 # (name, q's shape, key-value heads, (block_q, block_k), window): the

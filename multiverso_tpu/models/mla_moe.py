@@ -662,29 +662,80 @@ def _run_block(x, p, layer: Layer, bias, cfg, remat: bool = True):
     return (jax.checkpoint(run) if remat else run)(x, p)
 
 
-def _chunked_ce(h, head, targets, weights, cfg):
-    """Sum over positions of ``weights * CE(h @ head.T, targets)``, float32,
-    ``loss_chunk`` positions at a time (the whole logits would be
-    positions x vocabulary floats); a chunk's logits are recomputed in the
-    backward pass."""
+def _ce_chunks(h, head, targets, weights, cfg, grads: bool):
+    """The scan under :func:`_chunked_ce`: ``loss_chunk`` positions at a
+    time, one logits product a chunk (operands in ``cfg.compute_dtype``,
+    float32 sums, float32 log-sum-exp). Returns the loss and, with
+    ``grads``, the three things it makes from the same logits while they
+    exist (``None`` without): ``weights * (softmax - onehot)``, cast to
+    the compute dtype, times the head (the gradient to ``h``, a chunk of
+    the stacked result) and times the chunk's ``h`` (the gradient to
+    ``head``, summed in a float32 carry), and each position's unweighted
+    loss (the gradient to ``weights``)."""
     n, d = h.shape
     chunk = min(cfg.loss_chunk, n)
     if n % chunk:
         raise ValueError(f"{n} positions do not divide into chunks of {chunk}")
+    dt = cfg.compute_dtype
 
-    @jax.checkpoint
-    def one(head, hc, tc, wc):
-        logits = matmul(hc, head, True, cfg.compute_dtype, jnp.float32)
+    def body(carry, xs):
+        total, dw = carry
+        hc, tc, wc = xs
+        hc, w = hc.astype(dt), head.astype(dt)
+        logits = _dot(hc, w, ((1,), (1,)), jnp.float32)
         lse = jax.nn.logsumexp(logits, -1)
-        at = jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
-        return jnp.sum(wc * (lse - at))
-
-    def body(total, xs):
-        return total + one(head, *xs), None
+        each = lse - jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
+        total = total + jnp.sum(wc * each)
+        if not grads:
+            return (total, dw), None
+        hit = jnp.arange(logits.shape[1])[None, :] == tc[:, None]
+        dl = (wc[:, None] * (jnp.exp(logits - lse[:, None]) - hit)).astype(dt)
+        dw = dw + _dot(dl, hc, ((0,), (0,)), jnp.float32)
+        return (total, dw), (_dot(dl, w, ((1,), (0,)), h.dtype), each)
 
     xs = (h.reshape(n // chunk, chunk, d), targets.reshape(-1, chunk),
           weights.reshape(-1, chunk))
-    return jax.lax.scan(body, jnp.zeros((), jnp.float32), xs)[0]
+    dw = jnp.zeros(head.shape, jnp.float32) if grads else None
+    (total, dw), out = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32), dw), xs)
+    if not grads:
+        return total, None
+    return total, (out[0].reshape(n, d), dw, out[1].reshape(n))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _chunked_ce(h, head, targets, weights, cfg):
+    """Sum over positions of ``weights * CE(h @ head.T, targets)``, float32,
+    ``loss_chunk`` positions at a time (the whole logits would be
+    positions x vocabulary floats). Under a gradient a chunk's logits are
+    made ONCE: the forward pass makes the chunk's gradients beside its
+    loss and the backward pass scales them by the loss's cotangent, so a
+    loss is three products of positions x vocabulary, nothing is kept but
+    the two gradients and nothing is made again. A caller that folds its
+    normaliser into ``weights`` hands back a cotangent of 1."""
+    return _ce_chunks(h, head, targets, weights, cfg, grads=False)[0]
+
+
+def _chunked_ce_fwd(h, head, targets, weights, cfg):
+    return _ce_chunks(h, head, targets, weights, cfg, grads=True)
+
+
+def _chunked_ce_bwd(cfg, grads, g):
+    dh, dw, each = grads
+    return g * dh, g * dw, None, g * each
+
+
+_chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
+
+
+def loss_grid(cfg, tokens: int) -> Dict[str, Any]:
+    """What the chunked losses of a training step over ``tokens`` positions
+    do, as ``lm.step`` spans carry it: the products of positions x
+    vocabulary (three a loss: the logits, and the gradients to the hidden
+    state and to the head) and the chunks a loss walks."""
+    losses = 1 + any(layer.name == "mtp" for layer in cfg.layers())
+    return {"head_products": 3 * losses,
+            "loss_chunks": tokens // min(cfg.loss_chunk, tokens)}
 
 
 def _embed(params, tokens, cfg):
@@ -731,12 +782,15 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
     x, aux = _trunk(params, bias, tokens, cfg)
     position = jnp.arange(s)[None, :]
     nxt = jnp.roll(tokens, -1, axis=1)
+    # a loss's normaliser lies in its weights: the chunked loss's cotangent
+    # is 1, and its gradients are cast where they were before
+    weights = lambda has_target, scale: jnp.broadcast_to(
+        jnp.where(has_target, jnp.float32(scale), 0.0), (b, s)).reshape(-1)
     with jax.named_scope("mv.lm.head"):
         main = _chunked_ce(
             rms_norm(x, params["final_norm"], cfg.eps).reshape(b * s, -1),
             params["head"], nxt.reshape(-1),
-            jnp.broadcast_to(position < s - 1, (b, s)).reshape(-1)
-            .astype(jnp.float32), cfg) / (b * (s - 1))
+            weights(position < s - 1, 1.0 / (b * (s - 1))), cfg)
     loss = main
     mtp = [layer for layer in cfg.layers() if layer.name == "mtp"]
     if mtp:
@@ -752,9 +806,9 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
             module = _chunked_ce(
                 rms_norm(y, p["out_norm"], cfg.eps).reshape(b * s, -1),
                 params["head"], jnp.roll(tokens, -2, axis=1).reshape(-1),
-                jnp.broadcast_to(position < s - 2, (b, s)).reshape(-1)
-                .astype(jnp.float32), cfg) / (b * (s - 2))
-        loss = main + cfg.mtp_weight * module
+                weights(position < s - 2, cfg.mtp_weight / (b * (s - 2))),
+                cfg)
+        loss = main + module
     counts, overflow, balance = (jnp.stack(a) for a in zip(*aux))
     if cfg.balance_coef:
         loss = loss + cfg.balance_coef * jnp.sum(balance)
@@ -922,8 +976,10 @@ class Trainer:
             if tokens is not None:
                 if self.steps == 1:     # one program, one shape
                     positions = int(tokens.shape[1])
-                    self._attn = dict(attn_grid(self.cfg, positions),
-                                      **mixer_grid(self.cfg, positions))
+                    self._attn = dict(
+                        attn_grid(self.cfg, positions),
+                        **mixer_grid(self.cfg, positions),
+                        **loss_grid(self.cfg, int(np.prod(tokens.shape))))
                 sp.set(tokens=int(np.prod(tokens.shape)))
                 t0_ns = time.time_ns()
                 self.states, self.bias, *back = self._step(

@@ -101,6 +101,7 @@ def test_lm_stage():
         kernel_shapes=(((1, 2, 64, 16), 32), ((1, 2, 64, 16), (16, 32))),
         expert_calls=(("gated", 128, 128, 2, 512, "gated_silu"),
                       ("relu2", 128, 128, 2, 512, "relu2")),
+        head_calls=(("chunks", 128, 96, 32, 32), ("whole", 64, 96, 32, 64)),
         chip=False)
     assert facts["batch_axis"] == "mv"      # kernel ran under shard_map
     assert facts["loss"][-1] < facts["loss"][0]
@@ -113,6 +114,11 @@ def test_lm_stage():
         assert call["experts_ms"] > 0 and call["experts_ms_padded"] > 0
         assert call["experts_tiles"] == 2 and call["experts_tiles_padded"] == 4
         assert call["apart_from_padded"] <= 1e-6
+    # the chunked loss alone, four chunks and one
+    assert set(facts["heads"]) == {"chunks", "whole"}
+    for call in facts["heads"].values():
+        assert call["loss_grad_ms"] > 0
+        assert max(call["rel_err_loss_dh_dhead"]) <= chip_smoke.ATTN_BF16_TOL
 
 
 def test_flash_stage():

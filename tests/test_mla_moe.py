@@ -9,6 +9,7 @@ import gc
 import hashlib
 import inspect
 import os
+import re
 import sys
 
 import jax
@@ -116,6 +117,130 @@ def test_loss_and_every_gradient_match_the_reference(attn, kernel):
     assert set(grads) == set(want) == set(mla_moe.param_shapes(cfg))
     bad = [n for n in grads if not _close(grads[n], want[n])]
     assert not bad, bad
+
+
+def _plain_ce(h, head, targets, weights, cfg):
+    """The unchunked cross-entropy, left to autodiff."""
+    logits = mla_moe.matmul(h, head, True, cfg.compute_dtype, jnp.float32)
+    at = jnp.take_along_axis(logits, targets[:, None], -1)[:, 0]
+    return jnp.sum(weights * (jax.nn.logsumexp(logits, -1) - at))
+
+
+def _checkpointed_ce(h, head, targets, weights, cfg):
+    """``_chunked_ce`` as the tree had it before its gradients were made
+    in the forward pass: a chunk under ``jax.checkpoint``, its logits made
+    again in the backward pass."""
+    n, d = h.shape
+    chunk = min(cfg.loss_chunk, n)
+    one = jax.checkpoint(lambda head, hc, tc, wc: _plain_ce(hc, head, tc, wc,
+                                                            cfg))
+    xs = (h.reshape(n // chunk, chunk, d), targets.reshape(-1, chunk),
+          weights.reshape(-1, chunk))
+    return jax.lax.scan(lambda total, x: (total + one(head, *x), None),
+                        jnp.zeros((), jnp.float32), xs)[0]
+
+
+def _head_inputs(n, zeros, seed=11):
+    k = jax.random.split(jax.random.key(seed), 4)
+    h = jax.random.normal(k[0], (n, CFG.dim))
+    head = 0.3 * jax.random.normal(k[1], (CFG.vocab, CFG.dim))
+    targets = jax.random.randint(k[2], (n,), 0, CFG.vocab)
+    weights = jnp.ones((n,))
+    if zeros:
+        weights = (jax.random.uniform(k[3], (n,)) < 0.7).astype(jnp.float32)
+    return h, head, targets, weights
+
+
+@pytest.mark.parametrize("scale", [1.0, CFG.mtp_weight])
+@pytest.mark.parametrize("zeros", [False, True])
+@pytest.mark.parametrize("chunk", [32, 128])
+def test_chunked_loss_and_its_gradients_are_plain_autodiffs(chunk, zeros,
+                                                            scale):
+    """Four chunks and one, every position weighted and some not, under a
+    cotangent of 1 and of the module's weight: value and the gradients to
+    the hidden state, the head and the weights."""
+    cfg = CFG._replace(loss_chunk=chunk)
+    h, head, targets, weights = _head_inputs(128, zeros)
+    both = lambda ce: jax.jit(jax.value_and_grad(
+        lambda h, head, w: scale * ce(h, head, targets, w, cfg),
+        (0, 1, 2)))(h, head, weights)
+    got, want = both(mla_moe._chunked_ce), both(_plain_ce)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert _close(g, w, 1e-5)
+    # a caller that takes no gradient runs the loss alone
+    assert _close(jax.jit(lambda: mla_moe._chunked_ce(
+        h, head, targets, weights, cfg))(), want[0] / scale, 1e-5)
+
+
+@pytest.mark.parametrize("scale", [1.0, CFG.mtp_weight])
+@pytest.mark.parametrize("chunk", [32, 128])
+def test_chunked_loss_in_bfloat16_gives_the_checkpointed_losss_gradients(
+        chunk, scale):
+    """With the normaliser in the weights the gradients are cast to
+    bfloat16 where the checkpointed chunk's were: they lie within one
+    bfloat16 unit of the largest value."""
+    cfg = CFG._replace(loss_chunk=chunk, compute_dtype=jnp.bfloat16)
+    h, head, targets, weights = _head_inputs(128, zeros=True)
+    norm = scale / float(weights.sum())
+    got = jax.jit(jax.grad(lambda h, head: mla_moe._chunked_ce(
+        h, head, targets, norm * weights, cfg), (0, 1)))(h, head)
+    want = jax.jit(jax.grad(lambda h, head: norm * _checkpointed_ce(
+        h, head, targets, weights, cfg), (0, 1)))(h, head)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype == jnp.float32
+        assert float(jnp.max(jnp.abs(g - w))) <= 2.0 ** -8 * float(
+            jnp.max(jnp.abs(w)))
+
+
+@pytest.mark.parametrize("n_mtp", [0, 1])
+def test_loss_fn_is_the_plain_losses_mean(n_mtp, monkeypatch):
+    """Through ``loss_fn`` with and without the prediction module: the
+    loss and every gradient are those of the plain cross-entropy in the
+    chunked loss's place."""
+    cfg = CFG._replace(n_mtp=n_mtp)
+    params, bias, tokens = _inputs(cfg)
+    both = lambda: jax.jit(jax.value_and_grad(
+        lambda p: mla_moe.loss_fn(p, bias, tokens, cfg)[0]))(params)
+    got = both()
+    monkeypatch.setattr(mla_moe, "_chunked_ce", _plain_ce)
+    want = both()
+    assert abs(float(got[0]) - float(want[0])) < 1e-5 * float(want[0])
+    bad = [n for n in want[1] if not _close(got[1][n], want[1][n], 1e-5)]
+    assert not bad, bad
+
+
+def _vocabulary_products(fn, *args) -> int:
+    """The ``dot_general`` operations of ``fn``'s lowering with the
+    vocabulary among an operand's or the result's dimensions (a scan's
+    body is lowered once)."""
+    text = jax.jit(fn).lower(*args).as_text()
+    dots = [line for line in text.splitlines() if "dot_general" in line]
+    assert dots
+    return sum(bool(re.search(rf"[<x]{CFG.vocab}x", line.split(" : ")[-1]))
+               for line in dots)
+
+
+@pytest.mark.parametrize("n_mtp", [0, 1])
+def test_a_loss_is_three_products_of_positions_x_vocabulary(n_mtp):
+    """One logits product a loss in the forward pass, and under a gradient
+    the two gradients' products beside it: nothing is made again."""
+    mv.init(mesh=Mesh(np.asarray(jax.devices()[:1]), ("mv",)))
+    cfg = CFG._replace(n_mtp=n_mtp, n_moe_layers=1)
+    assert CFG.vocab not in (cfg.dim, 2 * cfg.dim, cfg.dense_ffn, cfg.moe_ffn)
+    params, bias, tokens = _inputs(cfg)
+    losses = 1 + n_mtp
+    assert _vocabulary_products(jax.value_and_grad(
+        lambda p: mla_moe.loss_fn(p, bias, tokens, cfg)[0]), params
+                                ) == 3 * losses
+    assert mla_moe.loss_grid(cfg, tokens.size) == {
+        "head_products": 3 * losses, "loss_chunks": 128 // cfg.loss_chunk}
+    tables = mla_moe.make_tables(cfg, 0, 0.1, updater="adam")
+    states = {n: t.program_state() for n, t in tables.items()}
+    assert _vocabulary_products(mla_moe.make_forward(cfg), states, bias,
+                                tokens) == losses
+    assert _vocabulary_products(mla_moe.make_train_step(cfg, tables), states,
+                                bias, tokens) == 3 * losses
 
 
 def test_lean_reference_is_the_plain_reference():

@@ -780,7 +780,10 @@ def test_lm_step_carries_its_counts():
     assert wait["parent"] == step["id"] and step["request"] == 1
     layers = len(mla_moe.expert_layers(cfg))
     want = mla_moe.routing_counts(counts, cfg)
-    assert step["args"] == dict(want, tokens=64)
+    # ... and, static, the losses' products of positions x vocabulary
+    # (main and module, three each) and the chunks a loss walks
+    assert step["args"] == dict(want, tokens=64, head_products=6,
+                                loss_chunks=2)
     assert want["routed_rows"] == layers * 64 * cfg.top_k
     assert 0 <= want["held_rows"] <= want["routed_rows"]
     assert want["overflow_rows"] == 0 and want["load_max_over_mean"] >= 1.0
