@@ -919,12 +919,14 @@ def stage_lm(vocab: int = 32768, dim: int = 2048, heads: int = 16,
 
 # (name, q's shape, key-value heads, (block_q, block_k), window): the
 # window layers of mellum2-train-8k and trinity-train-16k, their full
-# layers' call, and glm47f-train-8k's
+# layers' call, glm47f-train-8k's and lfm2-train-8k's
 FLASH_CALLS = (
     ("mellum2.window", (2, 32, 8192, 128), 4, (1024, 1024), 1024),
     ("trinity.window", (1, 32, 16384, 128), 4, (1024, 1024), 2048),
     ("mellum2.full", (2, 32, 8192, 128), 4, (1024, 1024), None),
     ("glm47f.causal", (2, 20, 8192, 256), 20, (512, 1024), None),
+    # lfm2-train-8k's: heads of 64, half a lane tile a block
+    ("lfm2.causal", (2, 32, 8192, 64), 8, (1024, 1024), None),
 )
 
 
@@ -1104,6 +1106,84 @@ def stage_ssd(positions: int = 16384, heads: int = 64, head_dim: int = 64,
     return facts
 
 
+def stage_conv(sequences: int = 2, positions: int = 8192, dim: int = 2048,
+               taps: int = 3, repeats: int = 10,
+               check_positions: int = 512) -> Dict[str, Any]:
+    """``models/lfm2_moe.short_conv`` as ``lfm2-train-8k`` calls it (the
+    mixer alone on ``sequences`` x ``positions``, bfloat16 operands): the
+    seconds the compiler took and the ms a call, forward and forward with
+    every gradient, by this process's clock around ``repeats`` calls it
+    waits for; the pass between the two products alone (the gates and the
+    taps on a bfloat16 ``[positions, 3 dim]``) beside the least the chip's
+    memory allows it (``benchmark/conv_shapes.mixer_bytes`` over the HBM
+    peak of ``benchmark/peaks.json``); and the mixer's output and
+    gradients on the first ``check_positions`` of one sequence against the
+    convolution a position at a time in float32
+    (``benchmark/reference/lfm2_moe.short_conv``; max|err| over
+    max|reference|, as ``stage_lm``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import conv_shapes, shapes
+    from benchmark.reference import lfm2_moe as reference
+    from multiverso_tpu.models import lfm2_moe
+
+    cfg = lfm2_moe.LFM2MoEConfig(dim=dim, conv_taps=taps)
+    k = jax.random.split(jax.random.key(SEED), 5)
+    u = jax.random.normal(k[0], (sequences, positions, dim))
+    p = {"win": 0.02 * jax.random.normal(k[1], (dim, 3 * dim)),
+         "conv_w": taps ** -0.5 * jax.random.normal(k[2], (taps, dim)),
+         "wout": 0.02 * jax.random.normal(k[3], (dim, dim))}
+    weight = jax.random.normal(k[4], u.shape)
+    mixer = lambda u, p: lfm2_moe.short_conv(u, p, cfg)
+    both = lambda u, p: jax.value_and_grad(
+        lambda u, p: jnp.sum(weight[:u.shape[0], :u.shape[1]] * mixer(u, p)),
+        (0, 1))(u, p)
+
+    def between(proj, w):       # what lies between the mixer's products
+        return lfm2_moe.gated_taps(proj.astype(jnp.float32), w).astype(
+            proj.dtype)
+
+    proj = jax.random.normal(k[0], (sequences, positions, 3 * dim),
+                             jnp.bfloat16)
+    facts: Dict[str, Any] = {}
+    for name, fn, args in (("fwd", mixer, (u, p)), ("fwd_bwd", both, (u, p)),
+                           ("taps", between, (proj, p["conv_w"]))):
+        t0 = time.perf_counter()
+        compiled = jax.jit(fn).lower(*args).compile()
+        facts[f"{name}_compile_s"] = round(time.perf_counter() - t0, 2)
+        jax.block_until_ready(compiled(*args))
+        t0 = time.perf_counter()
+        for _ in range(repeats):
+            res = compiled(*args)
+        jax.block_until_ready(res)
+        facts[f"{name}_ms"] = round(
+            (time.perf_counter() - t0) / repeats * 1e3, 3)
+    device = jax.devices()[0]
+    if device.platform == "tpu":
+        facts["taps_least_ms"] = round(
+            conv_shapes.mixer_bytes(sequences, positions, dim)
+            / shapes.peak(device.device_kind, "hbm_bytes_per_s") * 1e3, 3)
+
+    recurrence = reference.short_conv       # one sequence, from the definition
+    few = u[:1, :min(check_positions, positions)]
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda u, p: jax.value_and_grad(
+            lambda u, p: jnp.sum(weight[0, :u.shape[0]] * recurrence(u, p)),
+            (0, 1))(u, p))(few[0], p)
+        y_want = jax.jit(recurrence)(few[0], p)
+    got = jax.jit(both)(few, p)
+    pairs = [(mixer(few, p)[0], y_want), (got[1][0][0], want[1][0])] + [
+        (got[1][1][n], want[1][1][n]) for n in ("win", "conv_w", "wout")]
+    errs = [float(jnp.max(jnp.abs(g - w)) / jnp.max(jnp.abs(w)))
+            for g, w in pairs]
+    if not max(errs) <= ATTN_BF16_TOL:      # a NaN fails too
+        raise AssertionError(f"conv: relative error {errs} (y, du, dwin, "
+                             f"dconv_w, dwout) > {ATTN_BF16_TOL}")
+    facts["rel_err_y_du_dwin_dconvw_dwout"] = [round(e, 5) for e in errs]
+    return facts
+
+
 def stage_memory() -> Dict[str, Any]:
     """After the run every device holds bytes: every chip was used."""
     import jax
@@ -1143,6 +1223,7 @@ STAGES: Tuple[Tuple[str, Callable[[], Dict[str, Any]]], ...] = (
     ("tables", stage_tables), ("we", stage_we), ("rows", stage_rows),
     ("ps", stage_ps),
     ("lm", stage_lm), ("flash", stage_flash), ("ssd", stage_ssd),
+    ("conv", stage_conv),
     ("memory", stage_memory))
 
 

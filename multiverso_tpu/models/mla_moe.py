@@ -24,15 +24,18 @@ whole. The equations, for a block with input ``x``:
 The decoder itself is one code path for more than this model: a
 configuration says its layers as data (``cfg.layers()``: a
 :class:`Layer` names each block's attention kind, ``latent`` here,
-``full`` or ``window`` grouped-query heads in ``models/gqa_moe.py``, and
-its feed-forward kind, ``dense``, ``shared+experts`` or ``experts``
+``full`` or ``window`` grouped-query heads in ``models/gqa_moe.py``,
+``ssm`` in ``models/nemotron_h.py``, ``conv`` in ``models/lfm2_moe.py``,
+and its feed-forward kind, ``dense``, ``shared+experts`` or ``experts``
 alone), how its routers score (``cfg.route`` and ``cfg.routed_scale``:
 ``parallel/moe.HeldExperts``'s), the shapes of an attention kind's
 parameters (``cfg.attn_shapes(kind)``), the attention itself
 (``cfg.attend(u, p, kind)``), where a block's norms stand
 (``cfg.post_norms``: before each branch alone, or on its way out as
-well, as ``models/afmoe.py`` has them) and what the embedding is
-multiplied by (``cfg.embed_scale``); :func:`block`, :func:`matmul`,
+well, as ``models/afmoe.py`` has them), what the embedding is
+multiplied by (``cfg.embed_scale``) and, where it has such a field,
+whether the embedding's table is the head as well (``cfg.tied_head``);
+:func:`block`, :func:`matmul`,
 :func:`rms_norm`, :func:`rotary`, the chunked loss, the tables, the step
 and :class:`Trainer` below are shared by every such configuration.
 
@@ -67,7 +70,7 @@ class Layer(NamedTuple):
     then ``ffn``, or ONE mixer (the other is ``None``: one norm, one
     residual sum)."""
     name: str                   # its parameters' prefix: "L<i>", or "mtp"
-    attn: Optional[str]         # "latent", "full", "window" or "ssm"
+    attn: Optional[str]         # "latent", "full", "window", "ssm", "conv"
     ffn: Optional[str]          # "dense", "shared+experts" or "experts"
 
 
@@ -217,10 +220,12 @@ def _ffn_shapes(cfg, kind: str) -> Dict[str, Tuple[int, ...]]:
 def param_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
     """Every trained parameter by name. Layers are ``L<i>.``; the
     prediction module is ``mtp.``; ``embed`` and ``head`` have a row a
-    token id."""
+    token id, and under a tied head (``cfg.tied_head``) there is no
+    ``head``: the embedding's table is both."""
     d = cfg.dim
-    out = {"embed": (cfg.vocab, d), "head": (cfg.vocab, d),
-           "final_norm": (d,)}
+    out = {"embed": (cfg.vocab, d), "final_norm": (d,)}
+    if not tied_head(cfg):
+        out["head"] = (cfg.vocab, d)
     for layer in cfg.layers():
         # an attention kind's shapes bring the block's two input norms
         block = (dict(cfg.attn_shapes(layer.attn)) if layer.attn
@@ -236,6 +241,11 @@ def param_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
                          out_norm=(d,))
         out.update({f"{layer.name}.{k}": v for k, v in block.items()})
     return out
+
+
+def tied_head(cfg) -> bool:
+    """Whether the logits' head is the embedding's table."""
+    return bool(getattr(cfg, "tied_head", False))
 
 
 def _draw(shape, key, scale: float, rule, pad: int = 0) -> jax.Array:
@@ -454,7 +464,15 @@ def attn_blocks(cfg, s: int) -> Tuple[int, int]:
     pair sub-tiles of 256, of which it computes the live ones (1.25 of
     the band, not 2.00) and masks those an edge passes through: 3.3 / 3.5
     / 4.7 at 1,024 x 1,024 (PERF.md section 6, PR 44), and the causal call
-    above 9.3 / 10.1 / 13.7 for 10.1 / 11.0 / 14.7."""
+    above 9.3 / 10.1 / 13.7 for 10.1 / 11.0 / 14.7. At (8192, 64) with 32
+    query heads over 8 key-value heads (PERF.md section 6, PR 50) a block is
+    half a lane tile, which Mosaic lowers as it is, and the same three read
+    9.9 / 11.6 / 14.9 at 1,024 x 1,024, 10.3 / 12.5 / 15.5 at 512 x 1,024
+    and 10.7 / 13.9 / 17.2 at 512 x 512 with sub-tiles of 256 (10.7 / 12.6 /
+    15.9, 11.1 / 13.2 / 16.2 and 10.8 / 14.1 / 17.2 whole): a head of 64
+    takes the blocks of a head of 128, and costs no less than one (the
+    products halve; the lanes, the exps, the masks and the rescales do
+    not)."""
     bq = min(cfg.attn_block, s)
     wide = 2 * bq <= 1024 and cfg.head_size <= 256 and s % (2 * bq) == 0
     if not wide:
@@ -478,7 +496,7 @@ def attn_grid(cfg, s: int) -> Dict[str, Any]:
     layers = cfg.layers()
     # the kinds whose core is the flash kernel
     kinds = [layer.attn for layer in layers
-             if layer.attn not in (None, "ssm")]
+             if layer.attn not in (None, "ssm", "conv")]
     branches = max(bool(layer.attn) + bool(layer.ffn) for layer in layers)
     blocks = attn_blocks(cfg, s)
     sub = sub_tile(*blocks, cfg.head_size)
@@ -504,19 +522,27 @@ def attn_grid(cfg, s: int) -> Dict[str, Any]:
 
 
 def mixer_grid(cfg, s: int) -> Dict[str, Any]:
-    """What a layer list of one-mixer blocks adds to the ``lm.step`` span:
-    every block's kind in order, the experts' form and, where some block
-    is a state-space mixer, its scan's static counts over ``s`` positions
-    (``cfg.ssm_grid``); nothing for a list of two-branch blocks."""
+    """What a layer list with a mixer that is no attention, or of
+    one-mixer blocks, adds to the ``lm.step`` span: every block's kinds in
+    order (a two-branch block's joined by ``+``), the experts' form and,
+    where some block is a state-space mixer, its scan's static counts over
+    ``s`` positions (``cfg.ssm_grid``), where some is a convolution mixer,
+    that mixer's (``cfg.conv_grid``); nothing for a list of two-branch
+    attention blocks."""
     layers = cfg.layers()
-    if all(layer.attn and layer.ffn for layer in layers):
+    mixers = {kind: sum(layer.attn == kind for layer in layers)
+              for kind in ("ssm", "conv")}
+    if not any(mixers.values()) and all(layer.attn and layer.ffn
+                                        for layer in layers):
         return {}
-    out = {"block_kinds": ",".join(layer.attn or layer.ffn
-                                   for layer in layers),
+    out = {"block_kinds": ",".join(
+               "+".join(kind for kind in (layer.attn, layer.ffn) if kind)
+               for layer in layers),
            "expert_form": cfg.expert_form}
-    ssm = sum(layer.attn == "ssm" for layer in layers)
-    if ssm:
-        out.update(ssm_layers=ssm, **cfg.ssm_grid(s))
+    if mixers["ssm"]:
+        out.update(ssm_layers=mixers["ssm"], **cfg.ssm_grid(s))
+    if mixers["conv"]:
+        out.update(cfg.conv_grid(s))
     return out
 
 
@@ -786,10 +812,13 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
     # is 1, and its gradients are cast where they were before
     weights = lambda has_target, scale: jnp.broadcast_to(
         jnp.where(has_target, jnp.float32(scale), 0.0), (b, s)).reshape(-1)
+    # a tied head is the embedding's table: autodiff adds the lookup's
+    # gradient to the chunked loss's
+    head = params["embed" if tied_head(cfg) else "head"]
     with jax.named_scope("mv.lm.head"):
         main = _chunked_ce(
             rms_norm(x, params["final_norm"], cfg.eps).reshape(b * s, -1),
-            params["head"], nxt.reshape(-1),
+            head, nxt.reshape(-1),
             weights(position < s - 1, 1.0 / (b * (s - 1))), cfg)
     loss = main
     mtp = [layer for layer in cfg.layers() if layer.name == "mtp"]
@@ -805,7 +834,7 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
             aux.append(a)
             module = _chunked_ce(
                 rms_norm(y, p["out_norm"], cfg.eps).reshape(b * s, -1),
-                params["head"], jnp.roll(tokens, -2, axis=1).reshape(-1),
+                head, jnp.roll(tokens, -2, axis=1).reshape(-1),
                 weights(position < s - 2, cfg.mtp_weight / (b * (s - 2))),
                 cfg)
         loss = main + module

@@ -195,8 +195,10 @@ def sub_tile(block_q: int, block_k: int, d: int) -> Optional[int]:
     """The rows and columns of the sub-tiles a crossed pair of ``block_q``
     x ``block_k`` is cut into at head size ``d``; ``None`` leaves it one
     tile under one mask. 256 where the blocks are of 512 rows or more
-    (what the chip read at head sizes 128 and 256, the module's table;
-    nothing under 128 is a whole lane tile)."""
+    (what the chip read at head sizes 128 and 256, the module's table, and
+    at 64, half a lane tile, where 1,024 x 1,024 blocks read 9.9 / 11.6 /
+    14.9 ms forward / dQ / dK with dV in sub-tiles of 256 for 10.7 / 12.6 /
+    15.9 whole: ``mla_moe.attn_blocks``, PR 50)."""
     if d <= 256 and block_q % 512 == 0 and block_k % 512 == 0:
         return 256
     return None

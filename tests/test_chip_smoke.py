@@ -152,6 +152,42 @@ def test_ssd_stage():
     assert "ssd" in dict(chip_smoke.STAGES)
 
 
+def test_conv_stage():
+    """The short-convolution mixer at a small size: ms and compile seconds
+    forward, with every gradient and of the pass between the products, the
+    mixer held to the convolution a position at a time; the least the
+    memory allows is a chip's number and is not made up here."""
+    facts = chip_smoke.stage_conv(sequences=2, positions=64, dim=32,
+                                  repeats=1, check_positions=48)
+    for name in ("fwd", "fwd_bwd", "taps"):
+        assert facts[f"{name}_ms"] > 0 and facts[f"{name}_compile_s"] >= 0
+    assert "taps_least_ms" not in facts
+    errs = facts["rel_err_y_du_dwin_dconvw_dwout"]
+    assert len(errs) == 5 and max(errs) <= chip_smoke.ATTN_BF16_TOL
+    assert "conv" in dict(chip_smoke.STAGES)
+    # what the chip's reading is held against, at the cell's shapes: 268 MB
+    # over 819 GB/s
+    from benchmark import conv_shapes, shapes
+    assert abs(conv_shapes.mixer_bytes(2, 8192, 2048) / shapes.peak(
+        "TPU v5 lite", "hbm_bytes_per_s") * 1e3 - 0.328) < 1e-3
+
+
+@pytest.mark.parametrize("head_dim,group", [(16, 4), (8, 2)])
+def test_flash_stage_at_heads_under_a_lane_tile(head_dim, group):
+    """The stage's arithmetic at the shape of ``lfm2-train-8k``'s call, tiny:
+    grouped query heads of less than a lane tile, whole tiles against
+    sub-tiles, the kernels against float32 attention; the cell's own call
+    is among the stage's."""
+    facts = chip_smoke.stage_flash(
+        calls=(("small", (2, 2 * group, 64, head_dim), 2, (32, 32), None),),
+        subs=(16,), repeats=1)["small"]
+    for tag in ("sub0", "sub16"):
+        assert max(facts[f"{tag}_rel_err"]) <= chip_smoke.ATTN_BF16_TOL
+    assert max(facts["sub16_vs_whole"]) <= 1e-2
+    assert ("lfm2.causal", (2, 32, 8192, 64), 8, (1024, 1024), None) in (
+        chip_smoke.FLASH_CALLS)
+
+
 def test_main_refuses_a_cpu(capsys):
     """No TPU: non-zero exit, the reason on stderr, no result on stdout."""
     assert chip_smoke.main() == chip_smoke.EXIT_NO_CHIP

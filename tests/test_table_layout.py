@@ -812,3 +812,80 @@ def test_attention_at_sixteen_query_heads_a_key_value_head_compiles_for_a_v5e(
         f32(1, 16384, 2688), p).compile().as_text()
     assert text.count("tpu_custom_call") >= 3       # forward, dQ, dK with dV
     assert "bf16[2,16384,128]" in text and "bf16[32,16384,128]" in text
+
+
+def test_attention_at_heads_of_64_compiles_for_a_v5e(one_chip, monkeypatch):
+    """``models/gqa_moe.gqa`` under ``models/lfm2_moe.py``'s switches (q/k
+    norms, rotary positions, no gate) as ``lfm2-train-8k`` calls it: 32
+    query heads of 64 over 8 key-value heads, two sequences of 8,192
+    positions at 1,024 x 1,024 blocks. A block's last dimension is half a
+    lane tile, and Mosaic takes it as it is: no operand is padded to 128."""
+    from multiverso_tpu.models import lfm2_moe, mla_moe
+    from multiverso_tpu.ops import attention_kernels
+
+    # the process's devices are the CPU's: the kernels would be interpreted
+    monkeypatch.setattr(attention_kernels, "_resolve_interpret",
+                        lambda interpret: False)
+    cfg = lfm2_moe.LFM2MoEConfig(dim=2048, n_heads=32, n_kv_heads=8,
+                                 head_dim=64, attn="flash")
+    assert mla_moe.attn_blocks(cfg, 8192) == (1024, 1024)
+    assert attention_kernels.sub_tile(1024, 1024, 64) == 256
+    assert cfg.kv_group == 4
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {n: f32(*s) for n, s in cfg.attn_shapes("full").items()}
+
+    def attend(u, p):
+        return cfg.attend(u, p, "full").sum()
+
+    text = jax.jit(jax.grad(attend, argnums=(0, 1))).lower(
+        f32(2, 8192, 2048), p).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3       # forward, dQ, dK with dV
+    assert "bf16[16,8192,64]" in text and "bf16[64,8192,64]" in text
+    assert "8192,128]" not in text
+
+
+def test_sigmoid_expert_layer_at_rows_of_1792_compiles_for_a_v5e(one_chip):
+    """The held experts' grouped products as ``lfm2-train-8k`` calls them:
+    a 32,768-row buffer in eight groups of 2,048 x 1,792 (1,792 = 2 x 896:
+    the tile over it is 896), under the sigmoid route over 32 outputs with
+    4 a token at scale 1 and no shared expert beside it."""
+    from multiverso_tpu.parallel import moe
+
+    tile = moe.product_tile(2048, 1792)
+    assert tile == (512, 512, 896)
+    held = moe.HeldExperts(num_experts=32, experts_held=8, top_k=4,
+                           routed_scale=1.0, buffer_rows=32768, tile=tile)
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+
+    def experts(u, router, wg, wu, wd):
+        out, counts, overflow, _ = moe.held_expert_layer(
+            u, {"router": router, "w_gate": wg, "w_up": wu, "w_down": wd},
+            jnp.zeros((32,)), held, kernel="pallas")
+        return out.sum(), (counts, overflow)
+
+    compiled = jax.jit(jax.grad(experts, argnums=(0, 1, 2, 3, 4),
+                                has_aux=True)).lower(
+        shape(16384, 2048), shape(32, 2048), shape(8, 2048, 1792),
+        shape(8, 2048, 1792), shape(8, 1792, 2048)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 9
+
+
+def test_short_convolution_mixer_compiles_for_a_v5e_at_published_widths(
+        one_chip):
+    """``models/lfm2_moe.short_conv`` on two sequences of 8,192 at width
+    2,048, forward and backward: two products forward (and two a product
+    backward), no kernel of the repo's own between them."""
+    from multiverso_tpu.models import lfm2_moe
+
+    cfg = lfm2_moe.LFM2MoEConfig(dim=2048)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {n: f32(*s) for n, s in lfm2_moe.short_conv_shapes(cfg).items()}
+
+    def mixer(u, p):
+        return lfm2_moe.short_conv(u, p, cfg).sum()
+
+    compiled = jax.jit(jax.grad(mixer, argnums=(0, 1))).lower(
+        f32(2, 8192, 2048), p).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    # the step's temporaries of one mixer stay far under the chip's memory
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30

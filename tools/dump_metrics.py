@@ -681,7 +681,7 @@ def format_timeline(events: List[Dict]) -> str:
     out += [f"    {r['run_ms']:12.3f} ms  {r['name']}  request={r['request']}"
             for r in runs[:5]]
     return "\n".join(out + _table_write_lines(events)
-                     + _attention_lines(events))
+                     + _attention_lines(events) + _mixer_lines(events))
 
 
 def _table_write_lines(events: List[Dict]) -> List[str]:
@@ -729,6 +729,27 @@ def _attention_lines(events: List[Dict]) -> List[str]:
             computed = args["attn_positions_computed" + tail]
             out.append(f"    {walk:6s}  {computed}  {needed}  "
                        f"{computed / needed:.3f}")
+    return out
+
+
+def _mixer_lines(events: List[Dict]) -> List[str]:
+    """The blocks' kinds in order, from the static counts on ``lm.step``
+    under a layer list with a mixer that is no attention
+    (``models/mla_moe.mixer_grid``) and, where some are convolution mixers,
+    their part of the matrix-product operations a token needs in a forward
+    pass of the whole step (``conv.mixer_flops_share``'s two counts)."""
+    args = next((e["args"] for e in events if e.get("name") == "lm.step"
+                 and "block_kinds" in e.get("args", {})), None)
+    if args is None:
+        return []
+    out = [f"  blocks: {args['block_kinds']}"]
+    if args.get("step_flops_token"):
+        mixers, step = args["mixer_flops_token"], args["step_flops_token"]
+        out.append(
+            f"    conv mixers: {args['conv_layers']} of {args['conv_taps']} "
+            f"taps, {mixers} of {step} forward operations a token = "
+            f"{100.0 * mixers / step:.2f}%"
+            + ("; tied head" if args.get("tied_head") else ""))
     return out
 
 
