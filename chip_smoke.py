@@ -693,18 +693,98 @@ def _attention_errors(shape: Tuple[int, int, int, int], block,
     return errs
 
 
-# (name, dim, expert width, experts held, buffer rows, form): ONE expert
-# layer of each language-model cell as ``models/mla_moe.held`` sizes it
-# (the buffer twice the even load)
+# (name, dim, expert width, experts held, buffer rows, form, experts routed
+# over, experts a token, the model's module): ONE expert layer of each
+# language-model cell as ``models/mla_moe.held`` sizes it (the buffer twice
+# the even load)
 EXPERT_CALLS = (
-    ("glm47f-train-8k", 2048, 1536, 8, 16384, "gated_silu"),
-    ("mellum2-train-8k", 2304, 896, 16, 65536, "gated_silu"),
-    ("trinity-train-16k", 2048, 1024, 16, 32768, "gated_silu"),
-    ("nemotron3n-train-16k", 2688, 1856, 8, 12288, "relu2"),
+    ("glm47f-train-8k", 2048, 1536, 8, 16384, "gated_silu", 64, 4, "mla_moe"),
+    ("mellum2-train-8k", 2304, 896, 16, 65536, "gated_silu", 64, 8,
+     "gqa_moe"),
+    ("trinity-train-16k", 2048, 1024, 16, 32768, "gated_silu", 128, 8,
+     "afmoe"),
+    ("nemotron3n-train-16k", 2688, 1856, 8, 12288, "relu2", 128, 6,
+     "nemotron_h"),
+    ("lfm2-train-8k", 2048, 1792, 8, 32768, "gated_silu", 32, 4, "lfm2_moe"),
 )
 
 
+def grouped_kernels(compiled_text: str) -> Dict[str, int]:
+    """The grouped products' kernels in a compiled TPU program's text, by
+    the ``megablox`` function that called them (``gmm``: a forward
+    product or the buffer's gradient; ``tgmm``: a matrix's gradient)."""
+    calls = [line for line in compiled_text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    return {name: sum(f"jit({name})" in c for c in calls)
+            for name in ("gmm", "tgmm")}
+
+
+def _expert_block(dim: int, ffn: int, held: int, rows: int, form: str,
+                  experts: int, top_k: int, model: str, kernel: str,
+                  repeats: int) -> Dict[str, Any]:
+    """ONE expert block (norm, route, sort, gather, the grouped products,
+    combine; no attention, no shared expert) of the configuration class
+    in ``models/<model>.py``, so with the cell's own route and with what
+    its blocks keep (``mla_moe.kept_names``), forward and backward under
+    ``models/mla_moe._run_block`` as a training step rematerialises it,
+    over the tokens whose even load half fills a buffer of ``rows``: the
+    ms a call (``block_ms``) and the grouped kernels of the compiled
+    program by the function that called them (``block_kernels``: ``gmm``
+    forward and for the buffer's gradient, ``tgmm`` for a matrix's; 7 and
+    3 where the block keeps the results of its products into the experts'
+    width and makes the one out of it again, 9 and 3 where the backward
+    pass makes them all again; 6 and 2 in ``NemotronHConfig``'s ``relu2``
+    block, which keeps nothing, 5 and 2 if it did; none off the chip,
+    where no kernel is compiled)."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import (afmoe, gqa_moe, lfm2_moe, mla_moe,
+                                       nemotron_h)
+
+    tokens = rows * experts // (2 * top_k * held)
+    cfg = {"mla_moe": mla_moe.MLAMoEConfig, "gqa_moe": gqa_moe.GQAMoEConfig,
+           "afmoe": afmoe.AFMoEConfig,
+           "nemotron_h": nemotron_h.NemotronHConfig,
+           "lfm2_moe": lfm2_moe.LFM2MoEConfig}[model](
+        dim=dim, moe_ffn=ffn, n_experts=experts, experts_held=held,
+        top_k=top_k, expert_kernel=kernel)
+    here = mla_moe.held(cfg, tokens)
+    if (here.buffer_rows, here.form) != (rows, form):
+        raise AssertionError(f"{tokens} tokens of {model} make a buffer of "
+                             f"{here.buffer_rows} rows of {here.form}")
+    shapes = dict(mla_moe._ffn_shapes(cfg, "experts"), ffn_norm=(dim,),
+                  **{"ffn_post_norm": (dim,)} if cfg.post_norms else {})
+    keys = jax.random.split(jax.random.key(SEED), len(shapes) + 2)
+    p = {n: 0.02 * jax.random.normal(k, s)
+         for (n, s), k in zip(sorted(shapes.items()), keys)}
+    x = jax.random.normal(keys[-2], (1, tokens, dim))
+    weight = jax.random.normal(keys[-1], x.shape)
+    layer = mla_moe.Layer("L0", None, "experts")
+    bias = jnp.zeros((experts,))
+
+    def loss(x, p):
+        y, (_, overflow, _) = mla_moe._run_block(x, p, layer, bias, cfg)
+        return jnp.sum(y * weight), overflow
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1), has_aux=True)).lower(
+        x, p).compile()
+    (_, overflow), grads = jax.block_until_ready(compiled(x, p))
+    if int(overflow) or not all(bool(jnp.all(jnp.isfinite(g)))
+                                for g in jax.tree.leaves(grads)):
+        raise AssertionError(f"expert block: {int(overflow)} rows past the "
+                             "buffer, or a gradient that is not finite")
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        res = compiled(x, p)
+    jax.block_until_ready(res)
+    return {"block_tokens": tokens,
+            "block_ms": round((time.perf_counter() - t0) / repeats * 1e3, 3),
+            "block_kernels": grouped_kernels(compiled.as_text())}
+
+
 def _expert_products(dim: int, ffn: int, held: int, rows: int, form: str,
+                     experts: int, top_k: int, model: str,
                      kernel: str = "pallas", repeats: int = 5
                      ) -> Dict[str, Any]:
     """``parallel/moe.expert_products`` over a buffer half full at an even
@@ -715,7 +795,8 @@ def _expert_products(dim: int, ffn: int, held: int, rows: int, form: str,
     (``experts_ms``); ONE program, the groups are data. Beside them the
     row tiles a forward product visits either way, and how far the live
     rows' results and the gradients of the two lie apart (0: the same
-    tiles do the same work)."""
+    tiles do the same work). Then the whole block they stand in, forward
+    and backward as a step runs it (:func:`_expert_block`)."""
     import jax
     import jax.numpy as jnp
 
@@ -767,6 +848,8 @@ def _expert_products(dim: int, ffn: int, held: int, rows: int, form: str,
     if not max(apart) <= 1e-2:      # NaN too
         raise AssertionError(f"grouped products without the padding lie "
                              f"{apart} from those with it")
+    facts.update(_expert_block(dim, ffn, held, rows, form, experts, top_k,
+                               model, kernel, repeats))
     return facts
 
 

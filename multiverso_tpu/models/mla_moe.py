@@ -672,9 +672,22 @@ def _sub(params: Dict[str, Any], prefix: str) -> Dict[str, Any]:
             if k.startswith(prefix + ".")}
 
 
+def kept_names(cfg) -> Tuple[str, ...]:
+    """What a rematerialised block of ``cfg`` keeps for its backward
+    pass: ``moe.KEPT_NAMES``, all of them or none. None where the
+    configuration says ``keeps_products = False`` (``NemotronHConfig``
+    alone: a kept result is reserved with the step's program, and
+    ``nemotron3n-train-16k`` has no room)."""
+    return moe.KEPT_NAMES if getattr(cfg, "keeps_products", True) else ()
+
+
 def _run_block(x, p, layer: Layer, bias, cfg, remat: bool = True):
-    """A block of ``layer``'s kinds, rematerialised unless told not to;
-    ``bias`` is its router's (a dense layer has none)."""
+    """A block of ``layer``'s kinds, rematerialised unless told not to:
+    the backward pass makes the block again but for the results that
+    :func:`kept_names` names (an expert layer's grouped products into the
+    experts' width, its sort and its route's choice; a block that meets
+    no such name is made again whole). ``bias`` is its router's (a dense
+    layer has none)."""
     attn = (None if layer.attn is None
             else lambda u, q: cfg.attend(u, q, layer.attn))
     if layer.ffn is None:
@@ -685,7 +698,8 @@ def _run_block(x, p, layer: Layer, bias, cfg, remat: bool = True):
         ffn = lambda u, q: expert_ffn(u, q, bias, cfg,
                                       layer.ffn == "shared+experts")
     run = lambda x, p: block(x, p, attn, ffn, cfg)
-    return (jax.checkpoint(run) if remat else run)(x, p)
+    keep = jax.checkpoint_policies.save_only_these_names(*kept_names(cfg))
+    return (jax.checkpoint(run, policy=keep) if remat else run)(x, p)
 
 
 def _ce_chunks(h, head, targets, weights, cfg, grads: bool):
@@ -762,6 +776,25 @@ def loss_grid(cfg, tokens: int) -> Dict[str, Any]:
     losses = 1 + any(layer.name == "mtp" for layer in cfg.layers())
     return {"head_products": 3 * losses,
             "loss_chunks": tokens // min(cfg.loss_chunk, tokens)}
+
+
+def kept_grid(cfg, tokens: int) -> Dict[str, int]:
+    """What the rematerialised blocks of a training step over ``tokens``
+    positions keep by name (:func:`kept_names`), as ``lm.step`` spans
+    carry it: ``expert_products_kept``, the grouped products' results
+    (those into the experts' width: two an expert layer, one of the
+    ``relu2`` form), and ``kept_bytes``, theirs ([rows, ffn] each), the
+    sorted buffers' row orders' (int32 [rows]) and the routes' choices'
+    (int32 [tokens, top_k]), from the shapes."""
+    if not kept_names(cfg):
+        return {"expert_products_kept": 0, "kept_bytes": 0}
+    here = held(cfg, tokens)
+    rows, layers = moe.buffer_length(here, tokens), len(expert_layers(cfg))
+    products = 1 + (cfg.expert_form == "gated_silu")
+    item = jnp.dtype(here.dtype).itemsize
+    return {"expert_products_kept": layers * products,
+            "kept_bytes": layers * (rows * (products * cfg.moe_ffn * item + 4)
+                                    + 4 * tokens * cfg.top_k)}
 
 
 def _embed(params, tokens, cfg):
@@ -1003,13 +1036,15 @@ class Trainer:
         with _trace.span("lm.step", request=self.steps) as sp:
             due = self._ahead
             if tokens is not None:
+                count = int(np.prod(tokens.shape))
                 if self.steps == 1:     # one program, one shape
                     positions = int(tokens.shape[1])
                     self._attn = dict(
                         attn_grid(self.cfg, positions),
                         **mixer_grid(self.cfg, positions),
-                        **loss_grid(self.cfg, int(np.prod(tokens.shape))))
-                sp.set(tokens=int(np.prod(tokens.shape)))
+                        **loss_grid(self.cfg, count),
+                        **kept_grid(self.cfg, count))
+                sp.set(tokens=count)
                 t0_ns = time.time_ns()
                 self.states, self.bias, *back = self._step(
                     self.states, self.bias, tokens)

@@ -117,6 +117,13 @@ class NemotronHConfig(NamedTuple):
     embed_scale = 1.0
     route = "sigmoid"                # parallel/moe.HeldExperts.route
     expert_form = "relu2"            # parallel/moe.HeldExperts.form
+    # ``mla_moe.kept_names``: a block keeps NOTHING here. What it keeps is
+    # reserved with the step's program, and beside this model's step
+    # ``nemotron3n-train-16k`` has 0.26 GB of 16.9 free (PERF.md, PR 51:
+    # with ``w_up``'s results kept, 46 MB a layer, the step no longer loads
+    # a second time after the reference's programs). Goes when the cell
+    # has room (ROADMAP Speed 7(c))
+    keeps_products = False
     balance_coef = 0.0               # no load-balance term in the loss
 
     @property

@@ -40,6 +40,7 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _gmm
 from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm as _tgmm
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -244,7 +245,8 @@ def sigmoid_route(u: jax.Array, router: jax.Array, bias: jax.Array,
     are chosen, gates are the chosen scores over their sum, times
     ``routed_scale``. ``bias`` only selects: it is in no gate and takes no
     gradient. Returns (chosen [T, K], gates [T, K] float32, counts [E]
-    int32: the tokens that chose each expert)."""
+    int32: the tokens that chose each expert). The choice is named for a
+    checkpoint's policy (``KEPT_NAMES``)."""
     with jax.named_scope("mv.lm.moe.route"):
         logits = jax.lax.dot_general(
             u.astype(jnp.float32), router.astype(jnp.float32),
@@ -252,6 +254,7 @@ def sigmoid_route(u: jax.Array, router: jax.Array, bias: jax.Array,
         scores = jax.nn.sigmoid(logits)
         _, chosen = jax.lax.top_k(
             scores + jax.lax.stop_gradient(bias)[None, :], cfg.top_k)
+        chosen = checkpoint_name(chosen, KEEP_CHOSEN)
         picked = jnp.take_along_axis(scores, chosen, axis=-1)
         gates = cfg.routed_scale * picked / picked.sum(-1, keepdims=True)
         counts = (chosen[..., None] == jnp.arange(cfg.num_experts)).sum(
@@ -266,16 +269,24 @@ def softmax_route(u: jax.Array, router: jax.Array, cfg: HeldExperts):
     (chosen [T, K], gates [T, K] float32, counts [E] int32, balance): the
     last is the load-balance term ``E * sum_e f_e P_e``, ``f_e`` the share
     of the T x K assignments that chose ``e`` (no gradient) and ``P_e``
-    the mean of ``p_e`` over the tokens: 1 where the load is even."""
+    the mean of ``p_e`` over the tokens: 1 where the load is even. The
+    choice is named for a checkpoint's policy (``KEPT_NAMES``)."""
     with jax.named_scope("mv.lm.moe.route"):
         logits = jax.lax.dot_general(
             u.astype(jnp.float32), router.astype(jnp.float32),
             (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST)
         probs = jax.nn.softmax(logits, -1)
-        picked, chosen = jax.lax.top_k(probs, cfg.top_k)
+        _, chosen = jax.lax.top_k(probs, cfg.top_k)
+        chosen = checkpoint_name(chosen, KEEP_CHOSEN)
+        # the gates are read AT the kept choice, so a backward pass that
+        # keeps it runs no ``top_k`` and sends each gate's gradient to the
+        # probability the forward pass chose; by a select over the experts
+        # and not ``take_along_axis``: that is a gather of tokens x top_k
+        # and its scatter, 10.7 ms a step of ``mellum2-train-8k``
+        at = chosen[..., None] == jnp.arange(cfg.num_experts)
+        picked = jnp.where(at, probs[:, None, :], 0.0).sum(-1)
         gates = picked / picked.sum(-1, keepdims=True)
-        counts = (chosen[..., None] == jnp.arange(cfg.num_experts)).sum(
-            (0, 1), dtype=jnp.int32)
+        counts = at.sum((0, 1), dtype=jnp.int32)
         share = counts.astype(jnp.float32) / (u.shape[0] * cfg.top_k)
         balance = cfg.num_experts * jnp.sum(share * probs.mean(0))
     return chosen, gates, counts, balance
@@ -377,13 +388,38 @@ def product_tiles(sizes, rows: int, tm: int) -> int:
                .sum())
 
 
+# What a rematerialised block keeps (``models/mla_moe._run_block``'s
+# policy, by ``checkpoint_name``; ``mla_moe.kept_names``): the result of
+# each grouped product INTO the experts' width as it leaves the kernel, in ``HeldExperts.dtype`` ([rows, ffn] of
+# ``w_gate`` and ``w_up``: neither is a residual of its own product, both
+# are needed downstream, so without them the backward pass runs their
+# forward kernels again), the sorted buffer's row order (int32 [rows]: the
+# stable sort over tokens x top_k keys), and the route's CHOICE (int32
+# [tokens, top_k]). The choice MUST be kept with the others: a backward
+# pass that made the route again may choose otherwise at a near-tie (XLA
+# fuses the two passes apart and a score differs in its last place), and
+# the kept results' rows would then sit in another expert's group: a
+# step's gradients are wrong and the loss is not a number some steps on.
+# Everything else of a block is made again, ``w_down``'s forward product
+# among it: its result is [rows, dim], the widest of the three, what is
+# kept is reserved with the step's program, and a float kept costs a
+# ``reduce_precision`` pass of its own over it, which at
+# ``mellum2-train-8k``'s shapes is what that kernel costs (0.90 against
+# 0.92 ms) and at ``lfm2-train-8k``'s half.
+KEPT_NAMES = KEEP_GATE, KEEP_UP, KEEP_TAKE, KEEP_CHOSEN = (
+    "mv.moe.gate", "mv.moe.up", "mv.moe.take", "mv.moe.chosen")
+
+
 def expert_products(x: jax.Array, params: Dict, groups: jax.Array,
                     cfg: HeldExperts, kernel: str) -> jax.Array:
     """The held experts' MLPs over the sorted buffer ``x`` [rows, D] in
     ``cfg.dtype``, ``groups`` [H] rows an expert: two or three grouped
-    products (``cfg.form``). The rows past the groups are UNWRITTEN in
-    the result, as they are in every intermediate (each product's result
-    and the hidden rows between them): see :func:`grouped_matmul`."""
+    products (``cfg.form``), those into the experts' width with their
+    results named as they leave the kernel for a checkpoint's policy
+    (``KEPT_NAMES``; an identity anywhere else). The rows past the groups
+    are UNWRITTEN in the result, as they are in every intermediate (each
+    product's result and the hidden rows between them): see
+    :func:`grouped_matmul`."""
     tile = cfg.tile             # over rows, over dim and over ffn
     if kernel == "xla":
         up = down = functools.partial(
@@ -394,12 +430,15 @@ def expert_products(x: jax.Array, params: Dict, groups: jax.Array,
                                interpret=kernel == "interpret")
         up = functools.partial(mm, tile=tile)       # dim -> ffn
         down = functools.partial(mm, tile=(tile[0], tile[2], tile[1]))
+
+    def kept(name: str, role: str) -> jax.Array:
+        return checkpoint_name(up(x, params[role]),
+                               name).astype(jnp.float32)
+
     if cfg.form == "gated_silu":
-        h = (jax.nn.silu(up(x, params["w_gate"]).astype(jnp.float32))
-             * up(x, params["w_up"]).astype(jnp.float32))
+        h = jax.nn.silu(kept(KEEP_GATE, "w_gate")) * kept(KEEP_UP, "w_up")
     elif cfg.form == "relu2":
-        h = jnp.square(jax.nn.relu(
-            up(x, params["w_up"]).astype(jnp.float32)))
+        h = jnp.square(jax.nn.relu(kept(KEEP_UP, "w_up")))
     else:
         raise ValueError(f"no expert form named {cfg.form!r}")
     return down(h.astype(cfg.dtype), params["w_down"])
@@ -426,7 +465,9 @@ def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
     hidden rows ``h``, ``down``'s ``y``) and their gradients are
     UNWRITTEN memory: ``x`` and ``y`` are SELECTED by ``live`` on their
     way in and out (a product with zero would keep a NaN), and nothing
-    else may reduce over those rows. ``overflow_rows`` counts rows
+    else may reduce over those rows; a backward pass that kept the
+    products' results (``KEPT_NAMES``) reads there what the forward
+    kernels left. ``overflow_rows`` counts rows
     that did not fit the buffer and were left out: 0 unless the buffer
     was sized under the load (it cannot be with ``buffer_rows=None``).
     ``kernel``: ``"pallas"`` (the chip's default), ``"interpret"`` (the
@@ -450,8 +491,9 @@ def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
         here = (local >= 0) & (local < held)
         # held assignments first, grouped by expert, in token order
         order = jnp.argsort(jnp.where(here, local, held), stable=True)
-        take = order[:rows] if rows <= t * k else jnp.pad(
-            order, (0, rows - t * k))
+        take = checkpoint_name(
+            order[:rows] if rows <= t * k else jnp.pad(
+                order, (0, rows - t * k)), KEEP_TAKE)
         sizes = jax.lax.dynamic_slice(counts, (cfg.expert_offset,), (held,))
         ends = jnp.minimum(jnp.cumsum(sizes), rows)
         held_rows = ends[-1]

@@ -99,8 +99,10 @@ def test_lm_stage():
     facts = chip_smoke.stage_lm(
         vocab=128, dim=32, heads=4, layers=2, seq=32, batch_per_chip=1,
         kernel_shapes=(((1, 2, 64, 16), 32), ((1, 2, 64, 16), (16, 32))),
-        expert_calls=(("gated", 128, 128, 2, 512, "gated_silu"),
-                      ("relu2", 128, 128, 2, 512, "relu2")),
+        expert_calls=(("gated", 128, 128, 2, 512, "gated_silu", 4, 2,
+                       "gqa_moe"),
+                      ("relu2", 128, 128, 2, 512, "relu2", 4, 2,
+                       "nemotron_h")),
         head_calls=(("chunks", 128, 96, 32, 32), ("whole", 64, 96, 32, 64)),
         chip=False)
     assert facts["batch_axis"] == "mv"      # kernel ran under shard_map
@@ -114,6 +116,10 @@ def test_lm_stage():
         assert call["experts_ms"] > 0 and call["experts_ms_padded"] > 0
         assert call["experts_tiles"] == 2 and call["experts_tiles_padded"] == 4
         assert call["apart_from_padded"] <= 1e-6
+        # the block around them, as a step rematerialises it (the count of
+        # its kernels is the chip's: the interpreter compiles none)
+        assert call["block_tokens"] == 256 and call["block_ms"] > 0
+        assert call["block_kernels"] == {"gmm": 0, "tgmm": 0}
     # the chunked loss alone, four chunks and one
     assert set(facts["heads"]) == {"chunks", "whole"}
     for call in facts["heads"].values():

@@ -870,6 +870,47 @@ def test_sigmoid_expert_layer_at_rows_of_1792_compiles_for_a_v5e(one_chip):
     assert compiled.as_text().count("tpu_custom_call") >= 9
 
 
+@pytest.mark.parametrize("model,gmm,tgmm", [
+    ("lfm2", 7, 3), ("lfm2_keeping_nothing", 9, 3), ("nemotron", 6, 2)])
+def test_a_rematerialised_expert_block_on_a_v5e_keeps_its_up_products(
+        one_chip, model, gmm, tgmm):
+    """An expert block of ``lfm2-train-8k`` (gated, 2,048 x 1,792) under
+    ``mla_moe._run_block`` as a training step rematerialises it: the
+    compiled backward and forward hold THREE grouped kernels a matrix
+    (``gmm`` forward and for the buffer's gradient, ``tgmm`` for the
+    matrix's) and the forward one out of the experts' width once more,
+    the results of those into it being kept by name; with nothing kept
+    every forward ``gmm`` call is there a second time, as it is in a
+    block of ``nemotron3n-train-16k`` (``relu2``, 2,688 x 1,856), whose
+    configuration keeps nothing."""
+    import chip_smoke
+    from multiverso_tpu.models import lfm2_moe, mla_moe, nemotron_h
+
+    lfm2 = lfm2_moe.LFM2MoEConfig(
+        dim=2048, moe_ffn=1792, n_experts=32, experts_held=8, top_k=4,
+        expert_kernel="pallas")
+    cfg = {"lfm2": lfm2,
+           "lfm2_keeping_nothing": type(
+               "Bare", (lfm2_moe.LFM2MoEConfig,),
+               {"keeps_products": False})(*lfm2),
+           "nemotron": nemotron_h.NemotronHConfig(
+               dim=2688, moe_ffn=1856, n_experts=128, experts_held=8,
+               top_k=6, expert_kernel="pallas")}[model]
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {n: f32(*s) for n, s in dict(mla_moe._ffn_shapes(cfg, "experts"),
+                                     ffn_norm=(cfg.dim,)).items()}
+    layer = mla_moe.Layer("L0", None, "experts")
+
+    def loss(x, p, bias):
+        y, _ = mla_moe._run_block(x, p, layer, bias, cfg)
+        return y.sum()
+
+    # the value as well: a gradient alone needs no forward pass
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        f32(1, 4096, cfg.dim), p, f32(cfg.n_experts)).compile().as_text()
+    assert chip_smoke.grouped_kernels(text) == {"gmm": gmm, "tgmm": tgmm}
+
+
 def test_short_convolution_mixer_compiles_for_a_v5e_at_published_widths(
         one_chip):
     """``models/lfm2_moe.short_conv`` on two sequences of 8,192 at width

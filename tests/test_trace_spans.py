@@ -781,9 +781,14 @@ def test_lm_step_carries_its_counts():
     layers = len(mla_moe.expert_layers(cfg))
     want = mla_moe.routing_counts(counts, cfg)
     # ... and, static, the losses' products of positions x vocabulary
-    # (main and module, three each) and the chunks a loss walks
+    # (main and module, three each), the chunks a loss walks, and what the
+    # two expert blocks keep for their backward pass: two float32
+    # products' results each over a buffer of 256 rows (48 wide), the
+    # buffer's int32 order and the route's int32 choice
     assert step["args"] == dict(want, tokens=64, head_products=6,
-                                loss_chunks=2)
+                                loss_chunks=2, expert_products_kept=4,
+                                kept_bytes=2 * (256 * (2 * 48 * 4 + 4)
+                                                + 64 * cfg.top_k * 4))
     assert want["routed_rows"] == layers * 64 * cfg.top_k
     assert 0 <= want["held_rows"] <= want["routed_rows"]
     assert want["overflow_rows"] == 0 and want["load_max_over_mean"] >= 1.0
