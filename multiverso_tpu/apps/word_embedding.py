@@ -248,6 +248,8 @@ class WordEmbedding:
         self._dev_negs = (not cfg.hs and cfg.negative > 0
                           and 4 * v <= cfg.data_block_size * cfg.negative)
         self._fused_cache: Dict[str, object] = {}
+        # the programs whose map has been recorded (xla.program)
+        self._described: set = set()
         # matmul dtype of the shared-negatives fused epoch, set when that
         # program is first built (bf16 on TPU, f32 elsewhere)
         self.fused_compute_dtype = None
@@ -585,6 +587,13 @@ class WordEmbedding:
             # pass's loss is ready (it waits for the chain)
             self._watcher.watch("we.fused.device", loss, t0_ns,
                                 request=self._calls, cause=disp.id)
+            if "we.fused" not in self._described:
+                # the epoch program's map, once, on what the last pass
+                # handed back (the tables' buffers now)
+                self._described.add("we.fused")
+                _devstats.describe_program(
+                    "we.fused", epoch_fn, win, wsec, *batches,
+                    *((self._lcg, plans) if shared else (sub,)))
             if shared:
                 call.set(**self._sharded_call_counts(
                     pair_rows, lcg_before, n_batches, epochs))
@@ -1386,6 +1395,18 @@ class WordEmbedding:
                 t_sec.adopt({"data": dsec, "ustate": usec})
         self._watcher.watch("we.block.device", loss, t0_ns, request=index,
                             cause=sp.id)
+        if "we.blocks" not in self._described:
+            # the block program's map, once, on the buffers it handed
+            # back, and the map of the program that made its plans
+            self._described.add("we.blocks")
+            _devstats.describe_program(
+                "we.blocks", fn, din, uin, dsec, usec, prep["ids_in"],
+                prep["ids_sec"], prep["valid"], batch, plans)
+            if ahead is not None:
+                _devstats.describe_program(
+                    "we.blocks.ahead", ahead, prep["batch"], prep["valid"],
+                    prep["ids_in"].shape[0] + 1, prep["remap"],
+                    prep["neg_seed"], self._neg_dev)
         return loss, counts
 
     def _ps_topology(self) -> Tuple[int, int]:

@@ -61,6 +61,7 @@ import numpy as np
 from multiverso_tpu.ops.attention_kernels import (causal_pairs,
                                                    flash_attention, sub_tile)
 from multiverso_tpu.parallel import moe
+from multiverso_tpu.telemetry import devstats as _devstats
 from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.updaters import AddOption
 
@@ -657,12 +658,15 @@ def block(x, p, attn, ffn, cfg):
         with jax.named_scope("mv.lm.norm.post"):
             return rms_norm(branch, p[name], cfg.eps)
 
+    def normed(stream, name):
+        with jax.named_scope("mv.lm.norm.pre"):
+            return rms_norm(stream, p[name], cfg.eps)
+
     h, aux = x, None
     if attn is not None:
-        h = x + out(attn(rms_norm(x, p["attn_norm"], cfg.eps), p),
-                    "attn_post_norm")
+        h = x + out(attn(normed(x, "attn_norm"), p), "attn_post_norm")
     if ffn is not None:
-        f, aux = ffn(rms_norm(h, p["ffn_norm"], cfg.eps), p)
+        f, aux = ffn(normed(h, "ffn_norm"), p)
         h = h + out(f, "ffn_post_norm")
     return h, aux
 
@@ -912,14 +916,18 @@ def make_train_step(cfg, tables: Dict[str, Any],
     biased = cfg.route == "sigmoid"     # the route that selects under a bias
 
     def step(states, bias, tokens):
-        params = _params_of(states, shapes)
+        # mv.lm.params / mv.lm.update: the names the tables' side of a
+        # step carries in the program's map (metadata only)
+        with jax.named_scope("mv.lm.params"):
+            params = _params_of(states, shapes)
         (loss, (counts, overflow, balance)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params, bias, tokens, cfg)
         new = {}
-        for name, table in tables.items():
-            delta = table.pad_delta(grads[name].reshape(
-                table_shape(shapes[name])))
-            new[name] = table.functional_add(states[name], delta, opt)
+        with jax.named_scope("mv.lm.update"):
+            for name, table in tables.items():
+                delta = table.pad_delta(grads[name].reshape(
+                    table_shape(shapes[name])))
+                new[name] = table.functional_add(states[name], delta, opt)
         if biased:
             bias = moe.bias_update(bias, counts, cfg.bias_speed)
         return (new, bias, loss, _with_overflow(counts, overflow),
@@ -1050,6 +1058,12 @@ class Trainer:
                     self.states, self.bias, tokens)
                 self._watcher.watch("lm.step.device", back[0], t0_ns,
                                     request=self.steps, cause=sp.id)
+                if self.steps == 1:
+                    # the program's map, once, from JAX's caches, while
+                    # the device runs the first step (xla.program)
+                    _devstats.describe_program(
+                        "lm.step", self._step, self.states, self.bias,
+                        tokens)
                 due, self._ahead = ((due, back) if ahead else (back, None))
             else:
                 self._ahead = None

@@ -52,6 +52,18 @@ warnings into a machine-readable report keyed (jitted fn, mesh shape).
 shipped workload at every mesh shape; :func:`dump_hygiene` writes
 ``compile-hygiene-rank<r>.json`` for ``tools/mvprof.py --report``.
 
+**The compiled program's map** (:func:`describe_program`,
+:func:`scope_seconds`): a device trace names an operation by its HLO
+instruction and carries no ``jax.named_scope``; the compiled program's
+text carries both. A trainer hands its jitted program over once, after
+its first call, and one coarse ``xla.program`` record says which
+``mv.*`` scope and which pass (forward, made again under
+``jax.checkpoint``, backward) each instruction belongs to and what the
+program reserves of the device; the join of
+that map with a trace's operations is :func:`scope_seconds`
+(``tools/dump_metrics.py scopes``). ``xla.compile`` carries the seconds
+of tracing and lowering that led to each compile.
+
 Cost discipline: the ``devstats`` flag (default ON) gates every
 recording site behind one attribute read; counters are one int add
 under a lock at per-batch (not per-row) sites; the live-arrays walk
@@ -64,13 +76,16 @@ from __future__ import annotations
 import json
 import logging
 import os
+import re
 import threading
 import time
+import traceback
 import warnings
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from multiverso_tpu.telemetry import profiler as _profiler
 from multiverso_tpu.telemetry import trace as _trace
-from multiverso_tpu.utils import config
+from multiverso_tpu.utils import config, log
 
 config.define_bool(
     "devstats", True,
@@ -90,6 +105,11 @@ _DIRECTIONS = ("h2d", "d2h")
 # compile events with no mesh scope active (host-plane jits, warmup
 # before any mesh exists) key under this label
 _NO_MESH = "unmeshed"
+
+# the jax.monitoring events of Python's part of a compile, by the last
+# component of their names, and the xla.compile count each adds up into
+_LED_TO_COMPILE = {"jaxpr_trace_duration": "trace_s",
+                   "jaxpr_to_mlir_module_duration": "lower_s"}
 
 
 # ---------------------------------------------------------------------- #
@@ -345,6 +365,13 @@ class DevStats:
             # the executable came from the persistent cache
             self._tls.cache_load = True
             return
+        led = _LED_TO_COMPILE.get(name.rpartition("/")[2])
+        if led is not None:
+            # Python's part of a compile: tracing (one event for every
+            # nested jit, hundreds a step) and lowering, on this thread,
+            # fold into the compile they lead to
+            setattr(self._tls, led, getattr(self._tls, led, 0.0) + float(dur))
+            return
         if not name.endswith("backend_compile_duration"):
             return
         label = self._mesh_label()
@@ -355,11 +382,28 @@ class DevStats:
             d["compile_s"] = round(d["compile_s"] + float(dur), 6)
         loaded = getattr(self._tls, "cache_load", False)
         self._tls.cache_load = False
+        trace_s, lower_s = self._take_led()
         t1 = time.time_ns()
         _trace.record("xla.compile", t1 - int(float(dur) * 1e9), t1,
                       seconds=float(dur), mesh=label,
                       event="cache_load" if loaded else "compile",
-                      fun=str(kw.get("fun_name", "")))
+                      fun=str(kw.get("fun_name", "")),
+                      trace_s=trace_s, lower_s=lower_s)
+
+    def _take_led(self, put: Tuple[float, float] = (0.0, 0.0)
+                  ) -> Tuple[float, float]:
+        """This thread's seconds of tracing and lowering since its last
+        compile event, replaced by ``put``."""
+        tls = self._tls
+        had = (getattr(tls, "trace_s", 0.0), getattr(tls, "lower_s", 0.0))
+        tls.trace_s, tls.lower_s = put
+        return had
+
+    def compile_events(self) -> int:
+        """Compile events heard so far (cache loads among them), all
+        meshes."""
+        with self._lock:
+            return sum(d["compiles"] for d in self._compiles.values())
 
     # ------------------------------------------------------------------ #
     # mesh context
@@ -598,3 +642,266 @@ def stats_snapshot() -> Optional[Dict[str, Any]]:
 
 def reset() -> None:
     DEVSTATS.reset()
+
+
+# ---------------------------------------------------------------------- #
+# the compiled program's map: instruction -> scope and pass
+# ---------------------------------------------------------------------- #
+PROGRAM_SPAN = "xla.program"
+# where scope_seconds files an operation whose instruction has no mv.*
+# scope in its path / is in no program's map / is claimed for two places
+UNSCOPED, UNKNOWN, AMBIGUOUS = "_unscoped_", "_unknown_", "_ambiguous_"
+PASSES = ("fwd", "remat", "bwd")
+NO_PASS = "-"               # the pass of what is in no map
+# a Pallas kernel is filed apart from XLA's instructions of its scope
+# ("mv.lm.attn:kernel"): a kernel-only reader's sum then has its row
+KERNEL = ":kernel"
+_KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
+
+# not operations of their own on the device
+_NOT_OPS = frozenset(("parameter", "constant", "tuple", "get-tuple-element",
+                      "bitcast"))
+# the instructions whose called computations the device runs as
+# operations of their own (a fusion's, a reduce's, a sort's are inside it)
+_CALLERS = frozenset(("while", "call", "conditional", "async-start"))
+_HEADER = re.compile(r"(ENTRY )?%?([^\s(]+) \(")
+_INSTR = re.compile(r"\s+(?:ROOT )?%?(\S+) = ")
+_TUPLE_OPCODE = re.compile(r"\) ([a-z][\w\-]*)\(")
+# a result shape as benchmark/trace_reduce.result_shape reads it off a
+# trace event: the first shape after " = ", layout and tiling left out
+_SHAPE = re.compile(r"\b(pred|[suf]\d+|bf16|c64|c128)\[([0-9,]*)\]")
+_CALLED = re.compile(
+    r"\b(?:condition|body|to_apply|calls|true_computation|false_computation)"
+    r"=%?([^\s,)}]+)|branch_computations=\{([^}]*)\}")
+_SCOPE = re.compile(r"(?<![\w.])mv\.[\w.\-]+")
+_OP_NAME = 'op_name="'
+
+
+def _shape_in(text: str, lo: int = 0, hi: Optional[int] = None) -> str:
+    m = (_SHAPE.search(text, lo) if hi is None
+         else _SHAPE.search(text, lo, hi))
+    return f"{m.group(1)}[{m.group(2)}]" if m else ""
+
+
+def place_of(op_name: str) -> Tuple[str, str]:
+    """(scope, pass) of an instruction from its metadata's ``op_name``
+    (``jit(step)/transpose(jvp(mv.lm.head))/dot_general``): the last
+    ``mv.*`` name in the path, :data:`UNSCOPED` where there is none;
+    ``remat`` under ``jax.checkpoint``'s ``rematted_computation``, else
+    ``bwd`` under a ``transpose(``, else ``fwd``."""
+    found = _SCOPE.findall(op_name)
+    return (found[-1] if found else UNSCOPED,
+            "remat" if "rematted_computation" in op_name
+            else "bwd" if "transpose(" in op_name else "fwd")
+
+
+def program_map(text: str) -> Dict[str, Any]:
+    """What a compiled program's text (``Compiled.as_text()``) says of
+    the instructions the device runs as operations of their own: those
+    of the entry computation and of every ``while`` body and condition,
+    called computation and branch reached from it, the insides of fused
+    computations and the opcodes of :data:`_NOT_OPS` left out.
+
+    A fusion whose own metadata is empty (one with several results: its
+    root is a ``tuple``) takes the path of the last instruction inside
+    it that has one; a Pallas kernel's scope ends in :data:`KERNEL`.
+
+    Returns ``module``, ``instructions``, ``scoped`` (those with an
+    ``mv.*`` scope) and ``scopes``: ``{scope: {pass: [[name, shape],
+    ...]}}``, the shape as a trace prints it (``f32[4096]``; of a tuple
+    its first)."""
+    module = ""
+    # a computation's instructions: (name, shape, op_name, the fused
+    # computation to ask where op_name is empty, whether a kernel)
+    bodies: Dict[str, List[Tuple[str, str, str, str, bool]]] = {}
+    calls: Dict[str, List[str]] = {}
+    last_path: Dict[str, str] = {}      # by computation, fused ones too
+    entry = current = None
+    for line in text.splitlines():
+        if not line:
+            continue
+        if line[0] != " ":
+            if line.startswith("HloModule "):
+                module = line.split()[1].rstrip(",")
+            elif line.endswith("{"):
+                head = _HEADER.match(line)
+                if head:
+                    current = head.group(2)
+                    bodies[current], calls[current] = [], []
+                    if head.group(1):
+                        entry = current
+            continue
+        m = _INSTR.match(line)
+        if m is None or current is None:
+            continue
+        at = m.end()
+        if line[at] == "(":         # a tuple: the opcode follows its ")"
+            found = _TUPLE_OPCODE.search(line, at)
+            if found is None:
+                continue
+            opcode, paren = found.group(1), found.end() - 1
+        else:
+            space = line.find(" ", at)
+            paren = line.find("(", space)
+            opcode = line[space + 1:paren]
+        i = line.find(_OP_NAME, paren)
+        op_name = (line[i + len(_OP_NAME):line.find('"', i + len(_OP_NAME))]
+                   if i >= 0 else "")
+        if op_name:
+            last_path[current] = op_name
+        if opcode in _NOT_OPS:
+            continue
+        inside = ""
+        if opcode in _CALLERS:
+            for one, many in _CALLED.findall(line, paren):
+                calls[current] += [one] if one else [
+                    c.strip().lstrip("%") for c in many.split(",")]
+        elif opcode == "fusion" and not op_name:
+            found = _CALLED.search(line, paren)
+            inside = found.group(1) if found and found.group(1) else ""
+        bodies[current].append(
+            (m.group(1), _shape_in(line, at, paren), op_name, inside,
+             opcode == "custom-call" and _KERNEL_TARGET in line))
+    scopes: Dict[str, Dict[str, List[List[str]]]] = {}
+    instructions = scoped = 0
+    todo, seen = [entry], set()
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in bodies:
+            continue
+        seen.add(comp)
+        todo += calls[comp]
+        for name, shape, op_name, inside, kernel in bodies[comp]:
+            scope, pas = place_of(op_name or last_path.get(inside, ""))
+            instructions += 1
+            if scope != UNSCOPED:
+                scoped += 1
+                scope += KERNEL if kernel else ""
+            scopes.setdefault(scope, {}).setdefault(pas, []).append(
+                [name, shape])
+    return {"module": module, "instructions": instructions,
+            "scoped": scoped, "scopes": scopes}
+
+
+def describe_program(program: str, fn: Any, *args: Any
+                     ) -> Optional[Dict[str, Any]]:
+    """Record ONE coarse ``xla.program`` span for ``fn``, a ``jax.jit``
+    function that has just run on arguments like ``args`` (the states
+    its first call returned, not the donated ones): the map of its
+    compiled program (:func:`program_map`), what the program reserves of
+    the device (``Compiled.memory_analysis()``: ``argument_bytes``,
+    ``output_bytes``, ``alias_bytes``, ``temp_bytes``, ``code_bytes``),
+    under the name ``program``. The span's ``dur`` is what this cost;
+    ``recompiled`` the compile events it started: 0 where ``args`` are
+    like the first call's, since
+    ``fn.lower(*args).compile()`` then finds the traced, lowered and
+    compiled program in JAX's caches, and 1 where the SECOND call would
+    have compiled anyway (its arguments' shardings are not the first's:
+    the language-model ``Trainer``), which this then does in its place:
+    the record describes the program every later call runs. A set-up
+    site, once per program; returns the counts, ``None`` where the flag
+    is off or the program will not say (logged as an error)."""
+    ds = DEVSTATS
+    if not ds.enabled:
+        return None
+    ds._install_listener()
+    t0 = time.time_ns()
+    before, led = ds.compile_events(), ds._take_led()
+    try:
+        compiled = fn.lower(*args).compile()
+        counts = program_map(compiled.as_text())
+        mem = compiled.memory_analysis()
+        counts.update(
+            program=program,
+            argument_bytes=int(mem.argument_size_in_bytes),
+            output_bytes=int(mem.output_size_in_bytes),
+            alias_bytes=int(mem.alias_size_in_bytes),
+            temp_bytes=int(mem.temp_size_in_bytes),
+            code_bytes=int(mem.generated_code_size_in_bytes))
+    except Exception:   # noqa: BLE001 — a program that will not describe
+        # itself trains all the same, and says why its metrics read None
+        log.error("xla.program: %s will not describe itself: %s",
+                  program, traceback.format_exc(limit=3).strip())
+        return None
+    finally:
+        # a cache hit still fires a trace event: not the next compile's
+        ds._take_led(led)
+    counts["recompiled"] = ds.compile_events() - before
+    _trace.record(PROGRAM_SPAN, t0, time.time_ns(), **counts)
+    return counts
+
+
+def _leaves(ops: Sequence[Tuple[str, str, float, float]]
+            ) -> List[Tuple[str, str, float, float]]:
+    """The operations of one chip's line that hold no other (a ``while``
+    holds its body's): on one line an operation that starts inside
+    another ends inside it."""
+    out, stack = [], []             # stack rows: [end, holds one, op]
+    for op in sorted(ops, key=lambda o: (o[2], -o[3])):
+        end = op[2] + op[3]
+        while stack and (stack[-1][0] < end - 1e-12
+                         or stack[-1][0] <= op[2]):
+            _, holds, done = stack.pop()
+            if not holds:
+                out.append(done)
+        if stack:
+            stack[-1][1] = True
+        stack.append([end, False, op])
+    return out + [op for _, holds, op in stack if not holds]
+
+
+def scope_seconds(ops: Dict[str, Sequence[Tuple[str, str, float, float]]],
+                  records: Sequence[Dict[str, Any]]) -> Dict[str, Any]:
+    """A traced window's device seconds by scope and pass: the join of
+    the trace's operations with the programs' maps.
+
+    ``ops`` maps a chip to its operations ``(name, text, start_s,
+    dur_s)``, the name and text as the trace has them (what
+    ``benchmark/trace_reduce.read_xplane`` returns); ``records`` are the
+    ring's ``xla.program`` records (their ``args``, or the events whole).
+    Of each chip the operations that hold no other are looked up by name
+    AND result shape: filed under the instruction's ``(scope, pass)``;
+    under :data:`UNSCOPED` with its pass where the map has it with no
+    ``mv.*`` in its path; under :data:`UNKNOWN` where no map has it;
+    under :data:`AMBIGUOUS` where two maps would file it differently.
+
+    Returns, as means over the chips that ran anything: ``seconds``
+    ``{scope: {pass: s}}``, ``busy_s`` (the union of every operation's
+    interval), ``filed_s`` (what lies under a scope or :data:`UNSCOPED`),
+    ``longest`` ``{scope: [[name, shape, s], ...]}`` (three a scope),
+    and ``chips``."""
+    where: Dict[Tuple[str, str], Tuple[str, str]] = {}
+    for rec in records:
+        for scope, by in rec.get("args", rec)["scopes"].items():
+            for pas, rows in by.items():
+                for name, shape in rows:
+                    had = where.setdefault((name, shape), (scope, pas))
+                    if had != (scope, pas):
+                        where[(name, shape)] = (AMBIGUOUS, NO_PASS)
+    seconds: Dict[str, Dict[str, float]] = {}
+    by_op: Dict[str, Dict[Tuple[str, str], float]] = {}
+    busy, chips = 0.0, 0
+    for chip_ops in ops.values():
+        if not chip_ops:
+            continue
+        chips += 1
+        busy += _profiler.union_length(
+            [(o[2], o[2] + o[3]) for o in chip_ops])
+        for name, text, _, dur in _leaves(chip_ops):
+            key = (name, _shape_in(text))
+            scope, pas = where.get(key, (UNKNOWN, NO_PASS))
+            by = seconds.setdefault(scope, {})
+            by[pas] = by.get(pas, 0.0) + dur
+            top = by_op.setdefault(scope, {})
+            top[key] = top.get(key, 0.0) + dur
+    n = max(chips, 1)
+    seconds = {scope: {pas: s / n for pas, s in by.items()}
+               for scope, by in seconds.items()}
+    return {
+        "chips": chips, "busy_s": busy / n, "seconds": seconds,
+        "filed_s": sum(s for scope, by in seconds.items()
+                       if scope not in (UNKNOWN, AMBIGUOUS)
+                       for s in by.values()),
+        "longest": {scope: [[k[0], k[1], s / n] for k, s in
+                            sorted(top.items(), key=lambda kv: -kv[1])[:3]]
+                    for scope, top in by_op.items()}}

@@ -10,6 +10,7 @@ records comparable across bench runs:
   python tools/dump_metrics.py diff  <a.jsonl> <b.jsonl>
   python tools/dump_metrics.py to-perfetto <trace.jsonl> <out.json>
   python tools/dump_metrics.py timeline <trace.jsonl>
+  python tools/dump_metrics.py scopes <trace_dir> <trace.jsonl> [--steps-from lm.step.device]
 
 ``show`` prints the chosen record (default: last) as a monitor table
 (count / mean / p50 / p90 / p99 / max) plus the shard stats. ``diff``
@@ -25,6 +26,14 @@ prints the device's timeline as the host knew it
 starved (no program in flight), those seconds by the host span that was
 open meanwhile, the five longest runs with their ``request``, and what
 each ``we.fused`` / ``we.blocks`` call handed its table writes.
+``scopes`` joins a ``jax.profiler`` trace (the directory given to
+``start_trace``) with the ``xla.program`` records of the same run's span
+file (``telemetry/devstats.scope_seconds``): the device's busy time by
+``mv.*`` scope and pass (forward, made again under ``jax.checkpoint``,
+backward), what the programs' maps could not place, each scope's longest
+instructions and what each program reserves of the device. With
+``--steps-from`` the times are a step: the window's total over the
+number of device spans of that name recorded under the profiler.
 
 Both commands also accept the cluster aggregator's time series
 (``cluster.jsonl``, records with ``kind: "cluster"`` — see
@@ -684,6 +693,83 @@ def format_timeline(events: List[Dict]) -> str:
                      + _attention_lines(events) + _mixer_lines(events))
 
 
+def window_ops(trace_dir: str) -> Dict[str, List[tuple]]:
+    """A trace's device operations by chip as ``(name, text, start_s,
+    dur_s)``, clipped to the ``bench.window`` span where the trace has
+    one (as ``benchmark/trace_reduce.reduce`` clips them)."""
+    from benchmark import trace_reduce
+
+    device_ops, host_spans = trace_reduce.read_xplane(
+        trace_reduce.find_xplane(trace_dir))
+    windows = [s for s in host_spans if s.name == trace_reduce.WINDOW_SPAN]
+    lo = min((s.start for s in windows), default=float("-inf"))
+    hi = max((s.start + s.dur for s in windows), default=float("inf"))
+    return {chip: [(o.name, o.text, max(o.start, lo),
+                    min(o.start + o.dur, hi) - max(o.start, lo))
+                   for o in ops if min(o.start + o.dur, hi) > max(o.start, lo)]
+            for chip, ops in device_ops.items()}
+
+
+def format_scopes(ops: Dict[str, List[tuple]], events: List[Dict],
+                  steps_from: Optional[str] = None) -> str:
+    """A traced window's device time by scope and pass, for an operator:
+    which layer of the program the device spent its time in."""
+    from multiverso_tpu.telemetry import devstats
+
+    records = [e for e in events if e.get("name") == devstats.PROGRAM_SPAN]
+    if not records:
+        return ("no xla.program record in the span file: the program "
+                "described none (docs/OBSERVABILITY.md, \"Reading a "
+                "trace by scope\")")
+    got = devstats.scope_seconds(ops, records)
+    steps = sum(1 for e in events if e.get("name") == steps_from
+                and e.get("cat") == "device" and e.get("prof"))
+    if steps_from and not steps:
+        return f"no {steps_from} device span recorded under the profiler"
+    steps, per = max(steps, 1), "a step" if steps_from else "the window"
+    busy = got["busy_s"]
+    ms = lambda s: 1e3 * s / steps
+    share = lambda s: 100.0 * s / busy if busy else 0.0
+    out = [f"device time by scope, ms {per} ({steps} step(s), "
+           f"{got['chips']} chip(s), busy {ms(busy):.3f} ms):",
+           f"  {'scope':28s}" + "".join(f"{p:>11s}" for p in devstats.PASSES)
+           + f"{'sum':>11s}{'% busy':>9s}"]
+    rows = sorted(got["seconds"].items(), key=lambda kv: -sum(kv[1].values()))
+    for scope, by in rows:
+        total = sum(by.values())
+        out.append(f"  {scope:28s}" + "".join(
+            f"{ms(by.get(p, 0.0)):11.3f}" for p in devstats.PASSES)
+            + f"{ms(total):11.3f}{share(total):9.2f}")
+    total = sum(sum(by.values()) for by in got["seconds"].values())
+    out.append(f"  {'(sum of the rows)':28s}{'':33s}{ms(total):11.3f}"
+               f"{share(total):9.2f}")
+    apart = {k: sum(got["seconds"].get(k, {}).values())
+             for k in (devstats.UNSCOPED, devstats.UNKNOWN,
+                       devstats.AMBIGUOUS)}
+    out.append(
+        f"  coverage: {share(got['filed_s']):.2f}% of busy filed under a "
+        f"scope or {devstats.UNSCOPED} ({share(apart[devstats.UNSCOPED]):.2f}"
+        f"%); {devstats.UNKNOWN} {share(apart[devstats.UNKNOWN]):.2f}%, "
+        f"{devstats.AMBIGUOUS} {share(apart[devstats.AMBIGUOUS]):.2f}%")
+    out.append("  longest instructions by scope (ms " + per + "):")
+    for scope, _ in rows:
+        out.append(f"    {scope}: " + "; ".join(
+            f"{name} {shape} {ms(s):.3f}"
+            for name, shape, s in got["longest"][scope]))
+    out.append("  programs (GB: arguments, results, aliased, temporaries, "
+               "code; instructions, scoped; what describing cost):")
+    for e in records:
+        a = e["args"]
+        out.append(
+            f"    {a['program']} ({a['module']}): "
+            + " ".join(f"{a[k] / 1e9:.3f}" for k in (
+                "argument_bytes", "output_bytes", "alias_bytes",
+                "temp_bytes", "code_bytes"))
+            + f"; {a['instructions']} {a['scoped']}; "
+              f"{e['dur'] * 1e-3:.1f} ms, recompiled {a['recompiled']}")
+    return "\n".join(out)
+
+
 def _table_write_lines(events: List[Dict]) -> List[str]:
     """What the trainers' calls handed their table writes, from the
     counts on ``we.fused`` and ``we.blocks`` (``ops/row_combine``): the
@@ -802,6 +888,15 @@ def main(argv: List[str]) -> int:
         return 0
     if cmd == "timeline":
         print(format_timeline(load_records(rest[0])))
+        return 0
+    if cmd == "scopes":
+        steps_from = None
+        if "--steps-from" in rest:
+            i = rest.index("--steps-from")
+            steps_from = rest[i + 1]
+            rest = rest[:i] + rest[i + 2:]
+        print(format_scopes(window_ops(rest[0]), load_records(rest[1]),
+                            steps_from))
         return 0
     print(__doc__)
     return 2
