@@ -719,6 +719,20 @@ def grouped_kernels(compiled_text: str) -> Dict[str, int]:
             for name in ("gmm", "tgmm")}
 
 
+def buffer_loops(compiled_text: str) -> Dict[str, int]:
+    """The ``while`` loops of a compiled program's text under the scopes
+    of the sorted buffer's two passes (``parallel/moe._walk``: a pass
+    that walks the rows past an even load in chunks is a loop, one over
+    the whole buffer is none). Forward, made again and backward: 3 of
+    the dispatch and 2 of the combine in a rematerialised block, whose
+    second forward pass needs no sums; 3 and 3 where a norm reads them
+    (``cfg.post_norms``)."""
+    loops = [line for line in compiled_text.splitlines()
+             if " while(" in line]
+    return {name: sum(f"mv.lm.moe.{name}" in line for line in loops)
+            for name in ("dispatch", "combine")}
+
+
 def _expert_block(dim: int, ffn: int, held: int, rows: int, form: str,
                   experts: int, top_k: int, model: str, kernel: str,
                   repeats: int) -> Dict[str, Any]:
@@ -735,7 +749,12 @@ def _expert_block(dim: int, ffn: int, held: int, rows: int, form: str,
     width and makes the one out of it again, 9 and 3 where the backward
     pass makes them all again; 6 and 2 in ``NemotronHConfig``'s ``relu2``
     block, which keeps nothing, 5 and 2 if it did; none off the chip,
-    where no kernel is compiled)."""
+    where no kernel is compiled), and the loops of the sorted buffer's
+    passes (``block_loops``: :func:`buffer_loops`; 0 would say a pass
+    walks the whole buffer again). ``parallel/moe.CHUNK`` was chosen
+    from ``block_ms`` here, and the STEP is the judge all the same: the
+    block alone misled once (PR 51: a select that cost nothing here cost
+    the step 2 ms a layer, where XLA fused and placed it otherwise)."""
     import jax
     import jax.numpy as jnp
 
@@ -778,9 +797,11 @@ def _expert_block(dim: int, ffn: int, held: int, rows: int, form: str,
     for _ in range(repeats):
         res = compiled(x, p)
     jax.block_until_ready(res)
-    return {"block_tokens": tokens,
-            "block_ms": round((time.perf_counter() - t0) / repeats * 1e3, 3),
-            "block_kernels": grouped_kernels(compiled.as_text())}
+    ms = round((time.perf_counter() - t0) / repeats * 1e3, 3)
+    text = compiled.as_text()
+    return {"block_tokens": tokens, "block_ms": ms,
+            "block_kernels": grouped_kernels(text),
+            "block_loops": buffer_loops(text)}
 
 
 def _expert_products(dim: int, ffn: int, held: int, rows: int, form: str,

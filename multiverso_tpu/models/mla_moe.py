@@ -994,7 +994,12 @@ def routing_counts(counts: np.ndarray, cfg) -> Dict[str, Any]:
     before it asks which expert was busiest) and, of the held experts'
     grouped products, ``product_tiles_visited`` (the row tiles ONE forward
     product visits, summed over the layers: ``moe.product_tiles``) beside
-    ``product_tiles_buffer`` (the row tiles the layers' buffers hold)."""
+    ``product_tiles_buffer`` (the row tiles the layers' buffers hold),
+    and of the passes round them ``buffer_rows_walked`` (the rows ONE
+    gather or scatter over the sorted buffers walks, a layer's even load
+    and whole chunks past it, summed over the layers:
+    ``moe.rows_walked``) beside ``buffer_rows`` (the layers' buffers
+    whole): 1.0 of it would say that the passes never stop short."""
     counts = np.asarray(counts)
     c = counts[:, :cfg.n_experts]
     lo = cfg.expert_offset
@@ -1009,7 +1014,10 @@ def routing_counts(counts: np.ndarray, cfg) -> Dict[str, Any]:
             "load_max_over_mean": float(np.max(c.max(1) / c.mean(1))),
             "expert_rows": c.tolist(),
             "product_tiles_visited": moe.product_tiles(mine, rows, tm),
-            "product_tiles_buffer": len(c) * rows // tm}
+            "product_tiles_buffer": len(c) * rows // tm,
+            "buffer_rows_walked": moe.rows_walked(
+                mine, rows, moe.even_rows(here, tokens)),
+            "buffer_rows": len(c) * rows}
 
 
 class Trainer:

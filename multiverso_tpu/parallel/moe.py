@@ -29,7 +29,11 @@ The rows routed here lie sorted by expert in one buffer, and the three
 products run over that buffer as grouped matrix products (Pallas
 ``megablox``) whose groups end where the routed rows end, so their cost
 follows the rows routed here: not the busiest expert, and not the
-buffer's padding. On one chip it runs without its exchange.
+buffer's padding. The passes round them do as the products do: the
+sort's gather into the buffer and the scatter-add back to the tokens, and
+the transpose of each, walk the rows an even load fills at once and the
+rows past them ``CHUNK`` at a time for as many chunks as hold routed rows
+(:func:`_walk`). On one chip it runs without its exchange.
 """
 
 from __future__ import annotations
@@ -444,6 +448,181 @@ def expert_products(x: jax.Array, params: Dict, groups: jax.Array,
     return down(h.astype(cfg.dtype), params["w_down"])
 
 
+# The rows of the sorted buffer that one trip of the dispatch's and the
+# combine's loops walks (:func:`_walk`), past the head that an even load
+# fills. A trip costs its control and a last chunk is walked whole, and
+# XLA's scatter of a chunk inside a loop costs four times a slot what its
+# scatter of a whole buffer does (it sorts a large scatter's slots and not
+# a small one's), so the chunks are for the few rows past the head:
+# ``chip_smoke.py``'s ``_expert_block`` at the five cells' shapes chose it
+# (PERF.md section 6, PR 53).
+CHUNK = 1024
+
+
+def even_rows(cfg: HeldExperts, tokens: int) -> int:
+    """The rows an even router sends the held experts for ``tokens``."""
+    return tokens * cfg.top_k * cfg.experts_held // cfg.num_experts
+
+
+def rows_walked(sizes, rows: int, head: int) -> int:
+    """The rows of a buffer of ``rows`` that ONE pass of :func:`_walk`
+    walks for held experts' loads ``sizes`` [..., H], summed over the
+    leading axes, on the host: the ``head``, and whole chunks from there
+    to the last routed row (the load cut at the buffer, as the layer
+    cuts its groups)."""
+    head = min(head, rows)
+    chunk = min(CHUNK, max(rows - head, 1))
+    past = np.maximum(
+        np.minimum(np.asarray(sizes, np.int64).sum(-1), rows) - head, 0)
+    return int((head + -(-past // chunk) * chunk).sum())
+
+
+def _walk(take, held_rows, head: int, carry, step):
+    """``step`` over the sorted buffer as far as routed rows lie: over
+    its first ``head`` rows at once, routed or not (the even load, which
+    a balanced router fills: XLA's own gather and scatter at the size
+    they do best), then over what lies past them ``CHUNK`` rows at a time for
+    ``ceil((held_rows - head) / chunk)`` trips of a ``lax.fori_loop``,
+    counted on the device: none under an even load, and never a chunk
+    past the last routed row. ``step(carry, start, idx, live, fresh)``
+    gives the next ``carry`` from the first row of a stretch, its part
+    ``idx`` of ``take`` and ``live``, which marks its rows under
+    ``held_rows``. Where the rows past the head are no multiple of the
+    chunk the last chunk starts early (``dynamic_slice`` would clamp it
+    there anyway) and its first rows are the chunk before's: ``fresh``
+    marks the live rows that no earlier stretch has walked, for a step
+    that ADDS."""
+    rows = take.shape[0]
+    head = min(head, rows)
+    if head:
+        live = jnp.arange(head) < held_rows
+        carry = step(carry, 0, take[:head], live, live)
+    if head == rows:
+        return carry
+    chunk = min(CHUNK, rows - head)
+
+    def trip(i, carry):
+        first = head + i * chunk
+        start = jnp.minimum(first, rows - chunk)
+        at = start + jnp.arange(chunk)
+        live = at < held_rows
+        return step(carry, start,
+                    jax.lax.dynamic_slice_in_dim(take, start, chunk),
+                    live, live & (at >= first))
+
+    return jax.lax.fori_loop(
+        0, (jnp.maximum(held_rows - head, 0) + chunk - 1) // chunk, trip,
+        carry)
+
+
+_cut = jax.lax.dynamic_slice_in_dim               # (array, start, rows)
+_put = functools.partial(jax.lax.dynamic_update_slice_in_dim, axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch(src, gates, take, held_rows, grid: Tuple[int, int, int]):
+    """The sort's gather into the buffer: ``(src[take // top_k],
+    gates[take])`` for the first ``held_rows`` of ``take`` [rows], zeros
+    past them. ``grid`` is (tokens, top_k, the walk's head), ``src``
+    [tokens, D], ``gates`` the tokens x top_k assignments' FLAT, ``take``
+    indices into them. Both ways walk the routed rows alone
+    (:func:`_walk`): the transpose adds the rows' cotangents to their
+    tokens, in ``src``'s type, and the gates' to their assignments, and
+    reads no row past the last chunk (what a product's gradient left
+    there is UNWRITTEN)."""
+    return _dispatch_fwd(src, gates, take, held_rows, grid)[0]
+
+
+# The four rules are ``jax.jit`` functions so that a step's expert layers
+# share one trace of each: traced a layer at a time they added 3.8 s of
+# Python to a cell's set-up (``xla.lower_s.setup``; XLA inlines the calls).
+@functools.partial(jax.jit, static_argnums=(4,))
+def _dispatch_fwd(src, gates, take, held_rows, grid):
+    _, top_k, head = grid
+
+    def step(carry, start, idx, live, _):
+        x, gate = carry
+        return (_put(x, jnp.where(live[:, None], src[idx // top_k], 0),
+                     start),
+                _put(gate, jnp.where(live, gates[idx], 0.0), start))
+
+    rows = take.shape[0]
+    buffers = (jnp.zeros((rows, src.shape[1]), src.dtype),
+               jnp.zeros((rows,), gates.dtype))
+    return _walk(take, held_rows, head, buffers, step), (take, held_rows)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _dispatch_bwd(grid, res, cts):
+    (tokens, top_k, head), (dx, dgate) = grid, cts
+
+    def step(carry, start, idx, _, fresh):
+        d_src, d_gates = carry
+        n = idx.shape[0]
+        return (d_src.at[idx // top_k].add(
+                    jnp.where(fresh[:, None], _cut(dx, start, n), 0)),
+                d_gates.at[idx].add(
+                    jnp.where(fresh, _cut(dgate, start, n), 0.0)))
+
+    sums = (jnp.zeros((tokens, dx.shape[1]), dx.dtype),
+            jnp.zeros((tokens * top_k,), dgate.dtype))
+    return _walk(*res, head, sums, step) + (None, None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine(y, gate, take, held_rows, grid: Tuple[int, int, int]):
+    """The scatter-add back to the tokens: float32 [tokens, D] sums of
+    ``gate[r] * y[r]`` at token ``take[r] // top_k`` over the first
+    ``held_rows`` rows ``r`` of the buffer (``grid`` as
+    :func:`_dispatch`'s); ``y`` past them is UNWRITTEN and reaches
+    nothing. Both ways walk the routed rows alone (:func:`_walk`): the
+    transpose gathers the sums' cotangent at their tokens and leaves
+    ``y``'s and ``gate``'s zero past the last chunk."""
+    return _combine_fwd(y, gate, take, held_rows, grid)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _combine_fwd(y, gate, take, held_rows, grid):
+    tokens, top_k, head = grid
+
+    def step(out, start, idx, _, fresh):
+        n = idx.shape[0]
+        return out.at[idx // top_k].add(
+            jnp.where(fresh[:, None],
+                      _cut(y, start, n).astype(jnp.float32), 0.0)
+            * _cut(gate, start, n)[:, None])
+
+    out = _walk(take, held_rows, head,
+                jnp.zeros((tokens, y.shape[1]), jnp.float32), step)
+    return out, (y, gate, take, held_rows)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _combine_bwd(grid, res, g):
+    y, gate, take, held_rows = res
+    _, top_k, head = grid
+
+    def step(carry, start, idx, live, _):
+        dy, dgate = carry
+        n, at = idx.shape[0], g[idx // top_k]
+        scale = _cut(gate, start, n)[:, None]
+        rows = _cut(y, start, n).astype(jnp.float32)
+        return (_put(dy, jnp.where(live[:, None], at * scale,
+                                   0.0).astype(y.dtype), start),
+                _put(dgate, jnp.where(live, (at * rows).sum(-1), 0.0),
+                     start))
+
+    return _walk(take, held_rows, head,
+                 (jnp.zeros_like(y), jnp.zeros_like(gate)),
+                 step) + (None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
                       cfg: HeldExperts, kernel: Optional[str] = None):
     """The routed part of an expert layer that this chip computes.
@@ -460,20 +639,24 @@ def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
     experts would add is left out. The rows routed here are sorted by
     expert into a buffer of ``buffer_rows`` rows; the padding after them
     belongs to no expert's group, so the products visit the row tiles
-    that hold routed rows and their time follows ``held_rows``, not the
-    buffer. Past ``held_rows`` the products' results (``up``'s, the
-    hidden rows ``h``, ``down``'s ``y``) and their gradients are
-    UNWRITTEN memory: ``x`` and ``y`` are SELECTED by ``live`` on their
-    way in and out (a product with zero would keep a NaN), and nothing
-    else may reduce over those rows; a backward pass that kept the
-    products' results (``KEPT_NAMES``) reads there what the forward
-    kernels left. ``overflow_rows`` counts rows
+    that hold routed rows, and the passes round them (:func:`_dispatch`
+    into the buffer, :func:`_combine` back to the tokens, and the
+    transpose of each) walk an even load's rows and the chunks past them
+    that hold routed rows (:func:`_walk`): the time of both follows
+    ``held_rows``, not the buffer. Past ``held_rows``
+    the products' results (``up``'s, the hidden rows ``h``, ``down``'s
+    ``y``) and their gradients are UNWRITTEN memory: the two passes
+    SELECT a chunk's rows by ``live`` on their way in and out (a product
+    with zero would keep a NaN) and read no chunk past the last routed
+    row, and nothing else may reduce over those rows; a backward pass
+    that kept the products' results (``KEPT_NAMES``) reads there what
+    the forward kernels left. ``overflow_rows`` counts rows
     that did not fit the buffer and were left out: 0 unless the buffer
     was sized under the load (it cannot be with ``buffer_rows=None``).
     ``kernel``: ``"pallas"`` (the chip's default), ``"interpret"`` (the
     same kernel in Pallas's interpreter) or ``"xla"`` (``ragged_dot``, the
     default off the chip)."""
-    t, d = u.shape
+    t = u.shape[0]
     held, k = cfg.experts_held, cfg.top_k
     if kernel is None:
         kernel = "pallas" if jax.devices()[0].platform == "tpu" else "xla"
@@ -500,14 +683,11 @@ def held_expert_layer(u: jax.Array, params: Dict, bias: jax.Array,
         overflow = sizes.sum() - held_rows
         # the padding rows are in no group: the grid ends with the rows
         groups = jnp.diff(ends, prepend=0)
-        live = jnp.arange(rows) < held_rows
-        token = take // k
-        x = jnp.where(live[:, None], u.astype(cfg.dtype)[token], 0)
-        gate = jnp.where(live, gates.reshape(-1)[take], 0.0)
+        grid = (t, k, even_rows(cfg, t))
+        x, gate = _dispatch(u.astype(cfg.dtype), gates.reshape(-1), take,
+                            held_rows, grid)
     with jax.named_scope("mv.lm.moe.experts"):
         y = expert_products(x, params, groups, cfg, kernel)
     with jax.named_scope("mv.lm.moe.combine"):
-        out = jnp.zeros((t, d), jnp.float32).at[token].add(
-            jnp.where(live[:, None], y.astype(jnp.float32), 0.0)
-            * gate[:, None])
+        out = _combine(y, gate, take, held_rows, grid)
     return out, counts, overflow, balance

@@ -120,6 +120,10 @@ def test_lm_stage():
         # its kernels is the chip's: the interpreter compiles none)
         assert call["block_tokens"] == 256 and call["block_ms"] > 0
         assert call["block_kernels"] == {"gmm": 0, "tgmm": 0}
+        # the sorted buffer's passes walk chunks: forward, made again and
+        # backward, the combine's sums made again where a norm reads them
+        assert call["block_loops"]["dispatch"] == 3
+        assert call["block_loops"]["combine"] in (2, 3)
     # the chunked loss alone, four chunks and one
     assert set(facts["heads"]) == {"chunks", "whole"}
     for call in facts["heads"].values():

@@ -158,6 +158,33 @@ def test_the_gradients_are_a_bare_checkpoints_bit_for_bit(form, route,
 
 @cases
 @forms
+@pytest.mark.parametrize("chunk", [100, 128])
+def test_the_chunked_passes_under_a_checkpoint_keep_every_bit(
+        form, route, chunk, monkeypatch):
+    """The dispatch's and the combine's loops (``moe._walk``), four trips
+    of 100 rows over a buffer of 384 that is no multiple of them, or
+    three of 128: made again under the block's checkpoint beside the kept
+    row order, they give the unrematerialised block's gradients."""
+    monkeypatch.setattr(moe, "CHUNK", chunk)
+    cfg, x, p, weight = _block(form, route, "xla")
+    # every token to the four held experts: 384 rows, the buffer's all
+    p = dict(p, router=p["router"].at[4:8].add(12.0))
+    x = x + 1.0
+    _, (counts, _, _) = mla_moe._run_block(
+        x, p, LAYER, jnp.zeros((cfg.n_experts,)), cfg, remat=False)
+    assert int(counts[4:8].sum()) == ROWS
+    got, still, bare = (
+        jax.jit(jax.value_and_grad(_loss(c, weight, remat), (0, 1)))(x, p)
+        for c, remat in ((cfg, True), (cfg, False), (_bare(cfg), True)))
+    assert all(np.isfinite(np.asarray(a)).all() for a in jax.tree.leaves(got))
+    assert float(jnp.abs(got[1][1]["ed"]).max()) > 0
+    for other in (still, bare):
+        for a, b in zip(_bits(got), _bits(other)):
+            np.testing.assert_array_equal(a, b)
+
+
+@cases
+@forms
 def test_poisoned_rows_in_the_kept_results_reach_no_gradient(form, route,
                                                              monkeypatch):
     """The rows past ``held_rows`` of a kept result are what the forward

@@ -278,6 +278,15 @@ def test_trainer_records_its_steps_program_exactly_once():
             "mv.rowapply.rule", "mv.lm.norm.pre", "mv.lm.params",
             "mv.lm.update", "mv.lm.embed"} <= set(a["scopes"])
     assert {"fwd", "remat", "bwd"} == set(a["scopes"]["mv.lm.attn"])
+    # the sorted buffer's passes have derivative rules of their own
+    # (``parallel/moe._dispatch``, ``_combine``): a rule's instructions
+    # are filed where its call stands, its loops' bodies among them
+    for scope, passes in (("mv.lm.moe.dispatch", {"fwd", "remat", "bwd"}),
+                          ("mv.lm.moe.combine", {"fwd", "bwd"})):
+        assert passes <= set(a["scopes"][scope])
+        assert all(any(name.startswith("while") for name, _ in rows)
+                   for pas, rows in a["scopes"][scope].items()
+                   if pas in passes)
     assert set(a["scopes"]["mv.rowapply.rule"]) == {"fwd"}
     assert 0.5 < a["scoped"] / a["instructions"] <= 1.0
     # recorded inside the first step's span, before anything of a window

@@ -794,6 +794,17 @@ def test_lm_step_carries_its_counts():
                                                 + 64 * cfg.top_k * 4))
     assert want["routed_rows"] == layers * 64 * cfg.top_k
     assert 0 <= want["held_rows"] <= want["routed_rows"]
+    # the rows the sorted buffers' passes walk (a layer's head of 64, the
+    # even load, and where more are routed here the 192 past it as one
+    # chunk) beside the rows the buffers hold
+    assert step["args"]["buffer_rows"] == layers * 256
+    assert step["args"]["buffer_rows_walked"] in range(
+        layers * 64, layers * 256 + 1, 192)
+    from tools import dump_metrics
+    assert dump_metrics._buffer_lines(events)[1].split()[-3:] == [
+        str(step["args"]["buffer_rows_walked"]), str(layers * 256),
+        f"{step['args']['buffer_rows_walked'] / (layers * 256):.3f}"]
+    assert dump_metrics._buffer_lines([{"name": "lm.step", "args": {}}]) == []
     assert want["overflow_rows"] == 0 and want["load_max_over_mean"] >= 1.0
 
 

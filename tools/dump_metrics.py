@@ -690,7 +690,8 @@ def format_timeline(events: List[Dict]) -> str:
     out += [f"    {r['run_ms']:12.3f} ms  {r['name']}  request={r['request']}"
             for r in runs[:5]]
     return "\n".join(out + _table_write_lines(events)
-                     + _attention_lines(events) + _mixer_lines(events))
+                     + _attention_lines(events) + _mixer_lines(events)
+                     + _buffer_lines(events))
 
 
 def window_ops(trace_dir: str) -> Dict[str, List[tuple]]:
@@ -836,6 +837,28 @@ def _mixer_lines(events: List[Dict]) -> List[str]:
             f"taps, {mixers} of {step} forward operations a token = "
             f"{100.0 * mixers / step:.2f}%"
             + ("; tied head" if args.get("tied_head") else ""))
+    return out
+
+
+def _buffer_lines(events: List[Dict]) -> List[str]:
+    """How much of the held experts' sorted buffers the steps of a span
+    file ran, summed over the ``lm.step`` spans that say it
+    (``models/mla_moe.routing_counts``): the rows a gather or scatter
+    over them walked and the row tiles a grouped product visited, beside
+    what the buffers hold. A share of 1.00 says nothing stops short."""
+    steps = [e["args"] for e in events if e.get("name") == "lm.step"
+             and "buffer_rows_walked" in e.get("args", {})]
+    if not steps:
+        return []
+    total = lambda key: sum(a[key] for a in steps)      # noqa: E731
+    out = [f"  sorted buffers over {len(steps)} steps (ran, held, ran / "
+           "held):"]
+    for what, ran, held in (
+            ("rows a pass walks", "buffer_rows_walked", "buffer_rows"),
+            ("tiles a product visits", "product_tiles_visited",
+             "product_tiles_buffer")):
+        out.append(f"    {what:22s}  {total(ran)}  {total(held)}  "
+                   f"{total(ran) / max(total(held), 1):.3f}")
     return out
 
 
