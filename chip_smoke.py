@@ -43,7 +43,7 @@ import sys
 import tempfile
 import time
 import traceback
-from typing import Any, Callable, Dict, List, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 from unittest import mock
 
 import numpy as np
@@ -1035,7 +1035,8 @@ FLASH_CALLS = (
 
 
 def stage_flash(calls: Tuple = FLASH_CALLS, subs: Tuple = (),
-                repeats: int = 10, ref_heads: int = 2) -> Dict[str, Any]:
+                repeats: int = 10, ref_heads: int = 2,
+                selected: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
     """The flash kernels' crossed pairs, whole-tile against sub-tiled
     (``ops/attention_kernels.sub_tile``'s choice for the call, or each of
     ``subs``): for the forward with its residual, dQ, and dK with dV, the
@@ -1043,7 +1044,10 @@ def stage_flash(calls: Tuple = FLASH_CALLS, subs: Tuple = (),
     around ``repeats`` calls it waits for; the sub-tiled results against
     the whole-tile ones on the same inputs (norm of the difference over
     the norm), and both against float32 attention on ``ref_heads`` query
-    heads of the call (max|err| over max|reference|, as ``stage_lm``)."""
+    heads of the call (max|err| over max|reference|, as ``stage_lm``).
+    Last, where ``selected`` is given (the run of all stages gives ``{}``),
+    the kernels under a selection (:func:`flash_selected` with
+    ``selected``'s arguments)."""
     import jax
     import jax.numpy as jnp
 
@@ -1129,6 +1133,9 @@ def stage_flash(calls: Tuple = FLASH_CALLS, subs: Tuple = (),
                     f"{ATTN_BF16_TOL}")
             facts[f"{tag}_rel_err"] = [round(e, 5) for e in errs]
         out[name] = facts
+    if selected is not None:
+        # keye-train-16k's: the same kernels with one more operand
+        out["keye.selected"] = flash_selected(**selected)
     return out
 
 
@@ -1288,6 +1295,276 @@ def stage_conv(sequences: int = 2, positions: int = 8192, dim: int = 2048,
     return facts
 
 
+def _timed(fn, args, repeats: int) -> Tuple[float, float, Any]:
+    """(compile seconds, ms a call by this process's clock around
+    ``repeats`` calls it waits for, the last result) of ``jit(fn)``."""
+    import jax
+
+    t0 = time.perf_counter()
+    compiled = jax.jit(fn).lower(*args).compile()
+    compile_s = time.perf_counter() - t0
+    res = jax.block_until_ready(compiled(*args))
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        res = compiled(*args)
+    jax.block_until_ready(res)
+    return (round(compile_s, 2),
+            round((time.perf_counter() - t0) / repeats * 1e3, 3), res)
+
+
+def _keye_layer(positions: int, dim: int, heads: int, kv_heads: int,
+                head_dim: int, index_heads: int, index_dim: int, topk: int,
+                chunk: int, dtype=None):
+    """``keye-train-16k``'s configuration of one layer, its indexer's
+    parameters at the configuration file's scales and a normed input of
+    one sequence."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import keye_moe
+
+    cfg = keye_moe.KeyeMoEConfig(
+        dim=dim, n_heads=heads, n_kv_heads=kv_heads, head_dim=head_dim,
+        layer_kinds=("sparse",), index_heads=index_heads,
+        index_dim=index_dim, index_topk=topk, index_chunk=chunk,
+        compute_dtype=dtype or jnp.bfloat16)
+    k = jax.random.split(jax.random.key(SEED), 6)
+    p = {"wq_i": 0.03 * jax.random.normal(k[0], (dim, index_heads * index_dim)),
+         "wk_i": 0.03 * jax.random.normal(k[1], (dim, index_dim)),
+         "ww_i": 0.03 * jax.random.normal(k[2], (dim, index_heads)),
+         "k_i_norm": jnp.ones((index_dim,)), "k_i_bias": jnp.zeros((index_dim,))}
+    u = jax.random.normal(k[3], (1, positions, dim))
+    u = u * jax.lax.rsqrt(jnp.mean(u * u, -1, keepdims=True))
+    return cfg, p, u, k[4:]
+
+
+def stage_select(positions: int = 16384, dim: int = 2048,
+                 index_heads: int = 16, index_dim: int = 64,
+                 topk: int = 2048, chunk: int = 512, top_k_chunks: int = 2,
+                 repeats: int = 3) -> Dict[str, Any]:
+    """``models/keye_moe``'s indexer and selection as ``keye-train-16k``
+    calls them, one sequence of ``positions`` rows over ``positions`` keys
+    in chunks of ``chunk`` rows: ms for the indexer's three products, for
+    the whole selection by the counting search (scores and thresholds, 32
+    passes over a chunk's integer keys) and, on the LAST ``top_k_chunks``
+    chunks alone, ms a chunk by the search and by ``lax.top_k`` with a
+    scatter of its indices, whose sets must be equal; a row's spread of
+    scores; and the share of the selected keys that bfloat16 operands
+    decide otherwise than float32 ones at the highest precision (what the
+    cell's comparison has to allow)."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import keye_moe
+
+    cfg, p, u, _ = _keye_layer(positions, dim, 4, 2, 8, index_heads,
+                               index_dim, topk, chunk)
+    facts: Dict[str, Any] = {}
+    operands = lambda u, p: keye_moe.index_operands(u, p, cfg)
+    facts["operands_compile_s"], facts["operands_ms"], (qi, ki, w) = _timed(
+        operands, (u, p), repeats)
+    whole = lambda qi, ki, w: keye_moe.selection(qi, ki, w, cfg)
+    facts["select_compile_s"], facts["select_ms"], chosen = _timed(
+        whole, (qi, ki, w), repeats)
+    rows = min(chunk, positions)
+    first = positions - top_k_chunks * rows
+    tail = (qi[:, first:], ki, w[:, first:])
+
+    def scores_of(qi_t, ki, w_t, dtype=cfg.compute_dtype):
+        return jax.lax.map(
+            lambda c: keye_moe._scores(c[0], ki, c[1], dtype),
+            (keye_moe._chunks(qi_t, rows), keye_moe._chunks(w_t, rows)))
+
+    def by_search(qi_t, ki, w_t):
+        return jax.lax.map(
+            lambda c: keye_moe.select(c[1], cfg, first + c[0] * rows),
+            (jnp.arange(top_k_chunks), scores_of(qi_t, ki, w_t)))
+
+    def by_top_k(qi_t, ki, w_t):
+        def one(c):
+            n, x = c
+            t = first + n * rows + jnp.arange(rows)
+            causal = jnp.arange(positions)[None, :] <= t[:, None]
+            x = jnp.where(causal, jnp.where(x == 0, 0.0, x), -jnp.inf)
+            _, at = jax.lax.top_k(x, min(topk, positions))
+            mine = jnp.zeros(x.shape, bool).at[
+                0, jnp.arange(rows)[:, None], at[0]].set(True)
+            return (mine & causal).astype(jnp.int8)
+        return jax.lax.map(one, (jnp.arange(top_k_chunks),
+                                 scores_of(qi_t, ki, w_t)))
+
+    _, both_ms, scored = _timed(scores_of, tail, repeats)
+    facts["search_compile_s"], ms, got = _timed(by_search, tail, repeats)
+    facts["search_ms_chunk"] = round((ms - both_ms) / top_k_chunks, 3)
+    facts["top_k_compile_s"], ms, want = _timed(by_top_k, tail, repeats)
+    facts["top_k_ms_chunk"] = round((ms - both_ms) / top_k_chunks, 3)
+    facts["scores_ms_chunk"] = round(both_ms / top_k_chunks, 3)
+    if not bool(jnp.all(got == want)):
+        raise AssertionError(
+            f"select: {int(jnp.sum(got != want))} keys of the search's set "
+            f"are not lax.top_k's")
+    if not bool(jnp.all(got.reshape(-1, positions)
+                        == chosen[0, first:])):
+        raise AssertionError("select: a chunk alone selects otherwise "
+                             "than the whole sequence's map")
+    last = scored[-1, 0, -1]
+    facts["row_spread"] = round(float(jnp.std(last)), 4)
+    kept = int(jnp.sum(got))
+    facts["selected_keys_tail"] = kept
+    with jax.default_matmul_precision("highest"):
+        exact = jax.jit(lambda qi_t, ki, w_t: jax.lax.map(
+            lambda c: keye_moe.select(c[1], cfg, first + c[0] * rows),
+            (jnp.arange(top_k_chunks),
+             scores_of(qi_t, ki, w_t, jnp.float32))))(*tail)
+    facts["bfloat16_decides_otherwise_share"] = round(
+        float(jnp.sum(got != exact)) / 2 / kept, 5)
+    return facts
+
+
+def flash_selected(shape=(1, 32, 16384, 128), hkv: int = 4,
+                   blocks=((1024, 1024), (512, 1024)), topk: int = 2048,
+                   repeats: int = 10, ref_heads: int = 2) -> Dict[str, Any]:
+    """The flash kernels under a selection as ``keye-train-16k`` calls
+    them (int8 [B, S, S], the ``topk`` largest of seeded scores a causal
+    row, shared by the heads), at each of ``blocks``: compile seconds and
+    ms a call of the forward with its residual, dQ, and dK with dV, beside
+    the same call without the operand (whole tiles too); and output and
+    gradients against float32 attention under the same mask on
+    ``ref_heads`` query heads (max|err| over max|reference|)."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import keye_moe
+    from multiverso_tpu.models.mla_moe import _xla_attention
+    from multiverso_tpu.ops import attention_kernels as ak
+
+    interpret = ak._resolve_interpret(None)
+    b, h, s, d = shape
+    rng = np.random.default_rng(SEED + 54)
+    q, g = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=(b, hkv, s, d)), jnp.bfloat16)
+            for _ in range(2))
+    cfg = keye_moe.KeyeMoEConfig(index_topk=topk, index_chunk=min(512, s))
+    rows = min(512, s)
+    chosen = jax.jit(lambda key: jnp.moveaxis(jax.lax.map(
+        lambda n: keye_moe.select(jax.random.normal(
+            jax.random.fold_in(key, n), (b, rows, s)), cfg, n * rows),
+        jnp.arange(s // rows)), 0, 1).reshape(b, s, s))(jax.random.key(SEED))
+    facts: Dict[str, Any] = {
+        "selected_share": round(float(jnp.mean(chosen.astype(jnp.float32)))
+                                * 2 * s / (s + 1), 4)}
+    few = lambda t, n: t[:1, :n]
+    small = (few(q, ref_heads), few(k, 1), few(v, 1))
+    gs = few(g, ref_heads)
+
+    def fwd_and_grads(fn, *args):
+        res, vjp = jax.vjp(fn, *args)
+        return (res,) + vjp(gs.astype(res.dtype))
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda *a: fwd_and_grads(
+            lambda q, k, v: _xla_attention(q, k, v, None, chosen[:1]),
+            *(t.astype(jnp.float32) for t in a)))(*small)
+    for bq, bk in blocks:
+        for tag, select in ((f"{bq}x{bk}_select", chosen),
+                            (f"{bq}x{bk}_causal", None)):
+            def forward(q, k, v):
+                return ak._flash_forward(q, k, v, True, bq, bk, interpret,
+                                         True, None, None, select)
+
+            def backward(keep, q, k, v, o, lse, g):
+                grads = ak._flash_backward(q, k, v, o, lse, g, True, bq, bk,
+                                           interpret, None, None, select)
+                return grads[:1] if keep == 0 else grads[1:]
+
+            o, lse = forward(q, k, v)
+            for kernel, fn, args in (
+                    ("fwd", forward, (q, k, v)),
+                    ("dq", functools.partial(backward, 0),
+                     (q, k, v, o, lse, g)),
+                    ("dkv", functools.partial(backward, 1),
+                     (q, k, v, o, lse, g))):
+                (facts[f"{tag}_{kernel}_compile_s"],
+                 facts[f"{tag}_{kernel}_ms"], _) = _timed(fn, args, repeats)
+        got = jax.jit(lambda *a: fwd_and_grads(
+            lambda q, k, v: ak.flash_attention(
+                q, k, v, True, bq, bk, select=chosen[:1]), *a))(*small)
+        errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32) - w))
+                      / jnp.max(jnp.abs(w))) for a, w in zip(got, want)]
+        if not max(errs) <= ATTN_BF16_TOL:      # a NaN fails too
+            raise AssertionError(f"flash under a selection at {bq} x {bk}: "
+                                 f"relative error {errs} > {ATTN_BF16_TOL}")
+        facts[f"{bq}x{bk}_rel_err"] = [round(e, 5) for e in errs]
+    return facts
+
+
+def stage_target(positions: int = 16384, dim: int = 2048, heads: int = 32,
+                 kv_heads: int = 4, head_dim: int = 128,
+                 index_heads: int = 16, index_dim: int = 64,
+                 topk: int = 2048, chunk: int = 512, repeats: int = 3,
+                 check_positions: int = 512) -> Dict[str, Any]:
+    """``models/keye_moe.index_loss`` as ``keye-train-16k`` calls it, one
+    layer's term over one sequence: compile seconds and ms a call forward
+    and with the gradients to the indexer's operands (made in its
+    forward); and on the first ``check_positions`` the term and those
+    gradients in float32 against plain autodiff of the definition over
+    whole arrays (max|err| over max|reference|)."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import keye_moe
+
+    cfg, p, u, keys = _keye_layer(positions, dim, heads, kv_heads, head_dim,
+                                  index_heads, index_dim, topk, chunk)
+    q = jax.random.normal(keys[0], (1, heads, positions, head_dim),
+                          jnp.bfloat16)
+    k = jax.random.normal(keys[1], (1, kv_heads, positions, head_dim),
+                          jnp.bfloat16)
+    qi, ki, w = jax.jit(lambda u, p: keye_moe.index_operands(u, p, cfg))(u, p)
+    chosen = jax.jit(lambda *a: keye_moe.selection(*a, cfg))(qi, ki, w)
+    term = lambda qi, ki, w: keye_moe.index_loss(qi, ki, w, q, k, chosen, cfg)
+    facts: Dict[str, Any] = {}
+    facts["fwd_compile_s"], facts["fwd_ms"], value = _timed(
+        term, (qi, ki, w), repeats)
+    facts["fwd_bwd_compile_s"], facts["fwd_bwd_ms"], _ = _timed(
+        jax.value_and_grad(term, (0, 1, 2)), (qi, ki, w), repeats)
+    facts["term"] = round(float(value), 5)
+
+    n = min(check_positions, positions)
+    exact = cfg._replace(compute_dtype=jnp.float32,
+                         index_chunk=min(chunk, n))
+    qs, ks = (t[:, :, :n].astype(jnp.float32) for t in (q, k))
+    few = (qi[:, :n], ki[:, :n], w[:, :n])
+    picked = jax.jit(lambda *a: keye_moe.selection(*a, exact))(*few)
+
+    def definition(qi, ki, w):
+        live = picked != 0
+        index = keye_moe._scores(qi, ki, w, jnp.float32)
+        dots = jnp.einsum("bkgrd,bksd->bkgrs", qs.reshape(
+            1, kv_heads, heads // kv_heads, n, head_dim), ks) / head_dim ** 0.5
+        pbar = jnp.mean(jax.nn.softmax(jnp.where(
+            live[:, None, None], dots, -jnp.inf), -1), (1, 2))
+        logq = jax.nn.log_softmax(jnp.where(live, index, -jnp.inf), -1)
+        return jnp.sum(jnp.where(live, jax.scipy.special.xlogy(pbar, pbar)
+                                 - pbar * jnp.where(live, logq, 0.0),
+                                 0.0)) / n
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.value_and_grad(definition, (0, 1, 2)))(*few)
+        got = jax.jit(jax.value_and_grad(
+            lambda *a: keye_moe.index_loss(*a, qs, ks, picked, exact),
+            (0, 1, 2)))(*few)
+    pairs = [(got[0], want[0])] + list(zip(got[1], want[1]))
+    errs = [float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+            for a, b in pairs]
+    if not max(errs) <= 1e-3:       # float32 on both sides; a NaN fails too
+        raise AssertionError(f"target: relative error {errs} (term, dqI, "
+                             f"dkI, dw) > 1e-3")
+    facts["rel_err_term_dqi_dki_dw"] = [round(e, 6) for e in errs]
+    return facts
+
+
 def stage_memory() -> Dict[str, Any]:
     """After the run every device holds bytes: every chip was used."""
     import jax
@@ -1326,8 +1603,10 @@ def result_line(ok: bool, device: Dict[str, Any]) -> Dict[str, Any]:
 STAGES: Tuple[Tuple[str, Callable[[], Dict[str, Any]]], ...] = (
     ("tables", stage_tables), ("we", stage_we), ("rows", stage_rows),
     ("ps", stage_ps),
-    ("lm", stage_lm), ("flash", stage_flash), ("ssd", stage_ssd),
-    ("conv", stage_conv),
+    ("lm", stage_lm), ("flash", lambda: stage_flash(selected={})),
+    ("ssd", stage_ssd),
+    ("conv", stage_conv), ("select", stage_select),
+    ("target", stage_target),
     ("memory", stage_memory))
 
 

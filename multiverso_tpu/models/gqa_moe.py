@@ -125,29 +125,53 @@ def gqa_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
     return out
 
 
+def heads_of(u, p, cfg, kind: str):
+    """The core's operands from the normed input ``u`` [B, S, D]: q [B, H,
+    S, hd] and k, v [B, Hkv, S, hd] in the compute dtype, after the
+    projections, the q/k norms where the configuration has them and the
+    positions where ``kind`` takes them. Called inside ``mv.lm.attn``."""
+    b, s, _ = u.shape
+    h, hkv, hd, dt = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.compute_dtype
+    yarn = cfg.yarn if kind == "full" else None
+    mm = functools.partial(mla_moe.matmul, dtype=dt)
+    q = mm(u, p["wq"], False, out_dtype=jnp.float32).reshape(b, s, h, hd)
+    k = mm(u, p["wk"], False, out_dtype=jnp.float32).reshape(b, s, hkv, hd)
+    v = mm(u, p["wv"], False, out_dtype=dt).reshape(b, s, hkv, hd)
+    if cfg.qk_norm:
+        with jax.named_scope("mv.lm.attn.qknorm"):
+            q = mla_moe.rms_norm(q, p["q_norm"], cfg.eps)
+            k = mla_moe.rms_norm(k, p["k_norm"], cfg.eps)
+    if kind in cfg.rope_kinds:
+        q = mla_moe.rotary(q, cfg.rope_theta, yarn)
+        k = mla_moe.rotary(k, cfg.rope_theta, yarn)
+    heads = lambda t: t.astype(dt).transpose(0, 2, 1, 3)
+    return heads(q), heads(k), heads(v)
+
+
+def out_of(o, u, p, cfg):
+    """The core's output ``o`` [B, H, S, hd] -> [B, S, D] float32: the gate
+    where the configuration has one, then ``W_o``. Called inside
+    ``mv.lm.attn``."""
+    b, h, s, hd = o.shape
+    mm = functools.partial(mla_moe.matmul, dtype=cfg.compute_dtype)
+    o = o.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
+    if cfg.attn_gate:
+        with jax.named_scope("mv.lm.attn.gate"):
+            o = o * jax.nn.sigmoid(
+                mm(u, p["wgate"], False, out_dtype=jnp.float32))
+    return mm(o, p["wo"], False, out_dtype=jnp.float32)
+
+
 def gqa(u, p, cfg, kind: str):
     """Grouped-query attention of ``kind`` (``"full"`` or ``"window"``) on
     the normed input ``u`` [B, S, D] -> [B, S, D] float32; ``cfg`` is a
     :class:`GQAMoEConfig` or another configuration with its attention's
-    fields."""
-    b, s, _ = u.shape
-    h, hkv, hd, dt = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.compute_dtype
+    fields. What lies round the core (:func:`heads_of`, :func:`out_of`) is
+    ``models/keye_moe.sparse_gqa``'s too."""
+    s = u.shape[1]
     window = cfg.window if kind == "window" else None
-    yarn = cfg.yarn if kind == "full" else None
-    mm = functools.partial(mla_moe.matmul, dtype=dt)
     with jax.named_scope("mv.lm.attn"):
-        q = mm(u, p["wq"], False, out_dtype=jnp.float32).reshape(b, s, h, hd)
-        k = mm(u, p["wk"], False, out_dtype=jnp.float32).reshape(b, s, hkv, hd)
-        v = mm(u, p["wv"], False, out_dtype=dt).reshape(b, s, hkv, hd)
-        if cfg.qk_norm:
-            with jax.named_scope("mv.lm.attn.qknorm"):
-                q = mla_moe.rms_norm(q, p["q_norm"], cfg.eps)
-                k = mla_moe.rms_norm(k, p["k_norm"], cfg.eps)
-        if kind in cfg.rope_kinds:
-            q = mla_moe.rotary(q, cfg.rope_theta, yarn)
-            k = mla_moe.rotary(k, cfg.rope_theta, yarn)
-        heads = lambda t: t.astype(dt).transpose(0, 2, 1, 3)
-        q, k, v = heads(q), heads(k), heads(v)
+        q, k, v = heads_of(u, p, cfg, kind)
         with jax.named_scope("mv.lm.attn." + kind):
             if mla_moe.attn_core(cfg) == "flash":
                 o = flash_attention(q, k, v, True,
@@ -155,9 +179,4 @@ def gqa(u, p, cfg, kind: str):
                                     window)
             else:
                 o = mla_moe._xla_attention(q, k, v, window)
-        o = o.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
-        if cfg.attn_gate:
-            with jax.named_scope("mv.lm.attn.gate"):
-                o = o * jax.nn.sigmoid(
-                    mm(u, p["wgate"], False, out_dtype=jnp.float32))
-        return mm(o, p["wo"], False, out_dtype=jnp.float32)
+        return out_of(o, u, p, cfg)

@@ -422,14 +422,31 @@ def rotary_frequencies(r: int, theta: float, yarn: Optional[Yarn] = None):
     return jnp.asarray(freq, jnp.float32), float(yarn.attention_factor)
 
 
-def rotary(x, theta: float, yarn: Optional[Yarn] = None):
+def rotary(x, theta: float, yarn: Optional[Yarn] = None, positions=None,
+           sections: Optional[Tuple[int, ...]] = None):
     """Rotary positions on the last axis of ``x`` [B, S, ..., R], pairing
     element ``i`` with ``i + R/2``; float32. ``yarn``: see
-    :func:`rotary_frequencies`."""
+    :func:`rotary_frequencies`. A token's position is its place in the
+    sequence, or, with ``positions`` [A, B, S] and ``sections`` (A counts
+    that add up to R/2), one id an axis: the first ``sections[0]``
+    frequencies turn by the first axis's id, the next ``sections[1]`` by
+    the second's, and so on (three axes, time, height and width, in a
+    model that reads images; a text token's ids are equal, and that is
+    the plain form)."""
     s, r = x.shape[1], x.shape[-1]
     freq, factor = rotary_frequencies(r, theta, yarn)
-    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None, :]
-    shape = (1, s) + (1,) * (x.ndim - 3) + (r // 2,)
+    if positions is None:
+        angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None, :]
+        shape = (1, s) + (1,) * (x.ndim - 3) + (r // 2,)
+    else:
+        if sum(sections) != r // 2 or len(sections) != positions.shape[0]:
+            raise ValueError(f"sections {sections} do not deal {r // 2} "
+                             f"frequencies over {positions.shape[0]} axes")
+        axis = np.repeat(np.arange(len(sections)), sections)    # [R/2]
+        # frequency i's own axis: [B, S, R/2]
+        ids = jnp.moveaxis(positions.astype(jnp.float32), 0, -1)[..., axis]
+        angle = ids * freq
+        shape = ids.shape[:2] + (1,) * (x.ndim - 3) + (r // 2,)
     cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
     if factor != 1.0:
         cos, sin = factor * cos, factor * sin
@@ -500,7 +517,8 @@ def attn_grid(cfg, s: int) -> Dict[str, Any]:
              if layer.attn not in (None, "ssm", "conv")]
     branches = max(bool(layer.attn) + bool(layer.ffn) for layer in layers)
     blocks = attn_blocks(cfg, s)
-    sub = sub_tile(*blocks, cfg.head_size)
+    # a selection masks every pair by its own tile: whole tiles
+    sub = None if "sparse" in kinds else sub_tile(*blocks, cfg.head_size)
     n = causal_pairs(s, *blocks, sub=sub)
     out = {"attn_grid_steps": n["grid_steps"], "attn_pairs_live": n["live"],
            "attn_pairs_masked": n["masked"],
@@ -528,11 +546,13 @@ def mixer_grid(cfg, s: int) -> Dict[str, Any]:
     order (a two-branch block's joined by ``+``), the experts' form and,
     where some block is a state-space mixer, its scan's static counts over
     ``s`` positions (``cfg.ssm_grid``), where some is a convolution mixer,
-    that mixer's (``cfg.conv_grid``); nothing for a list of two-branch
-    attention blocks."""
+    that mixer's (``cfg.conv_grid``), where some attends under a learned
+    selection (``sparse``), the indexer's and the selection's
+    (``cfg.index_grid``); nothing for a list of two-branch attention blocks
+    of the other kinds."""
     layers = cfg.layers()
     mixers = {kind: sum(layer.attn == kind for layer in layers)
-              for kind in ("ssm", "conv")}
+              for kind in ("ssm", "conv", "sparse")}
     if not any(mixers.values()) and all(layer.attn and layer.ffn
                                         for layer in layers):
         return {}
@@ -544,13 +564,17 @@ def mixer_grid(cfg, s: int) -> Dict[str, Any]:
         out.update(ssm_layers=mixers["ssm"], **cfg.ssm_grid(s))
     if mixers["conv"]:
         out.update(cfg.conv_grid(s))
+    if mixers["sparse"]:
+        out.update(cfg.index_grid(s))
     return out
 
 
-def _xla_attention(q, k, v, window: Optional[int] = None):
+def _xla_attention(q, k, v, window: Optional[int] = None, select=None):
     """Causal attention over q [B, H, S, D] and k, v [B, Hkv, S, D] in
-    plain XLA, float32 softmax, under a ``window`` where one is given:
-    the CPU tests' core, and the flash kernel's stand-in off the chip."""
+    plain XLA, float32 softmax, under a ``window`` where one is given and
+    under a selection (``select`` [B, S, S], nonzero where a query may see
+    a key, shared by the heads) where one is given: the CPU tests' core,
+    and the flash kernel's stand-in off the chip."""
     b, h, n, d = q.shape
     grouped = q.reshape(b, k.shape[1], h // k.shape[1], n, d)
     s = jnp.einsum("bkgqd,bkjd->bkgqj", grouped, k,
@@ -559,6 +583,8 @@ def _xla_attention(q, k, v, window: Optional[int] = None):
     seen = i >= j
     if window is not None:
         seen &= i - j < window
+    if select is not None:
+        seen = seen & (select != 0)[:, None, None]
     p = jax.nn.softmax(jnp.where(seen, s, -1e30), -1).astype(v.dtype)
     return jnp.einsum("bkgqj,bkjd->bkgqd", p, v,
                       preferred_element_type=jnp.float32
@@ -651,7 +677,10 @@ def block(x, p, attn, ffn, cfg):
     block's parameters; with ``cfg.post_norms`` each branch's output is
     normed as well before it joins the stream (four norms a block). A
     block of one mixer has ``None`` for the other branch, and one norm.
-    Every layer of every kind calls it. Returns (y, ffn's aux)."""
+    Every layer of every kind calls it. Returns (y, ffn's aux). A mixer
+    may hand back ``(out, term)``: a term of its own for the loss (a
+    learned selection's, ``models/keye_moe.py``), which then rides behind
+    the expert layer's aux as its fourth part."""
     def out(branch, name):
         if not cfg.post_norms:
             return branch
@@ -662,12 +691,19 @@ def block(x, p, attn, ffn, cfg):
         with jax.named_scope("mv.lm.norm.pre"):
             return rms_norm(stream, p[name], cfg.eps)
 
-    h, aux = x, None
+    h, aux, term = x, None, None
     if attn is not None:
-        h = x + out(attn(normed(x, "attn_norm"), p), "attn_post_norm")
+        mixed = attn(normed(x, "attn_norm"), p)
+        if isinstance(mixed, tuple):
+            mixed, term = mixed
+        h = x + out(mixed, "attn_post_norm")
     if ffn is not None:
         f, aux = ffn(normed(h, "ffn_norm"), p)
         h = h + out(f, "ffn_post_norm")
+    if term is not None:
+        if aux is None:
+            raise ValueError("a mixer's term rides an expert layer's aux")
+        aux = aux + (term,)
     return h, aux
 
 
@@ -681,8 +717,11 @@ def kept_names(cfg) -> Tuple[str, ...]:
     pass: ``moe.KEPT_NAMES``, all of them or none. None where the
     configuration says ``keeps_products = False`` (``NemotronHConfig``
     alone: a kept result is reserved with the step's program, and
-    ``nemotron3n-train-16k`` has no room)."""
-    return moe.KEPT_NAMES if getattr(cfg, "keeps_products", True) else ()
+    ``nemotron3n-train-16k`` has no room). And what the configuration's
+    own mixer names beside them (``cfg.kept_names``: ``KeyeMoEConfig``'s
+    gradients of its indexer's term)."""
+    return (moe.KEPT_NAMES if getattr(cfg, "keeps_products", True)
+            else ()) + tuple(getattr(cfg, "kept_names", ()))
 
 
 def _run_block(x, p, layer: Layer, bias, cfg, remat: bool = True):
@@ -832,7 +871,9 @@ def _trunk(params, bias, tokens, cfg, still: bool = False):
 def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
             tokens: jax.Array, cfg):
     """tokens [B, S] -> (loss, (counts [layers, E], overflow [layers],
-    balance [layers])).
+    balance [layers])), and where the mixers hand terms to the loss a
+    fourth part, those terms [layers], of which the loss gains
+    ``cfg.index_coef`` times the sum.
 
     ``CE(main, t_{i+1}) + mtp_weight * CE(module, t_{i+2})``, each a mean
     over the positions that have a target (S-1 and S-2 a sequence), plus
@@ -875,10 +916,12 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
                 weights(position < s - 2, cfg.mtp_weight / (b * (s - 2))),
                 cfg)
         loss = main + module
-    counts, overflow, balance = (jnp.stack(a) for a in zip(*aux))
+    counts, overflow, balance, *terms = (jnp.stack(a) for a in zip(*aux))
     if cfg.balance_coef:
         loss = loss + cfg.balance_coef * jnp.sum(balance)
-    return loss, (counts, overflow, balance)
+    if terms:
+        loss = loss + cfg.index_coef * jnp.sum(terms[0])
+    return loss, (counts, overflow, balance, *terms)
 
 
 # ---------------------------------------------------------------------- #
@@ -910,7 +953,9 @@ def make_train_step(cfg, tables: Dict[str, Any],
     returns the states to be adopted, the new biases, the loss, one int32
     array [layers, E + 1] (the tokens that chose each expert, and in the
     last column the rows that overflowed the held experts' buffer) and
-    the load-balance term as it stands in the loss (0 without one)."""
+    the load-balance term as it stands in the loss (0 without one); where
+    the mixers hand terms to the loss, their part of it as well
+    (``index_loss``), a sixth result."""
     shapes = param_shapes(cfg)
     opt = opt or AddOption(learning_rate=1e-4)
     biased = cfg.route == "sigmoid"     # the route that selects under a bias
@@ -920,8 +965,9 @@ def make_train_step(cfg, tables: Dict[str, Any],
         # step carries in the program's map (metadata only)
         with jax.named_scope("mv.lm.params"):
             params = _params_of(states, shapes)
-        (loss, (counts, overflow, balance)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, bias, tokens, cfg)
+        (loss, (counts, overflow, balance, *terms)), grads = (
+            jax.value_and_grad(loss_fn, has_aux=True)(
+                params, bias, tokens, cfg))
         new = {}
         with jax.named_scope("mv.lm.update"):
             for name, table in tables.items():
@@ -931,7 +977,8 @@ def make_train_step(cfg, tables: Dict[str, Any],
         if biased:
             bias = moe.bias_update(bias, counts, cfg.bias_speed)
         return (new, bias, loss, _with_overflow(counts, overflow),
-                cfg.balance_coef * jnp.sum(balance))
+                cfg.balance_coef * jnp.sum(balance),
+                *(cfg.index_coef * jnp.sum(t) for t in terms))
 
     return step
 
@@ -943,7 +990,7 @@ def make_forward(cfg):
     shapes = param_shapes(cfg)
 
     def forward(states, bias, tokens):
-        loss, (counts, overflow, _) = loss_fn(
+        loss, (counts, overflow, *_) = loss_fn(
             _params_of(states, shapes), bias, tokens, cfg)
         return loss, _with_overflow(counts, overflow)
 
@@ -971,7 +1018,8 @@ def make_balance_step(cfg, tables: Dict[str, Any]):
         def terms(moved):
             _, aux = _trunk({**params, **moved}, bias, tokens, cfg,
                             still=True)
-            counts, overflow, balance = (jnp.stack(a) for a in zip(*aux))
+            counts, overflow, balance = (jnp.stack(a)
+                                         for a in tuple(zip(*aux))[:3])
             return jnp.sum(balance), (_with_overflow(counts, overflow),
                                       balance)
 
@@ -1037,7 +1085,7 @@ class Trainer:
                              donate_argnums=(0, 1))
         self.states = {n: t.program_state() for n, t in tables.items()}
         self.steps = 0
-        # (loss, counts, balance) of a step not read back yet
+        # (loss, counts, balance[, index_loss]) of a step not read back yet
         self._ahead = None
         # attn_grid and mixer_grid of the first step
         self._attn: Dict[str, Any] = {}
@@ -1080,10 +1128,12 @@ class Trainer:
                 return None
             with _trace.span("lm.step.wait"):
                 # one read-back a step: it waits for the whole program
-                loss, counts, balance = jax.device_get(due)
+                loss, counts, balance, *terms = jax.device_get(due)
             sp.set(**routing_counts(counts, self.cfg))
             if self.cfg.balance_coef:
                 sp.set(aux_loss=float(balance))
+            if terms:
+                sp.set(index_loss=float(terms[0]))
         return float(loss), counts
 
     def step(self, tokens) -> Tuple[float, np.ndarray]:

@@ -257,7 +257,15 @@ class _Walk(NamedTuple):
     passes through (:meth:`pieces`). What is live in a pair follows from
     ``i * block_q - j * block_k`` alone, its *kind*, and a call's crossed
     pairs are of a few kinds (:meth:`kinds`): each is one branch of static
-    slices in the kernel."""
+    slices in the kernel.
+
+    ``heads`` (the query heads of a batch element; 0 without) says that
+    the call carries a *selection*: one more operand [B, S, S], nonzero
+    where query ``i`` may see key ``j``, shared by a batch element's
+    heads. Its (q block, k block) tile rides the same pair table
+    (:meth:`select_spec`) and is applied in EVERY live pair, an interior
+    one too, after the diagonal's own mask; a selection takes whole tiles
+    (``sub`` is ``None``)."""
     causal: bool
     s: int
     block_q: int
@@ -266,6 +274,7 @@ class _Walk(NamedTuple):
     window: Optional[int] = None
     group: int = 1
     sub: Optional[int] = None
+    heads: int = 0
 
     @property
     def nq(self) -> int:
@@ -335,23 +344,33 @@ class _Walk(NamedTuple):
                        else _Piece(mine, theirs, 1, edges))
         return tuple(out)
 
+    def _spec(self, rows: int, lanes: int, at) -> pl.BlockSpec:
+        """A block of ``rows`` x ``lanes`` at ``at(b, i, j, g)`` of this
+        walk's grid step."""
+        if self._group_walk:
+            index = lambda b, t, qi, kj, g: at(b, qi[t], kj[t], g[t])
+        elif self.causal:
+            index = lambda b, t, qi, kj: at(b, qi[t], kj[t], 0)
+        elif self.q_inner:
+            index = lambda b, j, i: at(b, i, j, 0)
+        else:
+            index = lambda b, i, j: at(b, i, j, 0)
+        return pl.BlockSpec((1, rows, lanes), index)
+
+    def select_spec(self) -> pl.BlockSpec:
+        """The block spec of the selection [B, S, S]: the pair's (q block,
+        k block) tile of the grid step's batch element (the grid's ``b``
+        counts key-value heads for ``q_inner``, else query heads)."""
+        per = self.heads // self.group if self.q_inner else self.heads
+        return self._spec(self.block_q, self.block_k,
+                          lambda b, i, j, g: (b // per, i, j))
+
     def specs(self, d: int) -> Tuple[pl.BlockSpec, ...]:
         """The block specs of a q-shaped operand, a k-shaped one and the
         logsumexp residual, at this walk's (q block, k block)."""
-        group = self.group
+        group, spec = self.group, self._spec
 
-        def spec(rows, lanes, at):      # at(b, i, j, g) -> block index
-            if self._group_walk:
-                index = lambda b, t, qi, kj, g: at(b, qi[t], kj[t], g[t])
-            elif self.causal:
-                index = lambda b, t, qi, kj: at(b, qi[t], kj[t], 0)
-            elif self.q_inner:
-                index = lambda b, j, i: at(b, i, j, 0)
-            else:
-                index = lambda b, i, j: at(b, i, j, 0)
-            return pl.BlockSpec((1, rows, lanes), index)
-
-        if self.q_inner:        # the grid's b is a key-value head
+        if self.q_inner:       # the grid's b is a key-value head
             of_q = lambda b, i, j, g: (b * group + g, i, 0)
             of_k = lambda b, i, j, g: (b, j, 0)
         else:                   # the grid's b is a query head
@@ -445,12 +464,28 @@ class _Mask(NamedTuple):
 
 
 def _masked_or_not(i, j, crossing, walk: _Walk,
-                   tile: Callable[[slice, slice, Optional[_Mask]], None]
-                   ) -> None:
+                   tile: Callable[[slice, slice, Optional[_Mask]], None],
+                   select_ref=None) -> None:
     """Run the pair's ``tile(rows, cols, mask)``: the whole tile unmasked
     on an interior pair; on a crossed one the whole tile under
     :func:`_causal_mask` or, where the walk has sub-tiles, the pieces of
-    the pair's kind, each under the mask of its edges."""
+    the pair's kind, each under the mask of its edges. With a selection
+    (``select_ref``: its tile of the pair) every pair is masked: the whole
+    tile under the selection, on a crossed pair after the diagonal's own
+    mask."""
+    if select_ref is not None:
+        def chosen(s, crossed: bool):
+            if crossed:
+                s = _causal_mask(s, i, j, walk)
+            return jnp.where(select_ref[0].astype(jnp.int32) != 0, s,
+                             _NEG_INF)
+
+        live = lambda p, s: jnp.where(s > _NEG_INF / 2, p, 0.0)
+        for crossed in (True, False):
+            pl.when(crossing if crossed else jnp.logical_not(crossing))(
+                lambda crossed=crossed: tile(_WHOLE, _WHOLE, _Mask(
+                    lambda s: chosen(s, crossed), live)))
+        return
     if crossing is None:
         tile(_WHOLE, _WHOLE, None)
         return
@@ -524,9 +559,18 @@ def _across(x, width: int):
     return wide if wide.shape[1] == width else wide[:, :width]
 
 
+def _select_of(refs, at: int, walk: _Walk):
+    """(the selection's ref or ``None``, the kernel's other refs): a call
+    with a selection hands it over as its LAST input, ``refs[at]``."""
+    if not walk.heads:
+        return None, refs
+    return refs[at], refs[:at] + refs[at + 1:]
+
+
 def _flash_kernel(*refs, walk: _Walk, scale: float, emit_lse: bool):
     i, j, first, last, crossing, refs = walk.enter(refs)
     q_ref, k_ref, v_ref = refs[:3]
+    select_ref, refs = _select_of(refs, 3, walk)
     if emit_lse:
         o_ref, lse_ref, m_ref, l_ref, acc_ref = refs[3:]
     else:   # inference-only call: skip the residual's VPU work + HBM write
@@ -565,7 +609,7 @@ def _flash_kernel(*refs, walk: _Walk, scale: float, emit_lse: bool):
         m_ref[rows] = m_next
         l_ref[rows] = l_next
 
-    _masked_or_not(i, j, crossing, walk, tile)
+    _masked_or_not(i, j, crossing, walk, tile, select_ref)
 
     @pl.when(last)
     def _emit():
@@ -593,32 +637,42 @@ def _heads(q, k, causal: bool) -> Tuple[int, int]:
 
 
 def _walk_of(q, k, causal: bool, block_q: int, block_k: int,
-             window: Optional[int], sub: Optional[int]) -> Tuple[_Walk, int]:
+             window: Optional[int], sub: Optional[int],
+             select=None) -> Tuple[_Walk, int]:
     """(the forward's and dQ's walk, batch x key-value heads) of a call."""
     s = q.shape[2]
     block_q, block_k = _blocks(s, block_q, block_k)
     bkv, group = _heads(q, k, causal)
     if not causal or _sub_blocks(block_q, block_k, sub) == (block_q, block_k):
         sub = None      # no crossed pair, or none to cut: whole tiles
-    return _Walk(causal, s, block_q, block_k, False,
-                 _band(s, window) if causal else None, group, sub), bkv
+    walk = _Walk(causal, s, block_q, block_k, False,
+                 _band(s, window) if causal else None, group, sub)
+    if select is None:
+        return walk, bkv
+    if not causal or select.shape != (q.shape[0], s, s):
+        raise ValueError("a selection [B, S, S] goes with a causal call; "
+                         f"got {select.shape} for q {q.shape}")
+    # every pair is masked by the selection's own tile: whole tiles
+    return walk._replace(sub=None, heads=q.shape[1]), bkv
 
 
 def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
                    interpret: bool, with_lse: bool,
-                   window: Optional[int] = None, sub: Optional[int] = None):
+                   window: Optional[int] = None, sub: Optional[int] = None,
+                   select=None):
     b, h, s, d = q.shape
-    walk, bkv = _walk_of(q, k, causal, block_q, block_k, window, sub)
+    walk, bkv = _walk_of(q, k, causal, block_q, block_k, window, sub, select)
     block_q, bh = walk.block_q, b * h
     qspec, kspec, lspec = walk.specs(d)
+    chosen = ((select,), [walk.select_spec()]) if walk.heads else ((), [])
     oshape = jax.ShapeDtypeStruct((bh, s, d), q.dtype)
     lshape = jax.ShapeDtypeStruct((bh, s, _RES_LANES), jnp.float32)
     res = walk.call(
         functools.partial(_flash_kernel, walk=walk, scale=1.0 / (d ** 0.5),
                           emit_lse=with_lse),
         bh, (q.reshape(bh, s, d), k.reshape(bkv, s, d),
-             v.reshape(bkv, s, d)), interpret=interpret,
-        in_specs=[qspec, kspec, kspec],
+             v.reshape(bkv, s, d)) + chosen[0], interpret=interpret,
+        in_specs=[qspec, kspec, kspec] + chosen[1],
         out_specs=[qspec, lspec] if with_lse else [qspec],
         out_shape=[oshape, lshape] if with_lse else [oshape],
         scratch_shapes=[
@@ -660,6 +714,7 @@ def _bwd_p_ds(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, rows, cols,
 
 def _bwd_dq_kernel(*refs, walk: _Walk, scale: float):
     i, j, first, last, crossing, refs = walk.enter(refs)
+    select_ref, refs = _select_of(refs, 6, walk)
     q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref, acc_ref = refs
 
     @pl.when(first)
@@ -673,7 +728,7 @@ def _bwd_dq_kernel(*refs, walk: _Walk, scale: float):
             ds, k_ref[0, cols], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # (bq, d)
 
-    _masked_or_not(i, j, crossing, walk, tile)
+    _masked_or_not(i, j, crossing, walk, tile, select_ref)
 
     @pl.when(last)
     def _emit():
@@ -682,6 +737,7 @@ def _bwd_dq_kernel(*refs, walk: _Walk, scale: float):
 
 def _bwd_dkv_kernel(*refs, walk: _Walk, scale: float):
     i, j, first, last, crossing, refs = walk.enter(refs)
+    select_ref, refs = _select_of(refs, 6, walk)
     (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
      dk_ref, dv_ref, dk_acc, dv_acc) = refs
 
@@ -701,7 +757,7 @@ def _bwd_dkv_kernel(*refs, walk: _Walk, scale: float):
             ds, q_ref[0, rows], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)               # (bk, d)
 
-    _masked_or_not(i, j, crossing, walk, tile)
+    _masked_or_not(i, j, crossing, walk, tile, select_ref)
 
     @pl.when(last)
     def _emit():
@@ -711,19 +767,22 @@ def _bwd_dkv_kernel(*refs, walk: _Walk, scale: float):
 
 def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
                     block_k: int, interpret: bool,
-                    window: Optional[int] = None, sub: Optional[int] = None):
+                    window: Optional[int] = None, sub: Optional[int] = None,
+                    select=None):
     b, h, s, d = q.shape
-    walk, bkv = _walk_of(q, k, causal, block_q, block_k, window, sub)
+    walk, bkv = _walk_of(q, k, causal, block_q, block_k, window, sub, select)
+    chosen = (select,) if walk.heads else ()
     block_q, block_k, bh = walk.block_q, walk.block_k, b * h
     scale = 1.0 / (d ** 0.5)
     operands = (q.reshape(bh, s, d), k.reshape(bkv, s, d),
                 v.reshape(bkv, s, d), out.reshape(bh, s, d),
-                do.reshape(bh, s, d), lse)
+                do.reshape(bh, s, d), lse) + chosen
     qspec, kspec, rspec = walk.specs(d)
     dq = walk.call(
         functools.partial(_bwd_dq_kernel, walk=walk, scale=scale),
         bh, operands, interpret=interpret,
-        in_specs=[qspec, kspec, kspec, qspec, qspec, rspec],
+        in_specs=([qspec, kspec, kspec, qspec, qspec, rspec]
+                  + [walk.select_spec() for _ in chosen]),
         out_specs=qspec, out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)])
 
@@ -733,7 +792,8 @@ def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
     dk, dv = walk.call(
         functools.partial(_bwd_dkv_kernel, walk=walk, scale=scale),
         bkv, operands, interpret=interpret,
-        in_specs=[qspec, kspec, kspec, qspec, qspec, rspec],
+        in_specs=([qspec, kspec, kspec, qspec, qspec, rspec]
+                  + [walk.select_spec() for _ in chosen]),
         out_specs=[kspec, kspec],
         out_shape=[jax.ShapeDtypeStruct((bkv, s, d), k.dtype),
                    jax.ShapeDtypeStruct((bkv, s, d), v.dtype)],
@@ -752,7 +812,8 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: int = 128, block_k: int = 128,
                     interpret: Optional[bool] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, select=None,
+                    with_lse: bool = False):
     """Fused attention over q [B, H, S, D] and k, v [B, Hkv, S, D]; S must
     divide by the block sizes (blocks auto-clamp to S when S < 128). With
     ``Hkv < H`` (a causal call) query head ``h`` reads key-value head
@@ -761,8 +822,24 @@ def flash_attention(q, k, v, causal: bool = False,
     window``. ``interpret=None`` auto-selects interpreter mode off-TPU
     (tests); pass False to force the compiled path. A causal call's
     crossed pairs are cut into the sub-tiles :func:`sub_tile` gives the
-    blocks and the head size.
+    blocks and the head size. ``select`` (a causal call's, int8 [B, S, S],
+    shared by a batch element's heads): position ``i`` sees ``j`` only
+    where ``select[b, i, j]`` is nonzero as well; the three kernels read
+    its tile of every live pair and mask by it, in whole tiles, and it
+    takes no gradient; ``with_lse`` (a selection's call) hands back the
+    rows' log-sum-exp over their live keys as well, float32 [B, H, S], a
+    constant to the gradient: ``exp(q . k / sqrt(D) - lse)`` is a live
+    key's probability. Without a selection the call is what it was, trace
+    for trace.
     """
+    if select is not None:
+        if window is not None:
+            raise ValueError("a selection goes with no window")
+        out, lse = _attention_selected(q, k, v, select, block_q, block_k,
+                                       interpret)
+        return (out, lse) if with_lse else out
+    if with_lse:
+        raise ValueError("the log-sum-exp comes with a selection's call")
     return _attention(q, k, v, causal, block_q, block_k, interpret, window,
                       sub_tile(*_blocks(q.shape[2], block_q, block_k),
                                q.shape[3]))
@@ -791,3 +868,33 @@ def _bwd(causal, block_q, block_k, interpret, window, sub, res, g):
 
 
 _attention.defvjp(_fwd, _bwd)
+
+
+def _rows_lse(lse, q):
+    """The forward's residual [B x H, S, lanes] as [B, H, S]."""
+    return lse[..., 0].reshape(q.shape[:3])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _attention_selected(q, k, v, select, block_q, block_k, interpret):
+    """:func:`flash_attention` under a selection: causal, whole tiles;
+    (the output, the rows' log-sum-exp [B, H, S])."""
+    return _fwd_selected(q, k, v, select, block_q, block_k, interpret)[0]
+
+
+def _fwd_selected(q, k, v, select, block_q, block_k, interpret):
+    out, lse = _flash_forward(q, k, v, True, block_q, block_k,
+                              _resolve_interpret(interpret), True,
+                              select=select)
+    return (out, _rows_lse(lse, q)), (q, k, v, out, lse, select)
+
+
+def _bwd_selected(block_q, block_k, interpret, res, g):
+    q, k, v, out, lse, select = res
+    # the log-sum-exp is a constant to the gradient: g[1] is dropped
+    return _flash_backward(q, k, v, out, lse, g[0], True, block_q, block_k,
+                           _resolve_interpret(interpret),
+                           select=select) + (None,)
+
+
+_attention_selected.defvjp(_fwd_selected, _bwd_selected)

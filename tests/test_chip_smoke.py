@@ -182,6 +182,56 @@ def test_conv_stage():
         "TPU v5 lite", "hbm_bytes_per_s") * 1e3 - 0.328) < 1e-3
 
 
+def test_select_stage():
+    """The indexer and the selection at a small size: ms of the three
+    products, of the whole selection and of a chunk by the counting search
+    and by ``lax.top_k`` (the stage itself holds their sets equal, and a
+    chunk alone to the whole map); a row's spread; the keys bfloat16
+    decides otherwise than float32."""
+    facts = chip_smoke.stage_select(positions=128, dim=64, index_heads=2,
+                                    index_dim=8, topk=16, chunk=32,
+                                    top_k_chunks=2, repeats=1)
+    for name in ("operands_ms", "select_ms", "scores_ms_chunk"):
+        assert facts[name] > 0
+    for name in ("search_ms_chunk", "top_k_ms_chunk"):
+        assert name in facts
+    assert facts["selected_keys_tail"] == 2 * 32 * 16
+    assert facts["row_spread"] > 0
+    assert 0 <= facts["bfloat16_decides_otherwise_share"] < 0.1
+    assert {"select", "target"} <= set(dict(chip_smoke.STAGES))
+
+
+def test_target_stage():
+    """The indexer's term at a small size: ms forward and with the
+    gradients its forward makes, and term and gradients in float32 against
+    plain autodiff of the definition over whole arrays."""
+    facts = chip_smoke.stage_target(positions=128, dim=64, heads=4,
+                                    kv_heads=2, head_dim=8, index_heads=2,
+                                    index_dim=8, topk=16, chunk=32,
+                                    repeats=1, check_positions=64)
+    assert facts["fwd_ms"] > 0 and facts["fwd_bwd_ms"] > 0
+    assert facts["term"] > 0
+    errs = facts["rel_err_term_dqi_dki_dw"]
+    assert len(errs) == 4 and max(errs) <= 1e-3
+
+
+def test_flash_stage_under_a_selection():
+    """The flash stage's last call, tiny: the three kernels with the
+    selection operand beside the same call without it, and against
+    float32 attention under the same mask."""
+    facts = chip_smoke.stage_flash(
+        calls=(), repeats=1, selected=dict(
+            shape=(1, 4, 128, 16), hkv=2, blocks=((32, 32), (32, 64)),
+            topk=16, repeats=1))["keye.selected"]
+    assert abs(facts["selected_share"] - (16 * 17 / 2 + 112 * 16)
+               / (128 * 129 / 2)) < 1e-3
+    for blocks in ("32x32", "32x64"):
+        for tag in ("select", "causal"):
+            for kernel in ("fwd", "dq", "dkv"):
+                assert facts[f"{blocks}_{tag}_{kernel}_ms"] > 0
+        assert max(facts[f"{blocks}_rel_err"]) <= chip_smoke.ATTN_BF16_TOL
+
+
 @pytest.mark.parametrize("head_dim,group", [(16, 4), (8, 2)])
 def test_flash_stage_at_heads_under_a_lane_tile(head_dim, group):
     """The stage's arithmetic at the shape of ``lfm2-train-8k``'s call, tiny:

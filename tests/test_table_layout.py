@@ -732,6 +732,39 @@ def test_gated_attention_compiles_for_a_v5e_at_16k_positions(
     assert "bf16[4,16384,128]" in text and "bf16[32,16384,128]" in text
 
 
+def test_attention_under_a_selection_compiles_for_a_v5e_at_16k_positions(
+        one_chip, monkeypatch):
+    """``models/keye_moe.sparse_gqa`` as ``keye-train-16k`` calls it: 32
+    query heads of 128 over 4 key-value heads, ONE sequence of 16,384
+    positions, the indexer's 16 heads of 64 and a selection of 2,048 keys a
+    query in chunks of 512 rows, the three flash kernels with the int8
+    selection's 1,024 x 1,024 tile beside q, k and v (1 MB more a pair: it
+    fits), forward and backward with the indexer's term."""
+    from multiverso_tpu.models import keye_moe, mla_moe
+    from multiverso_tpu.ops import attention_kernels
+
+    # the process's devices are the CPU's: the kernels would be interpreted
+    monkeypatch.setattr(attention_kernels, "_resolve_interpret",
+                        lambda interpret: False)
+    cfg = keye_moe.KeyeMoEConfig(
+        dim=2048, n_heads=32, n_kv_heads=4, head_dim=128,
+        mrope_section=(16, 24, 24), index_heads=16, index_dim=64,
+        index_topk=2048, index_chunk=512, attn="flash")
+    assert mla_moe.attn_blocks(cfg, 16384) == (1024, 1024)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {n: f32(*s) for n, s in cfg.attn_shapes("sparse").items()}
+
+    def attend(u, p):
+        out, term = cfg.attend(u, p, "sparse")
+        return out.sum() + term
+
+    text = jax.jit(jax.grad(attend, argnums=(0, 1))).lower(
+        f32(1, 16384, 2048), p).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3       # forward, dQ, dK with dV
+    assert "s8[1,16384,16384]" in text              # the selection, whole
+    assert "bf16[4,16384,128]" in text and "bf16[32,16384,128]" in text
+
+
 def test_sigmoid_expert_layer_over_128_compiles_for_a_v5e(one_chip):
     """The held experts' grouped products as ``trinity-train-16k`` calls
     them: a 32,768-row buffer in sixteen groups of 2,048 x 1,024 at GLM's

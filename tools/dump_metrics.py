@@ -824,13 +824,26 @@ def _mixer_lines(events: List[Dict]) -> List[str]:
     under a layer list with a mixer that is no attention
     (``models/mla_moe.mixer_grid``) and, where some are convolution mixers,
     their part of the matrix-product operations a token needs in a forward
-    pass of the whole step (``conv.mixer_flops_share``'s two counts)."""
+    pass of the whole step (``conv.mixer_flops_share``'s two counts);
+    where some attend under a learned selection, a head's positions
+    selected over those it sees causally (``sparse.selected_share``) and
+    those the kernels compute over the selected."""
     args = next((e["args"] for e in events if e.get("name") == "lm.step"
                  and "block_kinds" in e.get("args", {})), None)
     if args is None:
         return []
     out = [f"  blocks: {args['block_kinds']}"]
-    if args.get("step_flops_token"):
+    if "attn_positions_selected" in args:
+        selected, causal, computed = (
+            args["attn_positions_selected"], args["attn_positions_causal"],
+            args["attn_positions_computed"])
+        out.append(
+            f"    selection: top {args['index_topk']} by {args['index_heads']}"
+            f" index heads of {args['index_dim']}, chunks of "
+            f"{args['index_chunk']}; {selected} of {causal} causal positions"
+            f" a head selected = {100.0 * selected / causal:.2f}%, "
+            f"{computed} computed = {computed / selected:.2f}x the selected")
+    if "mixer_flops_token" in args and args.get("step_flops_token"):
         mixers, step = args["mixer_flops_token"], args["step_flops_token"]
         out.append(
             f"    conv mixers: {args['conv_layers']} of {args['conv_taps']} "
