@@ -1499,17 +1499,72 @@ def flash_selected(shape=(1, 32, 16384, 128), hkv: int = 4,
     return facts
 
 
+def _term_kernels(cfg, qi, ki, w, chosen, chunks, repeats: int,
+                  inner: int = 16) -> Dict[str, Any]:
+    """The term's two kernels alone (``ops/index_kernels.chunk_calls``) on
+    the chunks numbered ``chunks``: ms a call of the statistics' and of the
+    gradients', whose walks end at the chunk's diagonal, so the time should
+    grow with the chunk's number. A call is well under a dispatch's
+    latency, so ``inner`` of them run in one program (a ``fori_loop`` whose
+    chunk number XLA cannot tell is the same every trip). The target is a
+    stand-in (uniform over a row's selected keys): the kernels' time does
+    not depend on it."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.ops import attention_kernels, index_kernels
+
+    b, s = ki.shape[:2]
+    rows, dt = min(cfg.index_chunk, s), cfg.compute_dtype
+    walk, stats, grads = index_kernels.chunk_calls(
+        b, s, rows, cfg.index_heads, cfg.index_dim, dt,
+        interpret=attention_kernels._resolve_interpret(None))
+
+    def looped(call):
+        def run(n, *args):
+            trip = lambda i, acc: acc + call(
+                jnp.minimum(n, n + i), *args)[0].ravel()[0]
+            return jax.lax.fori_loop(0, inner, trip, jnp.zeros(()))
+        return run
+
+    facts: Dict[str, Any] = {"key_tile": walk.tile, "chunks": {}}
+    for n in chunks:
+        cut = slice(n * rows, (n + 1) * rows)
+        live = chosen[:, cut] != 0
+        pbar = live / jnp.maximum(jnp.sum(live, -1, keepdims=True), 1.0)
+        number = jnp.full((1,), n, jnp.int32)
+        args = (qi[:, cut].astype(dt).transpose(0, 2, 1, 3), ki.astype(dt),
+                w[:, cut], chosen[:, cut], pbar.astype(jnp.float32))
+        lse, _ = jax.jit(stats)(number, *args)
+        compile_s, stats_ms, _ = _timed(looped(stats), (number,) + args,
+                                        repeats)
+        _, grads_ms, _ = _timed(
+            looped(grads),
+            (number,) + args + (lse, jnp.zeros(ki.shape, jnp.float32)),
+            repeats)
+        facts["chunks"][str(n)] = {
+            "tiles": int(walk.last(n)) + 1,
+            "stats_ms": round(stats_ms / inner, 4),
+            "grads_ms": round(grads_ms / inner, 4),
+            "stats_compile_s": compile_s}
+    return facts
+
+
 def stage_target(positions: int = 16384, dim: int = 2048, heads: int = 32,
                  kv_heads: int = 4, head_dim: int = 128,
                  index_heads: int = 16, index_dim: int = 64,
                  topk: int = 2048, chunk: int = 512, repeats: int = 3,
-                 check_positions: int = 512) -> Dict[str, Any]:
+                 check_positions: int = 1024) -> Dict[str, Any]:
     """``models/keye_moe.index_loss`` as ``keye-train-16k`` calls it, one
-    layer's term over one sequence: compile seconds and ms a call forward
-    and with the gradients to the indexer's operands (made in its
-    forward); and on the first ``check_positions`` the term and those
-    gradients in float32 against plain autodiff of the definition over
-    whole arrays (max|err| over max|reference|)."""
+    layer's term over one sequence, in both its forms side by side (the
+    two kernels of ``ops/index_kernels.py``, which the cell runs, and XLA's
+    whole arrays, ``xla_*``): compile seconds and ms a call forward and
+    with the gradients to the indexer's operands (made in its forward), and
+    how far the two forms' term and gradients are apart at this size; the
+    kernels alone at the first, the middle and the last chunk
+    (:func:`_term_kernels`); and on the first ``check_positions`` the term
+    and those gradients of each form in float32 against plain autodiff of
+    the definition over whole arrays (max|err| over max|reference|)."""
     import jax
     import jax.numpy as jnp
 
@@ -1517,19 +1572,33 @@ def stage_target(positions: int = 16384, dim: int = 2048, heads: int = 32,
 
     cfg, p, u, keys = _keye_layer(positions, dim, heads, kv_heads, head_dim,
                                   index_heads, index_dim, topk, chunk)
+    forms = (("", cfg._replace(attn="flash")),
+             ("xla_", cfg._replace(attn="xla")))
     q = jax.random.normal(keys[0], (1, heads, positions, head_dim),
                           jnp.bfloat16)
     k = jax.random.normal(keys[1], (1, kv_heads, positions, head_dim),
                           jnp.bfloat16)
     qi, ki, w = jax.jit(lambda u, p: keye_moe.index_operands(u, p, cfg))(u, p)
     chosen = jax.jit(lambda *a: keye_moe.selection(*a, cfg))(qi, ki, w)
-    term = lambda qi, ki, w: keye_moe.index_loss(qi, ki, w, q, k, chosen, cfg)
     facts: Dict[str, Any] = {}
-    facts["fwd_compile_s"], facts["fwd_ms"], value = _timed(
-        term, (qi, ki, w), repeats)
-    facts["fwd_bwd_compile_s"], facts["fwd_bwd_ms"], _ = _timed(
-        jax.value_and_grad(term, (0, 1, 2)), (qi, ki, w), repeats)
-    facts["term"] = round(float(value), 5)
+    both = []
+    for tag, form in forms:
+        term = lambda qi, ki, w: keye_moe.index_loss(
+            qi, ki, w, q, k, chosen, form)
+        facts[tag + "fwd_compile_s"], facts[tag + "fwd_ms"], value = _timed(
+            term, (qi, ki, w), repeats)
+        (facts[tag + "fwd_bwd_compile_s"], facts[tag + "fwd_bwd_ms"],
+         (_, grads)) = _timed(jax.value_and_grad(term, (0, 1, 2)),
+                              (qi, ki, w), repeats)
+        facts[tag + "term"] = round(float(value), 5)
+        both.append((value,) + tuple(grads))
+    rel = lambda a, b: float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+    facts["forms_rel_diff_term_dqi_dki_dw"] = [
+        round(rel(a, b), 6) for a, b in zip(*both)]
+    n_chunks = positions // min(chunk, positions)
+    facts["kernels"] = _term_kernels(
+        cfg, qi, ki, w, chosen,
+        sorted({0, (n_chunks - 1) // 2, n_chunks - 1}), repeats)
 
     n = min(check_positions, positions)
     exact = cfg._replace(compute_dtype=jnp.float32,
@@ -1552,16 +1621,19 @@ def stage_target(positions: int = 16384, dim: int = 2048, heads: int = 32,
 
     with jax.default_matmul_precision("highest"):
         want = jax.jit(jax.value_and_grad(definition, (0, 1, 2)))(*few)
-        got = jax.jit(jax.value_and_grad(
-            lambda *a: keye_moe.index_loss(*a, qs, ks, picked, exact),
-            (0, 1, 2)))(*few)
-    pairs = [(got[0], want[0])] + list(zip(got[1], want[1]))
-    errs = [float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
-            for a, b in pairs]
-    if not max(errs) <= 1e-3:       # float32 on both sides; a NaN fails too
-        raise AssertionError(f"target: relative error {errs} (term, dqI, "
-                             f"dkI, dw) > 1e-3")
-    facts["rel_err_term_dqi_dki_dw"] = [round(e, 6) for e in errs]
+        for tag, form in forms:
+            form = exact._replace(attn=form.attn)
+            got = jax.jit(jax.value_and_grad(
+                lambda *a: keye_moe.index_loss(*a, qs, ks, picked, form),
+                (0, 1, 2)))(*few)
+            errs = [rel(a, b) for a, b in
+                    [(got[0], want[0])] + list(zip(got[1], want[1]))]
+            if not max(errs) <= 1e-3:   # float32 on both sides; a NaN fails
+                raise AssertionError(
+                    f"target ({tag or 'kernels'}): relative error {errs} "
+                    f"(term, dqI, dkI, dw) > 1e-3")
+            facts[tag + "rel_err_term_dqi_dki_dw"] = [round(e, 6)
+                                                      for e in errs]
     return facts
 
 

@@ -60,6 +60,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from multiverso_tpu.models import gqa_moe, mla_moe
 from multiverso_tpu.models.mla_moe import Layer
+from multiverso_tpu.ops import index_kernels
 from multiverso_tpu.ops.attention_kernels import causal_pairs, flash_attention
 
 _DEAD = -1e30
@@ -111,7 +112,10 @@ class KeyeMoEConfig(NamedTuple):
         positions, as ``lm.step`` spans carry them: the indexer's sizes;
         of ONE head of ONE sequence the positions a query selects, those
         it sees causally and those the kernels compute (the triangle in
-        whole tiles: a selection prunes no pair yet); a layer's selection
+        whole tiles: a selection prunes no pair yet); the key tiles one
+        of the term's two kernels visits a layer a sequence, a chunk's up
+        to its diagonal, and the tiles of the uncut rectangle (equal where
+        XLA makes the term: it computes every key); a layer's selection
         in bytes a sequence and what the blocks keep of the terms; and the
         operations a token of the matrix products a forward pass needs on
         this chip: the indexer's (its
@@ -125,6 +129,8 @@ class KeyeMoEConfig(NamedTuple):
         k = min(self.index_topk, s)
         selected = k * (k + 1) // 2 + (s - k) * k
         walked = causal_pairs(s, *mla_moe.attn_blocks(self, s))
+        tiles = index_kernels.walk_of(s, _chunk_rows(self, s))
+        kernels = mla_moe.attn_core(self) == "flash"
         index = 2 * d * (hi * di + di + hi) + hi * di * (s + 1)
         core, target = 4 * hd * h * selected // s, 2 * hd * h * selected // s
         rest = (2 * d * hd * 2 * (h + hkv) + 2 * d * self.n_experts
@@ -136,6 +142,9 @@ class KeyeMoEConfig(NamedTuple):
                 "attn_positions_selected": selected,
                 "attn_positions_causal": walked["needed"],
                 "attn_positions_computed": walked["computed"],
+                "index_tiles_walked": (tiles.walked() if kernels
+                                       else tiles.whole()),
+                "index_tiles_whole": tiles.whole(),
                 "select_bytes": s * s,
                 # what the rematerialised blocks keep of the terms: their
                 # float32 gradients to qI, kI and w, a sequence
@@ -312,13 +321,20 @@ def _kl_chunks(qi, ki, w, q, k, chosen, cfg, lse=None):
     term's gradient is made of: ``dI = (softmax(I) - pbar) / (B S)`` on
     the selected keys, taken back through the chunk's scores to ``qI`` and
     ``w`` (the chunk's rows) and to ``kI`` (summed in a carry). Returns
-    (the term, (dqI, dkI, dw))."""
+    (the term, (dqI, dkI, dw)).
+
+    Everything from ``I`` on is two kernels' where the core is
+    (``mla_moe.attn_core``: ``ops/index_kernels.term_chunk``, which walks
+    the key tiles up to the chunk's diagonal and hands back the rows' KL
+    and the three gradients, no [rows, S] array between them), and XLA's
+    whole arrays elsewhere."""
     b, h, s, hd = q.shape
     hkv, rows, dt = k.shape[1], _chunk_rows(cfg, s), cfg.compute_dtype
+    kernels = mla_moe.attn_core(cfg) == "flash"
 
     def body(carry, c):
         total, dki = carry
-        qi_c, w_c, q_c, chosen_c, lse_c = c
+        n, qi_c, w_c, q_c, chosen_c, lse_c = c
         live = chosen_c != 0                                    # [B, R, S]
         dots = jnp.einsum(
             "bkgrd,bksd->bkgrs", q_c.reshape(b, hkv, h // hkv, rows, hd), k,
@@ -330,6 +346,13 @@ def _kl_chunks(qi, ki, w, q, k, chosen, cfg, lse=None):
             prob = jnp.exp(dots - lse_c.reshape(
                 b, hkv, h // hkv, rows, 1))
         pbar = jnp.where(live, jnp.mean(prob, (1, 2)), 0.0)
+        if kernels:
+            with jax.named_scope("mv.lm.attn.index"):
+                kl, dqi_c, dki, dw_c = index_kernels.term_chunk(
+                    n, qi_c.astype(dt), ki.astype(dt), w_c, chosen_c, pbar,
+                    dki)
+            total = total + jnp.sum(kl) / (b * s)
+            return (total, dki), (dqi_c, dw_c)
         index, back = jax.vjp(
             lambda qi_c, ki, w_c: _scores(qi_c, ki, w_c, dt), qi_c, ki, w_c)
         logq = jax.nn.log_softmax(jnp.where(live, index, _DEAD), -1)
@@ -340,8 +363,8 @@ def _kl_chunks(qi, ki, w, q, k, chosen, cfg, lse=None):
         dqi_c, dki_c, dw_c = back(d_index)
         return (total, dki + dki_c), (dqi_c, dw_c)
 
-    xs = (_chunks(qi, rows), _chunks(w, rows), _chunks(q, rows, 2),
-          _chunks(chosen, rows),
+    xs = (jnp.arange(s // rows, dtype=jnp.int32), _chunks(qi, rows),
+          _chunks(w, rows), _chunks(q, rows, 2), _chunks(chosen, rows),
           None if lse is None else _chunks(lse, rows, 2))
     (total, dki), out = jax.lax.scan(
         body, (jnp.zeros((), jnp.float32), jnp.zeros(ki.shape, jnp.float32)),

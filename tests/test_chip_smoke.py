@@ -202,17 +202,24 @@ def test_select_stage():
 
 
 def test_target_stage():
-    """The indexer's term at a small size: ms forward and with the
-    gradients its forward makes, and term and gradients in float32 against
-    plain autodiff of the definition over whole arrays."""
+    """The indexer's term at a small size, in both its forms: ms forward
+    and with the gradients its forward makes, and term and gradients in
+    float32 against plain autodiff of the definition over whole arrays."""
     facts = chip_smoke.stage_target(positions=128, dim=64, heads=4,
                                     kv_heads=2, head_dim=8, index_heads=2,
                                     index_dim=8, topk=16, chunk=32,
                                     repeats=1, check_positions=64)
-    assert facts["fwd_ms"] > 0 and facts["fwd_bwd_ms"] > 0
-    assert facts["term"] > 0
-    errs = facts["rel_err_term_dqi_dki_dw"]
-    assert len(errs) == 4 and max(errs) <= 1e-3
+    for tag in ("", "xla_"):        # the two kernels' form, and XLA's
+        assert facts[tag + "fwd_ms"] > 0 and facts[tag + "fwd_bwd_ms"] > 0
+        assert facts[tag + "term"] > 0
+        errs = facts[tag + "rel_err_term_dqi_dki_dw"]
+        assert len(errs) == 4 and max(errs) <= 1e-3
+    assert max(facts["forms_rel_diff_term_dqi_dki_dw"]) < 3e-2
+    # the kernels alone at the first, a middle and the last chunk
+    walks = facts["kernels"]["chunks"]
+    assert [walks[n]["tiles"] for n in ("0", "1", "3")] == [1, 2, 4]
+    assert all(c["stats_ms"] > 0 and c["grads_ms"] > 0
+               for c in walks.values())
 
 
 def test_flash_stage_under_a_selection():

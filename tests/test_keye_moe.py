@@ -28,7 +28,7 @@ from benchmark.reference import keye_moe as ref
 from multiverso_tpu import updaters
 from multiverso_tpu.models import (afmoe, gqa_moe, keye_moe, lfm2_moe,
                                    mla_moe)
-from multiverso_tpu.ops import attention_kernels
+from multiverso_tpu.ops import attention_kernels, index_kernels
 from multiverso_tpu.ops.attention_kernels import flash_attention
 
 CFG = keye_moe.KeyeMoEConfig(
@@ -406,10 +406,23 @@ def _products(text: str, *dims: int) -> int:
                for line in dots)
 
 
-def test_the_lowered_step_has_the_indexers_products_and_the_four_scopes():
+def _pallas_calls(jaxpr, found=None):
+    """{a Pallas call's name: its name stack} over ``jaxpr`` and every
+    jaxpr inside it."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = str(eqn.source_info.name_stack)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_calls(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("attn", ["xla", "flash"])
+def test_the_lowered_step_has_the_indexers_products_and_the_four_scopes(attn):
     # widths that no other product of the model has
     cfg = CFG._replace(vocab=112, dim=40, index_heads=3, index_dim=6,
-                       loss_chunk=64)
+                       loss_chunk=64, attn=attn)
     params, bias, tokens = _inputs(cfg)
     layers = len(cfg.layers())
     forward = jax.jit(lambda p: mla_moe.loss_fn(p, bias, tokens, cfg)[0])
@@ -423,7 +436,10 @@ def test_the_lowered_step_has_the_indexers_products_and_the_four_scopes():
     # keys] a layer: once for the selection, once for the term and twice
     # back from it (the term makes its gradients beside itself)
     assert _products(plain, cfg.dim, 18) == layers
-    assert _products(plain, 3, 16, 64, 6) == 4 * layers
+    # where the core is the flash kernels the term's three are inside two
+    # kernels a chunk, a key tile at a time, and XLA's are the selection's
+    term_dots = 3 * layers if attn == "xla" else 0
+    assert _products(plain, 3, 16, 64, 6) == layers + term_dots
     heads = lambda text: _products(text, cfg.n_kv_heads, 2, 16, 64, 8)
     assert heads(plain) == layers
     step = jax.jit(jax.grad(lambda p: mla_moe.loss_fn(p, bias, tokens,
@@ -434,10 +450,29 @@ def test_the_lowered_step_has_the_indexers_products_and_the_four_scopes():
     # the query heads' probabilities a second time, and the remade forward
     # adds the selection's dots alone
     assert heads(lowered) == layers
-    assert _products(lowered, 3, 16, 64, 6) == 5 * layers
+    assert _products(lowered, 3, 16, 64, 6) == 2 * layers + term_dots
     assert mla_moe.kept_names(cfg)[-1] == keye_moe.KEPT_NAMES[0]
     assert mla_moe.kept_names(gqa_moe.GQAMoEConfig()) == (
         mla_moe.moe.KEPT_NAMES)
+    # the term alone: its two kernels sit inside ``mv.lm.attn.index`` under
+    # names of their own (``benchmark/layers/attn`` counts every custom
+    # call whose name holds ``mv.lm.attn`` against the flash kernels), and
+    # no float32 array of index heads x chunk x positions is left in it
+    u, p = _layer_inputs(cfg)
+    q, k, _ = gqa_moe.heads_of(u, p, cfg, "sparse")
+    operands = keye_moe.index_operands(u, p, cfg)
+    chosen = keye_moe.selection(*operands, cfg)
+    term = lambda *a: keye_moe.index_loss(*a, q, k, chosen, cfg)
+    calls = _pallas_calls(jax.make_jaxpr(term)(*operands).jaxpr)
+    whole = "x3x16x64xf32" in jax.jit(term).lower(*operands).as_text()
+    if attn == "xla":
+        assert not calls and whole
+        return
+    assert sorted(calls) == sorted((index_kernels.GRADS, index_kernels.STATS))
+    for name, stack in calls.items():
+        assert "mv.lm.attn" not in name
+        assert "mv.lm.attn.index" in stack, (name, stack)
+    assert not whole
 
 
 def test_one_step_through_the_adam_tables_is_reference_gradient_plus_adam():

@@ -739,9 +739,12 @@ def test_attention_under_a_selection_compiles_for_a_v5e_at_16k_positions(
     positions, the indexer's 16 heads of 64 and a selection of 2,048 keys a
     query in chunks of 512 rows, the three flash kernels with the int8
     selection's 1,024 x 1,024 tile beside q, k and v (1 MB more a pair: it
-    fits), forward and backward with the indexer's term."""
+    fits), forward and backward with the indexer's term, whose index scores
+    and their gradients are ``ops/index_kernels.py``'s two kernels a chunk
+    (16 heads' blocks of [512, 64] resident beside a key tile of 512: their
+    VMEM limit is set from the shapes), under names of their own."""
     from multiverso_tpu.models import keye_moe, mla_moe
-    from multiverso_tpu.ops import attention_kernels
+    from multiverso_tpu.ops import attention_kernels, index_kernels
 
     # the process's devices are the CPU's: the kernels would be interpreted
     monkeypatch.setattr(attention_kernels, "_resolve_interpret",
@@ -760,7 +763,16 @@ def test_attention_under_a_selection_compiles_for_a_v5e_at_16k_positions(
 
     text = jax.jit(jax.grad(attend, argnums=(0, 1))).lower(
         f32(1, 16384, 2048), p).compile().as_text()
-    assert text.count("tpu_custom_call") >= 3       # forward, dQ, dK with dV
+    # forward, dQ, dK with dV, and the term's two
+    assert text.count("tpu_custom_call") >= 5
+    for name in (index_kernels.STATS, index_kernels.GRADS):
+        assert re.search(rf"%{name}[.\d]* = ", text), name
+    assert not re.search(r"%mv\.lm\.attn\.(index|target)[.\d]* = ", text)
+    # the sixteen heads' dots over every key are the selection's alone
+    # (inside its fusion), the term's none
+    whole = [line for line in text.splitlines()
+             if "f32[1,16,512,16384]" in line]
+    assert whole and not any("mv.lm.attn.target" in line for line in whole)
     assert "s8[1,16384,16384]" in text              # the selection, whole
     assert "bf16[4,16384,128]" in text and "bf16[32,16384,128]" in text
 
