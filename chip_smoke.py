@@ -18,6 +18,9 @@ rule, a falling finite loss, ``jnp.take``,
           one tile against its sub-tiles: ms a call and compile seconds
   ssd     the chunked state-space scan at the hybrid cell's shapes: ms
           forward and backward, and its error against the recurrence
+  delta   the chunked gated delta rule at the delta cell's shapes: ms
+          forward and backward for each way of making its triangular
+          inverse, and its error against the recurrence
 
 and a closing ``memory`` check that every device ended up holding bytes.
 
@@ -1031,6 +1034,8 @@ FLASH_CALLS = (
     ("glm47f.causal", (2, 20, 8192, 256), 20, (512, 1024), None),
     # lfm2-train-8k's: heads of 64, half a lane tile a block
     ("lfm2.causal", (2, 32, 8192, 64), 8, (1024, 1024), None),
+    # qwen3next-train-16k's: heads of 256, a group of 8 query heads
+    ("qwen3next.causal", (1, 16, 16384, 256), 2, (512, 1024), None),
 )
 
 
@@ -1292,6 +1297,152 @@ def stage_conv(sequences: int = 2, positions: int = 8192, dim: int = 2048,
         raise AssertionError(f"conv: relative error {errs} (y, du, dwin, "
                              f"dconv_w, dwout) > {ATTN_BF16_TOL}")
     facts["rel_err_y_du_dwin_dconvw_dwout"] = [round(e, 5) for e in errs]
+    return facts
+
+
+def _inverse_by(how: str):
+    """The ways of making ``T = (I + M)^-1`` that stage ``delta`` reads:
+    ``ops/delta_rule.unit_lower_inverse`` (``solve``: XLA's triangular
+    solve against the identity); forward substitution by halves
+    (``halves``: with the inverses ``A'``, ``B'`` of the two diagonal
+    blocks of ``[[A, 0], [C, B]]`` the whole inverse is ``[[A', 0], [-B' C
+    A', B']]``, so ``log2 Q`` rounds of two products of whole [Q, Q]
+    matrices, the round's ``C`` blocks cut out by a mask); and the doubling
+    product ``(I - M)(I + M^2)(I + M^4)...``, exact for a nilpotent ``M``
+    but a sum of powers whose entries grow by binomials where keys repeat
+    (``doubling``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.ops import delta_rule
+
+    mm = lambda a, b: jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+    def halves(m):
+        q = m.shape[-1]
+        i = jnp.arange(q)
+        row, col = i[:, None], i[None, :]
+        inv, b = jnp.eye(q, dtype=m.dtype), 1
+        while b < q:
+            lower_left = ((row // (2 * b) == col // (2 * b))
+                          & (row // b % 2 == 1) & (col // b % 2 == 0))
+            inv = inv - mm(mm(inv, jnp.where(lower_left, m, 0.0)), inv)
+            b *= 2
+        return inv
+
+    def doubling(m):
+        eye = jnp.eye(m.shape[-1], dtype=m.dtype)
+        out, power, n = eye - m, m, 2
+        while n < m.shape[-1]:
+            power = mm(power, power)
+            out, n = mm(out, eye + power), 2 * n
+        return out
+
+    return {"solve": delta_rule.unit_lower_inverse, "halves": halves,
+            "doubling": doubling}[how]
+
+
+# (the way ``T`` is made, the chunk, the key heads a group): what stage
+# ``delta`` reads; the first is what ``qwen3next-train-16k`` runs
+DELTA_CALLS = (("solve", 64, 2), ("halves", 64, 2), ("doubling", 64, 2),
+               ("solve", 128, 2), ("solve", 64, 4), ("solve", 64, 8),
+               ("solve", 64, 16), ("halves", 128, 8))
+
+
+def stage_delta(positions: int = 16384, key_heads: int = 16,
+                value_heads: int = 32, head_dim: int = 128,
+                calls: Tuple = DELTA_CALLS, repeats: int = 5,
+                check_positions: int = 2048) -> Dict[str, Any]:
+    """``ops/delta_rule.gated_delta_chunked`` as ``qwen3next-train-16k``
+    calls it (one sequence of ``positions``, bfloat16 operands), for each
+    of ``calls`` (a way of making ``T``, a chunk, the key heads a group):
+    the seconds the compiler took and the ms a call, forward and forward
+    with every gradient, by this process's clock around ``repeats`` calls
+    it waits for; and ONE key head's output and gradients over the first
+    ``check_positions`` against the recurrence itself, a position at a time
+    in float32 (``benchmark/reference/qwen3_next.delta_rule``; max|err|
+    over max|reference|, as ``stage_lm``), for every way of making ``T``
+    among the calls, with float32 operands and with bfloat16 ones. Inputs
+    are drawn as the mixer makes them: unit keys, scaled unit queries, ``v``
+    a silu of unit normals, ``beta`` a sigmoid, ``g = -A softplus(a + 1)``
+    with ``A`` uniform in (0, 16), the slowest head at 1e-3."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference import qwen3_next as reference
+    from multiverso_tpu.ops.delta_rule import gated_delta_chunked
+
+    k = jax.random.split(jax.random.key(SEED), 7)
+    r = value_heads // key_heads
+    unit = reference.l2norm
+    q = unit(jax.nn.silu(jax.random.normal(
+        k[0], (1, positions, key_heads, head_dim)))) * head_dim ** -0.5
+    kk = unit(jax.nn.silu(jax.random.normal(
+        k[1], (1, positions, key_heads, head_dim))))
+    v = jax.nn.silu(jax.random.normal(
+        k[2], (1, positions, value_heads, head_dim)))
+    a = jax.random.uniform(k[3], (value_heads,), minval=0.0,
+                           maxval=16.0).at[0].set(1e-3)
+    g = -a * jax.nn.softplus(
+        jax.random.normal(k[4], (1, positions, value_heads)) + 1.0)
+    beta = jax.nn.sigmoid(jax.random.normal(k[5], g.shape))
+    weight = jax.random.normal(k[6], v.shape)
+    args = (q, kk, v, g, beta)
+
+    def rule_of(how, chunk, per, dtype=jnp.bfloat16):
+        return lambda *t: gated_delta_chunked(*t, chunk, dtype, per,
+                                              _inverse_by(how))
+
+    def with_grads(fn):
+        # the weights are an ARGUMENT: closed over, 268 MB of them would be
+        # a constant of every compiled program
+        return lambda w, *t: jax.value_and_grad(
+            lambda *u: jnp.sum(w * fn(*u)), range(5))(*t)
+
+    facts: Dict[str, Any] = {}
+    for how, chunk, per in calls:
+        rule = rule_of(how, chunk, per)
+        tag = f"{how}_q{chunk}_k{min(per, key_heads)}"
+        for name, fn, given in (("fwd", rule, args),
+                                ("fwd_bwd", with_grads(rule),
+                                 (weight,) + args)):
+            facts[f"{tag}_{name}_compile_s"], facts[f"{tag}_{name}_ms"], _ = (
+                _timed(fn, given, repeats))
+    _say("delta.timed", **facts)    # a failed check below keeps the readings
+
+    # one key head and its value heads against the recurrence
+    n = min(check_positions, positions)
+    few = tuple(t[:, :n, :h] for t, h in zip(args, (1, 1, r, r, r)))
+    w_few = weight[:, :n, :r]
+
+    def recurrence(q, k, v, g, beta):
+        q, k = (jnp.repeat(t[0], r, axis=1) for t in (q, k))
+        return reference.delta_rule(q, k, v[0], g[0], beta[0],
+                                    lean=True)[None]
+
+    with jax.default_matmul_precision("highest"):
+        _, want = jax.jit(with_grads(recurrence))(w_few, *few)
+        o_want = jax.jit(recurrence)(*few)
+    _, chunk, per = calls[0]
+    # every way of making ``T`` among the calls, with float32 operands at
+    # the highest precision (a TPU's default rounds a float32 product's
+    # operands to bfloat16) and with the cell's own
+    for how in dict.fromkeys(c[0] for c in calls):
+        for name, dtype, precision, tol in (
+                ("float32", jnp.float32, "highest", 1e-3),
+                ("bfloat16", jnp.bfloat16, None, ATTN_BF16_TOL)):
+            rule = rule_of(how, chunk, per, dtype)
+            with jax.default_matmul_precision(precision):
+                _, got = jax.jit(with_grads(rule))(w_few, *few)
+                o_got = jax.jit(rule)(*few)
+            errs = [float(jnp.max(jnp.abs(a - w)) / jnp.max(jnp.abs(w)))
+                    for a, w in zip((o_got,) + got, (o_want,) + want)]
+            facts[f"rel_err_{how}_{name}_o_dq_dk_dv_dg_dbeta"] = [
+                float(f"{e:.3g}") for e in errs]
+            if not max(errs) <= tol:        # a NaN fails too
+                raise AssertionError(
+                    f"delta {how} {name}: relative error {errs} (o, dq, dk, "
+                    f"dv, dg, dbeta) > {tol}")
     return facts
 
 
@@ -1677,7 +1828,7 @@ STAGES: Tuple[Tuple[str, Callable[[], Dict[str, Any]]], ...] = (
     ("ps", stage_ps),
     ("lm", stage_lm), ("flash", lambda: stage_flash(selected={})),
     ("ssd", stage_ssd),
-    ("conv", stage_conv), ("select", stage_select),
+    ("conv", stage_conv), ("delta", stage_delta), ("select", stage_select),
     ("target", stage_target),
     ("memory", stage_memory))
 

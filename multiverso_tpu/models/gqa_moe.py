@@ -25,7 +25,9 @@ chunked loss, the tables, the step and the ``Trainer`` are
   before the positions, one gain each), ``attn_gate`` (the core's output
   times ``sigmoid(u W_gate)``, element for element, before ``W_o``) and
   ``rope_kinds`` (the layer kinds that take rotary positions: a kind left
-  out sees none).
+  out sees none). A fourth, which this model's configuration has no field
+  for: ``rope_dim``, rotary over a leading part of the head
+  (``models/qwen3_next.py``).
 * Experts: ``parallel/moe.held_expert_layer`` under its softmax route:
   probabilities over all ``n_experts``, the ``top_k`` largest
   renormalised, no bias, no shared expert; this chip computes the part of
@@ -129,7 +131,10 @@ def heads_of(u, p, cfg, kind: str):
     """The core's operands from the normed input ``u`` [B, S, D]: q [B, H,
     S, hd] and k, v [B, Hkv, S, hd] in the compute dtype, after the
     projections, the q/k norms where the configuration has them and the
-    positions where ``kind`` takes them. Called inside ``mv.lm.attn``."""
+    positions where ``kind`` takes them (over the first ``cfg.rope_dim`` of
+    a head where the configuration has such a field, the rest passing
+    through; over the whole head without it). Called inside
+    ``mv.lm.attn``."""
     b, s, _ = u.shape
     h, hkv, hd, dt = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.compute_dtype
     yarn = cfg.yarn if kind == "full" else None
@@ -142,8 +147,13 @@ def heads_of(u, p, cfg, kind: str):
             q = mla_moe.rms_norm(q, p["q_norm"], cfg.eps)
             k = mla_moe.rms_norm(k, p["k_norm"], cfg.eps)
     if kind in cfg.rope_kinds:
-        q = mla_moe.rotary(q, cfg.rope_theta, yarn)
-        k = mla_moe.rotary(k, cfg.rope_theta, yarn)
+        turn = lambda t: mla_moe.rotary(t, cfg.rope_theta, yarn)
+        r = getattr(cfg, "rope_dim", None)
+        if r is not None:       # the first r of a head turn, the rest pass
+            turn = lambda t: jnp.concatenate(
+                [mla_moe.rotary(t[..., :r], cfg.rope_theta, yarn),
+                 t[..., r:]], -1)
+        q, k = turn(q), turn(k)
     heads = lambda t: t.astype(dt).transpose(0, 2, 1, 3)
     return heads(q), heads(k), heads(v)
 

@@ -71,7 +71,8 @@ class Layer(NamedTuple):
     then ``ffn``, or ONE mixer (the other is ``None``: one norm, one
     residual sum)."""
     name: str                   # its parameters' prefix: "L<i>", or "mtp"
-    attn: Optional[str]         # "latent", "full", "window", "ssm", "conv"
+    attn: Optional[str]         # "latent", "full", "window", "ssm", "conv",
+                                # "sparse", "delta"
     ffn: Optional[str]          # "dense", "shared+experts" or "experts"
 
 
@@ -215,6 +216,8 @@ def _ffn_shapes(cfg, kind: str) -> Dict[str, Tuple[int, ...]]:
     if cfg.expert_form == "relu2":              # two matrices: no gate
         del out["eg"]
         out.pop("sg", None)
+    if kind == "shared+experts" and getattr(cfg, "shared_gate", False):
+        out["sgate"] = (d,)                     # one number a token
     return out
 
 
@@ -490,7 +493,15 @@ def attn_blocks(cfg, s: int) -> Tuple[int, int]:
     15.9, 11.1 / 13.2 / 16.2 and 10.8 / 14.1 / 17.2 whole): a head of 64
     takes the blocks of a head of 128, and costs no less than one (the
     products halve; the lanes, the exps, the masks and the rescales do
-    not)."""
+    not). At (16384, 256) with 16 query heads over 2 key-value heads, a
+    group of 8 (PERF.md section 6, PR 56), the three read 15.4 / 20.6 /
+    26.6 at 512 x 512, 14.9 / 19.1 / 25.0 at 512 x 1,024, 14.3 / 19.6 /
+    25.3 at 1,024 x 512 and 14.5 / 18.3 / 24.4 at 1,024 x 1,024 with
+    sub-tiles of 256 (15.7 / 20.8 / 27.0, 15.5 / 19.9 / 26.0, 14.9 / 19.9
+    / 26.2 and 15.2 / 19.1 / 25.5 whole): every shape holds the
+    dK-with-dV kernel's group of 8 query heads against one k block in VMEM,
+    and the rule's 512 x 1,024 is within 3% of the fastest, so a head of
+    256 keeps one rule whatever its group."""
     bq = min(cfg.attn_block, s)
     wide = 2 * bq <= 1024 and cfg.head_size <= 256 and s % (2 * bq) == 0
     if not wide:
@@ -514,7 +525,7 @@ def attn_grid(cfg, s: int) -> Dict[str, Any]:
     layers = cfg.layers()
     # the kinds whose core is the flash kernel
     kinds = [layer.attn for layer in layers
-             if layer.attn not in (None, "ssm", "conv")]
+             if layer.attn not in (None, "ssm", "conv", "delta")]
     branches = max(bool(layer.attn) + bool(layer.ffn) for layer in layers)
     blocks = attn_blocks(cfg, s)
     # a selection masks every pair by its own tile: whole tiles
@@ -548,11 +559,12 @@ def mixer_grid(cfg, s: int) -> Dict[str, Any]:
     ``s`` positions (``cfg.ssm_grid``), where some is a convolution mixer,
     that mixer's (``cfg.conv_grid``), where some attends under a learned
     selection (``sparse``), the indexer's and the selection's
-    (``cfg.index_grid``); nothing for a list of two-branch attention blocks
-    of the other kinds."""
+    (``cfg.index_grid``), where some is a delta-rule linear-attention
+    mixer, that mixer's (``cfg.delta_grid``); nothing for a list of
+    two-branch attention blocks of the other kinds."""
     layers = cfg.layers()
     mixers = {kind: sum(layer.attn == kind for layer in layers)
-              for kind in ("ssm", "conv", "sparse")}
+              for kind in ("ssm", "conv", "sparse", "delta")}
     if not any(mixers.values()) and all(layer.attn and layer.ffn
                                         for layer in layers):
         return {}
@@ -566,6 +578,8 @@ def mixer_grid(cfg, s: int) -> Dict[str, Any]:
         out.update(cfg.conv_grid(s))
     if mixers["sparse"]:
         out.update(cfg.index_grid(s))
+    if mixers["delta"]:
+        out.update(cfg.delta_grid(s))
     return out
 
 
@@ -659,6 +673,9 @@ def expert_ffn(u, p, bias, cfg, shared: bool = True):
         with jax.named_scope("mv.lm.moe.shared"):
             beside = (gated_mlp(u, p["sg"], p["su"], p["sd"], cfg) if gated
                       else relu2_mlp(u, p["su"], p["sd"], cfg))
+            if getattr(cfg, "shared_gate", False):
+                beside = beside * jax.nn.sigmoid(jnp.sum(
+                    u.astype(jnp.float32) * p["sgate"], -1, keepdims=True))
     stacked = (("w_gate", "eg", (d, f)), ("w_up", "eu", (d, f)),
                ("w_down", "ed", (f, d)))
     weights = dict(router=p["router"], **{

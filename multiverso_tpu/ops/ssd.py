@@ -22,6 +22,15 @@ sums, exponentials, the carried state and the recurrence are float32; the
 four products take operands in ``dtype`` (bfloat16) and sum in float32,
 forward and backward (:func:`_ein`). Every exponent is of a number that
 is at most 0, so nothing overflows however long the sequence.
+
+``ops/delta_rule.py`` (a gated delta rule's chunked form) shares
+:func:`_ein`, the walk over groups of heads by a ``lax.map`` under
+``jax.checkpoint`` and the rule that no exponent is positive, and nothing
+else: there the state's update is a correction by what the state itself
+answers, so a chunk's contribution ``V' = U - W S_c`` needs the state the
+chunk starts from, and the scan over chunks carries two matrix products a
+step where this one carries a multiply-add of a ``left`` that is made for
+all chunks at once. Do not merge the two.
 """
 
 from __future__ import annotations

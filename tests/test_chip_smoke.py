@@ -162,6 +162,32 @@ def test_ssd_stage():
     assert "ssd" in dict(chip_smoke.STAGES)
 
 
+def test_delta_stage():
+    """The chunked gated delta rule at a small size: ms and compile
+    seconds forward and with every gradient for each way of making the
+    chunk's triangular inverse, one key head held to the recurrence with
+    float32 and with bfloat16 operands."""
+    calls = (("halves", 16, 2), ("solve", 16, 2), ("doubling", 16, 1),
+             ("halves", 32, 4))
+    facts = chip_smoke.stage_delta(positions=128, key_heads=2, value_heads=4,
+                                   head_dim=16, calls=calls, repeats=1,
+                                   check_positions=64)
+    for tag in ("halves_q16_k2", "solve_q16_k2", "doubling_q16_k1",
+                "halves_q32_k2"):
+        for name in ("fwd", "fwd_bwd"):
+            assert facts[f"{tag}_{name}_ms"] > 0
+            assert facts[f"{tag}_{name}_compile_s"] >= 0
+    for how in ("halves", "solve", "doubling"):
+        assert max(facts[f"rel_err_{how}_float32_o_dq_dk_dv_dg_dbeta"]) <= 1e-3
+        errs = facts[f"rel_err_{how}_bfloat16_o_dq_dk_dv_dg_dbeta"]
+        assert len(errs) == 6 and max(errs) <= chip_smoke.ATTN_BF16_TOL
+    assert "delta" in dict(chip_smoke.STAGES)
+    assert chip_smoke.DELTA_CALLS[0] == ("solve", 64, 2)
+    # the cell's call to the flash kernels is among the stage's
+    assert ("qwen3next.causal", (1, 16, 16384, 256), 2) in [
+        c[:3] for c in chip_smoke.FLASH_CALLS]
+
+
 def test_conv_stage():
     """The short-convolution mixer at a small size: ms and compile seconds
     forward, with every gradient and of the pass between the products, the

@@ -975,3 +975,96 @@ def test_short_convolution_mixer_compiles_for_a_v5e_at_published_widths(
     assert "tpu_custom_call" not in compiled.as_text()
     # the step's temporaries of one mixer stay far under the chip's memory
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def _qwen3next():
+    """``qwen3next-train-16k``'s configuration, kernels on."""
+    import json
+
+    from benchmark.drivers import lm_train_delta
+
+    class _Cell:
+        with open(os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "benchmark", "configs",
+                "qwen3-next-80b-a3b-ep16.json")) as f:
+            config = json.load(f)
+
+    return lm_train_delta._model_config(_Cell)._replace(
+        attn="flash", expert_kernel="pallas")
+
+
+def test_attention_at_heads_of_256_in_groups_of_eight_compiles_for_a_v5e(
+        one_chip, monkeypatch):
+    """``models/gqa_moe.gqa`` under ``models/qwen3_next.py``'s switches (q/k
+    norms, the gate, rotary over the first 64 of a head) as
+    ``qwen3next-train-16k`` calls it: 16 query heads of 256 over 2
+    key-value heads, one sequence of 16,384 positions at 512 x 1,024
+    blocks: the dK-with-dV kernel holds a group's 8 query heads against one
+    k block in VMEM."""
+    from multiverso_tpu.models import mla_moe
+    from multiverso_tpu.ops import attention_kernels
+
+    # the process's devices are the CPU's: the kernels would be interpreted
+    monkeypatch.setattr(attention_kernels, "_resolve_interpret",
+                        lambda interpret: False)
+    cfg = _qwen3next()
+    assert mla_moe.attn_blocks(cfg, 16384) == (512, 1024)
+    assert (cfg.kv_group, cfg.head_size, cfg.rope_dim) == (8, 256, 64)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {n: f32(*s) for n, s in cfg.attn_shapes("full").items()}
+
+    def attend(u, p):
+        return cfg.attend(u, p, "full").sum()
+
+    text = jax.jit(jax.grad(attend, argnums=(0, 1))).lower(
+        f32(1, 16384, 2048), p).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3       # forward, dQ, dK with dV
+    assert "bf16[2,16384,256]" in text and "bf16[16,16384,256]" in text
+
+
+def test_softmax_route_over_512_with_32_small_groups_compiles_for_a_v5e(
+        one_chip):
+    """The expert layer as ``qwen3next-train-16k`` calls it: a softmax
+    route over 512 outputs, 10 a token, 32 held experts of 2,048 x 512 that
+    see 320 rows each of a 20,480-row buffer (under a row tile of 512), a
+    gated shared expert beside them, forward and backward."""
+    from multiverso_tpu.models import mla_moe
+    from multiverso_tpu.parallel import moe
+
+    cfg = _qwen3next()
+    held = mla_moe.held(cfg, 16384)
+    assert (held.tile, held.buffer_rows, held.num_experts, held.top_k) == (
+        (512, 512, 512), 20480, 512, 10)
+    assert moe.even_rows(held, 16384) == 10240
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {n: f32(*mla_moe.table_shape(s))
+         for n, s in mla_moe._ffn_shapes(cfg, "shared+experts").items()}
+    assert p["sgate"].shape == (2048,) and p["eg"].shape == (32 * 2048, 512)
+
+    def ffn(u, p):
+        out, (counts, overflow, balance) = mla_moe.expert_ffn(u, p, None, cfg)
+        return out.sum() + balance, (counts, overflow)
+
+    compiled = jax.jit(jax.grad(ffn, argnums=(0, 1), has_aux=True)).lower(
+        f32(1, 16384, 2048), p).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 9
+
+
+def test_the_delta_mixer_compiles_for_a_v5e_at_16k_positions(one_chip):
+    """``models/qwen3_next.gated_delta_net`` as ``qwen3next-train-16k``
+    calls it (16 key and 32 value heads of 128, chunks of 64, one sequence
+    of 16,384 positions), forward and backward: the mixer's temporaries
+    stay under 4 GB (with its float32 stages kept for the backward pass
+    they were 7.0 GB, and the cell's tables and gradients take 10 of the
+    chip's 16)."""
+    cfg = _qwen3next()
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {n: f32(*s) for n, s in cfg.attn_shapes("delta").items()}
+
+    def mix(u, p):
+        return cfg.attend(u, p, "delta").sum()
+
+    compiled = jax.jit(jax.grad(mix, argnums=(0, 1))).lower(
+        f32(1, 16384, 2048), p).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+    assert "while" in compiled.as_text()        # the scan over chunks

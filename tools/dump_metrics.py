@@ -827,7 +827,10 @@ def _mixer_lines(events: List[Dict]) -> List[str]:
     pass of the whole step (``conv.mixer_flops_share``'s two counts);
     where some attend under a learned selection, a head's positions
     selected over those it sees causally (``sparse.selected_share``) and
-    those the kernels compute over the selected."""
+    those the kernels compute over the selected; where some are delta-rule
+    mixers (``mv.lm.delta*`` in the table above), their scan's counts and
+    their part of a token's forward operations
+    (``delta.mixer_flops_share``'s two counts)."""
     args = next((e["args"] for e in events if e.get("name") == "lm.step"
                  and "block_kinds" in e.get("args", {})), None)
     if args is None:
@@ -850,6 +853,15 @@ def _mixer_lines(events: List[Dict]) -> List[str]:
             f"taps, {mixers} of {step} forward operations a token = "
             f"{100.0 * mixers / step:.2f}%"
             + ("; tied head" if args.get("tied_head") else ""))
+    if "delta_flops_token" in args and args.get("step_flops_token"):
+        mixers, step = args["delta_flops_token"], args["step_flops_token"]
+        out.append(
+            f"    delta mixers: {args['delta_layers']} of "
+            f"{args['delta_heads']} value heads, {args['delta_chunks']} "
+            f"chunks of {args['delta_chunk']} = {args['delta_steps']} "
+            f"dependent scan steps a group, a state of "
+            f"{args['delta_state']} floats a head; {mixers} of {step} "
+            f"forward operations a token = {100.0 * mixers / step:.2f}%")
     return out
 
 
