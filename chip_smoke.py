@@ -1349,10 +1349,72 @@ DELTA_CALLS = (("solve", 64, 2), ("halves", 64, 2), ("doubling", 64, 2),
                ("solve", 64, 16), ("halves", 128, 8))
 
 
+def _delta_block(positions: int, dim: int, key_heads: int, value_heads: int,
+                 head_dim: int, chunk: int, repeats: int) -> Dict[str, Any]:
+    """ONE delta-rule mixer's block (input norm, mixer, residual; no
+    feed-forward) of ``models/qwen3_next.Qwen3NextConfig``, forward with
+    every gradient under ``models/mla_moe._run_block`` as a training step
+    rematerialises it, with what the configuration's blocks keep by name
+    (``kept``: the rule's result, ``qwen3_next.KEPT_NAMES``) and with that
+    name out of the policy (``bare``): the ms a call, the compiler's
+    temporaries, and the compiled program's loops under
+    ``mv.lm.delta.rule`` (a group's scan and the groups' map, forward and
+    in each pass that makes them again: a third fewer where the rule's
+    result is kept would say the block made again runs the rule no more).
+    The two must give the same gradients to the bit."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import mla_moe, qwen3_next
+
+    kept = qwen3_next.Qwen3NextConfig(
+        dim=dim, n_layers=1, full_every=2, lin_key_heads=key_heads,
+        lin_value_heads=value_heads, lin_key_dim=head_dim,
+        lin_value_dim=head_dim, delta_chunk=chunk)
+    bare = type("Bare", (qwen3_next.Qwen3NextConfig,),
+                {"kept_names": ()})(*kept)
+    layer = mla_moe.Layer("L0", "delta", None)
+    p = mla_moe._sub(mla_moe.init(kept, SEED, 0.02, {"conv_w": 0.3}), "L0")
+    p = {n: p[n] for n in kept.attn_shapes("delta")}
+    keys = jax.random.split(jax.random.key(SEED), 2)
+    x = jax.random.normal(keys[0], (1, positions, dim))
+    weight = jax.random.normal(keys[1], x.shape)
+    facts: Dict[str, Any] = {}
+    grads = {}
+    for name, cfg in (("kept", kept), ("bare", bare)):
+        # the weights are an ARGUMENT, as ``stage_delta``'s
+        def loss(x, p, weight, cfg=cfg):
+            return jnp.sum(mla_moe._run_block(x, p, layer, None, cfg)[0]
+                           * weight)
+
+        compiled = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+            x, p, weight).compile()
+        grads[name] = jax.block_until_ready(compiled(x, p, weight))
+        t0 = time.perf_counter()
+        for _ in range(repeats):
+            res = compiled(x, p, weight)
+        jax.block_until_ready(res)
+        facts[f"block_{name}_ms"] = round(
+            (time.perf_counter() - t0) / repeats * 1e3, 3)
+        facts[f"block_{name}_temp_gb"] = round(
+            compiled.memory_analysis().temp_size_in_bytes / 1e9, 3)
+        facts[f"block_{name}_rule_loops"] = sum(
+            " while(" in line and "mv.lm.delta.rule" in line
+            for line in compiled.as_text().splitlines())
+    facts["block_kept_bytes"] = kept.kept_bytes(1, positions)
+    if not all(bool(jnp.all(a == b)) and bool(jnp.all(jnp.isfinite(a)))
+               for a, b in zip(jax.tree.leaves(grads["kept"]),
+                               jax.tree.leaves(grads["bare"]))):
+        raise AssertionError("delta block: keeping the rule's result moved "
+                             "a gradient, or one is not finite")
+    return facts
+
+
 def stage_delta(positions: int = 16384, key_heads: int = 16,
                 value_heads: int = 32, head_dim: int = 128,
                 calls: Tuple = DELTA_CALLS, repeats: int = 5,
-                check_positions: int = 2048) -> Dict[str, Any]:
+                check_positions: int = 2048, dim: int = 2048
+                ) -> Dict[str, Any]:
     """``ops/delta_rule.gated_delta_chunked`` as ``qwen3next-train-16k``
     calls it (one sequence of ``positions``, bfloat16 operands), for each
     of ``calls`` (a way of making ``T``, a chunk, the key heads a group):
@@ -1365,7 +1427,10 @@ def stage_delta(positions: int = 16384, key_heads: int = 16,
     among the calls, with float32 operands and with bfloat16 ones. Inputs
     are drawn as the mixer makes them: unit keys, scaled unit queries, ``v``
     a silu of unit normals, ``beta`` a sigmoid, ``g = -A softplus(a + 1)``
-    with ``A`` uniform in (0, 16), the slowest head at 1e-3."""
+    with ``A`` uniform in (0, 16), the slowest head at 1e-3. Before the
+    checks, the whole mixer's block of width ``dim`` at the first call's
+    chunk as a step rematerialises it, with the rule's result kept by
+    name and without (:func:`_delta_block`)."""
     import jax
     import jax.numpy as jnp
 
@@ -1408,6 +1473,8 @@ def stage_delta(positions: int = 16384, key_heads: int = 16,
                                  (weight,) + args)):
             facts[f"{tag}_{name}_compile_s"], facts[f"{tag}_{name}_ms"], _ = (
                 _timed(fn, given, repeats))
+    facts.update(_delta_block(positions, dim, key_heads, value_heads,
+                              head_dim, calls[0][1], repeats))
     _say("delta.timed", **facts)    # a failed check below keeps the readings
 
     # one key head and its value heads against the recurrence

@@ -43,10 +43,14 @@ Scores, selection and term are made ``index_chunk`` query rows at a time
 (``lax.map`` / ``lax.scan``), so that no [S, S] float32 array outlives a
 chunk: at 16,384 positions a chunk of 512 rows is 32 MB of scores, and the
 32 heads' probabilities of the term 1 GB. The rematerialised block keeps
-nothing of the selection by name: a remade forward is consistent with its
-own backward (:func:`selection_remade` counts the rows that differ). It
-keeps the term's gradients to the indexer's operands (:data:`KEPT_NAMES`),
-which the forward makes beside the term.
+two things by name (:data:`KEPT_NAMES`): the SELECTION, int8 [B, S, S] (it
+carries no gradient, so nothing of how it was made is read again: the
+block made again runs neither the selection's scores nor its counting
+passes, and the forward kernel, the kernels of the backward pass and the
+term all read ONE selection, the forward's; :func:`selection_remade`
+still counts the rows that a selection made again would choose
+otherwise), and the term's gradients to the indexer's operands, which the
+forward makes beside the term.
 """
 
 from __future__ import annotations
@@ -64,6 +68,11 @@ from multiverso_tpu.ops import index_kernels
 from multiverso_tpu.ops.attention_kernels import causal_pairs, flash_attention
 
 _DEAD = -1e30
+# what a rematerialised block keeps of a sparse layer
+# (``mla_moe.kept_names``): the term's gradients to the indexer's operands
+# and the selection
+KEPT_NAMES = KEEP_GRADS, KEEP_SELECTION = (
+    "mv.lm.attn.target.grads", "mv.lm.attn.select.chosen")
 
 
 class KeyeMoEConfig(NamedTuple):
@@ -165,7 +174,16 @@ class KeyeMoEConfig(NamedTuple):
     route = "softmax"                # parallel/moe.HeldExperts.route
     routed_scale = 1.0               # the gates sum to 1
     expert_form = "gated_silu"       # parallel/moe.HeldExperts.form
-    kept_names = ("mv.lm.attn.target.grads",)    # ``mla_moe.kept_names``
+    kept_names = KEPT_NAMES          # ``mla_moe.kept_names``
+
+    def kept_bytes(self, b: int, s: int) -> int:
+        """What :data:`KEPT_NAMES` keeps a step of ``b`` sequences of ``s``
+        positions (``mla_moe.kept_grid``): ``index_grid``'s
+        ``target_kept_bytes`` and a layer's ``select_bytes``, every
+        layer."""
+        grid = self.index_grid(s)
+        return b * (grid["target_kept_bytes"]
+                    + len(self.layers()) * grid["select_bytes"])
 
     @property
     def head_size(self) -> int:
@@ -306,11 +324,6 @@ def selection(qi, ki, w, cfg):
 # ---------------------------------------------------------------------- #
 # the indexer's term in the loss
 # ---------------------------------------------------------------------- #
-# what a rematerialised block keeps of the term: its gradients to the
-# indexer's operands (``mla_moe.kept_names``)
-KEPT_NAMES = ("mv.lm.attn.target.grads",)
-
-
 def _kl_chunks(qi, ki, w, q, k, chosen, cfg, lse=None):
     """The scan under :func:`index_loss`, ``index_chunk`` query rows at a
     time: the query heads' scores of the chunk over every key, their
@@ -384,7 +397,7 @@ def index_loss(qi, ki, w, q, k, chosen, cfg, lse=None):
 
     Term and gradients are made ONCE, together, from constants (a chunk's
     probabilities exist once, as ``mla_moe._chunked_ce``'s logits do), the
-    gradients are named (:data:`KEPT_NAMES`: a rematerialised block keeps
+    gradients are named (:data:`KEEP_GRADS`: a rematerialised block keeps
     them, a float32 for every element of qI, kI and w, and so makes the
     query heads' scores no second time), and the term is handed on as
     itself plus, for each
@@ -396,7 +409,7 @@ def index_loss(qi, ki, w, q, k, chosen, cfg, lse=None):
             still(qi), still(ki), still(w), still(q), still(k), chosen, cfg,
             None if lse is None else still(lse))
         for x, g in zip((qi, ki, w), grads):
-            g = checkpoint_name(still(g), KEPT_NAMES[0])
+            g = checkpoint_name(still(g), KEEP_GRADS)
             term = term + jnp.sum(g * (x - still(x)))
         return term
 
@@ -407,12 +420,14 @@ def index_loss(qi, ki, w, q, k, chosen, cfg, lse=None):
 def sparse_gqa(u, p, cfg):
     """Grouped-query attention over the selected keys on the normed input
     ``u`` [B, S, D] -> ([B, S, D] float32, the indexer's term). The
-    indexer reads ``stop_gradient(u)``."""
+    indexer reads ``stop_gradient(u)``. The selection is named
+    (:data:`KEEP_SELECTION`): a rematerialised block keeps it, and its
+    backward pass reads the forward's."""
     s = u.shape[1]
     with jax.named_scope("mv.lm.attn"):
         q, k, v = gqa_moe.heads_of(u, p, cfg, "sparse")
         qi, ki, w = index_operands(jax.lax.stop_gradient(u), p, cfg)
-        chosen = selection(qi, ki, w, cfg)
+        chosen = checkpoint_name(selection(qi, ki, w, cfg), KEEP_SELECTION)
         with jax.named_scope("mv.lm.attn.sparse"):
             if mla_moe.attn_core(cfg) == "flash":
                 o, lse = flash_attention(q, k, v, True,

@@ -735,8 +735,11 @@ def kept_names(cfg) -> Tuple[str, ...]:
     configuration says ``keeps_products = False`` (``NemotronHConfig``
     alone: a kept result is reserved with the step's program, and
     ``nemotron3n-train-16k`` has no room). And what the configuration's
-    own mixer names beside them (``cfg.kept_names``: ``KeyeMoEConfig``'s
-    gradients of its indexer's term)."""
+    own mixer names beside them (``cfg.kept_names``): a value whose
+    backward pass needs nothing of how it was made. ``KeyeMoEConfig``'s
+    gradients of its indexer's term and its selection (no gradient), and
+    ``Qwen3NextConfig``'s result of the delta rule (its groups are
+    rematerialised by themselves from their inputs)."""
     return (moe.KEPT_NAMES if getattr(cfg, "keeps_products", True)
             else ()) + tuple(getattr(cfg, "kept_names", ()))
 
@@ -745,9 +748,9 @@ def _run_block(x, p, layer: Layer, bias, cfg, remat: bool = True):
     """A block of ``layer``'s kinds, rematerialised unless told not to:
     the backward pass makes the block again but for the results that
     :func:`kept_names` names (an expert layer's grouped products into the
-    experts' width, its sort and its route's choice; a block that meets
-    no such name is made again whole). ``bias`` is its router's (a dense
-    layer has none)."""
+    experts' width, its sort and its route's choice, and what the
+    configuration's mixer names; a block that meets no such name is made
+    again whole). ``bias`` is its router's (a dense layer has none)."""
     attn = (None if layer.attn is None
             else lambda u, q: cfg.attend(u, q, layer.attn))
     if layer.ffn is None:
@@ -838,23 +841,31 @@ def loss_grid(cfg, tokens: int) -> Dict[str, Any]:
             "loss_chunks": tokens // min(cfg.loss_chunk, tokens)}
 
 
-def kept_grid(cfg, tokens: int) -> Dict[str, int]:
-    """What the rematerialised blocks of a training step over ``tokens``
-    positions keep by name (:func:`kept_names`), as ``lm.step`` spans
-    carry it: ``expert_products_kept``, the grouped products' results
-    (those into the experts' width: two an expert layer, one of the
-    ``relu2`` form), and ``kept_bytes``, theirs ([rows, ffn] each), the
-    sorted buffers' row orders' (int32 [rows]) and the routes' choices'
-    (int32 [tokens, top_k]), from the shapes."""
-    if not kept_names(cfg):
-        return {"expert_products_kept": 0, "kept_bytes": 0}
-    here = held(cfg, tokens)
-    rows, layers = moe.buffer_length(here, tokens), len(expert_layers(cfg))
-    products = 1 + (cfg.expert_form == "gated_silu")
-    item = jnp.dtype(here.dtype).itemsize
-    return {"expert_products_kept": layers * products,
-            "kept_bytes": layers * (rows * (products * cfg.moe_ffn * item + 4)
-                                    + 4 * tokens * cfg.top_k)}
+def kept_grid(cfg, batch: int, positions: int) -> Dict[str, int]:
+    """What the rematerialised blocks of a training step over ``batch``
+    sequences of ``positions`` keep by name, as ``lm.step`` spans carry
+    it: ``kept_names``, how many names the blocks' policy holds
+    (:func:`kept_names`); ``expert_products_kept``, the grouped products'
+    results (those into the experts' width: two an expert layer, one of
+    the ``relu2`` form); and ``kept_bytes``, from the shapes: theirs
+    ([rows, ffn] each), the sorted buffers' row orders' (int32 [rows]),
+    the routes' choices' (int32 [tokens, top_k]) and what the
+    configuration's own names keep (``cfg.kept_bytes``)."""
+    out = {"kept_names": len(kept_names(cfg)), "expert_products_kept": 0,
+           "kept_bytes": 0}
+    if getattr(cfg, "keeps_products", True):
+        tokens = batch * positions
+        here = held(cfg, tokens)
+        rows, layers = moe.buffer_length(here, tokens), len(expert_layers(cfg))
+        products = 1 + (cfg.expert_form == "gated_silu")
+        item = jnp.dtype(here.dtype).itemsize
+        out.update(
+            expert_products_kept=layers * products,
+            kept_bytes=layers * (rows * (products * cfg.moe_ffn * item + 4)
+                                 + 4 * tokens * cfg.top_k))
+    if getattr(cfg, "kept_names", ()):
+        out["kept_bytes"] += cfg.kept_bytes(batch, positions)
+    return out
 
 
 def _embed(params, tokens, cfg):
@@ -1124,7 +1135,7 @@ class Trainer:
                         attn_grid(self.cfg, positions),
                         **mixer_grid(self.cfg, positions),
                         **loss_grid(self.cfg, count),
-                        **kept_grid(self.cfg, count))
+                        **kept_grid(self.cfg, *tokens.shape))
                 sp.set(tokens=count)
                 t0_ns = time.time_ns()
                 self.states, self.bias, *back = self._step(

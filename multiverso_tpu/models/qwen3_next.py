@@ -45,10 +45,15 @@ from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from multiverso_tpu.models import gqa_moe, mla_moe
 from multiverso_tpu.models.mla_moe import Layer
 from multiverso_tpu.ops.delta_rule import gated_delta_chunked
+
+# what a rematerialised block keeps of a linear-attention mixer: the rule's
+# result, float32 [B, S, Hv, dv] (``mla_moe.kept_names``)
+KEPT_NAMES = ("mv.lm.delta.rule.out",)
 
 
 class Qwen3NextConfig(NamedTuple):
@@ -146,6 +151,14 @@ class Qwen3NextConfig(NamedTuple):
     route = "softmax"                # parallel/moe.HeldExperts.route
     routed_scale = 1.0               # the gates sum to 1
     expert_form = "gated_silu"       # parallel/moe.HeldExperts.form
+    kept_names = KEPT_NAMES          # ``mla_moe.kept_names``
+
+    def kept_bytes(self, b: int, s: int) -> int:
+        """What :data:`KEPT_NAMES` keeps a step of ``b`` sequences of ``s``
+        positions (``mla_moe.kept_grid``): a float32 for every element of
+        a value head, every delta layer."""
+        deltas = sum(layer.attn == "delta" for layer in self.layers())
+        return deltas * 4 * b * s * self.lin_value_heads * self.lin_value_dim
 
     @property
     def head_size(self) -> int:
@@ -201,7 +214,13 @@ def gated_delta_net(u, p, cfg):
     rematerialised in the backward pass, as the rule's groups are: the
     mixer's float32 arrays of 8,192 to 12,288 columns are 0.5 to 0.8 GB
     each at 16,384 positions, and kept for a backward pass they were 7.0
-    GB of a step that has 16 (a v5e's compiler, PR 56)."""
+    GB of a step that has 16 (a v5e's compiler, PR 56). The rule's RESULT
+    is named (:data:`KEPT_NAMES`) and a rematerialised block keeps it
+    (268 MB a layer at the cell's sizes): the groups' backward pass reads
+    their inputs alone and what follows the rule its value alone, so the
+    block made again does not run the rule: its forward scan runs twice a
+    step, in the forward pass and where each group is made again for its
+    own backward pass."""
     b, s, _ = u.shape
     hk, hv = cfg.lin_key_heads, cfg.lin_value_heads
     dk, dv, dt_ = cfg.lin_key_dim, cfg.lin_value_dim, cfg.compute_dtype
@@ -238,5 +257,7 @@ def gated_delta_net(u, p, cfg):
         q, k, v, g, beta, z = jax.checkpoint(feed)(
             u, p["wqkvz"], p["wba"], p["conv_w"], p["a_log"], p["dt_bias"])
         with jax.named_scope("mv.lm.delta.rule"):
-            o = gated_delta_chunked(q, k, v, g, beta, cfg.delta_chunk, dt_)
+            o = checkpoint_name(
+                gated_delta_chunked(q, k, v, g, beta, cfg.delta_chunk, dt_),
+                KEPT_NAMES[0])
         return jax.checkpoint(close)(o, z, p["gate_norm"], p["wout"])

@@ -171,7 +171,13 @@ def test_delta_stage():
              ("halves", 32, 4))
     facts = chip_smoke.stage_delta(positions=128, key_heads=2, value_heads=4,
                                    head_dim=16, calls=calls, repeats=1,
-                                   check_positions=64)
+                                   check_positions=64, dim=32)
+    # the mixer's block as a step rematerialises it, the rule's result
+    # kept by name and not: a float32 for every element of a value head
+    for name in ("kept", "bare"):
+        assert facts[f"block_{name}_ms"] > 0
+        assert facts[f"block_{name}_temp_gb"] >= 0
+    assert facts["block_kept_bytes"] == 4 * 128 * 4 * 16
     for tag in ("halves_q16_k2", "solve_q16_k2", "doubling_q16_k1",
                 "halves_q32_k2"):
         for name in ("fwd", "fwd_bwd"):

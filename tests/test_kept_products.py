@@ -11,8 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from multiverso_tpu.models import (afmoe, gqa_moe, lfm2_moe, mla_moe,
-                                   nemotron_h)
+from multiverso_tpu.models import (afmoe, gqa_moe, keye_moe, lfm2_moe,
+                                   mla_moe, nemotron_h, qwen3_next)
 from multiverso_tpu.parallel import moe
 
 FORMS = {"gated_silu": 3, "relu2": 2}       # an expert's matrices
@@ -231,33 +231,42 @@ def _named(jaxpr, found):
 TINY = {"glm": mla_moe.MLAMoEConfig(), "mellum2": gqa_moe.GQAMoEConfig(),
         "trinity": afmoe.AFMoEConfig(),
         "nemotron": nemotron_h.NemotronHConfig(),
-        "lfm2": lfm2_moe.LFM2MoEConfig()}
+        "lfm2": lfm2_moe.LFM2MoEConfig(), "keye": keye_moe.KeyeMoEConfig(),
+        "qwen3next": qwen3_next.Qwen3NextConfig()}
+# what a configuration's own mixer names beside the expert layers' results
+OWN = {"keye": keye_moe.KEPT_NAMES, "qwen3next": qwen3_next.KEPT_NAMES}
 
 
 @pytest.mark.parametrize("model", sorted(TINY))
 def test_kept_grid_counts_what_the_loss_names(model):
-    """``lm.step``'s ``expert_products_kept`` and ``kept_bytes`` are the
-    results the traced loss names and the configuration keeps: their
-    count and their bytes (``NemotronHConfig`` keeps none: no room)."""
+    """``lm.step``'s ``kept_names``, ``expert_products_kept`` and
+    ``kept_bytes`` are the results the traced loss names and the
+    configuration keeps: the names' count, the products' count and the
+    bytes of all of them (``NemotronHConfig`` keeps none: no room; Keye
+    keeps its term's gradients and its selection, Qwen3-Next the delta
+    rule's result)."""
     cfg = TINY[model]._replace(attn="xla", expert_kernel="xla")
-    names = mla_moe.kept_names(cfg)
-    assert names == (() if model == "nemotron" else moe.KEPT_NAMES)
+    names, own = mla_moe.kept_names(cfg), OWN.get(model, ())
+    assert names == (() if model == "nemotron" else moe.KEPT_NAMES + own)
     tokens = jnp.zeros((2, 64), jnp.int32)
     shapes = {n: jax.ShapeDtypeStruct(s, jnp.float32)
               for n, s in mla_moe.param_shapes(cfg).items()}
     named = _named(jax.make_jaxpr(
         lambda p, b, t: mla_moe.loss_fn(p, b, t, cfg)[0])(
             shapes, mla_moe.init_bias(cfg), tokens).jaxpr, [])
-    assert {name for name, _ in named} <= set(moe.KEPT_NAMES)
+    assert set(own) <= {name for name, _ in named} <= set(
+        moe.KEPT_NAMES + own)
     layers = len(mla_moe.expert_layers(cfg))
     matrices = FORMS[cfg.expert_form] - 1       # into the experts' width
     whole = (moe.KEEP_TAKE, moe.KEEP_CHOSEN)        # int32: no product's
-    assert sum(name not in whole for name, _ in named) == layers * matrices
+    products = [aval for name, aval in named if name not in whole + own]
+    assert len(products) == layers * matrices
     assert all(sum(name == n for name, _ in named) == layers for n in whole)
-    assert all(aval.dtype == (jnp.int32 if name in whole
-                              else cfg.compute_dtype)
-               for name, aval in named)
-    kept = [aval for name, aval in named if name in names]
-    assert mla_moe.kept_grid(cfg, tokens.size) == {
-        "expert_products_kept": sum(a.dtype != jnp.int32 for a in kept),
-        "kept_bytes": sum(a.size * a.dtype.itemsize for a in kept)}
+    assert all(aval.dtype == cfg.compute_dtype for aval in products)
+    assert all(aval.dtype == jnp.int32 for name, aval in named
+               if name in whole)
+    kept = [(name, aval) for name, aval in named if name in names]
+    assert mla_moe.kept_grid(cfg, *tokens.shape) == {
+        "kept_names": len(names),
+        "expert_products_kept": (len(products) if names else 0),
+        "kept_bytes": sum(a.size * a.dtype.itemsize for _, a in kept)}

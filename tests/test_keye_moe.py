@@ -229,6 +229,151 @@ def test_a_remade_forward_selects_what_the_forward_selected():
 
 
 # ---------------------------------------------------------------------- #
+# what a rematerialised block keeps of the selection
+# ---------------------------------------------------------------------- #
+SPARSE = mla_moe.Layer("L0", "sparse", "experts")
+
+
+def _without(cfg, *names, **attrs):
+    """``cfg`` with ``names`` taken out of its own ``kept_names`` (and the
+    class attributes given): what its blocks' policy held before."""
+    kept = tuple(n for n in type(cfg).kept_names if n not in names)
+    return type("Without", (type(cfg),), dict(attrs, kept_names=kept))(*cfg)
+
+
+def _count(jaxpr, primitive: str) -> int:
+    """The equations of that primitive in a jaxpr and in every jaxpr its
+    equations hold."""
+    return sum((e.primitive.name == primitive)
+               + sum(_count(sub, primitive)
+                     for sub in jax.core.jaxprs_in_params(e.params))
+               for e in jaxpr.eqns)
+
+
+def _bits(tree):
+    return [np.asarray(a).view(np.uint32) for a in jax.tree.leaves(tree)]
+
+
+def _sparse_block(cfg):
+    """One sparse block's loss (with both of the loss's terms) over its
+    input and parameters, and both."""
+    x, p = _layer_inputs(cfg)
+    weight = jax.random.normal(jax.random.key(12), x.shape)
+
+    def loss(cfg, remat=True):
+        def run(x, p):
+            y, (_, _, balance, term) = mla_moe._run_block(
+                x, p, SPARSE, None, cfg, remat=remat)
+            return (jnp.sum(y * weight) + cfg.balance_coef * balance
+                    + cfg.index_coef * term)
+        return run
+    return loss, x, p
+
+
+def test_a_remade_block_makes_no_selection_again():
+    """The step's gradient: a layer's selection (the chunks' ``lax.map``,
+    a chunk's 32 counting passes, the cut of the ties) stands once and
+    not twice; with the name out of the policy the block made again makes
+    it again. The block's residuals hold the int8 [B, S, S] array."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    params, bias, tokens = _inputs(CFG)
+    layers = len(CFG.layers())
+    assert mla_moe.kept_names(CFG) == (mla_moe.moe.KEPT_NAMES
+                                       + keye_moe.KEPT_NAMES)
+    assert keye_moe.KEPT_NAMES == (keye_moe.KEEP_GRADS,
+                                   keye_moe.KEEP_SELECTION)
+    bare = _without(CFG, keye_moe.KEEP_SELECTION)
+    assert mla_moe.kept_names(bare)[-1] == keye_moe.KEEP_GRADS
+    counts = {}
+    for name, cfg in (("kept", CFG), ("bare", bare)):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p: mla_moe.loss_fn(p, bias, tokens, cfg)[0]))(params).jaxpr
+        counts[name] = {k: _count(jaxpr, k) for k in ("scan", "cond")}
+    # the map over the chunks and the threshold's passes, both ``scan``s
+    assert counts["kept"]["cond"] == layers
+    assert counts["bare"]["cond"] == 2 * layers
+    assert counts["bare"]["scan"] - counts["kept"]["scan"] == 3 * layers
+    loss, x, p = _sparse_block(CFG)
+    chosen = jax.core.ShapedArray((2, 64, 64), jnp.int8)
+    kept = lambda cfg: [why for aval, why in saved_residuals(loss(cfg), x, p)
+                        if aval == chosen]
+    assert len(kept(CFG)) == 1 and keye_moe.KEEP_SELECTION in kept(CFG)[0]
+    assert kept(bare) == []
+
+
+def test_keeping_the_selection_moves_no_gradient_by_a_bit():
+    """Every table's float32 gradient with the selection kept is the
+    gradient with the name out of the policy, and a sparse block's is the
+    un-rematerialised block's, bit for bit."""
+    params, bias, tokens = _inputs(CFG)
+    grads = lambda cfg: jax.jit(jax.value_and_grad(
+        lambda p: mla_moe.loss_fn(p, bias, tokens, cfg)[0]))(params)
+    got, bare = grads(CFG), grads(_without(CFG, keye_moe.KEEP_SELECTION))
+    assert set(got[1]) == set(mla_moe.param_shapes(CFG))
+    for n in got[1]:
+        assert float(jnp.abs(got[1][n]).max()) > 0, n
+    for a, b in zip(_bits(got), _bits(bare)):
+        np.testing.assert_array_equal(a, b)
+    loss, x, p = _sparse_block(CFG)
+    kept, still = (jax.jit(jax.value_and_grad(loss(CFG, remat), (0, 1)))(x, p)
+                   for remat in (True, False))
+    for a, b in zip(_bits(kept), _bits(still)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_backward_pass_reads_the_forwards_selection(monkeypatch):
+    """Poison: the selection among the residuals of a sparse block's
+    forward pass (one int8 array [B, S, S]; none without the name) is
+    swapped for every causal key before the backward pass runs. The core's
+    and the projections' gradients are then those of a block that selects
+    every causal key (its backward pass read the kept array), and the
+    indexer's are the unpoisoned block's: the term's kept gradients are
+    the forward's."""
+    # no expert product kept, so that what is made again follows the poison
+    cfg = _without(CFG, keeps_products=False)
+    assert mla_moe.kept_names(cfg) == keye_moe.KEPT_NAMES
+    loss, x, p = _sparse_block(cfg)
+    is_chosen = lambda a: (getattr(a, "shape", None) == (2, 64, 64)
+                           and a.dtype == jnp.int8)
+    _, back = jax.vjp(loss(cfg), x, p)
+    leaves, tree = jax.tree.flatten(back)
+    assert sum(map(is_chosen, leaves)) == 1
+    bare = jax.vjp(loss(_without(cfg, keye_moe.KEEP_SELECTION)), x, p)[1]
+    assert not any(map(is_chosen, jax.tree.leaves(bare)))
+    every = jnp.broadcast_to(jnp.tril(jnp.ones((64, 64), jnp.int8)),
+                             (2, 64, 64))
+    clean = back(jnp.ones(()))
+    poisoned = jax.tree.unflatten(
+        tree, [every if is_chosen(a) else a for a in leaves])(jnp.ones(()))
+    monkeypatch.setattr(keye_moe, "selection", lambda qi, ki, w, cfg: every)
+    want = jax.vjp(loss(cfg), x, p)[1](jnp.ones(()))
+    np.testing.assert_array_equal(*_bits((poisoned[0], want[0])))
+    for n in sorted(p):
+        other = clean if n in INDEXER else want
+        np.testing.assert_array_equal(
+            *_bits((poisoned[1][n], other[1][n])), err_msg=n)
+        if n in ("wq", "wk", "wv", "wo", "wq_i", "wk_i", "ww_i"):
+            assert (np.asarray(clean[1][n]) != np.asarray(want[1][n])).any()
+
+
+def test_the_steps_span_counts_what_the_sparse_layers_keep():
+    """``lm.step``'s ``kept_names`` and ``kept_bytes``: six names, and
+    beside the expert layers' the term's float32 gradients and a layer's
+    int8 selection, every layer, from the shapes."""
+    cfg = _published()
+    grid = mla_moe.kept_grid(cfg, 1, 16384)
+    bare = mla_moe.kept_grid(_without(cfg, *keye_moe.KEPT_NAMES), 1, 16384)
+    assert (grid["kept_names"], bare["kept_names"]) == (6, 4)
+    index = cfg.index_grid(16384)
+    assert cfg.kept_bytes(1, 16384) == (
+        index["target_kept_bytes"] + 4 * index["select_bytes"])
+    assert grid["kept_bytes"] - bare["kept_bytes"] == (
+        4 * (4 * 16384 * (16 * 64 + 64 + 16) + 16384 * 16384))
+    assert cfg.kept_bytes(2, 64) == 2 * cfg.kept_bytes(1, 64)
+
+
+# ---------------------------------------------------------------------- #
 # the kernels' selection operand
 # ---------------------------------------------------------------------- #
 def _random_selection(key, b, s, share=0.3):
@@ -446,12 +591,13 @@ def test_the_lowered_step_has_the_indexers_products_and_the_four_scopes(attn):
                                                       cfg)[0]))
     lowered = step.lower(params).as_text()
     assert _products(lowered, cfg.vocab) == 3
-    # the remade blocks keep the term's gradients by name: no pass makes
-    # the query heads' probabilities a second time, and the remade forward
-    # adds the selection's dots alone
+    # the remade blocks keep the term's gradients and the selection by
+    # name: no pass makes the query heads' probabilities a second time,
+    # and the remade forward makes no index score
     assert heads(lowered) == layers
-    assert _products(lowered, 3, 16, 64, 6) == 2 * layers + term_dots
-    assert mla_moe.kept_names(cfg)[-1] == keye_moe.KEPT_NAMES[0]
+    assert _products(lowered, 3, 16, 64, 6) == layers + term_dots
+    assert mla_moe.kept_names(cfg) == (mla_moe.moe.KEPT_NAMES
+                                       + keye_moe.KEPT_NAMES)
     assert mla_moe.kept_names(gqa_moe.GQAMoEConfig()) == (
         mla_moe.moe.KEPT_NAMES)
     # the term alone: its two kernels sit inside ``mv.lm.attn.index`` under

@@ -658,6 +658,147 @@ def test_the_mixers_scopes_are_in_the_lowered_step():
         assert scope in text, scope
 
 
+# ---------------------------------------------------------------------- #
+# what a rematerialised block keeps of the mixer
+# ---------------------------------------------------------------------- #
+DELTA = mla_moe.Layer("L0", "delta", "shared+experts")
+
+
+def _without(cfg, *names, **attrs):
+    """``cfg`` with ``names`` taken out of its own ``kept_names`` (and the
+    class attributes given): what its blocks' policy held before."""
+    kept = tuple(n for n in type(cfg).kept_names if n not in names)
+    return type("Without", (type(cfg),), dict(attrs, kept_names=kept))(*cfg)
+
+
+def _count(jaxpr, primitive: str) -> int:
+    """The equations of that primitive in a jaxpr and in every jaxpr its
+    equations hold."""
+    return sum((e.primitive.name == primitive)
+               + sum(_count(sub, primitive)
+                     for sub in jax.core.jaxprs_in_params(e.params))
+               for e in jaxpr.eqns)
+
+
+def _bits(tree):
+    return [np.asarray(a).view(np.uint32) for a in jax.tree.leaves(tree)]
+
+
+def _delta_block(cfg):
+    """One delta block's loss over its input and parameters, and both."""
+    params, _, _ = _inputs(cfg)
+    p = mla_moe._sub(params, "L0")
+    x = jax.random.normal(jax.random.key(11), (2, 64, cfg.dim))
+    weight = jax.random.normal(jax.random.key(12), x.shape)
+
+    def loss(cfg, remat=True):
+        def run(x, p):
+            y, (_, _, balance) = mla_moe._run_block(x, p, DELTA, None, cfg,
+                                                    remat=remat)
+            return jnp.sum(y * weight) + cfg.balance_coef * balance
+        return run
+    return loss, x, p
+
+
+def test_a_remade_block_runs_the_rules_forward_scans_once_fewer():
+    """The step's gradient: the rule's two ``scan`` equations (the groups'
+    ``lax.map`` and a group's scan over its chunks) and its triangular
+    solve stand once fewer a delta layer than with the name out of the
+    policy: forward and each group made again, and no third time in the
+    block made again. The block's residuals hold the named array."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    params, bias, tokens = _inputs(CFG)
+    deltas = sum(layer.attn == "delta" for layer in CFG.layers())
+    assert mla_moe.kept_names(CFG) == (mla_moe.moe.KEPT_NAMES
+                                       + qwen3_next.KEPT_NAMES)
+    counts = {}
+    for name, cfg in (("kept", CFG),
+                      ("bare", _without(CFG, *qwen3_next.KEPT_NAMES))):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p: mla_moe.loss_fn(p, bias, tokens, cfg)[0]))(params).jaxpr
+        counts[name] = {k: _count(jaxpr, k)
+                        for k in ("scan", "triangular_solve")}
+    assert counts["bare"]["scan"] - counts["kept"]["scan"] == 2 * deltas
+    # a solve forward, made again, and transposed: 3 a layer, 4 before
+    assert counts["kept"]["triangular_solve"] == 3 * deltas
+    assert counts["bare"]["triangular_solve"] == 4 * deltas
+    # a block's residuals: its arguments and what its checkpoint hands
+    # out, of which one array is the rule's result [B, S, Hv, dv]
+    loss, x, p = _delta_block(CFG)
+    o = jax.core.ShapedArray((2, 64, CFG.lin_value_heads, CFG.lin_value_dim),
+                             jnp.float32)
+    kept = lambda cfg: [why for aval, why in saved_residuals(loss(cfg), x, p)
+                        if aval == o]
+    assert len(kept(CFG)) == 1 and "remat" in kept(CFG)[0]
+    assert kept(_without(CFG, *qwen3_next.KEPT_NAMES)) == []
+
+
+def test_keeping_the_rules_result_moves_no_gradient_by_a_bit():
+    """Every table's float32 gradient with the rule's result kept is the
+    gradient with the name out of the policy, and a delta block's is the
+    un-rematerialised block's, bit for bit."""
+    params, bias, tokens = _inputs(CFG)
+    grads = lambda cfg: jax.jit(jax.value_and_grad(
+        lambda p: mla_moe.loss_fn(p, bias, tokens, cfg)[0]))(params)
+    got, bare = grads(CFG), grads(_without(CFG, *qwen3_next.KEPT_NAMES))
+    assert set(got[1]) == set(mla_moe.param_shapes(CFG))
+    for n in got[1]:
+        assert float(jnp.abs(got[1][n]).max()) > 0, n
+    for a, b in zip(_bits(got), _bits(bare)):
+        np.testing.assert_array_equal(a, b)
+    loss, x, p = _delta_block(CFG)
+    kept, still = (jax.jit(jax.value_and_grad(loss(CFG, remat), (0, 1)))(x, p)
+                   for remat in (True, False))
+    for a, b in zip(_bits(kept), _bits(still)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_backward_pass_reads_the_forwards_result_of_the_rule(
+        monkeypatch):
+    """Poison: the rule's result among the residuals of a delta block's
+    forward pass (one array of its shape; none without the name) is
+    swapped for another before the backward pass runs. The gradients are
+    then those of a block whose rule hands ``close`` that other value: the
+    backward pass read the kept array and made the rule's result no second
+    time."""
+    # nothing else kept, so that what is made again follows the poison
+    cfg = _without(CFG, keeps_products=False)
+    assert mla_moe.kept_names(cfg) == qwen3_next.KEPT_NAMES
+    loss, x, p = _delta_block(cfg)
+    shape = (2, 64, cfg.lin_value_heads, cfg.lin_value_dim)
+    is_o = lambda a: getattr(a, "shape", None) == shape
+    _, back = jax.vjp(loss(cfg), x, p)
+    leaves, tree = jax.tree.flatten(back)
+    assert sum(map(is_o, leaves)) == 1
+    bare = jax.vjp(loss(_without(cfg, *qwen3_next.KEPT_NAMES)), x, p)[1]
+    assert not any(map(is_o, jax.tree.leaves(bare)))
+    clean = back(jnp.ones(()))
+    shift = jax.random.normal(jax.random.key(13), shape)
+    poisoned = jax.tree.unflatten(
+        tree, [a + shift if is_o(a) else a for a in leaves])(jnp.ones(()))
+    assert any((a != b).any() for a, b in zip(_bits(poisoned), _bits(clean)))
+    rule = qwen3_next.gated_delta_chunked
+    monkeypatch.setattr(qwen3_next, "gated_delta_chunked",
+                        lambda *a, **kw: rule(*a, **kw) + shift)
+    want = jax.vjp(loss(cfg), x, p)[1](jnp.ones(()))
+    for a, b in zip(_bits(poisoned), _bits(want)):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_the_steps_span_counts_what_the_rule_keeps():
+    """``lm.step``'s ``kept_names`` and ``kept_bytes``: the five names of
+    the blocks' policy, and beside the expert layers' the rule's float32
+    result of every delta layer, from the shapes."""
+    cfg = _cell_config()
+    grid = mla_moe.kept_grid(cfg, 1, 16384)
+    bare = mla_moe.kept_grid(_without(cfg, *qwen3_next.KEPT_NAMES), 1, 16384)
+    assert (grid["kept_names"], bare["kept_names"]) == (5, 4)
+    assert cfg.kept_bytes(1, 16384) == 3 * 4 * 16384 * 32 * 128
+    assert grid["kept_bytes"] - bare["kept_bytes"] == 805_306_368
+    assert grid["expert_products_kept"] == bare["expert_products_kept"] == 8
+
+
 def _cell_config(with_file=False):
     """The cell's configuration as its driver builds it (and the file's
     dictionary)."""
@@ -698,9 +839,15 @@ def test_published_sizes_give_the_configurations_parameter_count():
 # locations, sha256's first 16 digits), made with ``git archive 7b285c9``
 # beside this tree: a tiny configuration of each of the six language-model
 # kinds the benchmark's ten cells run must lower to what it lowered to.
+# ``keye`` is THIS tree's text since PR 57 (the parent's was
+# 281095075d04bf5c): its sparse layers name their selection, which the
+# blocks' policy keeps, so the lowered backward pass makes no selection
+# again. ``qwen3_next`` (no entry) names the delta rule's result in the
+# same PR: ``test_a_remade_block_runs_the_rules_forward_scans_once_fewer``
+# holds what changed there.
 PARENT = {"mla": "1176c1bf3112ad40", "gqa": "f3ca378f315449a4",
           "afmoe": "06ebccb9b2fd87b1", "nemotron_h": "f19145b4231045ba",
-          "lfm2": "d8be808c75a5fa2e", "keye": "281095075d04bf5c"}
+          "lfm2": "d8be808c75a5fa2e", "keye": "6d0bfafb9008b220"}
 MODELS = {"mla": mla_moe.MLAMoEConfig, "gqa": gqa_moe.GQAMoEConfig,
           "afmoe": afmoe.AFMoEConfig, "nemotron_h": nemotron_h.NemotronHConfig,
           "lfm2": lfm2_moe.LFM2MoEConfig, "keye": keye_moe.KeyeMoEConfig}

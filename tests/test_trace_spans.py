@@ -789,7 +789,8 @@ def test_lm_step_carries_its_counts():
     # products' results each over a buffer of 256 rows (48 wide), the
     # buffer's int32 order and the route's int32 choice
     assert step["args"] == dict(want, tokens=64, head_products=6,
-                                loss_chunks=2, expert_products_kept=4,
+                                loss_chunks=2, kept_names=4,
+                                expert_products_kept=4,
                                 kept_bytes=2 * (256 * (2 * 48 * 4 + 4)
                                                 + 64 * cfg.top_k * 4))
     assert want["routed_rows"] == layers * 64 * cfg.top_k
