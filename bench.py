@@ -412,8 +412,8 @@ def bench_we_async(world: int = 4, n_tokens: int = 1_000_000):
             "mode": caches[0]["mode"],
             "rows_per_worker": [c["rows"] for c in caches],
         }
-    # step-profiler evidence (ISSUE 9): the worker profiles its measured
-    # epoch and asserts >= 90% attribution + zero steady recompiles
+    # the steps' evidence (ISSUE 9): the worker reads its measured
+    # epoch's step spans and asserts >= 90% attribution + zero steady recompiles
     # in-run; the record keeps rank 0's per-step phase breakdown as the
     # headline plus the cross-rank stall/attribution spread. bench.main
     # lifts this to extra.profile so run_bench can flag PHASE-level
@@ -1148,7 +1148,7 @@ def main() -> None:
         "flightrec_dumps": flightrec_dumps,
         "memory": memory_stats_rec,
     }
-    # phase-level profile of the WE async measured epoch (step profiler,
+    # phase-level profile of the WE async measured epoch (its step spans,
     # ISSUE 9): first-class extra key so tools/run_bench.py can flag
     # stall-fraction growth and steady-state recompiles run-over-run
     if isinstance(we_async_stats, dict) and we_async_stats.get("profile"):

@@ -39,7 +39,7 @@ from multiverso_tpu.ps.tables import AsyncMatrixTable
 from multiverso_tpu.serving.admission import AdmissionController
 from multiverso_tpu.serving.replica import ReadReplica
 from multiverso_tpu.telemetry import devstats as _devstats
-from multiverso_tpu.telemetry import profiler as _prof
+from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.updaters import AddOption
 
 
@@ -108,23 +108,21 @@ class DLRMServing:
         grad, push row-gradient deltas (blocking — the ack means
         applied). Returns ``(loss, write_ms)``: the write latency is
         the serving bench's protected metric (admission control exists
-        so THIS number survives an inference storm). Profiled as one
-        step (flag ``step_profile``): prepare / ps_wait / compute
-        phases + the table layer's ps.get / ps.add async spans."""
+        so THIS number survives an inference storm). One step span
+        (``trace.step_report``): prepare / ps_wait / compute / push
+        phases, and with ``trace_ids`` on the table layer's
+        send-to-reply spans beside them."""
         import time
-        with _prof.step("dlrm.train_step"):
-            with _prof.phase("prepare"):
+        with _trace.span("dlrm.train_step", step=1):
+            with _trace.span("dlrm.prepare", phase="prepare"):
                 b, f = np.asarray(cat).shape
                 ids = self._ids(cat)
-            with _prof.phase("ps_wait"):
+            with _trace.span("dlrm.pull", phase="ps_wait"):
                 rows = self.emb.get_rows(ids).reshape(
                     b, f, self.cfg.embed_dim)
-            with _prof.phase("compute"):
-                if _prof.enabled():
-                    _prof.watch_jit("dlrm.grad", self._grad)
+            with _trace.span("dlrm.compute", phase="compute"):
                 # pulled rows ride to device through the devstats
-                # chokepoint (per-direction device-plane accounting +
-                # the profiler's per-step transfer delta)
+                # chokepoint (per-direction device-plane accounting)
                 _devstats.note_transfer(rows.nbytes, "h2d")
                 loss, g_mlp, g_rows = self._grad(
                     self.mlp, jnp.asarray(rows), jnp.asarray(dense),
@@ -140,7 +138,7 @@ class DLRMServing:
             # duplicate ids (same user twice in a batch) f64-accumulate
             # in the client's _dedupe_batch — scatter-add semantics,
             # exactly the fused path's .at[].add
-            with _prof.phase("push"):
+            with _trace.span("dlrm.push", phase="push"):
                 self.emb.add_rows(ids, g_host, self._opt)
             return float(loss), (time.perf_counter() - t0) * 1e3
 

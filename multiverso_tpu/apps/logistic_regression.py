@@ -29,6 +29,7 @@ Usage: ``python -m multiverso_tpu.apps.logistic_regression <config file>``
 
 from __future__ import annotations
 
+import contextlib
 import sys
 import time
 from typing import Dict, Optional
@@ -40,7 +41,7 @@ import numpy as np
 import multiverso_tpu as mv
 from multiverso_tpu.io.sample_reader import SampleReader
 from multiverso_tpu.models import logreg as model_lib
-from multiverso_tpu.telemetry import profiler as _prof
+from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.updaters import AddOption
 from multiverso_tpu.utils import config as config_lib
 from multiverso_tpu.utils import log
@@ -211,16 +212,18 @@ class LogReg:
                                   cfg.minibatch_size, fmt=cfg.reader_type)
             batches = (self._sparse_lookahead(reader) if sparse_pipeline
                        else reader)
-            # WE-shaped step bracketing (flag step_profile, no-op
-            # otherwise): each step consumes the CURRENT minibatch and
-            # fetches the NEXT one, so the reader's io_wait phase (and
-            # the producer thread's io.produce intervals) land on the
-            # training step they stalled/overlapped
+            # WE-shaped step bracketing, per minibatch and so behind
+            # trace_ids like every fine site: each step consumes the
+            # CURRENT minibatch and fetches the NEXT one, so the
+            # reader's io_wait phase (and the producer thread's
+            # io.produce intervals) land on the training step they
+            # stalled/overlapped
             batches_it = iter(batches)
             item = next(batches_it, None)
             batch_idx = 0
             while item is not None:
-                with _prof.step("lr.minibatch"):
+                with (_trace.span("lr.minibatch", request=batch_idx, step=1)
+                      if _trace.enabled() else contextlib.nullcontext()):
                     if sparse_pipeline:
                         y_len = len(item["y"])
                         loss = self._train_sparse_prepared(item)

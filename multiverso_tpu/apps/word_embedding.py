@@ -53,7 +53,6 @@ from multiverso_tpu.ops import row_assemble as _rowasm
 from multiverso_tpu.ops import row_combine
 from multiverso_tpu.telemetry import devstats as _devstats
 from multiverso_tpu.telemetry import memstats as _memstats
-from multiverso_tpu.telemetry import profiler as _prof
 from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.utils import config, log
 from multiverso_tpu.tables.matrix_table import _bucket_size
@@ -721,8 +720,9 @@ class WordEmbedding:
                 self._host_negs(1, 1, np.random.default_rng(0))  # build once
             # K-deep ordered producer queue (io/sample_reader): replaces
             # the PR-5 fixed pool — same 2-thread default, but depth and
-            # threads are now the shared we_prepare_* knobs and the
-            # producers report io.produce / the consumer io_wait
+            # threads are now the shared we_prepare_* knobs; the
+            # producers' we.prepare spans and the consumer's wait below
+            # are what the queue leaves in the ring
             with BlockPrepareQueue(
                     list(range(len(schedule))),
                     lambda idx, _i: self._prepare_block_device(
@@ -758,11 +758,14 @@ class WordEmbedding:
                     ) as q:
                 prepared = self._dispatch_pulls(q.next())
                 for i, block in enumerate(schedule):
-                    with _prof.step("we.block"):
+                    with _trace.span("we.step", request=i, step=1):
                         nxt = None
                         if i + 1 < len(schedule):
-                            produced = q.next()   # io_wait-timed
-                            with _prof.phase("we.pipeline"):
+                            with _trace.span("we.block.wait_prepared",
+                                             request=i + 1,
+                                             phase="io_wait"):
+                                produced = q.next()
+                            with _trace.span("we.pipeline"):
                                 nxt = self._dispatch_pulls(produced)
                         loss, counts = self._train_prepared(prepared, nw)
                         losses.append(loss)
@@ -774,11 +777,11 @@ class WordEmbedding:
             # oracle the pipelined path is asserted bit-identical to.
             # Pipeline-fill prepare happens outside any step: steady-
             # state steps each cover ONE (prepare of block N+1, train of
-            # block N) pair — the overlap the profiler exists to measure
+            # block N) pair — the overlap a step's report measures
             prepared = (self._prepare_block(schedule[0], child_rngs[0])
                         if schedule else None)
             for i, block in enumerate(schedule):
-                with _prof.step("we.block"):
+                with _trace.span("we.step", request=i, step=1):
                     nxt = (self._prepare_block(schedule[i + 1],
                                                child_rngs[i + 1])
                            if i + 1 < len(schedule) else None)
@@ -899,7 +902,7 @@ class WordEmbedding:
         so the dispatch point within prepare never changes results)."""
         cfg = self.cfg
         b = cfg.batch_size
-        with _trace.span("we.prepare"):
+        with _trace.span("we.prepare", phase="prepare"):
             prep = self._block_arrays(block, rng)
             n = (prep["examples"].size // b) * b
             if n == 0:
@@ -967,12 +970,11 @@ class WordEmbedding:
 
     def _prepare_block(self, block: np.ndarray, rng) -> Optional[Dict]:
         """Inline host-plane block prep (-pipeline 0, the parity oracle):
-        produce + dispatch on the calling thread, profiled as the step's
-        ``prepare`` phase. Compute is the SAME packed ``lax.scan`` as the
-        device plane — only pull/push differ (table Get/Add over the wire
-        here, in-graph gather/scatter there)."""
-        with _prof.phase("prepare"):
-            return self._produce_block(block, rng, dispatch_early=True)
+        produce + dispatch on the calling thread, the step's ``prepare``
+        phase (``we.prepare``). Compute is the SAME packed ``lax.scan``
+        as the device plane — only pull/push differ (table Get/Add over
+        the wire here, in-graph gather/scatter there)."""
+        return self._produce_block(block, rng, dispatch_early=True)
 
     def _train_prepared(self, prep: Optional[Dict], num_workers: int
                         ) -> Tuple[float, Optional[jax.Array]]:
@@ -996,25 +998,21 @@ class WordEmbedding:
             # ps_wait: the residual of the pulls dispatched during
             # prepare — the part the prefetch overlap did NOT hide.
             # Cache-served blocks (dev_in/dev_sec) already sit on device.
-            with _prof.phase("ps_wait"):
+            with _trace.span("we.block.ps_wait", phase="ps_wait"):
                 rows_in = (None if "dev_in" in prep
                            else self.table_in.wait(prep["pull_in"]))
                 rows_sec = (None if "dev_sec" in prep
                             else sec_t.wait(
                                 prep["pull_hs" if cfg.hs else "pull_out"]))
-            with _prof.phase("compute"):
+            with _trace.span("we.block.compute", phase="compute"):
                 win_l = (prep["dev_in"] if rows_in is None
                          else padded(rows_in, prep["kb"]))
                 wsec_l = (prep["dev_sec"] if rows_sec is None
                           else padded(rows_sec,
                                       prep["hkb"] if cfg.hs
                                       else prep["kb"]))
-                if _prof.enabled():
-                    _prof.watch_jit("we.local_train",
-                                    self._local_train_fn())
-                # batch upload through the devstats chokepoint (feeds
-                # the per-direction device-plane counters AND, when
-                # profiling, the step's transfer_bytes delta)
+                # batch upload through the devstats chokepoint (the
+                # per-direction device-plane counters)
                 _devstats.note_transfer(sum(
                     int(np.asarray(a).nbytes)
                     for a in prep["batch"]), "h2d")
@@ -1023,8 +1021,8 @@ class WordEmbedding:
                     jax.device_put(prep["batch"]))
                 # materialize the deltas HERE: np.asarray is the device
                 # sync, so the scan's runtime lands in `compute`, not in
-                # the push's enqueue accounting (the push itself is an
-                # async ps.add span via the table layer)
+                # the push's enqueue accounting (the push itself is the
+                # client's send-to-reply span, with trace_ids on)
                 d_in = np.asarray(d_in)
                 d_sec = np.asarray(d_sec)
                 _devstats.note_transfer(d_in.nbytes + d_sec.nbytes, "d2h")

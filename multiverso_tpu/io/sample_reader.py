@@ -40,7 +40,7 @@ from typing import (IO, Any, Callable, Iterator, Optional, Sequence, Set,
 import numpy as np
 
 from multiverso_tpu.io.stream import TextReader, open_stream
-from multiverso_tpu.telemetry import profiler as _prof
+from multiverso_tpu.telemetry import trace as _trace
 
 FORMATS = ("libsvm", "dense", "weight", "weight_dense", "bsparse")
 
@@ -121,10 +121,10 @@ class BlockPrepareQueue:
     :meth:`next` yields results strictly IN ORDER — so a pure ``fn`` gives
     bit-identical results to calling it inline, regardless of thread
     scheduling. Generalizes this module's single-reader ring (SampleReader)
-    to N producers with ordered delivery; the same profiler contract
-    applies: each production interval lands as an ``io.produce`` async span
-    attached to whichever step it overlapped (``attach="any"``), and the
-    consumer's blocked time is the ``io_wait`` phase of ITS step.
+    to N producers with ordered delivery. The queue knows no telemetry:
+    ``fn`` records its own span on the producer's thread and the caller
+    wraps :meth:`next` in one (``apps/word_embedding.py``:
+    ``we.prepare``, ``we.block.wait_prepared``).
 
     A producer exception is delivered at the corresponding :meth:`next`
     call (order preserved) and ends the queue. ``close()`` releases the
@@ -163,37 +163,32 @@ class BlockPrepareQueue:
                     return
                 i = self._next_claim
                 self._next_claim += 1
-            t0 = time.time()
             try:
                 out = ("ok", self._fn(self._items[i], i))
             except BaseException as e:   # noqa: BLE001 — delivered in
                 out = ("err", e)         # order at the consumer's next()
-            t1 = time.time()
             with self._cond:
                 if self._closed:   # closed mid-produce: drop the payload
                     return         # (close() already purged _results)
                 self._results[i] = out
                 self._cond.notify_all()
-            if _prof.enabled():
-                _prof.note_async("io.produce", t0, t1, attach="any")
 
     def next(self) -> Any:
-        """The next result in submission order (io_wait-timed when the
+        """The next result in submission order (blocks while the
         producers are behind). Raises StopIteration past the last item,
         or the producer's exception for THIS index."""
         i = self._next_emit
         if i >= len(self._items):
             raise StopIteration
-        with _prof.phase("io_wait"):
-            with self._cond:
-                while i not in self._results and not self._closed:
-                    self._cond.wait()
-                if i not in self._results:
-                    raise RuntimeError("BlockPrepareQueue closed while "
-                                       f"item {i} was pending")
-                kind, payload = self._results.pop(i)
-                self._next_emit = i + 1
-                self._cond.notify_all()
+        with self._cond:
+            while i not in self._results and not self._closed:
+                self._cond.wait()
+            if i not in self._results:
+                raise RuntimeError("BlockPrepareQueue closed while "
+                                   f"item {i} was pending")
+            kind, payload = self._results.pop(i)
+            self._next_emit = i + 1
+            self._cond.notify_all()
         if kind == "err":
             self.close()
             raise payload
@@ -305,22 +300,23 @@ class SampleReader:
         # idle — inverting the diagnosis
         t_done = time.time()
         self._queue.put((X, y, k))
-        # step profiler: the producer thread holds no step of its own,
-        # so its per-batch parse+assemble interval attaches to the
-        # process's current step ("any") — which is how input-pipeline
-        # work shows up on the timeline of the training step it
-        # overlapped (or stalled)
-        if t_batch0 is not None and _prof.enabled():
-            _prof.note_async("io.produce", t_batch0, t_done,
-                             attach="any")
+        # per batch, so a FINE span (trace_ids): the producer thread is
+        # under no step, so a step's report counts the interval beside
+        # the training step it overlapped (or stalled)
+        if t_batch0 is not None and _trace.enabled():
+            _trace.add_span("io.produce", t_batch0, t_done, cat="io")
 
     def __iter__(self) -> Iterator[Tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]]:
         while True:
             # io_wait: time the CONSUMER (the training step's thread)
             # blocked on the producer — the "input pipeline is the
-            # critical path" phase, visible per step when profiling
-            with _prof.phase("io_wait"):
-                item = self._queue.get()
+            # critical path" phase of its step; per batch, so a FINE
+            # span (trace_ids)
+            t0 = time.time() if _trace.enabled() else None
+            item = self._queue.get()
+            if t0 is not None:
+                _trace.add_span("io.wait", t0, time.time(), cat="io",
+                                args={"phase": "io_wait"})
             if item is None:
                 if self._error is not None:
                     raise self._error

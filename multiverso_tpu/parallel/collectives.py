@@ -18,7 +18,7 @@ etc. directly inside its own ``shard_map``.
 Observability (ISSUE 12): every entry point wraps its dispatch in
 ``telemetry/devstats.collective_span`` — op/bytes/duration land as
 Dashboard ``coll[op]`` monitors (zoo shutdown report), flight-recorder
-``coll.begin``/``coll.end`` events, a step-profiler async span, and the
+``coll.begin``/``coll.end`` events, one ``coll.<op>`` trace span, and the
 MSG_STATS ``"devices"`` block; a compile fired inside is keyed to THIS
 mesh's shape. ``tools/check_obs_surface.py`` asserts the wrapping
 statically, so a future collective op cannot ship dark (the crack

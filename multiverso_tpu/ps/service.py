@@ -70,7 +70,6 @@ from multiverso_tpu.telemetry import devstats as _devstats
 from multiverso_tpu.telemetry import exporter as _exporter
 from multiverso_tpu.telemetry import flightrec as _flight
 from multiverso_tpu.telemetry import memstats as _memstats
-from multiverso_tpu.telemetry import profiler as _profiler
 from multiverso_tpu.telemetry import slo as _slo
 from multiverso_tpu.telemetry import tenants as _tenants
 from multiverso_tpu.telemetry import trace as _trace
@@ -718,7 +717,6 @@ class PSService:
         # watchdog thread starts (flag-gated) to age its in-flight table
         _trace.configure(rank)
         _flight.configure(rank)
-        _profiler.configure(rank)
         _devstats.configure(rank)
         # fault plane: adopt the rank; arms from faults_spec /
         # $MV_FAULTS_SPEC when set (chaos bench workers), else stays
@@ -1007,12 +1005,13 @@ class PSService:
                 payload["serving"] = serving
         except Exception:   # noqa: BLE001 — telemetry never breaks stats
             pass
-        # step-profiler block (flag step_profile): per-process stall
-        # fraction / recompile summary — mvtop's stall%/recompiles
-        # columns and the aggregator pass it through like serving.
-        # Process-global (same (host, pid) collapse as the monitors).
+        # the steps' block (trace.step_report over the step spans this
+        # process recorded): per-process stall fraction / recompile
+        # summary — mvtop's stall%/recompiles columns and the aggregator
+        # pass it through like serving. Process-global (same (host, pid)
+        # collapse as the monitors).
         try:
-            profile = _profiler.stats_snapshot()
+            profile = _trace.step_summary()
             if profile:
                 payload["profile"] = profile
         except Exception:   # noqa: BLE001
@@ -2088,7 +2087,6 @@ class PSContext:
             d = config.get_flag("metrics_dir")
             if d:
                 _trace.dump_to(d)
-                _profiler.dump_to(d)
         except Exception as e:  # noqa: BLE001 — telemetry never blocks
             log.error("telemetry flush at close failed: %s", e)  # shutdown
         self.service.close()

@@ -37,7 +37,6 @@ from multiverso_tpu.ps.shard import KVShard, RowShard
 from multiverso_tpu.serving import hotcache as _hotcache
 from multiverso_tpu.telemetry import flightrec as _flight
 from multiverso_tpu.telemetry import memstats as _memstats
-from multiverso_tpu.telemetry import profiler as _profiler
 from multiverso_tpu.telemetry import tenants as _tenants
 from multiverso_tpu.telemetry import trace as ttrace
 from multiverso_tpu.updaters import AddOption
@@ -334,35 +333,6 @@ def _attach_reply_span(futs: List, name: str, t0: float, tid: int,
     for f in futs:
         if isinstance(f, cf.Future):
             f.add_done_callback(_done)
-
-
-def _attach_profile_end(futs: List, span) -> bool:
-    """Close a step-profiler async span when the LAST per-owner future
-    completes (runs on a peer recv thread) — the exact round-trip end
-    the overlap-credit math needs. Returns False when ANY future lacks
-    callback support (native-transport handles — including a MIXED
-    list, where a dead owner's already-failed cf placeholder would
-    otherwise fire the close at dispatch while the live native
-    round-trips are still in flight): the caller then leaves the span
-    open and the wait()/sweep fallback closes it conservatively."""
-    if any(not isinstance(f, cf.Future) for f in futs):
-        return False
-    remaining = [len(futs)]
-    if not remaining[0]:
-        return False
-    lock = threading.Lock()
-
-    def _done(_f):
-        with lock:
-            remaining[0] -= 1
-            last = remaining[0] == 0
-        if last:
-            span.end()
-
-    for f in futs:
-        if isinstance(f, cf.Future):
-            f.add_done_callback(_done)
-    return True
 
 
 class _RetainedFrame:
@@ -1517,9 +1487,6 @@ class _AsyncBase:
         # can surface them deterministically (sweep timing must not decide
         # whether a lost delta is seen)
         self._swept_failures: List[Exception] = []
-        # step-profiler async spans per tracked msg_id (flag
-        # step_profile; empty dict and one attribute read otherwise)
-        self._prof_spans: Dict[int, Any] = {}
 
     def _wire_for(self, rank: int) -> str:
         """Wire codec per destination rank (overridden by tables with a
@@ -1566,8 +1533,7 @@ class _AsyncBase:
     # and flush() still surfaces every failure deterministically
     _SWEEP_THRESHOLD = 32
 
-    def _track(self, futures: List[cf.Future], finalize=None,
-               op: Optional[str] = None) -> int:
+    def _track(self, futures: List[cf.Future], finalize=None) -> int:
         with self._lock:
             # sweep fire-and-forget adds whose futures are all done; their
             # failures are LOGGED, not raised — raising here would poison
@@ -1579,9 +1545,6 @@ class _AsyncBase:
                     if len(self._pending) >= self._SWEEP_THRESHOLD else ())
             for mid in done:
                 futs, _ = self._pending.pop(mid)
-                sp = self._prof_spans.pop(mid, None)
-                if sp is not None:   # native-handle span: close at the
-                    sp.end()         # sweep (its futures are all done)
                 for f in futs:
                     exc = f.exception()
                     if exc is not None:
@@ -1592,24 +1555,6 @@ class _AsyncBase:
             msg_id = self._next_msg_id
             self._next_msg_id += 1
             self._pending[msg_id] = (futures, finalize)
-        # step-profiler async span (one attribute read when off): the
-        # op's dispatch->reply interval is what the overlap-credit math
-        # intersects with compute phases. Reply callbacks close it at
-        # the true round-trip end; native handles (no callbacks) fall
-        # back to closing at wait()/step-finalize.
-        if op is not None and _profiler.PROFILER.enabled:
-            span = _profiler.async_begin(op, attach="thread")
-            if span is not None:
-                if not _attach_profile_end(futures, span):
-                    with self._lock:
-                        # re-check under the lock: a concurrent _track's
-                        # sweep may have already popped this msg_id (all
-                        # native acks landed) — storing now would leak an
-                        # open span under a dead id forever
-                        if msg_id in self._pending:
-                            self._prof_spans[msg_id] = span
-                        else:
-                            span.end()
         return msg_id
 
     def wait(self, msg_id: int) -> Any:
@@ -1628,20 +1573,13 @@ class _AsyncBase:
         a per-owner send-lock sweep per op)."""
         with self._lock:
             entry = self._pending.pop(msg_id, None)
-            span = self._prof_spans.pop(msg_id, None)
         if entry is None:
-            if span is not None:
-                span.end()
             return None
         futures, finalize = entry
         timeout = config.get_flag("ps_timeout")
-        try:
-            results = [svc.await_reply(f, timeout,
-                                       f"table[{self.name}] op {msg_id}")
-                       for f in futures]
-        finally:
-            if span is not None:   # native-handle span: the reply is in
-                span.end()         # by the time await_reply returned
+        results = [svc.await_reply(f, timeout,
+                                   f"table[{self.name}] op {msg_id}")
+                   for f in futures]
         return finalize(results) if finalize is not None else None
 
     def flush(self) -> None:
@@ -2025,8 +1963,7 @@ class AsyncMatrixTable(_AsyncBase):
                               _owned_part(vals, ix))
                              for r, ix in oparts]
                 mid = self._track(
-                    self._window.submit(parts, opt, tid, tenant=tn),
-                    op="ps.add")
+                    self._window.submit(parts, opt, tid, tenant=tn))
                 if tid is not None:
                     ttrace.add_span("client.enqueue", t_enq0, time.time(),
                                     trace=tid,
@@ -2049,8 +1986,7 @@ class AsyncMatrixTable(_AsyncBase):
                     np.ascontiguousarray(vals))
                 return self._track(
                     _fanout_futures(
-                        parts, lambda c, s, m: _NativeAddFuture(c, s, m)),
-                    op="ps.add")
+                        parts, lambda c, s, m: _NativeAddFuture(c, s, m)))
             t_send0 = time.time() if tid is not None else 0.0
             futs = []
             parts = self._owner_slices(uids)
@@ -2106,7 +2042,7 @@ class AsyncMatrixTable(_AsyncBase):
             if tid is not None:
                 _attach_reply_span(futs, "client.add_rows", t_send0, tid,
                                    self.name)
-        return self._track(futs, op="ps.add")
+        return self._track(futs)
 
     def add_rows(self, row_ids, values,
                  opt: Optional[AddOption] = None) -> None:
@@ -2137,8 +2073,7 @@ class AsyncMatrixTable(_AsyncBase):
         tc = self._train_cache
         if tc is not None:
             return self._train_cache_get(row_ids, out)
-        return self._track(*self._get_rows_futs(row_ids, out),
-                           op="ps.get")
+        return self._track(*self._get_rows_futs(row_ids, out))
 
     def _train_cache_get(self, row_ids,
                          out: Optional[np.ndarray] = None) -> int:
@@ -2186,8 +2121,7 @@ class AsyncMatrixTable(_AsyncBase):
                 # (mvtop's get counters must not flatline on a warm
                 # cache) — incr only, no wire latency to record
                 Dashboard.get(f"table[{self.name}].get_rows").incr()
-                return self._track([], lambda _res: _expand(buf),
-                                   op="ps.get")
+                return self._track([], lambda _res: _expand(buf))
             full_miss = nhit == 0
             cold_sel = np.flatnonzero(~hit)
             cold_uids = uids[cold_sel]
@@ -2208,7 +2142,7 @@ class AsyncMatrixTable(_AsyncBase):
             tc.fill_since(cold_uids, rows_cold, token)
             return _expand(buf)
 
-        return self._track(futs, _fin, op="ps.get")
+        return self._track(futs, _fin)
 
     def _get_rows_futs(self, row_ids,
                        out: Optional[np.ndarray] = None,
@@ -2860,8 +2794,7 @@ class AsyncSparseKVTable(_SparseGetMixin, _AsyncBase):
                     parts = [(r, uids[m], vals[m])
                              for r, m in self._by_owner(uids)]
                 mid = self._track(
-                    self._window.submit(parts, opt, tid, tenant=tn),
-                    op="ps.add")
+                    self._window.submit(parts, opt, tid, tenant=tn))
                 if tid is not None:
                     ttrace.add_span("client.enqueue", t_enq0, time.time(),
                                     trace=tid,
@@ -2875,7 +2808,7 @@ class AsyncSparseKVTable(_SparseGetMixin, _AsyncBase):
                                              [uids[m], vals[m]],
                                              meta_b=meta_b)
                     for r, m in self._by_owner(uids)]
-        return self._track(futs, op="ps.add")
+        return self._track(futs)
 
     def add_rows(self, keys, values,
                  opt: Optional[AddOption] = None) -> None:
@@ -2900,7 +2833,7 @@ class AsyncSparseKVTable(_SparseGetMixin, _AsyncBase):
                     out[m] = arrays[0]
                 return out if inv is None else out[inv]
 
-        return self._track(futs, _assemble, op="ps.get")
+        return self._track(futs, _assemble)
 
     def get_rows(self, keys) -> np.ndarray:
         return self.wait(self.get_rows_async(keys))

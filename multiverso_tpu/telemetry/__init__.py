@@ -47,8 +47,8 @@ Nine cooperating pieces:
   direction), per-mesh-shape compile attribution off the
   ``jax.monitoring`` hook, collective op spans (every
   ``parallel/collectives.py`` entry lands Dashboard ``coll[op]``
-  monitors, flightrec ``coll.begin``/``coll.end`` events, and a
-  step-profiler async span), the per-device ``jax.live_arrays()``
+  monitors, flightrec ``coll.begin``/``coll.end`` events, and one
+  ``coll.<op>`` trace span), the per-device ``jax.live_arrays()``
   rollup riding MSG_STATS as the ``"devices"`` block, and the SPMD
   compile-hygiene capture ``tools/bench_scale.py`` asserts clean
   (docs/OBSERVABILITY.md "Device view & scale curves").

@@ -271,7 +271,7 @@ def merge_cluster(stats_by_rank: Dict[int, Any],
             ent["shed_rate"] = (round(ent["shed"] / dem, 4)
                                 if dem else None)
         rec["serving"] = serving
-    # step-profiler blocks (flag step_profile; PR 9): passed through
+    # the steps' blocks (trace.step_summary): passed through
     # per reporting rank like the serving block, plus two at-a-glance
     # fields folded into the rank entries (mvtop's stall%/recompiles
     # columns). Process-global like the monitors — in-process
@@ -655,7 +655,7 @@ def compact_record(rec: Dict, top: int = 8,
         # replica lag/hit-rate/shed summary (already compact)
         out["serving"] = rec["serving"]
     if rec.get("profile"):
-        # per-rank step-profiler summaries (already compact)
+        # per-rank step summaries (already compact)
         out["profile"] = rec["profile"]
     if rec.get("memory"):
         # per-rank RSS/device/ledger digests + cluster totals (already

@@ -14,10 +14,9 @@ layer — four gauges sharing the flight-recorder's cost discipline
   bytes PER DIRECTION (``h2d``/``d2h``). It generalizes the PR-9
   instrumented-site accounting into one funnel: the word-embedding and
   DLRM pipelines, ``sequence_shard``/``shard_params`` device_puts, and
-  ``process_sum``'s round trip all report here, and the h2d side still
-  feeds the step profiler's per-step ``transfer_bytes`` delta.
-* **Mesh-keyed compile events** — a ``jax.monitoring`` duration
-  listener (the PR-9 hook, extended) attributes every backend compile
+  ``process_sum``'s round trip all report here.
+* **Mesh-keyed compile events** — the package's one ``jax.monitoring``
+  duration listener attributes every backend compile
   to the ACTIVE mesh shape: :func:`mesh_scope` (collective spans push
   it automatically) or the Zoo's :func:`set_default_mesh`. A recompile
   now names which mesh configuration triggered it — the signal the
@@ -30,8 +29,9 @@ layer — four gauges sharing the flight-recorder's cost discipline
   ``parallel/`` collective entry point: op/bytes/duration land as
   Dashboard monitors (``coll[op]`` timed + ``.calls``/``.bytes``
   counters in the zoo shutdown report), flight-recorder
-  ``coll.begin``/``coll.end`` events, a step-profiler async span
-  (``attach="any"``), and this module's per-op tally. Durations are
+  ``coll.begin``/``coll.end`` events, one coarse ``coll.<op>`` span
+  (what a step's report counts as work beside it,
+  ``trace.step_report``), and this module's per-op tally. Durations are
   HOST dispatch+compile wall time — jax dispatch is async, so a
   non-blocking caller's span excludes device execution (same caveat
   as every Dashboard monitor around jitted code).
@@ -50,7 +50,7 @@ compiles and classifies SPMD remat / sharding-fallback / donation
 warnings into a machine-readable report keyed (jitted fn, mesh shape).
 ``tools/bench_scale.py`` asserts the report CLEAN in-run for the
 shipped workload at every mesh shape; :func:`dump_hygiene` writes
-``compile-hygiene-rank<r>.json`` for ``tools/mvprof.py --report``.
+``compile-hygiene-rank<r>.json`` for ``tools/mvprof.py``.
 
 **The compiled program's map** (:func:`describe_program`,
 :func:`scope_seconds`): a device trace names an operation by its HLO
@@ -83,16 +83,16 @@ import traceback
 import warnings
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-from multiverso_tpu.telemetry import profiler as _profiler
 from multiverso_tpu.telemetry import trace as _trace
 from multiverso_tpu.utils import config, log
+from multiverso_tpu.utils.intervals import union_length
 
 config.define_bool(
     "devstats", True,
     "device-plane observability (telemetry/devstats.py): host<->device "
     "transfer byte counters, per-mesh-shape compile attribution, "
     "collective op spans (Dashboard coll[op] monitors + flightrec "
-    "coll.begin/end + profiler async spans), and the per-device "
+    "coll.begin/end + one coll.<op> trace span), and the per-device "
     "live-arrays rollup in the MSG_STATS 'devices' block. On by "
     "default: one attribute read gates every site; the live-arrays "
     "walk runs only on a stats pull, never on a hot path")
@@ -256,7 +256,7 @@ class _MeshScope:
 
 
 class _CollSpan:
-    """One collective op's span: Dashboard + flightrec + profiler +
+    """One collective op's span: Dashboard + flightrec + the ring +
     the per-op tally, and a mesh scope so a compile triggered inside
     is keyed to the op's mesh."""
 
@@ -274,19 +274,18 @@ class _CollSpan:
         from multiverso_tpu.telemetry import flightrec as _flight
         if self._scope is not None:
             self._scope.__enter__()
-        self._t0 = time.time()
+        self._t0 = time.time_ns()
         _flight.record(_flight.EV_COLL_BEGIN, nbytes=self._nbytes,
                        note=f"coll.{self._op}")
         return self
 
     def __exit__(self, *exc):
         from multiverso_tpu.telemetry import flightrec as _flight
-        from multiverso_tpu.telemetry import profiler as _profiler
         from multiverso_tpu.utils.dashboard import Dashboard
-        t1 = time.time()
+        t1 = time.time_ns()
         if self._scope is not None:
             self._scope.__exit__()
-        ms = (t1 - self._t0) * 1e3
+        ms = (t1 - self._t0) * 1e-6
         with self._ds._lock:
             d = self._ds._coll.setdefault(
                 self._op, {"calls": 0, "bytes": 0, "ms": 0.0})
@@ -299,9 +298,8 @@ class _CollSpan:
         _flight.record(_flight.EV_COLL_END, nbytes=self._nbytes,
                        note=f"coll.{self._op}")
         # the wire-hiding question for collectives is the same as for
-        # PS round-trips: attach to whatever step is open, any thread
-        _profiler.note_async(f"coll.{self._op}", self._t0, t1,
-                             attach="any")
+        # PS round-trips: a step open on any thread counts it beside it
+        _trace.record(f"coll.{self._op}", self._t0, t1, nbytes=self._nbytes)
         return False
 
 
@@ -309,7 +307,7 @@ class _CollSpan:
 # the process-global gauge set
 # ---------------------------------------------------------------------- #
 class DevStats:
-    """One per process (like the FlightRecorder/StepProfiler);
+    """One per process (like the FlightRecorder/Tracer);
     in-process multi-rank worlds share it — the same documented
     collapse, deduped by (host, pid) in the cluster merge."""
 
@@ -355,9 +353,9 @@ class DevStats:
             pass            # degrade, not break, on exotic builds
 
     def _on_duration(self, name: str, dur: float, **kw) -> None:
-        # same event the PR-9 profiler counts globally; here each
-        # compile is ADDITIONALLY keyed to the active mesh shape, and
-        # leaves one coarse xla.compile span (telemetry/trace.py)
+        # each compile is keyed to the active mesh shape and leaves one
+        # coarse xla.compile span (telemetry/trace.py), which is what
+        # trace.step_report reads a step's recompiles from
         if not self.enabled:
             return
         if name.endswith("cache_retrieval_time_sec"):
@@ -430,9 +428,7 @@ class DevStats:
     # recording sites
     # ------------------------------------------------------------------ #
     def note_transfer(self, nbytes: int, direction: str = "h2d") -> None:
-        """THE host<->device transfer chokepoint. ``h2d`` additionally
-        feeds the step profiler's per-step transfer delta (the PR-9
-        counter this generalizes)."""
+        """THE host<->device transfer chokepoint."""
         if direction not in _DIRECTIONS:
             raise ValueError(f"direction {direction!r}: expected one of "
                              f"{_DIRECTIONS}")
@@ -441,9 +437,6 @@ class DevStats:
                 g = self._transfers[direction]
                 g[0] += 1
                 g[1] += int(nbytes)
-        if direction == "h2d":
-            from multiverso_tpu.telemetry import profiler as _profiler
-            _profiler.note_transfer(int(nbytes))
 
     def collective_span(self, op: str, nbytes: int, mesh: Any = None):
         """Span context for one collective call — see module
@@ -495,7 +488,7 @@ class DevStats:
     def dump_hygiene(self, directory: str,
                      rank: Optional[int] = None) -> str:
         """Write ``compile-hygiene-rank<r>.json`` (atomic replace) for
-        ``tools/mvprof.py --report``; returns the path."""
+        ``tools/mvprof.py``; returns the path."""
         r = self.rank if rank is None else rank
         rep = self.hygiene_report()
         rep["rank"] = r
@@ -599,7 +592,7 @@ class _HygieneScope:
 DEVSTATS = DevStats()
 
 
-# module-level wrappers (the call-site idiom, like telemetry.profiler)
+# module-level wrappers (the call-site idiom, like telemetry.trace)
 def enabled() -> bool:
     return DEVSTATS.enabled
 
@@ -885,7 +878,7 @@ def scope_seconds(ops: Dict[str, Sequence[Tuple[str, str, float, float]]],
         if not chip_ops:
             continue
         chips += 1
-        busy += _profiler.union_length(
+        busy += union_length(
             [(o[2], o[2] + o[3]) for o in chip_ops])
         for name, text, _, dur in _leaves(chip_ops):
             key = (name, _shape_in(text))

@@ -325,11 +325,6 @@ class MetricsExporter:
             os.replace(tmp, ppath)
         from multiverso_tpu.telemetry import trace as _trace
         _trace.dump_to(self.directory)
-        # step-profiler records stream alongside the spans (same
-        # drain-on-dump contract): profile-rank<r>.jsonl feeds
-        # tools/mvprof.py and dump_metrics show/diff
-        from multiverso_tpu.telemetry import profiler as _profiler
-        _profiler.dump_to(self.directory)
         return payload
 
 

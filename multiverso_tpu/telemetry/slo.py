@@ -1,7 +1,7 @@
 """SLO sentinel — declarative objectives judged on every cluster poll.
 
 The repo measures everything (histograms, flight recorder, cluster
-aggregator, profiler, memstats, devstats, tenant ledger) but judged
+aggregator, step spans, memstats, devstats, tenant ledger) but judged
 almost nothing continuously: the only standing verdicts were one-off
 sweeps (noisy-neighbor, leak). This module is the judging layer every
 real fleet has between metrics and action:

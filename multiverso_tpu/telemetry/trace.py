@@ -12,6 +12,14 @@ ID is this field), the thread, the counts given at entry or set on the
 handle before exit, and ``prof``: whether a ``jax.profiler`` trace was
 being captured.
 
+A **step** is a span that says it is one (the count ``step=1``: one
+iteration of a training loop), and a **phase** is a span inside it; the
+count ``phase=`` files a span's time under a name the apps share
+(``prepare``, ``compute``, ``ps_wait``, ``io_wait``, ``push``).
+:func:`step_report` reads a step's wall time, its phases, what other
+threads did meanwhile and what compiled inside it off the records, and
+:func:`step_summary` is the ``profile`` block of a MSG_STATS payload.
+
 Two classes of site, one gate each:
 
 * **coarse** sites (:func:`span`; :func:`record` for one that has
@@ -68,9 +76,10 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 from jax.profiler import TraceAnnotation
 
-from multiverso_tpu.telemetry import profiler as _profiler
 from multiverso_tpu.utils import config
 from multiverso_tpu.utils.dashboard import Dashboard
+from multiverso_tpu.utils.intervals import (clip, intersect_disjoint,
+                                            union_intervals, union_length)
 
 config.define_bool(
     "trace_ids", False,
@@ -89,6 +98,14 @@ config.define_bool(
 # traffic
 _MAX_EVENTS = 200_000
 ANCHOR = "mv.trace.anchor"
+COMPILE = "xla.compile"
+
+
+def _no_steps() -> Dict[str, Any]:
+    """Sums over no step yet: what :func:`_add_step` adds into."""
+    return {"steps": 0, "wall_ms": 0.0, "attributed_ms": 0.0,
+            "stall_ms": 0.0, "overlap_ms": 0.0, "phases": {},
+            "steady": set(), "compiles": 0}
 
 
 def profiling() -> bool:
@@ -102,21 +119,17 @@ class Span:
     thread passes as ``cause=``."""
 
     __slots__ = ("name", "id", "parent", "cause", "request", "counts",
-                 "prof", "_tracer", "_t0", "_ann", "_phase")
+                 "prof", "_tracer", "_t0", "_ann", "_mark")
 
     def __init__(self, tracer: "Tracer", name: str, request, cause,
-                 phase: Optional[str], counts: Dict[str, Any]):
+                 counts: Dict[str, Any]):
         self._tracer, self.name = tracer, name
         self.request, self.cause = request, cause
         self.counts = counts
         self.id = next(tracer._span_ids)
         self.parent: Optional[int] = None
         self.prof = False
-        self._ann = None
-        # the step profiler's phase of that name, while step_profile is
-        # on: the site stays one with-statement
-        self._phase = (_profiler.phase(phase)
-                       if phase and _profiler.enabled() else None)
+        self._ann = self._mark = None
 
     def set(self, **counts) -> None:
         self.counts.update(counts)
@@ -133,15 +146,13 @@ class Span:
                          if self.request is None else
                          TraceAnnotation(self.name, request=self.request))
             self._ann.__enter__()
-        if self._phase is not None:
-            self._phase.__enter__()
         self._t0 = time.time_ns()
+        if "step" in self.counts:
+            self._mark = self._tracer._step_opens(self._t0 / 1e3)
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.time_ns()
-        if self._phase is not None:
-            self._phase.__exit__(*exc)
         if self._ann is not None:
             self._ann.__exit__(*exc)
         self._tracer._stack().pop()
@@ -149,6 +160,8 @@ class Span:
             self.name, self._t0, t1, self.id, self.parent, self.cause,
             self.request, "prog", self.counts, self.prof)
         Dashboard.get(self.name).observe_ms((t1 - self._t0) * 1e-6)
+        if self._mark is not None:
+            self._tracer._step_closes(self.id, self._mark)
 
 
 class Tracer:
@@ -164,6 +177,12 @@ class Tracer:
         self._span_ids = itertools.count(1)
         self._tls = threading.local()
         self._anchored = False   # an anchor was written for this capture
+        # the steps so far, added up as each closes (_step_closes): how
+        # many records the ring has ever taken, the sums, and each
+        # thread's first step (its interval; open-ended while it runs)
+        self._taken = 0
+        self._steps = _no_steps()
+        self._first: Dict[int, Tuple[float, float]] = {}
 
     # ------------------------------------------------------------------ #
     def configure(self, rank: Optional[int] = None) -> None:
@@ -227,6 +246,8 @@ class Tracer:
         # otherwise drop a span landing between its two steps
         with self._lock:
             self._events.append(ev)
+            self._taken += 1
+            self._steps["compiles"] += name == COMPILE
 
     def add_span(self, name: str, t0: float, t1: float,
                  trace: Optional[int] = None, cat: str = "ps",
@@ -257,23 +278,65 @@ class Tracer:
                      counts, profiling() if prof is None else prof)
 
     def span(self, name: str, *, request=None, cause: Optional[int] = None,
-             phase: Optional[str] = None, **counts) -> Span:
+             **counts) -> Span:
         """A COARSE span around a ``with`` block: always recorded, feeds
-        the Dashboard monitor ``name``, annotates the profiler's trace
-        while one is captured, and (``phase=``) marks that phase of the
-        step profiler's current step. Not for per-request or
+        the Dashboard monitor ``name``, and annotates the profiler's
+        trace while one is captured. Not for per-request or
         per-minibatch sites."""
-        return Span(self, name, request, cause, phase, counts)
+        return Span(self, name, request, cause, counts)
 
     # ------------------------------------------------------------------ #
     def events(self) -> List[Dict]:
         with self._lock:
             return list(self._events)
 
+    def _step_opens(self, t0: float) -> int:
+        """A step span opens at ``t0`` (microseconds): its thread's first
+        is noted while it still runs, so a compile inside it is warm-up
+        to every step that closes meanwhile. Returns the ring's count,
+        from which the step's own fold reads."""
+        with self._lock:
+            self._first.setdefault(threading.get_ident() & 0x7FFFFFFF,
+                                   (t0, float("inf")))
+            return self._taken
+
+    def _step_closes(self, step_id: int, mark: int) -> None:
+        """A step span has closed and left its record: add its report
+        (:func:`step_report`'s, over what the ring took since it opened)
+        into the sums. The ring's lock is held for the copy of that tail
+        alone. A step from under which a dump or the ring's bound took
+        spans is left out of the sums; the file holds it whole."""
+        with self._lock:
+            n = self._taken - mark
+            tail = (list(itertools.islice(reversed(self._events), n))
+                    if 0 < n <= len(self._events) else ())
+            first = dict(self._first)
+        step = next((e for e in tail if e["id"] == step_id), None)
+        report = _Steps(tail, first).report(step) if step else None
+        with self._lock:
+            tid = threading.get_ident() & 0x7FFFFFFF
+            t0, t1 = self._first.get(tid, (0.0, 0.0))
+            if t1 == float("inf"):
+                self._first[tid] = (t0, time.time_ns() / 1e3)
+            if report is not None:
+                _add_step(self._steps, report)
+
+    def step_summary(self) -> Optional[Dict[str, Any]]:
+        """The ``profile`` block of a MSG_STATS payload: every step this
+        process has closed since :meth:`reset`, each added up as it
+        closed (cumulative, whatever a dump drained or the ring dropped
+        since); ``None`` while no step span was recorded. A step is
+        credited with what the ring held when it closed: a span that
+        ends later (another thread's still open, a send-to-reply span
+        recorded afterwards) is the file's report's to credit."""
+        with self._lock:
+            return profile_block(self._steps)
+
     def reset(self) -> None:
         with self._lock:
             self._events.clear()
             self._next_id = 0
+            self._taken, self._steps, self._first = 0, _no_steps(), {}
         self._rank_pinned = False
 
     def dump(self, path: str, append: bool = True) -> int:
@@ -378,7 +441,7 @@ def self_ms(events: List[Dict]) -> Dict[int, float]:
     out: Dict[int, float] = {}
     for e in events:
         lo, hi = e["ts"], e["ts"] + e["dur"]
-        covered = _profiler.union_length([(max(a, lo), min(b, hi))
+        covered = union_length([(max(a, lo), min(b, hi))
                                 for a, b in children.get(e["id"], ())])
         out[e["id"]] = (e["dur"] - covered) * 1e-3
     return out
@@ -422,7 +485,7 @@ def device_timeline(events: List[Dict], since: Optional[float] = None
     lo = min(a for a, _ in flights) if since is None else since
     hi = flights[-1][1]
     in_flight = [(max(a, lo), b) for a, b in
-                 _profiler.union_intervals(flights) if b > lo]
+                 union_intervals(flights) if b > lo]
     by_id = {e["id"]: e for e in events}
     by_tid: Dict[Any, List[Dict]] = {}      # host spans by thread, by start
     for e in sorted((e for e in events if e.get("cat") == "prog"),
@@ -454,6 +517,180 @@ def device_timeline(events: List[Dict], since: Optional[float] = None
             "runs": runs}
 
 
+def _is_step(e: Dict) -> bool:
+    return "step" in (e.get("args") or {})
+
+
+def _touching(events: List[Dict]):
+    """``events`` indexed for the question "which of you touch [lo, hi]":
+    sorted by start, with the latest end so far, so the answer walks back
+    from the last that starts in time and stops where none reaches."""
+    es = sorted(events, key=lambda e: e["ts"])
+    starts = [e["ts"] for e in es]
+    reach = list(itertools.accumulate((e["ts"] + e["dur"] for e in es), max))
+
+    def touching(lo: float, hi: float):
+        i = bisect.bisect_right(starts, hi) - 1
+        while i >= 0 and reach[i] >= lo:
+            if es[i]["ts"] + es[i]["dur"] >= lo:
+                yield es[i]
+            i -= 1
+    return touching
+
+
+class _Steps:
+    """What every step's report needs of one list of span records, made
+    once: the children of every span, the work that can lie beside a
+    step, the compiles by their ends and the threads' first steps."""
+
+    def __init__(self, events: List[Dict],
+                 first: Optional[Dict[Any, Tuple[float, float]]] = None):
+        self.steps = sorted(filter(_is_step, events), key=lambda e: e["ts"])
+        self.kids: Dict[int, List[Dict]] = {}
+        for e in events:
+            if e.get("parent") is not None:
+                self.kids.setdefault(e["parent"], []).append(e)
+        # every thread's first step, by thread: the caller's where it has
+        # seen more of the run than these records hold
+        if first is None:
+            first = {}
+            for s in self.steps:
+                first.setdefault(s["tid"], (s["ts"], s["ts"] + s["dur"]))
+        self.first = list(first.values())
+        # beside a step: the top-level spans of the threads that run none
+        self.beside_at = _touching([e for e in events if e["tid"] not in first
+                                    and e.get("parent") is None])
+        self.compiles = sorted((e for e in events if e["name"] == COMPILE),
+                               key=lambda e: e["ts"] + e["dur"])
+        self.compile_ends = [e["ts"] + e["dur"] for e in self.compiles]
+
+    def report(self, s: Dict) -> Dict[str, Any]:
+        lo, hi = s["ts"], s["ts"] + s["dur"]
+        wall = max(s["dur"], 1e-3)
+        # the step's tree: its descendants, each with the phase it is
+        # filed under and whether it carries that name itself
+        tree, todo = [], [(c, None) for c in self.kids.get(s["id"], ())]
+        while todo:
+            e, above = todo.pop()
+            own = e["args"].get("phase")
+            tree.append((e, own or above or e["name"], bool(own or not above)))
+            todo += [(c, own or above) for c in self.kids.get(e["id"], ())]
+        self_time = self_ms([e for e, _, _ in tree])
+        phases: Dict[str, Dict[str, Any]] = {}
+        for e, name, marks in tree:
+            d = phases.setdefault(name, {"ms": 0.0, "count": 0})
+            d["ms"] += self_time[e["id"]]
+            d["count"] += marks
+        under = union_intervals(
+            [iv for iv in (clip(c["ts"], c["ts"] + c["dur"], lo, hi)
+                           for c in self.kids.get(s["id"], ())) if iv])
+        beside: Dict[str, Dict[str, Any]] = {}
+        covered = list(under)
+        for e in self.beside_at(lo, hi):
+            iv = clip(e["ts"], e["ts"] + e["dur"], lo, hi)
+            if iv is None:
+                continue
+            covered.append(iv)
+            d = beside.setdefault(e["name"], {"ms": 0.0, "overlap_ms": 0.0,
+                                              "count": 0, "open": 0})
+            d["ms"] += (iv[1] - iv[0]) * 1e-3
+            d["overlap_ms"] += intersect_disjoint(iv, under) * 1e-3
+            d["count"] += 1
+            d["open"] += e["ts"] + e["dur"] > hi
+        attributed = union_length(covered)
+        ends = self.compile_ends
+        i, j = bisect.bisect_left(ends, lo), bisect.bisect_right(ends, hi)
+        compiles = [
+            {"id": c["id"], "fun": c["args"].get("fun", ""),
+             "seconds": c["args"].get("seconds", c["dur"] * 1e-6),
+             "steady": not any(a <= t <= b for a, b in self.first)}
+            for c, t in zip(self.compiles[i:j], ends[i:j])]
+        return {
+            "name": s["name"], "request": s.get("request"),
+            "rank": s.get("pid", 0), "tid": s["tid"], "ts": lo,
+            "wall_ms": wall * 1e-3,
+            "attributed_ms": attributed * 1e-3,
+            "attributed_fraction": min(attributed / wall, 1.0),
+            "overlap_ms": sum(d["overlap_ms"] for d in beside.values()),
+            "stall_ms": max(wall - attributed, 0.0) * 1e-3,
+            "stall_fraction": max(wall - attributed, 0.0) / wall,
+            "phases": dict(sorted(phases.items())),
+            "async": dict(sorted(beside.items())),
+            "compiles": compiles,
+        }
+
+
+def step_report(events: List[Dict]) -> List[Dict[str, Any]]:
+    """One report a step span (a span with the count ``step``), from span
+    records alone, oldest first (``step``: its place in that order).
+
+    ``wall_ms`` is the step's duration. ``phases`` is the self time
+    (:func:`self_ms`) of the spans under it on its thread, each filed
+    under the nearest ``phase`` count on it or above it inside the step,
+    or under its own name: ``{name: {"ms", "count"}}``, ``count`` the
+    spans that carry the name themselves. ``async`` is what ran beside
+    it: the work of the threads that run no step of their own (a
+    producer's ``we.prepare``, a recv thread's send-to-reply span, the
+    watcher's device span), each thread's taken by its top-level spans
+    (no parent: what is nested lies inside its root's interval), those
+    that intersect the step, clipped to it: ``{name: {"ms",
+    "overlap_ms", "count", "open"}}``, ``overlap_ms`` the part under
+    the step's own spans (work the wait for it did not cost) and
+    ``open`` those that outlast the step. A thread that runs steps is a
+    trainer and none of its spans is beside anybody's step: the span
+    its steps run inside (``we.blocks`` round its ``we.step``'s) covers
+    them whole by construction. ``attributed_ms`` is the union of the
+    step's own spans and what ran beside it, ``stall_ms`` the wall time
+    nothing claims, each with its fraction of the wall.
+    ``compiles`` lists the ``xla.compile`` records that ended inside the
+    step, any thread: ``{"id", "fun", "seconds", "steady"}``. A compile
+    is **steady** iff it ended inside some step and inside no thread's
+    FIRST step: a rule per compile, so one warm-up compile shared by two
+    trainers' first steps is no recompile of either."""
+    index = _Steps(events)
+    return [dict(index.report(s), step=i) for i, s in enumerate(index.steps)]
+
+
+def _add_step(totals: Dict[str, Any], report: Dict[str, Any]) -> None:
+    """One step's report added into ``totals`` (:func:`_no_steps`)."""
+    totals["steps"] += 1
+    for k in ("wall_ms", "attributed_ms", "stall_ms", "overlap_ms"):
+        totals[k] += report[k]
+    for name, d in report["phases"].items():
+        totals["phases"][name] = totals["phases"].get(name, 0.0) + d["ms"]
+    totals["steady"].update(c["id"] for c in report["compiles"]
+                            if c["steady"])
+
+
+def step_totals(events: List[Dict]) -> Dict[str, Any]:
+    """:func:`step_report` over ``events`` added up: ``steps``,
+    ``wall_ms``, ``attributed_ms``, ``stall_ms``, ``overlap_ms``,
+    ``phases`` (ms by name), ``steady`` (the ids of the steady compiles:
+    each once, however many steps it ended inside) and ``compiles``
+    (every ``xla.compile`` record, inside a step or not)."""
+    totals = _no_steps()
+    for r in step_report(events):
+        _add_step(totals, r)
+    totals["compiles"] = sum(e["name"] == COMPILE for e in events)
+    return totals
+
+
+def profile_block(totals: Dict[str, Any]) -> Optional[Dict[str, Any]]:
+    """The six keys MSG_STATS carries as ``profile`` (the aggregator, the
+    ``stall_fraction`` objective and the straggler verdict of
+    ``telemetry/slo.py``, ``tools/mvtop.py``); ``None`` without a step."""
+    if not totals["steps"]:
+        return None
+    wall = totals["wall_ms"] or 1e-6
+    return {"steps": totals["steps"],
+            "stall_fraction": round(totals["stall_ms"] / wall, 4),
+            "attributed_fraction": round(totals["attributed_ms"] / wall, 4),
+            "steady_recompiles": len(totals["steady"]),
+            "compiles": totals["compiles"],
+            "phases": {n: round(v, 3)
+                       for n, v in sorted(totals["phases"].items())}}
+
+
 def enabled() -> bool:
     """THE hot-path gate of the fine sites (attribute read, no locks)."""
     return TRACER.enabled
@@ -480,13 +717,16 @@ def record(name: str, t0_ns: int, t1_ns: int, *, request=None,
 
 
 def span(name: str, *, request=None, cause: Optional[int] = None,
-         phase: Optional[str] = None, **counts) -> Span:
-    return TRACER.span(name, request=request, cause=cause, phase=phase,
-                       **counts)
+         **counts) -> Span:
+    return TRACER.span(name, request=request, cause=cause, **counts)
 
 
 def events() -> List[Dict]:
     return TRACER.events()
+
+
+def step_summary() -> Optional[Dict[str, Any]]:
+    return TRACER.step_summary()
 
 
 def trace_path(directory: str, rank: Optional[int] = None) -> str:

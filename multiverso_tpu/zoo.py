@@ -75,10 +75,8 @@ class Zoo:
         from multiverso_tpu.telemetry import devstats as _devstats
         from multiverso_tpu.telemetry import exporter as _exporter
         from multiverso_tpu.telemetry import flightrec as _flightrec
-        from multiverso_tpu.telemetry import profiler as _profiler
         from multiverso_tpu.telemetry import trace as _trace
         _trace.configure(self.rank())
-        _profiler.configure(self.rank())
         # device plane: adopt the devstats flag and key compiles with
         # no explicit scope to THIS mesh's shape (the default label a
         # recompile is attributed to when nothing narrower is active)
@@ -156,11 +154,6 @@ class Zoo:
                 _trace.dump_to(d)
             except OSError as e:
                 log.error("trace dump at shutdown failed: %s", e)
-            try:
-                from multiverso_tpu.telemetry import profiler as _profiler
-                _profiler.dump_to(d)
-            except OSError as e:
-                log.error("profile dump at shutdown failed: %s", e)
         if config.get_flag("dashboard"):
             Dashboard.display(log.info)
             # a second init/stop cycle must not reprint this run's

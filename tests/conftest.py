@@ -140,12 +140,10 @@ def _fresh_runtime():
     from multiverso_tpu.ps import failover as _failover
     _failover.stop_global(final=False)
     _exporter.stop_global()
+    # the ring, the steps a dump drained from it and the fine gate (a
+    # test's steps must not count into its neighbors' profile block)
     _trace.TRACER.reset()
     _trace.TRACER.enabled = False
-    # step profiler: drop records/aggregates and disable (a test that
-    # enabled step_profile must not leak steps into its neighbors)
-    from multiverso_tpu.telemetry import profiler as _profiler
-    _profiler.reset()
     # memory plane: stop a leaked sampler thread and drop the ledger's
     # sample history / verdict episodes / peaks (a test's deliberate
     # leak must not verdict a neighbor's sweep). Registrations stay:

@@ -211,16 +211,16 @@ class TestTransfersAndSpans:
         with pytest.raises(ValueError):
             devstats.note_transfer(1, "sideways")
 
-    def test_h2d_feeds_profiler_delta(self):
-        # the PR-9 counter this chokepoint generalizes is gated on the
-        # step_profile flag like every profiler site
-        from multiverso_tpu.telemetry import profiler
-        from multiverso_tpu.utils import config
-        config.set_flag("step_profile", True)
-        profiler.configure()
-        before = profiler.jax_counters().get("transfer_bytes", 0)
-        devstats.note_transfer(4096, "h2d")
-        assert profiler.jax_counters()["transfer_bytes"] - before == 4096
+    def test_span_leaves_one_record_in_the_ring(self):
+        # what a step's report counts beside it (trace.step_report)
+        from multiverso_tpu.telemetry import trace
+        with trace.span("t.step", step=1):
+            with devstats.collective_span("test_op", 2048, mesh={"mv": 2}):
+                pass
+        [rec] = [e for e in trace.events() if e["name"] == "coll.test_op"]
+        assert rec["args"] == {"nbytes": 2048} and rec["cat"] == "prog"
+        [step] = trace.step_report(trace.events())
+        assert step["phases"]["coll.test_op"]["count"] == 1
 
     def test_span_lands_dashboard_flightrec_and_tally(self):
         from multiverso_tpu.utils.dashboard import Dashboard

@@ -146,14 +146,14 @@ def test_span_is_recorded_when_its_body_raises():
         assert s.parent is None              # the stack was unwound
 
 
-def test_phase_keyword_marks_the_step_profilers_phase():
-    from multiverso_tpu.telemetry import profiler as prof
-    config.set_flag("step_profile", True)
-    prof.configure(0)
-    with prof.step("s"):
+def test_phase_and_step_are_counts_on_the_record():
+    with ttrace.span("t.step", step=1):
         with ttrace.span("t.push", phase="push"):
             time.sleep(0.002)
-    [rec] = prof.records()
+    by = {e["name"]: e for e in ttrace.events()}
+    assert by["t.push"]["args"] == {"phase": "push"}
+    assert by["t.step"]["args"] == {"step": 1}
+    [rec] = ttrace.step_report(ttrace.events())
     assert rec["phases"]["push"]["count"] == 1
 
 
@@ -469,6 +469,21 @@ def _tiny_we(**kw):
     return we, we.prepare_ids(tokens)
 
 
+# every name the measured paths left in the ring at PR 57 (the parent of
+# ISSUE 58, which rewired the readers' ring): benchmark/layers read these,
+# and a new name on one of these paths is a new record in every window
+FUSED_SPANS = {"we.fused", "we.fused.pairs", "we.pairs.generate",
+               "we.pairs.upload", "we.fused.dispatch", "we.fused.wait",
+               "we.fused.count", "xla.compile", "xla.program"}
+LM_SPANS = {"lm.step", "lm.step.wait", "xla.compile", "xla.program"}
+HOST_PLANE_SPANS = {"we.blocks", "we.prepare", "we.block", "we.push",
+                    "we.blocks.drain", "xla.compile"}
+# ... and what ISSUE 58 made spans of on the host plane, which no cell
+# runs: the step round a block and the phases the step profiler marked
+HOST_PLANE_STEP_SPANS = {"we.step", "we.block.wait_prepared", "we.pipeline",
+                         "we.block.ps_wait", "we.block.compute"}
+
+
 def _children(events, parent):
     # what the compiler's listener and the program's map leave under a
     # call (xla.compile, xla.program) is no part of the call's own shape
@@ -493,6 +508,7 @@ def test_train_fused_leaves_its_spans_and_counts(mode):
     want = ["we.fused.pairs", "we.fused.dispatch", "we.fused.wait",
             "we.fused.count"]
     for events, hit in ((first, 0), (second, 0 if mode == "cbow" else 1)):
+        assert {e["name"] for e in events} <= FUSED_SPANS
         [call] = [e for e in events if e["name"] == "we.fused"]
         assert _children(events, call) == want
         a = call["args"]
@@ -620,6 +636,8 @@ def test_device_plane_blocks_leave_their_spans_and_counts(mode, tmp_path):
     watched = mode != "defaults"
     names = {e["name"] for e in events}
     assert names == BLOCK_SPANS | ({"we.block.device"} if watched else set())
+    # a call and its drain, and six spans a block (seven when watched)
+    assert len(events) == 2 + n_blocks * (7 if watched else 6)
     assert all(e["prof"] is (mode == "profiler") for e in events)
     [call] = [e for e in events if e["name"] == "we.blocks"]
     # ISSUE 40: what the scans' table writes were handed, as we.fused says
@@ -690,6 +708,7 @@ def test_blocks_count_the_rows_the_tile_kernel_walked(monkeypatch):
 def test_host_plane_keeps_its_block_monitors():
     we, ids = _tiny_we(use_ps=1, data_block_size=1500, ps_device_plane="0")
     Dashboard.reset()
+    start = len(ttrace.events())
     we.train_ps_blocks(ids, epochs=1)
     snap = Dashboard.snapshot()
     n_blocks = -(-ids.size // 1500)
@@ -697,6 +716,22 @@ def test_host_plane_keeps_its_block_monitors():
         assert snap[name].count == n_blocks, name
     [call] = [e for e in ttrace.events() if e["name"] == "we.blocks"]
     assert call["args"]["plane"] == "host"
+    names = {e["name"] for e in ttrace.events()[start:]}
+    assert HOST_PLANE_SPANS - {"xla.compile"} <= names
+    assert names <= HOST_PLANE_SPANS | HOST_PLANE_STEP_SPANS
+    steps = ttrace.step_report(ttrace.events())
+    assert [r["name"] for r in steps] == ["we.step"] * n_blocks
+    # the consumer's phases (the producers prepare on their own threads)
+    assert {"io_wait", "ps_wait", "compute", "push"} <= set().union(
+        *(r["phases"] for r in steps))
+    # the call the steps run inside is not their work: a step is covered
+    # by its own spans and the producers' (where one still prepares when
+    # the steps begin), and the rest of it is stall
+    assert set().union(*(r["async"] for r in steps)) <= {"we.prepare"}
+    assert all(r["attributed_ms"] <= r["wall_ms"] for r in steps)
+    assert sum(r["stall_ms"] for r in steps) > 0
+    block = ttrace.step_summary()
+    assert block["steps"] == n_blocks and 0 < block["stall_fraction"] < 1
 
 
 # ---------------------------------------------------------------------- #
@@ -778,6 +813,7 @@ def test_lm_step_carries_its_counts():
     _, counts = trainer.step(tokens)
     trainer.adopt()
     events = ttrace.events()[before:]
+    assert {e["name"] for e in events} <= LM_SPANS
     step = next(e for e in events if e["name"] == "lm.step")
     wait = next(e for e in events if e["name"] == "lm.step.wait")
     assert wait["parent"] == step["id"] and step["request"] == 1

@@ -12,7 +12,7 @@ the 8-virtual-device host platform. Process-per-point is load-bearing,
 not convenience: two shard counts' collective executables coexisting
 in one XLA CPU client raced the process-global rendezvous (observed
 live: interleaved all_reduce participants wedged both worlds) — and it
-also gives each point a process-fresh devstats/profiler reading.
+also gives each point a process-fresh devstats/span-ring reading.
 
 **Constant offered load (ISSUE 15).** Every point drives the SAME
 ``M = min(cpu_count, 4)`` worker threads — the textbook scaling-curve
@@ -40,7 +40,7 @@ concurrent jit work). Recorded per point:
   **E_n = T_n / (n * T_1)** in-run via :func:`efficiency_curve`
   (pure; oracle-tested in tests/test_devstats.py).
 * per-shard **skew** from the PR-6 aggregator's merged record;
-* **stall fraction** from the PR-9 step profiler;
+* **stall fraction** from the step spans (``trace.step_summary``);
 * per-direction **transfer bytes**, per-op **collective** tallies, and
   per-mesh-shape **compile** cost from ``telemetry/devstats.py`` —
   each compile keyed to the ``{'mv': n}`` configuration that fired it.
@@ -188,7 +188,7 @@ def run_point(n: int, seconds: float, rows: int, dim: int):
     from multiverso_tpu.ps.tables import AsyncMatrixTable
     from multiverso_tpu.telemetry import aggregator
     from multiverso_tpu.telemetry import devstats
-    from multiverso_tpu.telemetry import profiler as prof
+    from multiverso_tpu.telemetry import trace
     from multiverso_tpu.utils import config
 
     devices = jax.devices()
@@ -224,11 +224,14 @@ def run_point(n: int, seconds: float, rows: int, dim: int):
     # set)
     config.set_flag("hotkeys_capacity", 16384)
     # acceptance config: skew from the aggregator, stall fraction from
-    # the step profiler, device costs from devstats — the whole
+    # the step spans, device costs from devstats — the whole
     # instrument live while the point is measured
     config.set_flag("stats_poll_interval_s", 1.0)
-    config.set_flag("step_profile", True)
-    prof.configure(0)
+    # a step here is one loop iteration of a worker thread: per request,
+    # so its spans are fine ones and follow trace_ids, as the client's
+    # send-to-reply spans beside them do
+    config.set_flag("trace_ids", True)
+    trace.configure(0)
     devstats.configure(0)
 
     batch = BATCH_ROWS
@@ -256,7 +259,7 @@ def run_point(n: int, seconds: float, rows: int, dim: int):
         # WARMUP (ISSUE 15 satellite): a short loop-shaped pass per
         # worker slot — strided route, both shard programs, the fan-out
         # super-frame path, the async-add/wait pipeline AND one
-        # profiled step each — so point 1's first-compile +
+        # step span each — so point 1's first-compile +
         # first-dispatch cost stops polluting T_1 (a depressed T_1
         # inflated every E_n of the curve)
         for w in range(workers):
@@ -266,10 +269,10 @@ def run_point(n: int, seconds: float, rows: int, dim: int):
             for k in range(4):
                 mids.append(t.add_rows_async(ids, vals))
                 t.get_rows(ids)
-            with prof.step(f"scale.np{n}"):
-                with prof.phase("push"):
+            with trace.span(f"scale.np{n}", step=1):
+                with trace.span("scale.push", phase="push"):
                     mids.append(t.add_rows_async(ids, vals))
-                with prof.phase("ps_wait"):
+                with trace.span("scale.pull", phase="ps_wait"):
                     t.get_rows(ids)
             for m in mids:
                 t.wait(m)
@@ -295,15 +298,15 @@ def run_point(n: int, seconds: float, rows: int, dim: int):
             ids = (np.arange(batch) * (rows // batch) + w) % rows
             mids = []
             while time.monotonic() < stop:
-                with prof.step(f"scale.np{n}"):
-                    with prof.phase("prepare"):
+                with trace.span(f"scale.np{n}", step=1):
+                    with trace.span("scale.prepare", phase="prepare"):
                         v = vals * (1.0 + 1e-4 * counts[w])
-                    with prof.phase("push"):
+                    with trace.span("scale.push", phase="push"):
                         mids.append(t.add_rows_async(ids, v))
                         if len(mids) >= 4:
-                            with prof.phase("ps_wait"):
+                            with trace.span("scale.wait", phase="ps_wait"):
                                 t.wait(mids.pop(0))
-                    with prof.phase("ps_wait"):
+                    with trace.span("scale.pull", phase="ps_wait"):
                         t.get_rows(ids)
                 counts[w] += 2
             for m in mids:
@@ -342,7 +345,7 @@ def run_point(n: int, seconds: float, rows: int, dim: int):
             # drop gets a who, not just a how-much
             from multiverso_tpu.telemetry import slo as _slo
             straggler = _slo.straggler(rec)
-        summary = prof.summary()
+        summary = trace.step_summary() or {}
         snap = devstats.stats_snapshot() or {}
         compiles = (snap.get("compiles_by_mesh") or {}).get(
             devstats.mesh_label(mesh)) or {}
@@ -482,8 +485,8 @@ def main():
                             for n, c in curve.items()})
     transfers, colls, compiles = _merge_devices(points)
 
-    # machine-readable report for tools/mvprof.py --report (beside the
-    # profiler/trace files when a metrics dir is configured)
+    # machine-readable report for tools/mvprof.py (beside the
+    # trace files when a metrics dir is configured)
     from multiverso_tpu.utils import config
     mdir = config.get_flag("metrics_dir")
     if mdir:

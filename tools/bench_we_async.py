@@ -8,15 +8,15 @@ Same config/corpus as bench.bench_wordembedding_ps()'s 1M-token run
 plane's ``loss_1M``. Each rank trains blocks[rank::world] of the shared
 corpus against async tables owned across the plane.
 
-The MEASURED epoch runs with the step profiler live (flag
-``step_profile``, telemetry/profiler.py): every block is one step with
+The MEASURED epoch is read off its step spans
+(``telemetry/trace.step_report``): every block is one step with
 ``prepare``/``ps_wait``/``compute``/``push`` phases (plus ``io_wait`` /
-``we.pipeline`` on the ISSUE-11 pipelined path) and per-op
-``ps.get``/``ps.add`` async spans, and the RESULT carries the phase
+``we.pipeline`` on the ISSUE-11 pipelined path), the producers'
+``we.prepare`` spans beside them, and the RESULT carries the phase
 breakdown, stall fraction, overlap credit, and compile counts (bench
 ``extra.profile``). In-run assertions:
 
-* ISSUE 9: the profiler must attribute >= 90% of per-step wall time, and
+* ISSUE 9: the spans must attribute >= 90% of per-step wall time, and
   the steady state must not recompile.
 * ISSUE 11: stall fraction < 0.2 (the pipelined path's whole point is
   that the consumer never sits unattributed), and — on a real chip at
@@ -48,6 +48,7 @@ Prints "RESULT <json>".
 import hashlib
 import json
 import sys
+import time
 
 # ISSUE-11 acceptance floors, asserted in-run by _assert_perf_gates
 WORDS_PER_S_CHIP_FLOOR = 2_000_000     # at the 1M-token config, on TPU
@@ -105,7 +106,8 @@ def main():
     from multiverso_tpu.apps.word_embedding import (WEConfig, WordEmbedding,
                                                     synthetic_corpus)
     from multiverso_tpu.data.dictionary import Dictionary
-    from multiverso_tpu.telemetry import profiler as _prof
+    from multiverso_tpu.telemetry import devstats as _devstats
+    from multiverso_tpu.telemetry import trace as _trace
     from multiverso_tpu.utils import config
     from multiverso_tpu.utils.filesync import file_barrier
 
@@ -147,25 +149,30 @@ def main():
     file_barrier(rdv_dir, world, rank, "tables", timeout=180)
     we.train_ps_blocks(ids)               # warm: compile block programs
     file_barrier(rdv_dir, world, rank, "warm", timeout=180)
-    # profile the MEASURED epoch only: the warm epoch's compiles belong
-    # to warmup; steady-state steps must attribute >= 90% of wall and
-    # recompile zero times (both asserted below)
-    config.set_flag("step_profile", True)
-    _prof.configure()
+    # read the MEASURED epoch's spans only: the warm epoch's compiles
+    # belong to warmup; steady-state steps must attribute >= 90% of wall
+    # and recompile zero times (both asserted below)
+    def h2d_bytes():
+        snap = _devstats.stats_snapshot() or {}
+        return ((snap.get("transfers") or {}).get("h2d") or {}).get(
+            "bytes", 0)
+
+    began, h2d0 = time.time_ns() / 1e3, h2d_bytes()
     stats = we.train_ps_blocks(ids)       # measured epoch
-    config.set_flag("step_profile", False)
-    _prof.configure()
+    # the measured epoch's spans alone: its first step is the warm-up one
+    totals = _trace.step_totals([e for e in _trace.events()
+                                 if e["ts"] >= began])
     file_barrier(rdv_dir, world, rank, "trained", timeout=180)
-    prof = _prof.summary()
+    prof = _trace.profile_block(totals)
     profile = None
-    if prof["steps"]:
+    if prof is not None:
         # ISSUE 9 acceptance, asserted IN-RUN: the phase/span instrument
         # must account for >= 90% of the measured epoch's wall clock —
-        # a profiler that misses a tenth of the step cannot name the
-        # critical path. Interval-union math (profiler._finalize), so
+        # an instrument that misses a tenth of the step cannot name the
+        # critical path. Interval-union math (trace.step_report), so
         # overlapping phases cannot inflate the fraction past 1.
         assert prof["attributed_fraction"] >= 0.90, (
-            f"profiler attributed only "
+            f"the step spans attributed only "
             f"{prof['attributed_fraction']:.1%} of step wall time")
         # ISSUE 11, asserted IN-RUN: the pipelined path exists to keep
         # the consumer off the floor — stall (unattributed wall: gaps
@@ -176,7 +183,7 @@ def main():
             "not covering the step (see phases/io_wait in extra.we)")
         # steady state must not recompile: every block program compiled
         # during the warm epoch, and a silent mid-measure retrace is a
-        # perf regression the profiler exists to name
+        # perf regression the step's report exists to name
         assert prof["steady_recompiles"] == 0, (
             f"{prof['steady_recompiles']} steady-state recompiles "
             "during the measured epoch")
@@ -184,10 +191,10 @@ def main():
         steps = max(prof["steps"], 1)
         profile = {
             "steps": prof["steps"],
-            "wall_ms_per_step": round(prof["wall_ms"] / steps, 2),
+            "wall_ms_per_step": round(totals["wall_ms"] / steps, 2),
             "attributed_fraction": prof["attributed_fraction"],
             "stall_fraction": prof["stall_fraction"],
-            "overlap_ms_per_step": round(prof["overlap_ms"] / steps, 2),
+            "overlap_ms_per_step": round(totals["overlap_ms"] / steps, 2),
             # per-step EXCLUSIVE phase means — the ROADMAP item-2
             # headline ("prepare dominates block") read off directly
             "phase_ms_per_step": {n: round(v / steps, 2)
@@ -196,9 +203,8 @@ def main():
                 phases.get("prepare", 0.0)
                 > phases.get("compute", 0.0)),
             "steady_recompiles": prof["steady_recompiles"],
-            "compiles": prof["jax"]["compiles"],
-            "transfer_mb": round(
-                prof["jax"]["transfer_bytes"] / 1e6, 2),
+            "compiles": prof["compiles"],
+            "transfer_mb": round((h2d_bytes() - h2d0) / 1e6, 2),
         }
     platform = jax.devices()[0].platform
     perf_gate = _assert_perf_gates(platform, stats["words_per_sec"],

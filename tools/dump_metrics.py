@@ -42,12 +42,8 @@ per-table cluster totals/rates/skew, and the hot-key table; ``diff`` of
 two cluster records prints per-table RATE and SKEW deltas between the
 two runs alongside the merged-monitor comparison.
 
-Step-profiler files (``profile-rank<r>.jsonl``, records with
-``kind: "step"`` — telemetry/profiler.py) are recognized too: ``show``
-prints the per-step critical-path table (top phase, stall %, compile
-counts) and ``diff`` compares per-phase mean times and stall fractions
-between two runs. The deeper merge (profile + trace spans on one
-Perfetto timeline) is ``tools/mvprof.py``.
+A span file's steps (the per-step critical-path table: top phase,
+stall %, compiles) are ``tools/mvprof.py``'s to print.
 """
 
 from __future__ import annotations
@@ -61,11 +57,6 @@ from typing import Dict, List, Optional
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
-
-# shared step-record aggregation (telemetry/profiler.py) — the step
-# tables here and tools/mvprof.py's report must never drift
-from multiverso_tpu.telemetry.profiler import (  # noqa: E402
-    aggregate_step_records, step_top_phase)
 
 
 def load_records(path: str) -> List[Dict]:
@@ -358,67 +349,6 @@ def format_record(rec: Dict) -> str:
         lines.extend(_slo_lines(slo))
     for name in sorted(rec.get("notes", {})):
         lines.append(f"note[{name}] {rec['notes'][name]}")
-    return "\n".join(lines)
-
-
-def format_profile_records(records: List[Dict]) -> str:
-    """Step-profiler JSONL (``profile-rank<r>.jsonl``, records with
-    ``kind: "step"``) -> a per-step critical-path table plus the
-    aggregate phase breakdown."""
-    steps = [r for r in records if r.get("kind") == "step"]
-    if not steps:
-        return "(no step records)"
-    lines = [f"{'step':>5} {'name':<18} {'wall_ms':>9} {'top phase':<22} "
-             f"{'stall%':>7} {'overlap':>8} {'compiles':>8}"]
-    for r in steps:
-        top_n, top_ms = step_top_phase(r)
-        top_s = f"{top_n} ({top_ms:.1f} ms)" if top_n else "-"
-        lines.append(
-            f"{r.get('step', '?'):>5} {r.get('name', '?'):<18} "
-            f"{r.get('wall_ms', 0):>9.2f} {top_s:<22} "
-            f"{100 * r.get('stall_fraction', 0):>6.1f}% "
-            f"{r.get('overlap_ms', 0):>8.2f} "
-            f"{r.get('jax', {}).get('compiles', 0):>8}")
-    agg = aggregate_step_records(steps)
-    wall, stall = agg["wall_ms"], agg["stall_ms"]
-    lines.append("")
-    lines.append(f"{agg['steps']} steps, {wall:.1f} ms wall; exclusive "
-                 "phase totals: " + "  ".join(
-                     f"{n}={v:.1f}ms" for n, v in
-                     sorted(agg["phases_ms"].items(),
-                            key=lambda kv: -kv[1]))
-                 + f"  stall={stall:.1f}ms"
-                 + (f" ({100 * stall / wall:.1f}%)" if wall else ""))
-    return "\n".join(lines)
-
-
-def diff_profile_records(a: List[Dict], b: List[Dict]) -> str:
-    """Two profile JSONL files -> per-phase mean-ms ratios and the
-    stall-fraction comparison (b relative to a)."""
-
-    def agg(records):
-        g = aggregate_step_records(records)
-        n = max(g["steps"], 1)
-        return ({k: v / n for k, v in g["phases_ms"].items()},
-                (g["stall_ms"] / g["wall_ms"] if g["wall_ms"] else 0.0),
-                g["steps"])
-
-    pa, sa, na = agg(a)
-    pb, sb, nb = agg(b)
-    lines = [f"{'phase':<24} {'mean ms a':>10} {'mean ms b':>10} "
-             f"{'b/a':>6}"]
-    for name in sorted(set(pa) | set(pb)):
-        va, vb = pa.get(name), pb.get(name)
-        if va is None or vb is None:
-            lines.append(f"{name:<24} "
-                         f"{'-' if va is None else round(va, 3):>10} "
-                         f"{'-' if vb is None else round(vb, 3):>10} "
-                         f"{'only ' + ('b' if va is None else 'a'):>6}")
-            continue
-        ratio = f"{vb / va:>6.2f}" if va else f"{'-':>6}"
-        lines.append(f"{name:<24} {va:>10.3f} {vb:>10.3f} {ratio}")
-    lines.append(f"stall fraction: {sa:.3f} ({na} steps) -> "
-                 f"{sb:.3f} ({nb} steps)")
     return "\n".join(lines)
 
 
@@ -899,13 +829,6 @@ def main(argv: List[str]) -> int:
             idx = int(rest[i + 1])
             rest = rest[:i] + rest[i + 2:]
         records = load_records(rest[0])
-        if records[-1].get("kind") == "step":
-            # step-profiler JSONL: the per-step table IS the show (a
-            # single step record says little; --record still narrows)
-            if idx is not None:
-                records = [records[idx]]
-            print(format_profile_records(records))
-            return 0
         if is_history_record(records[-1]):
             # BENCH_HISTORY.jsonl: the whole trajectory IS the show
             print(format_history_records(
@@ -915,10 +838,6 @@ def main(argv: List[str]) -> int:
         return 0
     if cmd == "diff":
         ra, rb = load_records(rest[0]), load_records(rest[1])
-        if (ra[-1].get("kind") == "step"
-                and rb[-1].get("kind") == "step"):
-            print(diff_profile_records(ra, rb))
-            return 0
         if is_history_record(ra[-1]) and is_history_record(rb[-1]):
             # diffing a history file against itself compares the last
             # two runs of the trajectory; two files compare their tails

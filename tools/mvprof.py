@@ -1,34 +1,33 @@
 #!/usr/bin/env python
-"""mvprof — per-step critical-path report over step-profiler records.
+"""mvprof — per-step critical-path report over a run's span files.
 
-The step profiler (``multiverso_tpu/telemetry/profiler.py``, flag
-``step_profile``) writes one JSON record per training step to
-``profile-rank<r>.jsonl`` under ``metrics_dir``; PR-3 tracing writes
-request spans to ``trace-rank<r>.jsonl`` beside them. This tool is the
-read side — point it at the metrics directory (or explicit files):
+A training loop's iteration is a **step** span (the count ``step=1``)
+and what it does inside are **phase** spans; with ``metrics_dir`` set
+they land in ``trace-rank<r>.jsonl`` like every other span
+(``multiverso_tpu/telemetry/trace.py``). This tool is the read side:
+``trace.step_report`` over each file, rendered. Point it at the metrics
+directory (or explicit files):
 
-    python tools/mvprof.py DIR_OR_FILES... [--report] [--json]
-    python tools/mvprof.py DIR_OR_FILES... --to-perfetto OUT.json
+    python tools/mvprof.py DIR_OR_FILES... [--json]
 
-``--report`` (the default) prints, per rank:
+It prints, per rank:
 
 * the per-step table — wall, top (critical-path) phase, stall %,
   overlap credit, compile count — and which phase won the critical
   path across steps (the "prepare dominates block" headline, measured
   instead of inferred);
-* a stall-fraction histogram (how much wall time NO instrument
-  claimed, bucketed across steps);
-* the recompile table: every step whose boundary sampling attributed
-  a jit compile, with per-function retrace counts where ``watch_jit``
-  was registered — a silent mid-run recompile names its step.
+* a stall-fraction histogram (how much wall time NO span claimed,
+  bucketed across steps);
+* the recompile table: every step an ``xla.compile`` record ended
+  inside, with the functions compiled — a silent mid-run recompile
+  names its step and its function;
+* the SPMD compile-hygiene reports found beside the span files
+  (``compile-hygiene-rank<r>.json``).
 
-``--to-perfetto`` writes a Chrome/Perfetto ``traceEvents`` envelope
-with **one track per phase per rank** (pid = rank, named tids): step
-spans, phase marks, and async PS spans from the profile records, plus
-every PR-3 trace span found alongside — the wire's serve/apply spans
-land on the same wall-clock timeline as the steps that issued them.
+For a timeline, ``tools/dump_metrics.py to-perfetto`` wraps the same
+span file: steps and phases lie in it beside the request spans.
 
-Exit status: 0 with output, 1 when no step records were found.
+Exit status: 0 with output, 1 when no step span was found.
 """
 
 from __future__ import annotations
@@ -62,27 +61,24 @@ def _load_jsonl(path: str) -> List[Dict]:
     return out
 
 
-def collect(paths: List[str]) -> Tuple[List[Dict], List[Dict]]:
-    """(step records, trace events) from directories and/or explicit
-    files. A directory contributes every ``profile-rank*.jsonl`` and
-    ``trace-rank*.jsonl`` under it."""
-    steps: List[Dict] = []
-    spans: List[Dict] = []
+def collect(paths: List[str]) -> List[Dict]:
+    """Step reports (``trace.step_report``) from directories and/or
+    explicit span files, by rank and start. A directory contributes
+    every ``trace-rank*.jsonl`` under it; each file is one process's
+    spans and is read on its own (span ids are a process's)."""
+    from multiverso_tpu.telemetry.trace import step_report
     files: List[str] = []
     for p in paths:
         if os.path.isdir(p):
-            files += sorted(glob.glob(os.path.join(p, "profile-rank*.jsonl")))
             files += sorted(glob.glob(os.path.join(p, "trace-rank*.jsonl")))
-        else:
+        elif "compile-hygiene" not in os.path.basename(p):
             files.append(p)
+    steps: List[Dict] = []
     for f in files:
-        for rec in _load_jsonl(f):
-            if rec.get("kind") == "step":
-                steps.append(rec)
-            elif "ph" in rec and "ts" in rec:
-                spans.append(rec)
-    steps.sort(key=lambda r: (r.get("rank", 0), r.get("ts", 0.0)))
-    return steps, spans
+        steps += step_report([r for r in _load_jsonl(f)
+                              if "ph" in r and "ts" in r])
+    steps.sort(key=lambda r: (r["rank"], r["ts"]))
+    return steps
 
 
 def collect_hygiene(paths: List[str]) -> List[Dict]:
@@ -147,32 +143,52 @@ def _stall_histogram(steps: List[Dict], buckets=(5, 10, 20, 40, 100)
     return out
 
 
+def step_top_phase(rec: Dict) -> Tuple[Optional[str], float]:
+    """(name, exclusive ms) of a step's critical-path phase —
+    (None, 0.0) for a step with no span under it."""
+    name, ms = None, 0.0
+    for n, d in rec["phases"].items():
+        if d["ms"] > ms:
+            name, ms = n, d["ms"]
+    return name, ms
+
+
 def report_data(steps: List[Dict]) -> Dict:
-    """The report as data (--json; the text renderer consumes this).
-    Per-rank aggregation is ``profiler.aggregate_step_records`` — the
-    ONE definition dump_metrics' step renderers share."""
-    from multiverso_tpu.telemetry.profiler import aggregate_step_records
+    """The report as data (--json; the text renderer consumes this)."""
     by_rank: Dict[int, List[Dict]] = {}
     for r in steps:
-        by_rank.setdefault(int(r.get("rank", 0)), []).append(r)
+        by_rank.setdefault(int(r["rank"]), []).append(r)
     out: Dict = {"ranks": {}}
     for rank, recs in sorted(by_rank.items()):
-        agg = aggregate_step_records(recs)
-        wall = agg["wall_ms"]
+        wall = sum(r["wall_ms"] for r in recs)
+        phases: Dict[str, float] = {}
+        wins: Dict[str, int] = {}
+        for r in recs:
+            for n, d in r["phases"].items():
+                phases[n] = phases.get(n, 0.0) + d["ms"]
+            top, _ = step_top_phase(r)
+            if top:
+                wins[top] = wins.get(top, 0) + 1
         out["ranks"][str(rank)] = {
-            "steps": agg["steps"],
+            "steps": len(recs),
             "wall_ms": round(wall, 2),
-            "attributed_fraction": (round(agg["attributed_ms"] / wall, 4)
-                                    if wall else 0.0),
-            "stall_fraction": (round(agg["stall_ms"] / wall, 4)
-                               if wall else 0.0),
-            "overlap_ms": round(agg["overlap_ms"], 2),
-            "phases_ms": {n: round(v, 2)
-                          for n, v in agg["phases_ms"].items()},
-            "critical_path_wins": agg["critical_path_wins"],
+            "attributed_fraction": (
+                round(sum(r["attributed_ms"] for r in recs) / wall, 4)
+                if wall else 0.0),
+            "stall_fraction": (
+                round(sum(r["stall_ms"] for r in recs) / wall, 4)
+                if wall else 0.0),
+            "overlap_ms": round(sum(r["overlap_ms"] for r in recs), 2),
+            "phases_ms": {n: round(v, 2) for n, v in sorted(phases.items())},
+            "critical_path_wins": dict(
+                sorted(wins.items(), key=lambda kv: -kv[1])),
             "stall_histogram": _stall_histogram(recs),
-            "recompile_steps": agg["recompile_steps"],
-            "retraces_by_fn": agg["retraces_by_fn"],
+            "recompile_steps": [
+                {"step": r["step"], "name": r["name"],
+                 "compiles": len(r["compiles"]),
+                 "steady": sum(c["steady"] for c in r["compiles"]),
+                 "funs": sorted({c["fun"] for c in r["compiles"]})}
+                for r in recs if r["compiles"]],
         }
     return out
 
@@ -196,20 +212,17 @@ def render_report(steps: List[Dict], max_steps: int = 20) -> str:
         lines.append("stall histogram: " + "  ".join(
             f"{b}:{n}" for b, n in d["stall_histogram"]))
         if d["recompile_steps"]:
-            lines.append("recompiles (step: compiles / by fn):")
+            lines.append("recompiles (step: compiles, steady / functions):")
             for e in d["recompile_steps"][:16]:
-                by = ("  " + ", ".join(f"{f}+{k}"
-                                       for f, k in e["by_fn"].items())
-                      if e["by_fn"] else "")
                 lines.append(f"  step {e['step']} [{e['name']}]: "
-                             f"{e['compiles']}{by}")
+                             f"{e['compiles']}, {e['steady']} steady  "
+                             + ", ".join(e["funs"]))
         else:
             lines.append("recompiles: none")
         recs = [r for r in steps if str(r.get("rank", 0)) == rank]
         lines.append("")
         lines.append(f"{'step':>5} {'name':<18} {'wall_ms':>9} "
                      f"{'top phase':<24} {'stall%':>7} {'overlap':>8}")
-        from multiverso_tpu.telemetry.profiler import step_top_phase
         for r in recs[:max_steps]:
             top_n, top_ms = step_top_phase(r)
             top_s = f"{top_n} ({top_ms:.1f} ms)" if top_n else "-"
@@ -225,107 +238,35 @@ def render_report(steps: List[Dict], max_steps: int = 20) -> str:
     return "\n".join(lines).rstrip()
 
 
-# ---------------------------------------------------------------------- #
-# perfetto timeline
-# ---------------------------------------------------------------------- #
-def to_perfetto(steps: List[Dict], spans: List[Dict],
-                out_path: Optional[str]) -> Dict:
-    """Profile records + trace spans -> one traceEvents envelope. One
-    track per phase per rank: pid = rank, tid = a small stable index
-    per track name with thread_name metadata, so Perfetto renders
-    "step", each phase, and each async-span name as parallel lanes.
-    PR-3 trace spans keep their own (pid=rank, tid=thread) tracks —
-    same wall-clock microsecond timebase, one timeline."""
-    events: List[Dict] = []
-    tids: Dict[Tuple[int, str], int] = {}
-
-    def tid_for(rank: int, track: str) -> int:
-        key = (rank, track)
-        t = tids.get(key)
-        if t is None:
-            t = tids[key] = len([k for k in tids if k[0] == rank]) + 1
-            events.append({"ph": "M", "name": "thread_name", "pid": rank,
-                           "tid": t, "args": {"name": track}})
-        return t
-
-    for r in steps:
-        rank = int(r.get("rank", 0))
-        t0_us = int(float(r.get("ts", 0.0)) * 1e6)
-        events.append({
-            "name": f"{r.get('name', 'step')}#{r.get('step')}",
-            "cat": "profile", "ph": "X", "ts": t0_us,
-            "dur": int(float(r.get("wall_ms", 0.0)) * 1e3),
-            "pid": rank, "tid": tid_for(rank, "step"),
-            "args": {"stall_fraction": r.get("stall_fraction"),
-                     "attributed_fraction": r.get("attributed_fraction"),
-                     "compiles": r.get("jax", {}).get("compiles", 0)}})
-        for span in r.get("spans", []):
-            kind, name, a_us, b_us = span[0], span[1], span[2], span[3]
-            track = name if kind == "phase" else f"async:{name}"
-            ev = {"name": name, "cat": kind, "ph": "X",
-                  "ts": t0_us + int(a_us),
-                  "dur": max(int(b_us) - int(a_us), 1),
-                  "pid": rank, "tid": tid_for(rank, track)}
-            if len(span) > 4 and span[4] == "open":
-                ev["args"] = {"open_at_step_end": True}
-            events.append(ev)
-    events.extend(spans)   # PR-3 trace spans: already trace_event shaped
-    envelope = {"traceEvents": events, "displayTimeUnit": "ms"}
-    if out_path:
-        with open(out_path, "w") as f:
-            json.dump(envelope, f)
-    return envelope
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        prog="mvprof",
-        description="per-step critical-path report / Perfetto timeline")
+        prog="mvprof", description="per-step critical-path report")
     ap.add_argument("paths", nargs="+",
-                    help="metrics dir(s) and/or profile/trace JSONL files")
-    ap.add_argument("--report", action="store_true",
-                    help="print the critical-path report (default)")
-    ap.add_argument("--to-perfetto", metavar="OUT.json", default=None,
-                    help="write a Perfetto/chrome traceEvents envelope")
+                    help="metrics dir(s) and/or trace JSONL files")
     ap.add_argument("--json", action="store_true",
                     help="emit the report as JSON instead of tables")
     ap.add_argument("--steps", type=int, default=20,
                     help="per-rank step rows shown in the report")
     args = ap.parse_args(argv)
 
-    steps, spans = collect(args.paths)
+    steps = collect(args.paths)
     hygiene = collect_hygiene(args.paths)
     if not steps and not hygiene:
-        print("mvprof: no step records found (is step_profile on and "
-              "metrics_dir set?)", file=sys.stderr)
+        print("mvprof: no step span found (does the loop mark its steps, "
+              "and is metrics_dir set?)", file=sys.stderr)
         return 1
-    did = False
-    if args.to_perfetto:
-        if not steps:
-            # an explicitly requested export must fail loudly, not
-            # exit 0 with the output file silently never written
-            print("mvprof: --to-perfetto needs step records; the "
-                  "given paths hold only compile-hygiene reports",
-                  file=sys.stderr)
-            return 1
-        env = to_perfetto(steps, spans, args.to_perfetto)
-        print(f"wrote {len(env['traceEvents'])} events "
-              f"({len(steps)} steps, {len(spans)} trace spans) to "
-              f"{args.to_perfetto}")
-        did = True
-    if args.report or args.json or not did:
-        if args.json:
-            data = report_data(steps) if steps else {}
-            if hygiene:
-                data["hygiene"] = hygiene
-            print(json.dumps(data))
-        else:
-            parts = []
-            if steps:
-                parts.append(render_report(steps, args.steps))
-            if hygiene:
-                parts.append(render_hygiene(hygiene))
-            print("\n\n".join(parts))
+    if args.json:
+        data = report_data(steps) if steps else {}
+        if hygiene:
+            data["hygiene"] = hygiene
+        print(json.dumps(data))
+    else:
+        parts = []
+        if steps:
+            parts.append(render_report(steps, args.steps))
+        if hygiene:
+            parts.append(render_hygiene(hygiene))
+        print("\n\n".join(parts))
     return 0
 
 

@@ -177,9 +177,10 @@ def render(rec: Dict, prev: Optional[Dict] = None,
             status += "*"       # health answered, stats did not
         # incarnation generation: gen>0 = this rank was respawned by
         # the failover plane (the at-a-glance restarted-shard signal).
-        # stall% / recomp come from the step profiler's MSG_STATS block
-        # (flag step_profile): wall time no phase claimed, and
-        # steady-state recompiles past step 1 — "-" when not profiling
+        # stall% / recomp come from the MSG_STATS profile block
+        # (trace.step_summary): wall time no span claimed, and
+        # steady-state recompiles past step 1 — "-" where no loop
+        # marks its steps
         lines.append(
             f"{r:<5} {status:<12} {_fmt(e.get('gen')):>4} "
             f"{_fmt(e.get('addr')):<22} "
