@@ -18,6 +18,10 @@ rule, a falling finite loss, ``jnp.take``,
           one tile against its sub-tiles: ms a call and compile seconds
   ssd     the chunked state-space scan at the hybrid cell's shapes: ms
           forward and backward, and its error against the recurrence
+  taps    the mixers' short causal convolution at the delta and hybrid
+          cells' shapes: ms forward and backward of the two kernels and of
+          the plain form, and both against the convolution a position at
+          a time
   delta   the chunked gated delta rule at the delta cell's shapes: ms
           forward and backward for each way of making its triangular
           inverse, and its error against the recurrence
@@ -1300,6 +1304,107 @@ def stage_conv(sequences: int = 2, positions: int = 8192, dim: int = 2048,
     return facts
 
 
+# the two cells' convolutions: (name, channels, a bias or none)
+TAPS_CALLS = (("delta", 8192, False), ("ssm", 6144, True))
+# float32 against float32, as max|err| over max|reference|: the sums of
+# 16,384 positions' products in another order
+TAPS_F32_TOL = 2e-5
+
+
+def stage_taps(positions: int = 16384, calls: Tuple = TAPS_CALLS,
+               taps: int = 4, repeats: int = 10,
+               check_positions: int = 2048, tilings: Tuple = (),
+               tile: Optional[Tuple[int, int]] = None,
+               interpret: bool = False) -> Dict[str, Any]:
+    """``ops/short_conv.causal_taps`` as ``qwen3next-train-16k`` and
+    ``nemotron3n-train-16k`` call it (ONE sequence of ``positions``, float32,
+    a silu; ``calls``: the channels, and whether a bias): the ms a call,
+    forward and forward with every gradient (``dx``, ``dw``, ``dbias``), by
+    this process's clock around ``repeats`` calls it waits for, of the
+    kernels (``tile`` and ``interpret`` are a CPU test's; on the chip the
+    op chooses) and of the plain form beside them; the least the chip's
+    memory allows each (one read and one write of the array forward, two
+    reads and a write more backward, over the HBM peak of
+    ``benchmark/peaks.json``); and both forms' output and gradients on the
+    first ``check_positions`` against the convolution a position at a time
+    in float32 (max|err| over max|reference|). ``tilings`` reads other
+    ((position tile, channel tile), rows at a time) of the kernels."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import shapes
+    from multiverso_tpu.ops import short_conv
+
+    def recurrence(x, w, bias):         # one sequence, from the definition
+        def one(past, xt):
+            window = jnp.concatenate([past, xt[None]], 0)
+            pre = jnp.sum(window * w, 0) + (0.0 if bias is None else bias)
+            return window[1:], jax.nn.silu(pre)
+
+        return jax.lax.scan(one, jnp.zeros((taps - 1, x.shape[-1])), x)[1]
+
+    device = jax.devices()[0]
+    facts: Dict[str, Any] = {}
+    for name, channels, has_bias in calls:
+        k = jax.random.split(jax.random.key(SEED), 4)
+        x = jax.random.normal(k[0], (1, positions, channels))
+        w = taps ** -0.5 * jax.random.normal(k[1], (taps, channels))
+        bias = jax.random.normal(k[2], (channels,)) if has_bias else None
+        weight = jax.random.normal(k[3], x.shape)
+        wrt = (0, 1, 2) if has_bias else (0, 1)
+
+        def both(conv):     # the weight is an operand, not a constant
+            return lambda x, w, bias, weight: jax.value_and_grad(
+                lambda *t: jnp.sum(weight * conv(*t)), wrt)(x, w, bias)
+
+        kernel = lambda *t, tile=tile, rows=short_conv.ROWS: (
+            short_conv.causal_taps(*t, True, tile=tile, rows=rows,
+                                   interpret=interpret))
+        plain = lambda *t: short_conv.plain(*t, True)
+        forms = [("", kernel), ("plain_", plain)] + [
+            (f"{ts}x{tc}r{rows}_", functools.partial(
+                kernel, tile=(ts, tc), rows=rows))
+            for (ts, tc), rows in tilings]
+        for tag, conv in forms:
+            for what, fn, args in (
+                    ("fwd", conv, (x, w, bias)),
+                    ("fwd_bwd", both(conv), (x, w, bias, weight))):
+                compile_s, ms, _ = _timed(fn, args, repeats)
+                facts[f"{name}_{tag}{what}_ms"] = ms
+                facts[f"{name}_{tag}{what}_compile_s"] = compile_s
+        if device.platform == "tpu":
+            one = 4 * positions * channels / shapes.peak(
+                device.device_kind, "hbm_bytes_per_s") * 1e3
+            facts[f"{name}_fwd_least_ms"] = round(2 * one, 3)
+            facts[f"{name}_fwd_bwd_least_ms"] = round(5 * one, 3)
+        # a failed check keeps the readings
+        _say("taps.timed", **{k: v for k, v in facts.items()
+                              if k.startswith(name + "_")})
+        n = min(check_positions, positions)
+        few = (x[:, :n], w, bias, weight[:, :n])
+        y_want = jax.jit(recurrence)(few[0][0], w, bias)
+        want = jax.jit(both(lambda x, w, bias: recurrence(
+            x[0], w, bias)[None]))(*few)
+        for tag, conv in forms[:2]:
+            got = jax.jit(both(conv))(*few)
+            errs = [float(jnp.max(jnp.abs(g - t)) / jnp.max(jnp.abs(t)))
+                    for g, t in zip(jax.tree.leaves(got),
+                                    jax.tree.leaves(want))]
+            # the output's own error in the weighted sum's place
+            errs[0] = float(jnp.max(jnp.abs(jax.jit(conv)(*few[:3])[0]
+                                            - y_want))
+                            / jnp.max(jnp.abs(y_want)))
+            if not max(errs) <= TAPS_F32_TOL:       # a NaN fails too
+                raise AssertionError(
+                    f"taps: {name} {tag or 'kernel'} relative error {errs} "
+                    f"(y, dx, dw[, dbias]) > {TAPS_F32_TOL}")
+            facts[f"{name}_{tag}rel_err_y_dx_dw_db"] = [
+                float(f"{e:.3g}") for e in errs]
+    facts["kernels"] = bool(tile or short_conv.kernel_tiles(
+        positions, calls[0][1]))
+    return facts
+
+
 def _inverse_by(how: str):
     """The ways of making ``T = (I + M)^-1`` that stage ``delta`` reads:
     ``ops/delta_rule.unit_lower_inverse`` (``solve``: XLA's triangular
@@ -1895,8 +2000,8 @@ STAGES: Tuple[Tuple[str, Callable[[], Dict[str, Any]]], ...] = (
     ("ps", stage_ps),
     ("lm", stage_lm), ("flash", lambda: stage_flash(selected={})),
     ("ssd", stage_ssd),
-    ("conv", stage_conv), ("delta", stage_delta), ("select", stage_select),
-    ("target", stage_target),
+    ("conv", stage_conv), ("taps", stage_taps), ("delta", stage_delta),
+    ("select", stage_select), ("target", stage_target),
     ("memory", stage_memory))
 
 

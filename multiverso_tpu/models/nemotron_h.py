@@ -37,6 +37,7 @@ import jax.numpy as jnp
 
 from multiverso_tpu.models import gqa_moe, mla_moe
 from multiverso_tpu.models.mla_moe import Layer
+from multiverso_tpu.ops.short_conv import causal_taps, step_counts
 from multiverso_tpu.ops.ssd import ssd_chunked
 
 # a block's kind by its letter in ``hybrid_override_pattern``
@@ -93,9 +94,12 @@ class NemotronHConfig(NamedTuple):
 
     def ssm_grid(self, s: int) -> Dict[str, int]:
         """The scan's static counts over ``s`` positions, as ``lm.step``
-        spans carry them."""
+        spans carry them, and the mixers' short convolution's
+        (``short_conv.step_counts``)."""
+        mixers = sum(layer.attn == "ssm" for layer in self.layers())
         return {"ssm_chunks": s // self.chunk, "ssm_heads": self.ssm_heads,
-                "ssm_state": self.ssm_state}
+                "ssm_state": self.ssm_state,
+                **step_counts(mixers, s, mamba2_shapes(self)["conv_w"][1])}
 
     @property
     def first_values(self) -> Dict[str, Any]:
@@ -156,14 +160,16 @@ def mamba2(u, p, cfg):
     inner, dt_ = h * hd, cfg.compute_dtype
     conv = inner + 2 * g * n
     with jax.named_scope("mv.lm.ssm"):
-        proj = mla_moe.matmul(u, p["win"], False, dt_, jnp.float32)
-        z, xbc, dt = jnp.split(proj, (inner, inner + conv), axis=-1)
+        # the convolution's operand is a product of its own, from the
+        # table's column window: a kernel takes no fusion, and a window of
+        # ONE product's result would be copied out for it (0.4 GB a pass)
+        z_w, xbc_w, dt_w = jnp.split(p["win"], (inner, inner + conv), axis=-1)
+        xbc = mla_moe.matmul(u, xbc_w, False, dt_, jnp.float32)
+        z, dt = jnp.split(
+            mla_moe.matmul(u, jnp.concatenate([z_w, dt_w], -1), False, dt_,
+                           jnp.float32), (inner,), axis=-1)
         with jax.named_scope("mv.lm.ssm.conv"):
-            taps = cfg.conv_kernel
-            past = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
-            xbc = p["conv_b"] + sum(past[:, i:i + s] * p["conv_w"][i]
-                                    for i in range(taps))
-            xbc = jax.nn.silu(xbc)
+            xbc = causal_taps(xbc, p["conv_w"], p["conv_b"], True)
         x, bm, cm = jnp.split(xbc, (inner, inner + g * n), axis=-1)
         x = x.reshape(b, s, h, hd)
         dt = jax.nn.softplus(dt + p["dt_bias"])

@@ -50,6 +50,7 @@ from jax.ad_checkpoint import checkpoint_name
 from multiverso_tpu.models import gqa_moe, mla_moe
 from multiverso_tpu.models.mla_moe import Layer
 from multiverso_tpu.ops.delta_rule import gated_delta_chunked
+from multiverso_tpu.ops.short_conv import causal_taps, step_counts
 
 # what a rematerialised block keeps of a linear-attention mixer: the rule's
 # result, float32 [B, S, Hv, dv] (``mla_moe.kept_names``)
@@ -113,7 +114,8 @@ class Qwen3NextConfig(NamedTuple):
         (every mixer's and feed-forward's products, a router's, the held
         experts' at the even share of ``top_k * experts_held / n_experts``
         experts a token, the shared expert and its gate, the causal core's
-        two products over ``(s + 1) / 2`` keys a query, and the head's)."""
+        two products over ``(s + 1) / 2`` keys a query, and the head's);
+        and the mixers' short convolution's (``short_conv.step_counts``)."""
         d, hd, h = self.dim, self.head_dim, self.n_heads
         mixer = {"delta": mixer_flops_token(self),
                  # q, gate, k, v, o; then Q K^T and P V
@@ -124,7 +126,8 @@ class Qwen3NextConfig(NamedTuple):
                // self.n_experts)
         layers = self.layers()
         deltas = sum(layer.attn == "delta" for layer in layers)
-        return {"delta_layers": deltas, "delta_chunks": s // self.delta_chunk,
+        return {**step_counts(deltas, s, delta_shapes(self)["conv_w"][1]),
+                "delta_layers": deltas, "delta_chunks": s // self.delta_chunk,
                 "delta_heads": self.lin_value_heads,
                 "delta_chunk": self.delta_chunk,
                 "delta_state": self.lin_key_dim * self.lin_value_dim,
@@ -227,14 +230,15 @@ def gated_delta_net(u, p, cfg):
     key, value = hk * dk, hv * dv
 
     def feed(u, wqkvz, wba, conv_w, a_log, dt_bias):
-        proj = mla_moe.matmul(u, wqkvz, False, dt_, jnp.float32)
+        # the convolution's operand is a product of its own, from the
+        # table's column window: a kernel takes no fusion, and a window of
+        # ONE product's result would be copied out for it (0.5 GB a pass)
+        wide = 2 * key + value
+        qkv = mla_moe.matmul(u, wqkvz[:, :wide], False, dt_, jnp.float32)
+        z = mla_moe.matmul(u, wqkvz[:, wide:], False, dt_, jnp.float32)
         ba = mla_moe.matmul(u, wba, False, dt_, jnp.float32)
-        qkv, z = jnp.split(proj, (2 * key + value,), axis=-1)
         with jax.named_scope("mv.lm.delta.conv"):
-            taps = cfg.conv_kernel
-            past = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0)))
-            qkv = jax.nn.silu(sum(past[:, i:i + s] * conv_w[i]
-                                  for i in range(taps)))
+            qkv = causal_taps(qkv, conv_w, None, True)
         q, k, v = jnp.split(qkv, (key, 2 * key), axis=-1)
         with jax.named_scope("mv.lm.delta.gates"):
             unit = lambda t: t * jax.lax.rsqrt(

@@ -214,6 +214,38 @@ def test_conv_stage():
         "TPU v5 lite", "hbm_bytes_per_s") * 1e3 - 0.328) < 1e-3
 
 
+def test_taps_stage():
+    """The mixers' short convolution at a small size: ms and compile
+    seconds forward and with every gradient of the kernels (interpreted
+    here) and of the plain form, both held to the convolution a position at
+    a time across four position tiles; the least the memory allows is a
+    chip's number and is not made up here."""
+    calls = (("delta", 256, False), ("ssm", 128, True))
+    facts = chip_smoke.stage_taps(positions=128, calls=calls, repeats=1,
+                                  check_positions=128, tile=(32, 128),
+                                  interpret=True, tilings=(((64, 128), 8),))
+    assert facts["kernels"] is True
+    for name, _, has_bias in calls:
+        for tag in ("", "plain_", "64x128r8_"):
+            for what in ("fwd", "fwd_bwd"):
+                assert facts[f"{name}_{tag}{what}_ms"] > 0
+                assert facts[f"{name}_{tag}{what}_compile_s"] >= 0
+        assert f"{name}_fwd_least_ms" not in facts
+        for tag in ("", "plain_"):
+            errs = facts[f"{name}_{tag}rel_err_y_dx_dw_db"]
+            assert len(errs) == 3 + has_bias
+            assert max(errs) <= chip_smoke.TAPS_F32_TOL
+    assert "taps" in dict(chip_smoke.STAGES)
+    # off the chip the op itself takes the plain form
+    assert chip_smoke.stage_taps(positions=64, calls=calls[:1], repeats=1,
+                                 check_positions=64)["kernels"] is False
+    # what the chip's readings are held against, at the cells' shapes
+    from benchmark import shapes
+    least = lambda c: 2 * 4 * 16384 * c / shapes.peak(
+        "TPU v5 lite", "hbm_bytes_per_s") * 1e3
+    assert abs(least(8192) - 1.311) < 1e-3 and abs(least(6144) - 0.983) < 1e-3
+
+
 def test_select_stage():
     """The indexer and the selection at a small size: ms of the three
     products, of the whole selection and of a chunk by the counting search

@@ -125,9 +125,11 @@ def test_the_four_older_models_keep_their_heads_and_spans():
         assert not mla_moe.tied_head(cfg)
         assert shapes["head"] == shapes["embed"] == (cfg.vocab, cfg.dim)
         grid = mla_moe.mixer_grid(cfg, 64)
-        assert not any(k.startswith("conv") or k in (
-            "tied_head", "mixer_flops_token", "step_flops_token")
-                       for k in grid)
+        # (``conv_kernel_layers`` and ``conv_bytes`` are the state-space
+        # mixer's own short convolution's, PR 59)
+        assert not any(k in ("conv_layers", "conv_taps", "conv_width",
+                             "tied_head", "mixer_flops_token",
+                             "step_flops_token") for k in grid)
     assert mla_moe.mixer_grid(afmoe.AFMoEConfig(), 64) == {}
     assert mla_moe.mixer_grid(nemotron_h.NemotronHConfig(), 64)[
         "block_kinds"] == "ssm,shared+experts,ssm,full,shared+experts"
@@ -470,7 +472,9 @@ def test_one_step_through_the_adam_tables_is_reference_gradient_plus_adam():
     nemotron = mla_moe.mixer_grid(nemotron_h.NemotronHConfig(), 64)
     assert dump_metrics._mixer_lines(
         [{"name": "lm.step", "args": nemotron}]) == [
-            "  blocks: ssm,shared+experts,ssm,full,shared+experts"]
+            "  blocks: ssm,shared+experts,ssm,full,shared+experts",
+            "    short convolution: the kernels in 0 mixer(s) (0: the plain "
+            "form), 0 MB a mixer a pass at the least"]
 
 
 def _published():

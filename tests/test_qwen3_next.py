@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -844,9 +845,13 @@ def test_published_sizes_give_the_configurations_parameter_count():
 # blocks' policy keeps, so the lowered backward pass makes no selection
 # again. ``qwen3_next`` (no entry) names the delta rule's result in the
 # same PR: ``test_a_remade_block_runs_the_rules_forward_scans_once_fewer``
-# holds what changed there.
+# holds what changed there. ``nemotron_h`` is THIS tree's text since PR 59
+# (the parent's was f19145b4231045ba): the mixer makes its convolution's
+# operand as a product of its own from the table's column window, and
+# ``test_the_in_projections_two_products_are_the_parents_one`` holds the
+# step to the parent's one product, as it does ``qwen3_next``'s.
 PARENT = {"mla": "1176c1bf3112ad40", "gqa": "f3ca378f315449a4",
-          "afmoe": "06ebccb9b2fd87b1", "nemotron_h": "f19145b4231045ba",
+          "afmoe": "06ebccb9b2fd87b1", "nemotron_h": "cb7c9d0aae93395e",
           "lfm2": "d8be808c75a5fa2e", "keye": "6d0bfafb9008b220"}
 MODELS = {"mla": mla_moe.MLAMoEConfig, "gqa": gqa_moe.GQAMoEConfig,
           "afmoe": afmoe.AFMoEConfig, "nemotron_h": nemotron_h.NemotronHConfig,
@@ -862,3 +867,114 @@ def test_the_six_older_models_steps_lower_to_the_parents_text(name):
         lambda p, b, t: mla_moe.loss_fn(p, b, t, cfg), has_aux=True)).lower(
             params, bias, jnp.zeros((2, 64), jnp.int32)).as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT[name]
+
+
+# ---------------------------------------------------------------------- #
+# PR 59: the convolution's operand is a product of its own
+# ---------------------------------------------------------------------- #
+def _parents_mamba2(u, p, cfg):
+    """``nemotron_h.mamba2`` as the parent commit had it: ONE in-projection,
+    its result split, the taps as shifted slices of a padded array."""
+    from multiverso_tpu.ops.ssd import ssd_chunked
+    b, s, _ = u.shape
+    h, hd, g, n = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                   cfg.ssm_state)
+    inner, dt_ = h * hd, cfg.compute_dtype
+    conv = inner + 2 * g * n
+    proj = mla_moe.matmul(u, p["win"], False, dt_, jnp.float32)
+    z, xbc, dt = jnp.split(proj, (inner, inner + conv), axis=-1)
+    taps = cfg.conv_kernel
+    past = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
+    xbc = p["conv_b"] + sum(past[:, i:i + s] * p["conv_w"][i]
+                            for i in range(taps))
+    xbc = jax.nn.silu(xbc)
+    x, bm, cm = jnp.split(xbc, (inner, inner + g * n), axis=-1)
+    x = x.reshape(b, s, h, hd)
+    dt = jax.nn.softplus(dt + p["dt_bias"])
+    y = ssd_chunked(x, dt, -jnp.exp(p["a_log"]), bm.reshape(b, s, g, n),
+                    cm.reshape(b, s, g, n), cfg.chunk, dt_)
+    y = y + p["skip"][:, None] * x
+    y = (y.reshape(b, s, inner) * jax.nn.silu(z)).reshape(b, s, g, inner // g)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + cfg.eps)
+    y = y.reshape(b, s, inner) * p["gate_norm"]
+    return mla_moe.matmul(y, p["wout"], False, dt_, jnp.float32)
+
+
+def _parents_delta_net(u, p, cfg):
+    """``qwen3_next.gated_delta_net`` as the parent commit had it."""
+    b, s, _ = u.shape
+    hk, hv = cfg.lin_key_heads, cfg.lin_value_heads
+    dk, dv, dt_ = cfg.lin_key_dim, cfg.lin_value_dim, cfg.compute_dtype
+    key, value = hk * dk, hv * dv
+
+    def feed(u, wqkvz, wba, conv_w, a_log, dt_bias):
+        proj = mla_moe.matmul(u, wqkvz, False, dt_, jnp.float32)
+        ba = mla_moe.matmul(u, wba, False, dt_, jnp.float32)
+        qkv, z = jnp.split(proj, (2 * key + value,), axis=-1)
+        taps = cfg.conv_kernel
+        past = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0)))
+        qkv = jax.nn.silu(sum(past[:, i:i + s] * conv_w[i]
+                              for i in range(taps)))
+        q, k, v = jnp.split(qkv, (key, 2 * key), axis=-1)
+        unit = lambda t: t * jax.lax.rsqrt(
+            jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+        q = unit(q.reshape(b, s, hk, dk)) * dk ** -0.5
+        k = unit(k.reshape(b, s, hk, dk))
+        beta = jax.nn.sigmoid(ba[..., :hv])
+        g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
+        return q, k, v.reshape(b, s, hv, dv), g, beta, z
+
+    def close(o, z, gain, wout):
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                              + cfg.eps) * gain
+        o = (o * jax.nn.silu(z.reshape(b, s, hv, dv))).reshape(b, s, value)
+        return mla_moe.matmul(o, wout, False, dt_, jnp.float32)
+
+    q, k, v, g, beta, z = jax.checkpoint(feed)(
+        u, p["wqkvz"], p["wba"], p["conv_w"], p["a_log"], p["dt_bias"])
+    o = checkpoint_name(
+        gated_delta_chunked(q, k, v, g, beta, cfg.delta_chunk, dt_),
+        qwen3_next.KEPT_NAMES[0])
+    return jax.checkpoint(close)(o, z, p["gate_norm"], p["wout"])
+
+
+@pytest.mark.parametrize("name", ["nemotron_h", "qwen3_next"])
+@pytest.mark.parametrize("level", [None, 0], ids=["default", "as_written"])
+def test_the_in_projections_two_products_are_the_parents_one(
+        name, level, monkeypatch):
+    """The mixers make the convolution's operand as a product of its own
+    from the column window of the one table they have (PR 59): every output
+    column is the dot product it was, so the step's LOSS is the parent's
+    (the parent's mixers, one product and its result split, are kept above
+    and stand in the model's place for the comparison): bit for bit with
+    LLVM at level 0, every float operation done as it is written, and to
+    the last place as XLA:CPU compiles by default (it blocks a product by
+    its width: ``conftest.same_floats`` says the same of PR 38's). The
+    gradients are the parent's to float32's last places and no nearer at
+    either level: the gradient to the mixer's input was one sum over all
+    the table's columns and is now the sum of two, over the window and
+    over the rest, and a float32 sum in another order rounds otherwise."""
+    module, mixer, parents, cfg = {
+        "nemotron_h": (nemotron_h, "mamba2", _parents_mamba2,
+                       nemotron_h.NemotronHConfig(attn="xla")),
+        "qwen3_next": (qwen3_next, "gated_delta_net", _parents_delta_net,
+                       CFG)}[name]
+    params = mla_moe.init(cfg, 3, 0.1, scales={"embed": 1.0, "conv_w": 0.3})
+    bias = mla_moe.init_bias(cfg)
+    tokens = jax.random.randint(jax.random.key(5), (2, 64), 0, cfg.vocab)
+    options = ({} if level is None
+               else {"xla_backend_optimization_level": level})
+    step = lambda: jax.jit(jax.value_and_grad(
+        lambda p: mla_moe.loss_fn(p, bias, tokens, cfg)[0]),
+        compiler_options=options)(params)
+    loss, grads = step()
+    monkeypatch.setattr(module, mixer, parents)
+    want_loss, want = step()
+    if level == 0:
+        assert np.array_equal(np.asarray(loss), np.asarray(want_loss))
+    assert abs(float(loss) - float(want_loss)) <= 1e-6 * float(want_loss)
+    assert sorted(grads) == sorted(want)
+    worst = max((float(np.abs(np.asarray(grads[n]) - np.asarray(want[n])).max()
+                       / np.abs(np.asarray(want[n])).max()), n)
+                for n in want if np.abs(np.asarray(want[n])).max() > 0)
+    assert worst[0] < 5e-5, worst       # 7.3e-6 at the most, read here

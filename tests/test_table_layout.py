@@ -1068,3 +1068,49 @@ def test_the_delta_mixer_compiles_for_a_v5e_at_16k_positions(one_chip):
         f32(1, 16384, 2048), p).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
     assert "while" in compiled.as_text()        # the scan over chunks
+
+
+@pytest.mark.parametrize("kind", ["delta", "ssm"])
+def test_the_mixers_short_convolution_kernels_compile_for_a_v5e(
+        one_chip, monkeypatch, kind):
+    """``ops/short_conv.causal_taps`` inside ``qwen3_next.gated_delta_net``
+    ([1, 16384, 8192], no bias) and ``nemotron_h.mamba2`` ([1, 16384, 6144],
+    a bias) as the two cells call them, forward and backward: Mosaic
+    compiles both kernels at tiles of 512 x 512, and the convolution's
+    operand reaches them as the in-projection's own product leaves it (the
+    mixers slice the WEIGHT: a column window of one product's result would
+    be copied out before a custom call, 0.5 and 0.4 GB a pass)."""
+    from multiverso_tpu.models import nemotron_h
+    from multiverso_tpu.ops import short_conv
+
+    # the process's devices are the CPU's: the rule would take the plain form
+    monkeypatch.setattr(short_conv, "kernel_tiles",
+                        lambda s, c, dtype=None: (512, 512))
+    cfg, dim, channels = {
+        "delta": (_qwen3next(), 2048, 8192),
+        "ssm": (nemotron_h.NemotronHConfig(
+            dim=2688, ssm_heads=64, ssm_head_dim=64, ssm_groups=8,
+            ssm_state=128, chunk=128), 2688, 6144)}[kind]
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {n: f32(*s) for n, s in cfg.attn_shapes(kind).items()}
+    assert p["conv_w"].shape == (4, channels)
+    text = jax.jit(jax.grad(lambda u, p: cfg.attend(u, p, kind).sum(),
+                            argnums=(0, 1))).lower(
+        f32(1, 16384, dim), p).compile().as_text()
+    calls = {name: re.findall(rf"%{name}[.\d]* = [^\n]*? custom-call\(([^)]*)\)",
+                              text)
+             for name in (short_conv.FWD, short_conv.BWD)}
+    # the delta mixer's feed is rematerialised: its forward runs again
+    assert len(calls[short_conv.FWD]) == 1 + (kind == "delta")
+    assert len(calls[short_conv.BWD]) == 1
+    shape = f"f32[1,16384,{channels}]"
+    for name, found in calls.items():
+        for operands in found:
+            x = operands.split(",")[0].strip()
+            # the operand is the product's own result: a fusion that ends
+            # in the convolution, no copy and no slice of it
+            assert x.startswith("%convolution"), (name, x)
+            made = re.search(rf"{re.escape(x)} = {re.escape(shape)}[^\n]*",
+                             text).group(0)
+            assert "dot_general" in made, made
+    assert not re.search(rf"= {re.escape(shape)}\S* (copy|slice)\(", text)

@@ -760,7 +760,8 @@ def _mixer_lines(events: List[Dict]) -> List[str]:
     those the kernels compute over the selected; where some are delta-rule
     mixers (``mv.lm.delta*`` in the table above), their scan's counts and
     their part of a token's forward operations
-    (``delta.mixer_flops_share``'s two counts)."""
+    (``delta.mixer_flops_share``'s two counts); where a mixer has a short
+    causal convolution (``ops/short_conv``), how many run its kernels."""
     args = next((e["args"] for e in events if e.get("name") == "lm.step"
                  and "block_kinds" in e.get("args", {})), None)
     if args is None:
@@ -792,6 +793,11 @@ def _mixer_lines(events: List[Dict]) -> List[str]:
             f"dependent scan steps a group, a state of "
             f"{args['delta_state']} floats a head; {mixers} of {step} "
             f"forward operations a token = {100.0 * mixers / step:.2f}%")
+    if "conv_kernel_layers" in args:
+        out.append(
+            f"    short convolution: the kernels in "
+            f"{args['conv_kernel_layers']} mixer(s) (0: the plain form), "
+            f"{args['conv_bytes'] / 1e6:.0f} MB a mixer a pass at the least")
     return out
 
 
