@@ -1040,6 +1040,8 @@ FLASH_CALLS = (
     ("lfm2.causal", (2, 32, 8192, 64), 8, (1024, 1024), None),
     # qwen3next-train-16k's: heads of 256, a group of 8 query heads
     ("qwen3next.causal", (1, 16, 16384, 256), 2, (512, 1024), None),
+    # xing4-train-4k's: queries and keys of 192, values (a sixth entry) of 128
+    ("xing4.causal", (1, 32, 4096, 192), 32, (1024, 1024), None, 128),
 )
 
 
@@ -1065,13 +1067,13 @@ def stage_flash(calls: Tuple = FLASH_CALLS, subs: Tuple = (),
 
     interpret = ak._resolve_interpret(None)
     out: Dict[str, Any] = {}
-    for n, (name, shape, hkv, blocks, window) in enumerate(calls):
+    for n, (name, shape, hkv, blocks, window, *own) in enumerate(calls):
         rng = np.random.default_rng(SEED + n)
         kv = (shape[0], hkv) + shape[2:]
-        q, g = (jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
-                for _ in range(2))
-        k, v = (jnp.asarray(rng.normal(size=kv), jnp.bfloat16)
-                for _ in range(2))
+        # the values' head size, and the output's: the keys' unless given
+        wide = lambda like: like[:3] + (own[0] if own else like[3],)
+        q, k, g, v = (jnp.asarray(rng.normal(size=size), jnp.bfloat16)
+                      for size in (shape, kv, wide(shape), wide(kv)))
         rule = ak.sub_tile(*ak._blocks(shape[2], *blocks), shape[3])
         facts: Dict[str, Any] = {"sub_tile": rule}
         # float32 attention on a few heads of one sequence, so that the
@@ -1402,6 +1404,88 @@ def stage_taps(positions: int = 16384, calls: Tuple = TAPS_CALLS,
                 float(f"{e:.3g}") for e in errs]
     facts["kernels"] = bool(tile or short_conv.kernel_tiles(
         positions, calls[0][1]))
+    return facts
+
+
+HC_F32_TOL = 1e-4
+
+
+def stage_hc(positions: int = 4096, dim: int = 3584, streams: int = 4,
+             iters: int = 20, repeats: int = 10,
+             check_positions: int = 256) -> Dict[str, Any]:
+    """ONE hyper-connected sublayer's stream maps alone at
+    ``xing4-train-4k``'s shapes (``models/mla_moe.block`` under ``streams``
+    residual streams round a branch that hands its normed input back): the
+    norm, the [24 x 14,336] projection, sigmoid, exp, Sinkhorn's ``iters``
+    rounds, the pre-mix and the write back to every stream. The ms a call
+    forward and forward with every gradient (the streams', the three
+    tables'), by this process's clock around ``repeats`` calls it waits
+    for; on a TPU the least the chip's memory allows each
+    (``benchmark/hc_shapes.py``: 2n + 2 arrays of ``dim`` floats a position
+    forward, 7n + 5 with the backward pass, over the HBM peak of
+    ``benchmark/peaks.json``); the largest ``abs(row or column sum of H_res
+    - 1)``; and the result and the gradients on the first
+    ``check_positions`` against ``benchmark/reference/xing4.sublayer`` in
+    float32 (max|err| over max|reference|)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import hc_shapes, shapes
+    from benchmark.reference import xing4 as ref
+    from multiverso_tpu.models import mla_moe, xing4
+
+    cfg = xing4.Xing4Config(dim=dim, streams=streams, sinkhorn_iters=iters)
+    outs = streams * streams + 2 * streams
+    k = jax.random.split(jax.random.key(SEED), 5)
+    x = jax.random.normal(k[0], (1, positions, streams, dim))
+    weight = jax.random.normal(k[1], x.shape)
+    p = {"attn_norm": jnp.ones((dim,)),
+         "attn.hc_phi": (streams * dim) ** -0.5 * jax.random.normal(
+             k[2], (outs, streams * dim)),
+         "attn.hc_b": jax.random.normal(k[3], (outs,)),
+         "attn.hc_alpha": jnp.ones((3,))}
+    handed_back = lambda u, p: u
+    maps = lambda x, p: mla_moe.block(x, p, handed_back, None, cfg)[::2]
+
+    def both(x, p, weight):     # the weight is an operand, not a constant
+        return jax.value_and_grad(
+            lambda x, p: jnp.sum(weight * maps(x, p)[0]), (0, 1))(x, p)
+
+    facts: Dict[str, Any] = {}
+    for what, fn, args in (("fwd", maps, (x, p)),
+                           ("fwd_bwd", both, (x, p, weight))):
+        compile_s, ms, res = _timed(fn, args, repeats)
+        facts[f"{what}_ms"], facts[f"{what}_compile_s"] = ms, compile_s
+        if what == "fwd":
+            facts["res_error"] = float(res[1])
+    device = jax.devices()[0]
+    if device.platform == "tpu":
+        c = {"hc_mult": streams, "hidden_size": dim}
+        whole = hc_shapes.sublayer_bytes(c, positions) / shapes.peak(
+            device.device_kind, "hbm_bytes_per_s") * 1e3
+        facts["fwd_least_ms"] = round(
+            whole * (2 * streams + 2) / (7 * streams + 5), 3)
+        facts["fwd_bwd_least_ms"] = round(whole, 3)
+    _say("hc.timed", **facts)       # a failed check keeps the readings
+    n = min(check_positions, positions)
+    c = dict(hc_mult=streams, hc_sinkhorn_iters=iters, hc_eps=cfg.hc_eps,
+             rms_norm_eps=cfg.eps, mhc_h_res_clamp_min=cfg.res_clamp[0],
+             mhc_h_res_clamp_max=cfg.res_clamp[1])
+    few = (x[:, :n], p, weight[:, :n])
+
+    def plain(x, p, weight):
+        return jax.value_and_grad(lambda x, p: jnp.sum(weight[0] * ref.sublayer(
+            x[0], p, "attn", lambda u: (u, None), c)[0]), (0, 1))(x, p)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(plain)(*few)
+    got = jax.jit(both)(*few)
+    errs = [float(jnp.max(jnp.abs(g - t)) / jnp.max(jnp.abs(t)))
+            for g, t in zip(jax.tree.leaves(got), jax.tree.leaves(want))]
+    if not max(errs) <= HC_F32_TOL:         # a NaN fails too
+        raise AssertionError(f"hc: relative error {errs} (the weighted sum, "
+                             f"dx, the tables' gradients) > {HC_F32_TOL}")
+    facts["rel_err"] = [float(f"{e:.3g}") for e in errs]
     return facts
 
 
@@ -2001,6 +2085,7 @@ STAGES: Tuple[Tuple[str, Callable[[], Dict[str, Any]]], ...] = (
     ("lm", stage_lm), ("flash", lambda: stage_flash(selected={})),
     ("ssd", stage_ssd),
     ("conv", stage_conv), ("taps", stage_taps), ("delta", stage_delta),
+    ("hc", stage_hc),
     ("select", stage_select), ("target", stage_target),
     ("memory", stage_memory))
 

@@ -124,9 +124,13 @@ class MLAMoEConfig(NamedTuple):
     def attend(self, u, p, kind: str):
         return mla(u, p, self)
 
+    # rotary frequencies as they are and scores over ``sqrt(nope + rope)``:
+    # :func:`mla`'s two switches (``models/xing4.py`` sets both)
+    yarn = softmax_scale = None
+
     @property
-    def head_size(self) -> int:
-        return self.v_head_dim
+    def head_size(self) -> int:      # the wider of the core's two
+        return max(self.qk_nope_dim + self.qk_rope_dim, self.v_head_dim)
 
     @property
     def kv_group(self) -> int:       # query heads a key-value head
@@ -168,14 +172,19 @@ BUFFER_OVER_EVEN, BUFFER_FLOOR = 2, 2048
 
 
 def held(cfg, tokens: int) -> moe.HeldExperts:
-    """The expert layer's part that lies here, for ``tokens`` a layer."""
+    """The expert layer's part that lies here, for ``tokens`` a layer. The
+    grouped products' tile is ``moe.product_tile``'s from the widths, over
+    rows ``cfg.product_rows`` where the configuration says so
+    (``models/xing4.py``: a held expert sees 256 rows a step)."""
     most = tokens * min(cfg.top_k, cfg.experts_held)
     even = tokens * cfg.top_k * cfg.experts_held // cfg.n_experts
+    tile = moe.product_tile(cfg.dim, cfg.moe_ffn)
+    tile = (getattr(cfg, "product_rows", tile[0]),) + tile[1:]
     return moe.HeldExperts(
         num_experts=cfg.n_experts, experts_held=cfg.experts_held,
         expert_offset=cfg.expert_offset, top_k=cfg.top_k,
         routed_scale=cfg.routed_scale, route=cfg.route, form=cfg.expert_form,
-        tile=moe.product_tile(cfg.dim, cfg.moe_ffn), dtype=cfg.compute_dtype,
+        tile=tile, dtype=cfg.compute_dtype,
         buffer_rows=min(most, max(BUFFER_OVER_EVEN * even, BUFFER_FLOOR)))
 
 
@@ -221,11 +230,33 @@ def _ffn_shapes(cfg, kind: str) -> Dict[str, Tuple[int, ...]]:
     return out
 
 
+def streams_of(cfg) -> int:
+    """The residual streams a position has between blocks: 1, or the
+    configuration's ``streams`` (``models/xing4.py``: hyper-connections)."""
+    return int(getattr(cfg, "streams", 1))
+
+
+def _stream_shapes(cfg, branch: str) -> Dict[str, Tuple[int, ...]]:
+    """A sublayer's hyper-connection under several streams (none under
+    one): ``hc_phi``, a row an output as the router's are, [n + n + n^2,
+    n x dim] (pre, post, then the n x n residual mix row by row);
+    ``hc_b``, an offset an output; ``hc_alpha``, one gain for each of the
+    three groups."""
+    n = streams_of(cfg)
+    if n == 1:
+        return {}
+    outs = n * n + 2 * n
+    return {f"{branch}.hc_phi": (outs, n * cfg.dim),
+            f"{branch}.hc_b": (outs,), f"{branch}.hc_alpha": (3,)}
+
+
 def param_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
     """Every trained parameter by name. Layers are ``L<i>.``; the
     prediction module is ``mtp.``; ``embed`` and ``head`` have a row a
     token id, and under a tied head (``cfg.tied_head``) there is no
-    ``head``: the embedding's table is both."""
+    ``head``: the embedding's table is both. Under several residual
+    streams every sublayer has its hyper-connection's three tables
+    (``<layer>.attn.hc_*``, ``<layer>.ffn.hc_*``: :func:`_stream_shapes`)."""
     d = cfg.dim
     out = {"embed": (cfg.vocab, d), "final_norm": (d,)}
     if not tied_head(cfg):
@@ -240,6 +271,9 @@ def param_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
             del block["ffn_norm"]
         if cfg.post_norms:
             block.update(attn_post_norm=(d,), ffn_post_norm=(d,))
+        for branch in (("attn",) if layer.attn else ()) + (
+                ("ffn",) if layer.ffn else ()):
+            block.update(_stream_shapes(cfg, branch))
         if layer.name == "mtp":
             block.update(enorm=(d,), hnorm=(d,), eh_proj=(2 * d, d),
                          out_norm=(d,))
@@ -467,9 +501,9 @@ def attn_blocks(cfg, s: int) -> Tuple[int, int]:
     """The flash kernel's (q, k) blocks over ``s`` positions, from ``s``
     and the head size: ``attn_block`` rows of q, and twice as many of k
     where a k block of at most 1,024 rows at a head size of at most 256
-    divides ``s``; at a head size of at most 128 the q block doubles with
-    it (what fits the kernels' VMEM: at 256, 1,024 x 1,024 and 512 x 2,048
-    do not). At (8192, 256) on a v5e the forward, dQ and dK/dV kernels
+    divides ``s``; at a head size of at most 192 (the wider of a core's
+    two) the q block doubles with it (what fits the kernels' VMEM: at 256,
+    1,024 x 1,024 and 512 x 2,048 do not). At (8192, 256) on a v5e the forward, dQ and dK/dV kernels
     read 13.3 / 13.9 / 17.9 ms a call at 512 x 512, 12.0 / 13.4 / 17.7 at
     1,024 x 512 and 10.9 / 13.4 / 17.6 at 512 x 1,024: an accumulator is
     rescaled once a k block. At (8192, 128) with 32 query heads over 4
@@ -501,12 +535,19 @@ def attn_blocks(cfg, s: int) -> Tuple[int, int]:
     / 26.2 and 15.2 / 19.1 / 25.5 whole): every shape holds the
     dK-with-dV kernel's group of 8 query heads against one k block in VMEM,
     and the rule's 512 x 1,024 is within 3% of the fastest, so a head of
-    256 keeps one rule whatever its group."""
+    256 keeps one rule whatever its group. At (4096, 192) for queries and
+    keys and 128 for values, 32 heads (PERF.md section 6, PR 60), a block
+    of a lane tile and a half with sub-tiles of 256: 2.12 / 2.95 / 3.48 at
+    512 x 512, 2.05 / 2.82 / 3.32 at 512 x 1,024, 2.01 / 2.91 / 3.33 at
+    1,024 x 512, **1.96 / 2.66 / 3.15 at 1,024 x 1,024**, which fits at
+    these sizes, 2.39 / 3.10 / 3.68 at 256 x 1,024 (whole tiles: 2.17 /
+    3.06 / 3.52, 2.30 / 3.12 / 3.61, 2.26 / 3.11 / 3.61, 2.24 / 2.99 /
+    3.51): a head of 192 takes the blocks of a head of 128."""
     bq = min(cfg.attn_block, s)
     wide = 2 * bq <= 1024 and cfg.head_size <= 256 and s % (2 * bq) == 0
     if not wide:
         return bq, bq
-    return (2 * bq if cfg.head_size <= 128 else bq), 2 * bq
+    return (2 * bq if cfg.head_size <= 192 else bq), 2 * bq
 
 
 def attn_grid(cfg, s: int) -> Dict[str, Any]:
@@ -583,16 +624,20 @@ def mixer_grid(cfg, s: int) -> Dict[str, Any]:
     return out
 
 
-def _xla_attention(q, k, v, window: Optional[int] = None, select=None):
-    """Causal attention over q [B, H, S, D] and k, v [B, Hkv, S, D] in
-    plain XLA, float32 softmax, under a ``window`` where one is given and
-    under a selection (``select`` [B, S, S], nonzero where a query may see
-    a key, shared by the heads) where one is given: the CPU tests' core,
-    and the flash kernel's stand-in off the chip."""
+def _xla_attention(q, k, v, window: Optional[int] = None, select=None,
+                   scale: Optional[float] = None):
+    """Causal attention over q [B, H, S, D] and k [B, Hkv, S, D], v [B,
+    Hkv, S, Dv] in plain XLA, float32 softmax, under a ``window`` where one
+    is given and under a selection (``select`` [B, S, S], nonzero where a
+    query may see a key, shared by the heads) where one is given, the
+    scores times ``scale`` where one is given and over ``sqrt(D)`` where
+    not: the CPU tests' core, and the flash kernel's stand-in off the
+    chip."""
     b, h, n, d = q.shape
     grouped = q.reshape(b, k.shape[1], h // k.shape[1], n, d)
     s = jnp.einsum("bkgqd,bkjd->bkgqj", grouped, k,
-                   preferred_element_type=jnp.float32) / d ** 0.5
+                   preferred_element_type=jnp.float32)
+    s = s / d ** 0.5 if scale is None else s * scale
     i, j = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
     seen = i >= j
     if window is not None:
@@ -602,18 +647,20 @@ def _xla_attention(q, k, v, window: Optional[int] = None, select=None):
     p = jax.nn.softmax(jnp.where(seen, s, -1e30), -1).astype(v.dtype)
     return jnp.einsum("bkgqj,bkjd->bkgqd", p, v,
                       preferred_element_type=jnp.float32
-                      ).astype(q.dtype).reshape(q.shape)
+                      ).astype(q.dtype).reshape(q.shape[:3] + v.shape[3:])
 
 
 def mla(u, p, cfg: MLAMoEConfig):
     """Latent attention on the normed input ``u`` [B, S, D] -> [B, S, D]
-    float32."""
+    float32. Queries and keys are heads of ``qk_nope_dim + qk_rope_dim``,
+    values and the core's output heads of ``v_head_dim``, equal or not;
+    the rotary frequencies are ``cfg.yarn``'s where it has one, and the
+    scores are multiplied by ``cfg.softmax_scale`` where it has one (by
+    ``1 / sqrt(nope + rope)`` where not)."""
     b, s, _ = u.shape
     h, dt = cfg.n_heads, cfg.compute_dtype
     nope, rope, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    if nope + rope != dv:
-        # the kernel takes one head size; so does the published model
-        raise ValueError("qk_nope_dim + qk_rope_dim must equal v_head_dim")
+    yarn, scale = cfg.yarn, cfg.softmax_scale
     mm = functools.partial(matmul, dtype=dt)
     with jax.named_scope("mv.lm.attn"):
         c_q = rms_norm(mm(u, p["wdq"], False, out_dtype=jnp.float32),
@@ -622,20 +669,21 @@ def mla(u, p, cfg: MLAMoEConfig):
         q = q.reshape(b, s, h, nope + rope)
         down = mm(u, p["wdkv"], True, out_dtype=jnp.float32)
         c_kv = rms_norm(down[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.eps)
-        k_r = rotary(down[..., cfg.kv_lora_rank:], cfg.rope_theta)
+        k_r = rotary(down[..., cfg.kv_lora_rank:], cfg.rope_theta, yarn)
         kv = mm(c_kv, p["wukv"], False, out_dtype=dt)
         kv = kv.reshape(b, s, h, nope + dv)
         q = jnp.concatenate(
-            [q[..., :nope], rotary(q[..., nope:], cfg.rope_theta)], -1)
+            [q[..., :nope], rotary(q[..., nope:], cfg.rope_theta, yarn)], -1)
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(
                 k_r[:, :, None, :], (b, s, h, rope)).astype(dt)], -1)
         heads = lambda t: t.astype(dt).transpose(0, 2, 1, 3)
         q, k, v = heads(q), heads(k), heads(kv[..., nope:])
         if attn_core(cfg) == "flash":
-            o = flash_attention(q, k, v, True, *attn_blocks(cfg, s))
+            o = flash_attention(q, k, v, True, *attn_blocks(cfg, s),
+                                scale=scale)
         else:
-            o = _xla_attention(q, k, v)
+            o = _xla_attention(q, k, v, scale=scale)
         o = o.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
         return mm(o, p["wo"], False, out_dtype=jnp.float32)
 
@@ -689,6 +737,51 @@ def expert_ffn(u, p, bias, cfg, shared: bool = True):
                                                      balance)
 
 
+def stream_maps(x, phi, b, alpha, cfg):
+    """The three maps of one sublayer's hyper-connection (manifold-
+    constrained, arXiv:2512.24880 equations 7 and 8) from the streams ``x``
+    [B, S, n, C]: ``(pre [n, T], post [n, T], res [n, n, T])``, ``T = B x
+    S``, float32, a POSITION A LANE (an [T, n, n] array would lie in tiles
+    of 8 x 128 for its 16 numbers, and Sinkhorn's 40 normalisations keep
+    two such a step for the backward pass).
+
+    ``h = (vec(x) / sqrt(mean(vec(x)^2) + eps)) phi^T`` (no gain; the
+    product at the highest precision, as the router's: 24 numbers a
+    position steer everything after them), split as pre, post, res; ``pre =
+    sigmoid(alpha_0 h + b)``, ``post = 2 sigmoid(alpha_1 h + b)``, ``res``
+    = ``exp(clip(alpha_2 h + b, cfg.res_clamp))`` [n, n] made doubly
+    stochastic by ``cfg.sinkhorn_iters`` rounds of (every column over its
+    sum + ``hc_eps``, then every row over its sum + ``hc_eps``)."""
+    bsz, s, n, c = x.shape
+    flat = x.reshape(bsz * s, n * c).astype(jnp.float32)
+    with jax.named_scope("mv.lm.hc.norm"):
+        r = jax.lax.rsqrt(jnp.mean(flat * flat, -1) + cfg.eps)      # [T]
+    with jax.named_scope("mv.lm.hc.project"):
+        # [n^2 + 2n, T]: the norm's factor is a number a position, taken
+        # after the product, so no normed copy of the streams is made
+        h = jax.lax.dot_general(
+            phi, flat, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32) * r[None, :]
+        h = jnp.repeat(alpha, np.array([n, n, n * n]),
+                       total_repeat_length=n * n + 2 * n)[:, None] * h \
+            + b[:, None]
+        pre = jax.nn.sigmoid(h[:n])
+        post = 2.0 * jax.nn.sigmoid(h[n:2 * n])
+    with jax.named_scope("mv.lm.hc.sinkhorn"):
+        m = jnp.exp(jnp.clip(h[2 * n:], *cfg.res_clamp)).reshape(n, n, -1)
+        for _ in range(cfg.sinkhorn_iters):
+            m = m / (jnp.sum(m, 0, keepdims=True) + cfg.hc_eps)  # columns
+            m = m / (jnp.sum(m, 1, keepdims=True) + cfg.hc_eps)  # rows
+    return pre, post, m
+
+
+def res_error(res):
+    """The largest ``abs(row or column sum - 1)`` of ``res`` [n, n, T]."""
+    return jnp.maximum(jnp.max(jnp.abs(jnp.sum(res, 0) - 1.0)),
+                       jnp.max(jnp.abs(jnp.sum(res, 1) - 1.0)))
+
+
 def block(x, p, attn, ffn, cfg):
     """The one block: ``attn`` and ``ffn`` take the normed input and the
     block's parameters; with ``cfg.post_norms`` each branch's output is
@@ -697,7 +790,18 @@ def block(x, p, attn, ffn, cfg):
     Every layer of every kind calls it. Returns (y, ffn's aux). A mixer
     may hand back ``(out, term)``: a term of its own for the loss (a
     learned selection's, ``models/keye_moe.py``), which then rides behind
-    the expert layer's aux as its fourth part."""
+    the expert layer's aux as its fourth part.
+
+    The residual path is the configuration's too. With one stream (every
+    configuration but ``models/xing4.py``'s) a block is handed ``x`` [B,
+    S, C] and a branch's result is added to it. With ``cfg.streams`` = n
+    of them it is handed, and hands back, ``x`` [B, S, n, C], four times
+    a position what one stream weighs, and a sublayer ``F`` is, under its
+    own :func:`stream_maps`: ``u = pre . x`` [B, S, C] (the branch reads a
+    mix of the streams), ``y = F(norm(u))``, ``x' = res @ x + outer(post,
+    y)`` (the streams mixed among themselves, the result written to all
+    of them); the block then returns a third thing, the largest
+    :func:`res_error` of its sublayers."""
     def out(branch, name):
         if not cfg.post_norms:
             return branch
@@ -708,19 +812,50 @@ def block(x, p, attn, ffn, cfg):
         with jax.named_scope("mv.lm.norm.pre"):
             return rms_norm(stream, p[name], cfg.eps)
 
+    errors = []
+
+    def read(stream, branch):
+        """(what the branch reads of ``stream``, how its result joins)."""
+        if streams_of(cfg) == 1:
+            return stream, lambda y: stream + y
+        pre, post, res = stream_maps(
+            stream, *(p[f"{branch}.hc_{k}"] for k in ("phi", "b", "alpha")),
+            cfg)
+        errors.append(res_error(jax.lax.stop_gradient(res)))
+        # the mixes are sums of n products a number, written out: float32
+        # on the vector unit, where an einsum over 4 would go through the
+        # matrix unit at its default precision
+        n, lead = stream.shape[2], stream.shape[:2]
+        at = lambda m: m.reshape(*lead, 1)      # a map's [T] at [B, S, 1]
+        with jax.named_scope("mv.lm.hc.pre"):
+            each = [stream[:, :, i] for i in range(n)]
+            u = sum(at(pre[i]) * each[i] for i in range(n))
+
+        def write(y):
+            with jax.named_scope("mv.lm.hc.post"):
+                return jnp.stack(
+                    [sum(at(res[i, j]) * each[j] for j in range(n))
+                     + at(post[i]) * y for i in range(n)], 2)
+
+        return u, write
+
     h, aux, term = x, None, None
     if attn is not None:
-        mixed = attn(normed(x, "attn_norm"), p)
+        u, join = read(x, "attn")
+        mixed = attn(normed(u, "attn_norm"), p)
         if isinstance(mixed, tuple):
             mixed, term = mixed
-        h = x + out(mixed, "attn_post_norm")
+        h = join(out(mixed, "attn_post_norm"))
     if ffn is not None:
-        f, aux = ffn(normed(h, "ffn_norm"), p)
-        h = h + out(f, "ffn_post_norm")
+        u, join = read(h, "ffn")
+        f, aux = ffn(normed(u, "ffn_norm"), p)
+        h = join(out(f, "ffn_post_norm"))
     if term is not None:
         if aux is None:
             raise ValueError("a mixer's term rides an expert layer's aux")
         aux = aux + (term,)
+    if errors:
+        return h, aux, functools.reduce(jnp.maximum, errors)
     return h, aux
 
 
@@ -750,7 +885,9 @@ def _run_block(x, p, layer: Layer, bias, cfg, remat: bool = True):
     :func:`kept_names` names (an expert layer's grouped products into the
     experts' width, its sort and its route's choice, and what the
     configuration's mixer names; a block that meets no such name is made
-    again whole). ``bias`` is its router's (a dense layer has none)."""
+    again whole). ``bias`` is its router's (a dense layer has none).
+    Under several streams what a block is handed, and so what the policy
+    keeps of it beside the names, is the streams: :func:`block`."""
     attn = (None if layer.attn is None
             else lambda u, q: cfg.attend(u, q, layer.attn))
     if layer.ffn is None:
@@ -850,7 +987,9 @@ def kept_grid(cfg, batch: int, positions: int) -> Dict[str, int]:
     the ``relu2`` form); and ``kept_bytes``, from the shapes: theirs
     ([rows, ffn] each), the sorted buffers' row orders' (int32 [rows]),
     the routes' choices' (int32 [tokens, top_k]) and what the
-    configuration's own names keep (``cfg.kept_bytes``)."""
+    configuration's own names keep (``cfg.kept_bytes``). A block's INPUT is
+    kept beside them whatever the names, and under several residual
+    streams it is what weighs: :func:`stream_grid` says those bytes."""
     out = {"kept_names": len(kept_names(cfg)), "expert_products_kept": 0,
            "kept_bytes": 0}
     if getattr(cfg, "keeps_products", True):
@@ -868,32 +1007,76 @@ def kept_grid(cfg, batch: int, positions: int) -> Dict[str, int]:
     return out
 
 
+def stream_grid(cfg, batch: int, positions: int) -> Dict[str, int]:
+    """What several residual streams add to the ``lm.step`` span (nothing
+    under one): ``streams``, ``sinkhorn_iters``, ``hc_sublayers`` (the
+    sublayers that have a hyper-connection of their own) and
+    ``hc_stream_bytes``, a block's input [batch, positions, streams, dim]
+    float32 times the blocks whose input the rematerialised step keeps
+    (every one), beside ``kept_bytes``."""
+    n = streams_of(cfg)
+    if n == 1:
+        return {}
+    layers = cfg.layers()
+    return {"streams": n, "sinkhorn_iters": cfg.sinkhorn_iters,
+            "hc_sublayers": sum(bool(layer.attn) + bool(layer.ffn)
+                                for layer in layers),
+            "hc_stream_bytes": 4 * batch * positions * n * cfg.dim
+            * len(layers)}
+
+
 def _embed(params, tokens, cfg):
     x = jnp.take(params["embed"], tokens, axis=0)
     return x if cfg.embed_scale == 1.0 else x * cfg.embed_scale
 
 
+def _expand(x, cfg):
+    """One vector a position [B, S, C] as the blocks take it: itself, or
+    copied to each of ``cfg.streams`` [B, S, n, C]."""
+    n = streams_of(cfg)
+    if n == 1:
+        return x
+    with jax.named_scope("mv.lm.hc.expand"):
+        return jnp.broadcast_to(x[:, :, None, :],
+                                x.shape[:2] + (n,) + x.shape[2:])
+
+
+def _reduce(x, cfg):
+    """The blocks' result as one vector a position: itself, or the sum of
+    the streams."""
+    if streams_of(cfg) == 1:
+        return x
+    with jax.named_scope("mv.lm.hc.reduce"):
+        return jnp.sum(x, 2)
+
+
 def _trunk(params, bias, tokens, cfg, still: bool = False):
     """Embedding and every layer but the prediction module: (x, [each
-    expert layer's aux]). ``still``: each block's input is held fixed (no
-    gradient flows from a layer into the one before it, so nothing is
-    rematerialised either)."""
+    expert layer's aux]) and, under several streams, a third thing: the
+    largest of the blocks' stream-mix errors. ``still``: each block's input
+    is held fixed (no gradient flows from a layer into the one before it,
+    so nothing is rematerialised either). Under several streams the
+    embedding is copied to each before the first block and the last
+    block's are summed."""
     with jax.named_scope("mv.lm.embed"):
         x = _embed(params, tokens, cfg)
+    x = _expand(x, cfg)
     rows = {name: row for row, name in enumerate(expert_layers(cfg))}
-    aux = []
+    aux, errors = [], []
     for layer in cfg.layers():
         if layer.name == "mtp":
             continue
         if still:
             x = jax.lax.stop_gradient(x)
-        x, a = _run_block(
+        x, a, *error = _run_block(
             x, _sub(params, layer.name), layer,
             bias[rows[layer.name]] if layer.name in rows else None, cfg,
             remat=not still)
+        errors += error
         if a is not None:
             aux.append(a)
-    return x, aux
+    return (_reduce(x, cfg), aux) + (
+        (functools.reduce(jnp.maximum, errors),) if errors else ())
 
 
 def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
@@ -901,7 +1084,11 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
     """tokens [B, S] -> (loss, (counts [layers, E], overflow [layers],
     balance [layers])), and where the mixers hand terms to the loss a
     fourth part, those terms [layers], of which the loss gains
-    ``cfg.index_coef`` times the sum.
+    ``cfg.index_coef`` times the sum; under several residual streams a
+    last part, the largest :func:`res_error` of the step's sublayers (the
+    prediction module then takes the trunk's summed streams, copies
+    ``eh_proj``'s result to each stream, runs its block under its own
+    hyper-connections and sums again before ``out_norm``).
 
     ``CE(main, t_{i+1}) + mtp_weight * CE(module, t_{i+2})``, each a mean
     over the positions that have a target (S-1 and S-2 a sequence), plus
@@ -911,7 +1098,7 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
     last, which has no next token, takes the sequence's first in its
     place and has no target."""
     b, s = tokens.shape
-    x, aux = _trunk(params, bias, tokens, cfg)
+    x, aux, *errors = _trunk(params, bias, tokens, cfg)
     position = jnp.arange(s)[None, :]
     nxt = jnp.roll(tokens, -1, axis=1)
     # a loss's normaliser lies in its weights: the chunked loss's cotangent
@@ -936,10 +1123,13 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
                  rms_norm(x, p["hnorm"], cfg.eps)], -1)
             y = matmul(joined, p["eh_proj"], False, cfg.compute_dtype,
                        jnp.float32)
-            y, a = _run_block(y, p, mtp[0], bias[len(aux)], cfg)
+            y, a, *error = _run_block(_expand(y, cfg), p, mtp[0],
+                                      bias[len(aux)], cfg)
             aux.append(a)
+            errors += error
             module = _chunked_ce(
-                rms_norm(y, p["out_norm"], cfg.eps).reshape(b * s, -1),
+                rms_norm(_reduce(y, cfg), p["out_norm"],
+                         cfg.eps).reshape(b * s, -1),
                 head, jnp.roll(tokens, -2, axis=1).reshape(-1),
                 weights(position < s - 2, cfg.mtp_weight / (b * (s - 2))),
                 cfg)
@@ -949,6 +1139,8 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
         loss = loss + cfg.balance_coef * jnp.sum(balance)
     if terms:
         loss = loss + cfg.index_coef * jnp.sum(terms[0])
+    if errors:
+        terms = (*terms, functools.reduce(jnp.maximum, errors))
     return loss, (counts, overflow, balance, *terms)
 
 
@@ -983,7 +1175,8 @@ def make_train_step(cfg, tables: Dict[str, Any],
     last column the rows that overflowed the held experts' buffer) and
     the load-balance term as it stands in the loss (0 without one); where
     the mixers hand terms to the loss, their part of it as well
-    (``index_loss``), a sixth result."""
+    (``index_loss``), a sixth result; under several residual streams the
+    step's largest :func:`res_error` (``hc_res_error``), the last."""
     shapes = param_shapes(cfg)
     opt = opt or AddOption(learning_rate=1e-4)
     biased = cfg.route == "sigmoid"     # the route that selects under a bias
@@ -996,6 +1189,7 @@ def make_train_step(cfg, tables: Dict[str, Any],
         (loss, (counts, overflow, balance, *terms)), grads = (
             jax.value_and_grad(loss_fn, has_aux=True)(
                 params, bias, tokens, cfg))
+        errors = [terms.pop()] if streams_of(cfg) > 1 else []
         new = {}
         with jax.named_scope("mv.lm.update"):
             for name, table in tables.items():
@@ -1006,7 +1200,7 @@ def make_train_step(cfg, tables: Dict[str, Any],
             bias = moe.bias_update(bias, counts, cfg.bias_speed)
         return (new, bias, loss, _with_overflow(counts, overflow),
                 cfg.balance_coef * jnp.sum(balance),
-                *(cfg.index_coef * jnp.sum(t) for t in terms))
+                *(cfg.index_coef * jnp.sum(t) for t in terms), *errors)
 
     return step
 
@@ -1044,8 +1238,8 @@ def make_balance_step(cfg, tables: Dict[str, Any]):
         params = _params_of({**others, **routers}, shapes)
 
         def terms(moved):
-            _, aux = _trunk({**params, **moved}, bias, tokens, cfg,
-                            still=True)
+            aux = _trunk({**params, **moved}, bias, tokens, cfg,
+                         still=True)[1]
             counts, overflow, balance = (jnp.stack(a)
                                          for a in tuple(zip(*aux))[:3])
             return jnp.sum(balance), (_with_overflow(counts, overflow),
@@ -1113,10 +1307,13 @@ class Trainer:
                              donate_argnums=(0, 1))
         self.states = {n: t.program_state() for n, t in tables.items()}
         self.steps = 0
-        # (loss, counts, balance[, index_loss]) of a step not read back yet
+        # (loss, counts, balance[, index_loss][, hc_res_error]) of a step
+        # not read back yet
         self._ahead = None
         # attn_grid and mixer_grid of the first step
         self._attn: Dict[str, Any] = {}
+        # the largest stream-mix error of the steps read back so far
+        self.hc_res_error = 0.0
         # closes a step's ``lm.step.device`` span when the device is done
         # with it; idle unless a capture or ``trace_ids`` can read it
         self._watcher = _trace.DeviceWatcher()
@@ -1135,7 +1332,8 @@ class Trainer:
                         attn_grid(self.cfg, positions),
                         **mixer_grid(self.cfg, positions),
                         **loss_grid(self.cfg, count),
-                        **kept_grid(self.cfg, *tokens.shape))
+                        **kept_grid(self.cfg, *tokens.shape),
+                        **stream_grid(self.cfg, *tokens.shape))
                 sp.set(tokens=count)
                 t0_ns = time.time_ns()
                 self.states, self.bias, *back = self._step(
@@ -1157,6 +1355,10 @@ class Trainer:
             with _trace.span("lm.step.wait"):
                 # one read-back a step: it waits for the whole program
                 loss, counts, balance, *terms = jax.device_get(due)
+            if streams_of(self.cfg) > 1:
+                error = float(terms.pop())
+                self.hc_res_error = max(self.hc_res_error, error)
+                sp.set(hc_res_error=error)
             sp.set(**routing_counts(counts, self.cfg))
             if self.cfg.balance_coef:
                 sp.set(aux_loss=float(balance))

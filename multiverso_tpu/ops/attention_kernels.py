@@ -198,7 +198,10 @@ def sub_tile(block_q: int, block_k: int, d: int) -> Optional[int]:
     (what the chip read at head sizes 128 and 256, the module's table, and
     at 64, half a lane tile, where 1,024 x 1,024 blocks read 9.9 / 11.6 /
     14.9 ms forward / dQ / dK with dV in sub-tiles of 256 for 10.7 / 12.6 /
-    15.9 whole: ``mla_moe.attn_blocks``, PR 50)."""
+    15.9 whole: ``mla_moe.attn_blocks``, PR 50; and at 192 for queries and
+    keys with values of 128, a lane tile and a half, where 1,024 x 1,024
+    blocks over 4,096 positions read 1.96 / 2.66 / 3.15 for 2.24 / 2.99 /
+    3.51 whole: PR 60)."""
     if d <= 256 and block_q % 512 == 0 and block_k % 512 == 0:
         return 256
     return None
@@ -656,31 +659,45 @@ def _walk_of(q, k, causal: bool, block_q: int, block_k: int,
     return walk._replace(sub=None, heads=q.shape[1]), bkv
 
 
+def _scale_of(q, scale: Optional[float]) -> float:
+    """The scores' multiplier: ``scale``, or ``1 / sqrt(D)`` of q's heads."""
+    return 1.0 / (q.shape[3] ** 0.5) if scale is None else float(scale)
+
+
+def _specs(walk: _Walk, d: int, dv: int):
+    """A walk's block specs for a call at head sizes ``d`` (q, k) and
+    ``dv`` (v, the output): (q's, k's, the output's, v's, lse's)."""
+    qspec, kspec, lspec = walk.specs(d)
+    ospec, vspec, _ = walk.specs(dv)
+    return qspec, kspec, ospec, vspec, lspec
+
+
 def _flash_forward(q, k, v, causal: bool, block_q: int, block_k: int,
                    interpret: bool, with_lse: bool,
                    window: Optional[int] = None, sub: Optional[int] = None,
-                   select=None):
+                   select=None, scale: Optional[float] = None):
     b, h, s, d = q.shape
+    dv = v.shape[3]             # the values' head size, and the output's
     walk, bkv = _walk_of(q, k, causal, block_q, block_k, window, sub, select)
     block_q, bh = walk.block_q, b * h
-    qspec, kspec, lspec = walk.specs(d)
+    qspec, kspec, ospec, vspec, lspec = _specs(walk, d, dv)
     chosen = ((select,), [walk.select_spec()]) if walk.heads else ((), [])
-    oshape = jax.ShapeDtypeStruct((bh, s, d), q.dtype)
+    oshape = jax.ShapeDtypeStruct((bh, s, dv), q.dtype)
     lshape = jax.ShapeDtypeStruct((bh, s, _RES_LANES), jnp.float32)
     res = walk.call(
-        functools.partial(_flash_kernel, walk=walk, scale=1.0 / (d ** 0.5),
+        functools.partial(_flash_kernel, walk=walk, scale=_scale_of(q, scale),
                           emit_lse=with_lse),
         bh, (q.reshape(bh, s, d), k.reshape(bkv, s, d),
-             v.reshape(bkv, s, d)) + chosen[0], interpret=interpret,
-        in_specs=[qspec, kspec, kspec] + chosen[1],
-        out_specs=[qspec, lspec] if with_lse else [qspec],
+             v.reshape(bkv, s, dv)) + chosen[0], interpret=interpret,
+        in_specs=[qspec, kspec, vspec] + chosen[1],
+        out_specs=[ospec, lspec] if with_lse else [ospec],
         out_shape=[oshape, lshape] if with_lse else [oshape],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),   # running max
             pltpu.VMEM((block_q, _LANES), jnp.float32),   # denominator
-            pltpu.VMEM((block_q, d), jnp.float32),        # output acc
+            pltpu.VMEM((block_q, dv), jnp.float32),       # output acc
         ])
-    out = res[0].reshape(b, h, s, d)
+    out = res[0].reshape(b, h, s, dv)
     return (out, res[1]) if with_lse else (out, None)
 
 
@@ -768,38 +785,39 @@ def _bwd_dkv_kernel(*refs, walk: _Walk, scale: float):
 def _flash_backward(q, k, v, out, lse, do, causal: bool, block_q: int,
                     block_k: int, interpret: bool,
                     window: Optional[int] = None, sub: Optional[int] = None,
-                    select=None):
+                    select=None, scale: Optional[float] = None):
     b, h, s, d = q.shape
+    dv = v.shape[3]
     walk, bkv = _walk_of(q, k, causal, block_q, block_k, window, sub, select)
     chosen = (select,) if walk.heads else ()
     block_q, block_k, bh = walk.block_q, walk.block_k, b * h
-    scale = 1.0 / (d ** 0.5)
+    scale = _scale_of(q, scale)
     operands = (q.reshape(bh, s, d), k.reshape(bkv, s, d),
-                v.reshape(bkv, s, d), out.reshape(bh, s, d),
-                do.reshape(bh, s, d), lse) + chosen
-    qspec, kspec, rspec = walk.specs(d)
+                v.reshape(bkv, s, dv), out.reshape(bh, s, dv),
+                do.reshape(bh, s, dv), lse) + chosen
+    qspec, kspec, ospec, vspec, rspec = _specs(walk, d, dv)
     dq = walk.call(
         functools.partial(_bwd_dq_kernel, walk=walk, scale=scale),
         bh, operands, interpret=interpret,
-        in_specs=([qspec, kspec, kspec, qspec, qspec, rspec]
+        in_specs=([qspec, kspec, vspec, ospec, ospec, rspec]
                   + [walk.select_spec() for _ in chosen]),
         out_specs=qspec, out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)])
 
     # dK/dV walk the key-value heads, q-blocks (and the group) innermost
     walk = walk._replace(q_inner=True)
-    qspec, kspec, rspec = walk.specs(d)
-    dk, dv = walk.call(
+    qspec, kspec, ospec, vspec, rspec = _specs(walk, d, dv)
+    dk, dv_ = walk.call(
         functools.partial(_bwd_dkv_kernel, walk=walk, scale=scale),
         bkv, operands, interpret=interpret,
-        in_specs=([qspec, kspec, kspec, qspec, qspec, rspec]
+        in_specs=([qspec, kspec, vspec, ospec, ospec, rspec]
                   + [walk.select_spec() for _ in chosen]),
-        out_specs=[kspec, kspec],
+        out_specs=[kspec, vspec],
         out_shape=[jax.ShapeDtypeStruct((bkv, s, d), k.dtype),
-                   jax.ShapeDtypeStruct((bkv, s, d), v.dtype)],
+                   jax.ShapeDtypeStruct((bkv, s, dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)])
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+                        pltpu.VMEM((block_k, dv), jnp.float32)])
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv_.reshape(v.shape)
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -813,7 +831,7 @@ def flash_attention(q, k, v, causal: bool = False,
                     block_q: int = 128, block_k: int = 128,
                     interpret: Optional[bool] = None,
                     window: Optional[int] = None, select=None,
-                    with_lse: bool = False):
+                    with_lse: bool = False, scale: Optional[float] = None):
     """Fused attention over q [B, H, S, D] and k, v [B, Hkv, S, D]; S must
     divide by the block sizes (blocks auto-clamp to S when S < 128). With
     ``Hkv < H`` (a causal call) query head ``h`` reads key-value head
@@ -830,11 +848,15 @@ def flash_attention(q, k, v, causal: bool = False,
     rows' log-sum-exp over their live keys as well, float32 [B, H, S], a
     constant to the gradient: ``exp(q . k / sqrt(D) - lse)`` is a live
     key's probability. Without a selection the call is what it was, trace
-    for trace.
+    for trace. ``v`` may have a head size of its own, [B, Hkv, S, Dv]: the
+    output and dV are then [.., Dv], the second product, its accumulator
+    and its blocks are Dv wide and nothing is padded to D. ``scale`` (a
+    call's without a selection) multiplies the scores in ``1 / sqrt(D)``'s
+    place, in all three kernels; without either the call is what it was.
     """
     if select is not None:
-        if window is not None:
-            raise ValueError("a selection goes with no window")
+        if window is not None or scale is not None:
+            raise ValueError("a selection goes with no window and no scale")
         out, lse = _attention_selected(q, k, v, select, block_q, block_k,
                                        interpret)
         return (out, lse) if with_lse else out
@@ -842,29 +864,31 @@ def flash_attention(q, k, v, causal: bool = False,
         raise ValueError("the log-sum-exp comes with a selection's call")
     return _attention(q, k, v, causal, block_q, block_k, interpret, window,
                       sub_tile(*_blocks(q.shape[2], block_q, block_k),
-                               q.shape[3]))
+                               q.shape[3]), scale)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _attention(q, k, v, causal, block_q, block_k, interpret, window, sub):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _attention(q, k, v, causal, block_q, block_k, interpret, window, sub,
+               scale=None):
     """:func:`flash_attention` at a given ``sub`` (``None``: whole tiles)."""
     out, _ = _flash_forward(q, k, v, causal, block_q, block_k,
                             _resolve_interpret(interpret), False, window,
-                            sub)
+                            sub, scale=scale)
     return out
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret, window, sub):
+def _fwd(q, k, v, causal, block_q, block_k, interpret, window, sub, scale):
     out, lse = _flash_forward(q, k, v, causal, block_q, block_k,
                               _resolve_interpret(interpret), True, window,
-                              sub)
+                              sub, scale=scale)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, block_q, block_k, interpret, window, sub, res, g):
+def _bwd(causal, block_q, block_k, interpret, window, sub, scale, res, g):
     q, k, v, out, lse = res
     return _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
-                           _resolve_interpret(interpret), window, sub)
+                           _resolve_interpret(interpret), window, sub,
+                           scale=scale)
 
 
 _attention.defvjp(_fwd, _bwd)

@@ -349,3 +349,30 @@ def test_last_line_is_the_result_and_nothing_else(monkeypatch, capsys, fail):
     summary = json.loads(lines[-2])
     assert summary["stage"] == "summary" and summary["claim"] is None
     assert list(summary)[-1] == "claim"
+
+
+def test_hc_stage():
+    """One hyper-connected sublayer's stream maps alone at a small size: ms
+    and compile seconds forward and with every gradient, the mix's error,
+    and the result and gradients held to the reference's sublayer; the
+    least the memory allows is a chip's number and is not made up here."""
+    facts = chip_smoke.stage_hc(positions=64, dim=128, repeats=1,
+                                check_positions=64)
+    for what in ("fwd", "fwd_bwd"):
+        assert facts[f"{what}_ms"] > 0 and facts[f"{what}_compile_s"] >= 0
+        assert f"{what}_least_ms" not in facts
+    assert 0 < facts["res_error"] < 5e-2
+    # the weighted sum, dx, and the four tables' gradients
+    assert len(facts["rel_err"]) == 6
+    assert max(facts["rel_err"]) <= chip_smoke.HC_F32_TOL
+    assert "hc" in dict(chip_smoke.STAGES)
+    # what the chip's readings are held against, at the cell's shapes
+    from benchmark import hc_shapes, shapes
+    whole = hc_shapes.sublayer_bytes({"hc_mult": 4, "hidden_size": 3584},
+                                     4096)
+    assert whole == 4096 * 33 * 3584 * 4
+    assert round(whole / shapes.peak("TPU v5 lite", "hbm_bytes_per_s") * 1e3,
+                 2) == 2.37
+    # the flash stage's call at two head sizes is among the cells' calls
+    assert ("xing4.causal", (1, 32, 4096, 192), 32, (1024, 1024), None,
+            128) in chip_smoke.FLASH_CALLS

@@ -1114,3 +1114,126 @@ def test_the_mixers_short_convolution_kernels_compile_for_a_v5e(
                              text).group(0)
             assert "dot_general" in made, made
     assert not re.search(rf"= {re.escape(shape)}\S* (copy|slice)\(", text)
+
+
+def _xing4():
+    from multiverso_tpu.models import xing4
+
+    return xing4.Xing4Config(
+        vocab=16384, dim=3584, n_heads=32, q_lora_rank=768, kv_lora_rank=512,
+        qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128, dense_ffn=9216,
+        n_dense_layers=1, n_moe_layers=4, moe_ffn=1024, n_experts=64,
+        experts_held=8, top_k=4, attn="flash", expert_kernel="pallas")
+
+
+def test_a_hyper_connections_projection_lies_a_row_an_output_on_a_v5e(
+        one_chip):
+    """The 24-wide table's case: ``xing4-train-4k``'s projection is stored
+    [24, 14,336], a row an output. The chip tiles a float32 array in 8 x
+    128: the paper's [14,336, 24] would lie in 128 lanes a row, 5.3 times
+    the bytes for the table, its moments and its gradient, and its row
+    programs would keep it in a layout of their own (row-major, where the
+    chip's default for so narrow an array is not: ``row_program_layout``),
+    compiled in the process on every run. As stored it is 24 whole rows of
+    whole lanes in the device's default layout, padded row and all."""
+    from multiverso_tpu.models import mla_moe
+
+    cfg = _xing4()
+    shape = mla_moe.param_shapes(cfg)["L0.attn.hc_phi"]
+    assert shape == mla_moe.table_shape(shape) == (24, 4 * 3584)
+    f32 = jnp.dtype(jnp.float32)
+    for s in (shape, (shape[0] + 1, shape[1])):
+        assert table_lib.row_program_layout(s, f32, one_chip) is None, s
+    own = table_lib.row_program_layout(shape[::-1], f32, one_chip)
+    assert own is not None and own.major_to_minor == (0, 1)
+    size = lambda s: jax.jit(lambda a: a + 1.0).lower(
+        jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    ).compile().memory_analysis().output_size_in_bytes
+    assert size(shape) == 4 * 24 * 14336
+
+
+def test_latent_attention_at_two_head_sizes_compiles_for_a_v5e(
+        one_chip, monkeypatch):
+    """``xing4-train-4k``'s attention core: 32 heads of 192 for queries and
+    keys and of 128 for values over 4,096 positions under the
+    configuration's scale, forward and backward, at the blocks
+    ``attn_blocks`` gives the cell: Mosaic takes a block of a lane tile
+    and a half, the three kernels are in the program, and no operand of
+    theirs is padded to 256 in HBM (no [.., 4096, 256] array anywhere)."""
+    from multiverso_tpu.models import mla_moe
+    from multiverso_tpu.ops import attention_kernels
+
+    monkeypatch.setattr(attention_kernels, "_resolve_interpret",
+                        lambda interpret: False)
+    cfg = _xing4()
+    assert cfg.head_size == 192
+    blocks = mla_moe.attn_blocks(cfg, 4096)
+    assert blocks == (1024, 1024)
+    assert attention_kernels.sub_tile(*blocks, cfg.head_size) == 256
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16,
+                                            sharding=one_chip)
+    qk, v = shape(1, 32, 4096, 192), shape(1, 32, 4096, 128)
+
+    def core(q, k, v):
+        return attention_kernels.flash_attention(
+            q, k, v, True, *blocks, scale=cfg.softmax_scale).astype(
+                jnp.float32).sum()
+
+    text = jax.jit(jax.grad(core, argnums=(0, 1, 2))).lower(
+        qk, qk, v).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3      # forward, dQ, dK with dV
+    assert not re.search(r"bf16\[(1,)?32,4096,256\]", text)
+    assert re.search(r"bf16\[(1,)?32,4096,128\]", text)
+
+
+def test_a_hyper_connected_sublayers_maps_on_a_v5e_keep_a_position_a_lane(
+        one_chip):
+    """One sublayer's stream maps at ``xing4-train-4k``'s shapes (four
+    streams of 3,584 over 4,096 positions, 20 Sinkhorn rounds), forward
+    and backward: the compiler's temporaries stay near the streams' own
+    size (235 MB an array). A [4096, 4, 4] array would lie in 8 x 128
+    tiles, 16 MB for 262 KB, and Sinkhorn's backward pass keeps two a
+    round: the maps are [.., 4096], a position a lane."""
+    from multiverso_tpu.models import mla_moe
+
+    cfg = _xing4()
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {"attn_norm": f32(3584), "attn.hc_phi": f32(24, 14336),
+         "attn.hc_b": f32(24), "attn.hc_alpha": f32(3)}
+
+    def loss(x, p):
+        return mla_moe.block(x, p, lambda u, p: u, None, cfg)[0].sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        f32(1, 4096, 4, 3584), p).compile()
+    stream = 4 * 4096 * 4 * 3584
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * stream
+    assert "f32[4096,4,4]" not in compiled.as_text()
+
+
+def test_sigmoid_expert_layer_at_row_tiles_of_128_compiles_for_a_v5e(one_chip):
+    """The held experts' grouped products as ``xing4-train-4k`` calls them:
+    a 4,096-row buffer in eight groups of 3,584 x 1,024 at a row tile of 128
+    (``Xing4Config.product_rows``: a group is about 256 rows), under the
+    sigmoid route over 64 outputs with 4 a token."""
+    from multiverso_tpu.models import mla_moe
+
+    cfg = _xing4()
+    held = mla_moe.held(cfg, 4096)
+    assert held.tile == (128, 512, 512) and held.buffer_rows == 4096
+    assert mla_moe.held(mla_moe.MLAMoEConfig(dim=2048, moe_ffn=1536),
+                        16384).tile == (512, 512, 512)
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+
+    def experts(u, router, wg, wu, wd):
+        out, counts, overflow, _ = moe.held_expert_layer(
+            u, {"router": router, "w_gate": wg, "w_up": wu, "w_down": wd},
+            jnp.zeros((64,)), held, kernel="pallas")
+        return out.sum(), (counts, overflow)
+
+    from multiverso_tpu.parallel import moe
+    compiled = jax.jit(jax.grad(experts, argnums=(0, 1, 2, 3, 4),
+                                has_aux=True)).lower(
+        shape(4096, 3584), shape(64, 3584), shape(8, 3584, 1024),
+        shape(8, 3584, 1024), shape(8, 1024, 3584)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 9

@@ -31,8 +31,10 @@ each ``we.fused`` / ``we.blocks`` call handed its table writes.
 file (``telemetry/devstats.scope_seconds``): the device's busy time by
 ``mv.*`` scope and pass (forward, made again under ``jax.checkpoint``,
 backward), what the programs' maps could not place, each scope's longest
-instructions and what each program reserves of the device. With
-``--steps-from`` the times are a step: the window's total over the
+instructions and what each program reserves of the device (a
+hyper-connected decoder's stream maps read there as ``mv.lm.hc.expand``,
+``.norm``, ``.project``, ``.sinkhorn``, ``.pre``, ``.post`` and
+``.reduce``). With ``--steps-from`` the times are a step: the window's total over the
 number of device spans of that name recorded under the profiler.
 
 Both commands also accept the cluster aggregator's time series
@@ -621,7 +623,7 @@ def format_timeline(events: List[Dict]) -> str:
             for r in runs[:5]]
     return "\n".join(out + _table_write_lines(events)
                      + _attention_lines(events) + _mixer_lines(events)
-                     + _buffer_lines(events))
+                     + _stream_lines(events) + _buffer_lines(events))
 
 
 def window_ops(trace_dir: str) -> Dict[str, List[tuple]]:
@@ -799,6 +801,25 @@ def _mixer_lines(events: List[Dict]) -> List[str]:
             f"{args['conv_kernel_layers']} mixer(s) (0: the plain form), "
             f"{args['conv_bytes'] / 1e6:.0f} MB a mixer a pass at the least")
     return out
+
+
+def _stream_lines(events: List[Dict]) -> List[str]:
+    """Under several residual streams (``models/mla_moe.stream_grid``; the
+    device scopes ``mv.lm.hc.*``): how many, Sinkhorn's rounds a sublayer,
+    the sublayers that mix them, what the kept block inputs weigh, and the
+    largest ``abs(row or column sum of H_res - 1)`` any step read back."""
+    steps = [e["args"] for e in events if e.get("name") == "lm.step"
+             and "streams" in e.get("args", {})]
+    if not steps:
+        return []
+    a = steps[0]
+    errors = [s["hc_res_error"] for s in steps if "hc_res_error" in s]
+    return [f"  residual streams: {a['streams']}, mixed round "
+            f"{a['hc_sublayers']} sublayers by {a['sinkhorn_iters']} "
+            f"Sinkhorn rounds each; kept block inputs "
+            f"{a['hc_stream_bytes'] / 1e6:.0f} MB"
+            + (f"; largest mix error {max(errors):.3g} over {len(errors)} "
+               "steps" if errors else "")]
 
 
 def _buffer_lines(events: List[Dict]) -> List[str]:
