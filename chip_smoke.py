@@ -44,6 +44,7 @@ This is a health check, not a benchmark: its wall times are set-up costs
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 import sys
@@ -1410,29 +1411,123 @@ def stage_taps(positions: int = 16384, calls: Tuple = TAPS_CALLS,
 HC_F32_TOL = 1e-4
 
 
+@contextlib.contextmanager
+def _hc_walks_as(tiles, interpret: bool = False):
+    """Inside: the sublayer's rule (``mla_moe._hyper_bwd``) takes its walks
+    as ``tiles(t, n, c)`` says (``None``: the plain forms) whatever the
+    device, in the interpreter where ``interpret``. The program has no
+    option for it; a smoke's readings of both forms on one device need
+    one."""
+    from multiverso_tpu.ops import stream_walks
+
+    names = ("walk_tiles", "gather", "dots", "spread")
+    found = {name: getattr(stream_walks, name) for name in names}
+    stream_walks.walk_tiles = lambda t, n, c, *dtypes: tiles(t, n, c)
+    if interpret:
+        for name in names[1:]:
+            setattr(stream_walks, name,
+                    functools.partial(found[name], interpret=True))
+    try:
+        yield
+    finally:
+        for name in names:
+            setattr(stream_walks, name, found[name])
+
+
+def _hc_walks(positions: int, dim: int, streams: int, repeats: int,
+              interpret: bool = False) -> Dict[str, Any]:
+    """The three backward walks of ``ops/stream_walks.py`` alone at a
+    sublayer's shapes: each kernel's ms a call, the bytes it must move over
+    that time over the chip's HBM peak, and its error against the plain
+    form (max|err| over max|plain|). Off a TPU the kernels run in the
+    interpreter where ``interpret`` says so, and no share is made up."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import shapes
+    from multiverso_tpu.ops import stream_walks
+
+    t, n, c = positions, streams, dim
+    tiles = (stream_walks.tiles_for(t, n, c) if interpret
+             else stream_walks.walk_tiles(t, n, c, jnp.float32))
+    if tiles is None:
+        return {"kernels": False}
+    outs = n * n + 2 * n
+    k = jax.random.split(jax.random.key(SEED + 1), 10)
+    # the streams as XLA lays them out between its own fusions and as the
+    # kernels read them, [n, C, T]: a stream at a time, positions on the
+    # lanes (handed over a position at a time, a parameter's layout, a call
+    # would open with a copy of each); the branch's side likewise
+    g, x = (jax.random.normal(k[i], (n, c, t)) for i in (0, 1))
+    y, du = (jax.random.normal(k[i], (c, t)) for i in (2, 3))
+    post, pre = (jax.random.uniform(k[i], (n, t)) for i in (4, 5))
+    res = jax.random.uniform(k[6], (n, n, t))
+    a, q = jax.random.normal(k[7], (outs, t)), jax.random.normal(k[8], (t,))
+    phi = (n * c) ** -0.5 * jax.random.normal(k[9], (outs, n * c))
+    how = dict(tiles=tiles, interpret=interpret)
+    turned = lambda v: v.transpose(2, 0, 1)        # [n, C, T] as [T, n, C]
+    calls = (
+        ("gather", 2 * n + 2,
+         lambda walk, g, x, y, *o: (lambda dy, *sums: (dy.T,) + sums)(
+             *walk(turned(g), turned(x), y.T, *o)), (g, x, y, post)),
+        ("dots", n + 1, lambda walk, du, x: walk(du.T, turned(x)), (du, x)),
+        ("spread", 3 * n + 1,
+         lambda walk, g, x, du, *o: walk(
+             turned(g), turned(x), du.T, *o).transpose(1, 2, 0),
+         (g, x, du, phi, pre, res, a, q)))
+    device = jax.devices()[0]
+    facts: Dict[str, Any] = {"kernels": True, "tiles": list(tiles)}
+    for name, arrays, call, args in calls:
+        kernel = functools.partial(getattr(stream_walks, name), **how)
+        plain = getattr(stream_walks, name + "_plain")
+        compile_s, ms, got = _timed(functools.partial(call, kernel), args,
+                                    repeats)
+        facts[f"{name}_ms"], facts[f"{name}_compile_s"] = ms, compile_s
+        _, facts[f"{name}_plain_ms"], want = _timed(
+            functools.partial(call, plain), args, repeats)
+        if device.platform == "tpu":
+            facts[f"{name}_hbm_share"] = round(
+                100 * arrays * 4 * t * c / (ms * 1e-3) / shapes.peak(
+                    device.device_kind, "hbm_bytes_per_s"), 1)
+        facts[f"{name}_rel_err"] = float("%.3g" % max(
+            float(jnp.max(jnp.abs(o - w)) / jnp.max(jnp.abs(w)))
+            for o, w in zip(jax.tree.leaves(got), jax.tree.leaves(want))))
+    return facts
+
+
 def stage_hc(positions: int = 4096, dim: int = 3584, streams: int = 4,
              iters: int = 20, repeats: int = 10,
-             check_positions: int = 256) -> Dict[str, Any]:
+             check_positions: int = 256, stack: int = 10,
+             interpret: bool = False) -> Dict[str, Any]:
     """ONE hyper-connected sublayer's stream maps alone at
     ``xing4-train-4k``'s shapes (``models/mla_moe.block`` under ``streams``
     residual streams round a branch that hands its normed input back): the
     norm, the [24 x 14,336] projection, sigmoid, exp, Sinkhorn's ``iters``
     rounds, the pre-mix and the write back to every stream. The ms a call
-    forward and forward with every gradient (the streams', the three
-    tables'), by this process's clock around ``repeats`` calls it waits
+    forward (``fwd``) and forward with every gradient (the streams', the
+    three tables') three ways: under the sublayer's one differentiation
+    rule as this device runs it (``fwd_bwd``: the backward walks are
+    ``ops/stream_walks.py``'s kernels on a TPU), under the rule with the
+    walks in plain ``jax.numpy`` (``rule_plain``) and by plain autodiff of
+    the forward's own lines (``autodiff``: what every pass was before PR
+    62, 13.20 ms), by this process's clock around ``repeats`` calls it waits
     for; on a TPU the least the chip's memory allows each
     (``benchmark/hc_shapes.py``: 2n + 2 arrays of ``dim`` floats a position
     forward, 7n + 5 with the backward pass, over the HBM peak of
-    ``benchmark/peaks.json``); the largest ``abs(row or column sum of H_res
-    - 1)``; and the result and the gradients on the first
-    ``check_positions`` against ``benchmark/reference/xing4.sublayer`` in
-    float32 (max|err| over max|reference|)."""
+    ``benchmark/peaks.json``); the three walks alone (:func:`_hc_walks`);
+    the seconds one trace and one lowering of ``stack`` rematerialised
+    sublayers with every gradient take (``stack_trace_s``,
+    ``stack_lower_s``); the largest ``abs(row or column sum of H_res -
+    1)``; and the result and the gradients of the first two ways on the
+    first ``check_positions`` against ``benchmark/reference/xing4.sublayer``
+    in float32 (max|err| over max|reference|)."""
     import jax
     import jax.numpy as jnp
 
     from benchmark import hc_shapes, shapes
     from benchmark.reference import xing4 as ref
     from multiverso_tpu.models import mla_moe, xing4
+    from multiverso_tpu.ops import stream_walks
 
     cfg = xing4.Xing4Config(dim=dim, streams=streams, sinkhorn_iters=iters)
     outs = streams * streams + 2 * streams
@@ -1447,45 +1542,81 @@ def stage_hc(positions: int = 4096, dim: int = 3584, streams: int = 4,
     handed_back = lambda u, p: u
     maps = lambda x, p: mla_moe.block(x, p, handed_back, None, cfg)[::2]
 
-    def both(x, p, weight):     # the weight is an operand, not a constant
+    def unruled(x, p):      # the forward's own lines under plain autodiff
+        hc = tuple(p[f"attn.hc_{k}"] for k in ("phi", "b", "alpha"))
+        branch = lambda u, q: (mla_moe.rms_norm(u, q["attn_norm"], cfg.eps),
+                               None)
+        return mla_moe._hyper.fun(branch, cfg, x, hc, p)[::2]
+
+    def both(x, p, weight, maps=maps):  # the weight is an operand
         return jax.value_and_grad(
             lambda x, p: jnp.sum(weight * maps(x, p)[0]), (0, 1))(x, p)
 
+    def plain_walks(*args):
+        with _hc_walks_as(lambda t, n, c: None):
+            return both(*args)
+
+    steered = (_hc_walks_as(stream_walks.tiles_for, True) if interpret
+               else contextlib.nullcontext())   # the CPU's rehearsal
     facts: Dict[str, Any] = {}
-    for what, fn, args in (("fwd", maps, (x, p)),
-                           ("fwd_bwd", both, (x, p, weight))):
-        compile_s, ms, res = _timed(fn, args, repeats)
-        facts[f"{what}_ms"], facts[f"{what}_compile_s"] = ms, compile_s
-        if what == "fwd":
-            facts["res_error"] = float(res[1])
-    device = jax.devices()[0]
-    if device.platform == "tpu":
-        c = {"hc_mult": streams, "hidden_size": dim}
-        whole = hc_shapes.sublayer_bytes(c, positions) / shapes.peak(
-            device.device_kind, "hbm_bytes_per_s") * 1e3
-        facts["fwd_least_ms"] = round(
-            whole * (2 * streams + 2) / (7 * streams + 5), 3)
-        facts["fwd_bwd_least_ms"] = round(whole, 3)
-    _say("hc.timed", **facts)       # a failed check keeps the readings
-    n = min(check_positions, positions)
-    c = dict(hc_mult=streams, hc_sinkhorn_iters=iters, hc_eps=cfg.hc_eps,
-             rms_norm_eps=cfg.eps, mhc_h_res_clamp_min=cfg.res_clamp[0],
-             mhc_h_res_clamp_max=cfg.res_clamp[1])
-    few = (x[:, :n], p, weight[:, :n])
+    with steered:
+        for what, fn, args in (
+                ("fwd", maps, (x, p)), ("fwd_bwd", both, (x, p, weight)),
+                ("rule_plain", plain_walks, (x, p, weight)),
+                ("autodiff", functools.partial(both, maps=unruled),
+                 (x, p, weight))):
+            compile_s, ms, res = _timed(fn, args, repeats)
+            facts[f"{what}_ms"], facts[f"{what}_compile_s"] = ms, compile_s
+            if what == "fwd":
+                facts["res_error"] = float(res[1])
+        device = jax.devices()[0]
+        if device.platform == "tpu":
+            c = {"hc_mult": streams, "hidden_size": dim}
+            whole = hc_shapes.sublayer_bytes(c, positions) / shapes.peak(
+                device.device_kind, "hbm_bytes_per_s") * 1e3
+            facts["fwd_least_ms"] = round(
+                whole * (2 * streams + 2) / (7 * streams + 5), 3)
+            facts["fwd_bwd_least_ms"] = round(whole, 3)
+        facts["walks"] = _hc_walks(positions, dim, streams, repeats,
+                                   interpret)
 
-    def plain(x, p, weight):
-        return jax.value_and_grad(lambda x, p: jnp.sum(weight[0] * ref.sublayer(
-            x[0], p, "attn", lambda u: (u, None), c)[0]), (0, 1))(x, p)
+        def stacked(x, p, weight):
+            for _ in range(stack):
+                x = jax.checkpoint(lambda x, p: maps(x, p)[0])(x, p)
+            return jnp.sum(weight * x)
 
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(plain)(*few)
-    got = jax.jit(both)(*few)
-    errs = [float(jnp.max(jnp.abs(g - t)) / jnp.max(jnp.abs(t)))
-            for g, t in zip(jax.tree.leaves(got), jax.tree.leaves(want))]
-    if not max(errs) <= HC_F32_TOL:         # a NaN fails too
-        raise AssertionError(f"hc: relative error {errs} (the weighted sum, "
-                             f"dx, the tables' gradients) > {HC_F32_TOL}")
-    facts["rel_err"] = [float(f"{e:.3g}") for e in errs]
+        t0 = time.perf_counter()
+        traced = jax.jit(jax.grad(stacked, (0, 1))).trace(x, p, weight)
+        t1 = time.perf_counter()
+        traced.lower()
+        facts["stack_trace_s"] = round(t1 - t0, 2)
+        facts["stack_lower_s"] = round(time.perf_counter() - t1, 2)
+        _say("hc.timed", **facts)   # a failed check keeps the readings
+        n = min(check_positions, positions)
+        c = dict(hc_mult=streams, hc_sinkhorn_iters=iters, hc_eps=cfg.hc_eps,
+                 rms_norm_eps=cfg.eps, mhc_h_res_clamp_min=cfg.res_clamp[0],
+                 mhc_h_res_clamp_max=cfg.res_clamp[1])
+        few = (x[:, :n], p, weight[:, :n])
+
+        def plain(x, p, weight):
+            return jax.value_and_grad(
+                lambda x, p: jnp.sum(weight[0] * ref.sublayer(
+                    x[0], p, "attn", lambda u: (u, None), c)[0]),
+                (0, 1))(x, p)
+
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(plain)(*few)
+        for name, fn in (("rel_err", both), ("rule_plain_rel_err",
+                                            plain_walks)):
+            got = jax.jit(fn)(*few)
+            errs = [float(jnp.max(jnp.abs(g - t)) / jnp.max(jnp.abs(t)))
+                    for g, t in zip(jax.tree.leaves(got),
+                                    jax.tree.leaves(want))]
+            if not max(errs) <= HC_F32_TOL:         # a NaN fails too
+                raise AssertionError(
+                    f"hc: {name} {errs} (the weighted sum, dx, the tables' "
+                    f"gradients) > {HC_F32_TOL}")
+            facts[name] = [float(f"{e:.3g}") for e in errs]
     return facts
 
 

@@ -60,6 +60,7 @@ import numpy as np
 
 from multiverso_tpu.ops.attention_kernels import (causal_pairs,
                                                    flash_attention, sub_tile)
+from multiverso_tpu.ops import stream_walks
 from multiverso_tpu.parallel import moe
 from multiverso_tpu.telemetry import devstats as _devstats
 from multiverso_tpu.telemetry import trace as _trace
@@ -737,6 +738,82 @@ def expert_ffn(u, p, bias, cfg, shared: bool = True):
                                                      balance)
 
 
+def _stream_scores(x, phi, cfg):
+    """``(r [T], phi vec(x) [n^2 + 2n, T], the two multiplied)``: the norm's
+    factor a position, the projection of the streams as they are (the
+    product at the highest precision), and the scores ``h`` of
+    :func:`stream_maps`: the factor is a number a position, taken after the
+    product, so no normed copy of the streams is made."""
+    bsz, s, n, c = x.shape
+    flat = x.reshape(bsz * s, n * c).astype(jnp.float32)
+    with jax.named_scope("mv.lm.hc.norm"):
+        r = jax.lax.rsqrt(jnp.mean(flat * flat, -1) + cfg.eps)      # [T]
+    with jax.named_scope("mv.lm.hc.project"):
+        raw = jax.lax.dot_general(
+            phi, flat, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        return r, raw, raw * r[None, :]
+
+
+# what is traced once a process and bound again from its jaxpr: the small
+# mathematics and the mixes of a hyper-connected sublayer, the same
+# equations in every sublayer of a step, in each of its passes
+_ONCE: Dict[tuple, Any] = {}
+
+
+def _once(key, fn, *operands):
+    """``fn(*operands)``, traced once a process for ``key`` (hashable: what
+    ``fn`` is and its statics), the operands' structure and their types,
+    and bound again from its jaxpr, as ``ops/index_kernels._bind`` binds a
+    kernel: Python's part of a trace is most of a warm set-up, and ten
+    sublayers in three passes run these lines thirty times. ``fn`` closes
+    over no array."""
+    leaves, tree = jax.tree.flatten(operands)
+    key = (key, tree) + tuple(jax.typeof(v) for v in leaves)
+    found = _ONCE.get(key)
+    if found is None:
+        if len(_ONCE) >= 64:
+            _ONCE.clear()
+        closed, shapes = jax.make_jaxpr(fn, return_shape=True)(*operands)
+        found = _ONCE[key] = (closed, jax.tree.structure(shapes))
+    closed, out = found
+    return jax.tree.unflatten(out, jax.core.eval_jaxpr(
+        closed.jaxpr, closed.consts, *leaves))
+
+
+class _MapsOf(NamedTuple):
+    """The statics of a configuration's small mathematics (hashable, so
+    that configurations that agree on them share one trace)."""
+    streams: int
+    sinkhorn_iters: int
+    hc_eps: float
+    res_clamp: Tuple[float, float]
+
+    @classmethod
+    def of(cls, cfg) -> "_MapsOf":
+        return cls(streams_of(cfg), int(cfg.sinkhorn_iters),
+                   float(cfg.hc_eps), tuple(map(float, cfg.res_clamp)))
+
+    def __call__(self, h, b, alpha):
+        """The three maps from the scores ``h`` [n^2 + 2n, T]: the small
+        mathematics of :func:`stream_maps`, a position a lane."""
+        n = self.streams
+        with jax.named_scope("mv.lm.hc.project"):
+            h = jnp.repeat(alpha, np.array([n, n, n * n]),
+                           total_repeat_length=n * n + 2 * n)[:, None] * h \
+                + b[:, None]
+            pre = jax.nn.sigmoid(h[:n])
+            post = 2.0 * jax.nn.sigmoid(h[n:2 * n])
+        with jax.named_scope("mv.lm.hc.sinkhorn"):
+            m = jnp.exp(jnp.clip(h[2 * n:], *self.res_clamp)).reshape(
+                n, n, -1)
+            for _ in range(self.sinkhorn_iters):
+                m = m / (jnp.sum(m, 0, keepdims=True) + self.hc_eps)  # columns
+                m = m / (jnp.sum(m, 1, keepdims=True) + self.hc_eps)  # rows
+        return pre, post, m
+
+
 def stream_maps(x, phi, b, alpha, cfg):
     """The three maps of one sublayer's hyper-connection (manifold-
     constrained, arXiv:2512.24880 equations 7 and 8) from the streams ``x``
@@ -752,28 +829,8 @@ def stream_maps(x, phi, b, alpha, cfg):
     = ``exp(clip(alpha_2 h + b, cfg.res_clamp))`` [n, n] made doubly
     stochastic by ``cfg.sinkhorn_iters`` rounds of (every column over its
     sum + ``hc_eps``, then every row over its sum + ``hc_eps``)."""
-    bsz, s, n, c = x.shape
-    flat = x.reshape(bsz * s, n * c).astype(jnp.float32)
-    with jax.named_scope("mv.lm.hc.norm"):
-        r = jax.lax.rsqrt(jnp.mean(flat * flat, -1) + cfg.eps)      # [T]
-    with jax.named_scope("mv.lm.hc.project"):
-        # [n^2 + 2n, T]: the norm's factor is a number a position, taken
-        # after the product, so no normed copy of the streams is made
-        h = jax.lax.dot_general(
-            phi, flat, (((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32) * r[None, :]
-        h = jnp.repeat(alpha, np.array([n, n, n * n]),
-                       total_repeat_length=n * n + 2 * n)[:, None] * h \
-            + b[:, None]
-        pre = jax.nn.sigmoid(h[:n])
-        post = 2.0 * jax.nn.sigmoid(h[n:2 * n])
-    with jax.named_scope("mv.lm.hc.sinkhorn"):
-        m = jnp.exp(jnp.clip(h[2 * n:], *cfg.res_clamp)).reshape(n, n, -1)
-        for _ in range(cfg.sinkhorn_iters):
-            m = m / (jnp.sum(m, 0, keepdims=True) + cfg.hc_eps)  # columns
-            m = m / (jnp.sum(m, 1, keepdims=True) + cfg.hc_eps)  # rows
-    return pre, post, m
+    maps_of = _MapsOf.of(cfg)
+    return _once(maps_of, maps_of, _stream_scores(x, phi, cfg)[2], b, alpha)
 
 
 def res_error(res):
@@ -802,54 +859,42 @@ def block(x, p, attn, ffn, cfg):
     y)`` (the streams mixed among themselves, the result written to all
     of them); the block then returns a third thing, the largest
     :func:`res_error` of its sublayers."""
-    def out(branch, name):
+    def out(branch, name, q):
         if not cfg.post_norms:
             return branch
         with jax.named_scope("mv.lm.norm.post"):
-            return rms_norm(branch, p[name], cfg.eps)
+            return rms_norm(branch, q[name], cfg.eps)
 
-    def normed(stream, name):
+    def normed(stream, name, q):
         with jax.named_scope("mv.lm.norm.pre"):
-            return rms_norm(stream, p[name], cfg.eps)
+            return rms_norm(stream, q[name], cfg.eps)
 
-    errors = []
-
-    def read(stream, branch):
-        """(what the branch reads of ``stream``, how its result joins)."""
+    def sublayer(stream, name, branch, q):
+        """``stream`` after the sublayer ``name`` whose ``branch(u, q)``
+        gives (its result as it joins the streams, what else it hands
+        back): (the streams, that, the hyper-connection's error or none)."""
         if streams_of(cfg) == 1:
-            return stream, lambda y: stream + y
-        pre, post, res = stream_maps(
-            stream, *(p[f"{branch}.hc_{k}"] for k in ("phi", "b", "alpha")),
-            cfg)
-        errors.append(res_error(jax.lax.stop_gradient(res)))
-        # the mixes are sums of n products a number, written out: float32
-        # on the vector unit, where an einsum over 4 would go through the
-        # matrix unit at its default precision
-        n, lead = stream.shape[2], stream.shape[:2]
-        at = lambda m: m.reshape(*lead, 1)      # a map's [T] at [B, S, 1]
-        with jax.named_scope("mv.lm.hc.pre"):
-            each = [stream[:, :, i] for i in range(n)]
-            u = sum(at(pre[i]) * each[i] for i in range(n))
+            y, extra = branch(stream, q)
+            return stream + y, extra, []
+        hc = tuple(q[f"{name}.hc_{k}"] for k in ("phi", "b", "alpha"))
+        h, extra, error = _hyper(branch, cfg, stream, hc, q)
+        return h, extra, [error]
 
-        def write(y):
-            with jax.named_scope("mv.lm.hc.post"):
-                return jnp.stack(
-                    [sum(at(res[i, j]) * each[j] for j in range(n))
-                     + at(post[i]) * y for i in range(n)], 2)
+    def attention(u, q):
+        mixed = attn(normed(u, "attn_norm", q), q)
+        mixed, term = mixed if isinstance(mixed, tuple) else (mixed, None)
+        return out(mixed, "attn_post_norm", q), term
 
-        return u, write
+    def feed_forward(u, q):
+        f, aux = ffn(normed(u, "ffn_norm", q), q)
+        return out(f, "ffn_post_norm", q), aux
 
-    h, aux, term = x, None, None
+    h, aux, term, errors = x, None, None, []
     if attn is not None:
-        u, join = read(x, "attn")
-        mixed = attn(normed(u, "attn_norm"), p)
-        if isinstance(mixed, tuple):
-            mixed, term = mixed
-        h = join(out(mixed, "attn_post_norm"))
+        h, term, errors = sublayer(h, "attn", attention, p)
     if ffn is not None:
-        u, join = read(h, "ffn")
-        f, aux = ffn(normed(u, "ffn_norm"), p)
-        h = join(out(f, "ffn_post_norm"))
+        h, aux, error = sublayer(h, "ffn", feed_forward, p)
+        errors = errors + error
     if term is not None:
         if aux is None:
             raise ValueError("a mixer's term rides an expert layer's aux")
@@ -857,6 +902,100 @@ def block(x, p, attn, ffn, cfg):
     if errors:
         return h, aux, functools.reduce(jnp.maximum, errors)
     return h, aux
+
+
+def _pre_mix(x, pre):
+    """``u = pre . x`` [B, S, C] of a hyper-connected sublayer from the
+    streams ``x`` [B, S, n, C]: what the branch reads. The mixes are sums
+    of n products a number, written out: float32 on the vector unit, where
+    an einsum over 4 would go through the matrix unit at its default
+    precision."""
+    n, lead = x.shape[2], x.shape[:2]
+    with jax.named_scope("mv.lm.hc.pre"):
+        return sum(pre[i].reshape(*lead, 1) * x[:, :, i] for i in range(n))
+
+
+def _post_mix(x, y, post, res):
+    """``x' = res @ x + outer(post, y)``: the streams mixed among
+    themselves, the branch's result written to all of them."""
+    n, lead = x.shape[2], x.shape[:2]
+    at = lambda m: m.reshape(*lead, 1)      # a map's [T] at [B, S, 1]
+    with jax.named_scope("mv.lm.hc.post"):
+        each = [x[:, :, i] for i in range(n)]
+        return jnp.stack(
+            [sum(at(res[i, j]) * each[j] for j in range(n))
+             + at(post[i]) * y for i in range(n)], 2)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _hyper(branch, cfg, x, hc, q):
+    """One hyper-connected sublayer: the streams ``x`` [B, S, n, C] under
+    the maps of ``hc`` = (phi, b, alpha) round ``branch(u, q)`` -> (y [B, S,
+    C], what else it hands back): ``(x', that, the maps' res_error)``.
+
+    This is the forward pass as XLA makes it (and makes it again under
+    ``jax.checkpoint``). ONE differentiation rule stands over the whole
+    sublayer (:func:`_hyper_fwd`, :func:`_hyper_bwd`): plain autodiff of
+    these lines reads the streams once for the norm, once for the
+    projection and once for each mix, and writes the streams' gradient in
+    parts that it then adds (13.2 ms a sublayer where the least is 2.4:
+    PERF.md section 6, PR 60)."""
+    pre, post, res = stream_maps(x, *hc, cfg)
+    y, extra = branch(_once("pre", _pre_mix, x, pre), q)
+    return _once("post", _post_mix, x, y, post, res), extra, res_error(res)
+
+
+def _hyper_fwd(branch, cfg, x, hc, q):
+    """:func:`_hyper` beside what its backward pass reads: the streams, the
+    branch's result, the maps and what made them, and the maps' and the
+    branch's own ``jax.vjp``."""
+    phi, b, alpha = hc
+    r, raw, h = _stream_scores(x, phi, cfg)
+    maps_of = _MapsOf.of(cfg)
+    maps, maps_back = _once((maps_of, "vjp"),
+                            lambda *of: jax.vjp(maps_of, *of), h, b, alpha)
+    pre, post, res = maps
+    (y, extra), branch_back = jax.vjp(
+        branch, _once("pre", _pre_mix, x, pre), q)
+    out = (_once("post", _post_mix, x, y, post, res), extra, res_error(res))
+    return out, (x, y, phi, r, raw, maps, maps_back, branch_back)
+
+
+def _hyper_bwd(branch, cfg, kept, cts):
+    """The streams' gradient ``g`` to every gradient of the sublayer, by
+    the walks of ``ops/stream_walks.py`` (its docstring has the
+    equations): :func:`stream_walks.gather` before the branch's own
+    backward pass, :func:`stream_walks.dots` and the small mathematics on
+    [n^2 + 2n, T] arrays after it (the maps' own ``jax.vjp``: Sinkhorn's
+    rounds are 0.45% of a step and XLA's), :func:`stream_walks.spread`
+    last, which writes the streams' gradient once."""
+    x, y, phi, r, raw, (pre, post, res), maps_back, branch_back = kept
+    g, dextra, _ = cts
+    n, c = x.shape[2:]
+    flat = lambda v: v.reshape((-1,) + v.shape[2:])
+    g, xs = flat(g.astype(jnp.float32)), flat(x)
+    with jax.named_scope("mv.lm.hc.bwd"):
+        dy, dpost, dres = stream_walks.gather(g, xs, flat(y), post)
+    du, dq = branch_back((dy.reshape(y.shape).astype(y.dtype), dextra))
+    du = flat(du)
+    with jax.named_scope("mv.lm.hc.bwd"):
+        dpre = stream_walks.dots(du, xs)
+    dh, db, dalpha = _once("pull", lambda back, cts: back(cts), maps_back,
+                           (dpre, dpost, dres))
+    with jax.named_scope("mv.lm.hc.bwd"):
+        # h = r (phi x): a = r dh goes back through the product, and the
+        # norm's factor r = (mean(x^2) + eps)^-1/2 takes <dh, phi x>
+        a = r[None, :] * dh
+        norm = jnp.sum(dh * raw, 0) * r * r * r / (n * c)
+        dx = stream_walks.spread(g, xs, du, phi, pre, res, a, norm)
+        dphi = jax.lax.dot_general(
+            a, xs.reshape(-1, n * c), (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    return dx.reshape(x.shape).astype(x.dtype), (dphi, db, dalpha), dq
+
+
+_hyper.defvjp(_hyper_fwd, _hyper_bwd)
 
 
 def _sub(params: Dict[str, Any], prefix: str) -> Dict[str, Any]:
@@ -1013,16 +1152,22 @@ def stream_grid(cfg, batch: int, positions: int) -> Dict[str, int]:
     sublayers that have a hyper-connection of their own) and
     ``hc_stream_bytes``, a block's input [batch, positions, streams, dim]
     float32 times the blocks whose input the rematerialised step keeps
-    (every one), beside ``kept_bytes``."""
+    (every one), beside ``kept_bytes``; and of the sublayers' backward pass
+    (:func:`_hyper_bwd`) ``hc_kernel_sublayers``, those whose walks over the
+    streams are ``ops/stream_walks.py``'s kernels on this device (all or
+    none), and ``hc_bwd_stream_bytes``, what the walks read and write a
+    step (``stream_walks.step_counts``)."""
     n = streams_of(cfg)
     if n == 1:
         return {}
     layers = cfg.layers()
+    sublayers = sum(bool(layer.attn) + bool(layer.ffn) for layer in layers)
     return {"streams": n, "sinkhorn_iters": cfg.sinkhorn_iters,
-            "hc_sublayers": sum(bool(layer.attn) + bool(layer.ffn)
-                                for layer in layers),
+            "hc_sublayers": sublayers,
             "hc_stream_bytes": 4 * batch * positions * n * cfg.dim
-            * len(layers)}
+            * len(layers),
+            **stream_walks.step_counts(sublayers, batch * positions, n,
+                                       cfg.dim)}
 
 
 def _embed(params, tokens, cfg):

@@ -351,16 +351,41 @@ def test_last_line_is_the_result_and_nothing_else(monkeypatch, capsys, fail):
     assert list(summary)[-1] == "claim"
 
 
+def test_hc_stage_rehearses_the_kernels_in_the_interpreter():
+    """The stage as the chip runs it, the three walks' kernels in the
+    Pallas interpreter: each kernel's ms and error against its plain form
+    (no share of a peak off a TPU), and the rule with them held to the
+    reference's sublayer as the plain walks are."""
+    from multiverso_tpu.ops import stream_walks
+
+    found = stream_walks.gather
+    facts = chip_smoke.stage_hc(positions=128, dim=64, repeats=1,
+                                check_positions=128, stack=2, interpret=True)
+    walks = facts["walks"]
+    assert walks["kernels"] and walks["tiles"] == [64, 64, 64]
+    for name in ("gather", "dots", "spread"):
+        assert walks[f"{name}_ms"] > 0 and walks[f"{name}_plain_ms"] > 0
+        assert walks[f"{name}_rel_err"] <= 2e-6
+        assert f"{name}_hbm_share" not in walks
+    assert max(facts["rel_err"]) <= chip_smoke.HC_F32_TOL
+    assert stream_walks.gather is found         # nothing stays steered
+
+
 def test_hc_stage():
     """One hyper-connected sublayer's stream maps alone at a small size: ms
     and compile seconds forward and with every gradient, the mix's error,
     and the result and gradients held to the reference's sublayer; the
     least the memory allows is a chip's number and is not made up here."""
     facts = chip_smoke.stage_hc(positions=64, dim=128, repeats=1,
-                                check_positions=64)
-    for what in ("fwd", "fwd_bwd"):
+                                check_positions=64, stack=2)
+    for what in ("fwd", "fwd_bwd", "rule_plain", "autodiff"):
         assert facts[f"{what}_ms"] > 0 and facts[f"{what}_compile_s"] >= 0
         assert f"{what}_least_ms" not in facts
+    # off a TPU the rule's walks are the plain forms, and nothing of the
+    # kernels is read; the stack's trace and lowering are timed apart
+    assert facts["walks"] == {"kernels": False}
+    assert facts["rule_plain_rel_err"] == facts["rel_err"]
+    assert facts["stack_trace_s"] > 0 and facts["stack_lower_s"] > 0
     assert 0 < facts["res_error"] < 5e-2
     # the weighted sum, dx, and the four tables' gradients
     assert len(facts["rel_err"]) == 6

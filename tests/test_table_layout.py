@@ -1211,6 +1211,48 @@ def test_a_hyper_connected_sublayers_maps_on_a_v5e_keep_a_position_a_lane(
     assert "f32[4096,4,4]" not in compiled.as_text()
 
 
+def test_a_hyper_connected_blocks_backward_walks_compile_for_a_v5e(
+        one_chip, monkeypatch):
+    """``xing4-train-4k``'s backward walks (``ops/stream_walks.py``) at the
+    cell's shapes, under the sublayer's one rule in a rematerialised block
+    of two sublayers: Mosaic takes the three kernels at the tiles
+    ``tiles_for`` gives (blocks of 512 channels x 128 positions a stream,
+    inside the default scoped VMEM), each
+    is in the program once a sublayer, and the kernels read and write the
+    streams a stream at a time without a pass of their own for it: the
+    transposes round a call are layout (XLA lays the streams out [B, n, S,
+    C] between its own fusions), and none is left as an operation."""
+    from multiverso_tpu.models import mla_moe
+    from multiverso_tpu.ops import stream_walks
+
+    monkeypatch.setattr(
+        stream_walks, "walk_tiles",
+        lambda t, n, c, *dtypes: stream_walks.tiles_for(t, n, c))
+    cfg = _xing4()
+    assert stream_walks.tiles_for(4096, 4, 3584) == (512, 512, 512)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {f"{b}{k}": f32(*s) for b in ("attn", "ffn") for k, s in (
+        ("_norm", (3584,)), (".hc_phi", (24, 14336)), (".hc_b", (24,)),
+        (".hc_alpha", (3,)))}
+
+    def loss(x, p, weight):
+        run = lambda x, p: mla_moe.block(
+            x, p, lambda u, p: jnp.tanh(u), lambda u, p: (jnp.tanh(u), None),
+            cfg)[0]
+        return (jax.checkpoint(run)(x, p) * weight).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        f32(1, 4096, 4, 3584), p, f32(1, 4096, 4, 3584)).compile()
+    text = compiled.as_text()
+    for name in (stream_walks.GATHER, stream_walks.DOTS, stream_walks.SPREAD):
+        assert len(re.findall(rf'custom-call\(.*"{name}"|{name}', text)) >= 2
+    assert text.count("tpu_custom_call") == 6
+    assert not re.search(
+        r"= f32\[(1,)?4(096)?,4(096)?,3584\]\S* transpose\(", text)
+    stream = 4 * 4096 * 4 * 3584
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * stream
+
+
 def test_sigmoid_expert_layer_at_row_tiles_of_128_compiles_for_a_v5e(one_chip):
     """The held experts' grouped products as ``xing4-train-4k`` calls them:
     a 4,096-row buffer in eight groups of 3,584 x 1,024 at a row tile of 128

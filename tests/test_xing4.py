@@ -409,7 +409,11 @@ def test_span_fields_of_several_streams():
     cfg = _published()
     grid = mla_moe.stream_grid(cfg, 1, 4096)
     assert grid == {"streams": 4, "sinkhorn_iters": 20, "hc_sublayers": 10,
-                    "hc_stream_bytes": 5 * 4096 * 4 * 3584 * 4}
+                    "hc_stream_bytes": 5 * 4096 * 4 * 3584 * 4,
+                    # the backward walks (PR 62): plain off a TPU; (2n + 2)
+                    # + (n + 1) + (3n + 1) + n arrays a sublayer
+                    "hc_kernel_sublayers": 0,
+                    "hc_bwd_stream_bytes": 10 * 32 * 4096 * 3584 * 4}
     assert mla_moe.kept_grid(cfg, 1, 4096)["kept_bytes"] > 0
 
 
@@ -474,7 +478,10 @@ def test_the_timeline_prints_the_streams():
     lines = dump_metrics._stream_lines([{"name": "lm.step", "args": args}])
     assert lines == ["  residual streams: 4, mixed round 10 sublayers by 20 "
                      "Sinkhorn rounds each; kept block inputs 1174 MB; "
-                     "largest mix error 0.004 over 1 steps"]
+                     "largest mix error 0.004 over 1 steps",
+                     "  their backward walks: kernels in 0 of 10 sublayers "
+                     "(0: the plain forms), 18790 MB of streams read and "
+                     "written a step"]
     assert dump_metrics._stream_lines([{"name": "lm.step", "args": {}}]) == []
     assert "mv.lm.hc.expand" in dump_metrics.__doc__
 

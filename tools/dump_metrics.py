@@ -806,20 +806,29 @@ def _mixer_lines(events: List[Dict]) -> List[str]:
 def _stream_lines(events: List[Dict]) -> List[str]:
     """Under several residual streams (``models/mla_moe.stream_grid``; the
     device scopes ``mv.lm.hc.*``): how many, Sinkhorn's rounds a sublayer,
-    the sublayers that mix them, what the kept block inputs weigh, and the
-    largest ``abs(row or column sum of H_res - 1)`` any step read back."""
+    the sublayers that mix them, what the kept block inputs weigh, the
+    largest ``abs(row or column sum of H_res - 1)`` any step read back, and
+    whether the sublayers' backward walks ran as kernels and what they
+    moved."""
     steps = [e["args"] for e in events if e.get("name") == "lm.step"
              and "streams" in e.get("args", {})]
     if not steps:
         return []
     a = steps[0]
     errors = [s["hc_res_error"] for s in steps if "hc_res_error" in s]
-    return [f"  residual streams: {a['streams']}, mixed round "
-            f"{a['hc_sublayers']} sublayers by {a['sinkhorn_iters']} "
-            f"Sinkhorn rounds each; kept block inputs "
-            f"{a['hc_stream_bytes'] / 1e6:.0f} MB"
-            + (f"; largest mix error {max(errors):.3g} over {len(errors)} "
-               "steps" if errors else "")]
+    lines = [f"  residual streams: {a['streams']}, mixed round "
+             f"{a['hc_sublayers']} sublayers by {a['sinkhorn_iters']} "
+             f"Sinkhorn rounds each; kept block inputs "
+             f"{a['hc_stream_bytes'] / 1e6:.0f} MB"
+             + (f"; largest mix error {max(errors):.3g} over {len(errors)} "
+                "steps" if errors else "")]
+    if "hc_kernel_sublayers" in a:      # the backward walks (PR 62)
+        lines.append(
+            f"  their backward walks: kernels in {a['hc_kernel_sublayers']} "
+            f"of {a['hc_sublayers']} sublayers (0: the plain forms), "
+            f"{a['hc_bwd_stream_bytes'] / 1e6:.0f} MB of streams read and "
+            "written a step")
+    return lines
 
 
 def _buffer_lines(events: List[Dict]) -> List[str]:
