@@ -47,6 +47,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import json
+import re
 import sys
 import tempfile
 import time
@@ -1408,6 +1409,238 @@ def stage_taps(positions: int = 16384, calls: Tuple = TAPS_CALLS,
     return facts
 
 
+# ---------------------------------------------------------------------- #
+# between a projection and the attention core (PR 63)
+# ---------------------------------------------------------------------- #
+def parent_gqa(u, p, cfg, kind: str, core=None):
+    """``models/gqa_moe.gqa`` as it stood before ``mla_moe.heads``, worded
+    from the equations of that file's docstring: three projections, q/k
+    norms, rotary positions by the half-split, one rounding, a transpose
+    into the core; a transpose out of it, the gate, ``W_o``."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import mla_moe
+
+    b, s, _ = u.shape
+    h, hkv, hd, dt = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.compute_dtype
+    mm = functools.partial(mla_moe.matmul, dtype=dt)
+    q = mm(u, p["wq"], False, out_dtype=jnp.float32).reshape(b, s, h, hd)
+    k = mm(u, p["wk"], False, out_dtype=jnp.float32).reshape(b, s, hkv, hd)
+    v = mm(u, p["wv"], False, out_dtype=dt).reshape(b, s, hkv, hd)
+    if cfg.qk_norm:
+        q = mla_moe.rms_norm(q, p["q_norm"], cfg.eps)
+        k = mla_moe.rms_norm(k, p["k_norm"], cfg.eps)
+    if kind in cfg.rope_kinds:
+        yarn = cfg.yarn if kind == "full" else None
+        r = getattr(cfg, "rope_dim", None) or hd
+        turn = lambda t: jnp.concatenate(
+            [mla_moe.rotary(t[..., :r], cfg.rope_theta, yarn), t[..., r:]], -1)
+        q, k = turn(q), turn(k)
+    q, k, v = (t.astype(dt).transpose(0, 2, 1, 3) for t in (q, k, v))
+    if core is None:
+        return q, k, v
+    o = core(q, k, v).transpose(0, 2, 1, 3).reshape(b, s, h * hd)
+    if cfg.attn_gate:
+        o = o * jax.nn.sigmoid(mm(u, p["wgate"], False, out_dtype=jnp.float32))
+    return mm(o, p["wo"], False, out_dtype=jnp.float32)
+
+
+def parent_mla(u, p, cfg, core=None):
+    """``models/mla_moe.mla`` as it stood before ``mla_moe.heads``, worded
+    from the equations of that file's docstring: one product a latent,
+    column windows of its result, a concatenation a core operand."""
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import mla_moe
+
+    b, s, _ = u.shape
+    h, dt = cfg.n_heads, cfg.compute_dtype
+    nope, rope, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    mm = functools.partial(mla_moe.matmul, dtype=dt)
+    c_q = mla_moe.rms_norm(mm(u, p["wdq"], False, out_dtype=jnp.float32),
+                           p["q_norm"], cfg.eps)
+    q = mm(c_q, p["wuq"], False, out_dtype=jnp.float32).reshape(b, s, h, -1)
+    down = mm(u, p["wdkv"], True, out_dtype=jnp.float32)
+    c_kv = mla_moe.rms_norm(down[..., :cfg.kv_lora_rank], p["kv_norm"],
+                            cfg.eps)
+    k_r = mla_moe.rotary(down[..., cfg.kv_lora_rank:], cfg.rope_theta,
+                         cfg.yarn)
+    kv = mm(c_kv, p["wukv"], False, out_dtype=dt).reshape(b, s, h, nope + dv)
+    q = jnp.concatenate([q[..., :nope], mla_moe.rotary(
+        q[..., nope:], cfg.rope_theta, cfg.yarn)], -1)
+    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+        k_r[:, :, None, :], (b, s, h, rope)).astype(dt)], -1)
+    q, k, v = (t.astype(dt).transpose(0, 2, 1, 3)
+               for t in (q, k, kv[..., nope:]))
+    if core is None:
+        return q, k, v
+    o = core(q, k, v).transpose(0, 2, 1, 3).reshape(b, s, h * dv)
+    return mm(o, p["wo"], False, out_dtype=jnp.float32)
+
+
+_HLO_SHAPE = re.compile(r"\b(pred|[su](?:8|16|32|64)|bf16|f16|f32|f64)"
+                        r"\[([0-9,]*)\]")
+_HLO_FREE = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast",
+             "after-all", "iota", "partition-id")
+
+
+def _hlo_bytes(text: str) -> int:
+    total = 0
+    for kind, dims in _HLO_SHAPE.findall(text):
+        size = 1 if kind == "pred" else int(
+            re.sub(r"\D", "", kind)) // 8
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        total += size
+    return total
+
+
+def bytes_outside_kernels(compiled_text: str) -> Dict[str, Any]:
+    """Of a compiled program's text: the bytes its entry computation's
+    instructions write and read (result and operands of every top-level
+    instruction, a fusion counted as one), those of the Pallas kernels
+    (``tpu_custom_call``) apart, in GB: ``{"all_gb", "kernels_gb",
+    "outside_gb", "kernels"}``. An upper reading of the HBM traffic round
+    the kernels (an operand prefetched into VMEM is counted where it is
+    copied and where it is read); nothing where the text has no entry."""
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", compiled_text,
+                      re.S | re.M)
+    if not entry:
+        return {}
+    results: Dict[str, int] = {}
+    total = kernels = count = 0
+    for line in entry.group(1).splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?(\S+) = (.*?) ([\w\-]+)\((.*)$", line)
+        if not m:
+            continue
+        name, result, op, rest = m.groups()
+        results[name] = _hlo_bytes(result)
+        if op in _HLO_FREE or (op == "custom-call"
+                               and "tpu_custom_call" not in line):
+            continue
+        moved = results[name] + sum(
+            results.get(a, 0) for a in re.findall(
+                r"%([\w\.\-]+)", rest.split("), ")[0]))
+        total += moved
+        if op == "custom-call":
+            kernels, count = kernels + moved, count + 1
+    return {"all_gb": round(total / 1e9, 3),
+            "kernels_gb": round(kernels / 1e9, 3),
+            "outside_gb": round((total - kernels) / 1e9, 3), "kernels": count}
+
+
+def _heads_cells():
+    """(name, configuration, layer kind, sequences, positions) of ONE
+    attention layer as seven cells run it."""
+    from multiverso_tpu.models import (afmoe, gqa_moe, lfm2_moe, mla_moe,
+                                       qwen3_next, xing4)
+
+    yarn = mla_moe.Yarn(16.0, 8192, 32.0, 1.0, 1.2772588722239782)
+    mellum = gqa_moe.GQAMoEConfig(dim=2304, n_heads=32, n_kv_heads=4,
+                                  head_dim=128, window=1024, yarn=yarn)
+    return (
+        ("glm", mla_moe.MLAMoEConfig(
+            dim=2048, n_heads=20, q_lora_rank=768, kv_lora_rank=512,
+            qk_nope_dim=192, qk_rope_dim=64, v_head_dim=256), "latent", 2,
+         8192),
+        ("mellum_full", mellum, "full", 2, 8192),
+        ("mellum_window", mellum, "window", 2, 8192),
+        ("trinity", afmoe.AFMoEConfig(
+            dim=2048, n_heads=32, n_kv_heads=4, head_dim=128, window=2048),
+         "window", 1, 16384),
+        # Keye's projections round a plain causal core: no selection here
+        ("keye", mellum._replace(dim=2048, qk_norm=True, yarn=None,
+                                 rope_theta=1e7, eps=1e-6), "full", 1, 16384),
+        ("xing", xing4.Xing4Config(
+            dim=3584, n_heads=32, q_lora_rank=768, kv_lora_rank=512,
+            qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128), "latent", 1,
+         4096),
+        # and two more head shapes: 64 whole, 64 of 256 under a gate
+        ("lfm2", lfm2_moe.LFM2MoEConfig(
+            dim=2048, n_heads=32, n_kv_heads=8, head_dim=64), "full", 2, 8192),
+        ("qwen3next", qwen3_next.Qwen3NextConfig(
+            dim=2048, n_heads=16, n_kv_heads=2, head_dim=256, rope_dim=64),
+         "full", 1, 16384))
+
+
+# bfloat16 operands on both sides: the gradients' max|err| over max|parent|
+HEADS_TOL = 2e-2
+
+
+def stage_heads(cells: Optional[Tuple] = None, repeats: int = 5,
+                attn: Optional[str] = None) -> Dict[str, Any]:
+    """ONE attention layer with every gradient (``dx`` and every ``dW``) at
+    the shapes five language-model cells run it, the parameters and the
+    input ARGUMENTS of the program: ``mla_moe.heads`` / ``out_of_heads``
+    round the core (``cfg.attend``) beside the formulation before them
+    (:func:`parent_gqa`, :func:`parent_mla`), ms a call by this process's
+    clock, compile seconds, and the compiled layer's bytes outside its
+    Pallas kernels (:func:`bytes_outside_kernels`, for the device at hand);
+    then the two forms' gradients against each other. ``cells``: other
+    (name, configuration, kind, sequences, positions); ``attn``: the core
+    (a CPU test's ``"xla"``)."""
+    import jax
+    import jax.numpy as jnp
+
+    from multiverso_tpu.models import gqa_moe, mla_moe
+    from multiverso_tpu.ops.attention_kernels import flash_attention
+
+    facts: Dict[str, Any] = {}
+    for name, cfg, kind, b, s in cells or _heads_cells():
+        cfg = cfg._replace(attn=attn) if attn else cfg
+        window = cfg.window if kind == "window" else None
+        scale = getattr(cfg, "softmax_scale", None)
+
+        def core(q, k, v, cfg=cfg, window=window, scale=scale, s=s):
+            if mla_moe.attn_core(cfg) != "flash":
+                return mla_moe._xla_attention(q, k, v, window, scale=scale)
+            return flash_attention(q, k, v, True, *mla_moe.attn_blocks(cfg, s),
+                                   None, window, scale=scale)
+
+        if kind == "latent":
+            parent = lambda u, p, cfg=cfg, core=core: parent_mla(
+                u, p, cfg, core)
+            new = lambda u, p, cfg=cfg: mla_moe.mla(u, p, cfg)
+        else:
+            parent = lambda u, p, cfg=cfg, kind=kind, core=core: parent_gqa(
+                u, p, cfg, kind, core)
+            new = lambda u, p, cfg=cfg, kind=kind: gqa_moe.gqa(
+                u, p, cfg, kind)
+        shapes = cfg.attn_shapes(kind)
+        keys = jax.random.split(jax.random.key(SEED), len(shapes) + 2)
+        p = {n: (jnp.ones(sh) if n.endswith("norm") else
+                 sh[0] ** -0.5 * jax.random.normal(key, sh))
+             for (n, sh), key in zip(sorted(shapes.items()), keys)}
+        u = jax.random.normal(keys[-1], (b, s, cfg.dim))
+        weight = jax.random.normal(keys[-2], (b, s, cfg.dim))
+        got = {}
+        for tag, form in (("parent", parent), ("new", new)):
+            fn = lambda u, p, weight, form=form: jax.grad(
+                lambda u, p: jnp.sum(weight * form(u, p)), (0, 1))(u, p)
+            t0 = time.perf_counter()
+            compiled = jax.jit(fn).lower(u, p, weight).compile()
+            facts[f"{name}_{tag}_compile_s"] = round(
+                time.perf_counter() - t0, 2)
+            _, ms, got[tag] = _timed(fn, (u, p, weight), repeats)
+            facts[f"{name}_{tag}_ms"] = ms
+            facts.update({f"{name}_{tag}_{k}": v for k, v in
+                          bytes_outside_kernels(compiled.as_text()).items()})
+        # a failed check keeps the readings
+        _say("heads.timed", **{k: v for k, v in facts.items()
+                               if k.startswith(name + "_")})
+        errs = jax.tree.map(
+            lambda g, t: float(jnp.max(jnp.abs(g - t)) / jnp.max(jnp.abs(t))),
+            got["new"], got["parent"])
+        worst = max(jax.tree.leaves(errs))
+        if not worst <= HEADS_TOL:      # a NaN fails too
+            raise AssertionError(f"heads: {name} gradients differ from the "
+                                 f"parent formulation's: {errs}")
+        facts[f"{name}_rel_err"] = float(f"{worst:.3g}")
+    return facts
+
+
+
 HC_F32_TOL = 1e-4
 
 
@@ -2215,8 +2448,8 @@ STAGES: Tuple[Tuple[str, Callable[[], Dict[str, Any]]], ...] = (
     ("ps", stage_ps),
     ("lm", stage_lm), ("flash", lambda: stage_flash(selected={})),
     ("ssd", stage_ssd),
-    ("conv", stage_conv), ("taps", stage_taps), ("delta", stage_delta),
-    ("hc", stage_hc),
+    ("conv", stage_conv), ("taps", stage_taps), ("heads", stage_heads),
+    ("delta", stage_delta), ("hc", stage_hc),
     ("select", stage_select), ("target", stage_target),
     ("memory", stage_memory))
 

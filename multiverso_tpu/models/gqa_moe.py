@@ -37,7 +37,6 @@ chunked loss, the tables, the step and the ``Trainer`` are
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -133,43 +132,34 @@ def heads_of(u, p, cfg, kind: str):
     projections, the q/k norms where the configuration has them and the
     positions where ``kind`` takes them (over the first ``cfg.rope_dim`` of
     a head where the configuration has such a field, the rest passing
-    through; over the whole head without it). Called inside
-    ``mv.lm.attn``."""
-    b, s, _ = u.shape
+    through; over the whole head without it). Each is ONE product with
+    the weight viewed [D, heads, hd], written once with the head axis before
+    the positions (``mla_moe.heads``). Called inside ``mv.lm.attn``."""
     h, hkv, hd, dt = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.compute_dtype
-    yarn = cfg.yarn if kind == "full" else None
-    mm = functools.partial(mla_moe.matmul, dtype=dt)
-    q = mm(u, p["wq"], False, out_dtype=jnp.float32).reshape(b, s, h, hd)
-    k = mm(u, p["wk"], False, out_dtype=jnp.float32).reshape(b, s, hkv, hd)
-    v = mm(u, p["wv"], False, out_dtype=dt).reshape(b, s, hkv, hd)
-    if cfg.qk_norm:
-        with jax.named_scope("mv.lm.attn.qknorm"):
-            q = mla_moe.rms_norm(q, p["q_norm"], cfg.eps)
-            k = mla_moe.rms_norm(k, p["k_norm"], cfg.eps)
+    plain = mla_moe.Heads(dt, eps=cfg.eps)
+    turned = plain
     if kind in cfg.rope_kinds:
-        turn = lambda t: mla_moe.rotary(t, cfg.rope_theta, yarn)
-        r = getattr(cfg, "rope_dim", None)
-        if r is not None:       # the first r of a head turn, the rest pass
-            turn = lambda t: jnp.concatenate(
-                [mla_moe.rotary(t[..., :r], cfg.rope_theta, yarn),
-                 t[..., r:]], -1)
-        q, k = turn(q), turn(k)
-    heads = lambda t: t.astype(dt).transpose(0, 2, 1, 3)
-    return heads(q), heads(k), heads(v)
+        r = getattr(cfg, "rope_dim", None)      # the first r of a head turn
+        turned = plain._replace(
+            rope=hd if r is None else r, theta=cfg.rope_theta,
+            yarn=cfg.yarn if kind == "full" else None)
+    gains = (p["q_norm"], p["k_norm"]) if cfg.qk_norm else (None, None)
+    of = lambda name, n, how, gain=None: mla_moe.heads(
+        u, p[name].reshape(-1, n, hd), how, gain)
+    return (of("wq", h, turned, gains[0]), of("wk", hkv, turned, gains[1]),
+            of("wv", hkv, plain))
 
 
 def out_of(o, u, p, cfg):
     """The core's output ``o`` [B, H, S, hd] -> [B, S, D] float32: the gate
-    where the configuration has one, then ``W_o``. Called inside
-    ``mv.lm.attn``."""
-    b, h, s, hd = o.shape
-    mm = functools.partial(mla_moe.matmul, dtype=cfg.compute_dtype)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
-    if cfg.attn_gate:
-        with jax.named_scope("mv.lm.attn.gate"):
-            o = o * jax.nn.sigmoid(
-                mm(u, p["wgate"], False, out_dtype=jnp.float32))
-    return mm(o, p["wo"], False, out_dtype=jnp.float32)
+    where the configuration has one, then ``W_o`` viewed [H, hd, D], with no
+    transposed copy of ``o`` between (``mla_moe.out_of_heads``). Called
+    inside ``mv.lm.attn``."""
+    _, h, _, hd = o.shape
+    gate = (p["wgate"].reshape(-1, h, hd) if cfg.attn_gate else None)
+    return mla_moe.out_of_heads(o, p["wo"].reshape(h, hd, -1),
+                                cfg.compute_dtype, u if cfg.attn_gate
+                                else None, gate)
 
 
 def gqa(u, p, cfg, kind: str):

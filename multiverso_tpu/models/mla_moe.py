@@ -60,7 +60,7 @@ import numpy as np
 
 from multiverso_tpu.ops.attention_kernels import (causal_pairs,
                                                    flash_attention, sub_tile)
-from multiverso_tpu.ops import stream_walks
+from multiverso_tpu.ops import head_turns, stream_walks
 from multiverso_tpu.parallel import moe
 from multiverso_tpu.telemetry import devstats as _devstats
 from multiverso_tpu.telemetry import trace as _trace
@@ -460,6 +460,29 @@ def rotary_frequencies(r: int, theta: float, yarn: Optional[Yarn] = None):
     return jnp.asarray(freq, jnp.float32), float(yarn.attention_factor)
 
 
+def rotary_tables(s: int, r: int, theta: float, yarn: Optional[Yarn] = None,
+                  positions=None,
+                  sections: Optional[Tuple[int, ...]] = None):
+    """(cos, sin) of :func:`rotary`'s angles over ``s`` positions and ``r``
+    columns, YaRN's factor in them: [S, r/2], or [B, S, r/2] under
+    ``positions`` [A, B, S] dealt by ``sections``."""
+    freq, factor = rotary_frequencies(r, theta, yarn)
+    if positions is None:
+        angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None, :]
+    else:
+        if sum(sections) != r // 2 or len(sections) != positions.shape[0]:
+            raise ValueError(f"sections {sections} do not deal {r // 2} "
+                             f"frequencies over {positions.shape[0]} axes")
+        axis = np.repeat(np.arange(len(sections)), sections)    # [R/2]
+        # frequency i's own axis: [B, S, R/2]
+        angle = jnp.moveaxis(positions.astype(jnp.float32), 0, -1)[
+            ..., axis] * freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if factor != 1.0:
+        cos, sin = factor * cos, factor * sin
+    return cos, sin
+
+
 def rotary(x, theta: float, yarn: Optional[Yarn] = None, positions=None,
            sections: Optional[Tuple[int, ...]] = None):
     """Rotary positions on the last axis of ``x`` [B, S, ..., R], pairing
@@ -472,22 +495,10 @@ def rotary(x, theta: float, yarn: Optional[Yarn] = None, positions=None,
     model that reads images; a text token's ids are equal, and that is
     the plain form)."""
     s, r = x.shape[1], x.shape[-1]
-    freq, factor = rotary_frequencies(r, theta, yarn)
-    if positions is None:
-        angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None, :]
-        shape = (1, s) + (1,) * (x.ndim - 3) + (r // 2,)
-    else:
-        if sum(sections) != r // 2 or len(sections) != positions.shape[0]:
-            raise ValueError(f"sections {sections} do not deal {r // 2} "
-                             f"frequencies over {positions.shape[0]} axes")
-        axis = np.repeat(np.arange(len(sections)), sections)    # [R/2]
-        # frequency i's own axis: [B, S, R/2]
-        ids = jnp.moveaxis(positions.astype(jnp.float32), 0, -1)[..., axis]
-        angle = ids * freq
-        shape = ids.shape[:2] + (1,) * (x.ndim - 3) + (r // 2,)
-    cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
-    if factor != 1.0:
-        cos, sin = factor * cos, factor * sin
+    cos, sin = rotary_tables(s, r, theta, yarn, positions, sections)
+    shape = ((1, s) if positions is None else cos.shape[:2]) + (
+        1,) * (x.ndim - 3) + (r // 2,)
+    cos, sin = cos.reshape(shape), sin.reshape(shape)
     a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
@@ -551,7 +562,43 @@ def attn_blocks(cfg, s: int) -> Tuple[int, int]:
     return (2 * bq if cfg.head_size <= 192 else bq), 2 * bq
 
 
-def attn_grid(cfg, s: int) -> Dict[str, Any]:
+def turned_parts(cfg, kind: str) -> Tuple[Tuple[int, int, bool], ...]:
+    """(heads, head size, whether normed) of every projection of a ``kind``
+    layer that takes :func:`heads`'s pass on its way to the core (a norm
+    or rotary positions): the latent layer's q; a grouped-query layer's q
+    and k where the configuration norms them or the kind takes positions;
+    none where a layer's parts all go as their products write them."""
+    if kind == "latent":
+        return ((cfg.n_heads, cfg.qk_nope_dim + cfg.qk_rope_dim, False),)
+    if not (cfg.qk_norm or kind in cfg.rope_kinds):
+        return ()
+    return tuple((n, cfg.head_dim, bool(cfg.qk_norm))
+                 for n in (cfg.n_heads, cfg.n_kv_heads))
+
+
+def heads_grid(cfg, kinds, sequences: int, s: int) -> Dict[str, int]:
+    """What :func:`heads` does a step, from the shapes: the layers whose
+    core operands it makes, the bytes its turning pass reads and writes
+    (float32 in and the compute dtype out, forward and made again under
+    ``jax.checkpoint``; the core's cotangent in, beside the float32 sum
+    where there is a norm, and the product's out, backward), and the
+    layers whose pass is ``ops/head_turns.py``'s kernels on this device
+    (0: XLA runs the plain form)."""
+    item = jnp.dtype(cfg.compute_dtype).itemsize
+    moved = kernel_layers = 0
+    for kind in kinds:
+        parts = turned_parts(cfg, kind)
+        for n, hd, normed in parts:
+            moved += sequences * s * n * hd * (
+                2 * (4 + item) + 2 * item + 4 * normed)
+        kernel_layers += bool(parts) and all(
+            head_turns.kernel_tile(s, hd, cfg.compute_dtype)
+            for _, hd, _ in parts)
+    return {"heads_layers": len(kinds), "heads_turned_bytes": moved,
+            "heads_kernel_layers": kernel_layers}
+
+
+def attn_grid(cfg, s: int, sequences: int = 1) -> Dict[str, Any]:
     """What one flash kernel call over ``s`` positions does a (batch x
     head), as ``lm.step`` spans carry it: the layers' attention kinds,
     the query heads a key-value head, a block's norms and the embedding's
@@ -561,7 +608,9 @@ def attn_grid(cfg, s: int) -> Dict[str, Any]:
     (``gqa_moe.gqa``'s switches); the positions a forward call computes
     (whole tiles, and of a crossed pair the sub-tiles of
     ``attention_kernels.sub_tile`` that hold a live position) beside those
-    it needs; nothing where XLA is the core."""
+    it needs; what lies between the projections and the core over
+    ``sequences`` of them (:func:`heads_grid`); nothing where XLA is the
+    core."""
     if attn_core(cfg) != "flash":
         return {}
     layers = cfg.layers()
@@ -580,7 +629,8 @@ def attn_grid(cfg, s: int) -> Dict[str, Any]:
            "attn_kinds": ",".join(kinds),
            "kv_group": cfg.kv_group,
            "block_norms": (2 if cfg.post_norms else 1) * branches,
-           "embed_scale": float(cfg.embed_scale)}
+           "embed_scale": float(cfg.embed_scale),
+           **heads_grid(cfg, kinds, sequences, s)}
     if "window" in kinds:
         band = causal_pairs(s, *blocks, cfg.window, sub)
         out.update(attn_pairs_live_window=band["live"],
@@ -651,6 +701,206 @@ def _xla_attention(q, k, v, window: Optional[int] = None, select=None,
                       ).astype(q.dtype).reshape(q.shape[:3] + v.shape[3:])
 
 
+# ---------------------------------------------------------------------- #
+# between a projection and the core
+# ---------------------------------------------------------------------- #
+class Heads(NamedTuple):
+    """How one projection's result reaches the attention core (hashable:
+    :func:`heads`'s statics). Columns ``[lo, lo + rope)`` of every head
+    take rotary positions (``rope`` 0: none); ``pad`` zero columns follow
+    the product's own, where :func:`heads`'s ``beside`` goes."""
+    dtype: Any
+    eps: float = 0.0
+    lo: int = 0
+    rope: int = 0
+    theta: float = 1.0
+    yarn: Optional[Yarn] = None
+    sections: Optional[Tuple[int, ...]] = None
+    pad: int = 0
+
+
+def _turning(how: Heads, s: int, hd: int, positions):
+    """(``C``, ``S``) [S, hd] float32 ([B, S, hd] under ``positions``):
+    :func:`rotary`'s cos on the
+    columns that turn and 1 on the others; ``-sin`` on the first half of
+    them, ``sin`` on the second and 0 on the others. ``y * C +
+    partner(y) * S`` is the turn, element for element what :func:`rotary`
+    computes."""
+    cos, sin = rotary_tables(s, how.rope, how.theta, how.yarn, positions,
+                             how.sections)
+    lead = cos.shape[:-1]
+    one = jnp.ones(lead + (how.lo,), jnp.float32)
+    rest = jnp.ones(lead + (hd - how.lo - how.rope,), jnp.float32)
+    c = jnp.concatenate([one, cos, cos, rest], -1)
+    sg = jnp.concatenate([0 * one, -sin, sin, 0 * rest], -1)
+    return c, sg
+
+
+def _turn_of(how: Heads) -> head_turns.Turn:
+    return head_turns.Turn(how.lo, how.rope, how.eps, how.dtype)
+
+
+def _pass_scope(gain):
+    """The pass's own scope in a reading by scope: a normed part's (its
+    positions with it) files where the q/k norm alone did, a turned part's
+    under ``mv.lm.attn.turn``; the kernels of either then stand apart from
+    the flash kernels' row (``<scope>:kernel``)."""
+    return jax.named_scope("mv.lm.attn.turn" if gain is None
+                           else "mv.lm.attn.qknorm")
+
+
+def _heads_fwd(x, w, gain, beside, positions, how: Heads):
+    dt, f32 = how.dtype, jnp.float32
+    s, hd = x.shape[1], w.shape[2]
+    xc, wc = x.astype(dt), w.astype(dt)
+    if how.pad:
+        wc = jnp.pad(wc, ((0, 0), (0, 0), (0, how.pad)))
+    # [B, S, K] x [K, H, hd] -> [B, H, S, hd]: heads before positions
+    y = jax.lax.dot_general(xc, wc, (((2,), (0,)), ((), ())),
+                            preferred_element_type=f32).transpose(0, 2, 1, 3)
+    kept = None
+    if gain is not None or how.rope:
+        if how.pad:
+            raise ValueError("a part with columns beside it takes no norm "
+                             "and no positions of its own")
+        tables = _turning(how, s, hd, positions) if how.rope else (None, None)
+        kept = (y if gain is not None else None,) + tables
+        with _pass_scope(gain):
+            z = head_turns.forward(y, *tables, gain, _turn_of(how))
+    else:
+        if beside is not None:
+            y = y + jnp.pad(beside.astype(f32),
+                            ((0, 0), (0, 0), (hd, 0)))[:, None]
+        z = y.astype(dt)
+    return z, (xc, wc, kept, gain, positions, jnp.zeros((0,), x.dtype))
+
+
+def _heads_bwd(how: Heads, res, g):
+    xc, wc, kept, gain, positions, like = res
+    f32 = jnp.float32
+    hd = g.shape[3] - how.pad
+    dgain = dbeside = None
+    if how.pad:
+        # the shared part's: a sum over the heads of its columns
+        dbeside = jnp.sum(g[..., hd:].astype(f32), 1)
+    if kept is not None:
+        with _pass_scope(gain):
+            g, dgain = head_turns.backward(g, *kept, gain, _turn_of(how))
+    dx = jax.lax.dot_general(g, wc, (((1, 3), (1, 2)), ((), ())),
+                             preferred_element_type=f32).astype(like.dtype)
+    dw = jax.lax.dot_general(xc, g, (((0, 1), (0, 2)), ((), ())),
+                             preferred_element_type=f32)
+    return (dx, dw[..., :hd] if how.pad else dw, dgain, dbeside,
+            None if positions is None else jnp.zeros_like(positions))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _heads(x, w, gain, beside, positions, how: Heads):
+    return _heads_fwd(x, w, gain, beside, positions, how)[0]
+
+
+_heads.defvjp(_heads_fwd, _heads_bwd)
+
+
+def heads(x, w, how: Heads, gain=None, beside=None, positions=None):
+    """One operand of the attention core from one projection: ``x`` [B, S,
+    K] times ``w`` [K, H, hd] (float32, a view or a column block of a
+    table) -> [B, H, S, hd + pad] in ``how.dtype``, written once, the head
+    axis before the positions as the core reads it. Operands of the
+    product in ``how.dtype``, its sum float32; on that sum, in float32 and
+    in this order: an RMSNorm over a head under ``gain`` [hd] (where one
+    is given), rotary positions on the columns ``how`` names (:class:`Heads`;
+    ``positions`` [A, B, S] under ``how.sections``), ``beside`` [B, S, pad]
+    added into the ``pad`` columns of every head (MLA's shared rotary key);
+    then ONE rounding.
+
+    One differentiation rule: the core's cotangent, [B, H, S, hd + pad] in
+    ``how.dtype`` as the flash kernels write it, goes into the two
+    products of the backward pass as it lies, through the turn's and the
+    norm's transposes in float32 where the part has them and through
+    nothing where it has none."""
+    return _heads(x, w, gain, beside, positions, how)
+
+
+def _out_fwd(o, x, wgate, wo, dtype):
+    f32 = jnp.float32
+    sig = xc = wgc = None
+    wc = wo.astype(dtype)
+    oc = o
+    if wgate is not None:
+        with jax.named_scope("mv.lm.attn.gate"):
+            xc, wgc = x.astype(dtype), wgate.astype(dtype)
+            sig = jax.nn.sigmoid(jax.lax.dot_general(
+                xc, wgc, (((2,), (0,)), ((), ())),
+                preferred_element_type=f32).transpose(0, 2, 1, 3))
+            oc = (o * sig).astype(dtype)
+    y = jax.lax.dot_general(oc, wc, (((1, 3), (0, 1)), ((), ())),
+                            preferred_element_type=f32)
+    return y, (o, oc, wc, sig, xc, wgc,
+               None if x is None else jnp.zeros((0,), x.dtype))
+
+
+def _out_bwd(dtype, res, g):
+    o, oc, wc, sig, xc, wgc, like = res
+    f32 = jnp.float32
+    gc = g.astype(dtype)
+    do = jax.lax.dot_general(gc, wc, (((2,), (2,)), ((), ())),
+                             preferred_element_type=f32
+                             ).transpose(0, 2, 1, 3)       # [B, H, S, dv]
+    dwo = jax.lax.dot_general(oc, gc, (((0, 2), (0, 1)), ((), ())),
+                              preferred_element_type=f32)
+    if sig is None:
+        return do.astype(o.dtype), None, None, dwo
+    with jax.named_scope("mv.lm.attn.gate"):
+        dt_ = (do * o * (sig * (1 - sig))).astype(dtype)
+        dx = jax.lax.dot_general(dt_, wgc, (((1, 3), (1, 2)), ((), ())),
+                                 preferred_element_type=f32
+                                 ).astype(like.dtype)
+        dwg = jax.lax.dot_general(xc, dt_, (((0, 1), (0, 2)), ((), ())),
+                                  preferred_element_type=f32)
+        return (do * sig).astype(o.dtype), dx, dwg, dwo
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _out(o, x, wgate, wo, dtype):
+    return _out_fwd(o, x, wgate, wo, dtype)[0]
+
+
+_out.defvjp(_out_fwd, _out_bwd)
+
+
+def out_of_heads(o, wo, dtype, x=None, wgate=None):
+    """The way out of the core, :func:`heads`'s mirror: ``o`` [B, H, S, dv]
+    as the core writes it times ``wo`` [H, dv, D] (float32), contracted
+    over heads and columns -> [B, S, D] float32, no transposed copy of
+    ``o`` between; under a gate (``wgate`` [K, H, dv], ``x`` [B, S, K])
+    ``o`` is first multiplied by ``sigmoid(x wgate)``, float32, and
+    rounded once."""
+    return _out(o, x, wgate, wo, dtype)
+
+
+def mla_heads_of(u, p, cfg: MLAMoEConfig):
+    """The core's operands of :func:`mla` from the normed input ``u`` [B,
+    S, D]: q, k [B, H, S, nope + rope] and v [B, H, S, dv] in the compute
+    dtype, each written once (:func:`heads`). q: ONE product, its rope
+    columns turned on the way; k: the nope columns' product with the
+    shared rotary key beside it in every head; v: its own product."""
+    h, dt = cfg.n_heads, cfg.compute_dtype
+    nope, rope, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    mm = functools.partial(matmul, dtype=dt)
+    c_q = rms_norm(mm(u, p["wdq"], False, out_dtype=jnp.float32),
+                   p["q_norm"], cfg.eps)
+    down = mm(u, p["wdkv"], True, out_dtype=jnp.float32)
+    c_kv = rms_norm(down[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.eps)
+    k_r = rotary(down[..., cfg.kv_lora_rank:], cfg.rope_theta, cfg.yarn)
+    wukv = p["wukv"].reshape(-1, h, nope + dv)
+    q = heads(c_q, p["wuq"].reshape(-1, h, nope + rope),
+              Heads(dt, lo=nope, rope=rope, theta=cfg.rope_theta,
+                    yarn=cfg.yarn))
+    k = heads(c_kv, wukv[..., :nope], Heads(dt, pad=rope), beside=k_r)
+    return q, k, heads(c_kv, wukv[..., nope:], Heads(dt))
+
+
 def mla(u, p, cfg: MLAMoEConfig):
     """Latent attention on the normed input ``u`` [B, S, D] -> [B, S, D]
     float32. Queries and keys are heads of ``qk_nope_dim + qk_rope_dim``,
@@ -658,35 +908,16 @@ def mla(u, p, cfg: MLAMoEConfig):
     the rotary frequencies are ``cfg.yarn``'s where it has one, and the
     scores are multiplied by ``cfg.softmax_scale`` where it has one (by
     ``1 / sqrt(nope + rope)`` where not)."""
-    b, s, _ = u.shape
-    h, dt = cfg.n_heads, cfg.compute_dtype
-    nope, rope, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    yarn, scale = cfg.yarn, cfg.softmax_scale
-    mm = functools.partial(matmul, dtype=dt)
+    s, scale = u.shape[1], cfg.softmax_scale
     with jax.named_scope("mv.lm.attn"):
-        c_q = rms_norm(mm(u, p["wdq"], False, out_dtype=jnp.float32),
-                       p["q_norm"], cfg.eps)
-        q = mm(c_q, p["wuq"], False, out_dtype=jnp.float32)
-        q = q.reshape(b, s, h, nope + rope)
-        down = mm(u, p["wdkv"], True, out_dtype=jnp.float32)
-        c_kv = rms_norm(down[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.eps)
-        k_r = rotary(down[..., cfg.kv_lora_rank:], cfg.rope_theta, yarn)
-        kv = mm(c_kv, p["wukv"], False, out_dtype=dt)
-        kv = kv.reshape(b, s, h, nope + dv)
-        q = jnp.concatenate(
-            [q[..., :nope], rotary(q[..., nope:], cfg.rope_theta, yarn)], -1)
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(
-                k_r[:, :, None, :], (b, s, h, rope)).astype(dt)], -1)
-        heads = lambda t: t.astype(dt).transpose(0, 2, 1, 3)
-        q, k, v = heads(q), heads(k), heads(kv[..., nope:])
+        q, k, v = mla_heads_of(u, p, cfg)
         if attn_core(cfg) == "flash":
             o = flash_attention(q, k, v, True, *attn_blocks(cfg, s),
                                 scale=scale)
         else:
             o = _xla_attention(q, k, v, scale=scale)
-        o = o.transpose(0, 2, 1, 3).reshape(b, s, h * dv)
-        return mm(o, p["wo"], False, out_dtype=jnp.float32)
+        return out_of_heads(o, p["wo"].reshape(cfg.n_heads, cfg.v_head_dim,
+                                               -1), cfg.compute_dtype)
 
 
 def gated_mlp(u, wg, wu, wd, cfg):
@@ -1474,7 +1705,8 @@ class Trainer:
                 if self.steps == 1:     # one program, one shape
                     positions = int(tokens.shape[1])
                     self._attn = dict(
-                        attn_grid(self.cfg, positions),
+                        attn_grid(self.cfg, positions,
+                                  int(tokens.shape[0])),
                         **mixer_grid(self.cfg, positions),
                         **loss_grid(self.cfg, count),
                         **kept_grid(self.cfg, *tokens.shape),

@@ -246,6 +246,44 @@ def test_taps_stage():
     assert abs(least(8192) - 1.311) < 1e-3 and abs(least(6144) - 0.983) < 1e-3
 
 
+def test_heads_stage():
+    """ONE attention layer of each form at a small size: ms, compile
+    seconds and the compiled layer's bytes outside its kernels for
+    ``mla_moe.heads`` and for the formulation before it, the two forms'
+    gradients held to each other; the byte reading itself on a program of
+    known traffic."""
+    from multiverso_tpu.models import gqa_moe, mla_moe
+    cells = (("mla", mla_moe.MLAMoEConfig(), "latent", 2, 32),
+             ("gqa", gqa_moe.GQAMoEConfig(qk_norm=True, attn_gate=True),
+              "window", 1, 64))
+    facts = chip_smoke.stage_heads(cells, repeats=1, attn="xla")
+    for name, *_ in cells:
+        for tag in ("parent", "new"):
+            assert facts[f"{name}_{tag}_ms"] > 0
+            assert facts[f"{name}_{tag}_compile_s"] >= 0
+            assert facts[f"{name}_{tag}_kernels"] == 0      # XLA's core
+            assert facts[f"{name}_{tag}_outside_gb"] >= 0
+        assert facts[f"{name}_rel_err"] <= chip_smoke.HEADS_TOL
+    assert "heads" in dict(chip_smoke.STAGES)
+    assert [c[0] for c in chip_smoke._heads_cells()][:6] == [
+        "glm", "mellum_full", "mellum_window", "trinity", "keye", "xing"]
+    # result and operands of every top-level instruction, kernels apart
+    text = """HloModule m
+ENTRY %main (a: f32[1024,256]) -> bf16[1024,256] {
+  %a = f32[1024,256]{1,0} parameter(0)
+  %fusion.1 = f32[1024,256]{1,0} fusion(f32[1024,256]{1,0} %a), kind=kLoop
+  %call = bf16[1024,256]{1,0} custom-call(%fusion.1), custom_call_target="tpu_custom_call"
+  %bitcast.2 = bf16[256,1024]{1,0} bitcast(%call)
+  ROOT %copy.3 = bf16[1024,256]{1,0} copy(%call)
+}
+"""
+    assert chip_smoke.bytes_outside_kernels(text) == {
+        "all_gb": round((2 * 4 + 4 + 2 + 2 + 2) * 1024 * 256 / 1e9, 3),
+        "kernels_gb": round(6 * 1024 * 256 / 1e9, 3),
+        "outside_gb": round(12 * 1024 * 256 / 1e9, 3), "kernels": 1}
+    assert chip_smoke.bytes_outside_kernels("no entry here") == {}
+
+
 def test_select_stage():
     """The indexer and the selection at a small size: ms of the three
     products, of the whole selection and of a chunk by the counting search

@@ -850,9 +850,17 @@ def test_published_sizes_give_the_configurations_parameter_count():
 # operand as a product of its own from the table's column window, and
 # ``test_the_in_projections_two_products_are_the_parents_one`` holds the
 # step to the parent's one product, as it does ``qwen3_next``'s.
-PARENT = {"mla": "1176c1bf3112ad40", "gqa": "f3ca378f315449a4",
-          "afmoe": "06ebccb9b2fd87b1", "nemotron_h": "cb7c9d0aae93395e",
-          "lfm2": "d8be808c75a5fa2e", "keye": "6d0bfafb9008b220"}
+# ALL SIX are THIS tree's text since PR 63 (the entries before it were
+# 1176c1bf3112ad40, f3ca378f315449a4, 06ebccb9b2fd87b1, cb7c9d0aae93395e,
+# d8be808c75a5fa2e, 6d0bfafb9008b220): every model's attention reaches its
+# core through ``mla_moe.heads`` and leaves it through ``out_of_heads``
+# (one product a part with the head axis before the positions, one
+# differentiation rule), so every step's text moved ON PURPOSE;
+# ``tests/test_head_turns.py`` holds the new lines to the parent's
+# formulation bit for bit and gradient for gradient.
+PARENT = {"mla": "969aac75f944a61b", "gqa": "dd417f7a7132a1fc",
+          "afmoe": "9a893072e43c71af", "nemotron_h": "443da8f4cc17c58b",
+          "lfm2": "5bc1d38d9eeb95f5", "keye": "94022a33919fd895"}
 MODELS = {"mla": mla_moe.MLAMoEConfig, "gqa": gqa_moe.GQAMoEConfig,
           "afmoe": afmoe.AFMoEConfig, "nemotron_h": nemotron_h.NemotronHConfig,
           "lfm2": lfm2_moe.LFM2MoEConfig, "keye": keye_moe.KeyeMoEConfig}

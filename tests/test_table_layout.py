@@ -1279,3 +1279,53 @@ def test_sigmoid_expert_layer_at_row_tiles_of_128_compiles_for_a_v5e(one_chip):
         shape(4096, 3584), shape(64, 3584), shape(8, 3584, 1024),
         shape(8, 3584, 1024), shape(8, 1024, 3584)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 9
+
+
+@pytest.mark.parametrize("cell,passes,share", [
+    ("glm", 1, 2 / 3), ("mellum_full", 2, 2 / 3), ("trinity", 2, 2 / 3),
+    ("xing", 1, 0.85), ("lfm2", 2, 2 / 3)])
+def test_the_pass_between_projection_and_core_compiles_for_a_v5e(
+        one_chip, monkeypatch, cell, passes, share):
+    """ONE attention layer with every gradient as five cells run it
+    (``chip_smoke._heads_cells``), ``ops/head_turns.py``'s kernels where
+    the chip would run them: Mosaic takes a tile at heads of 256 (the last
+    64 turning), 128, 192 and 64; each turned part is one kernel forward
+    and one backward beside the three flash kernels; no float32 array of
+    the core's shape crosses a copy, and what the program moves outside
+    its kernels is under two thirds of what the formulation before PR 63
+    moved (the compiler's own bytes: ``chip_smoke.bytes_outside_kernels``;
+    under 85% at ``xing4``'s 4,096 positions, where the latents' products
+    over a width of 3,584 weigh more than the heads)."""
+    import chip_smoke
+    from multiverso_tpu.models import mla_moe
+    from multiverso_tpu.ops import attention_kernels, head_turns
+
+    monkeypatch.setattr(attention_kernels, "_resolve_interpret",
+                        lambda interpret: False)
+    monkeypatch.setattr(head_turns, "kernel_tile", head_turns.tile_of)
+    _, cfg, kind, b, s = next(c for c in chip_smoke._heads_cells()
+                              if c[0] == cell)
+    cfg = cfg._replace(attn="flash")
+    f32 = lambda *sh: jax.ShapeDtypeStruct(sh, jnp.float32, sharding=one_chip)
+    p = {n: f32(*sh) for n, sh in cfg.attn_shapes(kind).items()}
+    core = lambda q, k, v: attention_kernels.flash_attention(
+        q, k, v, True, *mla_moe.attn_blocks(cfg, s), None,
+        cfg.window if kind == "window" else None,
+        scale=getattr(cfg, "softmax_scale", None))
+    parent = ((lambda u, p: chip_smoke.parent_mla(u, p, cfg, core))
+              if kind == "latent" else
+              (lambda u, p: chip_smoke.parent_gqa(u, p, cfg, kind, core)))
+    moved = {}
+    for form, layer in (("parent", parent),
+                        ("new", lambda u, p: cfg.attend(u, p, kind))):
+        text = jax.jit(jax.grad(lambda u, p: jnp.sum(layer(u, p) ** 2),
+                                (0, 1))).lower(f32(b, s, cfg.dim),
+                                               p).compile().as_text()
+        moved[form] = chip_smoke.bytes_outside_kernels(text)
+        if form == "new":
+            assert not re.search(
+                r"%%?copy(\.\d+)? = f32\[%d,\d+,%d,\d+\]" % (b, s), text)
+    assert moved["parent"]["kernels"] == 3
+    assert moved["new"]["kernels"] == 3 + 2 * passes
+    assert moved["new"]["outside_gb"] < share * moved["parent"]["outside_gb"], \
+        moved

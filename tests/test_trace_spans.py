@@ -907,8 +907,22 @@ def test_lm_step_carries_the_flash_kernels_grid_counts():
         assert {k: e["args"][k] for k in grid} == grid
     assert "attn_positions_needed_window" not in steps[0]["args"]
     from tools import dump_metrics
-    assert dump_metrics._attention_lines(steps)[1:] == [
-        "    causal  768  528  1.455"]
+    lines = dump_metrics._attention_lines(steps)
+    assert lines[1] == "    causal  768  528  1.455"
+    # what lies between the projections and the core (PR 63): every latent
+    # layer's q takes the pass, float32 in and the compute dtype (float32
+    # here) out, forward and made again, the core's cotangent in and the
+    # product's out backward; off the chip the pass is the plain form
+    heads = {"heads_layers": len(cfg.layers()), "heads_kernel_layers": 0,
+             "heads_turned_bytes": len(cfg.layers()) * tokens.size
+             * cfg.n_heads * (cfg.qk_nope_dim + cfg.qk_rope_dim)
+             * (2 * (4 + 4) + 2 * 4)}
+    assert {k: steps[0]["args"][k] for k in heads} == heads
+    assert lines[2:] == [
+        f"  heads into the core: {heads['heads_layers']} layer(s), the "
+        "pass's kernels in 0 (0: the plain form), "
+        f"{heads['heads_turned_bytes'] / 1e6:.0f} MB a step through the "
+        "pass"]
     assert dump_metrics._attention_lines([{"name": "lm.step", "args": {}}]
                                          ) == []
     assert "routed_rows" not in steps[0]["args"]     # nothing read back yet

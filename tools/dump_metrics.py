@@ -735,7 +735,8 @@ def _attention_lines(events: List[Dict]) -> List[str]:
     """What one flash kernel call computes beside what it needs, from the
     static counts on ``lm.step`` (``models/mla_moe.attn_grid``): positions
     a (batch x head) of a forward call, causal and, where a layer is of
-    the window kind, banded."""
+    the window kind, banded; and what lies between the projections and the
+    core (``mla_moe.heads_grid``)."""
     args = next((e["args"] for e in events if e.get("name") == "lm.step"
                  and "attn_positions_needed" in e.get("args", {})), None)
     if args is None:
@@ -748,6 +749,12 @@ def _attention_lines(events: List[Dict]) -> List[str]:
             computed = args["attn_positions_computed" + tail]
             out.append(f"    {walk:6s}  {computed}  {needed}  "
                        f"{computed / needed:.3f}")
+    if "heads_layers" in args:      # between projection and core (PR 63)
+        out.append(
+            f"  heads into the core: {args['heads_layers']} layer(s), the "
+            f"pass's kernels in {args['heads_kernel_layers']} (0: the plain "
+            f"form), {args['heads_turned_bytes'] / 1e6:.0f} MB a step "
+            "through the pass")
     return out
 
 
