@@ -25,6 +25,10 @@ rule, a falling finite loss, ``jnp.take``,
   delta   the chunked gated delta rule at the delta cell's shapes: ms
           forward and backward for each way of making its triangular
           inverse, and its error against the recurrence
+  loop    the looped cell's stack alone: eight published-width blocks run
+          four times with one set of weights as a scan, the same passes
+          unrolled and one pass alone: trace, lowering and compile
+          seconds, ms a call, and the error against the reference
 
 and a closing ``memory`` check that every device ended up holding bytes.
 
@@ -1853,6 +1857,153 @@ def stage_hc(positions: int = 4096, dim: int = 3584, streams: int = 4,
     return facts
 
 
+LOOP_BF16_TOL = 0.2     # bfloat16 operands through 32 block applications
+
+
+def stage_loop(positions: int = 4096, dim: int = 2048, heads: int = 16,
+               head_dim: int = 128, ffn: int = 5632, layers: int = 8,
+               passes: int = 4, repeats: int = 3,
+               check_positions: Optional[int] = None,
+               dtype: Any = None) -> Dict[str, Any]:
+    """``ouro-train-4k``'s looped stack alone (``models/mla_moe._passes``
+    under ``models/ouro.OuroConfig``): ``layers`` published-width blocks run
+    ``passes`` times with ONE set of weights, the stream normed after every
+    pass, forward and with every gradient (the input's, every table's) at
+    ``positions`` positions. Three programs side by side, the weights an
+    ARGUMENT of each: the loop as the step has it (``scan``: one
+    ``lax.scan`` over the passes), the same passes unrolled in Python
+    (``unrolled``: ``passes x layers`` blocks in the program text) and one
+    pass alone (``pass``: ``layers`` unrolled blocks): of each the seconds
+    one trace and one lowering take (what every warm set-up pays:
+    ``*_trace_s``, ``*_lower_s``), the compile seconds and the ms a call by
+    this process's clock around ``repeats`` calls it waits for. The scan
+    and the unrolled passes must give the same exits and gradients (to the
+    operands' rounding: XLA fuses the two differently); and the scan's are
+    held to ``benchmark/reference/ouro.exit_states`` in float32, a block at
+    a time, on the first ``check_positions`` (all by default): max|err| over
+    max|reference| of the exits, the input's gradient and each kind of
+    table's. The exits are unit-RMS vectors, so their error reads as the
+    operands' rounding carried through the block runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.reference import ouro as ref
+    from multiverso_tpu.models import mla_moe, ouro
+
+    cfg = ouro.OuroConfig(
+        vocab=8, dim=dim, n_heads=heads, n_kv_heads=heads, head_dim=head_dim,
+        n_layers=layers, passes=passes, dense_ffn=ffn,
+        **({} if dtype is None else {"compute_dtype": dtype}))
+    small = 0.02 / (2 * passes * layers) ** 0.5
+    p = {n: v for n, v in mla_moe.init(
+        cfg, SEED, 0.02, {"wo": small, "wd": small}).items()
+        if n.startswith("L") or n == "final_norm"}
+    keys = jax.random.split(jax.random.key(SEED), 2)
+    x = jax.random.normal(keys[0], (1, positions, dim))
+    weight = jax.random.normal(keys[1], (passes,) + x.shape)
+
+    def unrolled(x, p, n_passes=passes):
+        exits = []
+        for _ in range(n_passes):
+            for layer in cfg.layers():
+                x, _ = mla_moe._run_block(
+                    x, mla_moe._sub(p, layer.name), layer, None, cfg)
+            x = mla_moe.rms_norm(x, p["final_norm"], cfg.eps)
+            exits.append(x)
+        return jnp.stack(exits)
+
+    def with_grads(stack, n_passes=passes):
+        """((the weighted sum, the exits), (the input's gradient, every
+        table's))."""
+        def fn(x, p, weight):       # the weight is an operand
+            def value(x, p):
+                exits = stack(x, p)
+                return jnp.sum(weight[:n_passes] * exits), exits
+            return jax.value_and_grad(value, (0, 1), has_aux=True)(x, p)
+        return fn
+
+    programs = {
+        "scan": with_grads(lambda x, p: mla_moe._passes(x, p, cfg)),
+        "unrolled": with_grads(unrolled),
+        "pass": with_grads(lambda x, p: unrolled(x, p, 1), 1)}
+    facts: Dict[str, Any] = {"block_runs": passes * layers}
+    results = {}
+    # what a process traces once (the kernels' bodies, the rules of the
+    # products) is traced before the clocks start, whichever program is
+    # timed first
+    jax.jit(lambda *of: programs["pass"](*of)).trace(x, p, weight)
+    for name, fn in programs.items():
+        t0 = time.perf_counter()
+        traced = jax.jit(fn).trace(x, p, weight)
+        t1 = time.perf_counter()
+        lowered = traced.lower()
+        t2 = time.perf_counter()
+        compiled = lowered.compile()
+        t3 = time.perf_counter()
+        results[name] = jax.block_until_ready(compiled(x, p, weight))
+        t4 = time.perf_counter()
+        for _ in range(repeats):
+            res = compiled(x, p, weight)
+        jax.block_until_ready(res)
+        facts.update({
+            f"{name}_trace_s": round(t1 - t0, 2),
+            f"{name}_lower_s": round(t2 - t1, 2),
+            f"{name}_compile_s": round(t3 - t2, 2),
+            f"{name}_ms": round((time.perf_counter() - t4) / repeats * 1e3,
+                                3),
+            f"{name}_temp_gb": round(
+                compiled.memory_analysis().temp_size_in_bytes / 1e9, 3)})
+        del compiled, res
+    _say("loop.timed", **facts)     # a failed check keeps the readings
+
+    def rel(got, want):
+        return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+    def by_kind(got, want):
+        """The exits, the input's gradient, and each kind of table's
+        worst."""
+        kinds: Dict[str, float] = {}
+        for n in want[1][1]:
+            kind = n.split(".")[-1]
+            kinds[kind] = max(kinds.get(kind, 0.0),
+                              rel(got[1][1][n], want[1][1][n]))
+        return {"exits": rel(got[0][1], want[0][1]),
+                "dx": rel(got[1][0], want[1][0]), **kinds}
+
+    facts["scan_against_unrolled"] = {
+        k: float(f"{v:.3g}") for k, v in by_kind(
+            results["scan"], results["unrolled"]).items()}
+    del results["unrolled"], results["pass"]
+    n = min(check_positions or positions, positions)
+    c = dict(hidden_size=dim, num_attention_heads=heads,
+             num_key_value_heads=heads, head_dim=head_dim,
+             intermediate_size=ffn, num_hidden_layers=layers,
+             total_ut_steps=passes, rms_norm_eps=cfg.eps,
+             rope_theta=cfg.rope_theta)
+
+    def plain(x, p, weight):
+        def value(x, p):
+            exits = jnp.stack(ref.exit_states(
+                dict(p, embed=x[0]), jnp.arange(n), c, lean=True))[:, None]
+            return jnp.sum(weight * exits), exits
+        return jax.value_and_grad(value, (0, 1), has_aux=True)(x, p)
+
+    few = (x[:, :n], p, weight[:, :, :n])
+    with jax.default_matmul_precision("highest"):
+        want = jax.block_until_ready(jax.jit(plain)(*few))
+    got = (results["scan"] if n == positions
+           else jax.jit(programs["scan"])(*few))
+    errs = by_kind(got, want)
+    facts["rel_err"] = {k: float(f"{v:.3g}") for k, v in errs.items()}
+    tol = LOOP_BF16_TOL if cfg.compute_dtype == jnp.bfloat16 else HC_F32_TOL
+    if not max(errs.values()) <= tol:               # a NaN fails too
+        raise AssertionError(f"loop: {facts['rel_err']} > {tol}")
+    if not max(facts["scan_against_unrolled"].values()) <= tol:
+        raise AssertionError(
+            f"loop: scan against unrolled {facts['scan_against_unrolled']}")
+    return facts
+
+
 def _inverse_by(how: str):
     """The ways of making ``T = (I + M)^-1`` that stage ``delta`` reads:
     ``ops/delta_rule.unit_lower_inverse`` (``solve``: XLA's triangular
@@ -2449,7 +2600,7 @@ STAGES: Tuple[Tuple[str, Callable[[], Dict[str, Any]]], ...] = (
     ("lm", stage_lm), ("flash", lambda: stage_flash(selected={})),
     ("ssd", stage_ssd),
     ("conv", stage_conv), ("taps", stage_taps), ("heads", stage_heads),
-    ("delta", stage_delta), ("hc", stage_hc),
+    ("delta", stage_delta), ("hc", stage_hc), ("loop", stage_loop),
     ("select", stage_select), ("target", stage_target),
     ("memory", stage_memory))
 

@@ -34,7 +34,12 @@ parameters (``cfg.attn_shapes(kind)``), the attention itself
 (``cfg.post_norms``: before each branch alone, or on its way out as
 well, as ``models/afmoe.py`` has them), what the embedding is
 multiplied by (``cfg.embed_scale``) and, where it has such a field,
-whether the embedding's table is the head as well (``cfg.tied_head``);
+whether the embedding's table is the head as well (``cfg.tied_head``),
+how many residual streams a position has (``cfg.streams``) and how often
+the stack of blocks runs with the same tables (``cfg.passes``:
+:func:`_passes`, one loop of the program, an exit gate after every pass
+and :func:`_exit_loss`'s loss over the exits; ``models/ouro.py``, which
+has no expert layer at all: the step then has no router, bias or counts);
 :func:`block`, :func:`matmul`,
 :func:`rms_norm`, :func:`rotary`, the chunked loss, the tables, the step
 and :class:`Trainer` below are shared by every such configuration.
@@ -237,6 +242,12 @@ def streams_of(cfg) -> int:
     return int(getattr(cfg, "streams", 1))
 
 
+def passes_of(cfg) -> int:
+    """How often the stack of blocks runs, with the same tables: 1, or the
+    configuration's ``passes`` (``models/ouro.py``: a looped decoder)."""
+    return int(getattr(cfg, "passes", 1))
+
+
 def _stream_shapes(cfg, branch: str) -> Dict[str, Tuple[int, ...]]:
     """A sublayer's hyper-connection under several streams (none under
     one): ``hc_phi``, a row an output as the router's are, [n + n + n^2,
@@ -257,11 +268,15 @@ def param_shapes(cfg) -> Dict[str, Tuple[int, ...]]:
     token id, and under a tied head (``cfg.tied_head``) there is no
     ``head``: the embedding's table is both. Under several residual
     streams every sublayer has its hyper-connection's three tables
-    (``<layer>.attn.hc_*``, ``<layer>.ffn.hc_*``: :func:`_stream_shapes`)."""
+    (``<layer>.attn.hc_*``, ``<layer>.ffn.hc_*``: :func:`_stream_shapes`).
+    A stack run several times (:func:`passes_of`) has the exit gate's
+    ``exit.w`` [dim] and ``exit.b`` [1], and every layer's tables ONCE."""
     d = cfg.dim
     out = {"embed": (cfg.vocab, d), "final_norm": (d,)}
     if not tied_head(cfg):
         out["head"] = (cfg.vocab, d)
+    if passes_of(cfg) > 1:
+        out.update({"exit.w": (d,), "exit.b": (1,)})
     for layer in cfg.layers():
         # an attention kind's shapes bring the block's two input norms
         block = (dict(cfg.attn_shapes(layer.attn)) if layer.attn
@@ -352,7 +367,8 @@ def init(cfg, seed: int = 0, scale: float = 0.02,
 def init_bias(cfg) -> jax.Array:
     """The routers' selection biases, a row a layer of
     :func:`expert_layers`: not trained, moved by ``moe.bias_update``."""
-    return jnp.zeros((len(expert_layers(cfg)), cfg.n_experts), jnp.float32)
+    return jnp.zeros((len(expert_layers(cfg)), getattr(cfg, "n_experts", 0)),
+                     jnp.float32)
 
 
 def table_shape(shape: Tuple[int, ...]) -> Tuple[int, ...]:
@@ -630,7 +646,8 @@ def attn_grid(cfg, s: int, sequences: int = 1) -> Dict[str, Any]:
            "kv_group": cfg.kv_group,
            "block_norms": (2 if cfg.post_norms else 1) * branches,
            "embed_scale": float(cfg.embed_scale),
-           **heads_grid(cfg, kinds, sequences, s)}
+           # a layer run ``passes`` times makes its operands as often
+           **heads_grid(cfg, kinds * passes_of(cfg), sequences, s)}
     if "window" in kinds:
         band = causal_pairs(s, *blocks, cfg.window, sub)
         out.update(attn_pairs_live_window=band["live"],
@@ -1272,7 +1289,8 @@ def _run_block(x, p, layer: Layer, bias, cfg, remat: bool = True):
     return (jax.checkpoint(run, policy=keep) if remat else run)(x, p)
 
 
-def _ce_chunks(h, head, targets, weights, cfg, grads: bool):
+def _ce_chunks(h, head, targets, weights, cfg, grads: bool,
+               each_too: bool = False):
     """The scan under :func:`_chunked_ce`: ``loss_chunk`` positions at a
     time, one logits product a chunk (operands in ``cfg.compute_dtype``,
     float32 sums, float32 log-sum-exp). Returns the loss and, with
@@ -1281,7 +1299,8 @@ def _ce_chunks(h, head, targets, weights, cfg, grads: bool):
     the compute dtype, times the head (the gradient to ``h``, a chunk of
     the stacked result) and times the chunk's ``h`` (the gradient to
     ``head``, summed in a float32 carry), and each position's unweighted
-    loss (the gradient to ``weights``)."""
+    loss (the gradient to ``weights``); without ``grads`` and with
+    ``each_too``, each position's unweighted loss alone."""
     n, d = h.shape
     chunk = min(cfg.loss_chunk, n)
     if n % chunk:
@@ -1297,7 +1316,7 @@ def _ce_chunks(h, head, targets, weights, cfg, grads: bool):
         each = lse - jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
         total = total + jnp.sum(wc * each)
         if not grads:
-            return (total, dw), None
+            return (total, dw), (each if each_too else None)
         hit = jnp.arange(logits.shape[1])[None, :] == tc[:, None]
         dl = (wc[:, None] * (jnp.exp(logits - lse[:, None]) - hit)).astype(dt)
         dw = dw + _dot(dl, hc, ((0,), (0,)), jnp.float32)
@@ -1309,7 +1328,7 @@ def _ce_chunks(h, head, targets, weights, cfg, grads: bool):
     (total, dw), out = jax.lax.scan(
         body, (jnp.zeros((), jnp.float32), dw), xs)
     if not grads:
-        return total, None
+        return total, (out.reshape(n) if each_too else None)
     return total, (out[0].reshape(n, d), dw, out[1].reshape(n))
 
 
@@ -1338,14 +1357,38 @@ def _chunked_ce_bwd(cfg, grads, g):
 _chunked_ce.defvjp(_chunked_ce_fwd, _chunked_ce_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _chunked_ce_each(h, head, targets, weights, cfg):
+    """:func:`_chunked_ce` beside each position's UNWEIGHTED loss [n],
+    which the scan has anyway (it is the gradient to ``weights``): what a
+    loss whose weights are trained reports of its parts (:func:`_exit_loss`:
+    each pass's mean loss). No gradient flows through the second result."""
+    return _ce_chunks(h, head, targets, weights, cfg, grads=False,
+                      each_too=True)
+
+
+def _chunked_ce_each_fwd(h, head, targets, weights, cfg):
+    total, grads = _ce_chunks(h, head, targets, weights, cfg, grads=True)
+    return (total, grads[2]), grads
+
+
+_chunked_ce_each.defvjp(
+    _chunked_ce_each_fwd,
+    lambda cfg, grads, g: _chunked_ce_bwd(cfg, grads, g[0]))
+
+
 def loss_grid(cfg, tokens: int) -> Dict[str, Any]:
     """What the chunked losses of a training step over ``tokens`` positions
     do, as ``lm.step`` spans carry it: the products of positions x
     vocabulary (three a loss: the logits, and the gradients to the hidden
-    state and to the head) and the chunks a loss walks."""
-    losses = 1 + any(layer.name == "mtp" for layer in cfg.layers())
+    state and to the head) and the chunks a loss walks. A stack run
+    several times has a loss an exit (:func:`passes_of`), walked as one:
+    its products and its chunks count ``passes`` times."""
+    passes = passes_of(cfg)
+    losses = (1 + any(layer.name == "mtp" for layer in cfg.layers())) * passes
+    walked = passes * tokens            # the positions of one walk
     return {"head_products": 3 * losses,
-            "loss_chunks": tokens // min(cfg.loss_chunk, tokens)}
+            "loss_chunks": walked // min(cfg.loss_chunk, walked)}
 
 
 def kept_grid(cfg, batch: int, positions: int) -> Dict[str, int]:
@@ -1362,7 +1405,7 @@ def kept_grid(cfg, batch: int, positions: int) -> Dict[str, int]:
     streams it is what weighs: :func:`stream_grid` says those bytes."""
     out = {"kept_names": len(kept_names(cfg)), "expert_products_kept": 0,
            "kept_bytes": 0}
-    if getattr(cfg, "keeps_products", True):
+    if getattr(cfg, "keeps_products", True) and expert_layers(cfg):
         tokens = batch * positions
         here = held(cfg, tokens)
         rows, layers = moe.buffer_length(here, tokens), len(expert_layers(cfg))
@@ -1401,6 +1444,18 @@ def stream_grid(cfg, batch: int, positions: int) -> Dict[str, int]:
                                        cfg.dim)}
 
 
+def loop_grid(cfg) -> Dict[str, int]:
+    """What a stack run several times adds to the ``lm.step`` span (nothing
+    where it runs once): ``loop_passes``, ``loop_layers`` (the blocks the
+    program holds) and ``loop_block_runs`` (those a step runs, each of
+    whose inputs the rematerialised step keeps)."""
+    passes, layers = passes_of(cfg), len(cfg.layers())
+    if passes == 1:
+        return {}
+    return {"loop_passes": passes, "loop_layers": layers,
+            "loop_block_runs": passes * layers}
+
+
 def _embed(params, tokens, cfg):
     x = jnp.take(params["embed"], tokens, axis=0)
     return x if cfg.embed_scale == 1.0 else x * cfg.embed_scale
@@ -1426,6 +1481,79 @@ def _reduce(x, cfg):
         return jnp.sum(x, 2)
 
 
+def _passes(x, params, cfg):
+    """The stack run ``cfg.passes`` times on the embedding ``x`` [B, S, D]
+    with the SAME tables, the stream normed by ``final_norm`` after every
+    pass: ``exits`` [passes, B, S, D], each pass's normed stream, which is
+    that pass's exit state and the next pass's input. ONE loop in the
+    program (a ``lax.scan`` over the passes whose body holds the blocks,
+    each under its own ``jax.checkpoint``: the program text holds
+    ``layers`` blocks, not ``passes x layers``, and its lowering costs what
+    one pass's does), so a layer's table takes one gradient a step, the
+    sum over its uses, which the scan's transpose adds up. What the
+    backward pass keeps is every block's input of every pass."""
+    layers = cfg.layers()
+    if (expert_layers(cfg) or streams_of(cfg) > 1
+            or not all(layer.attn and layer.ffn for layer in layers)):
+        raise ValueError("a stack run several times holds two-branch dense "
+                         "blocks on one stream and no prediction module")
+
+    def one_pass(z, _):
+        for layer in layers:
+            z, _ = _run_block(z, _sub(params, layer.name), layer, None, cfg)
+        with jax.named_scope("mv.lm.norm.final"):
+            z = rms_norm(z, params["final_norm"], cfg.eps)
+        return z, z
+
+    with jax.named_scope("mv.lm.loop"):
+        return jax.lax.scan(one_pass, x, None, length=passes_of(cfg))[1]
+
+
+def exit_distribution(exits, w, b):
+    """The exit gate on every pass's state ``exits`` [T, ..., D] but the
+    last: ``lambda_t = sigmoid(x_t . w + b)``; ``p_1 = lambda_1``, ``p_t =
+    lambda_t prod_{j<t} (1 - lambda_j)`` and the last pass takes what is
+    left, ``p_T = prod_{j<T} (1 - lambda_j)``. Returns (p [T, ...], its
+    entropy ``-sum_t p_t ln p_t`` [...]), float32, made from the logs of
+    ``lambda`` and ``1 - lambda`` so that no factor rounds to 0."""
+    z = jnp.sum(exits[:-1].astype(jnp.float32) * w, -1) + b
+    go, stay = jax.nn.log_sigmoid(z), jax.nn.log_sigmoid(-z)
+    stayed = jnp.cumsum(stay, 0)            # ln prod_{j<=t} (1 - lambda_j)
+    log_p = jnp.concatenate([go + stayed - stay, stayed[-1:]], 0)
+    p = jnp.exp(log_p)
+    return p, -jnp.sum(p * log_p, 0)
+
+
+def _exit_loss(params, exits, tokens, cfg):
+    """The loss of a stack run several times: (loss, what the step hands
+    back of its exits). ``loss = (1/n) sum_i [sum_t p_t(i) l_t(i) -
+    exit_coef H(p(i))]`` over the ``n`` positions that have a target:
+    ``l_t`` the cross-entropy of pass ``t``'s state through the shared
+    head, ``p`` the exit distribution (:func:`exit_distribution`), which is
+    TRAINED through the loss: the chunked loss hands ``l_t(i)`` back as the
+    gradient to its weights ``p_t(i) / n``, and the gate and the streams
+    take it from there. The ``T`` exits go through the chunked loss as one
+    walk of ``T x B x S`` positions (one float32 sum for the head's
+    gradient, not one an exit). Handed back: ``loss`` [T], each pass's mean
+    ``l_t``; ``p_mean`` [T]; ``entropy``, the mean ``H(p)``; ``p`` [T, B, S]."""
+    t, b, s, d = exits.shape
+    has_target = (jnp.arange(s) < s - 1).astype(jnp.float32) / (b * (s - 1))
+    with jax.named_scope("mv.lm.loop.exit"):
+        p, entropy = exit_distribution(exits, params["exit.w"],
+                                       params["exit.b"])
+    head = params["embed" if tied_head(cfg) else "head"]
+    with jax.named_scope("mv.lm.head"):
+        total, each = _chunked_ce_each(
+            exits.reshape(t * b * s, d), head,
+            jnp.tile(jnp.roll(tokens, -1, axis=1).reshape(-1), t),
+            (p * has_target).reshape(-1), cfg)
+    with jax.named_scope("mv.lm.loop.exit"):
+        mean = lambda v: jnp.sum(v * has_target, (-2, -1))
+        back = {"loss": mean(each.reshape(t, b, s)), "p_mean": mean(p),
+                "entropy": mean(entropy), "p": p}
+        return total - cfg.exit_coef * back["entropy"], back
+
+
 def _trunk(params, bias, tokens, cfg, still: bool = False):
     """Embedding and every layer but the prediction module: (x, [each
     expert layer's aux]) and, under several streams, a third thing: the
@@ -1433,9 +1561,16 @@ def _trunk(params, bias, tokens, cfg, still: bool = False):
     is held fixed (no gradient flows from a layer into the one before it,
     so nothing is rematerialised either). Under several streams the
     embedding is copied to each before the first block and the last
-    block's are summed."""
+    block's are summed. A stack run several times (:func:`passes_of`)
+    hands back every pass's normed stream in ``x``'s place (:func:`_passes`:
+    [passes, B, S, D])."""
     with jax.named_scope("mv.lm.embed"):
         x = _embed(params, tokens, cfg)
+    if passes_of(cfg) > 1:
+        if still:
+            raise ValueError("a stack run several times has no router to "
+                             "balance")
+        return _passes(x, params, cfg), []
     x = _expand(x, cfg)
     rows = {name: row for row, name in enumerate(expert_layers(cfg))}
     aux, errors = [], []
@@ -1455,6 +1590,13 @@ def _trunk(params, bias, tokens, cfg, still: bool = False):
         (functools.reduce(jnp.maximum, errors),) if errors else ())
 
 
+def _no_experts():
+    """(counts, overflow, balance) of a step without an expert layer: no
+    rows."""
+    return (jnp.zeros((0, 0), jnp.int32), jnp.zeros((0,), jnp.int32),
+            jnp.zeros((0,), jnp.float32))
+
+
 def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
             tokens: jax.Array, cfg):
     """tokens [B, S] -> (loss, (counts [layers, E], overflow [layers],
@@ -1472,9 +1614,16 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
     the configuration has such a coefficient. The module runs on all S
     positions, so that its attention has the main model's shape; the
     last, which has no next token, takes the sequence's first in its
-    place and has no target."""
+    place and has no target.
+
+    A stack run several times (:func:`passes_of`) has :func:`_exit_loss`'s
+    loss and hands its exits back as the last part; a configuration
+    without an expert layer hands back counts of no rows."""
     b, s = tokens.shape
     x, aux, *errors = _trunk(params, bias, tokens, cfg)
+    if passes_of(cfg) > 1:
+        loss, exits = _exit_loss(params, x, tokens, cfg)
+        return loss, (*_no_experts(), exits)
     position = jnp.arange(s)[None, :]
     nxt = jnp.roll(tokens, -1, axis=1)
     # a loss's normaliser lies in its weights: the chunked loss's cotangent
@@ -1510,7 +1659,8 @@ def loss_fn(params: Dict[str, jax.Array], bias: jax.Array,
                 weights(position < s - 2, cfg.mtp_weight / (b * (s - 2))),
                 cfg)
         loss = main + module
-    counts, overflow, balance, *terms = (jnp.stack(a) for a in zip(*aux))
+    counts, overflow, balance, *terms = (
+        (jnp.stack(a) for a in zip(*aux)) if aux else _no_experts())
     if cfg.balance_coef:
         loss = loss + cfg.balance_coef * jnp.sum(balance)
     if terms:
@@ -1552,10 +1702,14 @@ def make_train_step(cfg, tables: Dict[str, Any],
     the load-balance term as it stands in the loss (0 without one); where
     the mixers hand terms to the loss, their part of it as well
     (``index_loss``), a sixth result; under several residual streams the
-    step's largest :func:`res_error` (``hc_res_error``), the last."""
+    step's largest :func:`res_error` (``hc_res_error``) after those; and of
+    a stack run several times what :func:`_exit_loss` hands back of its
+    exits, the last. A configuration without an expert layer has counts of
+    no rows, no bias and no rule for one."""
     shapes = param_shapes(cfg)
     opt = opt or AddOption(learning_rate=1e-4)
-    biased = cfg.route == "sigmoid"     # the route that selects under a bias
+    # the route that selects under a bias
+    biased = bool(expert_layers(cfg)) and cfg.route == "sigmoid"
 
     def step(states, bias, tokens):
         # mv.lm.params / mv.lm.update: the names the tables' side of a
@@ -1565,6 +1719,7 @@ def make_train_step(cfg, tables: Dict[str, Any],
         (loss, (counts, overflow, balance, *terms)), grads = (
             jax.value_and_grad(loss_fn, has_aux=True)(
                 params, bias, tokens, cfg))
+        exits = [terms.pop()] if passes_of(cfg) > 1 else []
         errors = [terms.pop()] if streams_of(cfg) > 1 else []
         new = {}
         with jax.named_scope("mv.lm.update"):
@@ -1576,7 +1731,8 @@ def make_train_step(cfg, tables: Dict[str, Any],
             bias = moe.bias_update(bias, counts, cfg.bias_speed)
         return (new, bias, loss, _with_overflow(counts, overflow),
                 cfg.balance_coef * jnp.sum(balance),
-                *(cfg.index_coef * jnp.sum(t) for t in terms), *errors)
+                *(cfg.index_coef * jnp.sum(t) for t in terms), *errors,
+                *exits)
 
     return step
 
@@ -1645,8 +1801,11 @@ def routing_counts(counts: np.ndarray, cfg) -> Dict[str, Any]:
     gather or scatter over the sorted buffers walks, a layer's even load
     and whole chunks past it, summed over the layers:
     ``moe.rows_walked``) beside ``buffer_rows`` (the layers' buffers
-    whole): 1.0 of it would say that the passes never stop short."""
+    whole): 1.0 of it would say that the passes never stop short. Nothing
+    where the step has no expert layer (counts of no rows)."""
     counts = np.asarray(counts)
+    if not len(counts):
+        return {}
     c = counts[:, :cfg.n_experts]
     lo = cfg.expert_offset
     mine = c[:, lo:lo + cfg.experts_held]
@@ -1666,6 +1825,19 @@ def routing_counts(counts: np.ndarray, cfg) -> Dict[str, Any]:
             "buffer_rows": len(c) * rows}
 
 
+def exit_facts(exits: Dict[str, np.ndarray]) -> Dict[str, Any]:
+    """What a step's exits say on its ``lm.step`` span: ``exit_loss`` (each
+    pass's mean loss), ``exit_p`` (the mean exit distribution),
+    ``exit_entropy`` (the mean ``H(p)``: ``ln passes`` where every pass is
+    as likely, 0 where the gate has collapsed onto one) and
+    ``exit_expected_pass`` (``sum_t t p_t``, passes counted from 1)."""
+    p = np.asarray(exits["p_mean"], np.float64)
+    return {"exit_loss": [float(x) for x in exits["loss"]],
+            "exit_p": [float(x) for x in p],
+            "exit_entropy": float(exits["entropy"]),
+            "exit_expected_pass": float(np.sum(p * (1 + np.arange(len(p)))))}
+
+
 class Trainer:
     """The host's side of training through the tables: holds the states
     between steps (one donated program a step, no table copied) and
@@ -1683,13 +1855,16 @@ class Trainer:
                              donate_argnums=(0, 1))
         self.states = {n: t.program_state() for n, t in tables.items()}
         self.steps = 0
-        # (loss, counts, balance[, index_loss][, hc_res_error]) of a step
-        # not read back yet
+        # (loss, counts, balance[, index_loss][, hc_res_error][, exits]) of
+        # a step not read back yet
         self._ahead = None
         # attn_grid and mixer_grid of the first step
         self._attn: Dict[str, Any] = {}
         # the largest stream-mix error of the steps read back so far
         self.hc_res_error = 0.0
+        # of a stack run several times: what the last step read back
+        # handed back of its exits (:func:`_exit_loss`)
+        self.exits: Optional[Dict[str, np.ndarray]] = None
         # closes a step's ``lm.step.device`` span when the device is done
         # with it; idle unless a capture or ``trace_ids`` can read it
         self._watcher = _trace.DeviceWatcher()
@@ -1710,7 +1885,8 @@ class Trainer:
                         **mixer_grid(self.cfg, positions),
                         **loss_grid(self.cfg, count),
                         **kept_grid(self.cfg, *tokens.shape),
-                        **stream_grid(self.cfg, *tokens.shape))
+                        **stream_grid(self.cfg, *tokens.shape),
+                        **loop_grid(self.cfg))
                 sp.set(tokens=count)
                 t0_ns = time.time_ns()
                 self.states, self.bias, *back = self._step(
@@ -1732,6 +1908,9 @@ class Trainer:
             with _trace.span("lm.step.wait"):
                 # one read-back a step: it waits for the whole program
                 loss, counts, balance, *terms = jax.device_get(due)
+            if passes_of(self.cfg) > 1:
+                self.exits = terms.pop()
+                sp.set(**exit_facts(self.exits))
             if streams_of(self.cfg) > 1:
                 error = float(terms.pop())
                 self.hc_res_error = max(self.hc_res_error, error)

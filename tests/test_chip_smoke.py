@@ -439,3 +439,32 @@ def test_hc_stage():
     # the flash stage's call at two head sizes is among the cells' calls
     assert ("xing4.causal", (1, 32, 4096, 192), 32, (1024, 1024), None,
             128) in chip_smoke.FLASH_CALLS
+
+
+def test_loop_stage():
+    """The looped stack alone at a small size, float32 operands: the scan
+    over the passes, the same passes unrolled and one pass alone, each with
+    its trace, lowering and compile seconds and its ms a call; the scan
+    held to the unrolled passes and to the reference a block at a time."""
+    import jax.numpy as jnp
+
+    facts = chip_smoke.stage_loop(positions=64, dim=64, heads=4, head_dim=16,
+                                  ffn=96, layers=2, passes=3, repeats=1,
+                                  dtype=jnp.float32)
+    assert facts["block_runs"] == 6
+    for name in ("scan", "unrolled", "pass"):
+        for what in ("trace_s", "lower_s", "compile_s", "ms", "temp_gb"):
+            assert facts[f"{name}_{what}"] >= 0
+        assert facts[f"{name}_ms"] > 0
+    kinds = {"exits", "dx", "final_norm", "attn_norm", "attn_post_norm",
+             "ffn_norm", "ffn_post_norm", "wq", "wk", "wv", "wo", "wg", "wu",
+             "wd"}
+    assert set(facts["rel_err"]) == set(facts["scan_against_unrolled"]) == kinds
+    assert max(facts["rel_err"].values()) <= chip_smoke.HC_F32_TOL
+    assert max(facts["scan_against_unrolled"].values()) <= 1e-5
+    assert "loop" in dict(chip_smoke.STAGES)
+    # a part of the positions can be held to the reference alone
+    part = chip_smoke.stage_loop(positions=64, dim=64, heads=4, head_dim=16,
+                                 ffn=96, layers=1, passes=2, repeats=1,
+                                 check_positions=32, dtype=jnp.float32)
+    assert max(part["rel_err"].values()) <= chip_smoke.HC_F32_TOL

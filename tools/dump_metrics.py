@@ -34,7 +34,11 @@ backward), what the programs' maps could not place, each scope's longest
 instructions and what each program reserves of the device (a
 hyper-connected decoder's stream maps read there as ``mv.lm.hc.expand``,
 ``.norm``, ``.project``, ``.sinkhorn``, ``.pre``, ``.post`` and
-``.reduce``). With ``--steps-from`` the times are a step: the window's total over the
+``.reduce``; a stack run several times reads as ``mv.lm.loop`` (what a pass
+runs outside its blocks' own scopes), ``mv.lm.norm.final`` (the norm after
+every pass) and ``mv.lm.loop.exit`` (the exit gate, its distribution and
+entropy), a row a scope, beside ``mv.lm.head``, entered once for all the
+exits). With ``--steps-from`` the times are a step: the window's total over the
 number of device spans of that name recorded under the profiler.
 
 Both commands also accept the cluster aggregator's time series
@@ -51,6 +55,7 @@ stall %, compiles) are ``tools/mvprof.py``'s to print.
 from __future__ import annotations
 
 import json
+import math
 import os
 import statistics
 import sys
@@ -623,7 +628,8 @@ def format_timeline(events: List[Dict]) -> str:
             for r in runs[:5]]
     return "\n".join(out + _table_write_lines(events)
                      + _attention_lines(events) + _mixer_lines(events)
-                     + _stream_lines(events) + _buffer_lines(events))
+                     + _stream_lines(events) + _loop_lines(events)
+                     + _buffer_lines(events))
 
 
 def window_ops(trace_dir: str) -> Dict[str, List[tuple]]:
@@ -836,6 +842,36 @@ def _stream_lines(events: List[Dict]) -> List[str]:
             f"{a['hc_bwd_stream_bytes'] / 1e6:.0f} MB of streams read and "
             "written a step")
     return lines
+
+
+def _loop_lines(events: List[Dict]) -> List[str]:
+    """Of a stack run several times (``models/mla_moe.loop_grid`` and
+    ``exit_facts``; the device scopes ``mv.lm.loop*``): the block runs a
+    step and, as means over the steps that say them, each exit's loss, the
+    exit distribution with its expected pass, and its entropy beside the
+    most it can be."""
+    steps = [e["args"] for e in events if e.get("name") == "lm.step"
+             and "loop_passes" in e.get("args", {})]
+    if not steps:
+        return []
+    a = steps[0]
+    line = (f"  looped stack: {a['loop_layers']} layers x {a['loop_passes']} "
+            f"passes = {a['loop_block_runs']} block runs a step (one loop "
+            "in the program)")
+    said = [s for s in steps if "exit_p" in s]
+    if said:
+        mean = lambda key: [statistics.fmean(col) for col in zip(
+            *(s[key] for s in said))]
+        numbers = lambda xs: " ".join(f"{x:.3f}" for x in xs)
+        line += (
+            f"; over {len(said)} steps the exits' mean loss "
+            f"{numbers(mean('exit_loss'))}, exit distribution "
+            f"{numbers(mean('exit_p'))} (expected pass "
+            f"{statistics.fmean(s['exit_expected_pass'] for s in said):.2f})"
+            f", entropy "
+            f"{statistics.fmean(s['exit_entropy'] for s in said):.3f} of ln "
+            f"{a['loop_passes']} = {math.log(a['loop_passes']):.3f}")
+    return [line]
 
 
 def _buffer_lines(events: List[Dict]) -> List[str]:
