@@ -17,7 +17,8 @@ rule, a falling finite loss, ``jnp.take``,
   flash   the language-model cells' flash kernel calls, a crossed pair as
           one tile against its sub-tiles: ms a call and compile seconds
   ssd     the chunked state-space scan at the hybrid cell's shapes: ms
-          forward and backward, and its error against the recurrence
+          forward and backward of its two kernels, of the plain form and
+          of the mixer's call, and every head against the recurrence
   taps    the mixers' short causal convolution at the delta and hybrid
           cells' shapes: ms forward and backward of the two kernels and of
           the plain form, and both against the convolution a position at
@@ -1160,19 +1161,26 @@ def stage_ssd(positions: int = 16384, heads: int = 64, head_dim: int = 64,
               groups: int = 8, state: int = 128, chunk: int = 128,
               repeats: int = 5) -> Dict[str, Any]:
     """``ops/ssd.ssd_chunked`` as ``nemotron3n-train-16k`` calls it (one
-    sequence of ``positions``, bfloat16 operands): the seconds the compiler
-    took and the ms a call, forward and forward with every gradient, by
-    this process's clock around ``repeats`` calls it waits for; and ONE
-    group's output and gradients against the recurrence itself, a position
-    at a time in float32 (max|err| over max|reference|, as ``stage_lm``).
-    Inputs are drawn as the mixer makes them: ``x``, ``B``, ``C`` a silu
-    of unit normals, step sizes and ``A`` by the Mamba-2 rule."""
+    sequence of ``positions``, bfloat16 operands): which form runs on this
+    device (``form``: the two Pallas kernels or the plain ``jax.numpy``
+    one), the seconds the compiler took and the ms a call, forward and
+    forward with every gradient, by this process's clock around
+    ``repeats`` calls it waits for: of that form (``fwd_ms``,
+    ``fwd_bwd_ms``), of the plain form beside it (``plain_*``) and of
+    the call as ``nemotron_h.mamba2`` makes it, on the mixer's one ``[x | B |
+    C]`` array (``whole``) with the skip (``mixer_*``); every large operand
+    is an ARGUMENT of the jitted function. Then EVERY head's output and
+    gradients against the recurrence itself, a position at a time in
+    float32, a group at a time (max|err| over max|reference|, as
+    ``stage_lm``). Inputs are drawn as the mixer makes them: ``x``, ``B``,
+    ``C`` a silu of unit normals, step sizes and ``A`` by the Mamba-2
+    rule."""
     import jax
     import jax.numpy as jnp
 
-    from multiverso_tpu.ops.ssd import ssd_chunked
+    from multiverso_tpu.ops import ssd
 
-    k = jax.random.split(jax.random.key(SEED), 7)
+    k = jax.random.split(jax.random.key(SEED), 8)
     per = heads // groups
     x = jax.nn.silu(jax.random.normal(k[0], (1, positions, heads, head_dim)))
     b, c = (jax.nn.silu(jax.random.normal(key, (1, positions, groups, state)))
@@ -1183,23 +1191,49 @@ def stage_ssd(positions: int = 16384, heads: int = 64, head_dim: int = 64,
                          + step + jnp.log(-jnp.expm1(-step)))
     a = -jax.random.uniform(k[5], (heads,), minval=1.0, maxval=16.0)
     weight = jax.random.normal(k[6], x.shape)
+    skip = jax.random.normal(k[7], (heads,))
     args = (x, dt, a, b, c)
-    scan = lambda *t: ssd_chunked(*t, chunk)
-    both = lambda *t: jax.value_and_grad(
-        lambda *u: jnp.sum(weight[:, :, :u[0].shape[2]] * scan(*u)),
-        range(5))(*t)
-    facts: Dict[str, Any] = {}
-    for name, fn in (("fwd", scan), ("fwd_bwd", both)):
-        t0 = time.perf_counter()
-        compiled = jax.jit(fn).lower(*args).compile()
-        facts[f"{name}_compile_s"] = round(time.perf_counter() - t0, 2)
-        jax.block_until_ready(compiled(*args))
-        t0 = time.perf_counter()
-        for _ in range(repeats):
-            res = compiled(*args)
-        jax.block_until_ready(res)
-        facts[f"{name}_ms"] = round(
-            (time.perf_counter() - t0) / repeats * 1e3, 3)
+    kernels = ssd.kernel_heads(positions, heads, head_dim, groups, state,
+                               chunk) is not None
+    scan = lambda *t: ssd.ssd_chunked(*t, chunk)
+    plain = lambda *t: ssd.plain(*t, chunk)
+
+    def mixer(xbc, dt, a, skip):
+        x, b, c = jnp.split(xbc, (heads * head_dim,
+                                  heads * head_dim + groups * state), -1)
+        # [positions, heads x head_dim] as the mixer's norm takes it: on
+        # the chip a head a tile row is another layout, a pass of its own
+        return ssd.ssd_chunked(
+            x.reshape(1, positions, heads, head_dim), dt, a,
+            b.reshape(1, positions, groups, state),
+            c.reshape(1, positions, groups, state), chunk, skip=skip,
+            whole=xbc).reshape(1, positions, heads * head_dim)
+
+    def both(fn, n):       # the weighted sum's value and every gradient
+        return lambda w, *t: jax.value_and_grad(
+            lambda *u: jnp.sum(w * fn(*u)), range(n))(*t)
+
+    xbc = jnp.concatenate([t.reshape(1, positions, -1) for t in (x, b, c)],
+                          -1)
+    facts: Dict[str, Any] = {"form": "kernels" if kernels else "plain"}
+    timed = [("", scan, args, weight),
+             ("mixer_", mixer, (xbc, dt, a, skip),
+              weight.reshape(1, positions, -1))] + (
+        [("plain_", plain, args, weight)] if kernels else [])
+    for tag, fn, operands, over in timed:
+        for name, call, ops in ((f"{tag}fwd", fn, operands),
+                                (f"{tag}fwd_bwd", both(fn, len(operands)),
+                                 (over,) + operands)):
+            t0 = time.perf_counter()
+            compiled = jax.jit(call).lower(*ops).compile()
+            facts[f"{name}_compile_s"] = round(time.perf_counter() - t0, 2)
+            jax.block_until_ready(compiled(*ops))
+            t0 = time.perf_counter()
+            for _ in range(repeats):
+                res = compiled(*ops)
+            jax.block_until_ready(res)
+            facts[f"{name}_ms"] = round(
+                (time.perf_counter() - t0) / repeats * 1e3, 3)
 
     def recurrence(x, dt, a, b, c):
         b, c = (jnp.repeat(t[0], per, axis=1) for t in (b, c))
@@ -1217,20 +1251,28 @@ def stage_ssd(positions: int = 16384, heads: int = 64, head_dim: int = 64,
         _, y = jax.lax.scan(stretch, jnp.zeros((per, head_dim, state)), each)
         return y.reshape((1, positions, per, head_dim))
 
-    one_group = (x[:, :, :per], dt[:, :, :per], a[:per], b[:, :, :1],
-                 c[:, :, :1])
-    want = jax.jit(lambda *t: jax.value_and_grad(
-        lambda *u: jnp.sum(weight[:, :, :per] * recurrence(*u)),
-        range(5))(*t))(*one_group)
-    got = jax.jit(both)(*one_group)
-    y_err = float(jnp.max(jnp.abs(scan(*one_group) - recurrence(*one_group)))
-                  / jnp.max(jnp.abs(recurrence(*one_group))))
-    errs = [y_err] + [float(jnp.max(jnp.abs(g - w)) / jnp.max(jnp.abs(w)))
-                      for g, w in zip(got[1], want[1])]
+    def group(at, t):      # group ``at``'s part of an operand or gradient
+        heads_of = slice(at * per, (at + 1) * per)
+        return (t[heads_of] if t.ndim == 1 else t[:, :, at:at + 1]
+                if t.shape[2] == groups else t[:, :, heads_of])
+
+    got_y, got = jax.jit(lambda w, *t: (scan(*t), both(scan, 5)(w, *t)[1]))(
+        weight, *args)
+    want_of = jax.jit(lambda w, *t: (recurrence(*t),
+                                     both(recurrence, 5)(w, *t)[1]))
+    errs = np.zeros(6)
+    for at in range(groups):
+        want_y, want = want_of(group(at, weight),
+                               *(group(at, t) for t in args))
+        pairs = [(group(at, got_y), want_y)] + [
+            (group(at, g), w) for g, w in zip(got, want)]
+        errs = np.maximum(errs, [float(jnp.max(jnp.abs(g - w))
+                                       / jnp.max(jnp.abs(w)))
+                                 for g, w in pairs])
     if not max(errs) <= ATTN_BF16_TOL:      # a NaN fails too
         raise AssertionError(f"ssd: relative error {errs} (y, dx, ddt, da, "
                              f"db, dc) > {ATTN_BF16_TOL}")
-    facts["rel_err_y_dx_ddt_da_db_dc"] = [round(e, 5) for e in errs]
+    facts["rel_err_y_dx_ddt_da_db_dc"] = [round(float(e), 5) for e in errs]
     return facts
 
 

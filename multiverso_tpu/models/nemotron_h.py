@@ -37,8 +37,8 @@ import jax.numpy as jnp
 
 from multiverso_tpu.models import gqa_moe, mla_moe
 from multiverso_tpu.models.mla_moe import Layer
+from multiverso_tpu.ops import ssd
 from multiverso_tpu.ops.short_conv import causal_taps, step_counts
-from multiverso_tpu.ops.ssd import ssd_chunked
 
 # a block's kind by its letter in ``hybrid_override_pattern``
 KINDS = {"M": ("ssm", None), "E": (None, "shared+experts"),
@@ -94,12 +94,16 @@ class NemotronHConfig(NamedTuple):
 
     def ssm_grid(self, s: int) -> Dict[str, int]:
         """The scan's static counts over ``s`` positions, as ``lm.step``
-        spans carry them, and the mixers' short convolution's
+        spans carry them (``ssd.step_counts`` says whether its kernels
+        run), and the mixers' short convolution's
         (``short_conv.step_counts``)."""
         mixers = sum(layer.attn == "ssm" for layer in self.layers())
         return {"ssm_chunks": s // self.chunk, "ssm_heads": self.ssm_heads,
                 "ssm_state": self.ssm_state,
-                **step_counts(mixers, s, mamba2_shapes(self)["conv_w"][1])}
+                **step_counts(mixers, s, mamba2_shapes(self)["conv_w"][1]),
+                **ssd.step_counts(mixers, s, self.ssm_heads,
+                                  self.ssm_head_dim, self.ssm_groups,
+                                  self.ssm_state, self.chunk)}
 
     @property
     def first_values(self) -> Dict[str, Any]:
@@ -174,10 +178,12 @@ def mamba2(u, p, cfg):
         x = x.reshape(b, s, h, hd)
         dt = jax.nn.softplus(dt + p["dt_bias"])
         with jax.named_scope("mv.lm.ssm.scan"):
-            y = ssd_chunked(x, dt, -jnp.exp(p["a_log"]),
-                            bm.reshape(b, s, g, n), cm.reshape(b, s, g, n),
-                            cfg.chunk, dt_)
-            y = y + p["skip"][:, None] * x
+            # the scan's kernels read x, B and C out of ``xbc`` as it lies
+            # and add the skip; the plain form takes the windows
+            y = ssd.ssd_chunked(
+                x, dt, -jnp.exp(p["a_log"]), bm.reshape(b, s, g, n),
+                cm.reshape(b, s, g, n), cfg.chunk, dt_, skip=p["skip"],
+                whole=xbc)
         with jax.named_scope("mv.lm.ssm.norm"):
             y = (y.reshape(b, s, inner) * jax.nn.silu(z)).reshape(
                 b, s, g, inner // g)

@@ -5,6 +5,7 @@ the assertions a TPU alone can meet (compiled kernel, device-backed
 shards); the comparisons against NumPy and reference_attention all run.
 """
 
+import functools
 import json
 import os
 import sys
@@ -150,16 +151,32 @@ def test_flash_stage():
             assert max(call[f"{tag}_vs_whole"]) <= 1e-2     # bfloat16 results
 
 
-def test_ssd_stage():
-    """The chunked scan at a small size: ms and compile seconds forward
-    and with every gradient, one group held to the recurrence."""
+def test_ssd_stage(monkeypatch):
+    """The chunked scan at a small size: which form ran, ms and compile
+    seconds forward and with every gradient of it and of the mixer's call,
+    every head held to the recurrence; where the kernels run, the plain
+    form's ms beside theirs."""
     facts = chip_smoke.stage_ssd(positions=256, heads=8, head_dim=8,
                                  groups=2, state=16, chunk=32, repeats=1)
-    for name in ("fwd", "fwd_bwd"):
+    assert facts["form"] == "plain"             # the CPU's
+    for name in ("fwd", "fwd_bwd", "mixer_fwd", "mixer_fwd_bwd"):
         assert facts[f"{name}_ms"] > 0 and facts[f"{name}_compile_s"] >= 0
+    assert not any(name.startswith("plain_") for name in facts)
     errs = facts["rel_err_y_dx_ddt_da_db_dc"]
     assert len(errs) == 6 and max(errs) <= chip_smoke.ATTN_BF16_TOL
     assert "ssd" in dict(chip_smoke.STAGES)
+    # the kernels (interpreted here) beside the plain form
+    from multiverso_tpu.ops import ssd
+    kernels = functools.partial(ssd.ssd_chunked, kernel=True, interpret=True)
+    monkeypatch.setattr(ssd, "kernel_heads", lambda *shape: 8)
+    monkeypatch.setattr(ssd, "ssd_chunked", kernels)
+    facts = chip_smoke.stage_ssd(positions=256, heads=8, head_dim=64,
+                                 groups=1, state=128, chunk=128, repeats=1)
+    assert facts["form"] == "kernels"
+    for name in ("fwd", "fwd_bwd", "mixer_fwd", "mixer_fwd_bwd", "plain_fwd",
+                 "plain_fwd_bwd"):
+        assert facts[f"{name}_ms"] > 0 and facts[f"{name}_compile_s"] >= 0
+    assert max(facts["rel_err_y_dx_ddt_da_db_dc"]) <= chip_smoke.ATTN_BF16_TOL
 
 
 def test_delta_stage():

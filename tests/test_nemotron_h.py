@@ -372,6 +372,9 @@ def test_one_step_through_the_adam_tables_is_reference_gradient_plus_adam():
     assert args["block_kinds"] == "ssm,shared+experts,ssm,full,shared+experts"
     assert (args["expert_form"], args["ssm_layers"], args["ssm_chunks"],
             args["ssm_heads"], args["ssm_state"]) == ("relu2", 2, 4, 4, 16)
+    # the scan's kernels take no shape this small, and no CPU
+    assert (args["ssd_kernel_layers"], args["ssd_bytes"]) == (
+        0, 4 * 64 * (2 * 4 * 8 + 2 * 2 * 16 + 4))
     # the one causal core, with no positions and one norm a block
     assert (args["attn_kinds"], args["block_norms"], args["kv_group"]) == (
         "full", 1, 4)
@@ -400,3 +403,38 @@ def test_published_sizes_give_the_configurations_parameter_count():
     assert shapes["L0.win"] == (2688, 4096 + 6144 + 64)
     assert shapes["L1.eu"] == (8, 2688, 1856)
     assert shapes["L1.su"] == (2688, 3712)
+
+
+def test_the_step_counts_the_mixers_whose_scan_runs_the_kernels(monkeypatch):
+    """``lm.step``'s two static counts of the scan: ``ssd_kernel_layers``
+    (every mixer or none, by this device and the shapes:
+    ``ssd.kernel_heads``) and ``ssd_bytes`` (``x``, ``B``, ``C``, ``dt`` read
+    and ``y`` written once, float32, ONE mixer's forward pass)."""
+    from multiverso_tpu.ops import ssd
+    from tools import dump_metrics
+
+    cell = nemotron_h.NemotronHConfig(
+        pattern="MEMEM*EME", ssm_heads=64, ssm_head_dim=64, ssm_groups=8,
+        ssm_state=128, chunk=128)
+    want = 4 * 16384 * (2 * 64 * 64 + 2 * 8 * 128 + 64)
+    assert want == 675_282_944
+    grid = cell.ssm_grid(16384)
+    assert (grid["ssd_kernel_layers"], grid["ssd_bytes"]) == (0, want)
+
+    class _Chip:
+        platform = "tpu"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Chip()])
+    grid = mla_moe.mixer_grid(cell, 16384)
+    assert (grid["ssd_kernel_layers"], grid["ssd_bytes"]) == (4, want)
+    assert grid["conv_kernel_layers"] == 4
+    # a chunk that is no lane tile, positions that are no whole chunks and
+    # heads of 8 run the plain form on a chip too
+    assert cell._replace(chunk=64).ssm_grid(16384)["ssd_kernel_layers"] == 0
+    assert cell.ssm_grid(16384 + 64)["ssd_kernel_layers"] == 0
+    assert CFG.ssm_grid(64)["ssd_kernel_layers"] == 0
+    # the timeline's line for an operator
+    lines = dump_metrics._mixer_lines([{"name": "lm.step", "args": grid}])
+    assert lines[-1] == ("    state-space scan: the kernels in 4 mixer(s) "
+                         "(0: the plain form), 675 MB a mixer a forward "
+                         "pass at the least")

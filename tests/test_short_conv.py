@@ -227,7 +227,7 @@ def test_the_step_counts_the_layers_that_run_the_kernels(monkeypatch):
     from tools import dump_metrics
     lines = dump_metrics._mixer_lines([{"name": "lm.step",
                                         "args": grids()[1]}])
-    assert lines[-1] == ("    short convolution: the kernels in 4 mixer(s) "
+    assert lines[-2] == ("    short convolution: the kernels in 4 mixer(s) "
                          "(0: the plain form), 805 MB a mixer a pass at the "
                          "least")
     # a length of no whole tiles runs the plain form on the chip too

@@ -977,6 +977,61 @@ def test_short_convolution_mixer_compiles_for_a_v5e_at_published_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
 
 
+def test_state_space_scan_compiles_for_a_v5e_at_published_widths(
+        one_chip, monkeypatch):
+    """``nemotron_h.mamba2`` as ``nemotron3n-train-16k`` calls it (one
+    sequence of 16,384 positions, 64 heads of 64 in 8 groups, a state of
+    128), forward and backward: Mosaic compiles ``ops/ssd.py``'s two
+    kernels at a group's [128, 512] blocks, and they read ``x``, ``B`` and
+    ``C`` out of the convolution kernel's ONE result as it lies; no copy
+    and no transpose of ``x``, ``y`` or their gradients round the calls
+    (the plain form's group-major walk made four)."""
+    from multiverso_tpu.models import nemotron_h
+    from multiverso_tpu.ops import short_conv, ssd
+
+    # the process's devices are the CPU's: the rules would take the plain
+    # forms
+    monkeypatch.setattr(short_conv, "kernel_tiles",
+                        lambda s, c, dtype=None: (512, 512))
+    monkeypatch.setattr(ssd, "kernel_heads",
+                        lambda s, h, p, g, n, chunk: h // g)
+    cfg = nemotron_h.NemotronHConfig(
+        dim=2688, ssm_heads=64, ssm_head_dim=64, ssm_groups=8,
+        ssm_state=128, chunk=128)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {n: f32(*s) for n, s in cfg.attn_shapes("ssm").items()}
+    compiled = jax.jit(jax.grad(lambda u, p: cfg.attend(u, p, "ssm").sum(),
+                                argnums=(0, 1))).lower(
+        f32(1, 16384, 2688), p).compile()
+    text = compiled.as_text()
+    calls = {name: re.findall(
+        rf"%{name}[.\d]* = ([^\n]*?) custom-call\(([^)]*)\)", text)
+        for name in (ssd.FWD, ssd.BWD)}
+    assert len(calls[ssd.FWD]) == len(calls[ssd.BWD]) == 1
+    for name, ((results, operands),) in calls.items():
+        first = [o.strip() for o in operands.split(",")[:3]]
+        # [x | B | C] whole, three times: the convolution kernel's result
+        assert len(set(first)) == 1 and first[0].startswith(
+            f"%{short_conv.FWD}"), (name, first)
+    # y as the gated norm takes it and the chunk-start states, float32;
+    # [dx | dB | dC] ONE array, as the convolution's backward kernel takes
+    # it: its operand is the scan's own result
+    assert "f32[1,16384,4096]" in calls[ssd.FWD][0][0]
+    assert "f32[1,8,128,128,512]" in calls[ssd.FWD][0][0]
+    assert "f32[1,16384,6144]" in calls[ssd.BWD][0][0]
+    taps, = re.findall(rf"%{short_conv.BWD}[.\d]* = [^\n]*? custom-call\("
+                       r"([^)]*)\)", text)
+    made = taps.split(",")[2].strip()
+    assert re.search(rf"{re.escape(made)} = [^\n]*get-tuple-element\("
+                     rf"[^\n]*%{ssd.BWD}", text), made
+    assert not re.search(
+        r"= f32\[1,16384,(4096|6144)\]\S* (copy|transpose|concatenate)\(",
+        text)
+    # one mixer's backward pass, its 0.27 GB of states among it (3.3 GB;
+    # 3.1 with the plain form, which keeps no states but four copies)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.6 * (1 << 30)
+
+
 def _qwen3next():
     """``qwen3next-train-16k``'s configuration, kernels on."""
     import json

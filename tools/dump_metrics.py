@@ -776,7 +776,8 @@ def _mixer_lines(events: List[Dict]) -> List[str]:
     mixers (``mv.lm.delta*`` in the table above), their scan's counts and
     their part of a token's forward operations
     (``delta.mixer_flops_share``'s two counts); where a mixer has a short
-    causal convolution (``ops/short_conv``), how many run its kernels."""
+    causal convolution (``ops/short_conv``), how many run its kernels;
+    where a mixer is a state-space scan (``ops/ssd``), how many run its."""
     args = next((e["args"] for e in events if e.get("name") == "lm.step"
                  and "block_kinds" in e.get("args", {})), None)
     if args is None:
@@ -813,6 +814,12 @@ def _mixer_lines(events: List[Dict]) -> List[str]:
             f"    short convolution: the kernels in "
             f"{args['conv_kernel_layers']} mixer(s) (0: the plain form), "
             f"{args['conv_bytes'] / 1e6:.0f} MB a mixer a pass at the least")
+    if "ssd_kernel_layers" in args:
+        out.append(
+            f"    state-space scan: the kernels in "
+            f"{args['ssd_kernel_layers']} mixer(s) (0: the plain form), "
+            f"{args['ssd_bytes'] / 1e6:.0f} MB a mixer a forward pass at "
+            "the least")
     return out
 
 
