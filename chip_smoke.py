@@ -1159,11 +1159,29 @@ def stage_flash(calls: Tuple = FLASH_CALLS, subs: Tuple = (),
 
 def stage_ssd(positions: int = 16384, heads: int = 64, head_dim: int = 64,
               groups: int = 8, state: int = 128, chunk: int = 128,
-              repeats: int = 5) -> Dict[str, Any]:
-    """``ops/ssd.ssd_chunked`` as ``nemotron3n-train-16k`` calls it (one
-    sequence of ``positions``, bfloat16 operands): which form runs on this
+              repeats: int = 5, one_group=(8192, 256)) -> Dict[str, Any]:
+    """``ops/ssd.ssd_chunked`` as ``nemotron3n-train-16k`` calls it
+    (:func:`_ssd_shape` at 8 groups of 8 heads) and, under ``one_group``
+    (its positions and its chunk), as ``granite4h-train-8k`` does: ALL the
+    heads in ONE group, walked in blocks of heads, the configuration's
+    chunk of 256 walked as lane tiles; forward and with every gradient,
+    every head against the recurrence, both shapes."""
+    facts = _ssd_shape(positions, heads, head_dim, groups, state, chunk,
+                       repeats)
+    if one_group:
+        facts["one_group"] = _ssd_shape(one_group[0], heads, head_dim, 1,
+                                        state, one_group[1], repeats)
+    return facts
+
+
+def _ssd_shape(positions: int, heads: int, head_dim: int, groups: int,
+               state: int, chunk: int, repeats: int) -> Dict[str, Any]:
+    """``ops/ssd.ssd_chunked`` on one sequence of ``positions`` at the
+    given heads, groups, state and chunk, bfloat16 operands: which form
+    runs on this
     device (``form``: the two Pallas kernels or the plain ``jax.numpy``
-    one), the seconds the compiler took and the ms a call, forward and
+    one; ``head_blocks``: the blocks a group's heads are walked in), the
+    seconds the compiler took and the ms a call, forward and
     forward with every gradient, by this process's clock around
     ``repeats`` calls it waits for: of that form (``fwd_ms``,
     ``fwd_bwd_ms``), of the plain form beside it (``plain_*``) and of
@@ -1215,7 +1233,9 @@ def stage_ssd(positions: int = 16384, heads: int = 64, head_dim: int = 64,
 
     xbc = jnp.concatenate([t.reshape(1, positions, -1) for t in (x, b, c)],
                           -1)
-    facts: Dict[str, Any] = {"form": "kernels" if kernels else "plain"}
+    facts: Dict[str, Any] = {
+        "form": "kernels" if kernels else "plain",
+        "head_blocks": per // ssd.head_block(per, head_dim)}
     timed = [("", scan, args, weight),
              ("mixer_", mixer, (xbc, dt, a, skip),
               weight.reshape(1, positions, -1))] + (

@@ -18,7 +18,8 @@ chunked loss, the tables, the step and the ``Trainer`` are
   frequencies and factor in a ``full`` one (``mla_moe.rotary``); query
   head ``h`` reads key-value head ``h // (n_heads / n_kv_heads)``, and K
   and V are never repeated: the flash kernel's index maps know the group.
-  Scores over ``sqrt(head_dim)``; position ``i`` sees ``j`` where ``0 <=
+  Scores over ``sqrt(head_dim)`` (or times ``cfg.softmax_scale`` where a
+  configuration has that field); position ``i`` sees ``j`` where ``0 <=
   i - j`` and, in a ``window`` layer, ``i - j < window``; float32
   softmax; ``o W_o``. Three switches of the configuration, all off here:
   ``qk_norm`` (an RMSNorm over ``head_dim`` on every head of q and of k
@@ -170,13 +171,17 @@ def gqa(u, p, cfg, kind: str):
     ``models/keye_moe.sparse_gqa``'s too."""
     s = u.shape[1]
     window = cfg.window if kind == "window" else None
+    # the scores' multiplier where the configuration publishes one
+    # (``models/granite_h.py``: 1 / head_dim), ``1 / sqrt(head_dim)`` where
+    # not, as ``mla_moe.mla`` reads the same field
+    scale = getattr(cfg, "softmax_scale", None)
     with jax.named_scope("mv.lm.attn"):
         q, k, v = heads_of(u, p, cfg, kind)
         with jax.named_scope("mv.lm.attn." + kind):
             if mla_moe.attn_core(cfg) == "flash":
                 o = flash_attention(q, k, v, True,
                                     *mla_moe.attn_blocks(cfg, s), None,
-                                    window)
+                                    window, scale=scale)
             else:
-                o = mla_moe._xla_attention(q, k, v, window)
+                o = mla_moe._xla_attention(q, k, v, window, scale=scale)
         return out_of(o, u, p, cfg)

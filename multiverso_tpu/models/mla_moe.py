@@ -302,6 +302,16 @@ def tied_head(cfg) -> bool:
     return bool(getattr(cfg, "tied_head", False))
 
 
+def residual_scale(cfg) -> float:
+    """What a branch's result is multiplied by as it joins the stream."""
+    return float(getattr(cfg, "residual_scale", 1.0))
+
+
+def logit_scale(cfg) -> float:
+    """What the head's logits are multiplied by before the softmax."""
+    return float(getattr(cfg, "logit_scale", 1.0))
+
+
 def _draw(shape, key, scale: float, rule, pad: int = 0) -> jax.Array:
     """A parameter's first values by ``rule`` (:func:`_rule_of`): ``"ones"``,
     ``"normal"`` (Normal(0, scale)), ``("log_uniform", lo, hi)`` (the log of
@@ -670,7 +680,8 @@ def mixer_grid(cfg, s: int) -> Dict[str, Any]:
     selection (``sparse``), the indexer's and the selection's
     (``cfg.index_grid``), where some is a delta-rule linear-attention
     mixer, that mixer's (``cfg.delta_grid``); nothing for a list of
-    two-branch attention blocks of the other kinds."""
+    two-branch attention blocks of the other kinds. The experts' form is
+    left out where no layer has experts."""
     layers = cfg.layers()
     mixers = {kind: sum(layer.attn == kind for layer in layers)
               for kind in ("ssm", "conv", "sparse", "delta")}
@@ -680,7 +691,8 @@ def mixer_grid(cfg, s: int) -> Dict[str, Any]:
     out = {"block_kinds": ",".join(
                "+".join(kind for kind in (layer.attn, layer.ffn) if kind)
                for layer in layers),
-           "expert_form": cfg.expert_form}
+           **({"expert_form": cfg.expert_form} if expert_layers(cfg)
+              else {})}
     if mixers["ssm"]:
         out.update(ssm_layers=mixers["ssm"], **cfg.ssm_grid(s))
     if mixers["conv"]:
@@ -690,6 +702,23 @@ def mixer_grid(cfg, s: int) -> Dict[str, Any]:
     if mixers["delta"]:
         out.update(cfg.delta_grid(s))
     return out
+
+
+def multiplier_grid(cfg) -> Dict[str, Any]:
+    """What a configuration that multiplies its residual sums or its logits
+    (``models/granite_h.py``) adds to the ``lm.step`` span: the four
+    published multipliers as the program applies them (the scores'
+    ``softmax_scale`` as the number the core multiplies by) and whether the
+    head is the embedding's table; nothing for every other
+    configuration."""
+    if residual_scale(cfg) == 1.0 and logit_scale(cfg) == 1.0:
+        return {}
+    return {"embed_scale": float(cfg.embed_scale),
+            "residual_scale": residual_scale(cfg),
+            "logit_scale": logit_scale(cfg),
+            "softmax_scale": float(getattr(cfg, "softmax_scale", None)
+                                   or cfg.head_size ** -0.5),
+            "tied_head": int(tied_head(cfg))}
 
 
 def _xla_attention(q, k, v, window: Optional[int] = None, select=None,
@@ -1099,8 +1128,12 @@ def block(x, p, attn, ffn, cfg):
 
     The residual path is the configuration's too. With one stream (every
     configuration but ``models/xing4.py``'s) a block is handed ``x`` [B,
-    S, C] and a branch's result is added to it. With ``cfg.streams`` = n
-    of them it is handed, and hands back, ``x`` [B, S, n, C], four times
+    S, C] and a branch's result is added to it, times
+    ``cfg.residual_scale`` where the configuration has such a multiplier
+    (``models/granite_h.py``: ``x + 0.22 F(norm(x))``, both branches; at 1
+    or without the field the product is not in the program). With
+    ``cfg.streams`` = n of them it is handed, and hands back, ``x`` [B, S,
+    n, C], four times
     a position what one stream weighs, and a sublayer ``F`` is, under its
     own :func:`stream_maps`: ``u = pre . x`` [B, S, C] (the branch reads a
     mix of the streams), ``y = F(norm(u))``, ``x' = res @ x + outer(post,
@@ -1123,7 +1156,8 @@ def block(x, p, attn, ffn, cfg):
         back): (the streams, that, the hyper-connection's error or none)."""
         if streams_of(cfg) == 1:
             y, extra = branch(stream, q)
-            return stream + y, extra, []
+            scale = residual_scale(cfg)
+            return stream + (y if scale == 1.0 else scale * y), extra, []
         hc = tuple(q[f"{name}.hc_{k}"] for k in ("phi", "b", "alpha"))
         h, extra, error = _hyper(branch, cfg, stream, hc, q)
         return h, extra, [error]
@@ -1300,8 +1334,13 @@ def _ce_chunks(h, head, targets, weights, cfg, grads: bool,
     the stacked result) and times the chunk's ``h`` (the gradient to
     ``head``, summed in a float32 carry), and each position's unweighted
     loss (the gradient to ``weights``); without ``grads`` and with
-    ``each_too``, each position's unweighted loss alone."""
+    ``each_too``, each position's unweighted loss alone. Where the
+    configuration multiplies its logits (``cfg.logit_scale``,
+    :func:`logit_scale`: ``models/granite_h.py``'s ``1 / 8``) the chunk's
+    logits and the ``dl`` made from them take the multiplier; at 1 neither
+    product is in the program."""
     n, d = h.shape
+    scale = logit_scale(cfg)
     chunk = min(cfg.loss_chunk, n)
     if n % chunk:
         raise ValueError(f"{n} positions do not divide into chunks of {chunk}")
@@ -1312,13 +1351,16 @@ def _ce_chunks(h, head, targets, weights, cfg, grads: bool,
         hc, tc, wc = xs
         hc, w = hc.astype(dt), head.astype(dt)
         logits = _dot(hc, w, ((1,), (1,)), jnp.float32)
+        if scale != 1.0:
+            logits = logits * scale
         lse = jax.nn.logsumexp(logits, -1)
         each = lse - jnp.take_along_axis(logits, tc[:, None], -1)[:, 0]
         total = total + jnp.sum(wc * each)
         if not grads:
             return (total, dw), (each if each_too else None)
         hit = jnp.arange(logits.shape[1])[None, :] == tc[:, None]
-        dl = (wc[:, None] * (jnp.exp(logits - lse[:, None]) - hit)).astype(dt)
+        dl = wc[:, None] * (jnp.exp(logits - lse[:, None]) - hit)
+        dl = (dl if scale == 1.0 else dl * scale).astype(dt)
         dw = dw + _dot(dl, hc, ((0,), (0,)), jnp.float32)
         return (total, dw), (_dot(dl, w, ((1,), (0,)), h.dtype), each)
 
@@ -1883,6 +1925,7 @@ class Trainer:
                         attn_grid(self.cfg, positions,
                                   int(tokens.shape[0])),
                         **mixer_grid(self.cfg, positions),
+                        **multiplier_grid(self.cfg),
                         **loss_grid(self.cfg, count),
                         **kept_grid(self.cfg, *tokens.shape),
                         **stream_grid(self.cfg, *tokens.shape),

@@ -18,7 +18,10 @@ that takes positions. The equations, for a block with input ``x`` [B, S, D]:
   taps with a bias, zeros before the sequence's start; ``xBC -> x`` [heads,
   head_dim], ``B``, ``C`` [groups, state]; ``dt = softplus(dt + dt_bias)``,
   ``A = -exp(A_log)``, one each a head; the scan ``H_t = exp(dt_t A) H_{t-1}
-  + dt_t x_t (x) B_t``, ``y_t = H_t C_t + D x_t`` (``ops/ssd.py``, chunked);
+  + dt_t x_t (x) B_t``, ``y_t = H_t C_t + D x_t`` (``ops/ssd.py``, chunked:
+  two Pallas kernels on a TPU that take any number of groups, a group's
+  heads in blocks of at most 1,024 lanes where they do not fit one grid
+  step, and a chunk of any whole number of lane tiles);
   ``y = GroupRMSNorm(y * silu(z)) * g``: the gate first, then an RMSNorm
   over each group's ``heads x head_dim / groups``; ``y W_out``. No
   projection bias. The state runs on across packed documents.
@@ -95,10 +98,13 @@ class NemotronHConfig(NamedTuple):
     def ssm_grid(self, s: int) -> Dict[str, int]:
         """The scan's static counts over ``s`` positions, as ``lm.step``
         spans carry them (``ssd.step_counts`` says whether its kernels
-        run), and the mixers' short convolution's
-        (``short_conv.step_counts``)."""
+        run, why where they do not, the chunk they walk where they do, and
+        the blocks a group's heads are walked in), and the mixers' short
+        convolution's (``short_conv.step_counts``). ``models/granite_h.py``'s
+        configuration takes this method as it is."""
         mixers = sum(layer.attn == "ssm" for layer in self.layers())
-        return {"ssm_chunks": s // self.chunk, "ssm_heads": self.ssm_heads,
+        return {"ssm_chunks": s // self.chunk, "ssm_chunk": self.chunk,
+                "ssm_heads": self.ssm_heads, "ssm_groups": self.ssm_groups,
                 "ssm_state": self.ssm_state,
                 **step_counts(mixers, s, mamba2_shapes(self)["conv_w"][1]),
                 **ssd.step_counts(mixers, s, self.ssm_heads,

@@ -18,24 +18,33 @@ chunk (so ``La_i <= 0`` and falling), ``Xd = dt x``:
 The chunk-local work is four batched matrix products (``C B^T`` once a
 group, the masked product with ``Xd``, the chunk's state ``S_c``, and ``C
 H_c``); the recurrence over the chunks' states is a ``lax.scan``; the
-groups are walked by a ``lax.map``. Running
+groups are walked by a ``lax.map``, a group of many heads in blocks of
+them (:func:`head_block`). Running
 sums, exponentials, the carried state and the recurrence are float32; the
 four products take operands in ``dtype`` (bfloat16) and sum in float32,
 forward and backward (:func:`_ein`). Every exponent is of a number that
 is at most 0, so nothing overflows however long the sequence.
 
 :func:`plain` is the definition. On a TPU, at shapes :func:`kernel_heads`
-takes (a chunk of 128, heads of 64 or of whole lane tiles in groups of 8 or
-16, a state of whole lane tiles), :func:`ssd_chunked` runs the same
-arithmetic, rounded where :func:`_group` rounds it, as two Pallas kernels
-under ONE ``jax.custom_vjp`` (:func:`_scan`), and nothing chunk-local
-reaches HBM:
+takes (a chunk of whole lane tiles, heads of 64 or of whole lane tiles, a
+state of whole lane tiles, ANY number of groups: a group's heads in blocks
+of whole sublane tiles on at most 1,024 lanes, the group itself where it
+fits; :func:`kernel_refusal` names what a shape fails), :func:`ssd_chunked`
+runs the same arithmetic, rounded where :func:`_group` rounds it, as two
+Pallas kernels under ONE ``jax.custom_vjp`` (:func:`_scan`), and nothing
+chunk-local reaches HBM. The kernels walk chunks of ONE lane tile whatever
+the configuration's chunk (:func:`kernel_chunk`: the recurrence does not
+depend on how it is chunked). A "unit" below is what a grid step holds: a
+block of ONE group's heads (8 groups of 8 heads are 8 units, the groups
+themselves; 1 group of 64 heads is 4 units of 16), and every unit of a
+group reads that group's ``B`` and ``C``:
 
-1. :func:`_fwd_kernel` (``ssd_chunk_fwd``): a grid over (sequence, group,
+1. :func:`_fwd_kernel` (``ssd_chunk_fwd``): a grid over (sequence, unit,
    chunk), the chunks innermost and in order. A step reads the chunk's
-   ``x`` as it lies, [128 positions, a group's heads side by side on the
-   lanes] out of ``[S, H P]``, its ``B`` and ``C`` tiles out of ``[S, G N]``
-   (or all three out of a mixer's one ``[x | B | C]``: ``whole``) and the
+   ``x`` as it lies, [128 positions, a unit's heads side by side on the
+   lanes] out of ``[S, H P]``, its group's ``B`` and ``C`` tiles out of
+   ``[S, G N]`` (or all three out of a mixer's one ``[x | B | C]``:
+   ``whole``) and the
    chunk's rows of ``dt`` and ``La`` out of ``[H, S]``; ``C B^T`` once, a
    head's ``L`` and its masked product, ``C H_c`` for all the group's heads
    in one product against the state, which rides TRANSPOSED in a VMEM
@@ -45,13 +54,16 @@ reaches HBM:
    each chunk starts from (float32, 268 MB a mixer at the cell's shapes,
    alive until that mixer's backward pass).
 2. :func:`_bwd_kernel` (``ssd_chunk_bwd``): the chunks in REVERSE and the
-   groups innermost, every group's ``dH`` carried in VMEM; the chunk's
+   units innermost, every unit's ``dH`` carried in VMEM; the chunk's
    products made again, every product's two gradients with the cotangent
    rounded to ``dtype`` (as :func:`_ein` does); ``dx`` a tile of ``[S, H
    P]``, ``dB`` and ``dC`` (sums over the group's heads: the products over
-   all its lanes make them) a tile each of ``[S, G N]``, or the three as
-   ONE block [128, H P + 2 G N] of the gradient of ``whole``, written once
-   all its groups are in; ``ddt`` and ``dLa`` rows of ``[H, S]``, the
+   a unit's lanes make a unit's part, and where a group is several units
+   their parts add up in the group's tile while it stays in VMEM, the
+   group's first unit writing and the others adding) a tile each of ``[S,
+   G N]``, or the three as ONE block [128, H P + 2 G N] of the gradient of
+   ``whole``, written once all its units are in; ``ddt`` and ``dLa`` rows
+   of ``[H, S]``, the
    sums over a head's lanes made on the matrix unit (:func:`_of_heads`);
    the skip's gradient added up over the walk.
 
@@ -158,38 +170,50 @@ def plain(x, dt, a, b, c, chunk: int, dtype=jnp.bfloat16):
 
     ``x`` [B, S, H, P]; ``dt`` [B, S, H] (positive: after the softplus);
     ``a`` [H] (negative); ``b``, ``c`` [B, S, G, N], head ``h`` reading
-    group ``h // (H / G)``; ``chunk`` divides S. The groups are computed
-    one after another, each rematerialised in the backward pass: a group's
-    ``L`` over 16,384 positions and 8 heads is 67 MB of float32, all 64
-    heads' 537 MB, several times over in a backward pass."""
+    group ``h // (H / G)``; ``chunk`` divides S. A group's heads are
+    walked in blocks of :func:`head_block` (a whole group where it is
+    small, as the kernels do), one block after another, each
+    rematerialised in the backward pass and each reading its group's ``B``
+    and ``C``: a block's ``L`` over 16,384 positions and 8 heads is 67 MB
+    of float32, all 64 heads' 537 MB, several times over in a backward
+    pass."""
     bsz, s, h, p = x.shape
     g, n = b.shape[2], b.shape[3]
     if s % chunk or h % g:
         raise ValueError(f"{s} positions do not divide into chunks of "
                          f"{chunk}, or {h} heads into {g} groups")
-    nc, k = s // chunk, h // g
-    # group-major, then chunk-major: [G, B, C, (K,) Q, ...]
-    xs = x.reshape(bsz, nc, chunk, g, k, p).transpose(3, 0, 1, 4, 2, 5)
-    dts = dt.astype(jnp.float32).reshape(bsz, nc, chunk, g, k).transpose(
+    k = head_block(h // g, p)
+    nc, units = s // chunk, h // k
+    # block-major (a group's blocks together), then chunk-major: [G x
+    # blocks, B, C, (K,) Q, ...]
+    xs = x.reshape(bsz, nc, chunk, units, k, p).transpose(3, 0, 1, 4, 2, 5)
+    dts = dt.astype(jnp.float32).reshape(bsz, nc, chunk, units, k).transpose(
         3, 0, 1, 4, 2)
     bs = b.reshape(bsz, nc, chunk, g, n).transpose(3, 0, 1, 2, 4)
     cs = c.reshape(bsz, nc, chunk, g, n).transpose(3, 0, 1, 2, 4)
+    if units > g:
+        # every block of a group reads that group's B and C; their
+        # gradients add up over its blocks
+        bs, cs = (jnp.repeat(t, units // g, axis=0) for t in (bs, cs))
     one = jax.checkpoint(lambda t: _group(*t, dtype))
-    y = jax.lax.map(one, (xs, dts, a.astype(jnp.float32).reshape(g, k),
+    y = jax.lax.map(one, (xs, dts, a.astype(jnp.float32).reshape(units, k),
                           bs, cs))
     return y.transpose(1, 2, 4, 0, 3, 5).reshape(bsz, s, h, p)
 
 
 class Walk(NamedTuple):
     """What a call of the kernels is, from its shapes: ``b`` sequences of
-    ``s`` positions, ``groups`` groups of ``heads`` heads of ``p`` with a
-    state of ``n``; ``b_at`` / ``c_at``: where ``B`` and ``C`` start in
-    their operand, in blocks of ``n`` columns (``x`` starts at 0: a mixer
-    hands ONE array ``[x | B | C]`` for all three, :func:`ssd_chunked`'s
-    ``whole``)."""
+    ``s`` positions, ``groups`` groups each of ``blocks`` blocks of
+    ``heads`` heads of ``p`` with a state of ``n`` (a grid step holds ONE
+    block of one group's heads; ``blocks`` is 1 where a group's heads fit a
+    step); ``b_at`` / ``c_at``: where ``B`` and ``C`` start in their
+    operand, in blocks of ``n`` columns (``x`` starts at 0: a mixer hands
+    ONE array ``[x | B | C]`` for all three, :func:`ssd_chunked`'s
+    ``whole``). A chunk of the walk holds :data:`CHUNK` positions."""
     b: int
     s: int
     groups: int
+    blocks: int
     heads: int
     p: int
     n: int
@@ -203,7 +227,11 @@ class Walk(NamedTuple):
         return self.s // CHUNK
 
     @property
-    def lanes(self) -> int:          # a group's heads side by side
+    def units(self) -> int:          # the blocks of heads of all groups
+        return self.groups * self.blocks
+
+    @property
+    def lanes(self) -> int:          # a block's heads side by side
         return self.heads * self.p
 
     @property
@@ -211,31 +239,83 @@ class Walk(NamedTuple):
         return self.b_at > 0
 
 
+def head_block(k: int, p: int) -> int:
+    """The heads of ONE group that a step of a walk takes together (the
+    kernels' grid step, :func:`plain`'s ``lax.map`` step): all ``k`` where
+    they lie on at most 1,024 lanes side by side, else the larger of 16 and
+    8 that divides ``k`` and fits; a group that neither divides is walked
+    whole (and the kernels refuse it)."""
+    if k * p <= 1024:
+        return k
+    return next((each for each in (16, 8)
+                 if k % each == 0 and each * p <= 1024), k)
+
+
+def kernel_chunk(chunk: int) -> int:
+    """The positions a chunk of the KERNELS' walk holds under a
+    configuration's ``chunk``: one lane tile, whatever whole number of
+    them the configuration's is (the recurrence does not depend on how it
+    is chunked). On the chip, 1 group x 64 heads over 8,192 positions, the
+    mixer's call read 0.68 ms forward and 2.98 with every gradient at 128
+    and, with the walk's chunk made a parameter for the reading, 0.68 and
+    2.83 at 256 (PERF.md section 6, PR 66): 0.3% of the step, for running
+    sums twice as long (their differences' rounding doubles) and a second
+    shape of both kernels to guard; not taken, and the parameter went."""
+    return CHUNK
+
+
+def kernel_refusal(s: int, h: int, p: int, g: int, n: int, chunk: int
+                   ) -> Optional[str]:
+    """Why :func:`plain` runs at these shapes on this process's device
+    (``lm.step``'s ``ssd_kernel_why``), or ``None`` where the kernels do."""
+    if jax.devices()[0].platform != "tpu":
+        return "no TPU"
+    if chunk % CHUNK or s % chunk:
+        return (f"a chunk of {chunk} is no whole lane tiles, or {s} "
+                f"positions no whole chunks")
+    if h % g:
+        return f"{h} heads do not divide into {g} groups"
+    if n % LANES:
+        return f"a state of {n} is no whole lane tiles"
+    if p != 64 and p % LANES:
+        return f"a head of {p} is neither 64 nor whole lane tiles"
+    k = head_block(h // g, p)
+    if k % 8 or k * p > 1024:
+        return (f"{h // g} heads of {p} a group divide into no blocks of "
+                f"whole sublane tiles on at most 1,024 lanes")
+    return None
+
+
 def kernel_heads(s: int, h: int, p: int, g: int, n: int, chunk: int
                  ) -> Optional[int]:
-    """The heads a group's grid step of the kernels holds on this
-    process's device, or ``None`` where :func:`plain` runs: off a TPU, at a
-    chunk that is no lane tile, at positions that are no whole chunks, at
-    a head that is neither 64 nor whole lane tiles, at a state that is no
-    whole lane tiles, at groups whose heads are no whole sublane tiles (or
-    more than 1,024 lanes side by side)."""
-    if (jax.devices()[0].platform != "tpu" or chunk != CHUNK or s % chunk
-            or h % g or n % LANES or (p != 64 and p % LANES)):
+    """The heads a grid step of the kernels holds on this process's
+    device (:func:`head_block` of a group's: the group whole, or a block of
+    it), or ``None`` where :func:`plain` runs (:func:`kernel_refusal` says
+    why: off a TPU, at a chunk or a state that is no whole lane tiles, at
+    positions that are no whole chunks, at a head that is neither 64 nor
+    whole lane tiles, at groups whose heads make no blocks of whole
+    sublane tiles)."""
+    if kernel_refusal(s, h, p, g, n, chunk) is not None:
         return None
-    k = h // g
-    return k if k % 8 == 0 and k * p <= 1024 else None
+    return head_block(h // g, p)
 
 
 def step_counts(mixers: int, s: int, h: int, p: int, g: int, n: int,
                 chunk: int) -> dict:
     """What ``lm.step`` spans say of ``mixers`` layers whose scan runs
     over ``s`` positions: ``ssd_kernel_layers``, those that run the kernels
-    on this device (all of them or none), and ``ssd_bytes``, what ONE
-    mixer's scan must move a forward pass of a sequence: ``x``, ``B``, ``C``
-    and ``dt`` read and ``y`` written once, float32 as the mixer holds
-    them."""
-    return {"ssd_kernel_layers":
-            mixers * (kernel_heads(s, h, p, g, n, chunk) is not None),
+    on this device (all of them or none); where none does,
+    ``ssd_kernel_why`` (:func:`kernel_refusal`), and where they do
+    ``ssd_kernel_chunk``, the positions a chunk of their walk holds;
+    ``ssm_head_blocks``, the blocks a group's heads are walked in
+    (:func:`head_block`); and ``ssd_bytes``, what ONE mixer's scan must
+    move a forward pass of a sequence: ``x``, ``B``, ``C`` and ``dt`` read
+    and ``y`` written once, float32 as the mixer holds them."""
+    why = kernel_refusal(s, h, p, g, n, chunk)
+    return {"ssd_kernel_layers": mixers * (why is None),
+            **({"ssd_kernel_chunk": kernel_chunk(chunk)} if why is None
+               else {"ssd_kernel_why": why}),
+            "ssm_head_blocks": h // g // head_block(h // g, p),
             "ssd_bytes": 4 * s * (2 * h * p + 2 * g * n + h)}
 
 
@@ -357,6 +437,8 @@ def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, la_ref, d_ref, y_ref, *rest,
 def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, la_ref, d_ref, g_ref, start_ref,
                 *rest, walk: Walk):
     *wide_refs, ddt_ref, dla_ref, dd_ref, dh_ref, acc_ref = rest
+    # ``group``: the block of heads this step holds, of all the groups'
+    # (a group itself where its heads are one block)
     t, group = pl.program_id(1), pl.program_id(2)
 
     @pl.when(t == 0)
@@ -402,20 +484,37 @@ def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, la_ref, d_ref, g_ref, start_ref,
     g = dcb.astype(dtype)
     dx = dxd * dt_x + gy * d_ref[...]
     dc, db = dc + _dot(g, bm, _NN), db + _dot(g, cm, _TN)
+    # dB and dC are sums over ALL the group's heads: where a group is
+    # several blocks they are consecutive steps, the group's tile stays in
+    # VMEM over them, and the first of them writes where the others add
+    of = group if walk.blocks == 1 else group // walk.blocks
     if walk.whole:      # [dx | dB | dC], as the operand lies
         dw_ref, = wide_refs
-        lanes = walk.lanes
 
-        def put(at, width, grad):       # ``width`` lanes from ``at`` blocks
-            dw_ref[0, :, pl.ds(pl.multiple_of(at * width, width), width)] = (
-                grad)
+        def put(at, width, grad, add=False):    # ``width`` lanes from ``at``
+            where = (0, slice(None),            # blocks
+                     pl.ds(pl.multiple_of(at * width, width), width))
+            dw_ref[where] = dw_ref[where] + grad if add else grad
 
-        put(group, lanes, dx)
-        put(walk.b_at + group, n, db)
-        put(walk.c_at + group, n, dc)
+        put(group, walk.lanes, dx)
+
+        def shared(add):
+            put(walk.b_at + of, n, db, add)
+            put(walk.c_at + of, n, dc, add)
     else:
-        for ref, grad in zip(wide_refs, (dx, db, dc)):
-            ref[0] = grad
+        dx_ref, db_ref, dc_ref = wide_refs
+        dx_ref[0] = dx
+
+        def shared(add):
+            db_ref[0] = db_ref[0] + db if add else db
+            dc_ref[0] = dc_ref[0] + dc if add else dc
+
+    if walk.blocks == 1:
+        shared(False)
+    else:
+        first = group % walk.blocks == 0
+        pl.when(first)(lambda: shared(False))
+        pl.when(jnp.logical_not(first))(lambda: shared(True))
     acc_ref[group] += jnp.sum(gy * x, 0, keepdims=True)
     dd_ref[0] = acc_ref[group]      # the walk's last chunk writes last
     last = jax.lax.broadcasted_iota(jnp.int32, (CHUNK, 1), 0) == CHUNK - 1
@@ -430,56 +529,62 @@ def _calls(walk: Walk):
     the backward): ``(x, B, C, dt^T, La^T, D) -> y [, H]`` and ``(x, B, C,
     dt^T, La^T, D, dy, H) -> (dx, dB, dC, ddt^T, dLa^T, dD)``, or ``(d[x | B
     | C], ...)`` where one operand holds the three; ``dt^T``, ``La^T`` [B,
-    heads, S], ``D`` [1, heads x p], ``H`` [B, groups, chunks, N, lanes]
-    float32. The forward walks a group's chunks in order; the backward
-    walks the chunks in reverse with the GROUPS innermost, every group's
-    ``dH`` in VMEM, so that a chunk's ``[dx | dB | dC]`` block of all the
-    groups is written as one."""
-    b, s, g, k, n = walk.b, walk.s, walk.groups, walk.heads, walk.n
-    lanes, steps = walk.lanes, walk.chunks
+    heads, S], ``D`` [1, heads x p], ``H`` [B, units, chunks, N, lanes]
+    float32, a unit a block of one group's heads (``walk.units``: the
+    groups themselves where a group's heads are one block). The forward
+    walks a unit's chunks in order, every block of a group reading that
+    group's ``B`` / ``C`` tile; the backward walks the chunks in reverse
+    with the UNITS innermost, every unit's ``dH`` in VMEM, so that a
+    chunk's ``[dx | dB | dC]`` block of all the groups is written as one,
+    a group's ``dB`` and ``dC`` summed over its blocks in it."""
+    b, s, k, n = walk.b, walk.s, walk.heads, walk.n
+    g, units, lanes, steps = walk.groups, walk.units, walk.lanes, walk.chunks
+    group = (lambda u: u) if walk.blocks == 1 else (
+        lambda u: u // walk.blocks)
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
     params = lambda *order: pltpu.CompilerParams(
         dimension_semantics=("parallel",) + order,
         vmem_limit_bytes=64 << 20)
 
-    def specs(of):      # of(*grid indices) -> (sequence, group, chunk)
+    def specs(of):      # of(*grid indices) -> (sequence, unit, chunk)
         def spec(block, at):
             return pl.BlockSpec(block, lambda *ids: at(*of(*ids)))
 
-        wide = spec((1, CHUNK, lanes), lambda b, g, c: (b, c, g))
+        wide = spec((1, CHUNK, lanes), lambda b, u, c: (b, c, u))
         state = lambda first: spec((1, CHUNK, n),
-                                   lambda b, g, c: (b, c, first + g))
-        small = spec((1, k, CHUNK), lambda b, g, c: (b, g, c))
-        skip = spec((1, lanes), lambda b, g, c: (0, g))
-        start = spec((1, 1, 1, n, lanes), lambda b, g, c: (b, g, c, 0, 0))
+                                   lambda b, u, c: (b, c, first + group(u)))
+        small = spec((1, k, CHUNK), lambda b, u, c: (b, u, c))
+        skip = spec((1, lanes), lambda b, u, c: (0, u))
+        start = spec((1, 1, 1, n, lanes), lambda b, u, c: (b, u, c, 0, 0))
         return wide, state, small, skip, start, spec
 
-    wide, state, small, skip, start, _ = specs(lambda b, g, t: (b, g, t))
+    wide, state, small, skip, start, _ = specs(lambda b, u, t: (b, u, t))
     ins = [wide, state(walk.b_at), state(walk.c_at), small, small, skip]
     fwd = [pl.pallas_call(
-        functools.partial(_fwd_kernel, walk=walk), grid=(b, g, steps),
+        functools.partial(_fwd_kernel, walk=walk), grid=(b, units, steps),
         in_specs=ins, out_specs=[wide, start][:1 + keep],
-        out_shape=[f32(b, s, g * lanes), f32(b, g, steps, n, lanes)][:1 + keep],
+        out_shape=[f32(b, s, units * lanes),
+                   f32(b, units, steps, n, lanes)][:1 + keep],
         scratch_shapes=[pltpu.VMEM((n, lanes), jnp.float32)],
         compiler_params=params("parallel", "arbitrary"), name=FWD,
         interpret=walk.interpret) for keep in (False, True)]
     wide, state, small, skip, start, spec = specs(
-        lambda b, t, g: (b, g, steps - 1 - t))
-    width = g * (lanes + 2 * n)
-    grads = ([spec((1, CHUNK, width), lambda b, g, c: (b, c, 0))],
+        lambda b, t, u: (b, u, steps - 1 - t))
+    width = units * lanes + 2 * g * n
+    grads = ([spec((1, CHUNK, width), lambda b, u, c: (b, c, 0))],
              [f32(b, s, width)]) if walk.whole else (
         [wide, state(0), state(0)],
-        [f32(b, s, g * lanes), f32(b, s, g * n), f32(b, s, g * n)])
+        [f32(b, s, units * lanes), f32(b, s, g * n), f32(b, s, g * n)])
     bwd = pl.pallas_call(
-        functools.partial(_bwd_kernel, walk=walk), grid=(b, steps, g),
+        functools.partial(_bwd_kernel, walk=walk), grid=(b, steps, units),
         in_specs=[wide, state(walk.b_at), state(walk.c_at), small, small,
                   skip, wide, start],
         out_specs=grads[0] + [small, small,
-                              spec((1, 1, lanes), lambda b, g, c: (b, 0, g))],
-        out_shape=grads[1] + [f32(b, g * k, s), f32(b, g * k, s),
-                              f32(b, 1, g * lanes)],
-        scratch_shapes=[pltpu.VMEM((g, n, lanes), jnp.float32),
-                        pltpu.VMEM((g, 1, lanes), jnp.float32)],
+                              spec((1, 1, lanes), lambda b, u, c: (b, 0, u))],
+        out_shape=grads[1] + [f32(b, units * k, s), f32(b, units * k, s),
+                              f32(b, 1, units * lanes)],
+        scratch_shapes=[pltpu.VMEM((units, n, lanes), jnp.float32),
+                        pltpu.VMEM((units, 1, lanes), jnp.float32)],
         compiler_params=params("arbitrary", "arbitrary"), name=BWD,
         interpret=walk.interpret)
     return fwd[0], fwd[1], bwd
@@ -522,7 +627,8 @@ def _kernels(wide, dt, a, skip, heads: int, p: int, groups: int, n: int,
     from its gradient (``ddt``'s second part, ``dA``) are XLA's, on [B, H,
     S] arrays; the skip's ``D`` is spread over each head's lanes."""
     b, s = dt.shape[:2]
-    walk = Walk(b, s, groups, heads // groups, p, n,
+    k = head_block(heads // groups, p)
+    walk = Walk(b, s, groups, heads // groups // k, k, p, n,
                 *((0, 0) if len(wide) == 3 else
                   (heads * p // n, heads * p // n + groups)),
                 jnp.dtype(dtype), interpret)

@@ -157,8 +157,14 @@ def test_ssd_stage(monkeypatch):
     every head held to the recurrence; where the kernels run, the plain
     form's ms beside theirs."""
     facts = chip_smoke.stage_ssd(positions=256, heads=8, head_dim=8,
-                                 groups=2, state=16, chunk=32, repeats=1)
+                                 groups=2, state=16, chunk=32, repeats=1,
+                                 one_group=(128, 64))
     assert facts["form"] == "plain"             # the CPU's
+    # ALL the heads in one group beside it, at a chunk of its own
+    one = facts.pop("one_group")
+    assert (one["form"], one["head_blocks"]) == ("plain", 1)
+    assert max(one["rel_err_y_dx_ddt_da_db_dc"]) <= chip_smoke.ATTN_BF16_TOL
+    assert one["mixer_fwd_bwd_ms"] > 0
     for name in ("fwd", "fwd_bwd", "mixer_fwd", "mixer_fwd_bwd"):
         assert facts[f"{name}_ms"] > 0 and facts[f"{name}_compile_s"] >= 0
     assert not any(name.startswith("plain_") for name in facts)
@@ -171,8 +177,9 @@ def test_ssd_stage(monkeypatch):
     monkeypatch.setattr(ssd, "kernel_heads", lambda *shape: 8)
     monkeypatch.setattr(ssd, "ssd_chunked", kernels)
     facts = chip_smoke.stage_ssd(positions=256, heads=8, head_dim=64,
-                                 groups=1, state=128, chunk=128, repeats=1)
-    assert facts["form"] == "kernels"
+                                 groups=1, state=128, chunk=128, repeats=1,
+                                 one_group=None)
+    assert facts["form"] == "kernels" and "one_group" not in facts
     for name in ("fwd", "fwd_bwd", "mixer_fwd", "mixer_fwd_bwd", "plain_fwd",
                  "plain_fwd_bwd"):
         assert facts[f"{name}_ms"] > 0 and facts[f"{name}_compile_s"] >= 0

@@ -476,7 +476,8 @@ def test_one_step_through_the_adam_tables_is_reference_gradient_plus_adam():
             "    short convolution: the kernels in 0 mixer(s) (0: the plain "
             "form), 0 MB a mixer a pass at the least",
             "    state-space scan: the kernels in 0 mixer(s) (0: the plain "
-            "form), 0 MB a mixer a forward pass at the least"]
+            "form), 0 MB a mixer a forward pass at the least",
+            "      the plain form because: no TPU"]
 
 
 def _published():

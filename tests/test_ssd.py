@@ -13,9 +13,12 @@ from multiverso_tpu.ops.ssd import ssd_chunked
 B, S, H, P, G, N = 2, 64, 4, 8, 2, 16
 SMALL = (B, S, H, P, G, N)
 # shapes the kernels take (a chunk of 128, heads of 64 in groups of 8, a
-# state of 128): sequences, chunks and groups vary, the skip rides or not
+# state of 128): sequences, chunks and groups vary, the skip rides or not;
+# ``head_blocks``: ONE group of 32 heads, walked as two blocks of 16 that
+# read the one B and C (their dB and dC add up over the blocks)
 KERNELS = {"two_groups": ((2, 256, 16, 64, 2, 128), False),
-           "three_chunks": ((1, 384, 8, 64, 1, 128), True)}
+           "three_chunks": ((1, 384, 8, 64, 1, 128), True),
+           "head_blocks": ((1, 256, 32, 64, 1, 128), True)}
 # dt A over a position: heads that forget at once, heads that keep nearly
 # everything, and a spread between
 DECAYS = {"spread": (-3.0, 2.5), "near_0": (2.5, 3.5), "near_1": (-9.0, -7.0)}
@@ -197,11 +200,23 @@ def test_the_rule_takes_whole_tiles_on_a_tpu_and_nothing_else(monkeypatch):
     monkeypatch.setattr(jax, "devices", lambda *a: [_Chip()])
     assert ssd.kernel_heads(**cell) == 8
     assert ssd.kernel_heads(**dict(cell, p=128, h=32, g=4)) == 8
+    # a group of more heads than a step holds is walked in blocks, and a
+    # chunk of several lane tiles as chunks of one
+    assert ssd.kernel_heads(**dict(cell, g=1)) == 16
+    assert ssd.kernel_heads(**dict(cell, g=1, chunk=256, s=8192)) == 16
+    assert ssd.kernel_heads(**dict(cell, p=128, h=32, g=1)) == 8
+    assert ssd.kernel_refusal(**cell) is None
+    assert ssd.step_counts(9, **dict(cell, g=1, chunk=256)) == {
+        "ssd_kernel_layers": 9, "ssd_kernel_chunk": 128,
+        "ssm_head_blocks": 4, "ssd_bytes": 4 * 16384 * (8192 + 256 + 64)}
     refused = [dict(cell, chunk=64), dict(cell, s=16384 + 64),
                dict(cell, p=32), dict(cell, p=96), dict(cell, n=64),
-               dict(cell, g=16), dict(cell, h=60), dict(cell, g=1)]
+               dict(cell, g=16), dict(cell, h=60), dict(cell, h=20, g=1)]
     for shape in refused:
         assert ssd.kernel_heads(**shape) is None, shape
+        assert ssd.kernel_refusal(**shape), shape
+    assert "1,024 lanes" in ssd.step_counts(4, **refused[-1])["ssd_kernel_why"]
+    assert ssd.step_counts(4, **refused[-1])["ssd_kernel_layers"] == 0
 
     def traced(s, h, p, g, n, chunk):
         f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)

@@ -1032,6 +1032,47 @@ def test_state_space_scan_compiles_for_a_v5e_at_published_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 3.6 * (1 << 30)
 
 
+def test_one_group_of_64_heads_compiles_for_a_v5e_in_blocks_of_heads(
+        one_chip, monkeypatch):
+    """``nemotron_h.mamba2`` as ``granite4h-train-8k`` calls it (one
+    sequence of 8,192 positions, 64 heads of 64 in ONE group, a state of
+    128, the configuration's chunk 256), forward and backward: Mosaic
+    compiles ``ops/ssd.py``'s two kernels at blocks of 16 heads, [128,
+    1024], four a group and 64 chunks of a lane tile, and they still read
+    ``x``, ``B`` and ``C`` out of the convolution kernel's one result and
+    write ONE ``[dx | dB | dC]`` array."""
+    from multiverso_tpu.models import granite_h
+    from multiverso_tpu.ops import short_conv, ssd
+
+    monkeypatch.setattr(short_conv, "kernel_tiles",
+                        lambda s, c, dtype=None: (512, 256))
+    monkeypatch.setattr(ssd, "kernel_refusal", lambda *shape: None)
+    cfg = granite_h.GraniteHConfig(
+        dim=2048, ssm_heads=64, ssm_head_dim=64, ssm_groups=1,
+        ssm_state=128, chunk=256)
+    assert ssd.kernel_heads(8192, 64, 64, 1, 128, 256) == 16
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {n: f32(*s) for n, s in cfg.attn_shapes("ssm").items()}
+    compiled = jax.jit(jax.grad(lambda u, p: cfg.attend(u, p, "ssm").sum(),
+                                argnums=(0, 1))).lower(
+        f32(1, 8192, 2048), p).compile()
+    text = compiled.as_text()
+    calls = {name: re.findall(
+        rf"%{name}[.\d]* = ([^\n]*?) custom-call\(([^)]*)\)", text)
+        for name in (ssd.FWD, ssd.BWD)}
+    assert len(calls[ssd.FWD]) == len(calls[ssd.BWD]) == 1
+    for name, ((results, operands),) in calls.items():
+        first = [o.strip() for o in operands.split(",")[:3]]
+        assert len(set(first)) == 1 and first[0].startswith(
+            f"%{short_conv.FWD}"), (name, first)
+    # y, the chunk-start states of four units x 64 chunks, and the one
+    # gradient array [x | B | C] of 4,096 + 2 x 128
+    assert "f32[1,8192,4096]" in calls[ssd.FWD][0][0]
+    assert "f32[1,4,64,128,1024]" in calls[ssd.FWD][0][0]
+    assert "f32[1,8192,4352]" in calls[ssd.BWD][0][0]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * (1 << 30)
+
+
 def _qwen3next():
     """``qwen3next-train-16k``'s configuration, kernels on."""
     import json

@@ -777,7 +777,10 @@ def _mixer_lines(events: List[Dict]) -> List[str]:
     their part of a token's forward operations
     (``delta.mixer_flops_share``'s two counts); where a mixer has a short
     causal convolution (``ops/short_conv``), how many run its kernels;
-    where a mixer is a state-space scan (``ops/ssd``), how many run its."""
+    where a mixer is a state-space scan (``ops/ssd``), how many run its
+    kernels (why none does, the blocks a group's heads are walked in); the
+    published multipliers where a configuration has them
+    (``mla_moe.multiplier_grid``)."""
     args = next((e["args"] for e in events if e.get("name") == "lm.step"
                  and "block_kinds" in e.get("args", {})), None)
     if args is None:
@@ -820,6 +823,24 @@ def _mixer_lines(events: List[Dict]) -> List[str]:
             f"{args['ssd_kernel_layers']} mixer(s) (0: the plain form), "
             f"{args['ssd_bytes'] / 1e6:.0f} MB a mixer a forward pass at "
             "the least")
+        if "ssd_kernel_why" in args:
+            out.append(f"      the plain form because: "
+                       f"{args['ssd_kernel_why']}")
+        if args.get("ssm_head_blocks", 1) > 1:
+            out.append(
+                f"      {args['ssm_groups']} group(s) of "
+                f"{args['ssm_heads'] // args['ssm_groups']} heads, walked "
+                f"in {args['ssm_head_blocks']} blocks a group; chunks of "
+                f"{args['ssm_chunk']}"
+                + (f" walked as {args['ssd_kernel_chunk']}"
+                   if "ssd_kernel_chunk" in args else ""))
+    if "residual_scale" in args:
+        out.append(
+            f"    multipliers: embedding x {args['embed_scale']:g}, a "
+            f"branch's result x {args['residual_scale']:g}, the scores x "
+            f"{args['softmax_scale']:g}, the logits x "
+            f"{args['logit_scale']:g}"
+            + ("; tied head" if args.get("tied_head") else ""))
     return out
 
 
