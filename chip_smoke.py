@@ -888,6 +888,108 @@ def _expert_products(dim: int, ffn: int, held: int, rows: int, form: str,
     return facts
 
 
+# a held expert's rows a step of ``nemotron3n-train-16k`` as its router
+# leaves them (an even 768 and a few dozen rows either way: the cell's
+# ``moe.load_max_over_mean.lm`` is 1.03), so that no group starts on a row
+# tile's edge: at an exactly even load 768 rows are three whole tiles of
+# 256 and two of 384, which no step sees
+UNEVEN_ROWS = (781, 746, 773, 759, 785, 765, 757, 778)
+
+
+def product_kernels(dim: int = 2688, ffn: int = 1856, rows: int = 12288,
+                    sizes: Tuple[int, ...] = UNEVEN_ROWS,
+                    tiles: Tuple[Tuple[int, int, int], ...] = (),
+                    kinds: Tuple[str, ...] = ("fwd", "dbuf", "dw"),
+                    repeats: int = 10, chip: bool = True) -> Dict[str, Any]:
+    """The SIX kernels of an expert layer's two products alone
+    (``parallel/moe.grouped_matmul``'s forward, ``_gmm_bwd``'s two: into
+    the experts' width ``up`` [rows, dim] x [G, dim, ffn], out of it
+    ``down``), each at every tile of ``tiles`` (over rows, over ``dim``,
+    over ``ffn``; the matrices' gradient ``dw`` at that very tile, not at
+    ``moe.weights_tile``'s), operands handed in as arguments, groups of
+    ``sizes`` rows in a buffer of ``rows``: the ms a call of the kernel
+    itself by the device's trace (``<product>.<kind>``) and of the whole
+    call by this process's clock (``host_ms``: with the groups' metadata,
+    which XLA makes before every kernel, and whatever copies it places
+    round a call that stands alone; a kernel inside a step reads nearer
+    the first), ``None`` where the chip's compiler refuses the tile
+    (scoped VMEM), and the row tiles a forward product visits.
+    ``moe.product_tile``'s rule for a width that no multiple of 128
+    divides was chosen here (PERF.md section 6, PR 67)."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import trace_reduce
+    from multiverso_tpu.parallel import moe
+
+    held = len(sizes)
+    k = jax.random.split(jax.random.key(SEED), 4)
+    bf = jnp.bfloat16
+    groups = jnp.asarray(sizes, jnp.int32)
+    wide = {"dim": jax.random.normal(k[0], (rows, dim)).astype(bf),
+            "ffn": jax.random.normal(k[1], (rows, ffn)).astype(bf)}
+    weights = {"up": (0.02 * jax.random.normal(k[2], (held, dim, ffn))
+                      ).astype(bf),
+               "down": (0.02 * jax.random.normal(k[3], (held, ffn, dim))
+                        ).astype(bf)}
+    out: Dict[str, Any] = {}
+    for tm, over_dim, over_ffn in tiles:
+        facts: Dict[str, Any] = {"tiles_visited": moe.product_tiles(
+            np.asarray(sizes), rows, tm)}
+        ready, host = [], {}
+        for name, lhs, g, tk, tn in (("up", "dim", "ffn", over_dim, over_ffn),
+                                     ("down", "ffn", "dim", over_ffn,
+                                      over_dim)):
+            calls = {
+                "fwd": (lambda a, w, n, t=(tm, tk, tn): moe._gmm(
+                    a, w, n, bf, t), wide[lhs], weights[name]),
+                "dbuf": (lambda c, w, n, t=(tm, tn, tk): moe._gmm(
+                    c, w, n, bf, t, transpose_rhs=True), wide[g],
+                    weights[name]),
+                "dw": (lambda a, c, n, t=(tm, tk, tn): moe._tgmm(
+                    a.swapaxes(0, 1), c, n, jnp.float32, t,
+                    num_actual_groups=held), wide[lhs], wide[g]),
+            }
+            for kind in kinds:
+                fn, first, second = calls[kind]
+                args = (first, second, groups)
+                try:
+                    compiled = jax.jit(fn).lower(*args).compile()
+                    jax.block_until_ready(compiled(*args))
+                except Exception as e:      # Mosaic: scoped VMEM
+                    facts[f"{name}.{kind}"] = None
+                    facts.setdefault("refused", str(e)[-160:])
+                    continue
+                ready.append((f"{name}.{kind}", compiled, args))
+        with tempfile.TemporaryDirectory() as trace_dir:
+            with (jax.profiler.trace(trace_dir) if chip
+                  else contextlib.nullcontext()):
+                for key, compiled, args in ready:
+                    t0 = time.perf_counter()
+                    for _ in range(repeats):
+                        res = compiled(*args)
+                    jax.block_until_ready(res)
+                    host[key] = (time.perf_counter() - t0) / repeats * 1e3
+            # the kernels in the order they ran, ``repeats`` of each
+            ops = next(iter(trace_reduce.read_xplane(trace_reduce.find_xplane(
+                trace_dir))[0].values()), []) if chip and ready else []
+        ran = sorted((o.start, o.dur) for o in ops
+                     if o.name.split(".")[0] in ("gmm", "tgmm"))
+        if len(ran) != repeats * len(ready):    # off the chip: no trace
+            ran = []
+        for i, (key, _, _) in enumerate(ready):
+            facts[key] = (round(1e3 * sum(
+                d for _, d in ran[i * repeats:(i + 1) * repeats]) / repeats,
+                4) if ran else None)
+        whole = len(ready) == 2 * len(kinds)
+        facts["sum_ms"] = (round(sum(facts[key] for key, _, _ in ready), 3)
+                           if whole and ran else None)
+        facts["host_ms"] = round(sum(host.values()), 3) if whole else None
+        out[f"{tm}x{over_dim}x{over_ffn}"] = facts
+        _say("products.timed", tile=[tm, over_dim, over_ffn], **facts)
+    return out
+
+
 # (name, positions a step, vocabulary, dim): each language-model cell's
 # chunked loss as ``models/mla_moe.loss_fn`` calls it
 HEAD_CALLS = (

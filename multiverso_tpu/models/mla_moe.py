@@ -1838,7 +1838,9 @@ def routing_counts(counts: np.ndarray, cfg) -> Dict[str, Any]:
     before it asks which expert was busiest) and, of the held experts'
     grouped products, ``product_tiles_visited`` (the row tiles ONE forward
     product visits, summed over the layers: ``moe.product_tiles``) beside
-    ``product_tiles_buffer`` (the row tiles the layers' buffers hold),
+    ``product_tiles_buffer`` (the row tiles the layers' buffers hold) at
+    ``product_tile`` (the tile that runs, rows x over ``dim`` x over
+    ``ffn``: which of ``moe.product_tile``'s rules engaged),
     and of the passes round them ``buffer_rows_walked`` (the rows ONE
     gather or scatter over the sorted buffers walks, a layer's even load
     and whole chunks past it, summed over the layers:
@@ -1862,6 +1864,7 @@ def routing_counts(counts: np.ndarray, cfg) -> Dict[str, Any]:
             "expert_rows": c.tolist(),
             "product_tiles_visited": moe.product_tiles(mine, rows, tm),
             "product_tiles_buffer": len(c) * rows // tm,
+            "product_tile": "x".join(map(str, here.tile)),
             "buffer_rows_walked": moe.rows_walked(
                 mine, rows, moe.even_rows(here, tokens)),
             "buffer_rows": len(c) * rows}

@@ -303,21 +303,66 @@ def bias_update(bias: jax.Array, counts: jax.Array, speed) -> jax.Array:
     return bias + speed * jnp.sign(c.mean(-1, keepdims=True) - c)
 
 
+def _fewest_tiles(width: int) -> int:
+    """The multiple of 128 up to 1,024 that covers ``width`` in the
+    fewest tiles, and of those the smallest, whose last tile is the
+    fullest (1,856: 1,024, two tiles 91% full). ``megablox`` takes a last
+    tile that hangs over the width: masked where the width is contracted,
+    its columns past the width dropped where it is not."""
+    return min(range(128, 1025, 128), key=lambda t: (-(-width // t), t))
+
+
+# the widest width a tile takes whole: a group's weight block of it by a
+# tile of 1,024 is 4 MiB of bfloat16, held twice in 16 MiB of scoped VMEM
+WHOLE_WIDTH = 2048
+
+
 def product_tile(dim: int, ffn: int) -> Tuple[int, int, int]:
     """The grouped products' tile (over rows, over ``dim``, over ``ffn``)
     from the widths. A width that divides by 512 takes 512; another takes
     the largest multiple of 128 up to 1,024 that divides it (2,304 = 18 x
     128 takes 768, 896 = 7 x 128 takes itself: at 128 cubed a 65,536-row
-    buffer is 64,512 grid steps a product), and the kernel's own 128 where
-    there is none. Rows go by 512 where both widths' tiles reach it."""
+    buffer is 64,512 grid steps a product). A width that is no multiple
+    of 128 (1,856 = 14.5 x 128) is ONE tile, the whole width: a block may
+    be as wide as its array whatever the lanes, so no tile hangs over and
+    a group's weight block moves once; past ``WHOLE_WIDTH`` it takes
+    :func:`_fewest_tiles`', as under 128 (the kernel's own 128). Rows go by
+    512 where both widths' tiles reach it and by 128 where one does not;
+    beside a whole width by 256: a step of 256 rows by 1,856 columns
+    already multiplies 225 FLOP a byte it fetches, and a longer row tile
+    only adds padding at each group's seam (``chip_smoke.product_kernels``
+    read 128, 256, 384 and 512 on the chip: PERF.md section 6, PR 67)."""
     def of(width: int) -> int:
         if width % 512 == 0:
             return 512
         fits = [t for t in range(128, 1025, 128) if width % t == 0]
-        return fits[-1] if fits else 128
+        if fits:
+            return fits[-1]
+        return width if 128 < width <= WHOLE_WIDTH else _fewest_tiles(width)
 
     over_dim, over_ffn = of(dim), of(ffn)
-    return 512 if min(over_dim, over_ffn) >= 512 else 128, over_dim, over_ffn
+    whole = any(width % 128 and tile == width
+                for tile, width in ((over_dim, dim), (over_ffn, ffn)))
+    rows = 256 if whole else 512 if min(over_dim, over_ffn) >= 512 else 128
+    return rows, over_dim, over_ffn
+
+
+# the float32 elements of ``tgmm``'s result block (a matrix's gradient
+# leaves the kernel in float32, k tile x n tile, held twice beside an
+# accumulator as large: 12 bytes an element of 16 MiB of scoped VMEM)
+WEIGHTS_BLOCK = 1 << 20
+
+
+def weights_tile(tile: Tuple[int, int, int]) -> Tuple[int, int, int]:
+    """A product's tile (m, k, n) as ``tgmm`` takes it for the matrix's
+    gradient: the same, unless k x n float32 is over ``WEIGHTS_BLOCK``;
+    then the wider of the two is cut to :func:`_fewest_tiles`' (896 x
+    1,856 becomes 896 x 1,024; every tile of widths that multiples of 128
+    divide is under it and stays)."""
+    m, k, n = tile
+    while k * n > WEIGHTS_BLOCK and max(k, n) > 1024:
+        k, n = ((_fewest_tiles(k), n) if k >= n else (k, _fewest_tiles(n)))
+    return m, k, n
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -352,8 +397,9 @@ def _gmm_bwd(tile, interpret, dtype, res, g):
     m, k, n = tile
     d_lhs = _gmm(g, rhs, group_sizes, dtype, (m, n, k), transpose_rhs=True,
                  interpret=interpret)
-    d_rhs = _tgmm(lhs.swapaxes(0, 1), g, group_sizes, jnp.float32, (m, k, n),
-                  num_actual_groups=rhs.shape[0], interpret=interpret)
+    d_rhs = _tgmm(lhs.swapaxes(0, 1), g, group_sizes, jnp.float32,
+                  weights_tile(tile), num_actual_groups=rhs.shape[0],
+                  interpret=interpret)
     return d_lhs, d_rhs, None
 
 

@@ -132,6 +132,35 @@ def test_lm_stage():
         assert max(call["rel_err_loss_dh_dhead"]) <= chip_smoke.ATTN_BF16_TOL
 
 
+def test_product_kernels_rehearses_a_tile_sweep(monkeypatch):
+    """``chip_smoke.product_kernels`` off the chip: the six kernels of a
+    layer's two products in the interpreter at a tile that divides
+    neither width, a clock reading and no device time (that is the
+    chip's), and the row tiles the uneven groups visit."""
+    import functools
+
+    from multiverso_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_gmm", functools.partial(moe._gmm,
+                                                       interpret=True))
+    monkeypatch.setattr(moe, "_tgmm", functools.partial(moe._tgmm,
+                                                        interpret=True))
+    got = chip_smoke.product_kernels(
+        dim=320, ffn=232, rows=64, sizes=(21, 17, 9),
+        tiles=((16, 128, 232), (16, 320, 128)), repeats=1, chip=False)
+    assert set(got) == {"16x128x232", "16x320x128"}
+    for facts in got.values():
+        assert facts["tiles_visited"] == 5 and facts["host_ms"] > 0
+        assert facts["sum_ms"] is None and "refused" not in facts
+        assert all(facts[f"{p}.{k}"] is None for p in ("up", "down")
+                   for k in ("fwd", "dbuf", "dw"))
+    only = chip_smoke.product_kernels(
+        dim=320, ffn=232, rows=64, sizes=(21, 17, 9),
+        tiles=((16, 128, 128),), kinds=("dw",), repeats=1, chip=False)
+    assert set(only["16x128x128"]) >= {"up.dw", "down.dw"}
+    assert "up.fwd" not in only["16x128x128"]
+
+
 def test_flash_stage():
     """Whole tiles against sub-tiles of 16 and 8 under blocks of 32 and
     32 x 64, a band and a plain diagonal, in the interpreter."""

@@ -375,14 +375,49 @@ def test_published_sizes_give_the_issues_parameter_count():
             assert c["published"][key] == value
 
 
+# the eight expert configurations of the benchmark: seven at the tile the
+# rule gave them before PR 67, written out, and the one width that no
+# multiple of 128 divides at the new one
+CELL_TILES = {
+    "glm-4.7-flash-ep8": (2048, 1536, (512, 512, 512)),
+    "mellum2-12b-a2.5b-ep4": (2304, 896, (512, 768, 896)),
+    "trinity-mini-ep8": (2048, 1024, (512, 512, 512)),
+    "lfm2-8b-a1b-ep4": (2048, 1792, (512, 512, 896)),
+    "keye-vl-2.0-30b-a3b-ep8": (2048, 768, (512, 512, 768)),
+    "qwen3-next-80b-a3b-ep16": (2048, 512, (512, 512, 512)),
+    "xing4.0-29b-a4b-ep8": (3584, 1024, (512, 512, 512)),
+    # 1,856 = 14.5 x 128 is one tile, whole; rows by 256 beside it
+    "nemotron-3-nano-30b-a3b-ep16": (2688, 1856, (256, 896, 1856)),
+}
+
+
 @pytest.mark.parametrize("dim,ffn,want", [
-    (2048, 1536, (512, 512, 512)),      # glm-4.7-flash-ep8: as it was
-    (2304, 896, (512, 768, 896)),       # mellum2-12b-a2.5b-ep4
     (64, 48, (128, 128, 128)),          # a test's widths: the kernel's own
     (1024, 384, (128, 512, 384)),
-])
+    (2688, 2176, (128, 896, 128)),      # 17 x 128: only 128 divides it
+    (1856, 200, (256, 1856, 200)),      # whole either way round
+    (2048, 2100, (512, 512, 768)),      # too wide to take whole: 3 tiles
+] + sorted(CELL_TILES.values()), ids=lambda v: str(v).replace(" ", ""))
 def test_the_grouped_products_tile_follows_the_widths(dim, ffn, want):
     assert moe.product_tile(dim, ffn) == want
+    # the matrices' float32 gradient blocks fit 16 MiB three times over,
+    # and only a tile with a whole width is cut for them
+    for m, k, n in (moe.weights_tile(want),
+                    moe.weights_tile((want[0], want[2], want[1]))):
+        assert m == want[0] and 12 * k * n <= 12 << 20
+    if dim % 128 == ffn % 128 == 0:
+        assert moe.weights_tile(want) == want
+
+
+@pytest.mark.parametrize("name", sorted(CELL_TILES))
+def test_the_tiles_are_pinned_at_the_benchmarks_own_widths(name):
+    """``CELL_TILES``' widths are what the benchmark's configuration
+    files hand ``mla_moe.held`` as ``cfg.dim`` and ``cfg.moe_ffn``."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           name + ".json")) as f:
+        c = json.load(f)
+    assert (c["hidden_size"], c["moe_intermediate_size"]) == (
+        CELL_TILES[name][:2])
 
 
 @pytest.mark.parametrize("yarn", [None, YARN,

@@ -59,6 +59,50 @@ def test_rows_past_the_groups_reach_nothing(load):
         assert np.isnan(np.asarray(got)[~live]).all()
 
 
+@pytest.mark.parametrize("tile", [
+    (16, 128, 256),     # k = 320: three tiles, the last half masked; n whole
+    (16, 256, 128),     # k = 320: two tiles; n = 464: four, the last 80 wide
+    (16, 256, 256),     # neither width in whole tiles
+    (16, 320, 256),     # the whole of k in one tile: nothing to mask
+])
+def test_a_tile_that_divides_neither_width_gives_the_plain_products(tile):
+    """``grouped_matmul`` at a tile that divides neither ``k`` (320) nor
+    ``n`` (464), as ``moe.product_tile`` hands one over for a width like
+    1,856: ``megablox`` masks the last ``k`` tile's columns past the width
+    (whatever lies there, NaN in the interpreter, must reach no sum) and
+    drops the last ``n`` tile's. Forward, the buffer's gradient (the tile
+    turned round: ``n`` is contracted, so ITS last tile is the masked one)
+    and the weights' float32 gradient against the plain product a group,
+    the rows past the last group NaN coming in and unwritten going out."""
+    m, k, n = 96, 320, 464
+    sizes = [20, 0, 33, 11]
+    groups = jnp.asarray(sizes, jnp.int32)
+    ends = np.cumsum(sizes)
+    live = np.arange(m) < ends[-1]
+    rng = np.random.default_rng(sum(tile))
+    lhs, ct = rng.normal(size=(m, k)), rng.normal(size=(m, n))
+    lhs[~live] = ct[~live] = np.nan
+    rhs = rng.normal(size=(len(sizes), k, n))
+    got, vjp = jax.vjp(
+        lambda a, b: moe.grouped_matmul(a, b, groups, tile, True,
+                                        jnp.float32),
+        jnp.asarray(lhs, jnp.float32), jnp.asarray(rhs, jnp.float32))
+    d_lhs, d_rhs = vjp(jnp.asarray(ct, jnp.float32))
+    assert d_rhs.dtype == jnp.float32 and d_rhs.shape == rhs.shape
+    for g, (lo, hi) in enumerate(zip(ends - sizes, ends)):
+        np.testing.assert_allclose(
+            np.asarray(got)[lo:hi], np.einsum(
+                "rk,kn->rn", lhs[lo:hi], rhs[g]), rtol=1e-4, atol=1e-3)
+        np.testing.assert_allclose(
+            np.asarray(d_lhs)[lo:hi], np.einsum(
+                "rn,kn->rk", ct[lo:hi], rhs[g]), rtol=1e-4, atol=1e-3)
+        np.testing.assert_allclose(
+            np.asarray(d_rhs)[g], np.einsum(
+                "rk,rn->kn", lhs[lo:hi], ct[lo:hi]), rtol=1e-4, atol=1e-3)
+    assert np.isnan(np.asarray(got)[~live]).all()
+    assert np.isnan(np.asarray(d_lhs)[~live]).all()
+
+
 def _layer(form: str, route: str):
     """A layer of 8 experts, 4 held from the third on, 48 tokens choosing
     2: about 48 rows here, in a buffer of 96 (12 row tiles of 8)."""
@@ -397,6 +441,7 @@ def test_routing_counts_say_the_tiles_visited_and_the_buffers(held_share):
         counts[:, 64] = 4 * tokens - 16384
     said = mla_moe.routing_counts(counts, cfg)
     assert said["product_tiles_buffer"] == layers * 32
+    assert said["product_tile"] == "512x512x512"    # the tile that runs
     assert said["product_tiles_visited"] == {
         "even": layers * 16, "none": 0, "all": layers * 32}[held_share]
     assert said["held_rows"] == {"even": layers * 8192, "none": 0,

@@ -806,12 +806,16 @@ def test_relu2_expert_layer_at_rows_of_1856_compiles_for_a_v5e(one_chip):
     """The held experts' grouped products as ``nemotron3n-train-16k`` calls
     them: TWO products an expert (no gate matrix), a 12,288-row buffer in
     eight groups of 2,688 x 1,856. 1,856 is 14.5 lanes of 128: no multiple
-    of 128 divides it, so the products take the kernel's own 128 over that
-    width (its last tile half full) and 896 over 2,688."""
+    of 128 divides it, so the products take the WHOLE width as one tile
+    (PR 67; a group's weight block of 896 x 1,856 bfloat16 is 3.3 MB, held
+    twice), 896 over 2,688 and rows by 256; the matrices' float32 gradient
+    blocks would be 20 MB of VMEM at that tile and take 896 x 1,024."""
     from multiverso_tpu.parallel import moe
 
     tile = moe.product_tile(2688, 1856)
-    assert tile == (128, 896, 128)
+    assert tile == (256, 896, 1856)
+    assert moe.weights_tile(tile) == (256, 896, 1024)
+    assert moe.weights_tile((256, 1856, 896)) == (256, 1024, 896)
     held = moe.HeldExperts(num_experts=128, experts_held=8, top_k=6,
                            routed_scale=2.5, buffer_rows=12288, tile=tile,
                            form="relu2")

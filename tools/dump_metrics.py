@@ -921,6 +921,9 @@ def _buffer_lines(events: List[Dict]) -> List[str]:
              "product_tiles_buffer")):
         out.append(f"    {what:22s}  {total(ran)}  {total(held)}  "
                    f"{total(ran) / max(total(held), 1):.3f}")
+    if "product_tile" in steps[-1]:
+        out.append("    the products' tile (rows x over dim x over ffn)  "
+                   f"{steps[-1]['product_tile']}")
     return out
 
 
